@@ -15,11 +15,12 @@ Pinning model (two fences, one grammar):
   the only fence available on the forced-host CPU shape CI uses
   (``XLA_FLAGS=--xla_force_host_platform_device_count=K`` makes every
   process see all K virtual devices).
-- ``TPU_VISIBLE_DEVICES="2,3"`` — process-level, TPU hardware only: libtpu
-  hides the other chips entirely, so the runtime of agent *i* cannot touch
-  a neighbor's chips even by bug. The launcher sets both; on hardware the
-  in-process slice then reduces to ``0:count`` over the already-restricted
-  view.
+- ``TPU_VISIBLE_CHIPS="2,3"`` plus the process's chip bounds and its own
+  runtime port (:func:`tpu_process_env`) — process-level, TPU hardware only:
+  libtpu hides the other chips entirely, so the runtime of agent *i* cannot
+  touch a neighbor's chips even by bug, and N libtpu processes share one
+  host. The launcher sets both; on hardware the in-process slice then
+  reduces to ``0:count`` over the already-restricted view.
 
 ``python -m agent_tpu.agent.fleet`` is the **child** entry point: it
 optionally pre-warms the op executables from ``AGENT_WARM_FILE`` (a JSON
@@ -41,12 +42,7 @@ import time
 from typing import Any, Callable, Dict, Iterable, List, Optional
 
 from agent_tpu.utils.logging import log
-
-# Repo/package root for child PYTHONPATH: children run `-m agent_tpu...`
-# and must import the same tree the parent did, installed or not.
-_PKG_ROOT = os.path.dirname(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-)
+from agent_tpu.utils.paths import REPO_ROOT
 
 _FORCE_DEVICES_RE = re.compile(
     r"--xla_force_host_platform_device_count=\d+"
@@ -61,6 +57,46 @@ def force_host_devices(xla_flags: str, n: int) -> str:
     leak a different mesh size into fleet children)."""
     flags = _FORCE_DEVICES_RE.sub("", xla_flags or "").strip()
     return (f"{flags} --xla_force_host_platform_device_count={n}").strip()
+
+
+# The chips ONE libtpu process owns, as the x,y,z bounds libtpu wants, by how
+# many it owns. Only the counts that have run on a v5e 2x2 host
+# (chip_smoke.py --chips 4): a wrong row is a hang on hardware, so any other
+# count is a ValueError until someone has run it.
+_TPU_CHIP_BOUNDS = {1: "1,1,1", 4: "2,2,1"}
+
+
+# libtpu's default runtime port; a member listens on this plus the index of
+# its first chip. Chips are exclusive, so members of one host never collide.
+_TPU_PORT_BASE = 8476
+
+
+def tpu_process_env(index: int, devices_per_agent: int) -> Dict[str, str]:
+    """The libtpu environment that pins fleet member ``index`` to its own
+    chips on a host it shares with other libtpu processes.
+
+    Visibility alone is not enough for several processes on one host: each
+    must also be told the bounds of the chips it owns, that it is a whole
+    slice by itself (one process, no peers to wait for), and a runtime port
+    of its own — without these every process sizes itself for the whole
+    host and they collide on the default port."""
+    bounds = _TPU_CHIP_BOUNDS.get(devices_per_agent)
+    if bounds is None:
+        raise ValueError(
+            f"no libtpu chip bounds known for {devices_per_agent} chips per "
+            f"process (known: {sorted(_TPU_CHIP_BOUNDS)})"
+        )
+    chips = range(index * devices_per_agent, (index + 1) * devices_per_agent)
+    port = _TPU_PORT_BASE + chips[0]
+    return {
+        "TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chips),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": bounds,
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "TPU_PROCESS_PORT": str(port),
+        "CLOUD_TPU_TASK_ID": "0",
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
 
 
 def fleet_slice(index: int, devices_per_agent: int) -> str:
@@ -88,7 +124,7 @@ def agent_env(
     ``platform="cpu"`` is the CI/virtual shape: every child forces
     ``n_agents * devices_per_agent`` host devices and pins itself to its
     slice in-process. ``platform="tpu"`` is hardware: the child's process
-    sees only its chips (``TPU_VISIBLE_DEVICES``) and the in-process slice
+    sees only its chips (:func:`tpu_process_env`) and the in-process slice
     becomes ``0:count`` over that restricted view. ``mesh_shape`` (e.g.
     ``"dp=4"``) rides through to ``MESH_SHAPE`` for mesh-mode members.
     """
@@ -100,17 +136,20 @@ def agent_env(
     env["CONTROLLER_URL"] = controller_url
     env["AGENT_NAME"] = f"{name_prefix}-{index}"
     env["TASKS"] = tasks
+    # Children run `-m agent_tpu...` and must import the same tree the
+    # parent did, installed or not.
     env["PYTHONPATH"] = (
-        _PKG_ROOT + os.pathsep + env["PYTHONPATH"]
-        if env.get("PYTHONPATH") else _PKG_ROOT
+        REPO_ROOT + os.pathsep + env["PYTHONPATH"]
+        if env.get("PYTHONPATH") else REPO_ROOT
     )
     if platform == "tpu":
         # Process-level pinning: libtpu hides every chip outside the slice,
         # so the in-process slice is the identity over the visible view.
-        chips = range(
-            index * devices_per_agent, (index + 1) * devices_per_agent
-        )
-        env["TPU_VISIBLE_DEVICES"] = ",".join(str(c) for c in chips)
+        # JAX_PLATFORMS=tpu makes a child that cannot reach its chip fail at
+        # start-up instead of carrying on on the CPU (and overrides a parent
+        # held to the CPU: tests, a controller host).
+        env["JAX_PLATFORMS"] = "tpu"
+        env.update(tpu_process_env(index, devices_per_agent))
         env["CHIP_SLICE"] = f"0:{devices_per_agent}"
     else:
         env["JAX_PLATFORMS"] = "cpu"

@@ -218,8 +218,6 @@ class DeviceConfig:
     # live in ops/_model_common.apply_quant_env; this field is the typed,
     # read-once view for telemetry (runtime.describe) and operators.
     quant: str = ""
-    # Persistent XLA compilation cache directory ("" disables).
-    compile_cache_dir: str = ""
     # Fused Pallas attention kernel on TPU (PALLAS_ATTN=0 falls back to the
     # XLA dot-product path; CPU/GPU always use the XLA path).
     pallas_attn: bool = True
@@ -228,7 +226,7 @@ class DeviceConfig:
     # launcher (agent/fleet.py) gives each agent process a disjoint slice so
     # N single-slice agents share one host without fighting over chips; on
     # TPU hardware the launcher additionally pins visibility at the process
-    # level (TPU_VISIBLE_DEVICES), making the in-process slice an identity
+    # level (fleet.tpu_process_env), making the in-process slice an identity
     # check rather than the only fence.
     chip_slice: str = ""                        # CHIP_SLICE "start:count"
     # Multi-host SPMD (jax.distributed.initialize trio); unset → single host.
@@ -264,7 +262,6 @@ class DeviceConfig:
             mesh_shape=mesh,
             compute_dtype=env_str("COMPUTE_DTYPE", "bfloat16"),
             quant=env_str("TPU_QUANT", "").strip().lower(),
-            compile_cache_dir=env_str("JAX_COMPILATION_CACHE_DIR", ""),
             pallas_attn=env_bool("PALLAS_ATTN", True),
             chip_slice=env_str("CHIP_SLICE", "").strip(),
             coordinator_address=os.environ.get("COORDINATOR_ADDRESS") or None,
@@ -693,8 +690,9 @@ class ServeConfig:
     decode_slots: int = 8                  # SERVE_DECODE_SLOTS
     # Decode iterations fused per engine dispatch: 1 = pure iteration-level
     # batching (membership may change between every step); >1 amortizes
-    # per-step dispatch overhead where it dominates (tiny models, CPU,
-    # tunneled chips) — joins/exits then happen between chunks.
+    # per-step dispatch overhead where it dominates (tiny models, CPU; not
+    # measured on a directly attached chip) — joins/exits then happen
+    # between chunks.
     decode_micro_steps: int = 1            # SERVE_MICRO_STEPS
     # HTTP long-poll cap for blocking POST /v1/infer / ?wait_ms GETs.
     wait_timeout_sec: float = 60.0         # SERVE_WAIT_TIMEOUT_SEC

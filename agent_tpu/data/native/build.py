@@ -1,11 +1,13 @@
 """Lazy g++ build + ctypes load of the native CSV scanner.
 
-The shared object compiles once per source change into a cache directory
-(``AGENT_TPU_NATIVE_CACHE`` env, default ``~/.cache/agent_tpu``, falling back
-to a temp dir), keyed by a hash of ``csv_scan.cpp`` so edits rebuild and
-stale binaries never load. Everything is best-effort: no compiler, failed
-compile, or failed load all mean "return None" and callers use the
-pure-Python scanner (``csv_index._scan_row_offsets_py``).
+The shared object compiles once per source change from the committed
+``csv_scan.cpp`` into the checkout's own ignored cache directory
+(``utils.paths.cache_dir("native")`` — never the home directory or a temp
+dir, so nothing but what git would commit decides what runs), keyed by a
+hash of the source so edits rebuild and stale binaries never load.
+Everything is best-effort: no compiler, failed compile, or failed load all
+mean "return None" and callers use the pure-Python scanner
+(``csv_index._scan_row_offsets_py``).
 """
 
 from __future__ import annotations
@@ -15,30 +17,18 @@ import hashlib
 import os
 import shutil
 import subprocess
-import tempfile
 import threading
 from typing import Optional
 
 import numpy as np
+
+from agent_tpu.utils.paths import cache_dir
 
 _SRC = os.path.join(os.path.dirname(__file__), "csv_scan.cpp")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
-
-
-def _cache_dir() -> str:
-    d = os.environ.get("AGENT_TPU_NATIVE_CACHE")
-    if not d:
-        home = os.path.expanduser("~")
-        d = (
-            os.path.join(home, ".cache", "agent_tpu")
-            if os.path.isdir(home)
-            else os.path.join(tempfile.gettempdir(), "agent_tpu_native")
-        )
-    os.makedirs(d, exist_ok=True)
-    return d
 
 
 def _build() -> Optional[str]:
@@ -48,11 +38,12 @@ def _build() -> Optional[str]:
         return None
     with open(_SRC, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    out = os.path.join(_cache_dir(), f"csv_scan_{digest}.so")
+    out = os.path.join(cache_dir("native"), f"csv_scan_{digest}.so")
     if os.path.exists(out):
         return out
     tmp = out + f".tmp{os.getpid()}"
     try:
+        os.makedirs(os.path.dirname(out), exist_ok=True)
         proc = subprocess.run(
             [gxx, "-O3", "-shared", "-fPIC", _SRC, "-o", tmp],
             capture_output=True,

@@ -1,8 +1,8 @@
 """Compact binary shard wire — the round-4 uint8 raw-byte classify wire
 generalized into a codec (ISSUE 6 tentpole).
 
-The lease/result protocol is JSON, and at drain scale the JSON bodies ARE
-the tunnel cost: a classify shard's columnar result spells every score as
+The lease/result protocol is JSON, and at drain scale the JSON bodies are
+the bulk of the wire: a classify shard's columnar result spells every score as
 ``0.123456`` decimal text and a summarize shard ships its texts twice (task
 in, summaries out) as escaped JSON strings. This module packs the bulk
 columns of classify/summarize task and result payloads into one columnar,

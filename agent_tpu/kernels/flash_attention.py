@@ -32,9 +32,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from agent_tpu.models.layers import NEG_INF, dot_product_attention
-from agent_tpu.utils.compat import shape_dtype_struct, shard_map
 
 _LANES = 128  # VPU lane width; scratch last dims pad to this anyway
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``interpret=None`` → interpreter mode off-TPU. The tests' convenience
+    only (the identical kernel runs on the CPU mesh): ``TpuRuntime`` decides
+    from its own devices and always passes ``interpret=False`` explicitly."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 # Below this key length the XLA dense path wins END TO END. Attention-only
 # microbenchmarks on v5e show the kernel ahead already at Lk=512/d_head 64
@@ -214,8 +222,7 @@ def flash_attention(
     SELECTION_COUNTS["flash" if supported else "dense"] += 1
     if not supported:
         return dot_product_attention(q, k, v, mask)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     # [B, 1, Lk]: the singleton keeps the mask block's last-two dims legal
     # under Mosaic's (8, 128)-divisible-or-full rule (1 == full dim).
@@ -313,8 +320,7 @@ def flash_fold(q, k, v, mask, m, l, acc, *, block_q: int = 512,
     Lk = k.shape[2]
     bq = min(block_q, Lq)
     bk = min(block_k, Lk)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     mask3d = jnp.broadcast_to(mask[:, 0, :, :], (B, 1, Lk)).astype(jnp.int32)
     n_q, n_k = Lq // bq, Lk // bk
     kernel = functools.partial(
@@ -337,9 +343,9 @@ def flash_fold(q, k, v, mask, m, l, acc, *, block_q: int = 512,
         ],
         out_specs=(sspec, sspec, qspec),
         out_shape=(
-            shape_dtype_struct(m.shape, jnp.float32, vma=vma),
-            shape_dtype_struct(l.shape, jnp.float32, vma=vma),
-            shape_dtype_struct(acc.shape, jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct(m.shape, jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct(l.shape, jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct(acc.shape, jnp.float32, vma=vma),
         ),
         scratch_shapes=[
             pltpu.VMEM((bq, _LANES), jnp.float32),
@@ -454,8 +460,7 @@ def flash_attention_t5(
     )
     if not supported:
         return None
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     mask3d = jnp.broadcast_to(mask[:, 0, :, :], (B, 1, Lk)).astype(jnp.int32)
     n_q, n_k = Lq // bq, Lk // bk
@@ -500,7 +505,7 @@ def flash_attention_t5(
     )(q, k, v, mask3d, rel_bias.astype(jnp.float32))
 
 
-def make_flash_attention_t5(mesh):
+def make_flash_attention_t5(mesh, interpret: Optional[bool] = None):
     """Mesh-aware T5 kernel: ``flash_attention_t5`` wrapped in ``shard_map``
     (batch over ``dp``, heads over ``tp`` — the bias table's head dim shards
     with the heads). Same rationale as :func:`make_flash_attention`:
@@ -508,9 +513,12 @@ def make_flash_attention_t5(mesh):
     multi-chip mesh would replicate the full batch per chip. Returns a
     callable with the kernel's signature that yields **None** (dense
     fallback) for shapes the wrapper can't shard or the kernel declines.
+    ``interpret`` binds the kernel's mode for every call through the
+    returned callable (the runtime passes False; see
+    :func:`resolve_interpret`).
     """
     if mesh.size == 1:
-        return flash_attention_t5
+        return functools.partial(flash_attention_t5, interpret=interpret)
 
     from jax.sharding import PartitionSpec as P
 
@@ -520,7 +528,7 @@ def make_flash_attention_t5(mesh):
 
     def wrapper(q, k, v, mask, rel_bias, *, bidirectional=True,
                 max_distance=128, scale=1.0, block_q=512, block_k=512,
-                min_key_len=None, interpret=None):
+                min_key_len=None):
         from agent_tpu.models.layers import (
             is_key_padding_mask,
             materialize_key_padding_mask,
@@ -552,7 +560,7 @@ def make_flash_attention_t5(mesh):
             min_key_len=0,  # validated above, on the GLOBAL shapes
             interpret=interpret,
         )
-        sharded = shard_map(
+        sharded = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(
@@ -868,8 +876,7 @@ def flash_attention_trainable(
     SELECTION_COUNTS[key] = SELECTION_COUNTS.get(key, 0) + 1
     if not supported:
         return dot_product_attention(q, k, v, mask)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     scale = 1.0 / float(np.sqrt(D))
     mask3d = jnp.broadcast_to(mask[:, 0, :, :], (B, 1, Lk)).astype(jnp.int32)
     return _trainable_core(block_q, block_k, interpret, scale)(
@@ -930,7 +937,7 @@ def _make_mesh_wrapper(mesh, inner, dense_counter_key: Optional[str]):
     dp = shape.get("dp", 1)
     tp = shape.get("tp", 1)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(
@@ -968,18 +975,19 @@ def _make_mesh_wrapper(mesh, inner, dense_counter_key: Optional[str]):
     return mesh_attention
 
 
-def make_flash_attention_trainable(mesh):
+def make_flash_attention_trainable(mesh, interpret: Optional[bool] = None):
     """Mesh-aware trainable flash attention — :func:`make_flash_attention`
     for the training path. Batch shards over ``dp``, heads over ``tp``;
     ``shard_map`` differentiates through the per-shard ``custom_vjp``, so
     the backward kernels also run sharded. Unsupported shapes fall back to
     the dense path (GSPMD + autodiff handle it)."""
+    kernel = functools.partial(flash_attention_trainable, interpret=interpret)
     if mesh.size == 1:
-        return flash_attention_trainable
-    return _make_mesh_wrapper(mesh, flash_attention_trainable, "dense_train")
+        return kernel
+    return _make_mesh_wrapper(mesh, kernel, "dense_train")
 
 
-def make_flash_attention(mesh):
+def make_flash_attention(mesh, interpret: Optional[bool] = None):
     """Mesh-aware flash attention: the kernel wrapped in ``shard_map``.
 
     ``pallas_call`` has no GSPMD partitioning rule, so jitting the bare kernel
@@ -988,9 +996,12 @@ def make_flash_attention(mesh):
     ``dp``, heads over ``tp``) keeps each chip on its own shard. Single-device
     meshes skip the wrapper. Shapes the wrapper can't shard (batch or heads
     indivisible) fall back to the dense XLA path, which GSPMD partitions fine.
+    ``interpret`` binds the kernel's mode (the runtime passes False; see
+    :func:`resolve_interpret`).
     """
+    kernel = functools.partial(flash_attention, interpret=interpret)
     if mesh.size == 1:
-        return flash_attention
+        return kernel
     # No counter key: the wrapper-level dense fallback predates the proof
     # discipline and tests pin the "dense" counter to per-kernel decisions.
-    return _make_mesh_wrapper(mesh, flash_attention, None)
+    return _make_mesh_wrapper(mesh, kernel, None)

@@ -27,10 +27,26 @@ StepFn = Callable[[jax.Array, jax.Array, Any], Tuple[jax.Array, Any]]
 
 # The continuous engine's per-row step: positions are a [rows] vector (each
 # running-batch slot sits at its own decode depth) and the encoder state is
-# an argument (slots join with their own prefill output).
+# an argument (slots join with their own prefill output). So are the model
+# parameters (first): a step that closed over them would compile every
+# weight into the program as a constant — at 768 wide a 393 MB executable
+# that no compile cache holds, and a second copy of the decoder in HBM.
 PositionalStepFn = Callable[
-    [jax.Array, jax.Array, Any, jax.Array, jax.Array], Tuple[jax.Array, Any]
+    [Any, jax.Array, jax.Array, Any, jax.Array, jax.Array],
+    Tuple[jax.Array, Any],
 ]
+
+
+def _state_sharding(params: Any) -> Optional[jax.sharding.Sharding]:
+    """Replicated over the mesh ``params`` are placed on; ``None`` for
+    parameters nobody placed (plain arrays, as the engine tests pass)."""
+    leaves = jax.tree_util.tree_leaves(params)
+    placed = getattr(leaves[0], "sharding", None) if leaves else None
+    if isinstance(placed, jax.sharding.NamedSharding):
+        return jax.sharding.NamedSharding(
+            placed.mesh, jax.sharding.PartitionSpec()
+        )
+    return None
 
 
 class KVPoolExhausted(Exception):
@@ -461,7 +477,14 @@ class ContinuousBatcher:
     Prefill is NOT this engine's job: callers encode (batched, as its own
     step — the ``summarize_mpmd`` encoded handoff) and admit
     ``(enc_row, mask_row)`` per request. ``step_fn`` is a
-    :data:`PositionalStepFn` (e.g. ``seq2seq.make_positional_step``).
+    :data:`PositionalStepFn` (e.g. ``seq2seq.make_positional_step``) and
+    ``params`` the model parameters it is called with — an argument of the
+    jitted step, never donated (they stay the params store's). Everything
+    else the jitted programs take (the state built here, the pushed block
+    table, the admitted rows) is placed on the params' mesh, replicated:
+    what a program over mesh-placed params returns is typed with that mesh,
+    and an input typed otherwise would retrace step and insert at every
+    join (``tests/test_serving.py`` counts the executables).
 
     Host loop by design: one jitted step per iteration, state threaded
     through with buffer donation where the backend supports it. That trades
@@ -474,6 +497,7 @@ class ContinuousBatcher:
         step_fn: PositionalStepFn,
         cache_factory: Callable[[int], Any],
         *,
+        params: Any,
         slots: int,
         vocab_size: int,
         max_tokens: int,
@@ -503,6 +527,8 @@ class ContinuousBatcher:
                 f"got {cache_reorder!r}"
             )
         self.step_fn = step_fn
+        self._params = params
+        self._sharding = _state_sharding(params)
         self.slots = int(slots)
         self.K = int(num_beams)
         self.V = int(vocab_size)
@@ -519,12 +545,13 @@ class ContinuousBatcher:
         # Decode iterations fused per dispatch: 1 (default) is pure
         # iteration-level batching — membership can change between every
         # step. Dispatch-overhead-bound deployments (small models, CPU
-        # smoke, tunneled chips) raise it: N iterations run as one jitted
-        # ``fori_loop`` program (XLA reuses buffers across the chained
-        # updates, recovering most of the scan engines' zero-overhead
-        # stepping), and joins/exits happen between CHUNKS — completed
-        # slots ride out the remainder of a chunk frozen, exactly like
-        # empty slots, so per-request outputs are unchanged.
+        # smoke; not measured on a directly attached chip) raise it: N
+        # iterations run as one jitted ``fori_loop`` program (XLA reuses
+        # buffers across the chained updates, recovering most of the scan
+        # engines' zero-overhead stepping), and joins/exits happen between
+        # CHUNKS — completed slots ride out the remainder of a chunk
+        # frozen, exactly like empty slots, so per-request outputs are
+        # unchanged.
         self.micro_steps = int(micro_steps)
         self._clock = clock
         S, K, T, R = self.slots, self.K, self.T, self.slots * self.K
@@ -583,12 +610,12 @@ class ContinuousBatcher:
             )
         else:
             dyn["toks"] = jnp.full((S, T), self.pad_id, dtype=jnp.int32)
-        self._dyn = dyn
-        self._stat: Dict[str, Any] = {
+        self._dyn = self._put(dyn)
+        self._stat: Dict[str, Any] = self._put({
             "limit": jnp.ones((S,), dtype=jnp.int32),
             "enc_out": jnp.zeros((R, self.enc_len, d_model), dtype=enc_dtype),
             "enc_mask": jnp.zeros((R, self.enc_len), dtype=jnp.int32),
-        }
+        })
         # Buffer donation makes the step/insert updates in-place on backends
         # that support it; CPU copies and warns — silence the known-benign
         # warning rather than fork the code path.
@@ -599,9 +626,9 @@ class ContinuousBatcher:
         if self.micro_steps > 1:
             n = self.micro_steps
 
-            def chunk(dyn, stat):
+            def chunk(dyn, stat, params):
                 return jax.lax.fori_loop(
-                    0, n, lambda _i, d: step_impl(d, stat), dyn
+                    0, n, lambda _i, d: step_impl(d, stat, params), dyn
                 )
 
             self._jstep = jax.jit(chunk, donate_argnums=0)
@@ -617,15 +644,20 @@ class ContinuousBatcher:
         self.max_occupancy = 0
         self.tokens_emitted = 0
 
+    def _put(self, tree: Any) -> Any:
+        """Host or device arrays → device arrays where the params live (the
+        default device, uncommitted, for params nobody placed)."""
+        return jax.device_put(tree, self._sharding)
+
     # ---- jitted programs ----
 
     def _step_greedy(
-        self, state: Dict[str, Any], stat: Dict[str, Any]
+        self, state: Dict[str, Any], stat: Dict[str, Any], params: Any
     ) -> Dict[str, Any]:
         S, T = self.slots, self.T
         pos, row_done = state["pos"], state["row_done"]
         logits, caches = self.step_fn(
-            state["tok"], pos, state["caches"],
+            params, state["tok"], pos, state["caches"],
             stat["enc_out"], stat["enc_mask"],
         )
         logits = _ban_eos_before_rows(
@@ -645,7 +677,7 @@ class ContinuousBatcher:
         )
 
     def _step_beam(
-        self, state: Dict[str, Any], stat: Dict[str, Any]
+        self, state: Dict[str, Any], stat: Dict[str, Any], params: Any
     ) -> Dict[str, Any]:
         """One continuous-batching beam step — ``beam_scan``'s body with the
         scalar step replaced by the per-slot ``pos`` vector, plus the
@@ -660,7 +692,7 @@ class ContinuousBatcher:
 
         pos_rows = jnp.repeat(pos, K)
         logits, caches = self.step_fn(
-            state["tok"], pos_rows, state["caches"],
+            params, state["tok"], pos_rows, state["caches"],
             stat["enc_out"], stat["enc_mask"],
         )
         logp = jax.nn.log_softmax(logits, axis=-1).reshape(S, K, V)
@@ -871,6 +903,15 @@ class ContinuousBatcher:
             return 0.0
         return self.occupancy_sum / self.steps_run
 
+    def executables(self) -> Dict[str, int]:
+        """Executables compiled so far for the two jitted programs. A warm
+        engine holds one of each however many requests have joined; more
+        means an input's type or shape changed between calls."""
+        return {
+            "step": self._jstep._cache_size(),
+            "insert": self._jinsert._cache_size(),
+        }
+
     # ---- paged-KV host allocator (ISSUE 16) ----
 
     @property
@@ -912,7 +953,7 @@ class ContinuousBatcher:
 
     def _push_table(self) -> None:
         if self.paged and self._table_dirty:
-            self._dyn["caches"]["table"] = jnp.asarray(self._table_np)
+            self._dyn["caches"]["table"] = self._put(self._table_np)
             self._table_dirty = False
 
     def admit(
@@ -959,7 +1000,7 @@ class ContinuousBatcher:
                 self._allocate_blocks(slot, ticket.limit)
             self._dyn, self._stat = self._jinsert(
                 self._dyn, self._stat, np.int32(slot),
-                jnp.asarray(ticket.enc_row), jnp.asarray(ticket.mask_row),
+                self._put(ticket.enc_row), self._put(ticket.mask_row),
                 np.int32(ticket.limit),
             )
             ticket.slot = slot
@@ -995,7 +1036,7 @@ class ContinuousBatcher:
             if not self._live:
                 return []
         self._push_table()
-        self._dyn = self._jstep(self._dyn, self._stat)
+        self._dyn = self._jstep(self._dyn, self._stat, self._params)
         self.steps_run += self.micro_steps
         self.occupancy_sum += len(self._live) * self.micro_steps
         self.max_occupancy = max(self.max_occupancy, len(self._live))

@@ -277,15 +277,16 @@ def beam_generate(
     )
 
 
-def make_positional_step(params: Params, cfg: Seq2SeqConfig):
+def make_positional_step(cfg: Seq2SeqConfig):
     """The per-row-position decode step the continuous-batching engine
     (``models.decoding.ContinuousBatcher``) drives: unlike the scan engines'
     closures, the encoder state is an ARGUMENT, because slots join a running
     batch with their own encoder output (the prefill/decode split — prefill
     produced ``enc_out`` earlier, possibly on another agent, cf.
-    ``greedy_generate_from_encoded``)."""
+    ``greedy_generate_from_encoded``). The parameters are an argument too
+    (see ``decoding.PositionalStepFn``)."""
 
-    def step_fn(tok, pos_rows, caches, enc_out, enc_mask):
+    def step_fn(params, tok, pos_rows, caches, enc_out, enc_mask):
         return _decode_step(
             params, tok, pos_rows, enc_out.astype(cfg.compute_dtype),
             enc_mask, caches, cfg,

@@ -217,32 +217,21 @@ def _pad_bias(mask: jax.Array) -> jax.Array:
 
 
 def encode(params: Params, src_ids: jax.Array, src_mask: jax.Array,
-           cfg: T5Config, use_flash: Optional[bool] = None,
-           kernel=None) -> jax.Array:
+           cfg: T5Config, kernel=None) -> jax.Array:
     """Encoder stack → [B, Ls, d].
 
-    Long-context path: self-attention routes through the fused Pallas T5
-    kernel, which computes the bucketed relative-position bias per tile in
-    VMEM instead of materializing the [H, Ls, Ls] bias in HBM. ``kernel``
-    lets the caller pass a mesh-aware wrapper
-    (``kernels.make_flash_attention_t5(mesh)`` — batch over dp, heads over
-    tp); with ``kernel=None``, ``use_flash`` (default: auto — single-chip
-    TPU traces only, since bare ``pallas_call`` has no GSPMD partitioning
-    rule) selects the plain kernel. Either declines unsupported shapes at
-    trace time (returns None) and the layer falls back to the dense path
-    with a lazily built dense bias; kernel == dense is asserted in tests.
+    Long-context path: with a ``kernel``, self-attention routes through the
+    fused Pallas T5 kernel, which computes the bucketed relative-position
+    bias per tile in VMEM instead of materializing the [H, Ls, Ls] bias in
+    HBM. The kernel comes from ``TpuRuntime.t5_attention_kernel()`` — the
+    runtime decides from its own devices and hands over the mesh-aware
+    wrapper (batch over dp, heads over tp) — or not at all: ``kernel=None``
+    is the dense path. The kernel declines unsupported shapes at trace time
+    (returns None) and the layer falls back to the dense path with a lazily
+    built dense bias; kernel == dense is asserted in tests.
     """
     dtype = cfg.compute_dtype
     B, L = src_ids.shape
-    if kernel is None:
-        if use_flash is None:
-            use_flash = (
-                jax.default_backend() == "tpu" and jax.device_count() == 1
-            )
-        if use_flash:
-            from agent_tpu.kernels.flash_attention import flash_attention_t5
-
-            kernel = flash_attention_t5
     x = jnp.asarray(params["embed"]).astype(dtype)[src_ids]
     rel_bias = jnp.asarray(params["enc"]["rel_bias"])
     mask4 = src_mask[:, None, None, :].astype(jnp.int32)

@@ -213,8 +213,8 @@ def byte_encode_pad(
     per-row lengths (not a mask): the device path rebuilds the mask on-chip.
 
     ``raw_uint8=True`` returns the UNSHIFTED bytes as uint8 — the minimal
-    wire format for tunnel-limited host→device links (1 byte/token instead
-    of 2): the compiled program reconstructs ``ids = (raw + N_SPECIAL) *
+    host→device wire format (1 byte/token instead of 2): the compiled
+    program reconstructs ``ids = (raw + N_SPECIAL) *
     mask`` on device (see ``map_classify_tpu``), which is exact because with
     no BOS/EOS every non-pad id is ``byte + N_SPECIAL`` and the mask already
     distinguishes a body NUL byte (raw 0, masked in) from padding (raw 0,

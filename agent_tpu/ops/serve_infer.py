@@ -55,6 +55,16 @@ def reset_engines() -> None:
     _PREFIX_KNOBS = None
 
 
+def engine_executables() -> List[Dict[str, int]]:
+    """Per cached engine: its source bucket and how many executables its
+    step and insert programs hold (``ContinuousBatcher.executables``). One
+    of each is a warm engine; more is a retrace at every join."""
+    return [
+        {"bucket": int(key[3]), **engine.executables()}
+        for key, engine in list(_ENGINES.items())
+    ]
+
+
 def _get_prefix_cache(serve):
     """The process prefix cache per the active knobs, or ``None`` when
     disabled."""
@@ -336,8 +346,9 @@ def _get_engine(runtime, params, state, serve):
         else:
             cache_factory = seq2seq.make_cache_factory(cfg)
         engine = ContinuousBatcher(
-            seq2seq.make_positional_step(params, cfg),
+            seq2seq.make_positional_step(cfg),
             cache_factory,
+            params=params,
             slots=slots,
             vocab_size=cfg.vocab_size,
             max_tokens=cfg.max_tgt_len,

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from agent_tpu.utils.compat import shard_map
-
 
 def _padded_len(n: int, multiple: int) -> int:
     """Smallest power-of-two bucket ≥ n that is a multiple of ``multiple``."""
@@ -65,7 +63,7 @@ def _build_stats_fn(runtime) -> Any:
         k_mx = lax.pmax(jnp.max(jnp.where(m > 0, key, jnp.uint32(0))), "dp")
         return s_hi, s_lo, k_mn, k_mx
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_stats,
         mesh=mesh,
         in_specs=(P("dp"), P("dp"), P("dp")),

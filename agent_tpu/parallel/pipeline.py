@@ -39,16 +39,12 @@ from jax.sharding import PartitionSpec as P
 
 from agent_tpu.models import layers
 from agent_tpu.models.layers import dot_product_attention
-from agent_tpu.utils.compat import shard_map, stack_leaves
 
 
 def stack_blocks(blocks: List[Any]) -> Any:
     """List of per-layer block pytrees → one pytree whose leaves carry a
-    leading ``n_layers`` dim (scan-ready; reshaped per-stage by the caller).
-    Staging goes through ``compat.stack_leaves``: the stacked leaves feed a
-    ``P("pp")``-sharded shard_map operand, which legacy jax miscompiles for
-    a traced concatenate."""
-    return jax.tree_util.tree_map(lambda *ls: stack_leaves(ls), *blocks)
+    leading ``n_layers`` dim (scan-ready; reshaped per-stage by the caller)."""
+    return jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *blocks)
 
 
 def stage_blocks(stacked: Any, pp: int) -> Any:
@@ -129,7 +125,7 @@ def pipeline_blocks(
         # Only the last stage accumulated; psum over pp broadcasts it.
         return jax.lax.psum(acc, "pp")
 
-    out = shard_map(
+    out = jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=(stage_specs(staged), P(None, "dp"), P(None, "dp")),

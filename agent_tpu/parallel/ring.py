@@ -31,7 +31,7 @@ so callers never need a compatibility check.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any
+from typing import Optional
 
 import numpy as np
 
@@ -41,10 +41,10 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from agent_tpu.models.layers import NEG_INF, dot_product_attention
-from agent_tpu.utils.compat import pcast_varying, shard_map
 
 
-def _ring_local(q, k, v, mask, sp: int, use_flash_fold: bool = False):
+def _ring_local(q, k, v, mask, sp: int, use_flash_fold: bool = False,
+                interpret: bool = False):
     """Per-device body: streaming-softmax attention over ``sp`` ring hops.
 
     q: [b, h, lq, d] (local Q block, f32-scaled below)
@@ -70,9 +70,8 @@ def _ring_local(q, k, v, mask, sp: int, use_flash_fold: bool = False):
 
     b, h, lq, _ = q.shape
     # Mark the zero-init carry device-varying: shard_map requires the scan
-    # carry's manual-axes type to match its (varying) outputs. (No-op on
-    # pre-vma jax — see compat.pcast_varying.)
-    varying = partial(pcast_varying, axis_name=("dp", "tp", "sp"))
+    # carry's manual-axes type to match its (varying) outputs.
+    varying = partial(lax.pcast, axis_name=("dp", "tp", "sp"), to="varying")
     m0 = varying(jnp.full((b, h, lq, 1), NEG_INF, dtype=jnp.float32))
     l0 = varying(jnp.zeros((b, h, lq, 1), dtype=jnp.float32))
     acc0 = varying(jnp.zeros(q.shape, dtype=jnp.float32))
@@ -89,7 +88,7 @@ def _ring_local(q, k, v, mask, sp: int, use_flash_fold: bool = False):
         if use_flash_fold:
             return flash_fold(
                 q, k_blk, v_blk, m_blk, m, l, acc,
-                vma=frozenset({"dp", "tp", "sp"}),
+                interpret=interpret, vma=frozenset({"dp", "tp", "sp"}),
             )
         scores = jnp.einsum(
             "bhqd,bhkd->bhqk", qf, k_blk.astype(jnp.float32)
@@ -125,7 +124,8 @@ def _ring_local(q, k, v, mask, sp: int, use_flash_fold: bool = False):
     return (acc / jnp.maximum(l, 1e-30)).astype(out_dtype)
 
 
-def make_ring_attention(mesh: Mesh, use_flash_fold: bool = None):
+def make_ring_attention(mesh: Mesh, use_flash_fold: bool = False,
+                        interpret: Optional[bool] = None):
     """``attn_fn`` running ring attention over ``mesh``'s ``sp`` axis.
 
     With ``sp == 1`` (or shapes/mask the ring can't take) this is exactly
@@ -133,8 +133,10 @@ def make_ring_attention(mesh: Mesh, use_flash_fold: bool = None):
     different mesh, preserving the framework's one-codepath rule
     (SURVEY.md §7: fallback is a backend/mesh switch, not a second model).
 
-    ``use_flash_fold`` (default: auto — on for real TPU) runs each hop's
-    local fold as the fused Pallas kernel.
+    ``use_flash_fold`` runs each hop's local fold as the fused Pallas
+    kernel, in the mode ``interpret`` names. ``TpuRuntime.attention_fn``
+    decides both from its own devices (fold on, compiled, on a TPU);
+    ``interpret=None`` is the kernels' off-TPU auto-select for tests.
     """
     shape = dict(mesh.shape)
     sp = shape.get("sp", 1)
@@ -142,11 +144,13 @@ def make_ring_attention(mesh: Mesh, use_flash_fold: bool = None):
         return dot_product_attention
     dp = shape.get("dp", 1)
     tp = shape.get("tp", 1)
-    if use_flash_fold is None:
-        use_flash_fold = jax.default_backend() == "tpu"
+    from agent_tpu.kernels.flash_attention import resolve_interpret
 
-    sharded = shard_map(
-        partial(_ring_local, sp=sp, use_flash_fold=use_flash_fold),
+    interpret = use_flash_fold and resolve_interpret(interpret)
+
+    sharded = jax.shard_map(
+        partial(_ring_local, sp=sp, use_flash_fold=use_flash_fold,
+                interpret=interpret),
         mesh=mesh,
         in_specs=(
             P("dp", "tp", "sp", None),   # q: heads over tp, Lq over sp
@@ -160,7 +164,7 @@ def make_ring_attention(mesh: Mesh, use_flash_fold: bool = None):
         # exactly this workaround). Scoped to interpret mode only: compiled
         # TPU runs keep full varying-mesh-axes verification (the fold's
         # outputs carry their vma annotation).
-        check_vma=not (use_flash_fold and jax.default_backend() != "tpu"),
+        check_vma=not (use_flash_fold and interpret),
     )
 
     def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,

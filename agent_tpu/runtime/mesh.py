@@ -98,12 +98,27 @@ def build_mesh(
 ) -> Mesh:
     """Build a :class:`jax.sharding.Mesh` over ``devices`` with spec ``shape``.
 
-    Device order is kept as given (``jax.devices()`` order respects ICI
-    topology on TPU, so neighboring mesh coordinates are ICI neighbors — the
-    property ring collectives need).
+    Device order is kept as given, with one exception: on several TPU chips
+    an axis that spans ALL of them is laid out by
+    ``mesh_utils.create_device_mesh``, which knows the chips' coordinates.
+    Collectives on such an axis walk a ring, and list order is not one — on
+    a 2x2 host ``jax.devices()`` goes 0→1→2→3 while the physical ring is
+    0→1→3→2 (1→2 and 3→0 are diagonals, two hops). A mesh with several
+    real axes keeps list order: on a 2x2 host that already puts every
+    axis's pairs on ICI neighbours, where the ring order would put one
+    axis's pairs on the diagonals.
     """
     if devices is None:
         devices = jax.devices()
     spec = MeshSpec.resolve(len(devices), shape)
-    grid = np.asarray(devices, dtype=object).reshape(spec.sizes)
+    if (
+        len(devices) > 1
+        and devices[0].platform == "tpu"
+        and max(spec.sizes) == len(devices)
+    ):
+        from jax.experimental import mesh_utils
+
+        grid = mesh_utils.create_device_mesh(spec.sizes, list(devices))
+    else:
+        grid = np.asarray(devices, dtype=object).reshape(spec.sizes)
     return Mesh(grid, spec.names)

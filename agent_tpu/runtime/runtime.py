@@ -22,6 +22,7 @@ from agent_tpu.config import DeviceConfig
 from agent_tpu.runtime.executor import ExecutableCache
 from agent_tpu.runtime.mesh import build_mesh
 from agent_tpu.utils.logging import log
+from agent_tpu.utils.paths import cache_dir
 
 
 def parse_chip_slice(spec: str) -> Tuple[int, int]:
@@ -70,16 +71,17 @@ def detect_platform(tpu_disabled: bool = False) -> str:
     backend ('cpu'/'gpu'). Mirrors reference worker_sizing.py:195-213.
 
     With the kill-switch on we return 'cpu' *without* querying the default
-    backend at all — ``jax.devices()`` would initialize the TPU plugin (HBM
+    backend at all — ``jax.devices()`` would initialize the TPU backend (HBM
     prealloc, possible hang on a wedged chip), which is exactly what the
     switch exists to prevent.
+
+    A backend that fails to initialize RAISES: an agent that was meant for a
+    chip must not carry on on the CPU and report success from the wrong
+    device.
     """
     if tpu_disabled:
         return "cpu"
-    try:
-        return jax.devices()[0].platform
-    except Exception:  # noqa: BLE001 — no backend at all ⇒ cpu fallback
-        return "cpu"
+    return jax.devices()[0].platform
 
 
 class TpuRuntime:
@@ -96,9 +98,15 @@ class TpuRuntime:
         devices: Optional[Sequence[jax.Device]] = None,
     ) -> None:
         self.config = config or DeviceConfig()
-        if self.config.compile_cache_dir:
+        if not jax.config.jax_compilation_cache_dir:
             # Persistent XLA compile cache: restarts skip recompiles (§5.4).
-            jax.config.update("jax_compilation_cache_dir", self.config.compile_cache_dir)
+            # JAX reads JAX_COMPILATION_CACHE_DIR itself; only when nothing
+            # placed the cache from outside does it go to the checkout's one
+            # fixed directory (the path is part of the cache key, so it must
+            # not move between runs).
+            jax.config.update(
+                "jax_compilation_cache_dir", cache_dir("xla")
+            )
         # Multi-host: join the coordination service BEFORE device discovery so
         # jax.devices() reports the global slice (SURVEY.md §5.8).
         from agent_tpu.runtime.distributed import maybe_initialize
@@ -119,6 +127,12 @@ class TpuRuntime:
                 devices = apply_chip_slice(devices, self.config.chip_slice)
         self.devices = list(devices)
         self.platform = self.devices[0].platform
+        # Decided ONCE, from the devices this runtime owns: a TPU runtime
+        # runs the Pallas kernels compiled (``interpret=False``, passed
+        # explicitly at every selection site below) and nothing else selects
+        # them — the kernels' own ``interpret=None`` auto-select is a test
+        # convenience, never the serving decision.
+        self.pallas = self.platform == "tpu" and self.config.pallas_attn
         if self.config.profile_port:
             # Live XProf endpoint (SURVEY.md §5.1): `xprof --port` /
             # TensorBoard can attach to capture device traces on demand.
@@ -173,11 +187,15 @@ class TpuRuntime:
             if self.axis_size("sp") > 1:
                 from agent_tpu.parallel.ring import make_ring_attention
 
-                self._attention_fn = make_ring_attention(self.mesh)
-            elif self.platform == "tpu" and self.config.pallas_attn:
+                self._attention_fn = make_ring_attention(
+                    self.mesh, use_flash_fold=self.pallas, interpret=False
+                )
+            elif self.pallas:
                 from agent_tpu.kernels import make_flash_attention
 
-                self._attention_fn = make_flash_attention(self.mesh)
+                self._attention_fn = make_flash_attention(
+                    self.mesh, interpret=False
+                )
             else:
                 from agent_tpu.models.layers import dot_product_attention
 
@@ -196,15 +214,11 @@ class TpuRuntime:
         unsupported shapes, keeping the return a safe drop-in ``attn_fn``.
         """
         if self._train_attention_fn is None:
-            if (
-                self.platform == "tpu"
-                and self.config.pallas_attn
-                and self.axis_size("sp") == 1
-            ):
+            if self.pallas and self.axis_size("sp") == 1:
                 from agent_tpu.kernels import make_flash_attention_trainable
 
                 self._train_attention_fn = make_flash_attention_trainable(
-                    self.mesh
+                    self.mesh, interpret=False
                 )
             else:
                 from agent_tpu.models.layers import dot_product_attention
@@ -226,12 +240,14 @@ class TpuRuntime:
         """
         if not self._t5_kernel_built:
             self._t5_kernel_built = True
-            if self.platform == "tpu" and self.config.pallas_attn:
+            if self.pallas:
                 from agent_tpu.kernels.flash_attention import (
                     make_flash_attention_t5,
                 )
 
-                self._t5_kernel = make_flash_attention_t5(self.mesh)
+                self._t5_kernel = make_flash_attention_t5(
+                    self.mesh, interpret=False
+                )
         return self._t5_kernel
 
     def replicated(self) -> NamedSharding:

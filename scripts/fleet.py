@@ -11,7 +11,8 @@ host, all leasing from one controller.
         --controller http://127.0.0.1:8080
 
 Each member is pinned to a disjoint device slice (``CHIP_SLICE``; plus
-``TPU_VISIBLE_DEVICES`` on hardware — see ``agent_tpu/agent/fleet.py``) and
+libtpu's per-process chip visibility, bounds and port on hardware — see
+``tpu_process_env`` in ``agent_tpu/agent/fleet.py``) and
 optionally pre-warms its executables from ``--warm-file`` before the first
 lease. The launcher waits for every member's first controller poll, then
 blocks until SIGINT/SIGTERM, which it forwards for a graceful drain.

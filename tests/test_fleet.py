@@ -119,14 +119,48 @@ class TestFleetEnv:
 
     def test_tpu_members_pin_at_process_level(self):
         env = fleet.agent_env(
-            1, 4, 2, controller_url="http://c:1", tasks="echo",
-            platform="tpu", base_env={},
+            0, 1, 4, controller_url="http://c:1", tasks="echo",
+            platform="tpu", base_env={"JAX_PLATFORMS": "cpu"},
+            mesh_shape="dp=4",
         )
-        assert env["TPU_VISIBLE_DEVICES"] == "2,3"
+        assert env["TPU_VISIBLE_CHIPS"] == "0,1,2,3"
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "2,2,1"
         # In-process slice is identity over the restricted view.
-        assert env["CHIP_SLICE"] == "0:2"
+        assert env["CHIP_SLICE"] == "0:4"
         assert "XLA_FLAGS" not in env or \
             "force_host_platform" not in env["XLA_FLAGS"]
+        # A child that cannot reach its chip fails; it never inherits the
+        # parent's CPU pin.
+        assert env["JAX_PLATFORMS"] == "tpu"
+
+    def test_tpu_members_are_whole_slices_on_their_own_ports(self):
+        """Four one-chip libtpu processes on one host: each sees one chip,
+        is a slice by itself, and owns a distinct runtime port."""
+        envs = [
+            fleet.agent_env(
+                i, 4, 1, controller_url="http://c:1", tasks="echo",
+                platform="tpu", base_env={},
+            )
+            for i in range(4)
+        ]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+        for e in envs:
+            assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+            assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+            assert e["CLOUD_TPU_TASK_ID"] == "0"
+            assert e["TPU_PROCESS_ADDRESSES"] == \
+                f"localhost:{e['TPU_PROCESS_PORT']}"
+        assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+
+    @pytest.mark.parametrize("chips", [2, 3, 8])
+    def test_tpu_chip_count_never_run_raises(self, chips):
+        """Only the per-process chip counts that have run on hardware have
+        bounds; any other is an error here, not a hang on the host."""
+        with pytest.raises(ValueError, match="chip bounds"):
+            fleet.agent_env(
+                0, 1, chips, controller_url="http://c:1", tasks="echo",
+                platform="tpu", base_env={},
+            )
 
     def test_mesh_and_warm_ride_through(self):
         env = fleet.agent_env(

@@ -239,6 +239,27 @@ def test_deferred_fetch_contract(classify, ctx):
         [e["index"] for e in out["topk"]]
 
 
+def test_packed_result_rides_integer_lanes(classify, ctx):
+    """The fused [B, k, 2] device result is an INTEGER array (score bit
+    patterns + indices), never indices bitcast into a float array: a small
+    index is a denormal float, and a TPU flushes denormals to zero where
+    the packing fuses with float arithmetic (seen on v5e: every index of a
+    1-row batch came back 0). The CPU does not flush, so the dtype is what
+    a CPU test can pin; ``chip_smoke.py`` checks the served labels."""
+    from agent_tpu.ops import map_classify_tpu as op
+
+    _, state = op.stage(
+        {"texts": ["packed row"], "topk": 3, "allow_fallback": False}, ctx
+    )
+    state = op.execute(state, ctx)
+    ((packed, n),) = state["pending_dev"]
+    assert packed.dtype == np.int32 and packed.shape[1:] == (3, 2)
+    vals, idx = op._fetch_pending(state["pending_dev"])
+    assert vals.dtype == np.float32 and idx.dtype == np.int32
+    assert n == 1 and 0.0 < vals[0, 0] <= 1.0
+    assert (np.diff(vals[0]) <= 0).all()  # top-k scores, descending
+
+
 def test_split_padded_chunk_unit(monkeypatch):
     """Dense-path dispatch splitting: budget respected, slices are batch
     buckets dividing the parent, real-row accounting exact, flash lengths

@@ -91,10 +91,11 @@ def _paged_engine(
     from agent_tpu.models.tokenizer import BOS_ID, EOS_ID, PAD_ID
 
     return ContinuousBatcher(
-        seq2seq.make_positional_step(params, cfg),
+        seq2seq.make_positional_step(cfg),
         seq2seq.make_paged_cache_factory(
             cfg, block_size=BLOCK_SIZE, pool_blocks=pool_blocks
         ),
+        params=params,
         slots=slots, vocab_size=cfg.vocab_size, max_tokens=cfg.max_tgt_len,
         enc_len=src_len, d_model=cfg.d_model,
         start_id=BOS_ID, eos_id=EOS_ID, pad_id=PAD_ID,

@@ -1,5 +1,9 @@
 """Device runtime tests — run on the 8-device virtual CPU mesh (conftest)."""
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -76,6 +80,65 @@ def test_executable_cache_counts():
 
 def test_detect_platform_cpu_here():
     assert detect_platform() == "cpu"  # conftest forces JAX_PLATFORMS=cpu
+
+
+def test_detect_platform_raises_when_no_backend_initializes(monkeypatch):
+    """A backend that cannot initialize is an error, never a quiet 'cpu':
+    an agent meant for a chip must not carry on on the wrong device."""
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        detect_platform()
+    # The kill-switch answers without asking JAX at all.
+    assert detect_platform(tpu_disabled=True) == "cpu"
+
+
+@pytest.fixture()
+def restore_cache_dir():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_defaults_to_one_dir_inside_the_checkout(
+    restore_cache_dir, monkeypatch, tmp_path
+):
+    """Nothing placed the cache from outside → the checkout's own fixed
+    directory, whatever the working directory (the path is part of the
+    cache's key: a directory that moves never hits)."""
+    from agent_tpu.utils.paths import REPO_ROOT
+
+    seen = []
+    for name in ("here", "there"):
+        (tmp_path / name).mkdir()
+        monkeypatch.chdir(tmp_path / name)
+        jax.config.update("jax_compilation_cache_dir", None)
+        TpuRuntime(DeviceConfig())
+        seen.append(jax.config.jax_compilation_cache_dir)
+    assert seen[0] == seen[1] == os.path.join(REPO_ROOT, ".cache", "xla")
+    assert os.path.isdir(os.path.join(REPO_ROOT, "agent_tpu"))  # the checkout
+
+
+def test_compile_cache_dir_set_from_outside_is_left_alone(tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` set → JAX uses it and the runtime sets
+    no other directory in code (a real process: the variable is read when
+    jax is imported)."""
+    outside = str(tmp_path / "placed_from_outside")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax\n"
+         "from agent_tpu.runtime.runtime import TpuRuntime\n"
+         "TpuRuntime()\n"
+         "print(jax.config.jax_compilation_cache_dir)"],
+        env={**os.environ, "JAX_COMPILATION_CACHE_DIR": outside,
+             "JAX_PLATFORMS": "cpu"},
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == outside
 
 
 def test_singleton_reset():

@@ -211,8 +211,9 @@ def _engine(cfg, params, num_beams, slots=3, src_len=16, **kw):
     from agent_tpu.models.tokenizer import BOS_ID, EOS_ID, PAD_ID
 
     return ContinuousBatcher(
-        seq2seq.make_positional_step(params, cfg),
+        seq2seq.make_positional_step(cfg),
         seq2seq.make_cache_factory(cfg),
+        params=params,
         slots=slots, vocab_size=cfg.vocab_size, max_tokens=cfg.max_tgt_len,
         enc_len=src_len, d_model=cfg.d_model,
         start_id=BOS_ID, eos_id=EOS_ID, pad_id=PAD_ID,
@@ -385,6 +386,30 @@ class TestControllerInfer:
         }
         assert outcomes[("classify", "completed")] == 1
         assert outcomes[("summarize", "completed")] == 1
+
+    @pytest.mark.parametrize("num_beams", [1, 2])
+    def test_second_same_bucket_request_is_served_warm(self, num_beams):
+        """The runtime places params on its mesh, and what a program over
+        them returns is typed with that mesh: engine state, block table and
+        admitted rows must be too, or step and insert retrace at every
+        join. Sequential same-bucket requests leave ONE executable each."""
+        from agent_tpu.ops.serve_infer import engine_executables, reset_engines
+
+        reset_engines()
+        c = self.make()
+        for text in ("first request", "second one", "and a third"):
+            rid = c.submit_infer(
+                "summarize", text,
+                params={"model_config": TINY_S2S, "max_length": 4,
+                        "num_beams": num_beams},
+            )
+            c._serve_pump()
+            _drain_serving(c)
+            c._serve_reap()
+            assert c.infer_snapshot(rid)["state"] == "done"
+        (engine,) = engine_executables()
+        assert engine["step"] == 1 and engine["insert"] == 1, engine
+        reset_engines()
 
     def test_serve_jobs_ride_interactive_tier_and_tenant(self):
         c = self.make(priority=8)
@@ -692,9 +717,16 @@ class TestRequestObservability:
         }, timeout=120)
         assert r.json()["state"] == "done", r.json()
         rid = r.json()["req_id"]
-        body = session.get(
-            server.url + "/v1/debug/requests?tenant=acme", timeout=10
-        ).json()
+        # The reap wakes the request's waiters first and writes the wide
+        # event right after: the record can trail ``done`` by a moment.
+        deadline = time.monotonic() + 10.0
+        while True:
+            body = session.get(
+                server.url + "/v1/debug/requests?tenant=acme", timeout=10
+            ).json()
+            if body["requests"] or time.monotonic() > deadline:
+                break
+            time.sleep(0.01)
         assert body["enabled"] and body["requests"]
         assert all(rec["tenant"] == "acme" for rec in body["requests"])
         assert body["stats"]["seen"] >= 1

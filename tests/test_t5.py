@@ -224,9 +224,11 @@ def test_encode_flash_equals_dense(tmp_path, monkeypatch):
     mask[0, 12:] = 0
 
     before = dict(fa.SELECTION_COUNTS)
-    flash = np.asarray(t5.encode(params, src, mask, cfg, use_flash=True))
+    flash = np.asarray(
+        t5.encode(params, src, mask, cfg, kernel=fa.flash_attention_t5)
+    )
     assert fa.SELECTION_COUNTS.get("t5_flash", 0) > before.get("t5_flash", 0)
-    dense = np.asarray(t5.encode(params, src, mask, cfg, use_flash=False))
+    dense = np.asarray(t5.encode(params, src, mask, cfg))
     np.testing.assert_allclose(flash, dense, atol=3e-5)
 
 
@@ -286,7 +288,7 @@ def test_encode_mesh_kernel_on_dp_tp_mesh(tmp_path, monkeypatch):
     before = dict(fa.SELECTION_COUNTS)
     flash = np.asarray(t5.encode(params, src, mask, cfg, kernel=kernel))
     assert fa.SELECTION_COUNTS.get("t5_flash", 0) > before.get("t5_flash", 0)
-    dense = np.asarray(t5.encode(params, src, mask, cfg, use_flash=False))
+    dense = np.asarray(t5.encode(params, src, mask, cfg))
     np.testing.assert_allclose(flash, dense, atol=3e-5)
 
     # generate() threads the kernel through its encoder pass.
