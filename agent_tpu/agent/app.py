@@ -39,6 +39,7 @@ from __future__ import annotations
 import os
 import signal
 import sys
+import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -50,13 +51,7 @@ from agent_tpu.obs.metrics import MetricsRegistry
 from agent_tpu.obs.profile import device_memory_stats
 from agent_tpu.obs.recorder import FlightRecorder, default_dump_path
 from agent_tpu.obs.usage import stamp_usage
-from agent_tpu.obs.trace import (
-    SpanBuffer,
-    TraceContext,
-    make_span,
-    new_span_id,
-    use_context,
-)
+from agent_tpu.obs.trace import SpanBuffer, TraceContext, make_span
 from agent_tpu.obs import trace as obs_trace
 from agent_tpu.ops import OpFn, load_ops
 from agent_tpu.utils.errors import structured_error
@@ -70,12 +65,12 @@ from agent_tpu.utils.retry import (
 
 # result-timings key → task_phase_seconds phase label. The ops stamp
 # milliseconds into ctx.tags["timings"] (see map_classify_tpu.finalize);
-# the loops turn them into histogram observations in seconds.
+# the loops turn them into histogram observations in seconds. The fetch wait
+# is not here: the op measures it itself, through obs.trace.phase("fetch").
 PHASE_KEYS = (
     ("stage_ms", "stage"),
     ("queue_ms", "queue"),
     ("device_ms", "execute"),
-    ("fetch_ms", "fetch"),
     ("finalize_ms", "finalize"),
 )
 
@@ -164,12 +159,17 @@ class Agent:
             ("op", "phase"))
         self.m_lease = self.obs.counter(
             "lease_requests_total", "Lease polls by outcome", ("outcome",))
+        self.m_lease_seconds = self.obs.histogram(
+            "agent_lease_seconds",
+            "One lease poll as the agent pays it: telemetry snapshot, the "
+            "/v1/leases round trip, response handling", ("outcome",))
         self.m_queue = self.obs.gauge(
             "queue_depth", "Pipeline queue occupancy (staged/post)",
             ("queue",))
         self.m_device_idle = self.obs.counter(
             "device_idle_seconds_total",
-            "Device-thread seconds blocked waiting for staged work")
+            "Seconds with nothing in flight on the device: from the last "
+            "program seen ready (or the agent's start) to the next dispatch")
         # Serving (ISSUE 15): live occupancy of the continuous-batching
         # decode engine's running batch — the "is iteration-level batching
         # actually batching" signal swarmtop's serving row shows.
@@ -183,12 +183,14 @@ class Agent:
         # unaffected (labels sum); value() readers must now pass op=.
         self.m_device_busy = self.obs.counter(
             "device_busy_seconds_total",
-            "Device-thread seconds dispatching op execute phases, per op",
+            "Seconds the device had this op's work in flight, per op: from "
+            "dispatch (or the previous program's completion, the device "
+            "runs them in order) to the result seen ready on the host",
             ("op",))
         self.m_duty = self.obs.gauge(
             "device_duty_cycle",
-            "Rolling duty cycle: device-busy seconds inside the last "
-            f"{int(DUTY_WINDOW_SEC)}s window / window span")
+            "Rolling duty cycle: device_busy_seconds_total gained inside "
+            f"the last {int(DUTY_WINDOW_SEC)}s window / window span")
         self.m_flops = self.obs.counter(
             "device_flops_total",
             "Analytic model FLOPs dispatched, per op and shape bucket "
@@ -196,9 +198,9 @@ class Agent:
             ("op", "shape"))
         self.m_mfu = self.obs.gauge(
             "device_mfu",
-            "Model FLOPs utilization per op: analytic FLOPs / device-busy "
-            "seconds / peak dense-bf16 FLOP/s (absent when the peak is "
-            "unknown — PEAK_TFLOPS overrides)", ("op",))
+            "Model FLOPs utilization per op: analytic FLOPs / "
+            "device_busy_seconds_total / peak dense-bf16 FLOP/s (absent "
+            "when the peak is unknown — PEAK_TFLOPS overrides)", ("op",))
         self.m_hbm = self.obs.gauge(
             "device_hbm_bytes",
             "Per-device accelerator memory from memory_stats(), across ALL "
@@ -267,8 +269,17 @@ class Agent:
         # callable returning a session; None = a fresh requests.Session.
         self.post_session_factory: Optional[Any] = None
         # Fleet health (ISSUE 8): rolling duty window + cumulative per-op
-        # busy/FLOPs for the MFU gauge. Touched only by the device-dispatch
-        # thread (serial loop or the pipeline's execute loop).
+        # busy/FLOPs for the MFU gauge, and the in-order queue model's
+        # state: when the device was last seen done (it is idle from the
+        # agent's start), and the intervals reported ahead of an earlier
+        # dispatch's, held until their turn. Fed by note_device_interval
+        # from the poster thread (deferred fetches) and the dispatch thread,
+        # and read by the lease thread's snapshot: all under _device_lock.
+        self._device_lock = threading.Lock()
+        self._t_ready_prev = time.perf_counter()
+        self._dispatch_seq = 0          # dispatches numbered so far
+        self._accounted_seq = 0         # ... and accounted, in order
+        self._held_intervals: Dict[int, Tuple[Any, ...]] = {}
         self._duty = RollingWindow(DUTY_WINDOW_SEC)
         self._busy_by_op: Dict[str, float] = {}
         self._flops_by_op: Dict[str, float] = {}
@@ -441,35 +452,84 @@ class Agent:
                 pass
         return caps
 
-    def note_device_time(
-        self, op: str, seconds: float, tags: Optional[Dict[str, Any]] = None
+    def device_dispatched(self) -> int:
+        """The device thread, as it dispatches work whose completion ANOTHER
+        thread will report: the work's place in the device's queue, to hand
+        to :meth:`note_device_interval` as ``seq``. Whoever takes a number
+        reports it, whatever becomes of the work: later intervals wait for
+        it."""
+        with self._device_lock:
+            self._dispatch_seq += 1
+            return self._dispatch_seq
+
+    def note_device_interval(
+        self, op: str, t_dispatch: float, t_ready: float,
+        tags: Optional[Dict[str, Any]] = None, seq: Optional[int] = None,
     ) -> None:
-        """Per-op device attribution (ISSUE 8), called by the dispatch loop
-        after every op execute: busy counter (op-labeled), rolling duty
-        cycle, and — when the op stamped its analytic FLOPs into
-        ``ctx.tags["device_attr"]`` — the FLOPs counter per shape bucket
-        and the ``device_mfu{op}`` gauge (FLOPs / busy / peak)."""
-        if seconds < 0:
-            seconds = 0.0
-        self.m_device_busy.inc(seconds, op=op)
+        """Per-op device attribution from completion events (the in-order
+        queue model): one thread dispatches and the device runs programs in
+        dispatch order, so work dispatched at ``t_dispatch`` and seen ready
+        on the host at ``t_ready`` (both ``perf_counter``) kept the device
+        busy from ``max(t_dispatch, previous ready)`` to ``t_ready``, and
+        the device idled from the previous ready to ``t_dispatch`` when
+        nothing was in flight. That SAME float feeds
+        ``device_busy_seconds_total{op}``, the rolling duty cycle,
+        ``device_mfu{op}`` (with the FLOPs the op stamped into
+        ``tags["device_attr"]``) and the task's ``usage.device_s``, so the
+        showback ledger reconciles with the counter exactly.
+
+        Callers: the pipeline's poster, after ``finalize`` returned, with
+        the instant the op's deferred fetch came back and the ``seq`` the
+        device thread took at dispatch (:meth:`device_dispatched`); and
+        whoever ran an execute that blocks until the result is on the host
+        (the serial loop, the serve pump), with its own start and end and no
+        ``seq``: it is numbered here, as dispatched just now. Intervals are
+        accounted in that order whatever order they are reported in: one
+        reported ahead of an earlier dispatch's (a decode step run while a
+        drain shard still waits for the poster) is held until the earlier
+        ones are in, or the earlier one would find its seconds already taken
+        and an idle gap booked that never was. The sum telescopes: a ready
+        seen late moves seconds between neighbouring tasks, never into or
+        out of the total, and can only hide an idle gap shorter than the
+        lateness."""
+        with self._device_lock:
+            if seq is None:
+                self._dispatch_seq += 1
+                seq = self._dispatch_seq
+            self._held_intervals[seq] = (op, t_dispatch, t_ready, tags)
+            while self._accounted_seq + 1 in self._held_intervals:
+                self._accounted_seq += 1
+                self._account_interval(
+                    *self._held_intervals.pop(self._accounted_seq))
+
+    def _account_interval(
+        self, op: str, t_dispatch: float, t_ready: float,
+        tags: Optional[Dict[str, Any]],
+    ) -> None:
+        """One interval, in its turn (under ``_device_lock``)."""
+        prev = self._t_ready_prev
+        seconds = max(0.0, t_ready - max(t_dispatch, prev))
+        idle = max(0.0, t_dispatch - prev)
+        self._t_ready_prev = max(prev, t_ready)
         self._duty.add(seconds)
-        self.m_duty.set(round(self._duty.fraction(), 4))
-        self._busy_by_op[op] = self._busy_by_op.get(op, 0.0) + seconds
+        busy = self._busy_by_op[op] = self._busy_by_op.get(op, 0.0) + seconds
         task_flops = 0.0
         attr = (tags or {}).get("device_attr")
         if isinstance(attr, dict):
             flops = attr.get("flops")
             if isinstance(flops, (int, float)) and flops > 0:
                 task_flops = float(flops)
-                self.m_flops.inc(
-                    float(flops), op=op, shape=str(attr.get("shape", "?"))
-                )
-                self._flops_by_op[op] = (
-                    self._flops_by_op.get(op, 0.0) + float(flops)
-                )
-        # Per-task usage stamp (ISSUE 9): the SAME seconds that feed the
-        # busy counter ride the result body, so the controller's showback
-        # ledger reconciles with device_busy_seconds_total exactly.
+        flops_total = self._flops_by_op[op] = (
+            self._flops_by_op.get(op, 0.0) + task_flops
+        )
+        self.m_device_busy.inc(seconds, op=op)
+        if idle:
+            self.m_device_idle.inc(idle)
+        self.m_duty.set(round(self._duty.fraction(), 4))
+        if task_flops:
+            self.m_flops.inc(
+                task_flops, op=op, shape=str(attr.get("shape", "?"))
+            )
         if self._usage_chips is None:
             try:
                 self._usage_chips = (
@@ -484,8 +544,6 @@ class Agent:
         )
         if self._peak_flops is None:
             self._peak_flops = resolve_peak_flops(self.runtime)
-        busy = self._busy_by_op.get(op, 0.0)
-        flops_total = self._flops_by_op.get(op, 0.0)
         if self._peak_flops and busy > 0 and flops_total > 0:
             self.m_mfu.set(
                 round(flops_total / busy / self._peak_flops, 6), op=op
@@ -565,7 +623,9 @@ class Agent:
         m = collect_host_metrics()
         # Duty decays while idle: refresh at snapshot time so a quiet agent
         # reads 0, not its last busy moment.
-        self.m_duty.set(round(self._duty.fraction(), 4))
+        with self._device_lock:
+            duty = self._duty.fraction()
+        self.m_duty.set(round(duty, 4))
         self._refresh_hbm_gauges()
         if self.runtime is not None:
             try:
@@ -673,6 +733,25 @@ class Agent:
     def _process_name(self) -> str:
         return f"agent:{self.config.agent.agent_name}"
 
+    def task_context(
+        self, op: str, job_id: Optional[str] = None,
+        trace_id: Optional[str] = None, span_parent: Optional[str] = None,
+        lease_id: Optional[str] = None, attempt: Any = None,
+    ) -> TraceContext:
+        """The context every phase of one task is measured under
+        (``obs.trace.phase``): this agent's registry, span ring and flight
+        recorder, the op, and the controller's lease span as the parent.
+        Without a job it is the agent's own context (a lease poll, a
+        release): metrics and annotations, no span."""
+        job = {} if job_id is None else {
+            "job_id": job_id, "lease_id": lease_id, "attempt": attempt}
+        return TraceContext(
+            trace_id=trace_id or "", parent_span_id=span_parent,
+            tracer=self.tracer, registry=self.obs,
+            process=self._process_name(), op=op,
+            recorder=self.recorder if job else None, job=job,
+        )
+
     def trace_span(
         self,
         name: str,
@@ -719,7 +798,21 @@ class Agent:
     def lease_once(self) -> Optional[Tuple[str, List[Dict[str, Any]]]]:
         """One ``/v1/leases`` round-trip → ``(lease_id, tasks)`` or None when
         idle. Raises RuntimeError on transport/protocol errors so the caller
-        applies backoff (reference ``app.py:161-195``)."""
+        applies backoff (reference ``app.py:161-195``). Timed whole, as the
+        polling thread pays it: ``agent_lease_seconds{outcome}`` and an
+        ``agent.lease`` annotation on that thread's profiler line."""
+        outcome = "error"
+        polled = obs_trace.phase("lease", histogram=False, span=False)
+        try:
+            with polled:
+                leased = self._lease_once()
+            outcome = "idle" if leased is None else "tasks"
+            return leased
+        finally:
+            self.m_lease.inc(outcome=outcome)
+            self.m_lease_seconds.observe(polled.seconds, outcome=outcome)
+
+    def _lease_once(self) -> Optional[Tuple[str, List[Dict[str, Any]]]]:
         a = self.config.agent
         metrics = self._metrics()
         spans = self._drain_spans()
@@ -754,21 +847,16 @@ class Agent:
                 self.tracer.requeue(spans)
             self._requeue_capture_results(captures)
         if status == STATUS_TRANSPORT_ERROR:
-            self.m_lease.inc(outcome="error")
             raise RuntimeError(f"lease transport error: {body}")
         if status == 204:
-            self.m_lease.inc(outcome="idle")
             return None
         if status != 200 or not isinstance(body, dict):
-            self.m_lease.inc(outcome="error")
             raise RuntimeError(f"lease HTTP {status}: {str(body)[:200]}")
         tasks = body.get("tasks")
         lease_id = body.get("lease_id")
         if not tasks:
-            self.m_lease.inc(outcome="idle")
             return None
         if not isinstance(lease_id, str) or not isinstance(tasks, list):
-            self.m_lease.inc(outcome="error")
             raise RuntimeError(f"malformed lease response: {str(body)[:200]}")
         # Binary-wire negotiation (ISSUE 6): the controller stamps every
         # granted lease it negotiated, so re-deriving here self-corrects if
@@ -778,7 +866,6 @@ class Agent:
         # SLO page alerts ride granted leases (absent in steady state);
         # entering page auto-dumps this agent's flight recorder.
         self.note_alerts(body.get("alerts"))
-        self.m_lease.inc(outcome="tasks")
         self.recorder.record(
             "lease", lease_id=lease_id, n_tasks=len(tasks),
             job_ids=[
@@ -817,9 +904,15 @@ class Agent:
             # metrics snapshot uses on leases. NOT stored in the spool: a
             # failed batch requeues and ships on the next post or lease.
             wire["spans"] = spans
-        http_status, body = self._post_json(
-            "/v1/results", wire, session=session,
-        )
+        # The /v1/results round trip alone (the enclosing ``post`` span also
+        # holds finalize): in the task's context when the caller set one.
+        with obs_trace.phase(
+            "post_http", obs_trace.current() or self.task_context(op),
+        ) as ph:
+            http_status, body = self._post_json(
+                "/v1/results", wire, session=session,
+            )
+            ph.attributes["http_status"] = http_status
         if http_status in (200, 204):
             return True
         if spans:
@@ -1072,8 +1165,7 @@ class Agent:
             return thunk()
         t0 = time.perf_counter()
         try:
-            with jax.profiler.TraceAnnotation(f"op:{op}"):
-                return thunk()
+            return thunk()   # annotated by the caller's obs.trace.phase
         except Exception:
             record["status"] = "op_failed"  # trace still captured; op raised
             raise
@@ -1116,8 +1208,7 @@ class Agent:
             import jax
 
             with jax.profiler.trace(dev.profile_dir):
-                with jax.profiler.TraceAnnotation(f"op:{op}"):
-                    return thunk()
+                return thunk()
         return thunk()
 
     def _maybe_profiled(self, op: str, fn: OpFn, payload: Dict[str, Any],
@@ -1167,57 +1258,61 @@ class Agent:
         in lockstep instead: leader and followers all re-raise (see
         ``run_follower``), because continuing past a diverged collective
         program would wedge the slice silently.
+
+        The loop's three phases (``stage``: resolution + broadcast,
+        ``execute``: the monolithic op call, ``post``) are spans and
+        annotations only: this loop's ``task_phase_seconds`` come from the
+        op's own timings (``record_phase_timings``).
         """
         t0 = time.perf_counter()
-        job_id, op, payload, epoch, fn, resolve_error = self.resolve_task(task)
         attempt = task.get("attempt") if isinstance(task, dict) else None
-        trace_id, span_parent = self.task_trace(task)
-        if resolve_error is not None:
-            if job_id is not None:
-                self.m_tasks.inc(op=op, status="failed")
-                self.recorder.record(
-                    "task", job_id=job_id, op=op, status="failed",
-                    lease_id=lease_id, attempt=attempt,
-                    error_type=resolve_error.get("type"),
-                )
-                self.post_result(
-                    lease_id, job_id, epoch, "failed", error=resolve_error,
-                    op=op,
-                )
-            return
-
-        ctx = self._op_context(job_id, lease_id=lease_id, attempt=attempt,
-                               parent_span_id=span_parent,
-                               tenant=task.get("tenant")
-                               if isinstance(task, dict) else None)
-        # The execute span id is minted up front so compile spans emitted
-        # INSIDE the op (executor cache misses) can parent to it.
-        exec_span_id = new_span_id()
-        t_exec0 = None
+        status, error, result = "succeeded", None, None
+        job_id = None
         try:
-            # Multi-host: every host must enter the same SPMD program in
-            # lockstep — the leader publishes the task before executing it
-            # (no-op on a single host). SURVEY.md §7 "multi-host control".
-            self._broadcast_to_followers(op, payload)
-            t_exec0 = time.perf_counter()
-            # Serial loop "stage": task resolution + the broadcast — the
-            # host-side work before the monolithic op call.
-            self.trace_span(
-                "stage", trace_id, span_parent,
-                start_mono=t0, duration_s=t_exec0 - t0, op=op,
-            )
-            stamp_usage(ctx.tags, host_s=t_exec0 - t0)
-            with use_context(TraceContext(
-                trace_id=trace_id or job_id,
-                parent_span_id=exec_span_id,
-                tracer=self.tracer,
-                registry=self.obs,
-                process=self._process_name(),
-            )):
-                result = self._maybe_profiled(op, fn, payload, ctx)
-            status = "succeeded"
-            error = None
+            with obs_trace.phase("stage", histogram=False) as staged:
+                job_id, op, payload, epoch, fn, resolve_error = \
+                    self.resolve_task(task)
+                if resolve_error is not None:
+                    if job_id is not None:
+                        self.m_tasks.inc(op=op, status="failed")
+                        self.recorder.record(
+                            "task", job_id=job_id, op=op, status="failed",
+                            lease_id=lease_id, attempt=attempt,
+                            error_type=resolve_error.get("type"),
+                        )
+                        self.post_result(
+                            lease_id, job_id, epoch, "failed",
+                            error=resolve_error, op=op,
+                        )
+                    return
+                trace_id, span_parent = self.task_trace(task)
+                ctx = self._op_context(
+                    job_id, lease_id=lease_id, attempt=attempt,
+                    parent_span_id=span_parent,
+                    tenant=task.get("tenant")
+                    if isinstance(task, dict) else None)
+                staged.ctx = tctx = self.task_context(
+                    op, job_id, trace_id, span_parent,
+                    lease_id=lease_id, attempt=attempt)
+                # Multi-host: every host must enter the same SPMD program
+                # in lockstep — the leader publishes the task before
+                # executing it (no-op on a single host). SURVEY.md §7.
+                self._broadcast_to_followers(op, payload)
+            stamp_usage(ctx.tags, host_s=staged.seconds)
+            # What the op compiles inside parents to this span.
+            with obs_trace.phase("execute", tctx, histogram=False,
+                                 annotation="agent.dispatch",
+                                 status=status) as executed:
+                try:
+                    result = self._maybe_profiled(op, fn, payload, ctx)
+                finally:
+                    # The monolithic call blocks until the result is on the
+                    # host: its own start and end are dispatch and ready.
+                    self.note_device_interval(
+                        op, executed.t0, time.perf_counter(), ctx.tags)
         except Exception as exc:  # noqa: BLE001 — every op error → failed result
+            if job_id is None:
+                raise   # not an op error: the task was never resolved
             result = None
             status = "failed"
             error = structured_error(exc)
@@ -1235,18 +1330,7 @@ class Agent:
                     op=op,
                 )
                 raise
-        t_done = time.perf_counter()
-        if t_exec0 is not None:
-            self.trace_span(
-                "execute", trace_id, span_parent, span_id=exec_span_id,
-                start_mono=t_exec0, duration_s=t_done - t_exec0,
-                op=op, status=status,
-            )
-            # Serial-loop device attribution (ISSUE 8): the monolithic call
-            # IS the dispatch window here (the pipelined loop measures its
-            # own). Previously only the pipeline recorded busy seconds.
-            self.note_device_time(op, t_done - t_exec0, ctx.tags)
-        duration_ms = (t_done - t0) * 1000.0
+        duration_ms = (time.perf_counter() - t0) * 1000.0
         if isinstance(result, dict):
             result.setdefault("duration_ms", duration_ms)
             if ctx.tags.get("timings"):
@@ -1256,17 +1340,13 @@ class Agent:
                 # Usage block (ISSUE 9): device/host seconds, chips, FLOPs,
                 # rows — what the controller's showback ledger bills.
                 result.setdefault("usage", ctx.tags["usage"])
-        t_post0 = time.perf_counter()
-        self.post_result(
-            lease_id, job_id, epoch, status, result=result, error=error, op=op
-        )
-        # Emitted after the post (a span cannot include its own ship); it
+        # Closed after the post (a span cannot include its own ship); it
         # rides the NEXT post or the final metrics-only flush.
-        self.trace_span(
-            "post", trace_id, span_parent,
-            start_mono=t_post0, duration_s=time.perf_counter() - t_post0,
-            op=op, status=status,
-        )
+        with obs_trace.phase("post", tctx, histogram=False, status=status):
+            self.post_result(
+                lease_id, job_id, epoch, status, result=result, error=error,
+                op=op,
+            )
         self.tasks_done += 1
         self.m_tasks.inc(op=op, status=status)
         # Serial phases come from the op's own timings (the monolithic call

@@ -34,6 +34,21 @@ Ops advertise phases as attributes on their registered handler
 without them run monolithically on the device thread, so the pipeline is
 safe for every op.
 
+Every phase boundary is measured once, by ``obs.trace.phase`` (histogram,
+span, flight recorder, profiler annotation from one pair of clock reads).
+Device time comes from completion events (``Agent.note_device_interval``).
+An op whose ``execute`` may return with the device still working DECLARES it
+(``fn.deferred = True`` beside the phase hooks) and stamps ``t_ready``, the
+instant its results were on the host, into its state from whichever phase
+fetched them; the device thread numbers the dispatch and the poster accounts
+the interval once ``finalize`` has returned. Any other execute is taken to
+have blocked until its result was on the host, and the device thread accounts
+it as it returns — so an op that defers without declaring it is billed its
+dispatch microseconds: the declaration is the contract. Intervals are
+accounted in dispatch order whichever thread reports them. The device
+thread's own wall time is split into the exclusive states of
+``device_thread_seconds_total{state}``.
+
 Wire-protocol semantics are unchanged: same lease/result bodies, same
 structured errors, same epoch fencing. Results may post out of task order —
 the protocol never required ordering (results are keyed by job_id).
@@ -49,7 +64,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
-from agent_tpu.obs.trace import TraceContext, new_span_id, use_context
+from agent_tpu.obs import trace as obs_trace
 from agent_tpu.obs.usage import stamp_usage
 from agent_tpu.utils.errors import structured_error
 from agent_tpu.utils.logging import log
@@ -73,21 +88,54 @@ class _Item:
     status: str = "succeeded"
     error: Any = None
     monolithic: bool = False      # op has no phase hooks
-    # Tracing (ISSUE 5): the task's trace context (trace_id = job_id,
-    # span_parent = the controller's lease span) and the phase boundary the
-    # queue span is measured from. The runner's existing wall-clock phase
-    # measurements become spans — no second clock.
-    trace_id: Any = None
-    span_parent: Any = None
+    # What every phase of this task is measured under (Agent.task_context:
+    # trace_id = job_id, parent = the controller's lease span), and the
+    # boundaries later phases measure from.
+    trace: Any = None
     t_staged: float = 0.0         # when staging finished (queue-span start)
+    t_exec0: float = 0.0          # execute entered (the dispatch instant)
+    # The op declares a deferred fetch (fn.deferred): the poster accounts
+    # the device interval, under the number this dispatch took.
+    deferred: bool = False
+    seq: Optional[int] = None
+    accounted: bool = False       # its device interval has been reported
     # Continuous serving (ISSUE 15): the engine handle while this item's
-    # requests ride the running batch, and the admit instant the execute
-    # span measures from.
+    # requests ride the running batch (its execute span runs from t_exec0,
+    # the admit instant, to the collect).
     serve_handle: Any = None
-    t_serve0: float = 0.0
 
 
 _STOP = object()
+
+
+class _ThreadClock:
+    """The device-owning thread's wall time as exclusive states: every
+    ``switch`` books the seconds since the last one to the state that was
+    current (``device_thread_seconds_total{state}``), so the states sum to
+    the loop's wall time by construction. Each state is also an
+    ``agent.<state>`` annotation on the thread's profiler line, except where
+    the caller's own phase annotates the same extent."""
+
+    def __init__(self, counter: Any, state: str) -> None:
+        self._counter = counter
+        self._state: Optional[str] = None
+        self._t = time.perf_counter()
+        self._ann: Any = None
+        self.switch(state)
+
+    def switch(self, state: Optional[str], annotate: bool = True
+               ) -> Optional[str]:
+        """Enter ``state`` (None: stop the clock); returns the state left."""
+        now = time.perf_counter()
+        left = self._state
+        if left is not None:
+            self._counter.inc(now - self._t, state=left)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._ann = (obs_trace.annotate(f"agent.{state}")
+                     if state is not None and annotate else None)
+        self._state, self._t = state, now
+        return left
 
 # How long a shutting-down device thread keeps waiting for the poster to free
 # a post-queue slot before giving up (wedged-poster escape; see _put_post).
@@ -141,6 +189,13 @@ class PipelineRunner:
         # the qsize read does not.)
         agent.staged_depth_fn = self._pool.backlog
         self.tasks_posted = 0
+        self.m_thread = agent.obs.counter(
+            "device_thread_seconds_total",
+            "Wall seconds of the device-owning thread by what it was doing: "
+            "wait_staged (blocked on the staged queue), prefeed, dispatch "
+            "(inside op execute), wait_post (blocked on the post queue), "
+            "serve_pump; exclusive, they sum to the loop's wall time",
+            ("state",))
         self._poster = threading.Thread(
             target=self._post_loop, name="agent-poster", daemon=True
         )
@@ -149,68 +204,62 @@ class PipelineRunner:
 
     def _stage_one(self, lease_id: str, task: Any) -> Optional[_Item]:
         agent = self.agent
-        t0 = time.perf_counter()
-        # Shared resolution (Agent.resolve_task): malformed-task salvage and
-        # the UnknownOp shape are single-sourced with the serial loop.
-        job_id, op, payload, epoch, fn, resolve_error = agent.resolve_task(task)
         attempt = task.get("attempt") if isinstance(task, dict) else None
-        trace_id, span_parent = agent.task_trace(task)
-        if resolve_error is not None:
+        # The stage phase resolves the task, so it learns its own context
+        # inside the block; a task that stops before that records nothing.
+        with obs_trace.phase("stage") as ph:
+            # Shared resolution (Agent.resolve_task): malformed-task salvage
+            # and the UnknownOp shape are single-sourced with the serial
+            # loop.
+            job_id, op, payload, epoch, fn, resolve_error = \
+                agent.resolve_task(task)
             if job_id is None:
                 return None
-            return _Item(
-                lease_id, job_id, epoch, op, {}, None, t0,
-                status="failed", error=resolve_error,
-                trace_id=trace_id, span_parent=span_parent,
+            trace_id, span_parent = agent.task_trace(task)
+            trace = agent.task_context(
+                op, job_id, trace_id, span_parent,
+                lease_id=lease_id, attempt=attempt)
+            if resolve_error is not None:
+                return _Item(
+                    lease_id, job_id, epoch, op, {}, None, ph.t0,
+                    status="failed", error=resolve_error, trace=trace,
+                )
+            item = _Item(
+                lease_id, job_id, epoch, op, payload,
+                agent._op_context(job_id, lease_id=lease_id, attempt=attempt,
+                                  parent_span_id=span_parent,
+                                  tenant=task.get("tenant")
+                                  if isinstance(task, dict) else None),
+                ph.t0, fn=fn, trace=trace,
             )
-
-        item = _Item(
-            lease_id, job_id, epoch, op, payload,
-            agent._op_context(job_id, lease_id=lease_id, attempt=attempt,
-                              parent_span_id=span_parent,
-                              tenant=task.get("tenant")
-                              if isinstance(task, dict) else None),
-            t0, fn=fn, trace_id=trace_id, span_parent=span_parent,
-        )
-        stage = getattr(fn, "stage", None)
-        if stage is None:
-            item.monolithic = True
-            item.t_staged = time.perf_counter()
-            return item
-        try:
-            phase, value = stage(payload, item.ctx)
-        except Exception as exc:  # noqa: BLE001 — same contract as run_task
-            item.status = "failed"
-            item.error = structured_error(exc)
-            agent.rate.log("exec", "stage raised", op=op, type=type(exc).__name__)
-            agent.recorder.record(
-                "error", phase="stage", job_id=job_id, op=op,
-                lease_id=lease_id, attempt=attempt,
-                type=type(exc).__name__, message=str(exc)[:200],
-            )
-            return item
-        item.t_staged = time.perf_counter()
-        agent.m_phase.observe(
-            item.t_staged - t0,
-            exemplar={"trace_id": job_id}, op=op, phase="stage",
-        )
+            stage = getattr(fn, "stage", None)
+            if stage is None:
+                item.monolithic = True
+                item.t_staged = time.perf_counter()
+                return item
+            ph.ctx = trace
+            try:
+                phase, value = stage(payload, item.ctx)
+            except Exception as exc:  # noqa: BLE001 — same contract as run_task
+                item.status = ph.attributes["status"] = "failed"
+                item.error = structured_error(exc)
+                agent.rate.log("exec", "stage raised", op=op,
+                               type=type(exc).__name__)
+                agent.recorder.record(
+                    "error", phase="stage", job_id=job_id, op=op,
+                    lease_id=lease_id, attempt=attempt,
+                    type=type(exc).__name__, message=str(exc)[:200],
+                )
+                return item
+            if phase == "done":
+                item.result = value
+            else:
+                item.staged = value
+        item.t_staged = ph.t1
         # Host-side usage attribution (ISSUE 9): stage seconds ride the
-        # result's usage block next to the device seconds the execute loop
-        # stamps.
-        stamp_usage(item.ctx.tags, host_s=item.t_staged - t0)
-        # The runner's existing stage measurement, as a span (ISSUE 5).
-        agent.trace_span(
-            "stage", trace_id, span_parent,
-            start_mono=t0, duration_s=item.t_staged - t0, op=op,
-        )
-        agent.recorder.record(
-            "phase", phase="staged", job_id=job_id, op=op,
-            lease_id=lease_id, attempt=attempt,
-        )
-        if phase == "done":
-            item.result = value
-        else:
-            item.staged = value
+        # result's usage block next to the device seconds the completion
+        # accounting stamps.
+        stamp_usage(item.ctx.tags, host_s=ph.seconds)
         return item
 
     # ---- device (calling) thread ----
@@ -224,6 +273,15 @@ class PipelineRunner:
         poster that has stopped draining (e.g. wedged in a fetch on a hung
         device) — a graceful drain keeps consuming and frees a slot well
         inside the grace window, so normal shutdown still posts everything."""
+        clock = self._clock
+        left = clock.switch("wait_post") if clock is not None else None
+        try:
+            return self._put_post_blocking(item)
+        finally:
+            if clock is not None:
+                clock.switch(left, annotate=False)
+
+    def _put_post_blocking(self, item: Any) -> bool:
         waited = 0.0
         while True:
             try:
@@ -291,10 +349,12 @@ class PipelineRunner:
         thread), the decode iterations run in :meth:`_serve_pump_once`
         interleaved with everything else the loop does."""
         agent = self.agent
+        self._clock.switch("dispatch")
         t0 = time.perf_counter()
-        item.t_serve0 = t0
+        item.t_exec0 = t0
         try:
-            item.serve_handle = item.fn.serve_admit(item.staged, item.ctx)
+            with obs_trace.use_context(item.trace):
+                item.serve_handle = item.fn.serve_admit(item.staged, item.ctx)
         except Exception as exc:  # noqa: BLE001 — op error → failed
             item.status = "failed"
             item.error = structured_error(exc)
@@ -307,9 +367,10 @@ class PipelineRunner:
             )
             self._put_post(item)
             return
-        # Prefill is device time; the decode iterations bill per pump.
-        agent.note_device_time(
-            item.op, time.perf_counter() - t0,
+        # Prefill blocks until the engine holds the rows: device time from
+        # its own start and end; the decode iterations bill per pump.
+        agent.note_device_interval(
+            item.op, t0, time.perf_counter(),
             item.ctx.tags if item.ctx is not None else None,
         )
         agent.recorder.record(
@@ -325,18 +386,23 @@ class PipelineRunner:
         finished. Finished sequences freed their slots inside the engine
         step, so backlogged requests joined BETWEEN iterations."""
         agent = self.agent
+        self._clock.switch("serve_pump")
         engines: Dict[int, Any] = {}
         for item in serving:
             engines.setdefault(id(item.serve_handle["engine"]), item)
         t0 = time.perf_counter()
         occupancy = 0
         for item in engines.values():
-            occupancy = max(occupancy, item.fn.serve_pump(item.serve_handle))
+            with obs_trace.use_context(item.trace):
+                occupancy = max(
+                    occupancy, item.fn.serve_pump(item.serve_handle))
         if engines:
             first = next(iter(engines.values()))
             # Decode-iteration device time, attributed once per pump (the
-            # overlapped items share the very same dispatch).
-            agent.note_device_time(first.op, time.perf_counter() - t0, None)
+            # overlapped items share the very same dispatch); the step reads
+            # its tokens back, so its return is its completion.
+            agent.note_device_interval(
+                first.op, t0, time.perf_counter(), None)
             agent.m_serve_occupancy.set(occupancy)
         for item in [
             it for it in serving if it.fn.serve_done(it.serve_handle)
@@ -353,26 +419,17 @@ class PipelineRunner:
                     type=type(exc).__name__, message=str(exc)[:200],
                 )
             item.serve_handle = None
-            dt = time.perf_counter() - item.t_serve0
-            agent.m_phase.observe(
-                dt, exemplar={"trace_id": item.job_id},
-                op=item.op, phase="execute",
-            )
-            agent.trace_span(
-                "execute", item.trace_id, item.span_parent,
-                start_mono=item.t_serve0, duration_s=dt,
-                op=item.op, status=item.status,
-            )
-            agent.recorder.record(
-                "phase", phase="executed", job_id=item.job_id, op=item.op,
-                lease_id=item.lease_id, status=item.status,
+            # Admit to collect, across many passes of the loop: no with
+            # block can hold it, so the phase's sinks are fed directly.
+            obs_trace.record_phase(
+                "execute", item.trace, item.t_exec0, time.perf_counter(),
+                status=item.status,
             )
             self._put_post(item)
         if not serving:
             agent.m_serve_occupancy.set(0)
 
     def _execute_loop(self) -> None:
-        agent = self.agent
         pending: Any = None
         # Continuous-serving items currently riding a decode engine
         # (ISSUE 15): the loop interleaves one engine iteration per pass
@@ -380,6 +437,7 @@ class PipelineRunner:
         # while bulk shards stage and new serving jobs join between steps.
         serving: list = []
         stopping = False
+        self._clock = clock = _ThreadClock(self.m_thread, "wait_staged")
         try:
             while True:
                 item = None
@@ -394,13 +452,13 @@ class PipelineRunner:
                         except queue.Empty:
                             item = None
                     else:
-                        # Busy/idle attribution (the tf.data question — is
-                        # the input stage or the accelerator the limiter?):
-                        # time blocked here is device idle; time inside the
-                        # op dispatch is device busy.
-                        t_wait = time.perf_counter()
+                        # The tf.data question — is the input stage or the
+                        # accelerator the limiter? — from this thread's
+                        # side: blocked here it is starved of staged work.
+                        # (Whether the DEVICE idles meanwhile is
+                        # note_device_interval's to say.)
+                        clock.switch("wait_staged")
                         item = self.staged_q.get()
-                        agent.m_device_idle.inc(time.perf_counter() - t_wait)
                 if item is _STOP:
                     # Keep pumping until in-flight serving work posts —
                     # a leased request must answer even through shutdown.
@@ -416,8 +474,11 @@ class PipelineRunner:
                     break
         finally:
             self._put_post(_STOP)  # same lost-sentinel guard as the stager
+            clock.switch(None)
 
     _peeked: Any = None
+    # The device thread's state clock, alive while _execute_loop runs.
+    _clock: Optional[_ThreadClock] = None
 
     def _execute_item(self, item: Any, serving: list) -> None:
         agent = self.agent
@@ -434,6 +495,7 @@ class PipelineRunner:
             # transfers now, so they run under the current item's execute.
             # The popped item is handed back to the loop via _peeked and
             # consumed on the next iteration — never lost.
+            self._clock.switch("prefeed")
             try:
                 peeked = self.staged_q.get_nowait()
             except queue.Empty:
@@ -441,29 +503,29 @@ class PipelineRunner:
             if peeked is not None and peeked is not _STOP:
                 self._prefeed(peeked)
             self._peeked = peeked
-        t_exec = time.perf_counter()
-        if item.t_staged:
-            # Time spent waiting in the staged queue — the
-            # backpressure gap between host staging and the device.
-            agent.trace_span(
-                "queue", item.trace_id, item.span_parent,
-                start_mono=item.t_staged,
-                duration_s=t_exec - item.t_staged, op=item.op,
-            )
-        # Pre-minted so compile spans emitted inside the dispatch
-        # (executor cache misses) parent to this execute span.
-        exec_span_id = new_span_id()
-        trace_ctx = TraceContext(
-            trace_id=item.trace_id or item.job_id,
-            parent_span_id=exec_span_id,
-            tracer=agent.tracer,
-            registry=agent.obs,
-            process=agent._process_name(),
-        )
-        try:
-            # profiled_call covers phased ops too — PROFILE_DIR
-            # traces capture the device phase either way (§5.1).
-            with use_context(trace_ctx):
+        # The execute phase below annotates this extent itself.
+        self._clock.switch("dispatch", annotate=False)
+        item.deferred = (not item.monolithic
+                         and bool(getattr(item.fn, "deferred", False)))
+        if item.deferred:
+            item.seq = agent.device_dispatched()
+        # What the dispatch compiles (the first call of a program) parents
+        # to the execute span, at the compile's own length.
+        with obs_trace.phase("execute", item.trace,
+                             annotation="agent.dispatch") as ph:
+            item.t_exec0 = ph.t0
+            if item.t_staged:
+                # Time spent waiting in the staged queue — the
+                # backpressure gap between host staging and the device.
+                # An item's wait, not this thread's work: span and
+                # flight-recorder event, no annotation (the histogram is
+                # fed from the op's own queue_ms at finalize).
+                obs_trace.record_phase(
+                    "queue", item.trace, item.t_staged, ph.t0,
+                    histogram=False)
+            try:
+                # profiled_call covers phased ops too — PROFILE_DIR
+                # traces capture the device phase either way (§5.1).
                 if item.monolithic:
                     item.result = agent.profiled_call(
                         item.op,
@@ -474,37 +536,35 @@ class PipelineRunner:
                         item.op,
                         lambda i=item: i.fn.execute(i.staged, i.ctx),
                     )
-        except Exception as exc:  # noqa: BLE001 — op error → failed
-            item.status = "failed"
-            item.error = structured_error(exc)
-            agent.rate.log("exec", "op raised", op=item.op,
-                           type=type(exc).__name__)
-            agent.recorder.record(
-                "error", phase="execute", job_id=item.job_id,
-                op=item.op, lease_id=item.lease_id,
-                type=type(exc).__name__, message=str(exc)[:200],
-            )
-        dt = time.perf_counter() - t_exec
-        # Per-op device attribution + duty/MFU rollup (ISSUE 8).
-        agent.note_device_time(
-            item.op, dt,
-            item.ctx.tags if item.ctx is not None else None,
+            except Exception as exc:  # noqa: BLE001 — op error → failed
+                item.status = "failed"
+                item.error = structured_error(exc)
+                agent.rate.log("exec", "op raised", op=item.op,
+                               type=type(exc).__name__)
+                agent.recorder.record(
+                    "error", phase="execute", job_id=item.job_id,
+                    op=item.op, lease_id=item.lease_id,
+                    type=type(exc).__name__, message=str(exc)[:200],
+                )
+            ph.attributes["status"] = item.status
+        if not item.deferred or item.status == "failed":
+            # The execute blocked until its result was on the host, or
+            # raised: its own start and end are the dispatch and the
+            # completion.
+            self._account(item, ph.t1)
+        if not self._put_post(item):
+            # Nobody will finalize it: its number must still come in.
+            self._account(item, time.perf_counter())
+
+    def _account(self, item: Any, t_ready: float) -> None:
+        """Report the item's device interval, once (either thread)."""
+        if item.accounted:
+            return
+        item.accounted = True
+        self.agent.note_device_interval(
+            item.op, item.t_exec0, t_ready,
+            item.ctx.tags if item.ctx is not None else None, seq=item.seq,
         )
-        agent.m_phase.observe(
-            dt, exemplar={"trace_id": item.job_id},
-            op=item.op, phase="execute",
-        )
-        agent.trace_span(
-            "execute", item.trace_id, item.span_parent,
-            span_id=exec_span_id, start_mono=t_exec, duration_s=dt,
-            op=item.op, status=item.status,
-        )
-        agent.recorder.record(
-            "phase", phase="executed", job_id=item.job_id,
-            op=item.op, lease_id=item.lease_id,
-            status=item.status,
-        )
-        self._put_post(item)
 
     # ---- poster thread ----
 
@@ -534,7 +594,29 @@ class PipelineRunner:
                 agent.flush_spool(session=session, force=True)
                 break
             agent.m_queue.set(self.post_q.qsize(), queue="post")
-            t_fin = time.perf_counter()
+            # Poster-thread cost as one span: finalize (incl. the deferred
+            # device→host fetch, which the op measures as its own ``fetch``
+            # phase inside) + the result post (``post_http`` inside). Ships
+            # on the NEXT post or the final metrics-only flush.
+            with obs_trace.phase("post", item.trace,
+                                 histogram=False) as posted:
+                self._finalize_and_post(item, session, posted)
+            # Spooled redelivery rides the poster cadence (backoff-gated
+            # inside flush_spool) — the pipelined drain heals from a
+            # controller blip the same way the serial loop does.
+            agent.flush_spool(session=session)
+            self.tasks_posted += 1
+            agent.tasks_done += 1
+            agent.m_tasks.inc(op=item.op, status=item.status)
+            agent.note_progress(queues={
+                "staged_q": self.staged_q.qsize(),
+                "post_q": self.post_q.qsize(),
+            })
+
+    def _finalize_and_post(self, item: Any, session: Any,
+                           posted: Any) -> None:
+        agent = self.agent
+        with obs_trace.phase("finalize", span=False) as fin:
             try:
                 if item.executed is not None:
                     item.result = item.fn.finalize(item.executed, item.ctx)
@@ -547,74 +629,58 @@ class PipelineRunner:
                     op=item.op, lease_id=item.lease_id,
                     type=type(exc).__name__, message=str(exc)[:200],
                 )
-            finalize_s = time.perf_counter() - t_fin
-            agent.m_phase.observe(
-                finalize_s, exemplar={"trace_id": item.job_id},
-                op=item.op, phase="finalize",
+        if item.deferred:
+            # The completion of an execute that may only have dispatched:
+            # the instant the op says its results were on the host (where it
+            # says none — the fetch itself failed — the device had nothing
+            # more to give by the time finalize gave up). BEFORE the usage
+            # block is read below: device_s is this interval.
+            state = item.executed
+            stamped = state.get("t_ready") if isinstance(state, dict) else None
+            self._account(item, stamped or fin.t1)
+        finalize_s = fin.seconds
+        duration_ms = (fin.t1 - item.t_start) * 1000.0
+        if item.ctx is not None:
+            # Poster-thread host seconds join the stage stamp (ISSUE 9).
+            stamp_usage(item.ctx.tags, host_s=finalize_s)
+            timings = item.ctx.tags.setdefault("timings", {})
+            # Stamped here because finalize cannot time its own return;
+            # rides the result body so scrape-side attribution sees the
+            # poster-thread cost too.
+            timings["finalize_ms"] = round(finalize_s * 1000.0, 3)
+            # The queue wait comes from the op's own timings; stage/execute/
+            # fetch/finalize were measured at their boundaries (observing
+            # both views would double-count those phases).
+            agent.record_phase_timings(
+                item.op, timings, keys=("queue_ms",), trace_id=item.job_id,
             )
-            duration_ms = (time.perf_counter() - item.t_start) * 1000.0
+        if isinstance(item.result, dict):
+            item.result.setdefault("duration_ms", duration_ms)
             if item.ctx is not None:
-                # Poster-thread host seconds join the stage stamp (ISSUE 9).
-                stamp_usage(item.ctx.tags, host_s=finalize_s)
-                timings = item.ctx.tags.setdefault("timings", {})
-                # Stamped here because finalize cannot time its own return;
-                # rides the result body so scrape-side attribution sees the
-                # poster-thread cost too.
-                timings["finalize_ms"] = round(finalize_s * 1000.0, 3)
-                # queue/fetch come from the op's own timings; stage/execute/
-                # finalize were measured wall-clock by the runner threads
-                # (observing both views would double-count those phases).
-                agent.record_phase_timings(
-                    item.op, timings, keys=("queue_ms", "fetch_ms"),
-                    trace_id=item.job_id,
-                )
-            if isinstance(item.result, dict):
-                item.result.setdefault("duration_ms", duration_ms)
-                if item.ctx is not None:
-                    if item.ctx.tags.get("timings"):
-                        item.result.setdefault(
-                            "timings", item.ctx.tags["timings"]
-                        )
+                if item.ctx.tags.get("timings"):
                     item.result.setdefault(
-                        "trace", item.ctx.tags.get("trace")
+                        "timings", item.ctx.tags["timings"]
                     )
-                    if item.ctx.tags.get("usage"):
-                        # Usage block (ISSUE 9): what the controller's
-                        # showback ledger bills for this task.
-                        item.result.setdefault(
-                            "usage", item.ctx.tags["usage"]
-                        )
-            agent.post_result(
-                item.lease_id, item.job_id, item.epoch, item.status,
-                result=item.result, error=item.error, session=session,
-                op=item.op,
-            )
-            # Poster-thread cost as one span: finalize (incl. the deferred
-            # device→host fetch) + the result post. Ships on the NEXT post
-            # or the final metrics-only flush.
-            agent.trace_span(
-                "post", item.trace_id, item.span_parent,
-                start_mono=t_fin,
-                duration_s=time.perf_counter() - t_fin,
-                op=item.op, status=item.status,
-                finalize_ms=round(finalize_s * 1e3, 3),
-            )
-            # Spooled redelivery rides the poster cadence (backoff-gated
-            # inside flush_spool) — the pipelined drain heals from a
-            # controller blip the same way the serial loop does.
-            agent.flush_spool(session=session)
-            self.tasks_posted += 1
-            agent.tasks_done += 1
-            agent.m_tasks.inc(op=item.op, status=item.status)
-            agent.recorder.record(
-                "phase", phase="posted", job_id=item.job_id, op=item.op,
-                lease_id=item.lease_id, status=item.status,
-                duration_ms=round(duration_ms, 3),
-            )
-            agent.note_progress(queues={
-                "staged_q": self.staged_q.qsize(),
-                "post_q": self.post_q.qsize(),
-            })
+                item.result.setdefault(
+                    "trace", item.ctx.tags.get("trace")
+                )
+                if item.ctx.tags.get("usage"):
+                    # Usage block (ISSUE 9): what the controller's
+                    # showback ledger bills for this task.
+                    item.result.setdefault(
+                        "usage", item.ctx.tags["usage"]
+                    )
+        agent.post_result(
+            item.lease_id, item.job_id, item.epoch, item.status,
+            result=item.result, error=item.error, session=session,
+            op=item.op,
+        )
+        # The span's own duration_ms is the poster's extent; the task's
+        # (stage entered to finalize returned) rides beside it.
+        posted.attributes.update(
+            status=item.status, finalize_ms=round(finalize_s * 1e3, 3),
+            task_duration_ms=round(duration_ms, 3),
+        )
 
     # ---- lifecycle ----
 

@@ -66,7 +66,10 @@ class Signals:
     slo_burning: bool = False
     verdict: str = "ok"
     # Live = polled recently AND not draining; duty cycles are the live
-    # members' rolling device_duty_cycle gauges (None = no data yet).
+    # members' rolling device_duty_cycle gauges: the share of the last 60 s
+    # in which the device had work in flight, measured from completion
+    # events (Agent.note_device_interval), not the dispatching thread's
+    # time (None = no data yet).
     live_agents: int = 0
     draining_agents: int = 0
     max_duty: Optional[float] = None

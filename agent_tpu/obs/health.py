@@ -6,10 +6,13 @@ Two halves (ISSUE 8):
   per-op device-busy increments into a *rolling duty cycle* (busy seconds
   inside the last N seconds / N), and :func:`resolve_peak_flops` maps a
   runtime's device kind to its peak dense-bf16 FLOP/s so the agent can
-  export an analytic-FLOPs MFU gauge per op. Both are estimates by design:
-  duty counts dispatch wall time (what the device *thread* spent inside op
-  execute), MFU counts matmul-term analytic FLOPs over that time — the same
-  accounting bench.py has always used, now live on ``/v1/metrics``.
+  export an analytic-FLOPs MFU gauge per op. Duty counts the seconds the
+  device had work in flight, from completion events: dispatch (or the
+  previous program's completion — the device runs them in order) to the
+  result seen ready on the host (``Agent.note_device_interval``); a ready
+  seen late moves seconds between neighbouring tasks, never into or out of
+  the total. MFU counts matmul-term analytic FLOPs (padded shapes, the ops'
+  own estimate) over those seconds.
 - **Verdict assembly.** :func:`build_health` rolls SLO judgments, queue
   pressure, starvation, and per-agent liveness/utilization into ONE
   machine-readable dict — the exact signal vector ROADMAP item 4's
@@ -146,8 +149,9 @@ def agent_health(
 ) -> Dict[str, Any]:
     """One agent's health row from its ``controller.agent_metrics`` entry:
     liveness plus the utilization series its obs snapshot carries. The
-    rolling ``device_duty_cycle`` gauge is preferred; agents predating it
-    degrade to the cumulative busy/(busy+idle) ratio."""
+    rolling ``device_duty_cycle`` gauge (completion-event busy seconds in
+    the last 60 s over the window) is preferred; agents predating it degrade
+    to the cumulative busy/(busy+idle) ratio."""
     if now_wall is None:
         now_wall = time.time()
     last_seen = float(entry.get("last_seen_wall", 0.0))

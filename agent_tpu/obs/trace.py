@@ -5,10 +5,11 @@ PR 2 gave the swarm aggregate metrics and a flight recorder; this module
 those layers already stamp into a *causal timeline*: one span tree per job,
 ``trace_id = job_id``, covering controller ``submit`` (the root), scheduler
 decisions, the lease window, the agent-side ``stage``/``queue``/``execute``/
-``post`` phases (the PipelineRunner's existing wall-clock measurements,
-converted to spans instead of re-clocked), XLA compile cost
-(``xla.compile`` spans emitted by the executor's compile cache on every
-miss), spool redeliveries, and controller ``apply``.
+``post`` phases (each measured ONCE, by :class:`phase`, which feeds the
+histogram, the span and a profiler annotation from the same two clock
+reads), XLA compile cost (``xla.compile`` spans emitted from the runtime's
+``jax.monitoring`` listener, one per executable obtained), spool
+redeliveries, and controller ``apply``.
 
 Dependency-free by the same rule as ``obs.metrics``: stdlib only.
 
@@ -29,9 +30,15 @@ Shapes:
   the metrics-only flush lease the same way metric snapshots ship;
   ``requeue`` puts them back when the post fails.
 - **TraceContext** (a contextvar) — the ambient ``(trace_id,
-  parent_span_id, tracer, registry)`` the agent sets around op execution so
-  deep layers (the executor's compile cache) can attribute their spans to
-  the task that triggered them without plumbing arguments through jax.
+  parent_span_id, tracer, registry, op, …)`` the agent sets around a task's
+  phases so deep layers (an op's own ``fetch`` phase, the runtime's compile
+  listener) can attribute what they measure to the task that triggered it
+  without plumbing arguments through jax.
+- **phase** — the one phase boundary: a context manager that observes
+  ``task_phase_seconds{op,phase}``, buffers the job's span, notes the
+  flight recorder and, for its whole extent, holds a
+  ``jax.profiler.TraceAnnotation("agent.<phase>")`` so any profiler capture
+  shows the agent's phases on the trace's own clock.
 - **TraceStore** — the controller-side assembly point: bounded per-trace
   span maps (dedup by ``span_id``, so redelivered piggybacks are
   idempotent), ``assemble()`` returning sorted spans with orphans flagged.
@@ -48,8 +55,10 @@ from __future__ import annotations
 import collections
 import contextlib
 import contextvars
+import dataclasses
 import json
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -289,14 +298,22 @@ def get_tracer() -> SpanBuffer:
 
 @dataclass(frozen=True)
 class TraceContext:
-    """What a deep layer needs to attribute a span to the current task:
-    where to record (``tracer``/``registry``) and what to parent to."""
+    """What a deep layer needs to attribute a measurement to the current
+    task: where to record (``tracer``/``registry``/``recorder``), what to
+    parent to, and which op and job it belongs to. ``trace_id`` is empty for
+    a task the controller stamped no trace on: its phases are still timed
+    and annotated, but emit no span and no exemplar."""
 
     trace_id: str = ""
     parent_span_id: Optional[str] = None
     tracer: Optional[SpanBuffer] = None
     registry: Any = None
     process: str = ""
+    op: str = ""
+    recorder: Any = None   # FlightRecorder: one "phase" event per span
+    # The task's identity as the flight recorder carries it
+    # (job_id / lease_id / attempt).
+    job: Mapping[str, Any] = field(default_factory=dict)
 
 
 _current: "contextvars.ContextVar[Optional[TraceContext]]" = (
@@ -317,65 +334,224 @@ def use_context(ctx: Optional[TraceContext]):
         _current.reset(token)
 
 
-def record_compile(
-    key: Sequence[Any], seconds: float, name: str = "xla.compile"
+# ---- the phase boundary (histogram + span + profiler annotation) ----
+
+PHASE_HISTOGRAM = "task_phase_seconds"
+_NO_CONTEXT = TraceContext()
+
+
+def annotate(name: str) -> Any:
+    """An entered ``jax.profiler.TraceAnnotation(name)``, or None on a
+    process that never imported jax (a host-only agent must not pay the
+    import for a profiler it cannot have). With no profiler session open an
+    annotation is one atomic load; the caller exits what it gets."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+class phase:
+    """One phase boundary, measured once: ``with phase("stage", ctx) as ph``
+    reads the clock on entry and exit and feeds every sink from that pair.
+
+    - ``histogram``: ``task_phase_seconds{op, phase}`` in ``ctx.registry``
+      (with the trace id as exemplar);
+    - ``span``: the job's span ``name`` in ``ctx.tracer``, parented to
+      ``ctx.parent_span_id``, plus one ``phase`` event in ``ctx.recorder``;
+      while the phase is open the AMBIENT context's parent is this span, so
+      what runs inside (an op's ``fetch``, an ``xla.compile``) nests under
+      it;
+    - ``annotation``: ``agent.<name>`` (or the string given) on the calling
+      thread's line of any profiler capture.
+
+    ``ctx`` defaults to the ambient context — an op calls ``phase("fetch")``
+    and lands in the task the agent set around it; with no context at all
+    only the clock and the annotation remain. A phase that itself finds out
+    which task it serves (``stage`` resolves the task) assigns ``ph.ctx``
+    inside the block. ``ph.t0``/``ph.t1``/``ph.seconds`` are the
+    measurement; ``ph.attributes`` may be filled before exit; an exception
+    passing through marks ``status="failed"``. Never raises from a sink."""
+
+    __slots__ = ("name", "ctx", "histogram", "span", "annotation",
+                 "attributes", "span_id", "t0", "t1", "_ann", "_token")
+
+    def __init__(self, name: str, ctx: Optional[TraceContext] = None, *,
+                 histogram: bool = True, span: bool = True,
+                 annotation: str = "", **attributes: Any) -> None:
+        self.name = name
+        self.ctx = ctx
+        self.histogram = histogram
+        self.span = span
+        self.annotation = annotation or f"agent.{name}"
+        self.attributes = attributes
+        self.span_id: Optional[str] = None
+        self.t0 = self.t1 = 0.0
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self) -> "phase":
+        ctx = self.ctx
+        if ctx is None:
+            ctx = self.ctx = _current.get() or _NO_CONTEXT
+        if self.span and enabled():
+            # What runs inside sees this span as its parent, and no flight
+            # recorder: the ring notes a task's top-level phases only.
+            self.span_id = new_span_id()
+            ctx = dataclasses.replace(
+                ctx, parent_span_id=self.span_id, recorder=None)
+        self._token = _current.set(ctx)
+        self._ann = annotate(self.annotation)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _current.reset(self._token)
+        if exc_type is not None:
+            self.attributes["status"] = "failed"
+        record_phase(
+            self.name, self.ctx, self.t0, self.t1, histogram=self.histogram,
+            span=self.span, span_id=self.span_id, **self.attributes)
+        return False
+
+
+def record_phase(
+    name: str, ctx: TraceContext, t0: float, t1: float, *,
+    histogram: bool = True, span: bool = True,
+    span_id: Optional[str] = None, **attributes: Any,
 ) -> None:
-    """Called by ``ExecutableCache`` on every build (cache miss): emit an
-    ``xla.compile`` span attributed to the ambient task context and tick
-    ``runtime_compile_seconds_total{op}``. Key convention: ``key[0]`` is the
-    op name, the rest is the shape/dtype/mesh signature. Must never raise —
-    a broken trace path must not fail a compile that already succeeded."""
+    """The sinks of :class:`phase` for an extent that is no ``with`` block
+    (a serving job's execute runs across many passes of the device loop):
+    ``task_phase_seconds{op, phase}``, the job's span, the flight recorder.
+    ``t0``/``t1`` are ``perf_counter`` reads. The recorder's event carries
+    the job's keys and the attributes under ``phase`` and ``duration_ms`` of
+    this extent (an attribute of either name loses: the poster's task
+    duration travels as ``task_duration_ms``). The sinks are this package's
+    own and raise on nothing well-formed, so nothing here is swallowed."""
+    seconds = t1 - t0
+    if histogram and ctx.registry is not None:
+        ctx.registry.histogram(
+            PHASE_HISTOGRAM, "", ("op", "phase")
+        ).observe(
+            seconds,
+            exemplar={"trace_id": ctx.trace_id} if ctx.trace_id else None,
+            op=ctx.op or "?", phase=name,
+        )
+    if not span:
+        return
+    attributes = {k: v for k, v in attributes.items() if v is not None}
+    if ctx.op:
+        attributes.setdefault("op", ctx.op)
+    if ctx.trace_id and ctx.tracer is not None and enabled():
+        ctx.tracer.add(make_span(
+            name, ctx.trace_id, ctx.parent_span_id,
+            start_mono=t0, duration_s=seconds, span_id=span_id,
+            process=ctx.process, attributes=attributes,
+        ))
+    if ctx.recorder is not None:
+        ctx.recorder.record("phase", **{
+            **ctx.job, **attributes,
+            "phase": name, "duration_ms": round(seconds * 1e3, 3),
+        })
+
+
+# ---- XLA executables, counted where they are obtained ----
+
+def _ambient_registry(ctx: Optional[TraceContext]) -> Any:
+    registry = getattr(ctx, "registry", None)
+    if registry is None:
+        from agent_tpu.obs.metrics import get_registry
+
+        registry = get_registry()
+    return registry
+
+
+def _count(name: str, help: str, amount: float = 1.0,
+           **labels: str) -> None:
+    """Tick a counter in the ambient task's registry, else the process's.
+    Must never raise: a broken metrics path must not fail what it counts."""
+    try:
+        _ambient_registry(current()).counter(
+            name, help, tuple(labels)
+        ).inc(amount, **labels)
+    except Exception:  # noqa: BLE001
+        pass
+
+
+def record_compile(seconds: float, program: str = "") -> None:
+    """One executable obtained from XLA — compiled, or loaded from the
+    persistent cache; either stalls the caller for ``seconds``. Called by
+    the runtime's ``jax.monitoring`` listener (``runtime/executor.py``) ON
+    the compiling thread, so the ambient context is the task whose call
+    needed the program: ticks ``runtime_xla_executables_total`` and
+    ``runtime_compile_seconds_total{op}`` (``op="?"`` outside a task) in that
+    task's registry, else the process registry, and emits the
+    ``xla.compile`` span at the event's own length. Must never raise — a
+    broken trace path must not fail a compile that already succeeded."""
     try:
         ctx = current()
-        op = str(key[0]) if key else "?"
-        shape_key = ",".join(str(k) for k in key[1:])
-        registry = getattr(ctx, "registry", None)
-        if registry is None:
-            from agent_tpu.obs.metrics import get_registry
-
-            registry = get_registry()
-        registry.counter(
-            "runtime_compile_seconds_total",
-            "Seconds spent in XLA compiles (executable-cache misses)",
-            ("op",),
-        ).inc(max(0.0, float(seconds)), op=op)
+        seconds = max(0.0, float(seconds))
+        op = (ctx.op if ctx else "") or "?"
+        _count("runtime_xla_executables_total",
+               "Executables obtained from XLA: compiled, or loaded from the "
+               "persistent compile cache (either stalls the caller)")
+        _count("runtime_compile_seconds_total",
+               "Seconds callers waited for XLA to hand over an executable "
+               "(backend compile or persistent-cache load), by the op whose "
+               "task was running; contains the small programs a params build "
+               "obtains, so it overlaps runtime_params_seconds_total",
+               seconds, op=op)
         if not enabled():
             return
         tracer = (ctx.tracer if ctx and ctx.tracer is not None
                   else get_tracer())
         tracer.add(make_span(
-            name,
+            "xla.compile",
             trace_id=ctx.trace_id if ctx else "",
             parent_span_id=ctx.parent_span_id if ctx else None,
-            start_mono=time.monotonic() - max(0.0, float(seconds)),
+            start_mono=time.monotonic() - seconds,
             duration_s=seconds,
             process=ctx.process if ctx else "",
-            attributes={"op": op, "shape_key": shape_key},
+            attributes={"op": op, "program": str(program)},
         ))
-    except Exception:  # noqa: BLE001 — tracing must never break a build
+    except Exception:  # noqa: BLE001 — tracing must never break a compile
         pass
 
 
-def record_cache_event(key: Sequence[Any], hit: bool, registry: Any = None
-                       ) -> None:
-    """Executable-cache hit/miss counters (``runtime_compile_cache_total``),
-    landing in the ambient context's registry when one is set."""
-    try:
-        if registry is None:
-            ctx = current()
-            registry = getattr(ctx, "registry", None)
-        if registry is None:
-            from agent_tpu.obs.metrics import get_registry
+def record_xla_cache_hit() -> None:
+    """An executable came out of the persistent compile cache; the
+    executable itself is counted by :func:`record_compile` when the load
+    returns."""
+    _count("runtime_xla_cache_hits_total",
+           "Executables loaded from the persistent compile cache "
+           "(a subset of runtime_xla_executables_total)")
 
-            registry = get_registry()
-        registry.counter(
-            "runtime_compile_cache_total",
-            "Executable-cache lookups by op and outcome",
-            ("op", "outcome"),
-        ).inc(op=str(key[0]) if key else "?",
-              outcome="hit" if hit else "miss")
-    except Exception:  # noqa: BLE001
-        pass
+
+def record_params_build(seconds: float) -> None:
+    """One model's weights built and placed on the device (a params-store
+    miss in ``TpuRuntime.get_params``)."""
+    _count("runtime_params_seconds_total",
+           "Seconds spent building one model's weights and placing them on "
+           "the device (params-store misses); contains the executables the "
+           "build obtains, so it overlaps runtime_compile_seconds_total",
+           max(0.0, float(seconds)))
+
+
+def record_cache_event(key: Sequence[Any], hit: bool) -> None:
+    """One lookup of the runtime's keyed cache of jit wrappers."""
+    _count("runtime_compile_cache_total",
+           "Lookups of the runtime's keyed cache of jit WRAPPERS by op and "
+           "outcome (a miss builds a wrapper, not an executable: see "
+           "runtime_xla_executables_total for those)",
+           op=str(key[0]) if key else "?",
+           outcome="hit" if hit else "miss")
 
 
 # ---- controller-side assembly ----

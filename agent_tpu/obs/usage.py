@@ -4,9 +4,11 @@ The fleet could say *how busy* it was (``device_busy_seconds_total``) but
 not *who* made it busy — the question a multi-tenant deployment bills on and
 the autoscaler's capacity math starts from. The accounting path:
 
-- **Agents** stamp a ``usage`` block into every result body (the dispatch
-  loop adds ``device_s``/``chips``/``flops`` in ``note_device_time`` — the
-  SAME float that feeds ``device_busy_seconds_total``, so ledger totals
+- **Agents** stamp a ``usage`` block into every result body
+  (``Agent.note_device_interval`` adds ``device_s``/``chips``/``flops``:
+  the seconds the device had THIS task's work in flight, from its dispatch
+  or the previous task's completion to its result seen ready — the SAME
+  float that feeds ``device_busy_seconds_total``, so ledger totals
   reconcile with the fleet counter exactly on clean traffic; the
   stage/finalize phases add ``host_s``; ops add ``rows`` via
   ``_model_common.stamp_rows``).

@@ -43,6 +43,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from agent_tpu.obs import trace as obs_trace
 from agent_tpu.ops import register_op
 from agent_tpu.utils.errors import bad_input
 
@@ -629,6 +630,7 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
                 fallback_reason=None,
                 t_device=time.perf_counter(),
             )
+            # Dispatch only: finalize stamps the completion (t_ready).
             return state
         vals, idx = _execute_chunks(
             runtime, state["chunks"], model_id, cfg, k,
@@ -656,11 +658,13 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
             state["degraded_reason"] = (
                 f"{type(exc).__name__}: {exc}; cpu retry: {cpu_exc}"
             )
-            state["t_device"] = time.perf_counter()
+            state["t_ready"] = state["t_device"] = time.perf_counter()
             return state
+    # Fetched above: the results are on the host, the device is done.
+    state["t_ready"] = t_device = time.perf_counter()
     state.update(
         vals=vals, idx=idx, device=device, fallback_reason=fallback_reason,
-        t_device=time.perf_counter(),
+        t_device=t_device,
     )
     return state
 
@@ -694,9 +698,10 @@ def finalize(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, A
         # off the device thread. elapsed_ms keeps covering the true span;
         # the wait is stamped as timings.fetch_ms (device_ms is dispatch
         # only in this mode).
-        t_f = time.perf_counter()
-        vals, idx = _fetch_pending(state["pending_dev"])
-        state["fetch_ms"] = (time.perf_counter() - t_f) * 1000.0
+        with obs_trace.phase("fetch") as fetched:
+            vals, idx = _fetch_pending(state["pending_dev"])
+        state["t_ready"] = fetched.t1
+        state["fetch_ms"] = fetched.seconds * 1000.0
     else:
         vals, idx = state["vals"], state["idx"]
 
@@ -791,3 +796,7 @@ def run(payload: Any, ctx: Optional[object] = None) -> Dict[str, Any]:
 run.stage = stage
 run.execute = execute
 run.finalize = finalize
+# execute may return with the device still working (drain mode defers the
+# fetch to finalize): the state carries ``t_ready``, the instant the results
+# were on the host, from whichever phase fetched them.
+run.deferred = True

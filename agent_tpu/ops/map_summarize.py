@@ -33,6 +33,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from agent_tpu.obs import trace as obs_trace
 from agent_tpu.ops import register_op
 from agent_tpu.utils.errors import bad_input
 
@@ -484,6 +485,7 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
     )
     state["device"] = runtime.platform
     state["t_device"] = time.perf_counter()
+    # Dispatch only: finalize stamps the completion (t_ready).
     return state
 
 
@@ -492,11 +494,12 @@ def finalize(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, A
     result. Safe off the device thread (reads numpy arrays only)."""
     # Deferred fetch: sync the device token arrays here, off the device
     # thread (the pipeline's poster thread pays the round trip).
-    t_f = time.perf_counter()
-    token_chunks = [
-        np.asarray(toks)[:n] for toks, n in state["token_chunks"]
-    ]
-    fetch_ms = (time.perf_counter() - t_f) * 1000.0
+    with obs_trace.phase("fetch") as fetched:
+        token_chunks = [
+            np.asarray(toks)[:n] for toks, n in state["token_chunks"]
+        ]
+    state["t_ready"] = fetched.t1
+    fetch_ms = fetched.seconds * 1000.0
     summaries: List[str] = []
     if state["family"] == "t5":
         from agent_tpu.models import t5
@@ -615,3 +618,6 @@ def run(payload: Any, ctx: Optional[object] = None) -> Dict[str, Any]:
 run.stage = stage
 run.execute = execute
 run.finalize = finalize
+# execute returns with the device still decoding (finalize fetches): the
+# state carries ``t_ready``, the instant the tokens were on the host.
+run.deferred = True

@@ -10,15 +10,53 @@ stays small and stops missing once the buckets are warm.
 
 Keys are caller-built tuples of hashables (op name, shape tuple, dtype string,
 mesh axis sizes). Stats are exported for the metrics channel (SURVEY.md §5.5).
+
+What the cache holds for ``runtime.compiled(key, build)`` is a ``jax.jit``
+WRAPPER: XLA compiles (or loads from the persistent cache) at the wrapper's
+first call, inside the op's dispatch. So executables are counted where JAX
+obtains them, by :func:`install_xla_listener`, not where the wrapper is built.
 """
 
 from __future__ import annotations
 
 import threading
-import time
-from typing import Any, Callable, Dict, Hashable, Optional, Tuple
+from typing import Any, Callable, Dict, Hashable, Tuple
 
 from agent_tpu.obs import trace as obs_trace
+
+# JAX's own monitoring events (jax/_src/dispatch.py, compilation_cache.py):
+# the duration event wraps ``compile_or_get_cached``, so it fires once per
+# executable obtained, compiled or loaded; the plain event marks a load.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+_listener_lock = threading.Lock()
+_listener_installed = False
+
+
+def _on_duration(event: str, seconds: float, **kwargs: Any) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        obs_trace.record_compile(seconds, program=kwargs.get("fun_name", ""))
+
+
+def _on_event(event: str, **_kwargs: Any) -> None:
+    if event == CACHE_HIT_EVENT:
+        obs_trace.record_xla_cache_hit()
+
+
+def install_xla_listener() -> None:
+    """Register the process's ONE ``jax.monitoring`` listener (idempotent).
+    JAX calls listeners on the thread that needed the executable, so
+    ``obs.trace.record_compile`` finds the task's ambient context there."""
+    global _listener_installed
+    with _listener_lock:
+        if _listener_installed:
+            return
+        import jax.monitoring as monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
+        _listener_installed = True
 
 
 class ExecutableCache:
@@ -30,21 +68,18 @@ class ExecutableCache:
     slow XLA compile does not serialize unrelated ops, with a per-key event so
     concurrent builders of the same key trigger exactly one build.
 
-    Compile-cost attribution (ISSUE 5): with ``trace_label`` set (the
-    default, ``"xla.compile"``), every miss emits a span named after it —
-    attributed to the ambient :mod:`agent_tpu.obs.trace` task context, so a
-    cold compile shows up inside the triggering job's ``execute`` span —
-    plus ``runtime_compile_seconds_total{op}`` and per-op hit/miss counters.
-    The params store passes ``trace_label=None``: an HBM transfer is not a
-    compile and must not pollute the compile-cost series.
+    With ``count_lookups`` (the default) every lookup ticks
+    ``runtime_compile_cache_total{op, outcome}`` in the ambient task's
+    registry. The params store passes ``False``: a weights transfer is not a
+    program lookup.
     """
 
-    def __init__(self, trace_label: Optional[str] = "xla.compile") -> None:
+    def __init__(self, count_lookups: bool = True) -> None:
         self._lock = threading.Lock()
         self._cache: Dict[Tuple[Hashable, ...], Any] = {}
         self._building: Dict[Tuple[Hashable, ...], threading.Event] = {}
         self._generation = 0  # bumped by clear(); fences in-flight builds
-        self._trace_label = trace_label
+        self._count_lookups = count_lookups
         self.hits = 0
         self.misses = 0
 
@@ -56,7 +91,7 @@ class ExecutableCache:
                 fn = self._cache.get(key)
                 if fn is not None:
                     self.hits += 1
-                    if self._trace_label:
+                    if self._count_lookups:
                         obs_trace.record_cache_event(key, hit=True)
                     return fn
                 ev = self._building.get(key)
@@ -66,15 +101,10 @@ class ExecutableCache:
                     gen = self._generation
                     break
             ev.wait()  # someone else is compiling this key
-        if self._trace_label:
+        if self._count_lookups:
             obs_trace.record_cache_event(key, hit=False)
         try:
-            t0 = time.perf_counter()
             fn = build()
-            if self._trace_label:
-                obs_trace.record_compile(
-                    key, time.perf_counter() - t0, name=self._trace_label
-                )
             with self._lock:
                 # A clear() that raced this build wins: return the value to
                 # the caller but do NOT cache it, so a post-clear store is

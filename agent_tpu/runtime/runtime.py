@@ -12,6 +12,7 @@ platform ``jax.devices()`` actually reports; env vars are hints, never proof.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Callable, Dict, Hashable, Optional, Sequence, Tuple
 
 import jax
@@ -19,10 +20,15 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from agent_tpu.config import DeviceConfig
-from agent_tpu.runtime.executor import ExecutableCache
+from agent_tpu.obs import trace as obs_trace
+from agent_tpu.runtime.executor import ExecutableCache, install_xla_listener
 from agent_tpu.runtime.mesh import build_mesh
 from agent_tpu.utils.logging import log
 from agent_tpu.utils.paths import cache_dir
+
+# Every program this package's users jit is counted from here on, whichever
+# entry point built it (a runtime, a bare ExecutableCache, a model's init).
+install_xla_listener()
 
 
 def parse_chip_slice(spec: str) -> Tuple[int, int]:
@@ -139,9 +145,9 @@ class TpuRuntime:
             jax.profiler.start_server(self.config.profile_port)
         self.mesh: Mesh = build_mesh(self.devices, self.config.mesh_shape)
         self.cache = ExecutableCache()
-        # Build-once dedup like executables, but NOT a compile: params
-        # builds are HBM transfers and stay out of the xla.compile series.
-        self._params = ExecutableCache(trace_label=None)
+        # Build-once dedup like executables, but not a program lookup: a
+        # params build is timed on its own (runtime_params_seconds_total).
+        self._params = ExecutableCache(count_lookups=False)
         self._model_ids: set = set()
         self._params_lock = threading.Lock()
         self._attention_fn = None
@@ -283,7 +289,7 @@ class TpuRuntime:
             self.axis_size("tp") > 1 or self.axis_size("ep") > 1
         )
 
-        def place() -> Any:
+        def put_tree() -> Any:
             host = build()
             if not use_specs:
                 return jax.tree_util.tree_map(
@@ -299,11 +305,25 @@ class TpuRuntime:
             def put(leaf, spec):
                 if isinstance(leaf, jax.Array) and leaf.committed:
                     return leaf
-                return jax.device_put(leaf, NamedSharding(self.mesh, spec))
+                return jax.device_put(
+                    leaf, NamedSharding(self.mesh, spec))
 
             return jax.tree_util.tree_map(
                 put, host, safe, is_leaf=lambda x: isinstance(x, P)
             )
+
+        def place() -> Any:
+            t0 = time.perf_counter()
+            try:
+                # ``device_put`` only enqueues: wait for the weights to
+                # land, or the counter holds the host build alone and the
+                # transfer hides in whatever first touches them. Once a
+                # model; the program that needs them waits for them anyway.
+                return jax.block_until_ready(put_tree())
+            finally:
+                # In the calling task's registry, as the compile listener
+                # counts: the first task of a model pays for its weights.
+                obs_trace.record_params_build(time.perf_counter() - t0)
 
         with self._params_lock:
             self._model_ids.add(model_id)

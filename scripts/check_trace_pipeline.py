@@ -2,8 +2,9 @@
 """CI smoke for the distributed-tracing pipeline (ISSUE 5).
 
 Drains a small multi-shard CSV map-reduce plus two ``compile_probe`` jobs
-(a smoke-local plugin op whose cold ``ExecutableCache`` build emits an
-``xla.compile`` span) through the real ``Agent`` loop over
+(a smoke-local plugin op whose first call of a fresh ``jax.jit`` program
+makes the runtime's compile listener emit an ``xla.compile`` span) through
+the real ``Agent`` loop over
 ``chaos.LoopbackSession``, then asserts the acceptance criteria end to end:
 
 1. every terminal job's ``GET /v1/trace/{job_id}`` is a single-rooted,
@@ -12,8 +13,8 @@ Drains a small multi-shard CSV map-reduce plus two ``compile_probe`` jobs
 2. the Perfetto export (``?format=perfetto``) round-trips through JSON and
    passes ``validate_chrome_trace`` — the schema the legacy Perfetto
    importer requires;
-3. at least one ``xla.compile`` span lands on the cold-cache probe run, and
-   the warm re-run stays a cache hit (counters prove it);
+3. exactly one ``xla.compile`` span lands on the cold-cache probe run, and
+   the warm re-run obtains no executable (counters prove it);
 4. the ``/v1/metrics`` exposition validates and its ``task_phase_seconds``
    buckets carry OpenMetrics exemplars whose trace_ids all resolve to jobs
    this smoke actually submitted;
@@ -27,7 +28,7 @@ Drains a small multi-shard CSV map-reduce plus two ``compile_probe`` jobs
 
 Exit 0 = clean; 1 = problems (one per line). Style sibling of
 ``scripts/check_metrics_endpoint.py``: repo-rooted, zero external deps
-(jax is optional — the probe's build falls back to a host callable).
+beyond jax (the compile span comes from JAX's own compile event).
 """
 
 from __future__ import annotations
@@ -65,13 +66,18 @@ BENCH_ROUNDS = 5
 BENCH_TOLERANCE = 0.90  # tracing-on rows/sec must stay within 10% of off
 
 # The probe op ships through the designed extension point (OPS_PLUGIN_PATH
-# / load_plugins) rather than monkey-patching the registry. Its build runs
-# inside the agent's ambient TraceContext, so the emitted span parents to
-# the triggering job's execute span — the same path a real op's
-# runtime.compiled() miss takes.
+# / load_plugins) rather than monkey-patching the registry. Its first call
+# runs inside the agent's ambient TraceContext, so the span the runtime's
+# compile listener emits parents to the triggering job's execute span — the
+# same path the first call of a real op's runtime.compiled() program takes.
+# Inputs are numpy arrays: a jnp constructor would be a program (and a
+# span) of its own.
 PLUGIN_SRC = '''\
-"""Smoke-only op: a cold ExecutableCache build per distinct payload n."""
+"""Smoke-only op: one fresh jit program per distinct payload n."""
 import time
+
+import jax
+import numpy as np
 
 from agent_tpu.ops import register_op
 from agent_tpu.runtime.executor import ExecutableCache
@@ -83,27 +89,12 @@ _CACHE = ExecutableCache()
 def run(payload, ctx=None):
     t0 = time.perf_counter()
     n = int(payload.get("n", 8))
-
-    def build():
-        try:
-            import jax
-            import jax.numpy as jnp
-
-            fn = jax.jit(lambda x: (x * 2.0 + 1.0).sum())
-            fn(jnp.zeros((n,), jnp.float32))  # the actual XLA compile
-
-            def call():
-                return float(fn(jnp.arange(n, dtype=jnp.float32)))
-        except Exception:  # jax-less host: the cache path is still the test
-
-            def call():
-                return float(sum(2.0 * i + 1.0 for i in range(n)))
-
-        return call
-
     t1 = time.perf_counter()
-    fn = _CACHE.get_or_build(("compile_probe", n), build)
-    value = fn()
+    fn = _CACHE.get_or_build(
+        ("compile_probe", n),
+        lambda: jax.jit(lambda x: (x * 2.0 + 1.0).sum()),
+    )
+    value = float(fn(np.arange(n, dtype=np.float32)))  # first call compiles
     t2 = time.perf_counter()
     if ctx is not None:
         # Stamp phase timings per the op contract (see
@@ -299,12 +290,24 @@ def main() -> int:
             for s in (controller.traces.spans(jid) or [])
             if s["name"] == "xla.compile"
         ]
-        if not compile_spans:
-            problems.append("no xla.compile span on the cold-cache run")
+        if len(compile_spans) != 1:
+            problems.append(
+                f"{len(compile_spans)} xla.compile spans on the cold-cache "
+                f"run, want exactly one"
+            )
         elif compile_spans[0]["trace_id"] != cold_probe:
             problems.append("xla.compile span attributed to the wrong job")
+        else:
+            execute = [
+                s for s in controller.traces.spans(cold_probe)
+                if s["name"] == "execute"
+            ]
+            if compile_spans[0]["parent_span_id"] != execute[0]["span_id"]:
+                problems.append("xla.compile span not under execute")
         if any(s["trace_id"] == warm_probe for s in compile_spans):
             problems.append("warm probe re-compiled (cache hit expected)")
+        if agent.obs.counter("runtime_xla_executables_total").value() != 1:
+            problems.append("runtime_xla_executables_total != 1")
         cache = agent.obs.counter(
             "runtime_compile_cache_total", "", ("op", "outcome")
         )

@@ -260,49 +260,91 @@ class TestExporters:
 
 
 class TestCompileAttribution:
-    def test_cache_miss_emits_span_and_counters(self):
+    """Executables are counted where JAX obtains them (the runtime's
+    ``jax.monitoring`` listener), not where a jit wrapper is built."""
+
+    @staticmethod
+    def _fresh_jit():
+        import jax
+
+        return jax.jit(lambda x: (x * 3.0 + 1.0).sum())
+
+    @staticmethod
+    def _counter(reg, name, **labels):
+        return reg.counter(name, "", tuple(labels)).value(**labels)
+
+    def test_first_call_emits_span_and_counters(self):
+        import numpy as np
+
         buf = SpanBuffer()
         reg = MetricsRegistry()
         cache = ExecutableCache()
         ctx = TraceContext(trace_id="job-c", parent_span_id="exec-span",
-                           tracer=buf, registry=reg, process="agent:t")
+                           tracer=buf, registry=reg, process="agent:t",
+                           op="my_op")
+        key = ("my_op", 8, 128, "f32")
         with use_context(ctx):
-            cache.get_or_build(("my_op", 8, 128, "f32"), lambda: object())
-            cache.get_or_build(("my_op", 8, 128, "f32"), lambda: object())
+            fn = cache.get_or_build(key, self._fresh_jit)
+            # Building the wrapper obtains nothing from XLA ...
+            assert buf.spans() == []
+            assert "runtime_xla_executables_total" not in reg.snapshot()
+            # ... the first call does, the second does not.
+            fn(np.zeros((8, 128), np.float32))
+            assert cache.get_or_build(key, self._fresh_jit) is fn
+            fn(np.ones((8, 128), np.float32))
         (span,) = buf.spans()
         assert span["name"] == "xla.compile"
         assert span["trace_id"] == "job-c"
         assert span["parent_span_id"] == "exec-span"
         assert span["attributes"]["op"] == "my_op"
-        assert span["attributes"]["shape_key"] == "8,128,f32"
-        assert reg.counter(
-            "runtime_compile_seconds_total", "", ("op",)
-        ).value(op="my_op") >= 0.0
+        assert span["attributes"]["program"].startswith("jit(")
+        assert self._counter(reg, "runtime_xla_executables_total") == 1
+        seconds = self._counter(
+            reg, "runtime_compile_seconds_total", op="my_op")
+        assert seconds > 0
+        # The span is the event's own duration, not a second clock.
+        assert span["duration_ms"] == pytest.approx(seconds * 1e3, abs=1e-3)
         hits = reg.counter("runtime_compile_cache_total", "",
                            ("op", "outcome"))
         assert hits.value(op="my_op", outcome="miss") == 1
         assert hits.value(op="my_op", outcome="hit") == 1
 
-    def test_params_cache_stays_out_of_compile_series(self):
+    def test_params_cache_stays_out_of_lookup_series(self):
         buf = SpanBuffer()
         reg = MetricsRegistry()
-        cache = ExecutableCache(trace_label=None)
+        cache = ExecutableCache(count_lookups=False)
         with use_context(TraceContext(trace_id="j", tracer=buf, registry=reg)):
             cache.get_or_build(("params", "m1", "rep"), lambda: object())
         assert len(buf) == 0
-        assert "runtime_compile_seconds_total" not in reg.snapshot()
+        assert reg.snapshot() == {}
 
     def test_disabled_tracing_skips_span_keeps_counter(self):
+        import numpy as np
+
         obs_trace.set_enabled(False)
         buf = SpanBuffer()
         reg = MetricsRegistry()
-        cache = ExecutableCache()
-        with use_context(TraceContext(trace_id="j", tracer=buf, registry=reg)):
-            cache.get_or_build(("op2", 1), lambda: object())
+        with use_context(TraceContext(trace_id="j", tracer=buf, registry=reg,
+                                      op="op2")):
+            self._fresh_jit()(np.zeros((3,), np.float32))
         assert len(buf) == 0  # span skipped
-        assert reg.counter(  # compile cost still counted — it's a metric
-            "runtime_compile_seconds_total", "", ("op",)
-        ).value(op="op2") >= 0.0
+        # compile cost still counted — it's a metric
+        assert self._counter(
+            reg, "runtime_compile_seconds_total", op="op2") > 0
+        assert self._counter(reg, "runtime_xla_executables_total") == 1
+
+    def test_outside_a_task_counts_in_the_process_registry(self):
+        import numpy as np
+
+        from agent_tpu.obs.metrics import get_registry
+
+        reg = get_registry()
+        before = self._counter(reg, "runtime_compile_seconds_total", op="?")
+        n0 = self._counter(reg, "runtime_xla_executables_total")
+        self._fresh_jit()(np.zeros((5,), np.float32))
+        assert self._counter(reg, "runtime_xla_executables_total") == n0 + 1
+        assert self._counter(
+            reg, "runtime_compile_seconds_total", op="?") > before
 
 
 class TestExemplars:
