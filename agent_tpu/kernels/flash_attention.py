@@ -1,10 +1,18 @@
-"""Fused (flash) attention as a Pallas TPU kernel.
+"""Fused attention as Pallas TPU kernels: streaming (flash) and whole-row.
 
-One grid program computes one [block_q, d_head] query tile for one (batch,
-head). The innermost grid axis walks K/V tiles sequentially (TPU grids are
-sequential, innermost fastest), carrying the streaming-softmax state — running
-row max ``m``, denominator ``l``, numerator ``acc`` — in VMEM scratch that
-persists across that axis. The [Lq, Lk] score matrix therefore never exists in
+Two schedules of one algorithm, chosen by shape and mask alone
+(:func:`selects_flash`, :func:`selects_whole_row`): from
+``FLASH_MIN_KEY_LEN`` keys on, the STREAMING kernel described next; below
+it, for whole-sequence attention under a key-padding mask, the WHOLE-ROW
+kernel (:func:`whole_row_attention`), whose score row fits VMEM and whose
+operands are the projections' own lane-dense [B, L, H*D]; dense XLA
+everywhere else.
+
+Streaming: one grid program computes one [block_q, d_head] query tile for
+one (batch, head). The innermost grid axis walks K/V tiles sequentially (TPU
+grids are sequential, innermost fastest), carrying the streaming-softmax
+state — running row max ``m``, denominator ``l``, numerator ``acc`` — in
+VMEM scratch that persists across that axis. The [Lq, Lk] score matrix therefore never exists in
 HBM; each tile's QKᵀ → mask → exp → ·V chain runs entirely out of VMEM, with
 the MXU doing both matmuls (``preferred_element_type=f32``) and the VPU the
 elementwise tail. This is the schedule XLA cannot be relied on to find whole:
@@ -44,21 +52,16 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
         return jax.default_backend() != "tpu"
     return interpret
 
-# Below this key length the XLA dense path wins END TO END. Attention-only
-# microbenchmarks on v5e show the kernel ahead already at Lk=512/d_head 64
-# (1.25-1.4×), but inside the full encoder the gate at 512 measured ~13%
-# SLOWER at BERT-base scale: pallas_call is a fusion barrier — XLA can no
-# longer fuse the projection matmuls/softmax chain around attention — and
-# the [B,L,H,D]→grid layout transitions eat the kernel's margin. The win
-# is real once the dense path's [Lq, Lk] score materialization dominates.
-# Measured per-call ratios vs the CURRENT dense path (which stores scores
-# in bf16 — that change roughly doubled dense speed and honestly shrank
-# these ratios from the old f32-score era's 3.7×/50×): 1.76× at 4k,
-# 2.21× at 8k, d_head 128 (driver artifact `flash_vs_dense[_8k]`,
-# BENCH_r05). The kernel's bigger win at long context is MEMORY — no
-# [L, L] score tensor in HBM, so batch/length scale past where dense
-# OOMs. Hence the 2048 gate; trust model-level numbers over kernel
-# microbenchmarks when moving it.
+# At and above this key length the STREAMING kernel below is selected: the
+# [Lq, Lk] score matrix never reaches HBM, so batch and length scale past
+# where the dense path runs out of memory. Its speed against the dense path
+# at 2048 and beyond: not measured on the present tree (no ledger cell runs
+# such lengths). Below it the whole score row fits VMEM and the whole-row
+# kernel (further down) takes over on the projections' own layout; this
+# kernel's [B, H, L, D] operands (d_head 64 in the lanes, a grid step a
+# (batch, head)) are what an old chip round found slower than dense inside
+# BERT-base at 512 keys, before the ledger: also not measured on the present
+# tree.
 FLASH_MIN_KEY_LEN = 2048
 
 # TRAINING gates lower. Serving loses at 512 because pallas_call breaks
@@ -77,6 +80,16 @@ FLASH_TRAIN_MIN_KEY_LEN = 512
 # around a warmup to *prove* which path a compiled executable contains —
 # "the bench exercises the Pallas kernel" becomes an assertion, not a belief.
 SELECTION_COUNTS = {"flash": 0, "dense": 0}
+
+
+def _note_selection(path: str) -> None:
+    """One attention block traced on ``path``: the module's tally, and
+    ``attention_blocks_traced_total{path}`` in the registry of the task whose
+    call traced the program (else the process's)."""
+    from agent_tpu.obs.trace import record_attention_block
+
+    SELECTION_COUNTS[path] = SELECTION_COUNTS.get(path, 0) + 1
+    record_attention_block(path)
 
 
 def selects_flash(seq_len: int, *, block: int = 512,
@@ -198,12 +211,9 @@ def flash_attention(
     ``interpret=None`` auto-selects interpreter mode off-TPU so the identical
     kernel is testable on the CPU mesh; pass False to require Mosaic.
 
-    Default 512×512 tiles measured best on v5e (scores tile = 1 MB VMEM).
-    Measured v5e per-call ratios vs the dense XLA path: 1.33× at 4k
-    context, 1.94× at 8k, at d_head 128 — see the ``FLASH_MIN_KEY_LEN``
-    note (incl. why these shrank when dense went bf16-score) and
-    ``bench.py``'s ``long_ctx`` leg, which records both as driver
-    artifacts (``flash_vs_dense_speedup``, ``flash_vs_dense_8k``).
+    Default 512×512 tiles (a score tile is 1 MB of VMEM). Speed against the
+    dense XLA path: not measured on the present tree (see the
+    ``FLASH_MIN_KEY_LEN`` note).
     """
     from agent_tpu.models.layers import is_key_padding_mask
 
@@ -219,7 +229,7 @@ def flash_attention(
         and Lq % bq == 0
         and Lk % bk == 0
     )
-    SELECTION_COUNTS["flash" if supported else "dense"] += 1
+    _note_selection("flash" if supported else "dense")
     if not supported:
         return dot_product_attention(q, k, v, mask)
     interpret = resolve_interpret(interpret)
@@ -260,6 +270,197 @@ def flash_attention(
         ),
         interpret=interpret,
     )(q, k, v, mask3d)
+
+
+# ---------------------------------------------------------------------------
+# Whole-row attention: below the streaming gate the score row fits VMEM, and
+# the operands stay in the projections' own lane-dense layout.
+#
+# ``flash_attention`` takes [B, H, L, D] and walks one (batch, head) a grid
+# step. At d_head 64 that puts 64 in the lanes of every operand (padded to
+# 128, relaid on the way in and out) and makes 3,072 steps a layer at
+# BERT-base 256 x 512. Here Q, K and V are [B, L, H*D] — what a
+# [B*L, d] x [d, H*D] matmul writes — and a grid step takes whole batch rows
+# with all their heads: one pass, no running maximum, no correction multiply,
+# no scratch carried across steps. Heads are cut from 128-lane groups inside
+# the kernel: with d_head 64 two heads share a group, and zeroing the partner
+# head's lanes of Q and contracting over all 128 gives the head's own sums on
+# the MXU passes a 64-deep contraction takes anyway, with no unaligned lane
+# slice; PV likewise yields the pair's [L, 128] and each head keeps its half.
+# ---------------------------------------------------------------------------
+
+# Key lengths at which the whole-row kernel is selected (inclusive); under
+# them the dense XLA path stays, from FLASH_MIN_KEY_LEN on the streaming
+# kernel. Set from chip runs of the WHOLE BERT-base encoder at 131,072 tokens
+# a program (512 x 64 at 64), dense against whole-row: 1.56 x at 64, 1.07 x at
+# 128, 1.19 x at 256, 1.26 x at 512, 1.31 x at 1024 (my chip run, PR 25; the
+# ledger's cells are `bert-base.drain-long` = 512 and `bert-base.drain-short`
+# = 64, metric `drain_rows_per_s`). 2048 and up: not measured.
+WHOLE_ROW_MIN_KEY_LEN = 64
+WHOLE_ROW_MAX_KEY_LEN = 1024
+
+# A step's blocks (q, k, v, out; double-buffered by the pipeline) and its
+# score temporaries must fit the scoped VMEM asked for below; v5e has 128 MiB.
+_WHOLE_ROW_VMEM_LIMIT = 64 * 1024 * 1024
+_WHOLE_ROW_BLOCK_BUDGET = 16 * 1024 * 1024
+# Rows x length a step: enough work to bury a step's fixed cost (~0.35 us).
+_WHOLE_ROW_STEP_TOKENS = 512
+
+
+def selects_whole_row(lq: int, lk: int, n_heads: int, d_head: int, *,
+                      key_padding: bool, dtype) -> bool:
+    """Shape-and-mask predicate: does whole-sequence attention of this kind
+    take the whole-row kernel? The one place the choice is made; callers
+    (``layers.attention``, ``bert.forward``) hand over the lane-dense layout
+    exactly when it says yes, and every other call keeps [B, H, L, D]."""
+    return bool(
+        key_padding
+        and lq == lk
+        and WHOLE_ROW_MIN_KEY_LEN <= lk <= WHOLE_ROW_MAX_KEY_LEN
+        and lk < FLASH_MIN_KEY_LEN
+        and (lk == 64 or lk % _LANES == 0)
+        and d_head in (64, _LANES)
+        and (n_heads * d_head) % _LANES == 0
+        and jnp.dtype(dtype) == jnp.bfloat16
+    )
+
+
+def _whole_row_tiles(batch: int, length: int, n_groups: int):
+    """(rows, lane groups) a grid step covers, from the shapes alone: all the
+    groups of a row unless the blocks outgrow their budget, and as many rows
+    as bring a step to ``_WHOLE_ROW_STEP_TOKENS`` tokens."""
+    groups = n_groups
+    while groups > 1 and (
+        8 * length * groups * _LANES * 2 > _WHOLE_ROW_BLOCK_BUDGET
+        or n_groups % groups
+    ):
+        groups -= 1
+    rows = max(1, min(batch, _WHOLE_ROW_STEP_TOKENS // length))
+    while batch % rows:
+        rows -= 1
+    return rows, groups
+
+
+def _whole_row_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *, scale: float,
+                      d_head: int, rows: int, groups: int):
+    heads_per_group = _LANES // d_head
+    # A power-of-two scale (d_head 64: 1/8) multiplies into Q exactly in
+    # bf16, on [L, 128] instead of [L, L]; any other scales the f32 scores.
+    fold_scale = float(np.log2(scale)).is_integer()
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+
+    def one_row(r):
+        keep = mask_ref[r] > 0                                # [1, L]
+        # A row with no key at all: exp(0) = 1 everywhere below, so the row
+        # would come out as V's mean; it is 0, as the streaming kernel has it.
+        any_key = jnp.max(keep.astype(jnp.float32), axis=-1, keepdims=True)
+        for g in range(groups):
+            lanes = slice(g * _LANES, (g + 1) * _LANES)
+            q_g = q_ref[r, :, lanes]                          # [L, 128]
+            k_g = k_ref[r, :, lanes]
+            v_g = v_ref[r, :, lanes]
+            out = None
+            for h in range(heads_per_group):
+                q_h = q_g
+                if heads_per_group > 1 or fold_scale:
+                    mine = (lane >= h * d_head) & (lane < (h + 1) * d_head)
+                    q_scale = jnp.where(
+                        mine, scale if fold_scale else 1.0, 0.0)
+                    q_h = (q_g.astype(jnp.float32) * q_scale).astype(
+                        q_g.dtype)
+                s = jax.lax.dot_general(                      # [L, L] f32
+                    q_h, k_g, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                if not fold_scale:
+                    s = s * scale
+                s = jnp.where(keep, s, NEG_INF)
+                m = jnp.max(s, axis=-1, keepdims=True)
+                p = jnp.exp(s - m)        # exactly 0 at a masked key
+                l = jnp.sum(p, axis=-1, keepdims=True)
+                pv = jax.lax.dot_general(                     # [L, 128] f32
+                    p.astype(v_g.dtype), v_g, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                pv = pv * (any_key / l)
+                out = pv if out is None else jnp.where(mine, pv, out)
+            o_ref[r, :, lanes] = out.astype(o_ref.dtype)
+
+    # The rows of a step run as a loop, not unrolled: the kernel's text, and
+    # the seconds to trace and lower it, stay those of one row.
+    if rows == 1:
+        one_row(0)
+    else:
+        jax.lax.fori_loop(0, rows, lambda r, _: one_row(r), None)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n_heads", "rows", "groups", "interpret"))
+def _whole_row_call(q, k, v, mask3d, *, n_heads: int, rows: int, groups: int,
+                    interpret: bool):
+    """The ``pallas_call``, under a jit of its own: an encoder calls it once
+    a block and an agent builds one program a tenant, and tracing and
+    lowering the kernel's body is seconds of Python each time. A jitted
+    callee is traced once a shape in the process and lowered once a
+    program."""
+    B, L, HD = q.shape
+    d_head = HD // n_heads
+    block = pl.BlockSpec((rows, L, groups * _LANES), lambda b, g: (b, 0, g),
+                         memory_space=pltpu.VMEM)
+    kernel = functools.partial(
+        _whole_row_kernel, scale=1.0 / float(np.sqrt(d_head)), d_head=d_head,
+        rows=rows, groups=groups,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid=(B // rows, HD // (groups * _LANES)),
+        in_specs=[
+            block, block, block,
+            pl.BlockSpec((rows, 1, L), lambda b, g: (b, 0, 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_WHOLE_ROW_VMEM_LIMIT,
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * B * n_heads * L * L * d_head,
+            bytes_accessed=4 * B * L * HD * q.dtype.itemsize,
+            transcendentals=B * n_heads * L * L,
+        ),
+        interpret=interpret,
+    )(q, k, v, mask3d)
+
+
+def whole_row_attention(
+    q: jax.Array,      # [B, L, H*D]
+    k: jax.Array,      # [B, L, H*D]
+    v: jax.Array,      # [B, L, H*D]
+    mask: jax.Array,   # [B|1, 1, 1, L] key-padding mask (1 = attend)
+    *,
+    n_heads: int,
+    rows_per_step: Optional[int] = None,
+    groups_per_step: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Fused attention on lane-dense operands → [B, L, H*D].
+
+    For the calls :func:`selects_whole_row` accepts, and only those: the
+    caller asks it first. f32 scores and statistics, bf16 into the PV matmul,
+    every key of every real row attended, 0 (not NaN) for a row with no key.
+    ``rows_per_step`` / ``groups_per_step`` override the tile geometry the
+    shapes give (:func:`_whole_row_tiles`) — for sweeps on the chip."""
+    B, L, HD = q.shape
+    rows, groups = _whole_row_tiles(B, L, HD // _LANES)
+    _note_selection("whole_row")
+    mask3d = jnp.broadcast_to(mask[:, 0, :, :], (B, 1, L)).astype(jnp.int32)
+    return _whole_row_call(
+        q, k, v, mask3d, n_heads=n_heads, rows=rows_per_step or rows,
+        groups=groups_per_step or groups,
+        interpret=resolve_interpret(interpret),
+    )
 
 
 def _flash_fold_kernel(q_ref, k_ref, v_ref, mask_ref,
@@ -455,9 +656,7 @@ def flash_attention_t5(
         and rel_bias.ndim == 2
         and rel_bias.shape[1] == H
     )
-    SELECTION_COUNTS["t5_flash" if supported else "t5_dense"] = (
-        SELECTION_COUNTS.get("t5_flash" if supported else "t5_dense", 0) + 1
-    )
+    _note_selection("t5_flash" if supported else "t5_dense")
     if not supported:
         return None
     interpret = resolve_interpret(interpret)
@@ -547,9 +746,7 @@ def make_flash_attention_t5(mesh, interpret: Optional[bool] = None):
             and H % tp == 0
             and rel_bias.shape[1] == H
         )
-        SELECTION_COUNTS["t5_flash" if ok else "t5_dense"] = (
-            SELECTION_COUNTS.get("t5_flash" if ok else "t5_dense", 0) + 1
-        )
+        _note_selection("t5_flash" if ok else "t5_dense")
         if not ok:
             return None
 
@@ -872,8 +1069,7 @@ def flash_attention_trainable(
         and Lq % bq == 0
         and Lk % bk == 0
     )
-    key = "flash_train" if supported else "dense_train"
-    SELECTION_COUNTS[key] = SELECTION_COUNTS.get(key, 0) + 1
+    _note_selection("flash_train" if supported else "dense_train")
     if not supported:
         return dot_product_attention(q, k, v, mask)
     interpret = resolve_interpret(interpret)
@@ -966,9 +1162,7 @@ def _make_mesh_wrapper(mesh, inner, dense_counter_key: Optional[str]):
         )
         if not ok:
             if dense_counter_key is not None:
-                SELECTION_COUNTS[dense_counter_key] = (
-                    SELECTION_COUNTS.get(dense_counter_key, 0) + 1
-                )
+                _note_selection(dense_counter_key)
             return dot_product_attention(q, k, v, mask)
         return sharded(q, k, v, materialize_key_padding_mask(mask, B, Lk))
 
@@ -987,8 +1181,55 @@ def make_flash_attention_trainable(mesh, interpret: Optional[bool] = None):
     return _make_mesh_wrapper(mesh, kernel, "dense_train")
 
 
+class WholeRowAttention:
+    """The lane-dense entry an ``attn_fn`` declares as ``attn_fn.whole_row``:
+    :meth:`selects` is :func:`selects_whole_row` on what one chip of the mesh
+    sees, and the call is :func:`whole_row_attention` (under ``shard_map`` on a
+    mesh: batch over ``dp``, the head-major lanes over ``tp``)."""
+
+    def __init__(self, mesh, interpret: Optional[bool] = None):
+        from jax.sharding import PartitionSpec as P
+
+        shape = dict(mesh.shape)
+        self.dp = shape.get("dp", 1)
+        self.tp = shape.get("tp", 1)
+        self._kernel = functools.partial(whole_row_attention,
+                                         interpret=interpret)
+        self._shard = None
+        if mesh.size > 1:
+            self._shard = functools.partial(
+                jax.shard_map, mesh=mesh,
+                in_specs=(P("dp", None, "tp"),) * 3
+                + (P("dp", None, None, None),),
+                out_specs=P("dp", None, "tp"),
+                check_vma=False,  # as _make_mesh_wrapper: no vma on pallas
+            )
+
+    def selects(self, batch: int, lq: int, lk: int, n_heads: int,
+                d_head: int, mask, dtype) -> bool:
+        from agent_tpu.models.layers import is_key_padding_mask
+
+        return _wrapper_shardable(batch, n_heads, self.dp, self.tp) and (
+            selects_whole_row(
+                lq, lk, n_heads // self.tp, d_head,
+                key_padding=is_key_padding_mask(mask, batch, lk), dtype=dtype,
+            )
+        )
+
+    def __call__(self, q, k, v, mask, *, n_heads: int):
+        if self._shard is None:
+            return self._kernel(q, k, v, mask, n_heads=n_heads)
+        from agent_tpu.models.layers import materialize_key_padding_mask
+
+        B, L, _ = q.shape
+        inner = functools.partial(self._kernel, n_heads=n_heads // self.tp)
+        return self._shard(inner)(
+            q, k, v, materialize_key_padding_mask(mask, B, L)
+        )
+
+
 def make_flash_attention(mesh, interpret: Optional[bool] = None):
-    """Mesh-aware flash attention: the kernel wrapped in ``shard_map``.
+    """Mesh-aware fused attention: the kernels wrapped in ``shard_map``.
 
     ``pallas_call`` has no GSPMD partitioning rule, so jitting the bare kernel
     over a dp/tp mesh silently all-gathers the batch and runs the full-batch
@@ -998,10 +1239,15 @@ def make_flash_attention(mesh, interpret: Optional[bool] = None):
     indivisible) fall back to the dense XLA path, which GSPMD partitions fine.
     ``interpret`` binds the kernel's mode (the runtime passes False; see
     :func:`resolve_interpret`).
+
+    The returned ``attn_fn`` takes [B, H, L, D] like every other, and
+    declares ``attn_fn.whole_row`` (:class:`WholeRowAttention`): callers that
+    can hand over [B, L, H*D] ask its ``selects`` first.
     """
     kernel = functools.partial(flash_attention, interpret=interpret)
-    if mesh.size == 1:
-        return kernel
-    # No counter key: the wrapper-level dense fallback predates the proof
-    # discipline and tests pin the "dense" counter to per-kernel decisions.
-    return _make_mesh_wrapper(mesh, kernel, None)
+    if mesh.size > 1:
+        # No counter key: the wrapper-level dense fallback predates the proof
+        # discipline and tests pin the "dense" counter to per-kernel decisions.
+        kernel = _make_mesh_wrapper(mesh, kernel, None)
+    kernel.whole_row = WholeRowAttention(mesh, interpret)
+    return kernel

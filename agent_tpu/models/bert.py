@@ -144,13 +144,23 @@ def forward(
     def split_heads(t):
         return t.reshape(B, L, cfg.num_heads, d_head).transpose(0, 2, 1, 3)
 
+    whole_row = layers.whole_row_entry(
+        attn_fn, B, L, L, cfg.num_heads, d_head, attn_mask, dtype
+    )
     for blk in params["layers"]:
         a = blk["attn"]
-        q = split_heads(layers.dense(a["q"], x, dtype))
-        k = split_heads(layers.dense(a["k"], x, dtype))
-        v = split_heads(layers.dense(a["v"], x, dtype))
-        ctx = attn_fn(q, k, v, attn_mask)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, L, cfg.hidden_size)
+        if whole_row is not None:
+            # [B, L, hidden] is what the projections write: no head split.
+            ctx = whole_row(
+                *(layers.dense(a[n], x, dtype) for n in ("q", "k", "v")),
+                attn_mask, n_heads=cfg.num_heads,
+            )
+        else:
+            q = split_heads(layers.dense(a["q"], x, dtype))
+            k = split_heads(layers.dense(a["k"], x, dtype))
+            v = split_heads(layers.dense(a["v"], x, dtype))
+            ctx = attn_fn(q, k, v, attn_mask)
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(B, L, cfg.hidden_size)
         x = _ln(a["ln"], x + layers.dense(a["o"], ctx, dtype),
                 cfg.layer_norm_eps)
         f = blk["ffn"]

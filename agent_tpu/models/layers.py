@@ -101,11 +101,10 @@ def dot_product_attention(
 
     Numerics/traffic contract: QKᵀ accumulates in f32 (MXU native), but the
     materialized [B, H, Lq, Lk] score array is stored in the **compute
-    dtype** (bf16 on TPU) — at seq 512 / BERT-base shapes that halves the
-    dominant HBM traffic of the layer and measures ~1.9× faster end-to-end
-    on v5e with max rel error identical to the bf16-input baseline (0.0056
-    vs f32 reference, both). Softmax statistics (exp, sum, divide) still
-    run in f32; with f32 inputs the whole path is f32 and matches the old
+    dtype** (bf16 on TPU), which halves the layer's dominant HBM traffic at
+    seq 512 / BERT-base shapes (its speed against f32 scores: not measured
+    on the present tree). Softmax statistics (exp, sum, divide) still run in
+    f32; with f32 inputs the whole path is f32 and matches the old
     ``jax.nn.softmax`` form exactly.
     """
     d = q.shape[-1]
@@ -143,6 +142,51 @@ def _proj_out(leaf: Any, x: jax.Array, dtype: Any) -> jax.Array:
     if quant.is_weight_only(leaf):
         return quant.wproj_out(leaf, x, dtype)
     return jnp.einsum("bhle,hed->bld", x, leaf.astype(dtype))
+
+
+def whole_row_entry(attn_fn, batch: int, lq: int, lk: int, n_heads: int,
+                    d_head: int, mask: jax.Array, dtype: Any):
+    """``attn_fn``'s lane-dense entry (``attn_fn.whole_row``, see
+    ``kernels.flash_attention.WholeRowAttention``) if it declares one AND its
+    shape-and-mask predicate takes this call; else None, and the caller keeps
+    the [B, H, L, D] path."""
+    entry = getattr(attn_fn, "whole_row", None)
+    if entry is not None and entry.selects(
+        batch, lq, lk, n_heads, d_head, mask, dtype
+    ):
+        return entry
+    return None
+
+
+def _attention_lane_dense(p: Params, x_q, x_kv, mask, dtype, attn_fn):
+    """:func:`attention` without a cache on [B, L, H*D] operands — the
+    projections' own layout, H*D in the lanes — where ``attn_fn`` takes them;
+    None where it does not (or a leaf is quantized: those projections write
+    [B, H, L, E])."""
+    from agent_tpu.models import quant
+
+    leaves = [p[name] for name in ("wq", "wk", "wv", "wo")]
+    if any(quant.is_quantized(w) or quant.is_weight_only(w) for w in leaves):
+        return None
+    wq, wk, wv, wo = leaves
+    d_model, H, E = wq.shape
+    B, Lq, _ = x_q.shape
+    entry = whole_row_entry(attn_fn, B, Lq, x_kv.shape[1], H, E, mask, dtype)
+    if entry is None:
+        return None
+
+    def proj(w, x):
+        # "bld,dhe->blhe" as the 2-D matmul it is: XLA lays a 4-D result out
+        # with L in the lanes and copies it back; a [B*L, H*E] one stays put.
+        y = jnp.dot(x.astype(dtype).reshape(-1, d_model),
+                    w.astype(dtype).reshape(d_model, H * E))
+        return y.reshape(B, x.shape[1], H * E)
+
+    out = entry(proj(wq, x_q), proj(wk, x_kv), proj(wv, x_kv), mask,
+                n_heads=H)
+    y = jnp.dot(out.reshape(-1, H * E),
+                wo.astype(dtype).reshape(H * E, d_model))
+    return y.reshape(B, Lq, d_model)
 
 
 def attention(
@@ -185,6 +229,10 @@ def attention(
     ``attn_fn`` is the inner attention kernel — the sp ring path
     (``agent_tpu.parallel.ring.ring_attention``) substitutes here.
     """
+    if cache is None:
+        lane_dense = _attention_lane_dense(p, x_q, x_kv, mask, dtype, attn_fn)
+        if lane_dense is not None:
+            return lane_dense, None
     q = _proj_in(p["wq"], x_q, dtype)
     k = _proj_in(p["wk"], x_kv, dtype)
     v = _proj_in(p["wv"], x_kv, dtype)

@@ -544,6 +544,19 @@ def record_params_build(seconds: float) -> None:
            max(0.0, float(seconds)))
 
 
+def record_attention_block(path: str) -> None:
+    """One attention block TRACED into an XLA program on ``path``
+    (``whole_row``, ``flash``, ``dense``, ...): the kernels decide from static
+    shapes while the surrounding jit traces, so this ticks once a block of a
+    program (twelve for a 12-layer encoder) and never again when the program
+    runs."""
+    _count("attention_blocks_traced_total",
+           "Attention blocks traced into XLA programs, by the path the "
+           "kernels' shape-and-mask predicates selected (ticks while a "
+           "program is traced, not when it runs)",
+           path=path)
+
+
 def record_cache_event(key: Sequence[Any], hit: bool) -> None:
     """One lookup of the runtime's keyed cache of jit wrappers."""
     _count("runtime_compile_cache_total",

@@ -183,7 +183,10 @@ class TpuRuntime:
         - mesh has an ``sp`` axis > 1 → ring attention over ``sp``
           (``agent_tpu.parallel.ring``);
         - real TPU (and ``PALLAS_ATTN`` not disabled) → the fused Pallas
-          flash kernel (``agent_tpu.kernels.flash_attention``);
+          kernels (``agent_tpu.kernels.flash_attention``): streaming from
+          2048 keys, and the whole-row kernel the returned ``attn_fn``
+          declares as ``attn_fn.whole_row`` for callers that can hand over
+          [B, L, H*D] (``layers.attention``, ``bert.forward``);
         - otherwise → the dense XLA dot-product path.
 
         Each choice silently degrades to dense for unsupported shapes, so the

@@ -318,6 +318,18 @@ def count_entries(path: Optional[str]) -> int:
     return len(os.listdir(path))
 
 
+def attention_blocks(registry=None) -> Dict[str, float]:
+    """``attention_blocks_traced_total`` by path, from ``registry`` (an
+    agent's: what its tasks traced) or the process registry (what code
+    outside any task traced): which attention path the programs built so far
+    contain."""
+    from agent_tpu.obs.metrics import get_registry
+
+    family = (registry or get_registry()).snapshot().get(
+        "attention_blocks_traced_total") or {"series": []}
+    return {s["labels"]["path"]: float(s["value"]) for s in family["series"]}
+
+
 # ---- phase: kernels ------------------------------------------------------
 
 def phase_kernels() -> None:
@@ -355,10 +367,11 @@ def phase_kernels() -> None:
     def f32(*xs):
         return tuple(x.astype(jnp.float32) for x in xs)
 
-    def ticked(key: str, before: Dict[str, int]) -> None:
-        check(fa.SELECTION_COUNTS.get(key, 0) > before.get(key, 0),
-              f"selection counter {key!r} did not tick — the kernel was "
-              f"not selected ({fa.SELECTION_COUNTS})")
+    def ticked(key: str, before: Dict[str, float]) -> None:
+        now = attention_blocks()
+        check(now.get(key, 0) > before.get(key, 0),
+              f"attention_blocks_traced_total{{path={key!r}}} did not tick — "
+              f"the kernel was not selected ({now})")
 
     def close(name: str, got, want, tol: float) -> float:
         got = np.asarray(got, dtype=np.float32)
@@ -386,13 +399,32 @@ def phase_kernels() -> None:
 
     for L in (2048, 4096):
         q, k, v, mask = inputs(L, seed=L)
-        before = dict(fa.SELECTION_COUNTS)
+        before = attention_blocks()
         got = jax.jit(lambda q, k, v, m: fa.flash_attention(
             q, k, v, m, interpret=interpret))(q, k, v, mask)
         ticked("flash", before)
         want = reference(dot_product_attention, *f32(q, k, v), mask)
         errors[f"flash_attention_L{L}"] = close(
             f"flash_attention L={L}", got, want, tol)
+
+    # The whole-row kernel on the projections' own [B, L, H*D] layout, at
+    # the two lengths the benchmark's cells run.
+    def lane_dense(t):
+        return t.transpose(0, 2, 1, 3).reshape(B, t.shape[2], H * D)
+
+    for L in (512, 64):
+        q, k, v, mask = inputs(L, seed=L)
+        check(fa.selects_whole_row(L, L, H, D, key_padding=True,
+                                   dtype=q.dtype),
+              f"selects_whole_row({L}) is false")
+        before = attention_blocks()
+        got = jax.jit(lambda q, k, v, m: fa.whole_row_attention(
+            lane_dense(q), lane_dense(k), lane_dense(v), m, n_heads=H,
+            interpret=interpret))(q, k, v, mask)
+        ticked("whole_row", before)
+        want = reference(dot_product_attention, *f32(q, k, v), mask)
+        errors[f"whole_row_attention_L{L}"] = close(
+            f"whole_row_attention L={L}", got, lane_dense(want), tol)
 
     # Training pair at L 512: forward and the gradient of a weighted sum.
     q, k, v, mask = inputs(512, seed=512)
@@ -406,7 +438,7 @@ def phase_kernels() -> None:
     def trainable(q, k, v, m):
         return fa.flash_attention_trainable(q, k, v, m, interpret=interpret)
 
-    before = dict(fa.SELECTION_COUNTS)
+    before = attention_blocks()
     got_o = jax.jit(trainable)(q, k, v, mask)
     got_g = jax.jit(grads(trainable))(q, k, v)
     ticked("flash_train", before)
@@ -423,7 +455,7 @@ def phase_kernels() -> None:
     q, k, v, mask = inputs(L, seed=5)
     q = (q.astype(jnp.float32) * D ** -0.5).astype(jnp.bfloat16)
     table = jax.random.normal(jax.random.PRNGKey(9), (buckets, H))
-    before = dict(fa.SELECTION_COUNTS)
+    before = attention_blocks()
     got = jax.jit(lambda q, k, v, m, t: fa.flash_attention_t5(
         q, k, v, m, t, bidirectional=True, max_distance=max_distance,
         scale=1.0, interpret=interpret))(q, k, v, mask, table)
@@ -489,6 +521,11 @@ def phase_drain(stack: Stack, data: Dict[str, Any]) -> Dict[str, Any]:
         data["classify_csv"], map_op="map_classify_tpu",
         total_rows=DRAIN_SHARD, shard_size=DRAIN_SHARD, extra=extra))
     t1 = time.perf_counter()
+    if REQUIRED_PLATFORM == "tpu":  # the CPU runtime selects no kernel
+        blocks = attention_blocks(stack.agent.obs)
+        check(blocks.get("whole_row", 0) >= CLASSIFY_MODEL["n_layers"],
+              "the classify program did not take the whole-row kernel: "
+              f"attention_blocks_traced_total is {blocks}")
     ids = stack.post_csv_job(
         data["classify_csv"], map_op="map_classify_tpu",
         total_rows=DRAIN_ROWS, shard_size=DRAIN_SHARD, extra=extra)
@@ -677,7 +714,7 @@ def phase_train(stack: Stack, data: Dict[str, Any], tmp: str) -> None:
     runtime = stack.agent.runtime
     t0 = time.perf_counter()
     out_path = os.path.join(tmp, "trained.npz")
-    before = dict(fa.SELECTION_COUNTS)
+    before = attention_blocks(stack.agent.obs)
     train = stack.wait_jobs([stack.post_job("train_classifier", {
         "source_uri": data["train_csv"], "text_field": "text",
         "label_field": "label", "output_path": out_path,
@@ -702,7 +739,7 @@ def phase_train(stack: Stack, data: Dict[str, Any], tmp: str) -> None:
             seq, batch=TRAIN_BATCH, n_heads=CLASSIFY_MODEL["n_heads"],
             mesh=runtime.mesh,
         ), f"selects_flash_train({seq}) is false")
-        check(fa.SELECTION_COUNTS.get("flash_train", 0)
+        check(attention_blocks(stack.agent.obs).get("flash_train", 0)
               > before.get("flash_train", 0),
               "the training step did not select flash_train")
         check(peak is not None and limit is not None

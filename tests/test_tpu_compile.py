@@ -94,6 +94,20 @@ def _fold(chip, L, D):
     return fn, (*_qkvm(chip, L, D), *state)
 
 
+def _whole_row(batch):
+    """The whole-row kernel at a benchmark cell's own shape: the step's
+    blocks and score temporaries must fit the VMEM it asks for."""
+    def build(chip, L, D):
+        x = jax.ShapeDtypeStruct((batch, L, H * D), jnp.bfloat16,
+                                 sharding=chip)
+        mask = jax.ShapeDtypeStruct((batch, 1, 1, L), jnp.int32,
+                                    sharding=chip)
+        fn = lambda q, k, v, m: fa.whole_row_attention(  # noqa: E731
+            q, k, v, m, n_heads=H, interpret=False)
+        return fn, (x, x, x, mask)
+    return build
+
+
 # (case, builder, key length, d_head, Pallas calls in the compiled program)
 CASES = [
     ("forward_L4096_d64", _forward, 4096, 64, 1),
@@ -102,6 +116,9 @@ CASES = [
     ("train_fwd_bwd_L2048", _train, 2048, 64, 3),
     ("t5_bias_L2048", _t5, 2048, 64, 1),
     ("ring_fold_hop1024", _fold, 1024, 64, 1),
+    ("whole_row_256x512", _whole_row(256), 512, 64, 1),   # drain-long
+    ("whole_row_512x64", _whole_row(512), 64, 64, 1),     # drain-short
+    ("whole_row_8x128_d128", _whole_row(8), 128, 128, 1),
 ]
 
 
