@@ -216,3 +216,45 @@ def read_shard_column(
 def read_shard_texts(payload: Dict, default_field: str = "text") -> List[str]:
     """The text-op flavor of :func:`read_shard_column` (``text_field`` key)."""
     return read_shard_column(payload, "text_field", default_field)
+
+
+# A row of token ids is one CSV field of megabytes at the longest documents;
+# the csv module's default field limit is 128 KiB.
+TOKEN_FIELD_LIMIT = 1 << 26
+
+
+def check_token_ids(ids: np.ndarray, vocab_size: int) -> np.ndarray:
+    """Integer ids → int32, or ValueError naming the first id outside
+    ``[0, vocab_size)``."""
+    if ids.min() < 0 or ids.max() >= vocab_size:
+        bad = ids[(ids < 0) | (ids >= vocab_size)][0]
+        raise ValueError(f"token id {int(bad)} out of range [0, {vocab_size})")
+    return ids.astype(np.int32)
+
+
+def parse_token_ids(text: str, vocab_size: int) -> np.ndarray:
+    """One row of space-separated token ids → int32 array, checked against
+    the vocabulary as ``map_classify_tpu`` checks ``input``. ValueError (a
+    caller's error → soft ``bad_input``) on an empty row, anything that is
+    not a whole number, or an id outside ``[0, vocab_size)``."""
+    parts = text.split()
+    if not parts:
+        raise ValueError("a row of token ids is empty")
+    try:
+        ids = np.array(parts, dtype=np.int64)
+    except (ValueError, OverflowError):
+        raise ValueError(
+            "token ids must be space-separated whole numbers") from None
+    return check_token_ids(ids, vocab_size)
+
+
+def read_shard_token_ids(payload: Dict, vocab_size: int,
+                         default_field: str = "ids") -> List[np.ndarray]:
+    """The pre-tokenized flavor of :func:`read_shard_texts`: the column
+    ``ids_field`` names holds space-separated token ids, one document a row.
+    Same error contract as :func:`read_shard_column`, plus ValueError for a
+    row :func:`parse_token_ids` rejects."""
+    if csv.field_size_limit() < TOKEN_FIELD_LIMIT:
+        csv.field_size_limit(TOKEN_FIELD_LIMIT)
+    return [parse_token_ids(t, vocab_size)
+            for t in read_shard_column(payload, "ids_field", default_field)]

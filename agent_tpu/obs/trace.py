@@ -557,6 +557,41 @@ def record_attention_block(path: str) -> None:
            path=path)
 
 
+def record_retention_block(path: str) -> None:
+    """One retention mixer TRACED into an XLA program on ``path``
+    (``state``: chunk-plus-state, ``quadratic``: the quadratic form alone;
+    ``kernels/power_retention.selects_state_path`` decides from the shapes).
+    Beside :func:`record_attention_block`: ticks while a program is traced,
+    once a mixer call (once a scanned layer stack), never when it runs."""
+    _count("retention_blocks_traced_total",
+           "Retention mixers traced into XLA programs, by the path the "
+           "kernel's shape predicate selected (ticks while a program is "
+           "traced, not when it runs)",
+           path=path)
+
+
+def record_retention_tokens(path: str, tokens: int) -> None:
+    """Real tokens DISPATCHED whose mixer did (``state``) or did not
+    (``quadratic``) read a carried state: counted by the op at dispatch,
+    from the chunk each token falls in."""
+    if tokens > 0:
+        _count("retention_tokens_total",
+               "Tokens dispatched to a retention mixer, by whether the "
+               "token's chunk read a carried state (state) or was the "
+               "quadratic form alone (quadratic)",
+               float(tokens), path=path)
+
+
+def record_lm_segments(op: str, segments: int) -> None:
+    """Fixed-shape segment programs an op dispatched (a document longer
+    than one program runs as several, the state handed on on the device)."""
+    if segments > 0:
+        _count("lm_segments_total",
+               "Fixed-shape segment programs dispatched by the "
+               "language-model ops (a long document is several)",
+               float(segments), op=op)
+
+
 def record_cache_event(key: Sequence[Any], hit: bool) -> None:
     """One lookup of the runtime's keyed cache of jit wrappers."""
     _count("runtime_compile_cache_total",

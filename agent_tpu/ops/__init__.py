@@ -70,6 +70,9 @@ OP_TO_MODULE: Dict[str, str] = {
     "trigger_sap": "trigger_sap",        # now a real registered op (gap 4 fixed)
     "trigger_oracle": "trigger_oracle",
     "train_classifier": "train_classifier",  # train → .npz artifact → serve
+    # Bulk scoring of pre-tokenized documents with a decoder language model
+    # (ISSUE 27): a row longer than one program, state handed on on device.
+    "map_score_lm": "map_score_lm",
 }
 
 # Deterministic ops whose results may be served from the content-addressed

@@ -1,0 +1,328 @@
+"""Score pre-tokenized documents with a base language model, in bulk.
+
+A drain op like ``map_classify_tpu`` (``stage`` / ``execute`` / ``finalize``,
+``run.deferred = True``): ``POST /v1/jobs`` with ``map_op: "map_score_lm"``
+and CSV shard addressing, ``ids_field`` naming a column of space-separated
+token ids; ``model_config`` and ``model_path`` as the other model ops take
+them (weights from the model id). Full contract:
+``map_score_lm.CONTRACT.md``. Result, columnar, one entry a document:
+
+- ``n_tokens``: tokens scored (a longer row is cut at ``max_len``);
+- ``logprob_sum``: sum over t = 1 .. L-1 of log p(token_t | tokens before
+  t), natural log, over the whole vocabulary;
+- ``block_logprob_sums``: the same sum by blocks of 1,024 PREDICTING
+  positions (block j holds the targets t with (t - 1) // 1024 == j): what a
+  filtering pipeline thresholds.
+
+New to the op layer: a ROW LONGER THAN ONE PROGRAM. ``_model_common``
+budgets a batch of short rows; here a document runs as fixed-shape SEGMENTS
+(``SEGMENT_BUCKETS`` tokens, one document a program) and the mixer's state
+goes from one segment program to the next as device arrays, never through
+the host. The loss head is a program of its own (``lm_loss_head``) that
+never holds more than a [segment, 4,096] block of logits (the full
+[L, 151,936] float32 logits of a 32 k document would be 19.9 GB).
+
+Programs have fixed shapes: per segment bucket one program for a document's
+first segment (no state comes in: its first chunk is the quadratic form
+alone) and one for every later segment, plus the head. There is no CPU
+retry: a device failure fails the shard, as ``allow_fallback: false`` does
+for the classify op.
+"""
+
+from __future__ import annotations
+
+import time
+from typing import Any, Dict, List, Optional, Tuple
+
+import numpy as np
+
+from agent_tpu.obs import trace as obs_trace
+from agent_tpu.ops import register_op
+from agent_tpu.utils.errors import bad_input
+
+OP = "map_score_lm"
+FAMILY = "decoder_lm"
+DEFAULT_MODEL_ID = "score-lm-default"
+# Tokens a segment program. A document is whole 4,096-token segments and a
+# last one in the smallest bucket that holds the rest. 4,096 x 5,120 keeps
+# every matmul of the published widths MXU-bound (measured: PERF.md) and the
+# MLP's [4096, 17408] intermediates at 143 MB; one document a program.
+SEGMENT_BUCKETS = (1024, 4096)
+
+
+def _get_cfg(payload: Dict[str, Any]):
+    from agent_tpu.models.decoder_lm import DecoderLMConfig, validate
+    from agent_tpu.ops._model_common import apply_quant_env, config_from_payload
+
+    cfg = apply_quant_env(payload, config_from_payload(payload, DecoderLMConfig))
+    validate(cfg)
+    return cfg
+
+
+def _collect_documents(payload: Dict[str, Any], cfg) -> List[np.ndarray]:
+    """Payload → one int32 id array a document: ``ids`` (a list of id lists)
+    or CSV shard addressing with ``ids_field``. ValueError → soft
+    ``bad_input``; shard I/O and integrity errors propagate so the shard
+    fails and is retried."""
+    from agent_tpu.data.csv_index import check_token_ids, read_shard_token_ids
+
+    if "source_uri" in payload and "ids" not in payload:
+        docs = read_shard_token_ids(payload, cfg.vocab_size)
+    else:
+        raw = payload.get("ids")
+        if not isinstance(raw, list) or not raw:
+            raise ValueError(
+                "payload requires 'ids' (a list of token-id lists) or "
+                "'source_uri' CSV shard addressing")
+        docs = []
+        for row in raw:
+            if not isinstance(row, list) or not row or any(
+                    isinstance(v, bool) or not isinstance(v, int) for v in row):
+                raise ValueError(
+                    "every document must be a non-empty list of ints")
+            docs.append(check_token_ids(np.asarray(row, dtype=np.int64),
+                                        cfg.vocab_size))
+    return [d[: cfg.max_len] for d in docs]
+
+
+def segment_plan(n_tokens: int) -> List[Tuple[int, int]]:
+    """``(first token, bucket)`` of each segment of a document."""
+    top = SEGMENT_BUCKETS[-1]
+    plan, at = [], 0
+    while n_tokens - at > top:
+        plan.append((at, top))
+        at += top
+    rest = n_tokens - at
+    plan.append((at, next(b for b in SEGMENT_BUCKETS if b >= rest)))
+    return plan
+
+
+def _stage_document(ids: np.ndarray) -> Dict[str, Any]:
+    """Pad a document into its segments: ids, the NEXT token of every
+    position, and how many positions of the segment have one."""
+    n = len(ids)
+    segments = []
+    for at, bucket in segment_plan(n):
+        seg = np.zeros((1, bucket), np.int32)
+        nxt = np.zeros((1, bucket), np.int32)
+        here = ids[at:at + bucket]
+        seg[0, :len(here)] = here
+        targets = ids[at + 1:at + bucket + 1]
+        nxt[0, :len(targets)] = targets
+        segments.append((seg, nxt, len(targets), at))
+    return {"n_tokens": n, "segments": segments}
+
+
+def stage(payload: Any, ctx: Optional[object] = None):
+    """Host-only phase: validation, the shard read, the parse, padding into
+    segments. ``("done", soft result)`` or ``("staged", state)``."""
+    t0 = time.perf_counter()
+    if not isinstance(payload, dict):
+        return "done", bad_input("payload must be a dict")
+    from agent_tpu.ops._model_common import resolve_model_id
+
+    try:
+        cfg = _get_cfg(payload)
+        docs = _collect_documents(payload, cfg)
+    except ValueError as exc:
+        return "done", bad_input(str(exc))
+    return "staged", {
+        "t0": t0,
+        "docs": [_stage_document(d) for d in docs],
+        "n_rows": len(docs),
+        "cfg": cfg,
+        "model_id": resolve_model_id(payload, "TPU_LM_MODEL_PATH",
+                                     DEFAULT_MODEL_ID),
+        "t_staged": time.perf_counter(),
+    }
+
+
+def _build_params(runtime, model_id: str, cfg):
+    from agent_tpu.models import decoder_lm
+    from agent_tpu.ops._model_common import maybe_quantize_params
+
+    params = decoder_lm.init_params(cfg, model_id,
+                                    sharding=runtime.replicated())
+    return maybe_quantize_params(params, FAMILY, cfg)
+
+
+def _programs(runtime, cfg, bucket: int):
+    """(first-segment program, later-segment program, loss head) of one
+    segment bucket; jit wrappers from the runtime's keyed cache. The XLA
+    module names (``jit_lm_segment``, ``jit_lm_loss_head``) are what a
+    trace shows and what the benchmark's readers match."""
+    import jax
+
+    from agent_tpu.models import decoder_lm
+    from agent_tpu.ops._model_common import cfg_key
+
+    opts = {"pallas": bool(runtime.pallas), "interpret": False}
+
+    def build_segment(carried: bool):
+        def build():
+            if carried:
+                def lm_segment(p, ids, pos0, state):
+                    return decoder_lm.forward_segment(
+                        p, ids, pos0, state, cfg, **opts)
+
+                # The incoming state's buffers become the outgoing state's.
+                donate = (3,) if runtime.platform != "cpu" else ()
+                return jax.jit(lm_segment, donate_argnums=donate)
+
+            def lm_segment(p, ids, pos0):
+                return decoder_lm.forward_segment(p, ids, pos0, None, cfg,
+                                                  **opts)
+
+            return jax.jit(lm_segment)
+
+        return build
+
+    def build_head():
+        def lm_loss_head(hidden, head, targets, n_valid):
+            return decoder_lm.segment_block_sums(hidden, head, targets,
+                                                 n_valid)
+
+        return jax.jit(lm_loss_head)
+
+    key = (bucket, cfg_key(cfg))
+    return (
+        runtime.compiled((OP, "segment", False, *key), build_segment(False)),
+        runtime.compiled((OP, "segment", True, *key), build_segment(True)),
+        runtime.compiled((OP, "loss_head", *key), build_head),
+    )
+
+
+def _count_tokens(state: Dict[str, Any]) -> Tuple[int, int, int, int]:
+    """(segments, dispatched tokens, real tokens whose chunk read a carried
+    state, real tokens whose chunk was the quadratic form alone)."""
+    from agent_tpu.kernels.power_retention import retention_chunk
+
+    segments = dispatched = carried = alone = 0
+    for doc in state["docs"]:
+        first = doc["segments"][0]
+        quadratic = min(doc["n_tokens"], retention_chunk(first[0].shape[1]))
+        alone += quadratic
+        carried += doc["n_tokens"] - quadratic
+        segments += len(doc["segments"])
+        dispatched += sum(seg[0].shape[1] for seg in doc["segments"])
+    return segments, dispatched, carried, alone
+
+
+def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, Any]:
+    """Device phase (owning thread only): every segment of every document is
+    dispatched, the state going from program to program on the device; the
+    block sums stay unfetched for :func:`finalize`."""
+    import jax
+    import jax.numpy as jnp
+
+    from agent_tpu.kernels.power_retention import retention_chunk
+    from agent_tpu.ops._model_common import (
+        cfg_key,
+        decoder_lm_fwd_flops,
+        stamp_device_flops,
+    )
+
+    state["t_exec0"] = time.perf_counter()
+    cfg, model_id = state["cfg"], state["model_id"]
+    if ctx is not None and getattr(ctx, "require_runtime", None):
+        runtime = ctx.require_runtime()
+    else:
+        from agent_tpu.runtime.runtime import get_runtime
+
+        runtime = get_runtime()
+    params = runtime.get_params(
+        f"{model_id}#{FAMILY}#{hash(cfg_key(cfg)) & 0xFFFFFFFF:08x}",
+        lambda: _build_params(runtime, model_id, cfg),
+    )
+    put = lambda a: jax.device_put(a, runtime.replicated())  # noqa: E731
+    programs: Dict[int, Tuple] = {}     # bucket -> its three programs
+    parts, layout = [], []
+    for doc in state["docs"]:
+        carried = None
+        for ids, targets, n_valid, pos0 in doc["segments"]:
+            bucket = ids.shape[1]
+            if bucket not in programs:
+                programs[bucket] = _programs(runtime, cfg, bucket)
+            first, later, head = programs[bucket]
+            pos = put(np.int32(pos0))
+            if carried is None:
+                hidden, carried = first(params, put(ids), pos)
+            else:
+                hidden, carried = later(params, put(ids), pos, carried)
+            parts.append(head(hidden, params["head"], put(targets),
+                              put(np.int32(n_valid))))
+        layout.append((sum(s[0].shape[1] for s in doc["segments"]),
+                       doc["n_tokens"]))
+    segments, dispatched, with_state, alone = _count_tokens(state)
+    obs_trace.record_lm_segments(OP, segments)
+    obs_trace.record_retention_tokens("state", with_state)
+    obs_trace.record_retention_tokens("quadratic", alone)
+    stamp_device_flops(ctx, decoder_lm_fwd_flops(
+        dispatched, cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.n_heads,
+        cfg.n_kv_heads, cfg.d_head, cfg.vocab_size,
+        retention_chunk(SEGMENT_BUCKETS[-1])),
+        f"B1xS{max(s[0].shape[1] for d in state['docs'] for s in d['segments'])}")
+    state.update(
+        # One array a shard, gathered on the device by the owner thread:
+        # one fetch, not one a segment.
+        pending_dev=jnp.concatenate(parts) if len(parts) > 1 else parts[0],
+        layout=layout, device=runtime.platform,
+        t_device=time.perf_counter(),
+    )
+    return state
+
+
+def finalize(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, Any]:
+    """Host phase: the deferred fetch (a READ of device arrays: safe on the
+    poster thread) and the result's shape."""
+    from agent_tpu.models.decoder_lm import LOSS_BLOCK
+    from agent_tpu.ops._model_common import stamp_rows
+
+    t0 = state["t0"]
+    with obs_trace.phase("fetch") as fetched:
+        sums = np.asarray(state["pending_dev"], dtype=np.float64)
+    state["t_ready"] = fetched.t1
+    if ctx is not None and hasattr(ctx, "tags"):
+        ctx.tags.setdefault("timings", {}).update(
+            stage_ms=round((state["t_staged"] - t0) * 1000.0, 3),
+            queue_ms=round((state["t_exec0"] - state["t_staged"]) * 1000.0, 3),
+            device_ms=round((state["t_device"] - state["t_exec0"]) * 1000.0, 3),
+            fetch_ms=round(fetched.seconds * 1000.0, 3),
+        )
+    stamp_rows(ctx, state["n_rows"])
+    n_tokens, totals, blocks, at = [], [], [], 0
+    for padded, n in state["layout"]:
+        real = -(-max(0, n - 1) // LOSS_BLOCK)
+        mine = sums[at:at + real]
+        at += padded // LOSS_BLOCK
+        n_tokens.append(int(n))
+        blocks.append([float(x) for x in mine])
+        totals.append(float(mine.sum()))
+    out: Dict[str, Any] = {
+        "ok": True,
+        "op": OP,
+        "model_path": state["model_id"],
+        "device": state["device"],
+        "n_rows": state["n_rows"],
+        "elapsed_ms": (time.perf_counter() - t0) * 1000.0,
+    }
+    out["n_tokens"] = n_tokens
+    out["logprob_sum"] = totals
+    out["block_logprob_sums"] = blocks
+    return out
+
+
+@register_op(OP)
+def run(payload: Any, ctx: Optional[object] = None) -> Dict[str, Any]:
+    """Monolithic entry: stage → execute → finalize inline."""
+    phase, value = stage(payload, ctx)
+    if phase == "done":
+        return value
+    return finalize(execute(value, ctx), ctx)
+
+
+run.stage = stage
+run.execute = execute
+run.finalize = finalize
+# execute returns with the device still working; finalize stamps ``t_ready``.
+run.deferred = True

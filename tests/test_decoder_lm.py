@@ -1,0 +1,184 @@
+"""``models/decoder_lm.py`` at tiny widths on the CPU, against the
+benchmark's plain reference (``benchmarks/reference/retention_lm.py``: one
+reference, the file the chip check uses).
+
+Tolerances, and why. With ``dtype: float32`` the family computes what the
+reference computes in another order (chunks and a state against one score
+matrix; a blocked log-sum-exp against a whole one): float32 reordering,
+below 2e-5 nats a token. In bf16 (the stored and compute dtype of the
+published configuration) every matmul operand is rounded to 2^-9: about
+1e-2 nats a token at these widths; the bf16 test holds it under 5e-2, which
+int8 projections (``test_int8_control_is_further_off``) exceed threefold."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from agent_tpu.models import decoder_lm as lm
+from benchmarks.harness import manifest
+
+ref = manifest.load_reference("retention_lm")
+
+TINY = dict(vocab_size=1000, d_model=64, n_heads=10, n_kv_heads=2, d_head=16,
+            d_ff=96, n_layers=2)
+F32_TOL = 2e-5      # nats a token, float32 reordering
+BF16_TOL = 5e-2     # nats a token, bf16 operands at width 64
+
+
+def _cfg(**over):
+    return lm.DecoderLMConfig(**{**TINY, "dtype": "float32", **over})
+
+
+def _ref_cfg(cfg):
+    return {k: getattr(cfg, k) for k in (
+        "vocab_size", "d_model", "n_heads", "n_kv_heads", "d_head", "d_ff",
+        "n_layers", "rms_norm_eps", "rope_theta", "dtype")}
+
+
+def _score(cfg, params, doc, cuts=(), **opts):
+    """Per-token log-probabilities of ``doc`` run as segments cut at
+    ``cuts`` (none: one program)."""
+    state, out, at = None, [], 0
+    for cut in (*cuts, len(doc)):
+        ids = jnp.asarray(doc[None, at:cut])
+        hidden, state = lm.forward_segment(
+            params, ids, jnp.int32(at), state, cfg, pallas=False, **opts)
+        targets = jnp.asarray(doc[at + 1:cut + 1])
+        out.append(np.asarray(lm.blocked_logprobs(
+            hidden[0, :len(targets)], params["head"], targets)))
+        at = cut
+    return np.concatenate(out)
+
+
+@pytest.fixture(scope="module")
+def doc():
+    return np.random.default_rng(3).integers(0, 1000, 300).astype(np.int32)
+
+
+def test_weights_follow_the_published_rule_and_are_stored_in_the_dtype():
+    cfg = _cfg(dtype="bfloat16")
+    params = lm.init_params(cfg, "rule-check")
+    rc = _ref_cfg(cfg)
+    assert params["embed"].dtype == jnp.bfloat16
+    assert params["layers"]["w_up"].shape == (2, 64, 96)
+    for name in ("embed", "head"):
+        np.testing.assert_array_equal(
+            np.asarray(params[name], np.float32),
+            np.asarray(ref.draw(rc, "rule-check", name), np.float32))
+    for name in ("wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down"):
+        for i in range(2):
+            np.testing.assert_array_equal(
+                np.asarray(params["layers"][name][i], np.float32),
+                np.asarray(ref.draw(rc, "rule-check", name, layer=i),
+                           np.float32))
+    np.testing.assert_allclose(np.asarray(params["layers"]["bg"][0]),
+                               ref.gate_bias(2))
+    np.testing.assert_allclose(
+        1.0 / (1.0 + np.exp(-lm.gate_bias(8))), 1.0 - 1.0 / (16 * 2.0 ** np.arange(8)),
+        rtol=1e-6)
+    other = lm.init_params(cfg, "another-id")
+    assert not np.array_equal(np.asarray(other["head"], np.float32),
+                              np.asarray(params["head"], np.float32))
+
+
+def test_float32_forward_matches_the_reference(doc):
+    cfg = _cfg()
+    params = lm.init_params(cfg, "m-f32")
+    want = ref.token_logprobs(_ref_cfg(cfg), "m-f32", [doc])[0]
+    got = _score(cfg, params, doc)
+    assert np.abs(got - want).max() < F32_TOL
+
+
+@pytest.mark.parametrize("cuts, chunk", [((128,), 32), ((64, 200), 64),
+                                         ((100, 101, 250), 16)])
+def test_segments_with_the_state_handed_on_equal_one_program(doc, cuts, chunk):
+    cfg = _cfg()
+    params = lm.init_params(cfg, "m-seg")
+    whole = _score(cfg, params, doc, chunk=chunk)
+    parts = _score(cfg, params, doc, cuts=cuts, chunk=chunk)
+    assert np.abs(parts - whole).max() < F32_TOL
+    want = ref.token_logprobs(_ref_cfg(cfg), "m-seg", [doc])[0]
+    assert np.abs(parts - want).max() < F32_TOL
+
+
+def test_bf16_forward_is_near_the_reference_and_int8_control_is_further_off(doc):
+    cfg = _cfg(dtype="bfloat16")
+    params = lm.init_params(cfg, "m-bf16")
+    want = ref.token_logprobs(_ref_cfg(cfg), "m-bf16", [doc])[0]
+    sound = np.abs(_score(cfg, params, doc, cuts=(128,), chunk=64) - want)
+    assert sound.mean() < BF16_TOL / 3 and sound.max() < BF16_TOL * 4
+    from agent_tpu.models.quant import quantize_for_family
+
+    q = quantize_for_family("decoder_lm", lm.init_params(cfg, "m-bf16"), "int8")
+    assert q["layers"]["w_up"]["w_q"].dtype == jnp.int8
+    assert q["layers"]["w_up"]["w_scale"].shape == (2, 96)
+    assert q["embed"].dtype == jnp.bfloat16
+    control = np.abs(_score(cfg, q, doc, cuts=(128,), chunk=64) - want)
+    assert control.mean() > 2 * sound.mean(), (control.mean(), sound.mean())
+    w8 = quantize_for_family("decoder_lm", lm.init_params(cfg, "m-bf16"), "w8a16")
+    assert "w8" in w8["layers"]["wq"]
+    assert np.isfinite(_score(cfg, w8, doc)).all()
+
+
+@pytest.mark.parametrize("vocab, block", [(1000, 256), (1000, 1000),
+                                          (777, 128), (512, 4096)])
+def test_blocked_head_equals_a_full_log_softmax(vocab, block):
+    rng = np.random.default_rng(vocab + block)
+    h = jnp.asarray(rng.standard_normal((50, 32)), jnp.float32)
+    head = jnp.asarray(rng.standard_normal((vocab, 32)), jnp.float32)
+    targets = jnp.asarray(rng.integers(0, vocab, 50), jnp.int32)
+    want = jnp.take_along_axis(
+        jax.nn.log_softmax(h @ head.T, axis=-1), targets[:, None], 1)[:, 0]
+    got = lm.blocked_logprobs(h, head, targets, vocab_block=block)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_blocked_head_against_the_references_own_logits(doc):
+    """The reference's whole-vocabulary logits at a few positions, through a
+    plain log-softmax, are its folded log-probabilities and the model's."""
+    cfg = _cfg()
+    params = lm.init_params(cfg, "m-logits")
+    at = [0, 7, 150, 298]
+    z = ref.logits(_ref_cfg(cfg), "m-logits", doc, at)
+    assert z.shape == (4, cfg.vocab_size)
+    want = np.asarray(jax.nn.log_softmax(jnp.asarray(z), axis=-1))[
+        np.arange(4), doc[np.asarray(at) + 1]]
+    folded = ref.token_logprobs(_ref_cfg(cfg), "m-logits", [doc])[0][at]
+    np.testing.assert_allclose(folded, want, atol=F32_TOL)
+    np.testing.assert_allclose(_score(cfg, params, doc)[at], want, atol=F32_TOL)
+
+
+def test_segment_block_sums_mask_positions_without_a_target():
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.standard_normal((1, 2048, 16)), jnp.float32)
+    head = jnp.asarray(rng.standard_normal((64, 16)), jnp.float32)
+    t = jnp.asarray(rng.integers(0, 64, (1, 2048)), jnp.int32)
+    lp = np.asarray(lm.blocked_logprobs(h[0], head, t[0]))
+    got = np.asarray(lm.segment_block_sums(h, head, t, jnp.int32(1500)))
+    np.testing.assert_allclose(
+        got, [lp[:1024].sum(), lp[1024:1500].sum()], rtol=1e-5)
+
+
+@pytest.mark.parametrize("over, message", [
+    ({"mixer": "softmax"}, "mixer"), ({"n_kv_heads": 3}, "n_kv_heads"),
+    ({"d_head": 15}, "d_head"), ({"n_layers": 0}, "n_layers"),
+])
+def test_validate_rejects_what_no_program_can_run(over, message):
+    with pytest.raises(ValueError, match=message):
+        lm.validate(_cfg(**over))
+    lm.validate(_cfg())
+
+
+def test_rope_rotates_pairs_and_keeps_norms():
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((5, 2, 16)),
+                    jnp.float32)
+    pos = jnp.arange(5) + 7
+    got = lm.rope(x, pos, 1e6)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(
+        ref.rope(x, pos, 1e6)), atol=1e-6)
+    np.testing.assert_allclose(np.linalg.norm(np.asarray(got), axis=-1),
+                               np.linalg.norm(np.asarray(x), axis=-1),
+                               rtol=1e-5)

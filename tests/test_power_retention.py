@@ -1,0 +1,225 @@
+"""``kernels/power_retention.py`` against the benchmark's plain reference
+(``benchmarks/reference/retention_lm.py``, the ATTENTION form: one reference,
+the file the chip check uses), on the CPU: the Pallas kernel in interpret
+mode at the lane width, the ``jax.numpy`` chunked form at small head sizes.
+
+Tolerances, and why. Inputs are float32-exact bf16 values. The jnp chunked
+form is float32 throughout: it differs from the attention form only by the
+order of float32 sums (1e-4 of the output's scale). The kernel rounds its MXU
+operands to bf16 (weights p, the expansion, the state copy): about 2^-9
+relative an operand, averaged over many terms, and the output itself is bf16: 2e-2 of scale + |value| at
+worst, typically 3e-3 rms. A STATE KEPT IN bf16 loses every update below
+2^-8 of what it already holds: at gates near 1 that is off by more than
+either tolerance, and ``test_bf16_state_fails`` holds the tolerances to it.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from agent_tpu.kernels import power_retention as pr
+from benchmarks.harness import manifest
+
+ref = manifest.load_reference("retention_lm")
+
+
+def _inputs(seed, L, hq, hkv, d, gate=(2.0, 6.0), B=1):
+    """bf16-exact q, k (unit rms, as after the per-head norm), v and a log
+    gate whose pre-activation is uniform in ``gate``."""
+    rng = np.random.default_rng(seed)
+    as_bf16 = lambda a: jnp.asarray(a, jnp.bfloat16)  # noqa: E731
+    q = as_bf16(rng.standard_normal((B, L, hq * d)))
+    k = as_bf16(rng.standard_normal((B, L, hkv * d)))
+    v = as_bf16(rng.standard_normal((B, L, hkv * d)))
+    log_g = jax.nn.log_sigmoid(jnp.asarray(
+        rng.uniform(gate[0], gate[1], (B, L, hkv)), jnp.float32))
+    return q, k, v, log_g
+
+
+def _reference(q, k, v, log_g, hq, hkv, d):
+    out = []
+    with jax.default_matmul_precision("highest"):
+        for b in range(q.shape[0]):
+            L = q.shape[1]
+            out.append(ref.retention_attention(
+                q[b].astype(jnp.float32).reshape(L, hq, d),
+                k[b].astype(jnp.float32).reshape(L, hkv, d),
+                v[b].astype(jnp.float32).reshape(L, hkv, d),
+                log_g[b]).reshape(L, hq * d))
+    return np.asarray(jnp.stack(out))
+
+
+def _err(y, want):
+    """(largest gap, rms gap) as shares of the output's scale; the largest
+    is taken against scale + |value|, since a bf16 output is off by 2^-9 of
+    its own size and the first tokens' values are many times the rms."""
+    scale = float(np.sqrt(np.mean(want ** 2)))
+    gap = np.asarray(y, np.float32) - want
+    return (float((np.abs(gap) / (scale + np.abs(want))).max()),
+            float(np.sqrt(np.mean(gap ** 2))) / scale)
+
+
+JNP_TOL = (1e-4, 2e-5)        # max, rms; float32 reordering only
+KERNEL_TOL = (2e-2, 4e-3)     # bf16 MXU operands and a bf16 output
+
+
+@pytest.mark.parametrize("L,chunk", [(64, 16), (96, 32), (100, 32), (33, 8),
+                                     (64, 64), (40, None)])
+def test_jnp_chunked_form_matches_attention_form(L, chunk):
+    hq, hkv, d = 10, 2, 16          # 5 query heads a key-value head
+    q, k, v, g = _inputs(L, L, hq, hkv, d)
+    y, _ = pr.power_retention(q.astype(jnp.float32), k.astype(jnp.float32),
+                              v.astype(jnp.float32), g, n_kv_heads=hkv,
+                              chunk=chunk, pallas=False)
+    mx, rms = _err(y, _reference(q, k, v, g, hq, hkv, d))
+    assert mx < JNP_TOL[0] and rms < JNP_TOL[1], (mx, rms)
+
+
+@pytest.mark.parametrize("gate", [(-6.0, -3.0), (-1.0, 1.0), (6.0, 12.0)],
+                         ids=["gates_near_0", "gates_mid", "gates_near_1"])
+def test_jnp_form_over_gate_ranges(gate):
+    hq, hkv, d = 5, 1, 16
+    q, k, v, g = _inputs(7, 96, hq, hkv, d, gate=gate)
+    y, _ = pr.power_retention(q.astype(jnp.float32), k.astype(jnp.float32),
+                              v.astype(jnp.float32), g, n_kv_heads=hkv,
+                              chunk=32, pallas=False)
+    mx, rms = _err(y, _reference(q, k, v, g, hq, hkv, d))
+    assert mx < JNP_TOL[0] and rms < JNP_TOL[1], (mx, rms)
+
+
+@pytest.mark.parametrize("L,chunk,hq,hkv", [
+    (256, 128, 5, 1),       # two chunks, 5:1 grouping
+    (384, 128, 4, 2),       # three chunks, two key-value heads
+    (200, 128, 5, 1),       # not a multiple of the chunk: padded inside
+    (128, 128, 2, 1),       # one chunk: the quadratic form alone
+])
+def test_kernel_interpret_matches_attention_form(L, chunk, hq, hkv):
+    d = 128
+    q, k, v, g = _inputs(L + hq, L, hq, hkv, d)
+    y, _ = pr.power_retention(q, k, v, g, n_kv_heads=hkv, chunk=chunk,
+                              pallas=True, interpret=True)
+    mx, rms = _err(y, _reference(q, k, v, g, hq, hkv, d))
+    assert mx < KERNEL_TOL[0] and rms < KERNEL_TOL[1], (mx, rms)
+
+
+@pytest.mark.parametrize("gate", [(-6.0, -3.0), (6.0, 12.0)],
+                         ids=["gates_near_0", "gates_near_1"])
+def test_kernel_interpret_over_gate_ranges(gate):
+    hq, hkv, d = 5, 1, 128
+    q, k, v, g = _inputs(3, 256, hq, hkv, d, gate=gate)
+    y, _ = pr.power_retention(q, k, v, g, n_kv_heads=hkv, chunk=128,
+                              pallas=True, interpret=True)
+    mx, rms = _err(y, _reference(q, k, v, g, hq, hkv, d))
+    assert mx < KERNEL_TOL[0] and rms < KERNEL_TOL[1], (mx, rms)
+
+
+@pytest.mark.parametrize("pallas,d,cuts", [
+    (False, 16, (40, 72)), (False, 16, (32, 64)), (True, 128, (128,)),
+])
+def test_segments_with_state_handed_on_equal_whole_document(pallas, d, cuts):
+    """A document as one call against the same document as segments, the
+    final state of one the initial state of the next."""
+    hq, hkv = 5, 1
+    L = 256 if pallas else 96
+    chunk = 128 if pallas else 8
+    q, k, v, g = _inputs(11, L, hq, hkv, d)
+    opts = dict(n_kv_heads=hkv, chunk=chunk, pallas=pallas,
+                interpret=True if pallas else None)
+    if not pallas:
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    whole, final = pr.power_retention(q, k, v, g, **opts)
+    parts, state, at = [], None, 0
+    for cut in (*cuts, L):
+        y, state = pr.power_retention(
+            q[:, at:cut], k[:, at:cut], v[:, at:cut], g[:, at:cut],
+            initial_state=state, **opts)
+        parts.append(y)
+        at = cut
+    got = jnp.concatenate(parts, axis=1)
+    tol = KERNEL_TOL if pallas else JNP_TOL
+    mx, rms = _err(got, np.asarray(whole, np.float32))
+    assert mx < tol[0] and rms < tol[1], (mx, rms)
+    for a, b in zip(state, final):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-2 if pallas else 1e-4,
+                                   atol=1e-2 if pallas else 1e-4)
+    mx, rms = _err(got, _reference(q, k, v, g, hq, hkv, d))
+    assert mx < tol[0] and rms < tol[1], (mx, rms)
+
+
+def test_recurrent_one_token_form_matches_attention_form():
+    """The recurrence S_t = g_t S_{t-1} + phi(k_t) v_t^T, one token at a
+    time: a second statement of the equations."""
+    hq, hkv, d, L = 10, 2, 16, 48
+    q, k, v, g = _inputs(5, L, hq, hkv, d)
+    state = pr.zero_state(1, hkv, d)
+    ys = []
+    for t in range(L):
+        y, state = pr.retention_step(
+            q[:, t].reshape(1, hkv, hq // hkv, d), k[:, t].reshape(1, hkv, d),
+            v[:, t].reshape(1, hkv, d), g[:, t], state)
+        ys.append(y.reshape(1, hq * d))
+    mx, rms = _err(jnp.stack(ys, axis=1), _reference(q, k, v, g, hq, hkv, d))
+    assert mx < JNP_TOL[0] and rms < JNP_TOL[1], (mx, rms)
+
+
+def test_expansion_is_the_squared_dot_product():
+    rng = np.random.default_rng(0)
+    for d in (8, 16, 128):
+        a, b = rng.standard_normal((2, d)).astype(np.float32)
+        got = float((pr._phi(jnp.asarray(a), False)
+                     * pr._phi(jnp.asarray(b), True)).sum())
+        assert got == pytest.approx(float(a @ b) ** 2, rel=1e-4)
+        assert pr._phi(jnp.asarray(a), False).shape == (pr.state_rows(d), d)
+
+
+def test_bf16_state_fails(monkeypatch):
+    """The tolerances are tight enough that a state kept in bf16 between
+    chunks fails them (gates near 1, many chunks)."""
+    hq, hkv, d, L = 5, 1, 16, 1024
+    q, k, v, g = _inputs(9, L, hq, hkv, d, gate=(8.0, 10.0))
+    step = pr._chunk_step
+
+    def rounding_step(carry, xs, **kw):
+        (S, Z), y = step(carry, xs, **kw)
+        lossy = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+        return (lossy(S), lossy(Z)), y
+
+    want = _reference(q, k, v, g, hq, hkv, d)
+    f32 = jnp.float32
+    args = (q.astype(f32), k.astype(f32), v.astype(f32), g)
+    sound, _ = pr.power_retention(*args, n_kv_heads=hkv, chunk=4, pallas=False)
+    monkeypatch.setattr(pr, "_chunk_step", rounding_step)
+    lossy, _ = pr.power_retention(*args, n_kv_heads=hkv, chunk=4, pallas=False)
+    mx, rms = _err(sound, want)
+    assert mx < JNP_TOL[0] and rms < JNP_TOL[1]
+    mx, rms = _err(lossy, want)
+    assert mx > KERNEL_TOL[0] or rms > KERNEL_TOL[1], (mx, rms)
+
+
+@pytest.mark.parametrize("seq_len,carried,chunk,want", [
+    (1024, False, None, False), (1025, False, None, True),
+    (4096, False, None, True), (128, True, None, True),
+    (16384, False, None, True), (64, False, 32, True), (32, False, 32, False),
+])
+def test_state_path_is_chosen_from_shapes(seq_len, carried, chunk, want):
+    assert pr.selects_state_path(seq_len, carried, chunk) is want
+
+
+def test_traced_blocks_are_counted_by_path():
+    from agent_tpu.obs.metrics import get_registry
+
+    def count(path):
+        snap = get_registry().snapshot().get("retention_blocks_traced_total")
+        return sum(s["value"] for s in (snap or {}).get("series", [])
+                   if s["labels"].get("path") == path)
+
+    before = count("state"), count("quadratic")
+    q, k, v, g = _inputs(1, 32, 2, 1, 16)
+    pr.power_retention(q, k, v, g, n_kv_heads=1, chunk=16, pallas=False)
+    pr.power_retention(q, k, v, g, n_kv_heads=1, chunk=32, pallas=False)
+    assert count("state") == before[0] + 1
+    assert count("quadratic") == before[1] + 1

@@ -137,6 +137,36 @@ def test_kernel_compiles_for_v5e(v5e, build, L, D, n_kernels):
         assert fa.SELECTION_COUNTS.get("dense", 0) == before.get("dense", 0)
 
 
+@pytest.mark.parametrize("carried", [False, True],
+                         ids=["first_segment", "later_segment"])
+def test_power_retention_compiles_for_v5e(v5e, carried):
+    """The retention kernel at the ``brumby-14b-base.score-long`` cell's own
+    shape: one 4,096-token segment, 40 query and 8 key-value heads of 128,
+    chunks of 1,024, with and without a state coming in. The state block,
+    its bf16 copy, the c x c score temporaries and the expansion buffer must
+    fit the VMEM the kernel asks for."""
+    from agent_tpu.kernels import power_retention as pr
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    L = 4096
+    args = [sd((1, L, 40 * 128), jnp.bfloat16), sd((1, L, 8 * 128), jnp.bfloat16),
+            sd((1, L, 8 * 128), jnp.bfloat16), sd((1, L, 8), jnp.float32)]
+    state = (sd((1, 8, 65, 128, 128), jnp.float32),
+             sd((1, 8, 128, 128), jnp.float32))
+    assert pr.retention_chunk(L) == 1024 and pr.selects_state_path(L, carried)
+    if carried:
+        fn = lambda q, k, v, g, st: pr.power_retention(  # noqa: E731
+            q, k, v, g, n_kv_heads=8, initial_state=st, pallas=True,
+            interpret=False)
+        args.append(state)
+    else:
+        fn = lambda q, k, v, g: pr.power_retention(  # noqa: E731
+            q, k, v, g, n_kv_heads=8, pallas=True, interpret=False)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
 @pytest.mark.parametrize("shape,want", [
     # One axis over every chip: the physical ring 0→1→3→2, not list order
     # (1→2 and 3→0 are diagonals of the 2x2).
