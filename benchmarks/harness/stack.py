@@ -217,13 +217,16 @@ class Tracer:
 
 def print_result(correct: bool, attempted: int, failed: int,
                  metrics: Dict[str, Dict[str, Any]], device: Dict[str, Any],
-                 breakdown: Optional[Dict[str, Any]] = None) -> None:
-    """The contract's result object, as the LAST line of stdout."""
+                 breakdown: Optional[Dict[str, Any]] = None,
+                 compared: Optional[Dict[str, Any]] = None) -> None:
+    """The contract's result object, as the LAST line of stdout; the numbers
+    compared, each with its limit, are its last key."""
     line: Dict[str, Any] = {
         "correct": bool(correct), "attempted": int(attempted),
         "failed": int(failed), "metrics": metrics, "device": device,
     }
     if breakdown:
         line["breakdown"] = breakdown
+    line["compared"] = compared or {}
     sys.stdout.flush()
     print(json.dumps(line), flush=True)

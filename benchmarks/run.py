@@ -62,6 +62,11 @@ def run_cell(manifest: Dict[str, Any], workload: str, seed: int,
     run = mf.load_kind(traffic["kind"]).run_cell(ctx)
     run["peaks"] = (peaks.lookup(run["device"]["kind"])
                     if run["device"]["platform"] == "tpu" else None)
+    # What the per-layer readers of set-up read in this run, traced or not:
+    # the parts of ``setup_s`` are told apart from the runs that time it.
+    stack.emit("setup_layers", **{
+        entry["name"]: module.read(run) for entry, module in readers
+        if entry["moves"] == "setup_s"})
     metrics: Dict[str, Dict[str, Any]] = {}
     if trace:
         for entry, module in readers:
@@ -102,16 +107,22 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         stack.emit("failed", error=f"{type(exc).__name__}: {exc}"[:2000],
                    correct=False)
         return 1
-    for c in run["checks"]:
-        stack.emit("compared", number=c["number"], value=c["value"],
-                   limit=c["limit"], ok=c["ok"])
     breakdown = None
     if args.trace and run.get("trace"):
         breakdown = {"device_ops": run["trace"]["device_ops"],
                      "idle_gaps": run["trace"]["idle_gaps"]}
+    # Every number compared beside its limit: the last lines of stderr, and
+    # the last key of the result line.
+    for c in run["checks"]:
+        stack.emit("compared", number=c["number"], value=c["value"],
+                   limit=c["limit"], ok=c["ok"])
+        print(f"compared {c['number']} {c['value']!r} limit {c['limit']!r} "
+              f"{'ok' if c['ok'] else 'NOT ok'}", file=sys.stderr, flush=True)
     stack.print_result(run["correct"], run["attempted"], run["failed"],
                        run["metrics"], device_block(run, args.trace),
-                       breakdown)
+                       breakdown, compared={
+                           c["number"]: {"value": c["value"], "limit": c["limit"]}
+                           for c in run["checks"]})
     return 0
 
 

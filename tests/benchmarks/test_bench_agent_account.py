@@ -120,10 +120,11 @@ def test_parent_commits_counters_are_not_read_under_the_new_names():
 
 
 def test_every_new_metric_is_in_the_manifest_for_both_drain_cells():
+    """Contains both: a later cell that the readers can read lists itself."""
     entries = {m["name"]: m for m in manifest.load_manifest()["per_layer"]}
     for name in READERS:
-        assert entries[name]["workloads"] == [
-            "bert-base.drain-long", "bert-base.drain-short"], name
+        assert {"bert-base.drain-long", "bert-base.drain-short"} <= set(
+            entries[name]["workloads"]), name
     assert entries["xla_compile_s.setup"]["moves"] == "setup_s"
     assert entries["params_s.setup"]["moves"] == "setup_s"
 
