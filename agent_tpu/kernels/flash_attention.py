@@ -3,10 +3,11 @@
 Two schedules of one algorithm, chosen by shape and mask alone
 (:func:`selects_flash`, :func:`selects_whole_row`): from
 ``FLASH_MIN_KEY_LEN`` keys on, the STREAMING kernel described next; below
-it, for whole-sequence attention under a key-padding mask, the WHOLE-ROW
-kernel (:func:`whole_row_attention`), whose score row fits VMEM and whose
-operands are the projections' own lane-dense [B, L, H*D]; dense XLA
-everywhere else.
+it, for whole-sequence attention under a key-padding mask or under segment
+ids (several short rows packed end to end in one program row, attention
+block-diagonal), the WHOLE-ROW kernel (:func:`whole_row_attention`), whose
+score row fits VMEM and whose operands are the projections' own lane-dense
+[B, L, H*D]; dense XLA everywhere else.
 
 Streaming: one grid program computes one [block_q, d_head] query tile for
 one (batch, head). The innermost grid axis walks K/V tiles sequentially (TPU
@@ -308,13 +309,19 @@ _WHOLE_ROW_STEP_TOKENS = 512
 
 
 def selects_whole_row(lq: int, lk: int, n_heads: int, d_head: int, *,
-                      key_padding: bool, dtype) -> bool:
+                      key_padding: bool, dtype, segments: bool = False) -> bool:
     """Shape-and-mask predicate: does whole-sequence attention of this kind
     take the whole-row kernel? The one place the choice is made; callers
     (``layers.attention``, ``bert.forward``) hand over the lane-dense layout
-    exactly when it says yes, and every other call keeps [B, H, L, D]."""
+    exactly when it says yes, and every other call keeps [B, H, L, D].
+
+    Two masks qualify: a key-padding mask (``key_padding``) and the SEGMENT
+    form (``segments``: a [B, L] array of segment ids, 0 = pad, under which
+    a query attends the keys of its own segment and no other: several short
+    rows packed end to end in one program row). The shapes it takes are the
+    same for both."""
     return bool(
-        key_padding
+        (key_padding or segments)
         and lq == lk
         and WHOLE_ROW_MIN_KEY_LEN <= lk <= WHOLE_ROW_MAX_KEY_LEN
         and lk < FLASH_MIN_KEY_LEN
@@ -341,8 +348,13 @@ def _whole_row_tiles(batch: int, length: int, n_groups: int):
     return rows, groups
 
 
-def _whole_row_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *, scale: float,
-                      d_head: int, rows: int, groups: int):
+def _whole_row_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale: float,
+                      d_head: int, rows: int, groups: int, segments: bool):
+    # Key-padding form: ``mask_ref`` [rows, 1, L] (1 = attend), rest =
+    # (o_ref,). Segment form: ``mask_ref`` holds the KEYS' segment ids
+    # [rows, 1, L] and rest = (the QUERIES' ids [rows, L, 1], o_ref): the
+    # same ids twice, each laid out the way its broadcast wants it.
+    o_ref = rest[-1]
     heads_per_group = _LANES // d_head
     # A power-of-two scale (d_head 64: 1/8) multiplies into Q exactly in
     # bf16, on [L, 128] instead of [L, L]; any other scales the f32 scores.
@@ -350,10 +362,17 @@ def _whole_row_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *, scale: float,
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
 
     def one_row(r):
-        keep = mask_ref[r] > 0                                # [1, L]
-        # A row with no key at all: exp(0) = 1 everywhere below, so the row
+        # A query with no key at all: exp(0) = 1 everywhere below, so it
         # would come out as V's mean; it is 0, as the streaming kernel has it.
-        any_key = jnp.max(keep.astype(jnp.float32), axis=-1, keepdims=True)
+        if segments:
+            seg_k, seg_q = mask_ref[r], rest[0][r]            # [1, L], [L, 1]
+            keep = (seg_q == seg_k) & (seg_k > 0)             # [L, L]
+            # A real query is its own key; a pad slot has none.
+            any_key = (seg_q > 0).astype(jnp.float32)         # [L, 1]
+        else:
+            keep = mask_ref[r] > 0                            # [1, L]
+            any_key = jnp.max(keep.astype(jnp.float32), axis=-1,
+                              keepdims=True)
         for g in range(groups):
             lanes = slice(g * _LANES, (g + 1) * _LANES)
             q_g = q_ref[r, :, lanes]                          # [L, 128]
@@ -396,8 +415,8 @@ def _whole_row_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *, scale: float,
 
 @functools.partial(
     jax.jit, static_argnames=("n_heads", "rows", "groups", "interpret"))
-def _whole_row_call(q, k, v, mask3d, *, n_heads: int, rows: int, groups: int,
-                    interpret: bool):
+def _whole_row_call(q, k, v, mask3d, seg_q=None, *, n_heads: int, rows: int,
+                    groups: int, interpret: bool):
     """The ``pallas_call``, under a jit of its own: an encoder calls it once
     a block and an agent builds one program a tenant, and tracing and
     lowering the kernel's body is seconds of Python each time. A jitted
@@ -409,16 +428,19 @@ def _whole_row_call(q, k, v, mask3d, *, n_heads: int, rows: int, groups: int,
                          memory_space=pltpu.VMEM)
     kernel = functools.partial(
         _whole_row_kernel, scale=1.0 / float(np.sqrt(d_head)), d_head=d_head,
-        rows=rows, groups=groups,
+        rows=rows, groups=groups, segments=seg_q is not None,
     )
+    masks, mask_specs = [mask3d], [
+        pl.BlockSpec((rows, 1, L), lambda b, g: (b, 0, 0),
+                     memory_space=pltpu.VMEM)]
+    if seg_q is not None:
+        masks.append(seg_q)
+        mask_specs.append(pl.BlockSpec((rows, L, 1), lambda b, g: (b, 0, 0),
+                                       memory_space=pltpu.VMEM))
     return pl.pallas_call(
         kernel,
         grid=(B // rows, HD // (groups * _LANES)),
-        in_specs=[
-            block, block, block,
-            pl.BlockSpec((rows, 1, L), lambda b, g: (b, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=[block, block, block, *mask_specs],
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -431,16 +453,17 @@ def _whole_row_call(q, k, v, mask3d, *, n_heads: int, rows: int, groups: int,
             transcendentals=B * n_heads * L * L,
         ),
         interpret=interpret,
-    )(q, k, v, mask3d)
+    )(q, k, v, *masks)
 
 
 def whole_row_attention(
     q: jax.Array,      # [B, L, H*D]
     k: jax.Array,      # [B, L, H*D]
     v: jax.Array,      # [B, L, H*D]
-    mask: jax.Array,   # [B|1, 1, 1, L] key-padding mask (1 = attend)
+    mask: Optional[jax.Array],   # [B|1, 1, 1, L] key-padding mask (1 = attend)
     *,
     n_heads: int,
+    segment_ids: Optional[jax.Array] = None,   # [B, L] int32, 0 = pad
     rows_per_step: Optional[int] = None,
     groups_per_step: Optional[int] = None,
     interpret: Optional[bool] = None,
@@ -450,14 +473,29 @@ def whole_row_attention(
     For the calls :func:`selects_whole_row` accepts, and only those: the
     caller asks it first. f32 scores and statistics, bf16 into the PV matmul,
     every key of every real row attended, 0 (not NaN) for a row with no key.
+
+    With ``segment_ids`` (the packed layout of ``ops/_model_common.py:
+    pack_padded_chunk``: several short rows end to end in one program row)
+    ``mask`` is not read and attention is block-diagonal: a query attends
+    the keys that carry its own id and no other, a pad slot (id 0) attends
+    nothing, is attended by nothing and comes out 0. The ids go in twice,
+    as [B, 1, L] for the keys and [B, L, 1] for the queries, so the [L, L]
+    compare is two broadcasts on the VPU and no transpose.
+
     ``rows_per_step`` / ``groups_per_step`` override the tile geometry the
     shapes give (:func:`_whole_row_tiles`) — for sweeps on the chip."""
     B, L, HD = q.shape
     rows, groups = _whole_row_tiles(B, L, HD // _LANES)
     _note_selection("whole_row")
-    mask3d = jnp.broadcast_to(mask[:, 0, :, :], (B, 1, L)).astype(jnp.int32)
+    if segment_ids is not None:
+        seg = segment_ids.astype(jnp.int32)
+        mask3d, seg_q = seg[:, None, :], seg[:, :, None]
+    else:
+        mask3d = jnp.broadcast_to(
+            mask[:, 0, :, :], (B, 1, L)).astype(jnp.int32)
+        seg_q = None
     return _whole_row_call(
-        q, k, v, mask3d, n_heads=n_heads, rows=rows_per_step or rows,
+        q, k, v, mask3d, seg_q, n_heads=n_heads, rows=rows_per_step or rows,
         groups=groups_per_step or groups,
         interpret=resolve_interpret(interpret),
     )
@@ -1206,22 +1244,31 @@ class WholeRowAttention:
             )
 
     def selects(self, batch: int, lq: int, lk: int, n_heads: int,
-                d_head: int, mask, dtype) -> bool:
+                d_head: int, mask, dtype, segments: bool = False) -> bool:
         from agent_tpu.models.layers import is_key_padding_mask
 
         return _wrapper_shardable(batch, n_heads, self.dp, self.tp) and (
             selects_whole_row(
                 lq, lk, n_heads // self.tp, d_head,
-                key_padding=is_key_padding_mask(mask, batch, lk), dtype=dtype,
+                key_padding=(not segments
+                             and is_key_padding_mask(mask, batch, lk)),
+                dtype=dtype, segments=segments,
             )
         )
 
-    def __call__(self, q, k, v, mask, *, n_heads: int):
+    def __call__(self, q, k, v, mask, *, n_heads: int, segment_ids=None):
         if self._shard is None:
-            return self._kernel(q, k, v, mask, n_heads=n_heads)
+            return self._kernel(q, k, v, mask, n_heads=n_heads,
+                                segment_ids=segment_ids)
         from agent_tpu.models.layers import materialize_key_padding_mask
 
         B, L, _ = q.shape
+        if segment_ids is not None:
+            # The ids ride in the mask's place and its spec: [B, 1, 1, L].
+            inner = lambda q, k, v, seg: self._kernel(  # noqa: E731
+                q, k, v, None, n_heads=n_heads // self.tp,
+                segment_ids=seg[:, 0, 0, :])
+            return self._shard(inner)(q, k, v, segment_ids[:, None, None, :])
         inner = functools.partial(self._kernel, n_heads=n_heads // self.tp)
         return self._shard(inner)(
             q, k, v, materialize_key_padding_mask(mask, B, L)

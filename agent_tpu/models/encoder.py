@@ -116,15 +116,104 @@ def load_npz(path: str, cfg: EncoderConfig) -> Params:
     return layers.assign_from_npz(init_params(cfg, model_id=path), path)
 
 
+def segment_layout(segment_lengths: jax.Array, length: int):
+    """The packed layout's slot arrays from its wire form. ``segment_lengths``
+    [P, G] int32: the token counts of the rows laid end to end in each
+    program row, in order (0 = no such segment, or a row with no token).
+    Returns ``(segment_ids [P, L] int32, positions [P, L] int32)``: a slot's
+    id is 1 + the index of the segment it falls in and 0 past the last real
+    token; its position counts from the start of its own segment."""
+    ends = jnp.cumsum(segment_lengths.astype(jnp.int32), axis=1)   # [P, G]
+    slot = jnp.arange(length, dtype=jnp.int32)[None, :]            # [1, L]
+    index = (slot[:, :, None] >= ends[:, None, :]).sum(-1)         # [P, L]
+    real = slot < ends[:, -1:]
+    starts = jnp.concatenate(
+        [jnp.zeros_like(ends[:, :1]), ends[:, :-1]], axis=1)
+    start = jnp.take_along_axis(
+        starts, jnp.minimum(index, ends.shape[1] - 1), axis=1)
+    segment_ids = jnp.where(real, index + 1, 0).astype(jnp.int32)
+    positions = jnp.where(real, slot - start, 0).astype(jnp.int32)
+    return segment_ids, positions
+
+
+def _run_blocks(params, x, attn_mask, cfg, attn_fn, remat, mesh,
+                segment_ids=None):
+    """The block stack and the final LayerNorm → (x [B, L, d], summed aux)."""
+    dtype = cfg.compute_dtype
+    moe_ctx = None
+    if cfg.moe_experts > 0:
+        moe_ctx = (
+            moe_cfg_of(cfg),
+            mesh if mesh is not None and "ep" in mesh.shape else None,
+        )
+    block_fn = lambda p, h, m, s: layers.encoder_block(  # noqa: E731
+        p, h, m, dtype, attn_fn=attn_fn, moe_ctx=moe_ctx, with_aux=True,
+        segment_ids=s,
+    )
+    if remat:
+        # Full-block recompute (minimum memory). Selective policies were
+        # swept on v5e at BERT-base/seq-512 and lost: dots-saveable OOMs at
+        # batch 256 and ties full remat at 128 (247 vs 246 ex/s); with the
+        # flash-train kernel the winner is no remat at all (bench `train`).
+        block_fn = jax.checkpoint(block_fn)
+    aux_total = jnp.float32(0.0)
+    for block in params["blocks"]:
+        x, aux = block_fn(block, x, attn_mask, segment_ids)
+        aux_total = aux_total + aux
+    return layers.layer_norm(params["ln_f"], x), aux_total
+
+
+def classify_head(params: Params, pooled: jax.Array, cfg: EncoderConfig):
+    """Pooled rows [R, d_model] (f32) → logits [R, n_classes] (f32)."""
+    dtype = cfg.compute_dtype
+    logits = layers.dense(params["head"], pooled.astype(dtype), dtype)
+    return logits.astype(jnp.float32)
+
+
+def pooled_segments(
+    params: Params,
+    ids: jax.Array,               # [P, L] int32: rows packed end to end
+    segment_lengths: jax.Array,   # [P, G] int32 (:func:`segment_layout`)
+    cfg: EncoderConfig,
+    attn_fn=layers.dot_product_attention,
+    remat: bool = False,
+    mesh=None,
+) -> jax.Array:
+    """The encoder on PACKED rows → the mean of every segment's real tokens,
+    [P, G, d_model] f32 (0 for a segment with no token): what
+    :func:`forward` computes for a row alone in its program row, for each of
+    the rows that share one. Positions restart at every segment, attention is
+    block-diagonal (``layers.segment_mask_to_attn``), pad slots take part in
+    nothing."""
+    dtype = cfg.compute_dtype
+    L, G = ids.shape[1], segment_lengths.shape[1]
+    segment_ids, positions = segment_layout(segment_lengths, L)
+    x = (params["embed"].astype(dtype)[ids]
+         + params["pos"][:L].astype(dtype)[positions])
+    x, _ = _run_blocks(
+        params, x, layers.segment_mask_to_attn(segment_ids), cfg, attn_fn,
+        remat, mesh, segment_ids=segment_ids)
+    member = (segment_ids[:, None, :]
+              == jnp.arange(1, G + 1, dtype=jnp.int32)[None, :, None])
+    # 0/1 weights: at full precision the sums are the tokens' own f32 sums.
+    sums = jnp.einsum("pgl,pld->pgd", member.astype(jnp.float32),
+                      x.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+    denom = jnp.maximum(segment_lengths, 1).astype(jnp.float32)
+    return sums / denom[:, :, None]
+
+
 def forward(
     params: Params,
     ids: jax.Array,      # [B, L] int32 token ids
-    mask: jax.Array,     # [B, L] int32 padding mask (1 = real)
+    mask: Optional[jax.Array],   # [B, L] int32 padding mask (1 = real)
     cfg: EncoderConfig,
     attn_fn=layers.dot_product_attention,
     remat: bool = False,
     mesh=None,
     with_aux: bool = False,
+    segment_lengths: Optional[jax.Array] = None,
+    row_slots: Optional[jax.Array] = None,
 ):
     """Logits [B, n_classes] (f32). Mean-pool over real tokens, linear head.
 
@@ -142,35 +231,33 @@ def forward(
     their aux through the (possibly checkpointed) block_fn, never via
     side-channel closures: a Python-list accumulator would leak tracers
     out of ``jax.checkpoint``'s inner trace.
+
+    The SEGMENT form — ``segment_lengths`` [P, G] given, ``mask`` not read:
+    ``ids`` [P, L] holds several rows end to end in each program row
+    (:func:`pooled_segments`). Logits come back one row a segment slot,
+    [P * G, n_classes], or, with ``row_slots`` [R] (the flat slot
+    ``program row * G + segment`` of each caller's row), gathered into the
+    caller's row order BEFORE the head: [R, n_classes]. Without
+    ``segment_lengths`` the traced program is what it was before the form
+    existed.
     """
+    if segment_lengths is not None:
+        if with_aux:
+            raise ValueError("the segment form returns no auxiliary loss")
+        pooled = pooled_segments(
+            params, ids, segment_lengths, cfg, attn_fn=attn_fn, remat=remat,
+            mesh=mesh).reshape(-1, cfg.d_model)
+        if row_slots is not None:
+            pooled = pooled[row_slots]
+        return classify_head(params, pooled, cfg)
     dtype = cfg.compute_dtype
     L = ids.shape[1]
     x = params["embed"].astype(dtype)[ids] + params["pos"][:L].astype(dtype)[None]
-    attn_mask = layers.pad_mask_to_attn(mask)
-    moe_ctx = None
-    if cfg.moe_experts > 0:
-        moe_ctx = (
-            moe_cfg_of(cfg),
-            mesh if mesh is not None and "ep" in mesh.shape else None,
-        )
-    block_fn = lambda p, h, m: layers.encoder_block(  # noqa: E731
-        p, h, m, dtype, attn_fn=attn_fn, moe_ctx=moe_ctx, with_aux=True
-    )
-    if remat:
-        # Full-block recompute (minimum memory). Selective policies were
-        # swept on v5e at BERT-base/seq-512 and lost: dots-saveable OOMs at
-        # batch 256 and ties full remat at 128 (247 vs 246 ex/s); with the
-        # flash-train kernel the winner is no remat at all (bench `train`).
-        block_fn = jax.checkpoint(block_fn)
-    aux_total = jnp.float32(0.0)
-    for block in params["blocks"]:
-        x, aux = block_fn(block, x, attn_mask)
-        aux_total = aux_total + aux
-    x = layers.layer_norm(params["ln_f"], x)
+    x, aux_total = _run_blocks(
+        params, x, layers.pad_mask_to_attn(mask), cfg, attn_fn, remat, mesh)
     denom = jnp.maximum(mask.sum(axis=1, keepdims=True), 1).astype(jnp.float32)
     pooled = (x.astype(jnp.float32) * mask[:, :, None]).sum(axis=1) / denom
-    logits = layers.dense(params["head"], pooled.astype(dtype), dtype)
-    logits = logits.astype(jnp.float32)
+    logits = classify_head(params, pooled, cfg)
     if with_aux:
         return logits, aux_total / max(1, cfg.n_layers)
     return logits

@@ -145,24 +145,28 @@ def _proj_out(leaf: Any, x: jax.Array, dtype: Any) -> jax.Array:
 
 
 def whole_row_entry(attn_fn, batch: int, lq: int, lk: int, n_heads: int,
-                    d_head: int, mask: jax.Array, dtype: Any):
+                    d_head: int, mask: jax.Array, dtype: Any,
+                    segments: bool = False):
     """``attn_fn``'s lane-dense entry (``attn_fn.whole_row``, see
     ``kernels.flash_attention.WholeRowAttention``) if it declares one AND its
-    shape-and-mask predicate takes this call; else None, and the caller keeps
-    the [B, H, L, D] path."""
+    shape-and-mask predicate takes this call (``segments``: under segment
+    ids, not under ``mask``); else None, and the caller keeps the
+    [B, H, L, D] path."""
     entry = getattr(attn_fn, "whole_row", None)
     if entry is not None and entry.selects(
-        batch, lq, lk, n_heads, d_head, mask, dtype
+        batch, lq, lk, n_heads, d_head, mask, dtype, segments=segments
     ):
         return entry
     return None
 
 
-def _attention_lane_dense(p: Params, x_q, x_kv, mask, dtype, attn_fn):
+def _attention_lane_dense(p: Params, x_q, x_kv, mask, dtype, attn_fn,
+                          segment_ids=None):
     """:func:`attention` without a cache on [B, L, H*D] operands — the
     projections' own layout, H*D in the lanes — where ``attn_fn`` takes them;
     None where it does not (or a leaf is quantized: those projections write
-    [B, H, L, E])."""
+    [B, H, L, E]). With ``segment_ids`` the entry is asked for its segment
+    form and reads the ids, not the mask."""
     from agent_tpu.models import quant
 
     leaves = [p[name] for name in ("wq", "wk", "wv", "wo")]
@@ -171,7 +175,8 @@ def _attention_lane_dense(p: Params, x_q, x_kv, mask, dtype, attn_fn):
     wq, wk, wv, wo = leaves
     d_model, H, E = wq.shape
     B, Lq, _ = x_q.shape
-    entry = whole_row_entry(attn_fn, B, Lq, x_kv.shape[1], H, E, mask, dtype)
+    entry = whole_row_entry(attn_fn, B, Lq, x_kv.shape[1], H, E, mask, dtype,
+                            segments=segment_ids is not None)
     if entry is None:
         return None
 
@@ -183,7 +188,7 @@ def _attention_lane_dense(p: Params, x_q, x_kv, mask, dtype, attn_fn):
         return y.reshape(B, x.shape[1], H * E)
 
     out = entry(proj(wq, x_q), proj(wk, x_kv), proj(wv, x_kv), mask,
-                n_heads=H)
+                n_heads=H, segment_ids=segment_ids)
     y = jnp.dot(out.reshape(-1, H * E),
                 wo.astype(dtype).reshape(H * E, d_model))
     return y.reshape(B, Lq, d_model)
@@ -199,8 +204,15 @@ def attention(
     cache_index: Optional[jax.Array] = None,
     block_table: Optional[jax.Array] = None,
     attn_fn=dot_product_attention,
+    segment_ids: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """Multi-head attention; optional KV cache for autoregressive decode.
+
+    ``segment_ids`` ([B, L] int32, 0 = pad; self-attention without a cache)
+    says that ``mask`` is the block-diagonal [B, 1, L, L] mask of those ids
+    (:func:`segment_mask_to_attn`): a fused path that takes the ids reads
+    them and not the mask; every other path reads the mask, so both give
+    the same answers.
 
     With ``cache`` (arrays ``k``/``v`` of shape [B, H, Lmax, D]) and a scalar
     ``cache_index``, the new K/V rows are written at ``cache_index`` via
@@ -230,7 +242,8 @@ def attention(
     (``agent_tpu.parallel.ring.ring_attention``) substitutes here.
     """
     if cache is None:
-        lane_dense = _attention_lane_dense(p, x_q, x_kv, mask, dtype, attn_fn)
+        lane_dense = _attention_lane_dense(p, x_q, x_kv, mask, dtype, attn_fn,
+                                           segment_ids)
         if lane_dense is not None:
             return lane_dense, None
     q = _proj_in(p["wq"], x_q, dtype)
@@ -330,8 +343,12 @@ def init_block(key: jax.Array, d_model: int, n_heads: int, d_ff: int,
 def encoder_block(
     p: Params, x: jax.Array, mask: jax.Array, dtype: Any,
     attn_fn=dot_product_attention, moe_ctx=None, with_aux: bool = False,
+    segment_ids: Optional[jax.Array] = None,
 ):
     """Pre-LN transformer block: x + Attn(LN(x)); x + FFN(LN(x)).
+
+    ``segment_ids``: see :func:`attention` (packed rows; ``mask`` is then
+    their block-diagonal mask).
 
     A block carrying a ``moe`` subtree (``encoder.init_params`` with
     ``moe_experts > 0``) routes its FFN sublayer through the Switch MoE
@@ -344,7 +361,8 @@ def encoder_block(
     the aux term collapses onto one expert); serving ignores it.
     """
     h = layer_norm(p["ln1"], x)
-    a, _ = attention(p["attn"], h, h, mask, dtype, attn_fn=attn_fn)
+    a, _ = attention(p["attn"], h, h, mask, dtype, attn_fn=attn_fn,
+                     segment_ids=segment_ids)
     x = x + a
     h = layer_norm(p["ln2"], x)
     if "moe" in p:
@@ -413,6 +431,14 @@ def causal_mask(length: int) -> np.ndarray:
 def pad_mask_to_attn(mask: jax.Array) -> jax.Array:
     """[B, L] padding mask (1 = real token) → [B, 1, 1, L] broadcastable."""
     return mask[:, None, None, :]
+
+
+def segment_mask_to_attn(segment_ids: jax.Array) -> jax.Array:
+    """[B, L] segment ids (0 = pad) → the block-diagonal [B, 1, L, L] attend
+    mask: a query attends the keys of its own segment and no other, a pad
+    slot attends nothing and is attended by nothing."""
+    seg_q, seg_k = segment_ids[:, :, None], segment_ids[:, None, :]
+    return ((seg_q == seg_k) & (seg_k > 0)).astype(jnp.int32)[:, None]
 
 
 def is_key_padding_mask(mask: jax.Array, batch: int, lk: int) -> bool:

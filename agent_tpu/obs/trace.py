@@ -557,6 +557,25 @@ def record_attention_block(path: str) -> None:
            path=path)
 
 
+def record_classify_shard(real_tokens: int, token_slots: int,
+                          packed: bool) -> None:
+    """One classify shard DISPATCHED: the tokens its rows hold, the token
+    slots of the programs it goes out as (program rows x length: what the
+    device computes on), and whether staging packed its short rows several
+    to a program row (``ops/_model_common.py: pack_padded_chunk``) or left
+    them padded. From the staged lengths and shapes, packed or not."""
+    for kind, amount in (("real", real_tokens), ("dispatched", token_slots)):
+        _count("classify_token_slots_total",
+               "Token slots of the classify programs dispatched "
+               "(kind=dispatched: program rows x length, padding included) "
+               "and the real tokens in them (kind=real)",
+               float(amount), kind=kind)
+    _count("classify_shards_total",
+           "Classify shards dispatched, by whether staging packed their rows "
+           "several to a program row (packed) or padded each to the length "
+           "bucket (padded)", layout="packed" if packed else "padded")
+
+
 def record_retention_block(path: str) -> None:
     """One retention mixer TRACED into an XLA program on ``path``
     (``state``: chunk-plus-state, ``quadratic``: the quadratic form alone;

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import fields
-from typing import Any, Dict, Iterator, List, Sequence, Tuple
+from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
 
 # ---- analytic FLOPs (ISSUE 8: the MFU numerator) ----
@@ -253,6 +253,139 @@ def split_padded_chunk(ids, lengths, n: int, dp: int) -> List[Tuple]:
     return out
 
 
+# ---- sequence packing: short rows share a program row ----
+#
+# A chunk padded to the length bucket of its longest row computes on every
+# slot; where the rows are short most slots are padding (rows of 8-64 bytes,
+# median 28, in a bucket of 64: 49 % real). Packed, several rows lie end to
+# end in one program row of the SAME length under a segment mask
+# (``models/encoder.py: pooled_segments``), and the chunk goes out as slices
+# of ONE fixed number of program rows: one executable whatever the rows'
+# lengths, the last slice filled with empty program rows.
+
+# Token slots of one packed slice program (program rows x length). From chip
+# runs of the BERT-base encoder on `bert-base.drain-short` (512 rows of 8-64
+# bytes, length 64; `drain_rows_per_s`, my chip runs, PR 29): slices of 128
+# program rows 19.4-19.6 k, of 64 rows 23.1-23.4 k, of 32 rows 23.2-23.6 k,
+# of 16 rows 23.3 k (padded: 13.4 k). Smaller slices waste less of the last
+# one but cost a program each: 64 rows ties 32 within the run-to-run spread
+# at half the dispatches (PERF.md section 5).
+PACKED_SLICE_TOKENS = 4096
+# The shortest row that still fills its share of a program row: a program
+# row of length L holds at most L // PACKED_MIN_SEGMENT rows, so a slice has
+# PACKED_SLICE_TOKENS // PACKED_MIN_SEGMENT segment slots at every length.
+PACKED_MIN_SEGMENT = 8
+
+
+class PackedChunk(NamedTuple):
+    """One staging chunk in the packed layout: what goes over the wire to
+    the device, and the way back to the chunk's own row order."""
+
+    ids: Any              # [slices * slice_rows, L] wire dtype: rows end to end
+    segment_lengths: Any  # [slices * slice_rows, G] int32: their token counts
+    row_slots: Any        # [B] int32: program row * G + segment of chunk row r
+    n: int                # real rows of the chunk (the rest of B is padding)
+    slice_rows: int       # program rows of one slice program
+
+
+def packed_slice_rows(length: int, dp: int) -> int:
+    """Program rows of one packed slice at ``length``: the power-of-two
+    multiple of ``dp`` (so the slice's rows divide the mesh, as batch
+    buckets do) nearest under ``PACKED_SLICE_TOKENS`` token slots."""
+    rows = max(1, dp)
+    while rows * 2 * length <= PACKED_SLICE_TOKENS:
+        rows *= 2
+    return rows
+
+
+def pack_rows(lengths: Sequence[int], capacity: int, max_segments: int
+              ) -> Tuple[List[int], List[int], int]:
+    """Best-fit-decreasing bin packing of rows into program rows of
+    ``capacity`` token slots and at most ``max_segments`` rows each; a row
+    is never split. Returns ``(program row of row r, its segment index
+    there, program rows used)``. Deterministic: the same lengths give the
+    same pack (longest first, ties in row order; a row goes where the least
+    room is left that still holds it).
+
+    Pure Python on purpose, and O(rows): ``open_at`` is a bit set of the
+    free-room values some open program row has, so "least room that fits" is
+    one shift and one lowest-set-bit."""
+    order = sorted(range(len(lengths)), key=lambda r: -int(lengths[r]))
+    where, segment = [0] * len(order), [0] * len(order)
+    by_room: List[List[int]] = [[] for _ in range(capacity + 1)]
+    count: List[int] = []
+    open_at = 0
+    for r in order:
+        need = int(lengths[r])
+        if not 0 <= need <= capacity:
+            raise ValueError(f"row of {need} tokens in a row of {capacity}")
+        fits = open_at >> need
+        if fits:
+            room = need + (fits & -fits).bit_length() - 1
+            rows_here = by_room[room]
+            b = rows_here.pop()
+            if not rows_here:
+                open_at &= ~(1 << room)
+        else:
+            room, b = capacity, len(count)
+            count.append(0)
+        where[r], segment[r] = b, count[b]
+        count[b] += 1
+        room -= need
+        if count[b] < max_segments:
+            by_room[room].append(b)
+            open_at |= 1 << room
+    return where, segment, len(count)
+
+
+def pack_padded_chunk(ids, lengths, n: int, dp: int) -> Optional[PackedChunk]:
+    """The packed layout of one padded ``(ids [B, L], lengths [B], n_real)``
+    staging chunk, or ``None`` where the chunk stays padded: THE predicate of
+    sequence packing, on what staging can see of the chunk (its length
+    bucket, its rows, their lengths) and nothing else. It packs only where
+    the slices dispatched hold FEWER token slots than the padded chunk
+    would; a chunk whose rows fill their bucket is answered ``None`` and runs
+    the padded program under the padded program's executable key. Streaming
+    (flash) lengths stay padded too: a block-diagonal mask has no streaming
+    kernel.
+
+    The wire stays narrow: the ids in the dtype they have (raw bytes stay
+    uint8), one int32 a segment slot, one int32 a chunk row."""
+    import numpy as np
+
+    from agent_tpu.kernels.flash_attention import selects_flash
+
+    B, L = ids.shape
+    if n <= 0 or selects_flash(L):
+        return None
+    rows = packed_slice_rows(L, dp)
+    real = np.minimum(np.asarray(lengths[:n], dtype=np.int64), L)
+    # The fewest slices any pack could need: where even that many hold no
+    # fewer slots than the padded chunk, there is nothing to compute.
+    if -(-int(real.sum()) // (L * rows)) * rows >= B:
+        return None
+    segments = max(1, L // PACKED_MIN_SEGMENT)
+    where, segment, used = pack_rows(real.tolist(), L, segments)
+    total = -(-used // rows) * rows
+    if total >= B:
+        return None
+    where_a = np.asarray(where, dtype=np.int64)
+    segment_a = np.asarray(segment, dtype=np.int64)
+    seg_lengths = np.zeros((total, segments), dtype=np.int32)
+    seg_lengths[where_a, segment_a] = real
+    # A row's first slot: the tokens of the segments before it in its
+    # program row (segments are numbered in the order they were placed).
+    starts = np.cumsum(seg_lengths, axis=1) - seg_lengths
+    first = where_a * L + starts[where_a, segment_a]
+    token = np.arange(L)[None, :] < real[:, None]             # [n, L]
+    packed = np.zeros((total, L), dtype=ids.dtype)
+    packed.reshape(-1)[(first[:, None] + np.arange(L)[None, :])[token]] = (
+        ids[:n][token])
+    row_slots = np.zeros(B, dtype=np.int32)
+    row_slots[:n] = where_a * segments + segment_a
+    return PackedChunk(packed, seg_lengths, row_slots, n, rows)
+
+
 def iter_chunks(seqs: Sequence, max_chunk: int) -> Iterator[Sequence]:
     """Slice an oversize batch into ≤ max_chunk pieces — rows beyond the top
     batch bucket run as extra device calls instead of overflowing ``pad_batch``
@@ -307,6 +440,7 @@ def stage_text_chunks(
     add_eos: bool = False,
     encode_pad=None,
     split_for_dispatch: bool = False,
+    pack_short_rows: bool = False,
 ) -> List[Tuple]:
     """Pure host: tokenize+pad ``texts`` into device-ready
     ``[(ids[B, L] wire-dtype, lengths[B] int32, n_real_rows), ...]`` chunks —
@@ -330,6 +464,14 @@ def stage_text_chunks(
 
     Length buckets come from :func:`length_buckets_for`; batch buckets are
     multiples of ``dp`` so the batch dim always divides the mesh.
+
+    ``pack_short_rows`` (the classify op alone asks for it, as it does for
+    ``split_for_dispatch``; summarize's staging is untouched by it): a
+    dispatch chunk whose rows leave most of their length bucket empty comes
+    back as a :class:`PackedChunk` — several rows end to end in a program
+    row of the same length, in slices of one fixed number of program rows —
+    where :func:`pack_padded_chunk` says that dispatches fewer token slots;
+    every other chunk stays the padded triple above.
     """
     import numpy as np
 
@@ -368,15 +510,16 @@ def stage_text_chunks(
                 )
         else:
             ids = ids.astype(wire_dtype)
-        staged = (ids, lengths, len(chunk))
+        staged = [(ids, lengths, len(chunk))]
         if split_for_dispatch:
             # Dense-path dispatch budget (split_padded_chunk docstring):
             # slices dispatch back-to-back, fetched once, so the split is
             # free on the wire but keeps score temps at the measured
             # per-program sweet spot.
-            chunks.extend(split_padded_chunk(*staged, dp))
-        else:
-            chunks.append(staged)
+            staged = split_padded_chunk(*staged[0], dp)
+        if pack_short_rows:
+            staged = [pack_padded_chunk(*c, dp) or c for c in staged]
+        chunks.extend(staged)
     return chunks
 
 

@@ -184,10 +184,45 @@ def _collect_sequences(payload: Dict[str, Any], cfg) -> Tuple[List, str, bool]:
 MAX_BATCH = 8192
 
 
+def _model_module(family: str):
+    """The module whose ``forward`` serves ``family``."""
+    if family == "bert":
+        from agent_tpu.models import bert
+
+        return bert
+    from agent_tpu.models import encoder
+
+    return encoder
+
+
+def _takes_packed_rows(cfg, family: str, rt) -> bool:
+    """May this program's short rows share a program row? By what the code
+    can observe of the program that will run, never by a model's name: its
+    ``forward`` has the segment form (``models/encoder.py``; the pretrained
+    BERT family, with learned positions, token types and its own pooling,
+    has not); no expert layer (capacity is counted in token slots, so fewer
+    slots change which tokens an expert drops: a different result); no
+    pipeline schedule (``encoder_forward_pp`` repeats the embedding and the
+    pooling) and no ``sp`` ring (it takes key-padding masks alone). Which
+    chunks of such a program ARE packed is staging's own predicate,
+    ``_model_common.pack_padded_chunk``."""
+    import inspect
+
+    forward = _model_module(family).forward
+    if "segment_lengths" not in inspect.signature(forward).parameters:
+        return False
+    if getattr(cfg, "moe_experts", 0) > 0 or getattr(cfg, "pp", 1) > 1:
+        return False
+    return rt is None or (rt.axis_size("pp") <= 1 and rt.axis_size("sp") <= 1)
+
+
 def _stage_chunks(dp: int, items: List, kind: str, cfg,
-                  family: str = "encoder", model_id: str = "") -> List[Tuple]:
+                  family: str = "encoder", model_id: str = "",
+                  pack: bool = False) -> List[Tuple]:
     """Pure host: tokenize+pad ``items`` into device-ready
-    ``[(ids[B, L] wire-dtype, lengths[B] int32, n_real_rows), ...]``.
+    ``[(ids[B, L] wire-dtype, lengths[B] int32, n_real_rows), ...]``; with
+    ``pack`` a chunk of short text rows comes back as a
+    ``_model_common.PackedChunk`` instead.
 
     Text rows go through the shared fused tokenize+pad hot path
     (``_model_common.stage_text_chunks`` — wire format documented there) for
@@ -215,7 +250,7 @@ def _stage_chunks(dp: int, items: List, kind: str, cfg,
         return stage_text_chunks(
             dp, items, max_len=cfg.max_len, vocab_size=cfg.vocab_size,
             max_batch=MAX_BATCH, encode_pad=encode_pad,
-            split_for_dispatch=True,
+            split_for_dispatch=True, pack_short_rows=pack,
         )
     # Length buckets must not exceed the position table (max_len).
     buckets = length_buckets_for(cfg.max_len)
@@ -262,14 +297,10 @@ def _execute_chunks(
     from agent_tpu.ops._model_common import cfg_key
     from agent_tpu.parallel.shardings import bert_param_specs, encoder_param_specs
 
-    if family == "bert":
-        from agent_tpu.models import bert as model_mod
-
-        specs = bert_param_specs(cfg)
-    else:
-        model_mod = encoder
-        specs = encoder_param_specs(cfg)
-    from agent_tpu.ops._model_common import maybe_quantize_specs
+    model_mod = _model_module(family)
+    specs = (bert_param_specs if family == "bert"
+             else encoder_param_specs)(cfg)
+    from agent_tpu.ops._model_common import PackedChunk, maybe_quantize_specs
 
     specs = maybe_quantize_specs(specs, family, cfg)
 
@@ -322,20 +353,102 @@ def _execute_chunks(
                 dot_product_attention as pp_attn,
             )
 
+    def rebuild_ids(i, real):
+        ids = i.astype(jnp.int32)
+        if i.dtype == jnp.uint8:
+            # Raw-byte wire (stage_text_chunks): unshifted bytes on the
+            # wire, ids rebuilt on device. Trace-time branch — jit
+            # specializes per input dtype, so the uint16/int32 wires trace
+            # without it.
+            ids = (ids + tokenizer.N_SPECIAL) * real
+        return ids
+
+    def pack_result(logits):
+        vals, idx = encoder.topk_probs(logits, k)
+        # One fused [B, k, 2] int32 result: a device→host read costs a full
+        # round trip regardless of size (its cost is not measured on a
+        # directly attached chip), so vals+idx fetch as ONE array. The
+        # SCORES ride as their exact float32 bit patterns in an integer
+        # array, not the indices in a float one: a small index is a
+        # denormal float, and the chip flushes denormals to zero wherever
+        # the packing fuses with float arithmetic (seen on v5e: every index
+        # of a 1-row batch came back 0). Integer lanes are never flushed.
+        return jnp.stack(
+            [jax.lax.bitcast_convert_type(vals, jnp.int32), idx], axis=-1,
+        )
+
+    def dispatch_packed(chunk):
+        """A packed chunk: its slices through ONE slice program, each to the
+        mean of every segment slot; then one head program gathers the
+        chunk's rows out of the slots, in the chunk's own order, and runs
+        head and top-k on those. Both take what a chunk of these shapes can
+        have at most, whatever this chunk has: the slice program the
+        chunk's ids and lengths as ``most`` slices (two transfers a chunk,
+        the missing slices empty) and the index of the one to run, the head
+        program ``most`` slot arrays (the first stands in for the rest; no
+        row reads them). Every packed chunk of a shape runs the same two
+        executables."""
+        rows, L = chunk.slice_rows, chunk.ids.shape[1]
+        B, G = len(chunk.row_slots), chunk.segment_lengths.shape[1]
+        most = B // rows - 1    # pack_padded_chunk packs under B rows only
+
+        def build_slice():
+            def run_fwd(p, ids_all, seg_all, s):
+                i, seg = (jax.lax.dynamic_index_in_dim(a, s, keepdims=False)
+                          for a in (ids_all, seg_all))
+                real = (jnp.arange(L)[None, :]
+                        < seg.sum(axis=1, keepdims=True)).astype(jnp.int32)
+                return model_mod.pooled_segments(
+                    p, rebuild_ids(i, real), seg, cfg, attn_fn=attn_fn,
+                    mesh=runtime.mesh,
+                ).reshape(rows * G, -1)
+
+            return jax.jit(run_fwd)
+
+        def build_head():
+            def run_head(p, slots, row_slots):
+                pooled = jnp.concatenate(slots, axis=0)[row_slots]
+                return pack_result(model_mod.classify_head(p, pooled, cfg))
+
+            return jax.jit(run_head)
+
+        fwd = runtime.compiled(
+            ("map_classify_tpu", model_id, family, rows, L, ("packed", most),
+             cfg_key(cfg)), build_slice)
+        # The head's weights are arguments: one executable for every model
+        # of a config.
+        head = runtime.compiled(
+            ("map_classify_tpu", "packed_head", family, B, rows * G, most, k,
+             cfg_key(cfg)), build_head)
+
+        def put_slices(a):
+            """[P, w] → [most, rows, w] on the device, a slice's rows over
+            ``dp``."""
+            out = np.zeros((most * rows, a.shape[1]), a.dtype)
+            out[:len(a)] = a
+            return jax.device_put(out.reshape(most, rows, -1),
+                                  runtime.sharding(None, "dp"))
+
+        ids_all, seg_all = put_slices(chunk.ids), put_slices(
+            chunk.segment_lengths)
+        slots = [fwd(params, ids_all, seg_all, np.int32(s))
+                 for s in range(chunk.ids.shape[0] // rows)]
+        slots += [slots[0]] * (most - len(slots))
+        return head({"head": params["head"]}, slots,
+                    runtime.put_batch(chunk.row_slots))
+
     pending: List[Tuple[Any, Any, int]] = []
-    for ids, lengths, n in chunks:
+    for chunk in chunks:
+        if isinstance(chunk, PackedChunk):
+            pending.append((dispatch_packed(chunk), chunk.n))
+            continue
+        ids, lengths, n = chunk
         B, L = ids.shape
 
         def build(L=L):
             def run_fwd(p, i, nlen):
                 mask = (jnp.arange(L)[None, :] < nlen[:, None]).astype(jnp.int32)
-                ids = i.astype(jnp.int32)
-                if i.dtype == jnp.uint8:
-                    # Raw-byte wire (stage_text_chunks): unshifted bytes on
-                    # the wire, ids rebuilt on device. Trace-time branch —
-                    # jit specializes per input dtype, so the uint16/int32
-                    # wires trace without it.
-                    ids = (ids + tokenizer.N_SPECIAL) * mask
+                ids = rebuild_ids(i, mask)
                 if pp_mesh is not None:
                     logits = encoder_forward_pp(
                         p, ids, mask, cfg, pp_mesh,
@@ -350,20 +463,7 @@ def _execute_chunks(
                     logits = model_mod.forward(
                         p, ids, mask, cfg, attn_fn=attn_fn
                     )
-                vals, idx = encoder.topk_probs(logits, k)
-                # One fused [B, k, 2] int32 result: a device→host read costs
-                # a full round trip regardless of size (its cost is not
-                # measured on a directly attached chip), so vals+idx fetch
-                # as ONE array. The SCORES ride as their exact float32 bit
-                # patterns in an integer array, not the indices in a float
-                # one: a small index is a denormal float, and the chip
-                # flushes denormals to zero wherever the packing fuses with
-                # float arithmetic (seen on v5e: every index of a 1-row
-                # batch came back 0). Integer lanes are never flushed.
-                return jnp.stack(
-                    [jax.lax.bitcast_convert_type(vals, jnp.int32), idx],
-                    axis=-1,
-                )
+                return pack_result(logits)
 
             return jax.jit(run_fwd)
 
@@ -541,12 +641,14 @@ def stage(payload: Any, ctx: Optional[object] = None):
         elif getattr(cfg, "pp", 1) > 1:
             dp_stage = rt.n_devices
     chunks = _stage_chunks(
-        dp_stage, items, kind, cfg, family=family, model_id=model_id
+        dp_stage, items, kind, cfg, family=family, model_id=model_id,
+        pack=_takes_packed_rows(cfg, family, rt),
     )
 
     state = {
         "t0": t0,
         "chunks": chunks,
+        "token_slots": _token_slots(chunks),
         "n_rows": len(items),
         "cfg": cfg,
         "k": min(topk, cfg.n_classes),  # clamp so lax.top_k stays legal
@@ -560,6 +662,24 @@ def stage(payload: Any, ctx: Optional[object] = None):
         "t_staged": time.perf_counter(),
     }
     return "staged", state
+
+
+def _token_slots(chunks: List[Tuple]) -> Tuple[int, int, bool]:
+    """(real tokens, token slots dispatched, any chunk packed) of a staged
+    shard, from the staged lengths and shapes: what
+    ``classify_token_slots_total`` and ``classify_shards_total`` tick."""
+    from agent_tpu.ops._model_common import PackedChunk
+
+    real = slots = 0
+    packed = False
+    for chunk in chunks:
+        slots += int(chunk[0].shape[0] * chunk[0].shape[1])
+        if isinstance(chunk, PackedChunk):
+            packed = True
+            real += int(chunk.segment_lengths.sum())
+        else:
+            real += int(np.minimum(chunk[1][:chunk[2]], chunk[0].shape[1]).sum())
+    return real, slots, packed
 
 
 def _stamp_flops(state: Dict[str, Any], ctx: Optional[object]) -> None:
@@ -606,6 +726,7 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
     # device time (it shows up as queue_ms instead).
     state["t_exec0"] = time.perf_counter()
     _stamp_flops(state, ctx)
+    obs_trace.record_classify_shard(*state["token_slots"])
     model_id, cfg, k = state["model_id"], state["cfg"], state["k"]
     fallback_reason = None
     try:

@@ -108,6 +108,20 @@ def _whole_row(batch):
     return build
 
 
+def _whole_row_segments(batch):
+    """The whole-row kernel under segment ids at a packed slice's own shape
+    (``ops/_model_common.py: packed_slice_rows``): the ids ride twice, as
+    [B, 1, L] and as [B, L, 1] blocks."""
+    def build(chip, L, D):
+        x = jax.ShapeDtypeStruct((batch, L, H * D), jnp.bfloat16,
+                                 sharding=chip)
+        seg = jax.ShapeDtypeStruct((batch, L), jnp.int32, sharding=chip)
+        fn = lambda q, k, v, s: fa.whole_row_attention(  # noqa: E731
+            q, k, v, None, n_heads=H, segment_ids=s, interpret=False)
+        return fn, (x, x, x, seg)
+    return build
+
+
 # (case, builder, key length, d_head, Pallas calls in the compiled program)
 CASES = [
     ("forward_L4096_d64", _forward, 4096, 64, 1),
@@ -119,6 +133,9 @@ CASES = [
     ("whole_row_256x512", _whole_row(256), 512, 64, 1),   # drain-long
     ("whole_row_512x64", _whole_row(512), 64, 64, 1),     # drain-short
     ("whole_row_8x128_d128", _whole_row(8), 128, 128, 1),
+    ("whole_row_segments_64x64", _whole_row_segments(64), 64, 64, 1),   # drain-short, packed
+    ("whole_row_segments_32x128", _whole_row_segments(32), 128, 64, 1),
+    ("whole_row_segments_8x512", _whole_row_segments(8), 512, 64, 1),
 ]
 
 
@@ -135,6 +152,45 @@ def test_kernel_compiles_for_v5e(v5e, build, L, D, n_kernels):
     if build is not _fold:  # the fold has no dense alternative to select
         assert sum(fa.SELECTION_COUNTS.values()) > sum(before.values())
         assert fa.SELECTION_COUNTS.get("dense", 0) == before.get("dense", 0)
+
+
+def test_packed_slice_program_compiles_for_v5e(v5e):
+    """The classify op's packed slice program (``encoder.pooled_segments``:
+    segment ids and positions rebuilt on the device, the position gather,
+    the whole-row kernel under segment ids, the per-segment pool) at
+    ``bert-base.drain-short``'s own slice shape, 64 program rows of 64, at
+    BERT-base widths (2 of the 12 layers: the blocks are alike)."""
+    from agent_tpu.kernels import make_flash_attention
+    from agent_tpu.models import encoder
+    from agent_tpu.ops._model_common import (
+        PACKED_MIN_SEGMENT,
+        packed_slice_rows,
+    )
+    from agent_tpu.runtime.mesh import build_mesh
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    cfg = encoder.EncoderConfig(d_model=768, n_heads=12, n_layers=2,
+                                d_ff=3072, max_len=512, n_classes=1000)
+    L = 64
+    rows, G = packed_slice_rows(L, 1), L // PACKED_MIN_SEGMENT
+    assert (rows, G) == (64, 8)
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    params = jax.tree_util.tree_map(
+        lambda leaf: sd(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: encoder.init_params(cfg)))
+    attn_fn = make_flash_attention(
+        build_mesh([v5e.devices[0]], {"dp": 1}), interpret=False)
+
+    def run_fwd(p, ids, seg):
+        return encoder.pooled_segments(p, ids, seg, cfg, attn_fn=attn_fn)
+
+    before = dict(fa.SELECTION_COUNTS)
+    compiled = jax.jit(run_fwd).lower(
+        params, sd((rows, L), jnp.int32), sd((rows, G), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == cfg.n_layers
+    assert (fa.SELECTION_COUNTS["whole_row"] - before.get("whole_row", 0)
+            == cfg.n_layers)
+    assert fa.SELECTION_COUNTS.get("dense", 0) == before.get("dense", 0)
 
 
 @pytest.mark.parametrize("carried", [False, True],
