@@ -37,8 +37,8 @@ def _scan_row_offsets_py(path: str) -> np.ndarray:
     Per chunk: numpy finds every quote and newline position at once; the
     number of quotes *before* each newline (``searchsorted``) plus the
     carried-in quote parity decides which newlines are row boundaries —
-    a '"' inside a quoted field has odd parity and is skipped. ~2 orders of
-    magnitude faster than a per-byte Python loop (the round-1 bottleneck).
+    a '"' inside a quoted field has odd parity and is skipped. No byte is
+    visited by the interpreter.
     """
     parts: List[np.ndarray] = [np.zeros(1, dtype=np.int64)]
     quote_parity = 0  # quotes seen so far, mod 2, carried across chunks

@@ -65,21 +65,20 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
 # tree.
 FLASH_MIN_KEY_LEN = 2048
 
-# TRAINING gates lower. Serving loses at 512 because pallas_call breaks
-# XLA's fusions around a forward-only pass — but the backward-dense path
-# also re-materializes and re-reads the [B, H, L, L] score tensor, which
-# at BERT-base train shapes (B 256, L 512) is ~1.6 GB of HBM traffic per
-# layer per direction. Measured on v5e at seq 512, remat=full: flash
-# 255 ex/s vs dense 246; and because the flash backward stores NO score
-# tensors, it unlocks remat-free training at batch 128 — 308 ex/s,
-# 45.3% MFU vs the dense+remat baseline's 246 / 36.2% (bench `train` leg).
+# TRAINING gates lower. The backward-dense path re-materializes and re-reads
+# the [B, H, L, L] score tensor, which at BERT-base train shapes (B 256,
+# L 512) is ~1.6 GB of HBM traffic per layer per direction; the flash
+# backward stores NO score tensors, which is what lets a training step run
+# without remat at batch 128. Its speed against dense + remat: not measured
+# on the present tree (PERF.md §7, rows 6-8: `train_classifier` at batch 128
+# needs a `train_tokens_per_s` cell).
 FLASH_TRAIN_MIN_KEY_LEN = 512
 
 # Trace-time selection tally: ``flash_attention`` decides kernel-vs-dense while
 # the surrounding jit TRACES (the gate is static shape metadata), so these
-# counters tick once per compiled program, not per call. bench.py diffs them
-# around a warmup to *prove* which path a compiled executable contains —
-# "the bench exercises the Pallas kernel" becomes an assertion, not a belief.
+# counters tick once per compiled program, not per call. Tests and
+# ``chip_smoke.py`` diff them around a compile to *prove* which path a
+# compiled executable contains: an assertion, not a belief.
 SELECTION_COUNTS = {"flash": 0, "dense": 0}
 
 
@@ -304,7 +303,7 @@ WHOLE_ROW_MAX_KEY_LEN = 1024
 # score temporaries must fit the scoped VMEM asked for below; v5e has 128 MiB.
 _WHOLE_ROW_VMEM_LIMIT = 64 * 1024 * 1024
 _WHOLE_ROW_BLOCK_BUDGET = 16 * 1024 * 1024
-# Rows x length a step: enough work to bury a step's fixed cost (~0.35 us).
+# Rows x length a step: enough work to bury a step's fixed cost.
 _WHOLE_ROW_STEP_TOKENS = 512
 
 

@@ -151,10 +151,10 @@ def _run_blocks(params, x, attn_mask, cfg, attn_fn, remat, mesh,
         segment_ids=s,
     )
     if remat:
-        # Full-block recompute (minimum memory). Selective policies were
-        # swept on v5e at BERT-base/seq-512 and lost: dots-saveable OOMs at
-        # batch 256 and ties full remat at 128 (247 vs 246 ex/s); with the
-        # flash-train kernel the winner is no remat at all (bench `train`).
+        # Full-block recompute (minimum memory): the one policy kept.
+        # Selective policies (dots-saveable) against it, and no remat at
+        # all on the flash-train kernel: not measured on the present tree
+        # (PERF.md §7, rows 6-8: `train_classifier` needs a cell).
         block_fn = jax.checkpoint(block_fn)
     aux_total = jnp.float32(0.0)
     for block in params["blocks"]:
@@ -276,8 +276,7 @@ def topk_rows(values: np.ndarray, indices: np.ndarray) -> list:
     """Device (values, indices) → per-row [{"index", "score"}] result shape
     (reference ``ops/map_classify_tpu.py:76-82``). lax.top_k returns sorted
     descending already. ``tolist()`` first: it converts to native Python
-    numbers in C, ~5× faster than per-element numpy scalar indexing at
-    bench batch sizes."""
+    numbers in C, where per-element indexing makes a numpy scalar each."""
     return [
         [{"index": i, "score": s} for i, s in zip(idx_row, val_row)]
         for idx_row, val_row in zip(

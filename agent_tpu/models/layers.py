@@ -292,8 +292,10 @@ def attention(
             # Per-row positions: one decode step (Lk == 1) written to each
             # row's own cache slot. Formulated as a one-hot select, NOT a
             # gather/scatter — XLA lowers scatters to element loops on some
-            # backends (measured 3× per-step cost on CPU), while the dense
-            # where is a single vectorized pass over the cache.
+            # backends, while the dense where is a single vectorized pass
+            # over the cache. Which form the chip's decode step runs faster
+            # in: not measured on the present tree (PERF.md §7, row 1: the
+            # `/v1/infer` kind that was built and not shipped).
             sel = (
                 jnp.arange(cache["k"].shape[2])[None, :]
                 == cache_index[:, None]

@@ -89,15 +89,13 @@ def expert_batch_spec() -> P:
 # Routing group size (tokens). Capacity — and therefore the [t, E, C]
 # dispatch/combine tensors and their einsums — scales with the token count
 # being routed TOGETHER, so routing a whole serving batch as one group makes
-# the dispatch einsums dominate: at BERT-base-8E serving shapes (B 1024 ×
-# L 512 = 524k tokens) the one-group formulation measured **51 rows/s** vs
-# the dense-FFN model's 1,097. Bounded groups are the standard GShard/Switch
-# answer — dispatch/FFN flops ≈ G·cf / (4·d_ff). Measured on v5e (bench
-# ``moe`` leg, same shapes): G=4096 → 473 rows/s, 1024 → 595, 512 → 635,
-# 256 → 615, 128 → 669. Default 512 = one seq-512 row per group (capacity
-# 80 at E=8/cf 1.25 — small-group drop variance still bounded) from the
-# plateau. Tokens route independently per group; drops depend only on
-# in-group competition.
+# the dispatch einsums dominate (at BERT-base-8E serving shapes, B 1024 ×
+# L 512, one group is 524k tokens). Bounded groups are the standard
+# GShard/Switch answer — dispatch/FFN flops ≈ G·cf / (4·d_ff). Default 512 =
+# one seq-512 row per group (capacity 80 at E=8/cf 1.25 — small-group drop
+# variance still bounded). Rows/s by group size: not measured on the present
+# tree (PERF.md §7: no cell runs `moe_experts > 0`). Tokens route
+# independently per group; drops depend only on in-group competition.
 MOE_GROUP_TOKENS = 512
 
 

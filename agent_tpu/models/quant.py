@@ -6,8 +6,8 @@ INT8-compiled TFLite artifact with an int8 input contract (reference
 Coral toolchain in ``Dockerfile:9-30``). The TPU-native successor is not a
 quantized *artifact* but a quantized *execution mode*: the same checkpoint /
 deterministic params, with the hot matmuls running ``int8 × int8 → int32``
-on the MXU — ~2× the bf16 MXU rate on v5e — and dequantizing into the f32
-residual stream. Serving contract, tokenization, and result shapes are
+on the MXU (a v5e's published peaks: 393 TOP/s in int8, 197 TFLOP/s in
+bf16) and dequantizing into the f32 residual stream. Serving contract, tokenization, and result shapes are
 unchanged; ``model_config: {"quant": "int8"}`` (or ``TPU_QUANT=int8``)
 flips the mode per task.
 
@@ -25,8 +25,8 @@ Scheme (the standard dynamic W8A8 recipe, AQT-style but hand-rolled):
 - **What stays high-precision**: embeddings, LayerNorms, softmax, residual
   adds, the attention score/context matmuls (QKᵀ, PV — both activations,
   dynamic-range-fragile), and the tiny classifier/pooler heads. FFN + QKVO
-  projections carry ~90% of encoder FLOPs, bounding the ideal speedup near
-  1.8×.
+  projections carry ~90% of encoder FLOPs: that share is all the int8 rate
+  can act on.
 
 Leaf convention: a quantized projection replaces the f32 array (or
 ``{"w", "b"}`` dense dict) with ``{"w_q": int8, "w_scale": f32[out-dims]}``
@@ -135,35 +135,18 @@ def quantize_act(x: jax.Array, axes: Tuple[int, ...] = (-1,)):
 
 # ---- quantized matmuls ----
 #
-# These stay on XLA's ``dot_general(int8, int8 → int32)`` ON PURPOSE. A
-# Pallas W8A8 kernel with the dequant epilogue fused in VMEM (int32 never
-# reaching HBM) was built and measured end to end at BERT-base serving
-# shapes on v5e (batch 4096, seq 512): bf16 1,136 rows/s, XLA int8 1,333,
-# Pallas kernel 587 — the ``pallas_call`` fusion barrier (activation
-# quantization can no longer fuse into the preceding LN/GELU) plus the
-# blocked re-reads of x per N-tile cost far more than the epilogue saves.
-#
-# Why the end-to-end win is ~1.2×, not the spec sheet's 2× — the measured
-# decomposition (v5e, calibrated chained-loop windows; the end-to-end
-# speedup and agreement are the recorded ``bert_base_int8`` bench leg,
-# BENCH_r05: 1.272× at top-1 agreement 1.0):
-#   - the int8 dot itself DOES run at ~2.0× the bf16 MXU rate
-#     (353-365 TOP/s vs 175-183 TF/s at MXU-saturating shapes);
-#   - the dequant epilogue is FREE — XLA fuses int32→f32·sx·sw+b into the
-#     dot's output pass (dot+epilogue == bare dot, 1.72 vs 1.75 ms at the
-#     BERT FFN shape);
-#   - dynamic activation quantization costs the one remaining overhead
-#     (~27% on a bare FFN matmul; partly amortized in-model where the amax
-#     pass fuses with the producing LN/GELU, and the identical Q/K/V
-#     quantizations CSE to one — verified in compiled HLO);
-#   - Amdahl does the rest: 40.6% of the bf16 forward is non-matmul
-#     elementwise/HBM traffic (LN, GELU, softmax, residuals — matmul-floor
-#     ablation) and the attention score/context matmuls stay bf16 by
-#     choice, so quantizing the projections+FFN at a true 2× bounds the
-#     whole forward near ~1.35×; measured 1.16-1.22×.
-# A ≥1.5× serving speedup therefore needs a smaller elementwise share
-# (fused attention at seq 512, activation-dtype changes), not a faster
-# int8 matmul — the matmul is already double-rate.
+# These stay on XLA's ``dot_general(int8, int8 → int32)`` ON PURPOSE: a
+# ``pallas_call`` with the dequant epilogue fused in VMEM is a fusion
+# barrier (activation quantization can no longer fuse into the preceding
+# LN/GELU) and re-reads x per N-tile, while XLA already fuses
+# int32→f32·sx·sw+b into the dot's output pass and CSEs the identical
+# Q/K/V quantizations to one. What is left to pay is the dynamic activation
+# quantization and everything that is not a quantized matmul (LN, GELU,
+# softmax, residuals, the attention score/context matmuls, which stay in
+# the compute dtype by choice). How much int8 gains end to end over bf16:
+# not measured on the present tree as a cell (PERF.md §7, rows 6-8: it
+# needs a reference that quantizes as the configuration states; the int8
+# CONTROL runs of the ledger's cells read `correct: false`, PERF.md §6).
 
 
 def qdense(p: Params, x: jax.Array, dtype: Any) -> jax.Array:
@@ -211,8 +194,9 @@ def qproj_out(p: Params, x: jax.Array, dtype: Any) -> jax.Array:
 #
 # The memory-bound recipe for DECODE: the per-step matmuls are [rows, d]-thin
 # (rows ≤ batch, d = d_model), so the MXU is idle waiting on HBM and the
-# W8A8 activation-quant overhead buys nothing (measured: 3,983 int8 vs
-# 4,980 bf16 rows/s at B=1024 — bench.py decode note). Weight-only keeps
+# W8A8 activation-quant pass is overhead with nothing to win (decode speed
+# by mode: not measured on the present tree; PERF.md §7, rows 6-8, scan
+# decode in `map_summarize`). Weight-only keeps
 # activations in the compute dtype and ships/reads the int8 table (half the
 # bf16 bytes, a quarter of f32), dequantizing by a per-output-channel scale
 # on the dot's OUTPUT — the epilogue fuses, and there is no quantize pass

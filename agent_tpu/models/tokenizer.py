@@ -39,12 +39,12 @@ SPECIAL_TOKENS = ("<pad>", "<bos>", "<eos>", "<unk>")
 # Default padding buckets: powers of two from 16 up. One compiled executable per
 # bucket per batch size — the executable cache stays small and recompiles stop
 # once the buckets are warm.
-# Powers of two PLUS their midpoints: a pure pow2 ladder wastes up to 2×
-# padding at the bucket edge (measured in the 10M-row drain: ~70-byte rows
-# bucketing to 128 ran summarize at 5.2k rows/s where the 64 bucket ran
-# 8.2k — ~44% of every matmul was padding). A ratio-1.5 ladder caps the
-# worst-case pad multiplier at ~1.5× (a 65-token row pads to 96 = 1.48×)
-# vs the pow2 ladder's 2×; all entries stay multiples of 8 (TPU sublane)
+# Powers of two PLUS their midpoints: a pure pow2 ladder pads a row just
+# over a bucket edge to twice its length (a ~70-byte row in the 128 bucket:
+# ~44% of every matmul is padding). A ratio-1.5 ladder caps the worst-case
+# pad multiplier at ~1.5× (a 65-token row pads to 96 = 1.48×) vs the pow2
+# ladder's 2×. What the finer ladder gains in rows/s: not measured on the
+# present tree (PERF.md §7: lengths neither 64 nor whole 128s, no cell); all entries stay multiples of 8 (TPU sublane)
 # and the ≥2048 ones multiples of 512 (the flash kernel's tile
 # divisibility gate).
 DEFAULT_BUCKETS = (

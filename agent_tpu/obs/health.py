@@ -32,9 +32,8 @@ import os
 import time
 from typing import Any, Dict, List, Mapping, Optional, Sequence
 
-# Peak dense-bf16 FLOP/s by jax device_kind (public spec sheets) — shared
-# source of truth for the agent's MFU gauge; bench.py keeps its own table
-# for report-side normalization. Unknown kinds → MFU is absent, never a
+# Peak dense-bf16 FLOP/s by jax device_kind (public spec sheets): the
+# source of truth for the agent's MFU gauge. Unknown kinds → MFU is absent, never a
 # guess. PEAK_TFLOPS overrides (useful on CPU CI and for new chip steppings).
 PEAK_BF16_TFLOPS = {
     "TPU v4": 275.0,

@@ -89,7 +89,7 @@ class HostProfiler:
     """Sampling host profiler: periodic ``sys._current_frames()`` walks
     aggregated into collapsed stacks.
 
-    Frames render as ``file.py:function`` (definition identity, not the
+    Frames render as ``<file>.py:function`` (definition identity, not the
     current line — a hot loop must aggregate into one stack, not one stack
     per bytecode line). Distinct-stack count is bounded (``max_stacks``);
     overflow samples aggregate under a sentinel stack so the memory bound

@@ -1,12 +1,11 @@
-"""Scrape-side helpers: read ``GET /v1/metrics`` and ``/v1/trace`` back.
+"""Scrape-side helpers: read ``GET /v1/metrics``, ``/v1/health`` and
+``/v1/trace`` back.
 
-``bench.py`` and ``scripts/drain_at_scale.py`` attribute drain time per op
-by scraping the controller's exposition instead of re-deriving spans from
-result bodies (``utils/spans.py`` stays as the fallback when scraping is
-unavailable — e.g. a controller predating the endpoint), and fetch the
-slowest job's assembled trace for a per-phase breakdown line (ISSUE 5
-satellite: a broken trace path fails loudly in bench runs instead of
-rotting silently). Stdlib-only, like the rest of ``agent_tpu.obs``.
+Drain time is attributed per op from the controller's exposition
+(``op_phase_seconds`` over a scraped ``/v1/metrics`` body), the autoscaler
+and ``scripts/swarmtop.py`` read the JSON endpoints through ``fetch_json``
+/ ``fetch_health``, and the stage/execute overlap of a drain is read off
+the assembled traces. Stdlib-only, like the rest of ``agent_tpu.obs``.
 """
 
 from __future__ import annotations
@@ -18,32 +17,16 @@ from typing import Any, Dict, Iterable, Optional
 from agent_tpu.obs.metrics import parse_exposition
 
 
-def fetch_metrics_text(
-    base_url: str, timeout: float = 10.0
-) -> Optional[str]:
-    """GET ``<base_url>/v1/metrics`` → exposition text, or None on any
-    failure (callers fall back to result-body spans)."""
-    url = base_url.rstrip("/") + "/v1/metrics"
-    try:
-        with urllib.request.urlopen(url, timeout=timeout) as resp:
-            if resp.status != 200:
-                return None
-            return resp.read().decode("utf-8", errors="replace")
-    except Exception:  # noqa: BLE001 — scrape is best-effort by contract
-        return None
-
-
 def op_phase_seconds(
     text: str,
     ops: Iterable[str],
     phases: Iterable[str] = ("execute", "fetch"),
 ) -> Dict[str, float]:
-    """Sum ``task_phase_seconds_sum{op,phase}`` over ``phases`` per op —
-    the scraped equivalent of ``utils.spans.op_span_ms`` (which sums
-    ``device_ms + fetch_ms``; the execute phase is the device-dispatch
-    span). Series carrying an ``agent`` label and the fleet-merged ones
-    would double-count if both were summed; only unlabeled (fleet/merged)
-    series count."""
+    """Sum ``task_phase_seconds_sum{op,phase}`` over ``phases`` per op
+    (the execute phase is the device-dispatch span, the fetch phase the
+    poster's wait for the device). Series carrying an ``agent`` label and
+    the fleet-merged ones would double-count if both were summed; only
+    unlabeled (fleet/merged) series count."""
     phases = set(phases)
     out = {op: 0.0 for op in ops}
     try:
@@ -63,9 +46,8 @@ def fetch_health(
     base_url: str, timeout: float = 10.0
 ) -> Optional[Dict[str, Any]]:
     """``GET /v1/health`` → the fleet verdict body (ISSUE 8), or None on
-    any failure. Callers that promised health reporting (bench,
-    drain_at_scale) must fail loudly on None instead of omitting the
-    fields silently."""
+    any failure. Callers that promised health reporting must fail loudly
+    on None instead of omitting the fields silently."""
     out = fetch_json(base_url, "/v1/health", timeout=timeout)
     return out if isinstance(out, dict) else None
 
@@ -92,27 +74,6 @@ def fetch_trace(
     """``GET /v1/trace/{job_id}`` → the assembled span tree, or None."""
     out = fetch_json(base_url, f"/v1/trace/{job_id}", timeout=timeout)
     return out if isinstance(out, dict) else None
-
-
-def slowest_trace(
-    base_url: str, limit: int = 64, timeout: float = 10.0
-) -> Optional[Dict[str, Any]]:
-    """The assembled trace of the slowest job in the controller's trace
-    window (largest closed root duration) — what the bench/drain scripts
-    print a phase-breakdown line for. None when the trace path is down."""
-    listing = fetch_json(base_url, f"/v1/traces?limit={int(limit)}",
-                         timeout=timeout)
-    if not isinstance(listing, dict):
-        return None
-    candidates = [
-        t for t in listing.get("traces", [])
-        if isinstance(t, dict)
-        and isinstance(t.get("root_duration_ms"), (int, float))
-    ]
-    if not candidates:
-        return None
-    worst = max(candidates, key=lambda t: t["root_duration_ms"])
-    return fetch_trace(base_url, worst["trace_id"], timeout=timeout)
 
 
 # ---- stage/execute overlap (ISSUE 6 satellite) ----
@@ -209,8 +170,8 @@ def stage_execute_overlap(
 ) -> Optional[Dict[str, Any]]:
     """:func:`overlap_from_spans` over the controller's newest ``limit``
     traces. None when the trace path is down or no stage/execute spans
-    assembled — callers that promised the breakdown (drain_at_scale) must
-    fail loudly on None."""
+    assembled — callers that promised the breakdown must fail loudly on
+    None."""
     spans = collect_trace_spans(base_url, limit=limit, timeout=timeout)
     if spans is None:
         return None
@@ -238,14 +199,3 @@ def overlap_by_process(spans) -> Dict[str, Dict[str, Any]]:
         if overlap is not None:
             out[name] = overlap
     return out
-
-
-def stage_execute_overlap_by_agent(
-    base_url: str, limit: int = 64, timeout: float = 10.0
-) -> Optional[Dict[str, Dict[str, Any]]]:
-    """:func:`overlap_by_process` over the controller's newest ``limit``
-    traces; None when the trace path is down."""
-    spans = collect_trace_spans(base_url, limit=limit, timeout=timeout)
-    if spans is None:
-        return None
-    return overlap_by_process(spans)

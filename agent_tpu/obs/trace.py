@@ -109,7 +109,7 @@ def enabled() -> bool:
 
 
 def new_span_id() -> str:
-    # os.urandom is ~5x cheaper than uuid4 and this runs several times per
+    # os.urandom is cheaper than uuid4 and this runs several times per
     # task on the drain hot path; 64 random bits is the OTel span-id width.
     return os.urandom(8).hex()
 
@@ -211,8 +211,9 @@ def span_link(
 
 
 def _valid_span(span: Any) -> bool:
-    # dict first: the typing.Mapping ABC check costs ~3µs and every span on
-    # the wire is a plain dict; the ABC path survives only for odd callers.
+    # dict first: the typing.Mapping ABC check is the dear one and every
+    # span on the wire is a plain dict; the ABC path survives only for odd
+    # callers.
     if type(span) is not dict and not isinstance(span, Mapping):
         return False
     return (

@@ -18,8 +18,7 @@ from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Sequence, Tu
 # ---- analytic FLOPs (ISSUE 8: the MFU numerator) ----
 #
 # Matmul terms only (2·M·N·K per matmul; elementwise/softmax are noise at
-# model scale) — the same accounting bench.py's encoder_flops_per_row has
-# always used, now stamped per executed shard so the agent can export a
+# model scale), stamped per executed shard so the agent can export a
 # live device_mfu{op} gauge. These are ESTIMATES by design: the point is a
 # stable utilization trend per shape bucket, not a profiler.
 
@@ -207,12 +206,13 @@ def batch_buckets(dp: int, cap: int) -> List[int]:
 
 
 # Device-dispatch chunk budget (rows × padded length) for DENSE-attention
-# shapes. The dense path materializes [B, H, L, L] score temps; past ~131k
-# tokens per program the score traffic degrades the matmul schedule —
-# measured on v5e at BERT-base/seq 512: 256-row chunks run the same 1,024
-# rows 11% faster in bf16 and 40% faster in int8 than one 1,024-row program
-# (chunks dispatch back-to-back, so the split costs no extra host↔device
-# round trips). Flash-path lengths (``kernels.flash_attention.selects_flash``)
+# shapes. The dense path materializes [B, H, L, L] score temps, which grow
+# with the rows of a program; chunks dispatch back-to-back, so the split
+# costs no extra host↔device round trips. Whether 131k tokens a program is
+# still the best size now that the whole-row kernel holds the scores in
+# VMEM: not measured on the present tree (PERF.md §7, "one 512 x 512
+# program a long shard"; `bert-base.drain-long` would show it).
+# Flash-path lengths (``kernels.flash_attention.selects_flash``)
 # stream their scores through VMEM and keep the large-batch grid.
 DENSE_CHUNK_TOKENS = 131_072
 
@@ -514,8 +514,8 @@ def stage_text_chunks(
         if split_for_dispatch:
             # Dense-path dispatch budget (split_padded_chunk docstring):
             # slices dispatch back-to-back, fetched once, so the split is
-            # free on the wire but keeps score temps at the measured
-            # per-program sweet spot.
+            # free on the wire but keeps score temps at the budgeted
+            # per-program size.
             staged = split_padded_chunk(*staged[0], dp)
         if pack_short_rows:
             staged = [pack_padded_chunk(*c, dp) or c for c in staged]

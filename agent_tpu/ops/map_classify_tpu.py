@@ -285,8 +285,8 @@ def _execute_chunks(
     thread-safe; only dispatch is owner-bound).
 
     Top-k runs on device, fused into the forward executable: the host fetches
-    k probabilities per row, not [B, n_classes] logits — at bench shapes that
-    is a ~100× smaller device→host transfer. Chunks dispatch asynchronously
+    k probabilities per row, not [B, n_classes] logits (5 of 1,000 a row in
+    the benchmark's cells). Chunks dispatch asynchronously
     and are fetched after the loop, so host staging of chunk i+1 overlaps
     device compute of chunk i even without the pipeline.
     """
@@ -468,10 +468,11 @@ def _execute_chunks(
             return jax.jit(run_fwd)
 
         # k is fused into the executable, so a task stream alternating topk
-        # values recompiles per (shape, k). Measured trade-off: splitting
-        # top-k into its own jit avoids that but costs an extra dispatch
-        # round-trip every call (-15% bench throughput); jobs use one topk,
-        # so the fused form wins.
+        # values recompiles per (shape, k). Splitting top-k into its own
+        # jit avoids that but costs an extra dispatch every call (what it
+        # costs in rows/s: not measured on the present tree; either
+        # `bert-base` cell would show it); jobs use one topk, so the fused
+        # form stays.
         fn = runtime.compiled(
             ("map_classify_tpu", model_id, family, B, L, k, cfg_key(cfg)),
             build,
@@ -888,8 +889,8 @@ def finalize(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, A
                 "scores": np.round(np.asarray(vals), 6),
             })
         # Drain-friendly wire shape: [N, k] index/score arrays instead of
-        # 5·N score dicts — ~3× smaller JSON and ~4× faster to serialize,
-        # which is real money when results travel per-shard over HTTP.
+        # 5·N score dicts: a third of the JSON, and no dict per score to
+        # build, when results travel per-shard over HTTP.
         out["indices"] = np.asarray(idx).tolist()
         out["scores"] = np.round(np.asarray(vals), 6).tolist()
         return out

@@ -45,7 +45,8 @@ FAMILY = "decoder_lm"
 DEFAULT_MODEL_ID = "score-lm-default"
 # Tokens a segment program. A document is whole 4,096-token segments and a
 # last one in the smallest bucket that holds the rest. 4,096 x 5,120 keeps
-# every matmul of the published widths MXU-bound (measured: PERF.md) and the
+# every matmul of the published widths MXU-bound (PERF.md §5, the ledger's
+# `brumby-14b-base.score-long` lines since PR 27) and the
 # MLP's [4096, 17408] intermediates at 143 MB; one document a program.
 SEGMENT_BUCKETS = (1024, 4096)
 

@@ -134,9 +134,10 @@ def _build_params(model_id: str, cfg, family: str = "seq2seq"):
 
 
 # Decode-row budget per compiled program: the per-step decode matmuls are
-# [rows, d_model]-thin, so bigger programs fill the MXU better right up to
-# this cap (measured on v5e at B=8192/greedy: 9,132 rows/s as ONE program
-# vs 8,485 as 8 chained B=1024 programs). Beam search multiplies rows in
+# [rows, d_model]-thin, so a bigger program gives the MXU more rows a step.
+# One 8,192-row program against chained 1,024-row ones: not measured on the
+# present tree (PERF.md §7, rows 6-8: scan decode in `map_summarize`; dense
+# KV may fill memory at this cap). Beam search multiplies rows in
 # flight by num_beams (beams flatten into the batch dim, and the KV caches
 # size with B*K), so staging divides the budget by num_beams.
 MAX_DECODE_ROWS = 8192
@@ -215,7 +216,8 @@ def _decode_chunks(runtime, chunks: List, model_id: str, cfg,
 
         # Lengths-on-wire like classify: ship uint16 ids + one length per
         # row, rebuild ids dtype and the [B, L] mask inside the compiled
-        # program — ~4× less host→device traffic per chunk.
+        # program: two bytes an id and one length a row cross the wire, not
+        # two [B, L] int32 arrays.
         def build(Ls=Ls):
             import jax.numpy as jnp
 

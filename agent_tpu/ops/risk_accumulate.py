@@ -24,7 +24,8 @@ from agent_tpu.ops import register_op
 from agent_tpu.utils.errors import bad_input
 
 # Below this many values the host reduce wins; above it the mesh psum path is
-# worth the transfer. Chosen conservatively; bench.py can sweep it.
+# worth the transfer. Chosen conservatively: the crossover is not measured
+# on the present tree (PERF.md §7: no cell exercises the host path).
 DEVICE_THRESHOLD = 4096
 
 

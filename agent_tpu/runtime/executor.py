@@ -24,7 +24,7 @@ from typing import Any, Callable, Dict, Hashable, Tuple
 
 from agent_tpu.obs import trace as obs_trace
 
-# JAX's own monitoring events (jax/_src/dispatch.py, compilation_cache.py):
+# JAX's own monitoring events (jax/_src/dispatch.py, jax/_src/compilation_cache.py):
 # the duration event wraps ``compile_or_get_cached``, so it fires once per
 # executable obtained, compiled or loaded; the plain event marks a load.
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"

@@ -1,17 +1,13 @@
-"""Shared per-op span accounting over controller result bodies.
+"""Which op a controller result body belongs to.
 
-One definition of "device-side span" for drain reports (bench.py and
-scripts/drain_at_scale.py): per-shard dispatch time (``timings.device_ms``)
-plus the deferred device→host fetch wait (``timings.fetch_ms``, paid on the
-pipeline's poster thread). Results without phase timings fall back to their
-``elapsed_ms``. Under pipeline overlap these spans can over- or under-count
-true device busy time — wall-clock throughput is the primary metric; spans
-are the per-op attribution signal.
+Per-op time is attributed from the scraped ``/v1/metrics`` series
+(``agent_tpu.obs.scrape.op_phase_seconds``); what is left here is the one
+lookup a result body still needs.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, Mapping
+from typing import Mapping
 
 
 def result_op(result: Mapping) -> str | None:
@@ -31,22 +27,3 @@ def result_op(result: Mapping) -> str | None:
     ):
         return "map_summarize"
     return None
-
-
-def op_span_ms(results: Iterable[Mapping], ops: Iterable[str]) -> Dict[str, float]:
-    """Sum per-op spans (milliseconds) over result bodies."""
-    spans = {op: 0.0 for op in ops}
-    for r in results:
-        if not isinstance(r, Mapping):
-            continue
-        op = result_op(r)
-        if op not in spans:
-            continue
-        t = r.get("timings", {})
-        if t.get("device_ms") is not None:
-            spans[op] += float(t.get("device_ms", 0.0)) + float(
-                t.get("fetch_ms", 0.0)
-            )
-        else:
-            spans[op] += float(r.get("elapsed_ms", 0.0))
-    return spans

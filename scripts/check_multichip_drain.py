@@ -16,8 +16,9 @@ Four checks on the forced-host CPU shape (4 virtual devices):
 3. **Scaling sanity floor**: fleet-of-2 rows/sec ÷ (2 × 1-chip rows/sec)
    is recorded and must clear a floor — 0.45 with ≥3 host cores (CI), 0.15
    on starved single-core boxes (throughput must at least be conserved).
-   The real ≥0.8 bar at 4 agents lives in ``bench.py``'s ``drain_multichip``
-   leg, gated on core count.
+   A sanity floor on virtual devices, not a speed figure: how a fleet of
+   four pinned agents scales on real chips is not measured on the present
+   tree (PERF.md §7, row 2).
 4. **MPMD pipeline chain**: summarize's encoder and decoder run as separate
    ops on DIFFERENT agents (``summarize_encode`` / ``summarize_decode``)
    chained through controller dependency gating (``after`` +

@@ -22,10 +22,11 @@ over real HTTP, then asserts the acceptance bar end to end:
 5. **Time-series ring** — ``GET /v1/timeseries?name=tasks_total`` serves
    ≥2 samples with non-negative rates; unknown names and pre-sample reads
    return empty series, never errors.
-6. **Overhead** — enabling usage+tsdb+host-profiling costs <3% rows/sec vs
-   all-disabled on the same drain (best-of-N interleaved; the CI assert
-   uses a 10% bar to absorb shared-runner noise, the measured ratio is
-   printed for the record).
+6. **Overhead** — enabling usage+tsdb+host-profiling keeps >=90% of the
+   all-disabled rows/sec on the same drain (best-of-N interleaved; the bar
+   absorbs shared-runner noise, and the ratio the runner read is printed).
+   A guard on the CPU runner: the cost on a chip is not measured on the
+   present tree.
 
 Exit 0 = clean; 1 = problems (one per line). Style sibling of
 ``scripts/check_slo_pipeline.py``: repo-rooted, stdlib-only driver.
@@ -56,8 +57,8 @@ SHARDS_PER_TENANT = 8
 TENANTS = ("tenant-a", "tenant-b")
 
 BENCH_ROUNDS = 3
-# True cost measures ~1-3%; the CI bar absorbs shared-runner noise. The
-# measured ratio prints either way — that number is the record.
+# The CI bar absorbs shared-runner noise; the ratio the runner read
+# prints either way.
 BENCH_TOLERANCE = 0.90
 
 

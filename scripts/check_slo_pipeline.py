@@ -19,8 +19,8 @@ then asserts the acceptance bar end to end:
 5. ``SLO_ENABLED=0`` no-ops the whole path: no tracker, no ``slo_*``
    metric families, health still serves fleet/queue signals;
 6. steady-state overhead: rows/sec over a 1024-row-shard drain with the
-   SLO engine on stays within 10% of off (best-of-3 interleaved — the
-   true cost is ≤2%, the CI bar absorbs shared-runner noise).
+   SLO engine on stays within 10% of off (best-of-3 interleaved; the bar
+   absorbs shared-runner noise. A guard on the CPU runner, not a figure).
 
 Exit 0 = clean; 1 = problems (one per line). Style sibling of
 ``scripts/check_trace_pipeline.py``: repo-rooted, stdlib-only driver.

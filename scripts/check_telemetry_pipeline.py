@@ -17,9 +17,9 @@ Four phases, each over the real surfaces:
    ONE correlated bundle (timeseries + flight recorder + status + health)
    fetchable by id.
 4. **Overhead** — the same drain with the durable store on vs off:
-   rows/sec with telemetry on must stay >=90% of off in CI (the true cost
-   measures <5%; the printed ratio is the record, bench.py tracks it as
-   ``tsdb_overhead_ratio``).
+   rows/sec with telemetry on must stay >=90% of off in CI (a guard on the
+   CPU runner, which prints the ratio it read; the cost on a chip is not
+   measured on the present tree).
 
 Exit 0 = clean; 1 = problems (one per line). Style sibling of
 ``scripts/check_profile_pipeline.py``: repo-rooted, stdlib-only driver.
@@ -51,8 +51,8 @@ from agent_tpu.controller.router import PartitionMap, RouterServer
 SHARD_ROWS = 1024
 SHARDS = 8
 BENCH_ROUNDS = 3
-# True cost measures <5%; the CI bar absorbs shared-runner noise. The
-# measured ratio prints either way — that number is the record.
+# The CI bar absorbs shared-runner noise; the ratio the runner read
+# prints either way.
 BENCH_TOLERANCE = 0.90
 
 

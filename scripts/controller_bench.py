@@ -1,9 +1,8 @@
 #!/usr/bin/env python3
-"""Controller micro-bench — the control-plane ceiling as tracked numbers
-(ISSUE 14; ROADMAP item 3a).
-
-Every data-plane leg got faster for nine PRs while the control plane's
-capacity was never measured. Three legs, no jax, < 30 s:
+"""Controller micro-bench — a CI guard on journal replay being O(live
+state), not O(history) (ISSUE 14). No chip and no jax are involved: the
+figures it prints are the CI runner's and go into no record of speed.
+Three legs, < 30 s:
 
 - **submits/sec** — in-process ``Controller.submit`` throughput against a
   live segmented journal (the production write path: JSON encode + append
@@ -19,9 +18,7 @@ capacity was never measured. Three legs, no jax, < 30 s:
   snapshot replay is not at least N× faster — the ISSUE 14 acceptance
   bar runs this at 5 on a ≥ 50k-event journal in CI.
 
-Emits one flat JSON line (``controller_*`` fields) that ``bench.py``
-embeds in its artifact, so ``scripts/check_bench_regression.py`` trends
-the control plane like every other leg.
+Emits one flat JSON line (``controller_*`` fields).
 """
 
 from __future__ import annotations
@@ -220,8 +217,7 @@ def run_bench(
     replay_live: int = 500,
     partitions: int = 0,
 ) -> Dict[str, Any]:
-    """All legs → one flat dict (the ``controller_*`` bench fields).
-    Importable — ``bench.py``'s controller leg calls this.
+    """All legs → one flat dict (the ``controller_*`` fields).
     ``partitions > 0`` adds the ISSUE 18 aggregate-submits leg (N
     concurrent partition processes) and its ``agg_*`` fields."""
     with tempfile.TemporaryDirectory(prefix="controller_bench_") as tmp:

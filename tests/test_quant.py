@@ -191,6 +191,55 @@ def test_encoder_forward_quantized_tracks_f32(mode):
     assert agree >= 0.9
 
 
+# (mode, how far its logits may stray, in units of the bf16 control's own
+# distance from float32)
+@pytest.mark.parametrize("mode,control_multiple", [("int8", 3.0), ("w8a16", 1.0)])
+def test_seq2seq_decode_step_quantized_within_the_dtype_control(
+        mode, control_multiple):
+    """Two decode steps through the KV cache (the continuous engine's own
+    step): the quantized logits track float32, pick its tokens, and stray
+    from it by no more than a small multiple of what computing the SAME
+    weights in bfloat16 does. Weight-only int8 stays under the control
+    itself: it adds no more than compute-dtype noise to a decode."""
+    from dataclasses import replace
+
+    from agent_tpu.models import seq2seq
+    from agent_tpu.models.tokenizer import BOS_ID
+
+    cfg = seq2seq.Seq2SeqConfig(
+        d_model=64, n_heads=4, n_enc_layers=2, n_dec_layers=2, d_ff=128,
+        max_src_len=64, max_tgt_len=16, dtype="float32",
+    )
+    params = seq2seq.init_params(cfg, model_id="quant-decode-step")
+    rng = np.random.default_rng(3)
+    B, Ls = 16, 32
+    ids = rng.integers(4, cfg.vocab_size, size=(B, Ls)).astype(np.int32)
+    mask = np.ones((B, Ls), dtype=np.int32)
+
+    def second_step_logits(p, c):
+        step = seq2seq.make_positional_step(c)
+        caches = seq2seq.make_cache_factory(c)(B)
+        enc = seq2seq.encode(p, ids, mask, c)
+        bos = jnp.full((B,), BOS_ID, jnp.int32)
+        first, caches = step(p, bos, jnp.zeros((B,), jnp.int32), caches,
+                             enc, mask)
+        tok = jnp.argmax(first, -1).astype(jnp.int32)
+        logits, _ = step(p, tok, jnp.ones((B,), jnp.int32), caches, enc, mask)
+        return np.asarray(logits, np.float32)
+
+    want = second_step_logits(params, cfg)
+    control = second_step_logits(params, replace(cfg, dtype="bfloat16"))
+    got = second_step_logits(
+        quant.quantize_for_family("seq2seq", params, mode), cfg)
+    cos = (want * got).sum(-1) / (
+        np.linalg.norm(want, axis=-1) * np.linalg.norm(got, axis=-1)
+    )
+    assert cos.min() > 0.999
+    assert (want.argmax(-1) == got.argmax(-1)).all()
+    assert np.abs(got - want).max() <= (
+        control_multiple * np.abs(control - want).max())
+
+
 # ---- op contract ----
 
 

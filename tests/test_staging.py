@@ -103,11 +103,11 @@ def _csv(tmp_path, n=96):
 
 
 def _drain(controller, server, runtime, workers, autotune=False,
-           double_buffer=True, depth=2):
+           double_buffer=True, depth=2, name=""):
     from agent_tpu.agent.pipeline import PipelineRunner
 
     cfg = Config(agent=AgentConfig(
-        controller_url=server.url, agent_name=f"pool-{workers}",
+        controller_url=server.url, agent_name=name or f"pool-{workers}",
         tasks=("map_classify_tpu",), idle_sleep_sec=0.0,
     ))
     agent = Agent(config=cfg, session=requests.Session(), runtime=runtime)
@@ -130,30 +130,58 @@ def _drain(controller, server, runtime, workers, autotune=False,
     return agent
 
 
-def test_multi_worker_drain_bit_identical_to_single(runtime, tmp_path):
+# The other side of the comparison, as (chip slice or "" for the module's
+# dp=8 mesh, stage workers, autotune) per agent on the one queue.
+DRAIN_VARIANTS = {
+    "stage_workers_4": [("", 4, True)],
+    "fleet_of_two_one_device_agents": [("0:1", 1, False), ("1:1", 1, False)],
+}
+
+
+def _pinned_runtime(chips):
+    return TpuRuntime(config=DeviceConfig(tpu_disabled=True, chip_slice=chips))
+
+
+@pytest.mark.parametrize("members", list(DRAIN_VARIANTS.values()),
+                         ids=list(DRAIN_VARIANTS))
+def test_multi_worker_drain_bit_identical_to_single(runtime, tmp_path, members):
     """The CI acceptance bar in miniature: 4 stage workers + autotune +
-    double buffering produce exactly the single-worker results."""
+    double buffering, and a fleet of two pinned one-device agents sharing
+    the queue, each produce exactly the results of one single-worker agent
+    on the dp=8 mesh."""
     csv = _csv(tmp_path)
     extra = {"text_field": "text", "allow_fallback": False,
              "result_format": "columnar", "model_config": dict(TINY),
              "topk": 3}
 
     results = {}
-    for workers, autotune in ((1, False), (4, True)):
+    for side, fleet in (("single", [("", 1, False)]), ("other", members)):
         controller = Controller()
         controller.submit_csv_job(csv, total_rows=96, shard_size=12,
                                   map_op="map_classify_tpu",
                                   extra_payload=extra)
         with ControllerServer(controller) as server:
-            _drain(controller, server, runtime, workers, autotune=autotune)
+            threads = [
+                threading.Thread(
+                    target=_drain,
+                    args=(controller, server,
+                          _pinned_runtime(chips) if chips else runtime,
+                          workers),
+                    kwargs={"autotune": autotune, "name": f"{side}-{i}"})
+                for i, (chips, workers, autotune) in enumerate(fleet)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=150)
         assert controller.counts() == {"succeeded": 8}
-        results[workers] = {
+        results[side] = {
             controller.job(j).payload["start_row"]: r
             for j, r in controller.results().items()
         }
-    assert set(results[1]) == set(results[4])
-    for start, want in results[1].items():
-        got = results[4][start]
+    assert set(results["single"]) == set(results["other"])
+    for start, want in results["single"].items():
+        got = results["other"][start]
         assert got["indices"] == want["indices"], f"shard @{start}"
         assert got["scores"] == want["scores"], f"shard @{start}"
 
@@ -239,7 +267,7 @@ def test_prefeed_places_chunks_on_device(runtime):
 
 
 # ---------------------------------------------------------------------------
-# Stage/execute overlap (ISSUE 6 satellite — the drain_at_scale breakdown)
+# Stage/execute overlap (ISSUE 6 satellite: the per-drain breakdown)
 # ---------------------------------------------------------------------------
 
 
@@ -263,15 +291,15 @@ def test_overlap_from_spans_math():
     assert out["stage_total_s"] == pytest.approx(4.0)
     assert out["overlap_ratio"] == pytest.approx(3.0 / 4.0)
     assert out["stage_p50_ms"] == pytest.approx(2000.0)
-    # No closed spans of both kinds → None (drain_at_scale fails loudly).
+    # No closed spans of both kinds → None (a caller must fail loudly).
     assert overlap_from_spans([span("stage", 0, 1)]) is None
     assert overlap_from_spans([]) is None
 
 
 def test_stage_execute_overlap_from_a_real_drain(runtime, tmp_path):
     """End-to-end: a pipelined drain's trace window yields an overlap
-    breakdown via the HTTP trace endpoints — the exact call
-    scripts/drain_at_scale.py makes (and fails loudly on None)."""
+    breakdown via the HTTP trace endpoints (None means the trace path is
+    down, and a caller that promised the breakdown fails loudly on it)."""
     from agent_tpu.obs.scrape import stage_execute_overlap
 
     csv = _csv(tmp_path, n=48)
