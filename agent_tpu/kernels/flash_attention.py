@@ -420,11 +420,23 @@ def _whole_row_call(q, k, v, mask3d, seg_q=None, *, n_heads: int, rows: int,
     a block and an agent builds one program a tenant, and tracing and
     lowering the kernel's body is seconds of Python each time. A jitted
     callee is traced once a shape in the process and lowered once a
-    program."""
-    B, L, HD = q.shape
+    program.
+
+    With ``k`` and ``v`` None, ``q`` is the one [B, L, 3*H*D] array a fused
+    Q, K, V matmul writes, columns ``[Q | K | V]``: it goes in three times,
+    and the three operands are column blocks of it (the same block shape,
+    the index maps a third of the lanes apart). No copy takes it apart."""
+    fused = k is None
+    B, L, lanes = q.shape
+    HD = lanes // 3 if fused else lanes
     d_head = HD // n_heads
-    block = pl.BlockSpec((rows, L, groups * _LANES), lambda b, g: (b, 0, g),
-                         memory_space=pltpu.VMEM)
+    shape = (rows, L, groups * _LANES)
+    steps = HD // (groups * _LANES)      # lane-group steps an operand spans
+
+    def operand(nth):
+        return pl.BlockSpec(shape, lambda b, g: (b, 0, nth * steps + g),
+                            memory_space=pltpu.VMEM)
+
     kernel = functools.partial(
         _whole_row_kernel, scale=1.0 / float(np.sqrt(d_head)), d_head=d_head,
         rows=rows, groups=groups, segments=seg_q is not None,
@@ -438,10 +450,11 @@ def _whole_row_call(q, k, v, mask3d, seg_q=None, *, n_heads: int, rows: int,
                                        memory_space=pltpu.VMEM))
     return pl.pallas_call(
         kernel,
-        grid=(B // rows, HD // (groups * _LANES)),
-        in_specs=[block, block, block, *mask_specs],
-        out_specs=block,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(B // rows, steps),
+        in_specs=[*(operand(nth if fused else 0) for nth in range(3)),
+                  *mask_specs],
+        out_specs=operand(0),
+        out_shape=jax.ShapeDtypeStruct((B, L, HD), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_WHOLE_ROW_VMEM_LIMIT,
@@ -452,13 +465,13 @@ def _whole_row_call(q, k, v, mask3d, seg_q=None, *, n_heads: int, rows: int,
             transcendentals=B * n_heads * L * L,
         ),
         interpret=interpret,
-    )(q, k, v, *masks)
+    )(*((q, q, q) if fused else (q, k, v)), *masks)
 
 
 def whole_row_attention(
-    q: jax.Array,      # [B, L, H*D]
-    k: jax.Array,      # [B, L, H*D]
-    v: jax.Array,      # [B, L, H*D]
+    q: jax.Array,      # [B, L, H*D], or [B, L, 3*H*D] = [Q | K | V]
+    k: Optional[jax.Array],      # [B, L, H*D]; None: ``q`` holds all three
+    v: Optional[jax.Array],      # [B, L, H*D]; None with ``k``
     mask: Optional[jax.Array],   # [B|1, 1, 1, L] key-padding mask (1 = attend)
     *,
     n_heads: int,
@@ -473,6 +486,11 @@ def whole_row_attention(
     caller asks it first. f32 scores and statistics, bf16 into the PV matmul,
     every key of every real row attended, 0 (not NaN) for a row with no key.
 
+    Two operand forms, one kernel: three [B, L, H*D] arrays, or (``k`` and
+    ``v`` None) the ONE [B, L, 3*H*D] array a fused Q, K, V projection
+    writes, columns ``[Q | K | V]``, head-major inside each, which the call
+    reads as three column blocks (:func:`_whole_row_call`).
+
     With ``segment_ids`` (the packed layout of ``ops/_model_common.py:
     pack_padded_chunk``: several short rows end to end in one program row)
     ``mask`` is not read and attention is block-diagonal: a query attends
@@ -483,7 +501,8 @@ def whole_row_attention(
 
     ``rows_per_step`` / ``groups_per_step`` override the tile geometry the
     shapes give (:func:`_whole_row_tiles`) — for sweeps on the chip."""
-    B, L, HD = q.shape
+    B, L, lanes = q.shape
+    HD = lanes // 3 if k is None else lanes
     rows, groups = _whole_row_tiles(B, L, HD // _LANES)
     _note_selection("whole_row")
     if segment_ids is not None:
@@ -1221,8 +1240,9 @@ def make_flash_attention_trainable(mesh, interpret: Optional[bool] = None):
 class WholeRowAttention:
     """The lane-dense entry an ``attn_fn`` declares as ``attn_fn.whole_row``:
     :meth:`selects` is :func:`selects_whole_row` on what one chip of the mesh
-    sees, and the call is :func:`whole_row_attention` (under ``shard_map`` on a
-    mesh: batch over ``dp``, the head-major lanes over ``tp``)."""
+    sees, and the call is :func:`whole_row_attention` in either operand form
+    (under ``shard_map`` on a mesh: batch over ``dp``, the head-major lanes
+    over ``tp``)."""
 
     def __init__(self, mesh, interpret: Optional[bool] = None):
         from jax.sharding import PartitionSpec as P
@@ -1235,10 +1255,7 @@ class WholeRowAttention:
         self._shard = None
         if mesh.size > 1:
             self._shard = functools.partial(
-                jax.shard_map, mesh=mesh,
-                in_specs=(P("dp", None, "tp"),) * 3
-                + (P("dp", None, None, None),),
-                out_specs=P("dp", None, "tp"),
+                jax.shard_map, mesh=mesh, out_specs=P("dp", None, "tp"),
                 check_vma=False,  # as _make_mesh_wrapper: no vma on pallas
             )
 
@@ -1259,19 +1276,31 @@ class WholeRowAttention:
         if self._shard is None:
             return self._kernel(q, k, v, mask, n_heads=n_heads,
                                 segment_ids=segment_ids)
+        from jax.sharding import PartitionSpec as P
+
         from agent_tpu.models.layers import materialize_key_padding_mask
 
         B, L, _ = q.shape
-        if segment_ids is not None:
-            # The ids ride in the mask's place and its spec: [B, 1, 1, L].
-            inner = lambda q, k, v, seg: self._kernel(  # noqa: E731
-                q, k, v, None, n_heads=n_heads // self.tp,
-                segment_ids=seg[:, 0, 0, :])
-            return self._shard(inner)(q, k, v, segment_ids[:, None, None, :])
-        inner = functools.partial(self._kernel, n_heads=n_heads // self.tp)
-        return self._shard(inner)(
-            q, k, v, materialize_key_padding_mask(mask, B, L)
-        )
+        if k is None and self.tp > 1:
+            # [Q | K | V] columns do not split by heads: three arrays, whose
+            # head-major lanes do. (No fused leaf is built for such a mesh.)
+            q, k, v = jnp.split(q, 3, axis=-1)
+        operands = (q,) if k is None else (q, k, v)
+        # The segment ids ride in the mask's place and its spec: [B, 1, 1, L].
+        rider = (segment_ids[:, None, None, :] if segment_ids is not None
+                 else materialize_key_padding_mask(mask, B, L))
+
+        def inner(*args):
+            *qkv, rider = args
+            qkv += [None] * (3 - len(qkv))
+            if segment_ids is not None:
+                return self._kernel(*qkv, None, n_heads=n_heads // self.tp,
+                                    segment_ids=rider[:, 0, 0, :])
+            return self._kernel(*qkv, rider, n_heads=n_heads // self.tp)
+
+        return self._shard(
+            inner, in_specs=(P("dp", None, "tp"),) * len(operands)
+            + (P("dp", None, None, None),))(*operands, rider)
 
 
 def make_flash_attention(mesh, interpret: Optional[bool] = None):

@@ -160,20 +160,60 @@ def whole_row_entry(attn_fn, batch: int, lq: int, lk: int, n_heads: int,
     return None
 
 
+def fuse_qkv(p: Params) -> Params:
+    """An attention subtree with its ``wq``, ``wk``, ``wv`` ([d, H, E] each)
+    as ONE leaf ``wqkv`` [d, 3 * H * E]: columns ``[Q | K | V]``, head-major
+    inside each as the three were, same dtype, same bytes. A SERVING layout,
+    made once a model where its weights are built
+    (``ops/_model_common.py: maybe_fuse_qkv_params``); ``init_attention``,
+    checkpoints and the ``tp`` specs keep the three."""
+    d_model = p["wq"].shape[0]
+    fused = {k: w for k, w in p.items() if k not in ("wq", "wk", "wv")}
+    fused["wqkv"] = jnp.concatenate(
+        [p[k].reshape(d_model, -1) for k in ("wq", "wk", "wv")], axis=1)
+    return fused
+
+
+def qkv_leaves(p: Params) -> Tuple[Any, Any, Any]:
+    """``(wq, wk, wv)`` of an attention subtree in either layout: its own
+    three leaves, or the column blocks of ``wqkv`` as [d, H, E] views (H and
+    E are ``wo``'s). Slices of the WEIGHT: where a fused subtree meets a call
+    that wants the three (a cache, cross-attention, a length the whole-row
+    predicate leaves dense)."""
+    if "wqkv" not in p:
+        return p["wq"], p["wk"], p["wv"]
+    H, E, _ = p["wo"].shape
+    w = p["wqkv"]
+    return tuple(
+        w[:, i * H * E:(i + 1) * H * E].reshape(w.shape[0], H, E)
+        for i in range(3))
+
+
+def _note_qkv(form: str) -> None:
+    from agent_tpu.obs.trace import record_attention_qkv
+
+    record_attention_qkv(form)
+
+
 def _attention_lane_dense(p: Params, x_q, x_kv, mask, dtype, attn_fn,
                           segment_ids=None):
     """:func:`attention` without a cache on [B, L, H*D] operands — the
     projections' own layout, H*D in the lanes — where ``attn_fn`` takes them;
     None where it does not (or a leaf is quantized: those projections write
     [B, H, L, E]). With ``segment_ids`` the entry is asked for its segment
-    form and reads the ids, not the mask."""
+    form and reads the ids, not the mask.
+
+    A fused subtree (``"wqkv" in p``, :func:`fuse_qkv`) in self-attention
+    runs Q, K and V as ONE matmul, and the entry takes its [B, L, 3*H*D]
+    result whole, as column blocks: the block's activations are read once
+    and nothing copies the result apart."""
     from agent_tpu.models import quant
 
-    leaves = [p[name] for name in ("wq", "wk", "wv", "wo")]
-    if any(quant.is_quantized(w) or quant.is_weight_only(w) for w in leaves):
+    if any(quant.is_quantized(w) or quant.is_weight_only(w)
+           for w in p.values()):
         return None
-    wq, wk, wv, wo = leaves
-    d_model, H, E = wq.shape
+    wo = p["wo"]
+    H, E, d_model = wo.shape
     B, Lq, _ = x_q.shape
     entry = whole_row_entry(attn_fn, B, Lq, x_kv.shape[1], H, E, mask, dtype,
                             segments=segment_ids is not None)
@@ -184,11 +224,17 @@ def _attention_lane_dense(p: Params, x_q, x_kv, mask, dtype, attn_fn,
         # "bld,dhe->blhe" as the 2-D matmul it is: XLA lays a 4-D result out
         # with L in the lanes and copies it back; a [B*L, H*E] one stays put.
         y = jnp.dot(x.astype(dtype).reshape(-1, d_model),
-                    w.astype(dtype).reshape(d_model, H * E))
-        return y.reshape(B, x.shape[1], H * E)
+                    w.astype(dtype).reshape(d_model, -1))
+        return y.reshape(B, x.shape[1], -1)
 
-    out = entry(proj(wq, x_q), proj(wk, x_kv), proj(wv, x_kv), mask,
-                n_heads=H, segment_ids=segment_ids)
+    if "wqkv" in p and x_q is x_kv:
+        _note_qkv("fused")
+        operands = (proj(p["wqkv"], x_q), None, None)
+    else:
+        _note_qkv("separate")
+        wq, wk, wv = qkv_leaves(p)
+        operands = (proj(wq, x_q), proj(wk, x_kv), proj(wv, x_kv))
+    out = entry(*operands, mask, n_heads=H, segment_ids=segment_ids)
     y = jnp.dot(out.reshape(-1, H * E),
                 wo.astype(dtype).reshape(H * E, d_model))
     return y.reshape(B, Lq, d_model)
@@ -246,9 +292,11 @@ def attention(
                                            segment_ids)
         if lane_dense is not None:
             return lane_dense, None
-    q = _proj_in(p["wq"], x_q, dtype)
-    k = _proj_in(p["wk"], x_kv, dtype)
-    v = _proj_in(p["wv"], x_kv, dtype)
+    _note_qkv("separate")
+    wq, wk, wv = qkv_leaves(p)
+    q = _proj_in(wq, x_q, dtype)
+    k = _proj_in(wk, x_kv, dtype)
+    v = _proj_in(wv, x_kv, dtype)
 
     if cache is not None:
         assert cache_index is not None

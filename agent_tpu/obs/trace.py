@@ -558,6 +558,21 @@ def record_attention_block(path: str) -> None:
            path=path)
 
 
+def record_attention_qkv(form: str) -> None:
+    """One attention block TRACED with its Q, K, V projections in ``form``:
+    ``fused`` (one matmul on the block's ``wqkv`` leaf, its result handed to
+    the whole-row kernel as column blocks: ``models/layers.py:
+    _attention_lane_dense``) or ``separate`` (three, every other call).
+    Beside :func:`record_attention_block`: ticks while a program is traced,
+    once an attention call, never when it runs."""
+    _count("attention_qkv_traced_total",
+           "Attention blocks traced into XLA programs, by the form of their "
+           "Q, K, V projections: one matmul on a fused weight leaf (fused) "
+           "or three (separate); ticks while a program is traced, not when "
+           "it runs",
+           form=form)
+
+
 def record_classify_shard(real_tokens: int, token_slots: int,
                           packed: bool) -> None:
     """One classify shard DISPATCHED: the tokens its rows hold, the token

@@ -187,6 +187,54 @@ def maybe_quantize_specs(specs, family: str, cfg):
     return specs
 
 
+def _fuses_qkv(family: str, cfg, tp: int) -> bool:
+    """Is ``[Q | K | V]`` the right column order for this placement? By what
+    the build can see: the in-house encoder's bias-free projections (the
+    pretrained ``bert`` family has its own, with biases), unquantized leaves
+    (quantized ones keep [d, H, E] tables and the dense path), and no ``tp``
+    axis to split by heads (``P(None, "tp")`` on the fused columns would
+    hand a chip all of Q and none of V)."""
+    return (family == "encoder" and getattr(cfg, "quant", "none") == "none"
+            and tp <= 1)
+
+
+def maybe_fuse_qkv_params(params, family: str, cfg, tp: int):
+    """Build-time twin of :func:`maybe_quantize_params` for the SERVING
+    layout of a block's self-attention projections: ``wq``, ``wk``, ``wv``
+    become ONE leaf ``wqkv`` (``models.layers.fuse_qkv``: same bytes, same
+    stored dtype) where :func:`_fuses_qkv` says so, once a model, outside
+    any program; ``layers.attention`` then reads a block's activations once
+    (one matmul for the three). Elsewhere the tree is returned as built.
+
+    IN PLACE, block by block: the tree is the build's own, and a block's
+    three leaves are let go as its fused leaf is made, so the device never
+    holds a model's projections twice (12 tenants' builds would each peak
+    85 MB over what stays resident)."""
+    if not _fuses_qkv(family, cfg, tp):
+        return params
+    from agent_tpu.models.layers import fuse_qkv
+
+    for block in params["blocks"]:
+        block["attn"] = fuse_qkv(block["attn"])
+    return params
+
+
+def maybe_fuse_qkv_specs(specs, family: str, cfg, tp: int):
+    """Spec-tree twin of :func:`maybe_fuse_qkv_params`, so ``get_params``'
+    trees stay congruent where ``ep`` > 1 places by specs: the fused leaf
+    replicates (it exists only where no ``tp`` axis splits it)."""
+    if not _fuses_qkv(family, cfg, tp):
+        return specs
+    from jax.sharding import PartitionSpec as P
+
+    def fuse(attn):
+        kept = {k: s for k, s in attn.items() if k not in ("wq", "wk", "wv")}
+        return {**kept, "wqkv": P()}
+
+    return {**specs, "blocks": [
+        {**block, "attn": fuse(block["attn"])} for block in specs["blocks"]]}
+
+
 def cfg_key(cfg) -> Tuple:
     """Hashable fingerprint of a frozen config dataclass — goes into both the
     params-store key and the executable-cache key so distinct configs never

@@ -105,7 +105,10 @@ def _resolve_model_id(payload: Dict[str, Any]) -> str:
     return resolve_model_id(payload, "TPU_MODEL_PATH", DEFAULT_MODEL_ID)
 
 
-def _build_params(model_id: str, cfg, family: str = "encoder"):
+def _build_params(model_id: str, cfg, family: str = "encoder", tp: int = 1):
+    """A model's weights as the agent holds them: the canonical tree of its
+    family, then the build-time serving transforms (quantized tables; the
+    fused Q, K, V leaf where a mesh whose ``tp`` axis is ``tp`` allows)."""
     import os
 
     if family == "bert":
@@ -122,9 +125,13 @@ def _build_params(model_id: str, cfg, family: str = "encoder"):
             params = encoder.load_npz(model_id, cfg)
         else:
             params = encoder.init_params(cfg, model_id=model_id)
-    from agent_tpu.ops._model_common import maybe_quantize_params
+    from agent_tpu.ops._model_common import (
+        maybe_fuse_qkv_params,
+        maybe_quantize_params,
+    )
 
-    return maybe_quantize_params(params, family, cfg)
+    return maybe_fuse_qkv_params(
+        maybe_quantize_params(params, family, cfg), family, cfg, tp)
 
 
 def _collect_sequences(payload: Dict[str, Any], cfg) -> Tuple[List, str, bool]:
@@ -300,16 +307,22 @@ def _execute_chunks(
     model_mod = _model_module(family)
     specs = (bert_param_specs if family == "bert"
              else encoder_param_specs)(cfg)
-    from agent_tpu.ops._model_common import PackedChunk, maybe_quantize_specs
+    from agent_tpu.ops._model_common import (
+        PackedChunk,
+        maybe_fuse_qkv_specs,
+        maybe_quantize_specs,
+    )
 
-    specs = maybe_quantize_specs(specs, family, cfg)
+    tp = runtime.axis_size("tp")
+    specs = maybe_fuse_qkv_specs(
+        maybe_quantize_specs(specs, family, cfg), family, cfg, tp)
 
     # On a tp>1 mesh the weights land sharded (Megatron-style specs) and XLA
     # inserts the tp collectives in the forward — the serving path for models
     # that exceed one chip's HBM, not just the train path.
     params = runtime.get_params(
         f"{model_id}#{family}#{hash(cfg_key(cfg)) & 0xFFFFFFFF:08x}",
-        lambda: _build_params(model_id, cfg, family),
+        lambda: _build_params(model_id, cfg, family, tp),
         specs=specs,
     )
     attn_fn = runtime.attention_fn()  # ring over sp when the mesh has one

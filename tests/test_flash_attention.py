@@ -391,14 +391,22 @@ def test_whole_row_matches_dense_and_float32(L, H, D):
     assert err <= np.abs(dense[real] - f32[real]).max() * 1.5 + 1e-3
 
 
+@pytest.mark.parametrize("operands", ["three", "column_blocks"])
 @pytest.mark.parametrize("rows,groups", [(1, 1), (2, 3), (4, 6)])
-def test_whole_row_tile_geometry_does_not_change_the_answer(rows, groups):
+def test_whole_row_tile_geometry_does_not_change_the_answer(rows, groups,
+                                                            operands):
+    """``column_blocks``: the ONE [B, L, 3*H*D] array ``[Q | K | V]`` in the
+    three operands' place; the index maps find each a third of the lanes on,
+    whatever the lane groups a step takes."""
     q, k, v, mask, _ = _lane_dense_case(4, 64, 12, 64, seed=9)
     want = fa_mod.whole_row_attention(q, k, v, mask, n_heads=12,
                                       interpret=True)
+    if operands == "column_blocks":
+        q, k, v = jnp.concatenate([q, k, v], axis=-1), None, None
     got = fa_mod.whole_row_attention(
         q, k, v, mask, n_heads=12, rows_per_step=rows,
         groups_per_step=groups, interpret=True)
+    assert got.shape == want.shape
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -483,20 +491,41 @@ def _ids_mask(B, L, vocab, seed):
     return ids, jnp.asarray(mask)
 
 
+@pytest.mark.parametrize("tree", ["three_leaf", "fused_qkv"])
 @pytest.mark.parametrize("L", [64, 128])
-def test_encoder_forward_whole_row_within_the_benchmark_limits(L):
+def test_encoder_forward_whole_row_within_the_benchmark_limits(L, tree):
+    """``fused_qkv``: the serving layout of the same weights (one ``wqkv``
+    leaf a block): one Q, K, V matmul, its result into the kernel as column
+    blocks. Every output column is the dot product it was, so the kernel's
+    answers are the three-leaf tree's, well inside the bf16 tolerance."""
     from agent_tpu.models import encoder
+    from agent_tpu.ops._model_common import maybe_fuse_qkv_params
 
     cfg = encoder.EncoderConfig(
         vocab_size=260, d_model=256, n_heads=4, n_layers=2, d_ff=512,
         max_len=128, n_classes=50,
     )
-    params = encoder.init_params(cfg, model_id="whole-row-test")
+    three_leaf = params = encoder.init_params(cfg, model_id="whole-row-test")
+    if tree == "fused_qkv":     # fused in place: a tree of its own
+        params = maybe_fuse_qkv_params(
+            encoder.init_params(cfg, model_id="whole-row-test"), "encoder",
+            cfg, 1)
     ids, mask = _ids_mask(4, L, 260, seed=L)
     before = dict(fa_mod.SELECTION_COUNTS)
     fused = encoder.forward(params, ids, mask, cfg, attn_fn=_fused_attn_fn())
     assert (fa_mod.SELECTION_COUNTS["whole_row"]
             - before.get("whole_row", 0)) == cfg.n_layers
+    if tree == "fused_qkv":
+        np.testing.assert_allclose(
+            np.asarray(fused), np.asarray(encoder.forward(
+                three_leaf, ids, mask, cfg, attn_fn=_fused_attn_fn())),
+            atol=2e-2)
+        # The XLA path in float32: bit for bit.
+        f32 = cfg.scaled(dtype="float32")
+        np.testing.assert_array_equal(
+            np.asarray(encoder.forward(params, ids, mask, f32)),
+            np.asarray(encoder.forward(three_leaf, ids, mask, f32)))
+        params = three_leaf     # the controls below: the canonical tree
     dense = encoder.forward(params, ids, mask, cfg)
     f32 = encoder.forward(params, ids, mask, cfg.scaled(dtype="float32"))
     _check_limits(fused, dense)

@@ -140,9 +140,14 @@ CFG = encoder.EncoderConfig(d_model=128, n_heads=2, n_layers=2, d_ff=256,
 
 
 def _forward_pair(cfg, lengths, L, seed=0, attn_fn=layers.dot_product_attention,
-                  edit=None):
-    """(padded logits, packed logits in row order) of the same rows."""
+                  edit=None, tree="three_leaf"):
+    """(padded logits, packed logits in row order) of the same rows; with
+    ``tree="fused"`` on the serving layout of the same weights (one ``wqkv``
+    leaf a block, ``_model_common.maybe_fuse_qkv_params``)."""
     params = encoder.init_params(cfg, model_id=f"pack-{seed}")
+    if tree == "fused":
+        params = mc.maybe_fuse_qkv_params(params, "encoder", cfg, 1)
+        assert "wqkv" in params["blocks"][0]["attn"]
     ids, full = _padded(lengths, L, seed=seed)
     if edit is not None:
         edit(ids)
@@ -167,13 +172,18 @@ def _forward_pair(cfg, lengths, L, seed=0, attn_fn=layers.dot_product_attention,
     return np.asarray(padded), np.asarray(packed), used
 
 
+@pytest.mark.parametrize("tree", ["three_leaf", "fused"])
 @pytest.mark.parametrize("seed", [0, 1, 2])
-def test_forward_packed_equals_padded_float32(seed):
+def test_forward_packed_equals_padded_float32(seed, tree):
     lengths = np.random.default_rng(seed).integers(0, 65, size=40)
     lengths[:3] = (0, 1, 64)
-    padded, packed, used = _forward_pair(CFG, lengths, 64, seed=seed)
+    padded, packed, used = _forward_pair(CFG, lengths, 64, seed=seed, tree=tree)
     assert used < len(lengths)
     np.testing.assert_allclose(packed, padded, atol=1e-5, rtol=1e-5)
+    if tree == "fused":     # the XLA path: bit for bit the three-leaf answers
+        for got, want in zip((padded, packed),
+                             _forward_pair(CFG, lengths, 64, seed=seed)):
+            np.testing.assert_array_equal(got, want)
 
 
 def _fused_attn_fn():
@@ -185,13 +195,16 @@ def _fused_attn_fn():
                                 interpret=True)
 
 
-@pytest.mark.parametrize("attn", ["dense", "whole_row"])
-def test_forward_packed_equals_padded_bfloat16(attn):
+@pytest.mark.parametrize("attn, tree", [
+    ("dense", "three_leaf"), ("whole_row", "three_leaf"),
+    ("dense", "fused"), ("whole_row", "fused"),
+])
+def test_forward_packed_equals_padded_bfloat16(attn, tree):
     cfg = CFG.scaled(dtype="bfloat16")
     lengths = np.random.default_rng(7).integers(1, 65, size=24)
     before = fa.SELECTION_COUNTS.get("whole_row", 0)
     padded, packed, _ = _forward_pair(
-        cfg, lengths, 64, seed=7,
+        cfg, lengths, 64, seed=7, tree=tree,
         attn_fn=_fused_attn_fn() if attn == "whole_row"
         else layers.dot_product_attention)
     if attn == "whole_row":     # both programs, every block

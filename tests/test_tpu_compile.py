@@ -108,6 +108,28 @@ def _whole_row(batch):
     return build
 
 
+def _whole_row_column_blocks(batch, segments=False):
+    """The whole-row kernel on the ONE [B, L, 3*H*D] array a fused Q, K, V
+    matmul writes (``models/layers.py: fuse_qkv``), read as three column
+    blocks: the same block shape, the index maps a third of the lanes apart.
+    Under a key-padding mask or under segment ids."""
+    def build(chip, L, D):
+        qkv = jax.ShapeDtypeStruct((batch, L, 3 * H * D), jnp.bfloat16,
+                                   sharding=chip)
+        if segments:
+            rider = jax.ShapeDtypeStruct((batch, L), jnp.int32, sharding=chip)
+            fn = lambda x, s: fa.whole_row_attention(  # noqa: E731
+                x, None, None, None, n_heads=H, segment_ids=s,
+                interpret=False)
+        else:
+            rider = jax.ShapeDtypeStruct((batch, 1, 1, L), jnp.int32,
+                                         sharding=chip)
+            fn = lambda x, m: fa.whole_row_attention(  # noqa: E731
+                x, None, None, m, n_heads=H, interpret=False)
+        return fn, (qkv, rider)
+    return build
+
+
 def _whole_row_segments(batch):
     """The whole-row kernel under segment ids at a packed slice's own shape
     (``ops/_model_common.py: packed_slice_rows``): the ids ride twice, as
@@ -136,6 +158,10 @@ CASES = [
     ("whole_row_segments_64x64", _whole_row_segments(64), 64, 64, 1),   # drain-short, packed
     ("whole_row_segments_32x128", _whole_row_segments(32), 128, 64, 1),
     ("whole_row_segments_8x512", _whole_row_segments(8), 512, 64, 1),
+    ("whole_row_column_blocks_256x512", _whole_row_column_blocks(256), 512, 64, 1),   # drain-long, fused Q, K, V
+    ("whole_row_column_blocks_segments_64x64",
+     _whole_row_column_blocks(64, segments=True), 64, 64, 1),   # drain-short, packed, fused
+    ("whole_row_column_blocks_8x128_d128", _whole_row_column_blocks(8), 128, 128, 1),
 ]
 
 
@@ -154,16 +180,23 @@ def test_kernel_compiles_for_v5e(v5e, build, L, D, n_kernels):
         assert fa.SELECTION_COUNTS.get("dense", 0) == before.get("dense", 0)
 
 
-def test_packed_slice_program_compiles_for_v5e(v5e):
+@pytest.mark.parametrize("tree", ["three_leaf", "fused_qkv"])
+def test_packed_slice_program_compiles_for_v5e(v5e, tree):
     """The classify op's packed slice program (``encoder.pooled_segments``:
     segment ids and positions rebuilt on the device, the position gather,
     the whole-row kernel under segment ids, the per-segment pool) at
     ``bert-base.drain-short``'s own slice shape, 64 program rows of 64, at
-    BERT-base widths (2 of the 12 layers: the blocks are alike)."""
+    BERT-base widths (2 of the 12 layers: the blocks are alike). On the
+    canonical tree and on the serving layout the op holds (``fused_qkv``:
+    one ``wqkv`` leaf a block), where the compiled program must hand the
+    kernel ONE projected array three times and copy no part of it."""
+    import re
+
     from agent_tpu.kernels import make_flash_attention
     from agent_tpu.models import encoder
     from agent_tpu.ops._model_common import (
         PACKED_MIN_SEGMENT,
+        maybe_fuse_qkv_params,
         packed_slice_rows,
     )
     from agent_tpu.runtime.mesh import build_mesh
@@ -175,9 +208,15 @@ def test_packed_slice_program_compiles_for_v5e(v5e):
     rows, G = packed_slice_rows(L, 1), L // PACKED_MIN_SEGMENT
     assert (rows, G) == (64, 8)
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+
+    def build():
+        params = encoder.init_params(cfg)
+        if tree == "fused_qkv":
+            params = maybe_fuse_qkv_params(params, "encoder", cfg, 1)
+        return params
+
     params = jax.tree_util.tree_map(
-        lambda leaf: sd(leaf.shape, leaf.dtype),
-        jax.eval_shape(lambda: encoder.init_params(cfg)))
+        lambda leaf: sd(leaf.shape, leaf.dtype), jax.eval_shape(build))
     attn_fn = make_flash_attention(
         build_mesh([v5e.devices[0]], {"dp": 1}), interpret=False)
 
@@ -187,10 +226,28 @@ def test_packed_slice_program_compiles_for_v5e(v5e):
     before = dict(fa.SELECTION_COUNTS)
     compiled = jax.jit(run_fwd).lower(
         params, sd((rows, L), jnp.int32), sd((rows, G), jnp.int32)).compile()
-    assert compiled.as_text().count("tpu_custom_call") == cfg.n_layers
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == cfg.n_layers
     assert (fa.SELECTION_COUNTS["whole_row"] - before.get("whole_row", 0)
             == cfg.n_layers)
     assert fa.SELECTION_COUNTS.get("dense", 0) == before.get("dense", 0)
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    same_thrice = [re.search(r" custom-call\((%[\w.-]+), \1, \1, ", line)
+                   for line in kernels]
+    if tree == "three_leaf":
+        assert not any(same_thrice)
+        return
+    assert len(kernels) == cfg.n_layers and all(same_thrice)
+    # What the fused matmul wrote ([64, 64, 2304] bf16) is no operand and no
+    # result of a copy or a slice: XLA moves WEIGHTS about (f32 [768, 2304],
+    # prefetched in row slices), never the projected activations.
+    wide = re.compile(r"bf16\[(64,64|4096),2304\]")
+    movers = [line for line in text.splitlines() if re.search(
+        r" (copy|copy-start|slice|slice-start|dynamic-slice|concatenate)\(",
+        line)]
+    assert movers and not [line for line in movers if wide.search(line)]
+    assert len(re.findall(r" = bf16\[64,64,2304\]\S* fusion\(", text)) == cfg.n_layers
 
 
 @pytest.mark.parametrize("carried", [False, True],
