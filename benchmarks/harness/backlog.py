@@ -233,7 +233,8 @@ def run(ctx: Dict[str, Any], *, make_rows: Callable[[int], List[Any]],
         controller = procs.ControllerProcess(os.path.join(out, "controller.log"))
         url = controller.url
         clock = PostClock()
-        agent = stack.AgentStack(controller.url, config["op"]["tasks"])
+        agent = stack.AgentStack(controller.url, config["op"]["tasks"],
+                                 traffic.get("agent"))
         agent.agent.post_session_factory = clock.factory
         t_stack = time.time()
 

@@ -4,17 +4,21 @@
 row and another check.
 
 Rows are documents of token ids: their lengths the quantiles of the traffic
-file's ``doc_tokens`` in seeded order, their ids Zipf-distributed over the
-whole vocabulary through a seeded permutation. ``correct``: a seeded sample
-of the documents the window answered goes through the plain float32
-reference (``benchmarks/reference/<config.reference>.py``), which makes the
-weights itself from the model id; their ``block_logprob_sums`` are compared.
+file's ``doc_tokens`` in the order its ``order_seed`` gives, their ids
+Zipf-distributed over the whole vocabulary through a seeded permutation.
+``correct``: a seeded sample of the documents the window answered goes
+through the plain float32 reference
+(``benchmarks/reference/<config.reference>.py``), which makes the weights
+itself from the model id; their ``block_logprob_sums`` are compared.
 
 Beside the keys the ``.drain`` and ``.setup`` readers read, the ``run``
 record carries for the language model's own readers ``lm_needed``
-(operations and bytes a document needs, ``harness/lm_flops.py``) and,
-traced, ``op_times`` (``harness/op_times.py`` over the readers'
-``OP_PATTERNS``)."""
+(operations and bytes a document needs: ``mean_needed(model, lengths)`` of
+the counter the configuration file names under ``needed_work``, found by
+that name as the reference is; ``flops``, ``head_flops`` and ``head_bytes``
+are what ``lm_roofline`` and ``loss_head_roofline`` read of it whatever the
+family, any further key is the family's own) and, traced, ``op_times``
+(``harness/op_times.py`` over the readers' ``OP_PATTERNS``)."""
 
 from __future__ import annotations
 
@@ -24,16 +28,16 @@ from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
-from benchmarks.harness import (backlog, lm_flops, manifest, op_times,
-                                schedule, trace_reduce)
+from benchmarks.harness import (backlog, manifest, op_times, schedule,
+                                trace_reduce)
 from benchmarks.harness.stack import check, emit
 
 
 def documents(traffic: Dict[str, Any], vocab_size: int, seed: int, n: int
               ) -> List[np.ndarray]:
     """``n`` documents of token ids, no two alike."""
-    lengths = schedule.size_set(traffic["doc_tokens"], n)
-    schedule.rng_of(seed, "order").shuffle(lengths)
+    lengths = schedule.ordered_sizes(traffic["doc_tokens"], n,
+                                     traffic["order_seed"])
     spec = traffic["token_ids"]
     check(spec["dist"] == "zipf", f"unknown id distribution {spec['dist']!r}")
     weight = 1.0 / np.arange(1, vocab_size + 1) ** float(spec["exponent"])
@@ -112,7 +116,8 @@ def run_cell(ctx: Dict[str, Any]) -> Dict[str, Any]:
         answer_keys=("n_tokens", "logprob_sum", "block_logprob_sums"))
     docs, accepted = run["backlog"], run["accepted"]
     lengths = [int(n) for _, _, b in accepted for n in b["n_tokens"]]
-    run["lm_needed"] = lm_flops.mean_needed(config["model"], lengths)
+    run["lm_needed"] = manifest.load_needed_work(
+        config["needed_work"]).mean_needed(config["model"], lengths)
     run["mean_flops_per_row"] = run["lm_needed"]["flops"]
     run["op_times"] = None
     emit("window", **run["window_record"])

@@ -3,10 +3,15 @@ configuration, one traffic mix or one per-layer metric sits in a file of its
 own, found here by the name in the manifest:
 
     benchmarks/configs/<config>.json        sizes, dtype, op payload, control
-    benchmarks/traffic/<mix>.json           kind and its parameters
+    benchmarks/traffic/<mix>.json           kind and its parameters (among them
+                                            ``order_seed``: the order of its sizes;
+                                            ``agent``: the agent's knobs that the
+                                            deployment sets for this traffic)
     benchmarks/layer_metrics/<metric>.py    read(run) -> number or None
     benchmarks/harness/kinds/<kind>.py      run_cell(cell, args) for a traffic kind
     benchmarks/reference/<family>.py        the plain float32 reference
+    benchmarks/harness/<needed_work>.py     mean_needed(model, lengths): the work a
+                                            configuration's rows need, for its rooflines
 """
 
 from __future__ import annotations
@@ -81,20 +86,27 @@ def load_traffic(name: str) -> Dict[str, Any]:
     return traffic
 
 
+def _load_named(what: str, name: str, *folders: str):
+    """``benchmarks/<folders>/<name>.py``, ``name`` as the manifest's rules
+    for a name have it."""
+    if not NAME.match(name):
+        raise ValueError(f"bad {what} {name!r}")
+    return _load_module(os.path.join(BENCH_DIR, *folders, name + ".py"),
+                        f"benchmarks_{what.replace(' ', '_')}_{name}")
+
+
 def load_kind(kind: str):
-    if not NAME.match(kind):
-        raise ValueError(f"bad traffic kind {kind!r}")
-    return _load_module(
-        os.path.join(BENCH_DIR, "harness", "kinds", kind + ".py"),
-        f"benchmarks_kind_{kind}")
+    return _load_named("traffic kind", kind, "harness", "kinds")
 
 
 def load_reference(family: str):
-    if not NAME.match(family):
-        raise ValueError(f"bad reference family {family!r}")
-    return _load_module(
-        os.path.join(BENCH_DIR, "reference", family + ".py"),
-        f"benchmarks_reference_{family}")
+    return _load_named("reference", family, "reference")
+
+
+def load_needed_work(name: str):
+    """The counter a configuration file names under ``needed_work``: what
+    its model needs of the chip for rows of given lengths, from shapes."""
+    return _load_named("needed-work counter", name, "harness")
 
 
 def load_layer_metric(name: str):

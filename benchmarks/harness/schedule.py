@@ -2,10 +2,15 @@
 every traffic file.
 
 The SET of sizes is a fixed function of the traffic file (quantiles of its
-distribution, not draws), and the seed decides their ORDER and the bytes of
-every text. So every seed offers the same work, in another order: runs with
-different seeds differ no more than two runs of one seed do. Everything is
-drawn up front from one seed (as ``agent_tpu/loadgen.py`` does)."""
+distribution, not draws), and so is their ORDER (its ``order_seed``): every
+run of a cell offers the same sequence of shards, each of the same lengths.
+``--seed`` decides what the rows SAY (the bytes of every text, the token ids
+and their permutation), the tenants' model ids and so their weights, and the
+rows the check samples. So runs with different seeds differ no more than two
+runs of one seed do: what is left to differ is the system. (A shard's cost
+can depend on which lengths fall into it: packed, a short shard is 4 slices
+or 5. An order drawn from ``--seed`` gave every seed another amount of
+work.) Everything is drawn up front (as ``agent_tpu/loadgen.py`` does)."""
 
 from __future__ import annotations
 
@@ -13,7 +18,6 @@ from statistics import NormalDist
 from typing import Any, List, Mapping
 
 import numpy as np
-
 
 
 def rng_of(seed: int, stream: str) -> np.random.Generator:
@@ -42,6 +46,16 @@ def size_set(dist: Mapping[str, Any], n: int) -> np.ndarray:
     else:
         raise ValueError(f"unknown size distribution {kind!r}")
     return out.astype(np.int64)
+
+
+def ordered_sizes(dist: Mapping[str, Any], n: int, order_seed: int
+                  ) -> np.ndarray:
+    """``size_set(dist, n)`` in the order the traffic file's ``order_seed``
+    gives it: one fixed shuffle of the whole backlog, so its shards differ
+    from each other as a population's do and are the same in every run."""
+    lengths = size_set(dist, n)
+    rng_of(order_seed, "order").shuffle(lengths)
+    return lengths
 
 
 PALETTE = 6     # letters a row draws from
@@ -76,8 +90,8 @@ def texts(rng: np.random.Generator, lengths: np.ndarray) -> List[str]:
 def drain_rows(traffic: Mapping[str, Any], seed: int, n_rows: int
                ) -> List[str]:
     """The ``n_rows`` texts of a drain job, no row twice."""
-    lengths = size_set(traffic["row_bytes"], n_rows)
-    rng_of(seed, "order").shuffle(lengths)
+    lengths = ordered_sizes(traffic["row_bytes"], n_rows,
+                            traffic["order_seed"])
     return texts(rng_of(seed, "text"), lengths)
 
 

@@ -93,9 +93,13 @@ def memory_stats() -> Dict[str, Any]:
 
 class AgentStack:
     """One in-process ``Agent`` leasing from ``controller_url`` on the
-    pipelined runner, every knob at the program's default."""
+    pipelined runner, every knob at the program's default but those the
+    cell's traffic file sets under ``agent`` (fields of the program's
+    ``AgentConfig``, as the deployment's operator would set them for that
+    traffic; an unknown one is a ``TypeError``)."""
 
-    def __init__(self, controller_url: str, tasks: Sequence[str]) -> None:
+    def __init__(self, controller_url: str, tasks: Sequence[str],
+                 knobs: Optional[Dict[str, Any]] = None) -> None:
         import requests
 
         from agent_tpu.agent.app import Agent
@@ -118,7 +122,7 @@ class AgentStack:
 
         config = Config.from_env()
         config = dataclasses.replace(config, agent=dataclasses.replace(
-            config.agent, controller_url=controller_url,
+            config.agent, **dict(knobs or {}), controller_url=controller_url,
             agent_name="bench-agent", tasks=tuple(tasks)))
         self.runtime = get_runtime(config.device)
         check(self.runtime.platform == REQUIRED_PLATFORM,

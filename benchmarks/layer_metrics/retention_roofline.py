@@ -17,6 +17,8 @@ def read(run):
     times = run.get("op_times") or {}
     if run["kind"] != "drain" or not trace or not peaks or not needed:
         return None
+    if "retention_flops" not in needed:     # another family's counter
+        return None
     seconds = (times.get("retention") or {}).get("seconds", 0.0)
     if seconds <= 0:
         return None

@@ -100,6 +100,54 @@ def test_the_window_opens_on_a_regular_acceptance(gaps, lead_in, want):
         assert got[1] == pytest.approx(statistics.median(gaps[:lead_in]))
 
 
+# ---- the agent's knobs: the traffic file's --------------------------------
+
+@pytest.mark.parametrize("traffic, knobs", [
+    ("drain-long", {}), ("drain-short", {"pipeline_depth": 12}),
+    ("score-long", {}),
+])
+def test_a_traffic_file_sets_only_knobs_the_program_has(traffic, knobs):
+    """Short shards are 21 ms of device work, so the short cell's deployment
+    stages and posts 12 ahead (PR 32); the other two run every default."""
+    import dataclasses
+
+    from agent_tpu.config import AgentConfig
+
+    got = manifest.load_traffic(traffic).get("agent", {})
+    assert got == knobs
+    assert set(got) <= {f.name for f in dataclasses.fields(AgentConfig)}
+
+
+@pytest.mark.parametrize("knobs, depth", [(None, 2), ({"pipeline_depth": 12}, 12)])
+def test_the_agent_gets_the_traffic_files_knobs(monkeypatch, knobs, depth):
+    """``AgentStack`` hands them to the program's own configuration, over
+    what ``Config.from_env`` read, and refuses a knob the program does not
+    have."""
+    import agent_tpu.agent.app as app
+
+    seen = {}
+
+    class Agent:
+        def __init__(self, config, **_):
+            seen["agent"] = config.agent
+
+        def run(self):
+            pass
+
+    monkeypatch.setattr(stack, "REQUIRED_PLATFORM", "cpu")
+    monkeypatch.setattr(app, "Agent", Agent)
+    monkeypatch.setenv("PIPELINE_DEPTH", "5" if knobs is None else "7")
+    reset_runtime()
+    try:
+        stack.AgentStack("http://127.0.0.1:9", ["map_classify_tpu"], knobs).close()
+        assert seen["agent"].pipeline_depth == (5 if knobs is None else depth)
+        assert seen["agent"].controller_url == "http://127.0.0.1:9"
+        with pytest.raises(TypeError):
+            stack.AgentStack("http://127.0.0.1:9", [], {"no_such_knob": 1})
+    finally:
+        reset_runtime()
+
+
 # ---- rehearsals ------------------------------------------------------------
 
 @pytest.fixture()

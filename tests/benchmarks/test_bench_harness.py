@@ -93,7 +93,7 @@ def test_interval_arithmetic():
 # ---- the schedule ----------------------------------------------------------
 
 SHORT = {"row_bytes": {"dist": "lognormal", "median": 28, "sigma": 0.6,
-                       "min": 8, "max": 64}}
+                       "min": 8, "max": 64}, "order_seed": 107}
 
 
 def test_rows_are_byte_identical_for_one_seed():
@@ -103,9 +103,17 @@ def test_rows_are_byte_identical_for_one_seed():
 
 
 def test_every_seed_offers_the_same_work_in_another_order():
+    """The same sizes in the same sequence (the traffic file's
+    ``order_seed`` shuffles them, PR 32), said in other bytes: what a seed
+    orders anew is the letters of every row, not which shard a row falls
+    into."""
     a, b = schedule.drain_rows(SHORT, 1, 1024), schedule.drain_rows(SHORT, 2, 1024)
-    assert sorted(map(len, a)) == sorted(map(len, b))
-    assert list(map(len, a)) != list(map(len, b))
+    assert list(map(len, a)) == list(map(len, b))
+    assert list(map(len, a)) != sorted(map(len, a))
+    assert not set(a) & set(b)
+    c = schedule.drain_rows(dict(SHORT, order_seed=108), 1, 1024)
+    assert sorted(map(len, c)) == sorted(map(len, a))
+    assert list(map(len, c)) != list(map(len, a))
     assert len(set(a)) == len(a)                      # no row twice
     lengths = sorted(map(len, a))
     assert lengths[0] == 8 and lengths[-1] == 64
@@ -114,7 +122,7 @@ def test_every_seed_offers_the_same_work_in_another_order():
 
 def test_drain_rows():
     long_rows = schedule.drain_rows(
-        {"row_bytes": {"dist": "fixed", "value": 600}}, 7, 64)
+        {"row_bytes": {"dist": "fixed", "value": 600}, "order_seed": 0}, 7, 64)
     assert {len(r) for r in long_rows} == {600} and len(set(long_rows)) == 64
     a = schedule.drain_rows(SHORT, 1, 1024)
     assert all(not r.startswith(" ") and not r.endswith(" ") and '"' not in r
@@ -125,7 +133,8 @@ def test_csv_round_trip(tmp_path):
     import csv
 
     rows = schedule.drain_rows(
-        {"row_bytes": {"dist": "uniform", "min": 5, "max": 40}}, 9, 50)
+        {"row_bytes": {"dist": "uniform", "min": 5, "max": 40}, "order_seed": 1},
+        9, 50)
     path = str(tmp_path / "job.csv")
     schedule.write_csv(path, rows)
     with open(path, newline="") as f:
@@ -215,16 +224,26 @@ def test_flops_hand_worked_bert_base():
 
 # ---- the manifest ---------------------------------------------------------
 
-def test_every_entry_resolves_and_every_name_passes_the_rules():
-    m = manifest.load_manifest()
+def test_every_entry_resolves_and_every_name_passes_the_rules(manifests):
+    """Over the committed manifest, and over a copy with a configuration, a
+    four-chip cell and two per-layer entries APPENDED (``conftest.py``): what
+    a later PR may do passes every rule, so no rule pins an order or a
+    count."""
+    m = manifests
     assert set(m) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
     assert m["paths"] == ["benchmarks", "tests/benchmarks"]
     e2e = {x["name"] for x in m["end_to_end"]}
     assert "setup_s" in e2e
     cells = {w["name"] for w in m["workloads"]}
+    assert len(cells) == len(m["workloads"]) <= 24
+    # The driver's rule: 1 chip or 4; of the cells at most a quarter, rounded
+    # down, may ask for 4, and one always may.
+    assert all(w["chips"] in (1, 4) for w in m["workloads"])
+    assert sum(w["chips"] == 4 for w in m["workloads"]) <= max(
+        1, len(m["workloads"]) // 4)
     for w in m["workloads"]:
-        assert w["chips"] == 1 and len(w["why"]) <= 200
+        assert 1 <= len(w["why"]) <= 200
         for key in ("name", "config", "traffic"):
             assert manifest.NAME.match(w[key]), w[key]
         config = manifest.load_config(m, w["config"])
@@ -233,6 +252,11 @@ def test_every_entry_resolves_and_every_name_passes_the_rules():
         ref = manifest.load_reference(config["reference"])
         assert callable(ref.logits) and callable(ref.compare)
         assert config["check"]["limits"] and traffic["tenants"] >= 1
+        # The order of a backlog's sizes is the traffic file's, never --seed's.
+        assert isinstance(traffic["order_seed"], int)
+        if "needed_work" in config:
+            assert callable(manifest.load_needed_work(
+                config["needed_work"]).mean_needed)
         reported = {x["name"] for x in manifest.metrics_of_cell(
             m, w["name"], "end_to_end")}
         assert "setup_s" in reported and len(reported) >= 2
@@ -261,7 +285,10 @@ def test_every_entry_resolves_and_every_name_passes_the_rules():
                           "moves", "workloads"}
         if "roofline" in x["name"]:
             assert x["unit"] == "%" and x["source"] == "device_trace"
-    for root, _, files in os.walk(os.path.join(ROOT, "benchmarks")):
+    for name in ("end_to_end", "per_layer", "configs"):
+        names = [x["name"] for x in m[name]]
+        assert len(set(names)) == len(names)
+    for root, _, files in os.walk(manifest.BENCH_DIR):
         for name in files:
             if "__pycache__" not in root:
                 assert re.match(r"^[A-Za-z0-9_.\-]+$", name), name

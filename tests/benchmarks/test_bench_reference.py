@@ -56,7 +56,7 @@ def test_reference_weights_follow_the_programs_recipe(model_id):
 
 
 TEXTS = schedule.drain_rows(
-    {"row_bytes": {"dist": "uniform", "min": 64, "max": 148}}, 5,
+    {"row_bytes": {"dist": "uniform", "min": 64, "max": 148}, "order_seed": 5}, 5,
     ROWS_PER_MODEL * len(MODEL_IDS))
 
 

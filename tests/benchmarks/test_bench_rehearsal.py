@@ -27,10 +27,14 @@ TINY_BERT = {
     "d_model": 32, "n_heads": 4, "n_layers": 1, "d_ff": 64, "max_len": 64,
     "n_classes": 16, "dtype": "float32",
 }
+# ``agent``: a rehearsal keeps the program's own pipeline depth. The tests'
+# platform is 8 virtual CPU devices, and their in-process collectives stop
+# in a rendezvous once a dozen shards' programs are in flight at a time
+# (``drain-short`` sets 12 for the chip, where a cell has one device).
 TINY_DRAIN = {
     "shard_rows": 16, "tenants": 3, "job_rows": 32,
     "backlog_rows_per_s": 4000, "lead_in_shards": 2, "trace_start_s": 0.2,
-    "trace_seconds": 0.5,
+    "trace_seconds": 0.5, "agent": {},
 }
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 

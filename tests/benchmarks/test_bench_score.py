@@ -118,6 +118,42 @@ def test_a_state_dropped_between_segments_is_not_correct(tiny, capsys):
     assert compared["block_logprob_gap_slope"]["ok"] is False
 
 
+def test_a_second_familys_needed_work_counter_is_found_by_name(
+        tiny, appended, capsys):
+    """A later PR's decoder family on the ``score`` kind: its configuration
+    names its own needed-work counter (``conftest.py`` writes the file and
+    appends the entries; nothing under ``harness/`` is edited), the kind
+    finds it by that name and the two shared readers read its three keys."""
+    cell = appended["workloads"][-1]
+    assert cell["config"] == "appended-lm" and cell["chips"] == 4
+    tiny.setitem(manifest.MODEL_OVERRIDES, "appended-lm", TINY_LM)
+    tiny.setitem(manifest.TRAFFIC_OVERRIDES, "appended-mix", dict(TINY_SCORE))
+    run = bench_run.run_cell(appended, cell["name"], 2 ** 31 + 32, 2.0, 1)
+    capsys.readouterr()
+    assert run["correct"] is True and run["failed"] == 0 and run["shards"] > 0
+    assert run["config"]["needed_work"] == "appended_needed"
+    assert run["lm_needed"] == {"flops": 35000.0, "head_flops": 10000.0,
+                                "head_bytes": 15000.0}
+    assert run["mean_flops_per_row"] == 35000.0
+    # The shared ``.drain`` readers read its cell; its own reader finds
+    # nothing in this run and is left out.
+    assert run["metrics"]["compiles_in_window.drain"]["value"] == 0
+    assert "appended_ms.drain" not in run["metrics"]
+    # No device plane in a CPU trace; on a recorded one the shared readers
+    # read this counter's keys, and brumby's own reader finds nothing.
+    recorded = dict(recorded_run(), lm_needed=run["lm_needed"])
+    read = lambda name: manifest.load_layer_metric(name).read(recorded)  # noqa: E731
+    assert read("lm_roofline") == pytest.approx(
+        100 * 1.25 * 35000.0 / (2.9 / 3.0) / 197e12)
+    assert read("loss_head_roofline") == pytest.approx(
+        100 * 1.25 * (15000.0 / 819e9) / (0.5 / 3.0))
+    assert read("retention_roofline") is None
+    # The committed cell still counts with the file it names.
+    brumby = manifest.load_config(appended, "brumby-14b-base")
+    assert manifest.load_needed_work(brumby["needed_work"]).mean_needed(
+        PUBLISHED, [16384]) == lm_flops.mean_needed(PUBLISHED, [16384])
+
+
 # ---- the counting functions against hand arithmetic ----------------------
 
 def test_counts_of_a_16384_token_document_at_the_published_widths():
@@ -253,8 +289,8 @@ def test_documents_are_seeded_zipf_over_the_whole_vocabulary():
     assert 0.03 < np.bincount(ids).max() / len(ids) < 0.25
 
 
-def test_manifest_entries_of_the_cell():
-    m = manifest.load_manifest()
+def test_manifest_entries_of_the_cell(manifests):
+    m = manifests
     cell = manifest.find_cell(m, CELL)
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
     cfg = manifest.load_config(m, cell["config"])
@@ -282,4 +318,8 @@ def test_manifest_entries_of_the_cell():
             "http_post_ms_per_shard.drain", "xla_executables_in_window.drain",
             "xla_compile_s.setup", "params_s.setup"} <= per_layer
     assert not {"encoder_roofline", "whole_row_attention_blocks.setup",
+                "fused_qkv_attention_blocks.setup",
                 "lease_rtt_ms.drain"} & per_layer
+    # The work a document needs is counted by the file the configuration
+    # names, found by that name as its reference is.
+    assert cfg["needed_work"] == "lm_flops" and cfg["reference"] == "retention_lm"
