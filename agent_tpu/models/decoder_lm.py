@@ -4,13 +4,27 @@ One file for the family, not one a checkpoint (ROADMAP.md D1): a block is
 described by its LAYER TYPE — pre-norm residual block, RMS norm, rotary
 positions, grouped-query projections with a per-head RMS norm on q and k, a
 ``mixer`` that mixes along the sequence, a SwiGLU feed-forward — and the
-``mixer`` key names which sequence mixer the type runs (``MIXERS``).
-``power_retention`` (gated power retention of degree 2,
-``kernels/power_retention.py``) is the first: it carries a fixed-size state
-along the sequence, so a document longer than one program runs as
-fixed-shape SEGMENTS with the state handed from one to the next
-(:func:`forward_segment`). Layers are stacked and scanned; embedding and
-output head are untied.
+``mixer`` key names which sequence mixer the type runs (``MIXERS``), and the
+feed-forward is chosen by the layer's place (``n_dense_layers`` leading SwiGLU
+layers, then expert layers when ``n_experts`` is set). A document longer than
+one program runs as fixed-shape SEGMENTS with the mixer's state handed from
+one to the next (:func:`forward_segment`):
+
+- ``power_retention`` (gated power retention of degree 2,
+  ``kernels/power_retention.py``) carries a FIXED-SIZE state along the
+  sequence (``None`` before the first segment);
+- ``sparse_mla`` (latent attention under a learned top-k key selection,
+  ``kernels/sparse_mla.py``) carries a CACHE that grows with position: one
+  latent vector (``kv_lora_rank + qk_rope_head_dim`` numbers) and one index
+  key (``index_head_dim``) a token a layer, allocated at the document's
+  padded length (:func:`init_state`), written in place, read up to the
+  segment's last token.
+
+Layers are stacked by group (the leading dense layers, then the expert
+layers) and each group is scanned; embedding and output head are untied. An
+expert layer routes over all ``n_experts`` and computes the experts it HOLDS
+(``n_experts_held`` from ``expert_first``: one chip's share of an
+expert-parallel deployment, ``models/moe.py``).
 
 Weights are STORED in the compute dtype (bf16) and made ON THE DEVICE, leaf
 by leaf, by one jitted initializer from the model id (:func:`init_params`):
@@ -20,10 +34,13 @@ time) if built on the host in float32 as the encoder families are.
 
 The weight rule (also written, independently, in the benchmark's reference):
 root key = ``layers.seed_from(model_id)``; leaf ``j`` of ``LEAVES`` draws
-from ``fold_in(root, j)``, a per-layer leaf for layer ``i`` from
-``fold_in(fold_in(root, j), i)``; a standard normal in float32 times
-``1/sqrt(fan_in)`` (embedding: 1), rounded once to the stored dtype. Norm
-weights are 1. The gate's bias gives key-value head ``b`` a memory of
+from ``fold_in(root, j)``, a per-layer leaf for layer ``i`` (counted over
+both groups) from ``fold_in(fold_in(root, j), i)``, expert ``e`` (its id among
+all ``n_experts``) of that layer from one more ``fold_in(., e)``; a standard
+normal in float32 times ``1/sqrt(fan_in)`` (embedding: 1), rounded once to the
+stored dtype. Norm weights are 1, the router's bias and the index keys'
+LayerNorm bias 0. New leaves are APPENDED to ``LEAVES``: a model made before
+keeps its keys. The gate's bias gives key-value head ``b`` a memory of
 ``16 * 2**b`` tokens: ``log(16 * 2**b - 1)`` (see ``GATE_TAU0``).
 """
 
@@ -41,9 +58,29 @@ from agent_tpu.models.layers import Params
 
 # Leaves that draw random numbers, in the order that keys them.
 LEAVES = ("embed", "head", "wq", "wk", "wv", "wo", "wg", "w_gate", "w_up",
-          "w_down")
-# The layer leaves a quantized mode replaces (``models.quant``).
-LINEAR_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+          "w_down",
+          # sparse_mla: query down / up, latent down / up, the indexer's
+          "w_dq", "w_uq", "w_dkv", "w_ukv", "wi_q", "wi_k", "wi_w",
+          # expert layers: router, shared expert, the routed experts held
+          "w_router", "ws_gate", "ws_up", "ws_down",
+          "we_gate", "we_up", "we_down")
+EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+# The layer leaves a quantized mode replaces (``models.quant``): projections
+# (the router's among them), feed-forwards and experts; the retention gate
+# and the indexer's head weights stay.
+LINEAR_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+                 "w_dq", "w_uq", "w_dkv", "w_ukv", "wi_q", "wi_k",
+                 "w_router", "ws_gate", "ws_up", "ws_down") + EXPERT_LEAVES
+# Which leaves a layer holds, by its mixer and by its feed-forward.
+MIXER_LEAVES = {
+    "power_retention": ("wq", "wk", "wv", "wo", "wg"),
+    "sparse_mla": ("wo", "w_dq", "w_uq", "w_dkv", "w_ukv", "wi_q", "wi_k",
+                   "wi_w"),
+}
+FFN_LEAVES = {
+    "dense": ("w_gate", "w_up", "w_down"),
+    "experts": ("w_router", "ws_gate", "ws_up", "ws_down") + EXPERT_LEAVES,
+}
 # sigmoid(log(tau - 1)) = 1 - 1/tau: head b forgets over tau0 * 2**b tokens.
 GATE_TAU0 = 16.0
 # Tokens a loss block: the op reports the log-probability summed a block.
@@ -71,10 +108,47 @@ class DecoderLMConfig:
     mixer: str = "power_retention"
     dtype: str = "bfloat16"
     quant: str = "none"
+    # sparse_mla (``n_heads`` heads; ``d_head`` / ``n_kv_heads`` unused):
+    q_lora_rank: int = 48
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    index_n_heads: int = 4
+    index_head_dim: int = 16
+    index_topk: int = 16
+    # YaRN (``rope_factor`` 1: plain rotary positions).
+    rope_factor: float = 1.0
+    rope_original_max_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    # Expert layers (``n_experts`` 0: every layer's FFN is the dense SwiGLU).
+    n_dense_layers: int = 1
+    n_experts: int = 0
+    n_experts_held: int = 0
+    expert_first: int = 0
+    n_experts_per_token: int = 8
+    n_expert_groups: int = 8
+    n_groups_per_token: int = 4
+    d_expert: int = 64
+    n_shared_experts: int = 1
+    routed_scale: float = 2.5
 
     @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def layer_groups(self) -> Tuple[Tuple[str, str, int, int], ...]:
+        """``(params key, feed-forward, first layer, layers)`` per stacked
+        group: the leading dense layers, then the expert layers."""
+        if not self.n_experts:
+            return (("layers", "dense", 0, self.n_layers),)
+        nd = self.n_dense_layers
+        return tuple(g for g in (
+            ("layers", "dense", 0, nd),
+            ("expert_layers", "experts", nd, self.n_layers - nd)) if g[3])
 
 
 def validate(cfg: DecoderLMConfig) -> None:
@@ -82,11 +156,35 @@ def validate(cfg: DecoderLMConfig) -> None:
     if cfg.mixer not in MIXERS:
         raise ValueError(f"mixer must be one of {sorted(MIXERS)}, "
                          f"got {cfg.mixer!r}")
-    if cfg.n_kv_heads <= 0 or cfg.n_heads % cfg.n_kv_heads:
-        raise ValueError("n_heads must be a multiple of n_kv_heads")
-    if cfg.d_head % 2:
-        raise ValueError("d_head must be even (rotary pairs)")
-    for name in ("vocab_size", "d_model", "d_ff", "n_layers", "max_len"):
+    if cfg.mixer == "power_retention":
+        if cfg.n_kv_heads <= 0 or cfg.n_heads % cfg.n_kv_heads:
+            raise ValueError("n_heads must be a multiple of n_kv_heads")
+        if cfg.d_head % 2:
+            raise ValueError("d_head must be even (rotary pairs)")
+    positive = ["vocab_size", "d_model", "d_ff", "n_layers", "max_len"]
+    if cfg.mixer == "sparse_mla":
+        positive += ["q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                     "v_head_dim", "index_n_heads", "index_topk"]
+        if cfg.qk_rope_head_dim % 2 or cfg.qk_rope_head_dim <= 0:
+            raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
+        if cfg.index_head_dim < cfg.qk_rope_head_dim:
+            raise ValueError("index_head_dim must hold the rotary part")
+    if cfg.n_experts:
+        positive += ["n_experts_held", "d_expert", "n_experts_per_token"]
+        if not 0 <= cfg.n_dense_layers <= cfg.n_layers:
+            raise ValueError("n_dense_layers must lie in 0 .. n_layers")
+        if (cfg.expert_first < 0
+                or cfg.expert_first + cfg.n_experts_held > cfg.n_experts):
+            raise ValueError("the experts held must be ids of n_experts")
+        if cfg.n_experts % max(1, cfg.n_expert_groups):
+            raise ValueError("n_experts must be whole groups")
+        size = cfg.n_experts // max(1, cfg.n_expert_groups)
+        if (cfg.n_groups_per_token > cfg.n_expert_groups
+                or cfg.n_experts_per_token > cfg.n_groups_per_token * size
+                or (cfg.n_expert_groups > 1 and size < 2)):
+            raise ValueError("the router cannot choose n_experts_per_token "
+                             "experts from n_groups_per_token groups")
+    for name in positive:
         if int(getattr(cfg, name)) <= 0:
             raise ValueError(f"{name} must be positive")
 
@@ -94,14 +192,34 @@ def validate(cfg: DecoderLMConfig) -> None:
 # ---- weights --------------------------------------------------------------
 
 def _leaf_shapes(cfg: DecoderLMConfig) -> Dict[str, Tuple[Tuple[int, ...], int]]:
-    """leaf → (shape of one layer's leaf (or the whole leaf), fan_in)."""
+    """leaf → (shape of one layer's leaf (of one expert's; or the whole
+    leaf), fan_in)."""
     d, f = cfg.d_model, cfg.d_ff
-    hq, hkv = cfg.n_heads * cfg.d_head, cfg.n_kv_heads * cfg.d_head
+    if cfg.mixer == "sparse_mla":
+        h, qr, kvr = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        hi, di = cfg.index_n_heads, cfg.index_head_dim
+        mixer = {
+            "wo": ((h * dv, d), h * dv),
+            "w_dq": ((d, qr), d), "w_uq": ((qr, h * (dn + dr)), qr),
+            "w_dkv": ((d, kvr + dr), d), "w_ukv": ((kvr, h * (dn + dv)), kvr),
+            "wi_q": ((qr, hi * di), qr), "wi_k": ((d, di), d),
+            "wi_w": ((d, hi), d),
+        }
+    else:
+        hq, hkv = cfg.n_heads * cfg.d_head, cfg.n_kv_heads * cfg.d_head
+        mixer = {
+            "wq": ((d, hq), d), "wk": ((d, hkv), d), "wv": ((d, hkv), d),
+            "wo": ((hq, d), hq), "wg": ((d, cfg.n_kv_heads), d),
+        }
+    fe, fs = cfg.d_expert, cfg.d_expert * cfg.n_shared_experts
     return {
         "embed": ((cfg.vocab_size, d), 1), "head": ((cfg.vocab_size, d), d),
-        "wq": ((d, hq), d), "wk": ((d, hkv), d), "wv": ((d, hkv), d),
-        "wo": ((hq, d), hq), "wg": ((d, cfg.n_kv_heads), d),
+        **mixer,
         "w_gate": ((d, f), d), "w_up": ((d, f), d), "w_down": ((f, d), f),
+        "w_router": ((d, cfg.n_experts), d),
+        "ws_gate": ((d, fs), d), "ws_up": ((d, fs), d), "ws_down": ((fs, d), fs),
+        "we_gate": ((d, fe), d), "we_up": ((d, fe), d), "we_down": ((fe, d), fe),
     }
 
 
@@ -110,49 +228,82 @@ def gate_bias(n_kv_heads: int) -> np.ndarray:
         np.float32)
 
 
+def _layer_constants(cfg: DecoderLMConfig, ffn: str) -> Dict[str, Tuple]:
+    """leaf → (value, shape of one layer's leaf): what is not drawn."""
+    d = cfg.d_model
+    out = {"ln1": (1.0, (d,)), "ln2": (1.0, (d,))}
+    if cfg.mixer == "sparse_mla":
+        out.update(q_norm=(1.0, (cfg.q_lora_rank,)),
+                   kv_norm=(1.0, (cfg.kv_lora_rank,)),
+                   ik_norm=(1.0, (cfg.index_head_dim,)),
+                   ik_bias=(0.0, (cfg.index_head_dim,)))
+    else:
+        out.update(bg=(gate_bias(cfg.n_kv_heads), (cfg.n_kv_heads,)),
+                   q_norm=(1.0, (cfg.d_head,)), k_norm=(1.0, (cfg.d_head,)))
+    if ffn == "experts":
+        out["router_bias"] = (0.0, (cfg.n_experts,))
+    return out
+
+
 def init_params(cfg: DecoderLMConfig, model_id: str, sharding=None) -> Params:
     """The family's weights, built on the device in the stored dtype. With
     ``sharding`` the leaves come out committed to it, which is how
     ``TpuRuntime.get_params`` keeps them as built."""
     dtype = cfg.compute_dtype
-    n = cfg.n_layers
     root = layers.seed_from(model_id)
+    shapes = _leaf_shapes(cfg)
 
-    def draw(shape, fan_in, stacked):
+    programs: Dict[Tuple, Callable] = {}
+
+    def program(shape, fan_in, first, n, experts):
+        """``key -> leaf``: one matrix; stacked over layers ``first .. first
+        + n`` when given, and under each over the ``experts`` (ids) held,
+        every entry from its own folded key. Layers are drawn at once; a
+        layer's experts one after another (one small program in a loop, not
+        a vmapped 16)."""
         def fn(key):
             def one(k):
                 w = jax.random.normal(k, shape, dtype=jnp.float32)
                 return (w * (1.0 / np.sqrt(max(1, fan_in)))).astype(dtype)
 
-            if not stacked:
+            if first is None:
                 return one(key)
-            return jax.vmap(one)(jax.vmap(
-                lambda i: jax.random.fold_in(key, i))(jnp.arange(n)))
+            keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(
+                jnp.arange(n) + first if first else jnp.arange(n))
+            if experts is None:
+                return jax.vmap(one)(keys)
+            return jax.lax.map(lambda k: jax.lax.map(
+                lambda e: one(jax.random.fold_in(k, e)),
+                jnp.asarray(experts)), keys)
 
         return jax.jit(fn, out_shardings=sharding)
+
+    def draw(name, first=None, n=0, experts=None):
+        # Leaves of one shape share ONE program (wk / wv, w_gate / w_up,
+        # embedding / head): tracing and lowering a draw costs the host more
+        # than the device takes to run it.
+        which = (*shapes[name], first, n, experts)
+        if which not in programs:
+            programs[which] = program(*which)
+        return programs[which](jax.random.fold_in(root, LEAVES.index(name)))
 
     def const(value, shape):
         return jax.jit(lambda: jnp.broadcast_to(
             jnp.asarray(value, jnp.float32), shape).astype(jnp.float32),
             out_shardings=sharding)()
 
-    shapes = _leaf_shapes(cfg)
-    drawn = {
-        name: draw(*shapes[name], stacked=name not in ("embed", "head"))(
-            jax.random.fold_in(root, j))
-        for j, name in enumerate(LEAVES)
-    }
-    d, dh = cfg.d_model, cfg.d_head
-    return {
-        "embed": drawn["embed"], "head": drawn["head"],
-        "final_norm": const(1.0, (d,)),
-        "layers": {
-            **{k: drawn[k] for k in LEAVES[2:]},
-            "bg": const(gate_bias(cfg.n_kv_heads), (n, cfg.n_kv_heads)),
-            "ln1": const(1.0, (n, d)), "ln2": const(1.0, (n, d)),
-            "q_norm": const(1.0, (n, dh)), "k_norm": const(1.0, (n, dh)),
-        },
-    }
+    held = tuple(range(cfg.expert_first, cfg.expert_first + cfg.n_experts_held))
+    params = {"embed": draw("embed"), "head": draw("head"),
+              "final_norm": const(1.0, (cfg.d_model,))}
+    for group, ffn, first, n in cfg.layer_groups:
+        params[group] = {
+            **{name: draw(name, first, n,
+                          held if name in EXPERT_LEAVES else None)
+               for name in MIXER_LEAVES[cfg.mixer] + FFN_LEAVES[ffn]},
+            **{name: const(value, (n, *shape)) for name, (value, shape)
+               in _layer_constants(cfg, ffn).items()},
+        }
+    return params
 
 
 # ---- the mathematics ------------------------------------------------------
@@ -212,40 +363,282 @@ def _power_retention_mixer(p: Params, h: jax.Array, positions: jax.Array,
                            initial_state=state, **kernel_opts)
 
 
+def yarn_inv_freq(cfg: DecoderLMConfig) -> np.ndarray:
+    """Inverse frequencies of the ``qk_rope_head_dim / 2`` rotary pairs under
+    YaRN (the DeepSeek inference code's ``precompute_freqs_cis``): below the
+    ``beta_fast`` rotations correction dimension untouched, above the
+    ``beta_slow`` one divided by ``rope_factor``, a linear ramp between.
+    Applied whenever ``max_len`` exceeds the original length."""
+    dim, base = cfg.qk_rope_head_dim, float(cfg.rope_theta)
+    inv = 1.0 / (base ** (np.arange(0, dim, 2, dtype=np.float64) / dim))
+    if cfg.rope_factor == 1.0 or cfg.max_len <= cfg.rope_original_max_len:
+        return inv.astype(np.float32)
+
+    def correction_dim(rotations):
+        return dim * np.log(cfg.rope_original_max_len / (
+            rotations * 2 * np.pi)) / (2 * np.log(base))
+
+    low = max(int(np.floor(correction_dim(cfg.rope_beta_fast))), 0)
+    high = min(int(np.ceil(correction_dim(cfg.rope_beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / ((high if high != low else high + 0.001) - low), 0, 1)
+    smooth = 1.0 - ramp
+    return (inv / cfg.rope_factor * (1 - smooth) + inv * smooth).astype(
+        np.float32)
+
+
+def softmax_scale(cfg: DecoderLMConfig) -> float:
+    """``(nope + rope)^-0.5``, times YaRN's ``mscale`` squared where the
+    positions are scaled: ``mscale = 0.1 rope_mscale ln(factor) + 1``."""
+    scale = float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_factor != 1.0 and cfg.max_len > cfg.rope_original_max_len:
+        scale *= (0.1 * cfg.rope_mscale * np.log(cfg.rope_factor) + 1.0) ** 2
+    return scale
+
+
+def rope_pairs(x: jax.Array, positions: jax.Array, inv_freq: np.ndarray,
+               interleaved: bool) -> jax.Array:
+    """Rotary positions on the last axis of x [L, ..., D] with given inverse
+    frequencies [D/2]: pairs ``(2i, 2i + 1)`` (``interleaved``: latent
+    attention's) or ``(i, i + D/2)`` (the indexer's)."""
+    d = x.shape[-1]
+    ang = positions.astype(jnp.float32)[:, None] * jnp.asarray(inv_freq)
+    ang = ang.reshape(ang.shape[0], *([1] * (x.ndim - 2)), d // 2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        pairs = xf.reshape(*xf.shape[:-1], d // 2, 2)
+        a, b = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+        return out.reshape(xf.shape).astype(x.dtype)
+    a, b = xf[..., : d // 2], xf[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                           axis=-1).astype(x.dtype)
+
+
+def _sparse_mla_mixer(p: Params, h: jax.Array, positions: jax.Array,
+                      state, cfg: DecoderLMConfig, kernel_opts):
+    """h [1, S, d] (normed) → (attended [1, S, H*v], the layer's cache with
+    the segment written at its positions). ``state``: ``{"kv": [1, Lk,
+    kv_lora_rank + rope], "ki": [1, Lk, index_head_dim]}``. One document a
+    program: the kernels take no batch."""
+    from agent_tpu.kernels import sparse_mla
+
+    if h.shape[0] != 1:
+        raise ValueError("sparse_mla runs one document a program")
+    dtype = cfg.compute_dtype
+    eps = cfg.rms_norm_eps
+    h = h[0]
+    S = h.shape[0]
+    nh, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    kvr, hi, di = cfg.kv_lora_rank, cfg.index_n_heads, cfg.index_head_dim
+    inv = yarn_inv_freq(cfg)
+    pos0 = positions[0]
+
+    cq = rms_norm(linear(p["w_dq"], h, dtype).astype(jnp.float32),
+                  p["q_norm"], eps)
+    # The softmax scale goes into the query latent before it is rounded to
+    # the compute dtype: a multiply less on every score of the attention.
+    q = linear(p["w_uq"], (cq * softmax_scale(cfg)).astype(dtype),
+               dtype).reshape(S, nh, dn + dr)
+    cq = cq.astype(dtype)
+    q_rope = rope_pairs(q[..., dn:], positions, inv, interleaved=True)
+    latent = linear(p["w_dkv"], h, dtype)
+    latent = jnp.concatenate([
+        rms_norm(latent[:, :kvr], p["kv_norm"], eps),
+        rope_pairs(latent[:, kvr:], positions, inv, interleaved=True)], -1)
+
+    # The indexer: rotary part FIRST, half-split pairs; a LayerNorm on keys.
+    qi = linear(p["wi_q"], cq, dtype).reshape(S, hi, di)
+    qi = jnp.concatenate([rope_pairs(qi[..., :dr], positions, inv, False),
+                          qi[..., dr:]], -1)
+    ki = layers.layer_norm({"scale": p["ik_norm"], "bias": p["ik_bias"]},
+                           linear(p["wi_k"], h, dtype), 1e-6)
+    ki = jnp.concatenate([rope_pairs(ki[:, :dr], positions, inv, False),
+                          ki[:, dr:]], -1)
+    wi = jnp.dot(h.astype(dtype), p["wi_w"].astype(dtype),
+                 preferred_element_type=jnp.float32) * float(
+        hi ** -0.5 * di ** -0.5)
+
+    kv = jax.lax.dynamic_update_slice(state["kv"][0], latent, (pos0, 0))
+    kic = jax.lax.dynamic_update_slice(state["ki"][0], ki, (pos0, 0))
+    mask = sparse_mla.index_select(qi, wi, kic, pos0, cfg.index_topk,
+                                   **kernel_opts)
+    w_ukv = _plain_weights(p["w_ukv"], dtype).reshape(
+        kvr, nh, dn + cfg.v_head_dim).transpose(1, 0, 2)
+    k_nope, v = sparse_mla.expand_latents(kv[:, :kvr], w_ukv, pos0 + S, dn,
+                                          **kernel_opts)
+    o = sparse_mla.masked_attention(
+        q[..., :dn].transpose(1, 0, 2), q_rope.transpose(1, 0, 2), k_nope,
+        kv[:, kvr:], v, mask, pos0, **kernel_opts)
+    return (o.transpose(1, 0, 2).reshape(1, S, nh * cfg.v_head_dim),
+            {"kv": kv[None], "ki": kic[None]})
+
+
+def _sparse_mla_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
+    """The empty cache of ``batch`` documents of ``cache_len`` (padded)
+    tokens: every layer's latents and index keys, in the stored dtype."""
+    n, dtype = cfg.n_layers, cfg.compute_dtype
+    return {"kv": jnp.zeros((n, batch, cache_len, cfg.kv_lora_rank
+                             + cfg.qk_rope_head_dim), dtype),
+            "ki": jnp.zeros((n, batch, cache_len, cfg.index_head_dim), dtype)}
+
+
 # mixer name → fn(layer params, normed h, positions, state, cfg, opts)
 # → (mixed [B, L, Hq*D], new state). One entry a sequence mixer.
-MIXERS: Dict[str, Callable] = {"power_retention": _power_retention_mixer}
+MIXERS: Dict[str, Callable] = {"power_retention": _power_retention_mixer,
+                               "sparse_mla": _sparse_mla_mixer}
+# mixer name → fn(cfg, batch, cache_len) → the state before a document's
+# first segment, for the mixers whose state is allocated (a cache); the
+# others start from ``None``.
+MIXER_STATES: Dict[str, Callable] = {"sparse_mla": _sparse_mla_state}
 
 
-def _layer(p: Params, x: jax.Array, positions, state, cfg, kernel_opts):
+def starts_from_nothing(cfg: DecoderLMConfig) -> bool:
+    """Whether :func:`init_state` is ``None``, from the config alone."""
+    return cfg.mixer not in MIXER_STATES and not cfg.n_experts
+
+
+def init_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
+    """What :func:`forward_segment` takes as ``state`` for a document's
+    FIRST segment: ``None`` where the mixer starts from nothing and no layer
+    routes; else ``{"mixer": the mixer's empty state or None, "pairs": 0}``
+    (see :func:`forward_segment`)."""
+    make = MIXER_STATES.get(cfg.mixer)
+    mixer = make(cfg, batch, cache_len) if make else None
+    if cfg.n_experts:
+        return {"mixer": mixer, "pairs": jnp.zeros((), jnp.float32)}
+    return mixer
+
+
+def _swiglu(p: Params, n: jax.Array, names, dtype) -> jax.Array:
+    gate, up, down = names
+    ff = jax.nn.silu(linear(p[gate], n, dtype)) * linear(p[up], n, dtype)
+    return linear(p[down], ff, dtype)
+
+
+def _plain_weights(leaf: Any, dtype) -> jax.Array:
+    """A leaf [..., in, out] in the compute dtype for a kernel that takes
+    plain weights (the grouped expert matmul, the latents' expansion): as
+    stored, or an int8 table (``models.quant``) times its scales."""
+    if isinstance(leaf, dict):
+        table = leaf["w_q"] if "w_q" in leaf else leaf["w8"]
+        return (table.astype(jnp.float32)
+                * leaf["w_scale"][..., None, :]).astype(dtype)
+    return leaf.astype(dtype)
+
+
+def _experts_ffn(p: Params, n: jax.Array, cfg: DecoderLMConfig, kernel_opts):
+    """n [B, S, d] (normed) → (shared expert + the routed experts held here,
+    [B, S, d]; the (token, expert) pairs routed here)."""
+    from agent_tpu.models import moe
+
+    dtype = cfg.compute_dtype
+    B, S, d = n.shape
+    flat = n.reshape(B * S, d)
+    # Scores that decide a discrete choice stay in float32.
+    if isinstance(p["w_router"], dict):
+        logits = linear(p["w_router"], flat, jnp.float32)
+    else:
+        logits = jnp.dot(flat.astype(dtype), p["w_router"].astype(dtype),
+                         preferred_element_type=jnp.float32)
+    experts, gates = moe.route_sigmoid_grouped(
+        logits, p["router_bias"], n_groups=cfg.n_expert_groups,
+        groups_kept=cfg.n_groups_per_token, top_k=cfg.n_experts_per_token,
+        scale=cfg.routed_scale)
+    routed, pairs = moe.held_experts_ffn(
+        flat.astype(dtype), experts, gates,
+        *(_plain_weights(p[name], dtype) for name in EXPERT_LEAVES),
+        cfg.expert_first, **kernel_opts)
+    shared = _swiglu(p, flat, ("ws_gate", "ws_up", "ws_down"), dtype)
+    y = (shared.astype(jnp.float32) + routed).astype(dtype)
+    return y.reshape(B, S, d), pairs.astype(jnp.float32)
+
+
+def _layer(p: Params, x: jax.Array, positions, state, cfg, kernel_opts,
+           ffn: str = "dense"):
     dtype = cfg.compute_dtype
     h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
     mixed, state = MIXERS[cfg.mixer](p, h, positions, state, cfg, kernel_opts)
     x = x + linear(p["wo"], mixed, dtype)
     n = rms_norm(x, p["ln2"], cfg.rms_norm_eps)
-    ff = jax.nn.silu(linear(p["w_gate"], n, dtype)) * linear(p["w_up"], n, dtype)
-    return x + linear(p["w_down"], ff, dtype), state
+    if ffn == "experts":
+        y, pairs = _experts_ffn(p, n, cfg, kernel_opts)
+        return x + y, state, pairs
+    return (x + _swiglu(p, n, ("w_gate", "w_up", "w_down"), dtype), state,
+            jnp.zeros((), jnp.float32))
 
 
 def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
                     state, cfg: DecoderLMConfig, **kernel_opts):
     """One fixed-shape segment of a document: ids [B, S] int32, ``pos0`` the
     position of its first token, ``state`` what the previous segment
-    returned (``None``: the document starts here; a pytree with a leading
-    layer axis otherwise). Returns the final-normed hidden states [B, S, d]
-    and the state after the segment."""
-    dtype = cfg.compute_dtype
-    x = params["embed"][ids].astype(dtype)
+    returned, or :func:`init_state` for the document's first. The mixer's
+    state is a pytree with a leading layer axis (``None``: a mixer that
+    starts from nothing); where the model has expert layers it comes inside
+    ``{"mixer": ..., "pairs": ...}``, ``pairs`` the running count of (token,
+    expert) pairs routed to the experts held here. Returns the final-normed
+    hidden states [B, S, d] and the state after the segment."""
+    x = params["embed"][ids].astype(cfg.compute_dtype)
     positions = pos0.astype(jnp.int32) + jnp.arange(ids.shape[1])
+    routed = bool(cfg.n_experts)
+    mixer_state = state["mixer"] if routed and state is not None else state
+    pairs = state["pairs"] if routed and state is not None else jnp.zeros(
+        (), jnp.float32)
+    new_states = []
+    for group, ffn, first, n in cfg.layer_groups:
+        mine = None if mixer_state is None else jax.tree_util.tree_map(
+            lambda a: a[first:first + n], mixer_state)
 
-    def step(x, xs):
-        p, st = xs if state is not None else (xs, None)
-        x, st = _layer(p, x, positions, st, cfg, kernel_opts)
-        return x, st
+        def step(carry, xs, ffn=ffn, mine=mine):
+            x, pairs = carry
+            p, st = xs if mine is not None else (xs, None)
+            x, st, more = _layer(p, x, positions, st, cfg, kernel_opts, ffn)
+            return (x, pairs + more), st
 
-    xs = params["layers"] if state is None else (params["layers"], state)
-    x, new_state = jax.lax.scan(step, x, xs)
-    return rms_norm(x, params["final_norm"], cfg.rms_norm_eps), new_state
+        xs = params[group] if mine is None else (params[group], mine)
+        (x, pairs), st = jax.lax.scan(step, (x, pairs), xs)
+        new_states.append(st)
+    mixer_state = new_states[0] if len(new_states) == 1 else \
+        jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, 0), *new_states)
+    hidden = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    return hidden, ({"mixer": mixer_state, "pairs": pairs} if routed
+                    else mixer_state)
+
+
+def segment_flops(cfg: DecoderLMConfig, n_tokens: int, pos0: int) -> float:
+    """Forward FLOPs the program does for one dispatched segment of
+    ``n_tokens`` tokens that starts at ``pos0`` (matmul terms, the ``device_
+    mfu{op}`` numerator): projections and feed-forwards per layer, the
+    mixer's own count, the untied head."""
+    d, t = float(cfg.d_model), float(n_tokens)
+    if cfg.mixer == "sparse_mla":
+        h, qr, kvr = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        hi, di = cfg.index_n_heads, cfg.index_head_dim
+        proj = 2.0 * (d * qr + qr * h * (dn + dr) + d * (kvr + dr)
+                      + h * dv * d + qr * hi * di + d * di + d * hi)
+        # Every causal pair: index scores, then the dense masked attention
+        # on expanded keys; the expansion of the keys seen so far.
+        seen = pos0 + t / 2.0
+        mixer = (2.0 * hi * di + 2.0 * h * (dn + dr + dv)) * seen + (
+            2.0 * kvr * h * (dn + dv) * (pos0 + t) / t)
+    else:
+        from agent_tpu.kernels.power_retention import retention_chunk
+
+        chunk, dh = retention_chunk(n_tokens), cfg.d_head
+        hq, hkv = float(cfg.n_heads * dh), float(cfg.n_kv_heads * dh)
+        proj = 2.0 * d * (2.0 * hq + 2.0 * hkv + cfg.n_kv_heads)
+        state = 2.0 * (dh // 2 + 1) * dh * dh
+        mixer = cfg.n_heads * (4.0 * chunk * dh + state) + cfg.n_kv_heads * state
+    dense = 6.0 * d * cfg.d_ff
+    experts = 2.0 * d * cfg.n_experts + 6.0 * d * cfg.d_expert * (
+        cfg.n_shared_experts + cfg.n_experts_per_token
+        * cfg.n_experts_held / max(1, cfg.n_experts))
+    ffn = sum(n * (experts if kind == "experts" else dense)
+              for _, kind, _, n in cfg.layer_groups)
+    return t * (cfg.n_layers * (proj + mixer) + ffn
+                + 2.0 * d * cfg.vocab_size)
 
 
 def blocked_logprobs(hidden: jax.Array, head: jax.Array, targets: jax.Array,

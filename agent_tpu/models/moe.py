@@ -217,3 +217,105 @@ def init_moe_block(key: jax.Array, cfg: MoeConfig) -> Params:
         "ln": layers.init_layer_norm(cfg.d_model),
         "moe": init_moe_ffn(key, cfg),
     }
+
+
+# ---- a held share of a group-limited, sigmoid-scored expert layer ---------
+#
+# The decoder language-model family's expert layer (``models/decoder_lm.py``):
+# the router scores ALL ``n_experts`` and picks ``top_k`` of them a token; the
+# chip holds ``w_gate.shape[0]`` of them, ids ``first ..``, and computes the
+# part of the result its own experts give. No capacity: no token is dropped.
+# What the experts held elsewhere would add is left out here, as on one chip
+# of an expert-parallel deployment before the combine.
+
+def route_sigmoid_grouped(logits: jax.Array, bias: jax.Array, *,
+                          n_groups: int, groups_kept: int, top_k: int,
+                          scale: float):
+    """logits [S, E] float32 → ``(experts [S, top_k] int32, gates [S, top_k]
+    float32)``. Scores are ``sigmoid(logits)``; the CHOICE is by score +
+    ``bias``: the experts in ``n_groups`` consecutive groups, a group's score
+    the sum of its two largest, the ``groups_kept`` best groups kept, the
+    ``top_k`` best experts among them (ties to the lower index). Gates are
+    the chosen experts' scores (no bias) over their sum, times ``scale``."""
+    S, E = logits.shape
+    score = jax.nn.sigmoid(logits.astype(jnp.float32))
+    choice = score + bias.astype(jnp.float32)
+    if n_groups > 1:
+        grouped = choice.reshape(S, n_groups, E // n_groups)
+        group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)
+        kept = jax.lax.top_k(group_score, groups_kept)[1]        # [S, kept]
+        keep = (kept[:, :, None] == jnp.arange(n_groups)).any(axis=1)
+        choice = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(S, E)
+    experts = jax.lax.top_k(choice, top_k)[1].astype(jnp.int32)
+    picked = jnp.take_along_axis(score, experts, axis=1)
+    return experts, scale * picked / picked.sum(axis=-1, keepdims=True)
+
+
+def _held_dense(x, local, gates, w_gate, w_up, w_down):
+    """Every held expert on every token, weighted by the token's gate for
+    it (0 where it did not choose it): the tests' sizes and off the chip."""
+    f32 = jnp.float32
+
+    def one(e, acc):
+        g = jnp.where(local == e, gates, 0.0).sum(axis=-1)            # [S]
+        h = jax.nn.silu(jnp.dot(x, w_gate[e])) * jnp.dot(x, w_up[e])
+        return acc + g[:, None] * jnp.dot(h, w_down[e]).astype(f32)
+
+    return jax.lax.fori_loop(0, w_gate.shape[0], one,
+                             jnp.zeros(x.shape, f32))
+
+
+def held_experts_ffn(x: jax.Array, experts: jax.Array, gates: jax.Array,
+                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                     first: int, *, pallas=None, interpret=None):
+    """x [S, d]; experts, gates [S, k] of :func:`route_sigmoid_grouped`;
+    w_gate, w_up [Eh, d, f], w_down [Eh, f, d]: experts ``first .. first +
+    Eh - 1``. Returns ``(sum over a token's chosen experts HELD HERE of gate
+    x SwiGLU_e(x), float32 [S, d]; how many (token, expert) pairs that
+    were)``. On the chip: pairs sorted by expert, each expert's rows padded
+    to whole tiles, one grouped matmul over the tiles that hold rows
+    (``kernels/grouped_ffn.py``)."""
+    from agent_tpu.kernels import grouped_ffn
+
+    S, k = experts.shape
+    n_held, d, fe = w_gate.shape
+    local = experts - first
+    held = (local >= 0) & (local < n_held)
+    pairs = held.sum()
+    if pallas is None:
+        pallas = jax.default_backend() == "tpu"
+    if not (pallas and grouped_ffn.pallas_supported(d, fe, x.dtype)):
+        return _held_dense(x, jnp.where(held, local, -1), gates, w_gate,
+                           w_up, w_down), pairs
+    from agent_tpu.kernels.flash_attention import resolve_interpret
+
+    tm = grouped_ffn.ROW_TILE
+    n_pairs = S * k
+    n_tiles_max = -(-n_pairs // tm) + n_held
+    of_pair = jnp.where(held, local, n_held).reshape(n_pairs)
+    order = jnp.argsort(of_pair, stable=True)
+    counts = (of_pair[None, :] == jnp.arange(n_held)[:, None]).sum(axis=1)
+    first_pair = jnp.cumsum(counts) - counts       # in the sorted pairs
+    tiles = (counts + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles)
+    first_row = (tile_end - tiles) * tm
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        tile_end, jnp.arange(n_tiles_max), side="right"), n_held - 1)
+    row = jnp.arange(n_tiles_max * tm)
+    e_row = tile_expert[row // tm]
+    j = row - first_row[e_row]
+    token = order[jnp.clip(first_pair[e_row] + j, 0, n_pairs - 1)] // k
+    x_rows = x[jnp.where(j < counts[e_row], token, 0)]
+    y_rows = grouped_ffn.grouped_swiglu(
+        x_rows, tile_expert, tile_end[-1], w_gate, w_up, w_down,
+        interpret=resolve_interpret(interpret))
+    place = jnp.zeros((n_pairs,), jnp.int32).at[order].set(
+        jnp.arange(n_pairs, dtype=jnp.int32))
+    e_pair = jnp.minimum(of_pair, n_held - 1)
+    row_of = jnp.where(held.reshape(n_pairs),
+                       first_row[e_pair] + place - first_pair[e_pair], 0)
+    y_pairs = y_rows[row_of].reshape(S, k, d).astype(jnp.float32)
+    # Rows of tiles that hold nothing are never written: select, not multiply.
+    y = jnp.where(held[:, :, None], y_pairs * gates[:, :, None],
+                  0.0).sum(axis=1)
+    return y, pairs

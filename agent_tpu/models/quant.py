@@ -555,29 +555,35 @@ def quantize_t5_specs(specs: Params, mode: str = "int8") -> Params:
 
 def _quantize_stacked(w: jax.Array, wkey: str) -> Params:
     """Symmetric per-output-channel int8 of a layer-stacked ``[n, in, out]``
-    leaf, ON THE DEVICE (the decoder family builds its weights there: 2.6 G
-    of them through host numpy would be minutes): the same scale and
-    rounding rule as :func:`quantize_weight`, contracting axis 1."""
+    leaf (or ``[n, experts, in, out]``), ON THE DEVICE (the decoder family
+    builds its weights there: 2.6 G of them through host numpy would be
+    minutes): the same scale and rounding rule as :func:`quantize_weight`,
+    contracting the axis before the last."""
     wf = w.astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(wf), axis=1, keepdims=True),
+    scale = jnp.maximum(jnp.max(jnp.abs(wf), axis=-2, keepdims=True),
                         _EPS) / _QMAX
     w_q = jnp.clip(jnp.round(wf / scale), -_QMAX, _QMAX).astype(jnp.int8)
-    return {wkey: w_q, "w_scale": scale[:, 0, :]}
+    return {wkey: w_q, "w_scale": scale[..., 0, :]}
 
 
 def quantize_decoder_lm(params: Params, mode: str = "int8") -> Params:
     """The decoder language-model family (``models/decoder_lm.py``): the
-    projection and FFN leaves of the scanned layer stack become int8 tables
-    (W8A8 or W8A16 by ``mode``); embedding, head, norms and the gate stay.
+    projection, FFN and expert leaves (``LINEAR_LEAVES``) of every scanned
+    layer group become int8 tables (W8A8 or W8A16 by ``mode``; the held
+    experts' tables are multiplied out again where the grouped matmul takes
+    them: their weights are rounded, their activations not); embedding,
+    head, norms, the retention gate and the indexer's head weights stay.
     ``params`` is CONSUMED: each bf16 leaf is dropped as its table is made,
     so the peak is one leaf's float32 copy over the stored model."""
     from agent_tpu.models.decoder_lm import LINEAR_LEAVES
 
     wkey = "w_q" if mode == "int8" else "w8"
     fn = jax.jit(_quantize_stacked, static_argnames="wkey")
-    layer_leaves = params["layers"]     # consumed: replaced leaf by leaf
-    for name in LINEAR_LEAVES:
-        layer_leaves[name] = fn(layer_leaves.pop(name), wkey=wkey)
+    for group in ("layers", "expert_layers"):
+        layer_leaves = params.get(group, {})   # consumed: leaf by leaf
+        for name in LINEAR_LEAVES:
+            if name in layer_leaves:
+                layer_leaves[name] = fn(layer_leaves.pop(name), wkey=wkey)
     return params
 
 

@@ -617,6 +617,32 @@ def record_retention_tokens(path: str, tokens: int) -> None:
                float(tokens), path=path)
 
 
+def record_sparse_attention_keys(kind: str, keys: int) -> None:
+    """Keys the real tokens of a DISPATCHED shard attend under a learned
+    top-k selection (``selected``) and the causal keys they could
+    (``causal``), a layer: counted by the op from the documents' lengths."""
+    if keys > 0:
+        _count("sparse_attention_keys_total",
+               "Keys attended by the tokens dispatched to a sparse-attention "
+               "mixer, a layer: under the selection (selected) and all "
+               "causal keys (causal)",
+               float(keys), kind=kind)
+
+
+def record_moe_routing(pairs: float, tokens: int) -> None:
+    """What a FETCHED shard's expert layers routed: (token, expert) pairs
+    that went to the experts held here (counted on the device, fetched with
+    the shard's answer) and the token slots the expert layers saw (dispatched
+    tokens, padding included, x expert layers)."""
+    if tokens > 0:
+        _count("moe_expert_pairs_total",
+               "(token, expert) pairs routed to the experts this process "
+               "holds, over all expert layers", float(pairs))
+        _count("moe_tokens_total",
+               "Token slots dispatched to expert layers (tokens x expert "
+               "layers, padding included)", float(tokens))
+
+
 def record_lm_segments(op: str, segments: int) -> None:
     """Fixed-shape segment programs an op dispatched (a document longer
     than one program runs as several, the state handed on on the device)."""

@@ -62,23 +62,6 @@ def seq2seq_fwd_flops(
     return enc + dec
 
 
-def decoder_lm_fwd_flops(
-    tokens: int, d_model: int, d_ff: int, n_layers: int, n_heads: int,
-    n_kv_heads: int, d_head: int, vocab_size: int, chunk: int,
-) -> float:
-    """Forward FLOPs of ``tokens`` dispatched tokens through a decoder
-    language model whose mixer is chunked power retention: the grouped-query
-    projections, gate and SwiGLU FFN per layer; the retention chunk (its
-    c x c block in full, the state read and a key-value head's update); the
-    untied head over the whole vocabulary."""
-    d, f = float(d_model), float(d_ff)
-    hq, hkv = float(n_heads * d_head), float(n_kv_heads * d_head)
-    proj = 2.0 * d * (2.0 * hq + 2.0 * hkv + n_kv_heads) + 6.0 * d * f
-    state = 2.0 * (d_head // 2 + 1) * d_head * d_head
-    mixer = n_heads * (4.0 * chunk * d_head + state) + n_kv_heads * state
-    return tokens * (n_layers * (proj + mixer) + 2.0 * d * vocab_size)
-
-
 def stamp_device_flops(ctx, flops: float, shape: str) -> None:
     """Accumulate an op's analytic-FLOPs estimate (and its dominant shape
     bucket) into ``ctx.tags["device_attr"]`` — the channel the agent's

@@ -18,15 +18,27 @@ New to the op layer: a ROW LONGER THAN ONE PROGRAM. ``_model_common``
 budgets a batch of short rows; here a document runs as fixed-shape SEGMENTS
 (``SEGMENT_BUCKETS`` tokens, one document a program) and the mixer's state
 goes from one segment program to the next as device arrays, never through
-the host. The loss head is a program of its own (``lm_loss_head``) that
-never holds more than a [segment, 4,096] block of logits (the full
-[L, 151,936] float32 logits of a 32 k document would be 19.9 GB).
+the host. What that state is depends on the mixer (``models/decoder_lm.py``):
 
-Programs have fixed shapes: per segment bucket one program for a document's
-first segment (no state comes in: its first chunk is the quadratic form
-alone) and one for every later segment, plus the head. There is no CPU
-retry: a device failure fails the shard, as ``allow_fallback: false`` does
-for the classify op.
+- ``power_retention``: a FIXED-SIZE retention state a layer; nothing comes
+  into a document's first segment (its first chunk is the quadratic form
+  alone), so per segment bucket there is one program for a first segment
+  and one for every later one;
+- ``sparse_mla``: a CACHE that grows with position (a latent vector and an
+  index key a token a layer), allocated on the device at the document's
+  PADDED length (the sum of its segments: not ``max_len``), donated from
+  program to program and written in place; every segment runs the one
+  program that takes a state, keyed by that length, and the kernels bound
+  their key loops by the segment's first position. Where the model has
+  expert layers the state also carries the count of (token, expert) pairs
+  routed to the experts held here; it comes back with the block sums in the
+  one fetch.
+
+The loss head is a program of its own (``lm_loss_head``) that never holds
+more than a [segment, 4,096] block of logits (the full [L, 151,936] float32
+logits of a 32 k document would be 19.9 GB). Programs have fixed shapes.
+There is no CPU retry: a device failure fails the shard, as
+``allow_fallback: false`` does for the classify op.
 """
 
 from __future__ import annotations
@@ -147,9 +159,11 @@ def _build_params(runtime, model_id: str, cfg):
     return maybe_quantize_params(params, FAMILY, cfg)
 
 
-def _programs(runtime, cfg, bucket: int):
+def _programs(runtime, cfg, bucket: int, cache_len: int):
     """(first-segment program, later-segment program, loss head) of one
-    segment bucket; jit wrappers from the runtime's keyed cache. The XLA
+    segment bucket; jit wrappers from the runtime's keyed cache.
+    ``cache_len``: the document's padded length where the mixer's state is
+    allocated at it (a shape of the later-segment program), else 0. The XLA
     module names (``jit_lm_segment``, ``jit_lm_loss_head``) are what a
     trace shows and what the benchmark's readers match."""
     import jax
@@ -188,25 +202,64 @@ def _programs(runtime, cfg, bucket: int):
     key = (bucket, cfg_key(cfg))
     return (
         runtime.compiled((OP, "segment", False, *key), build_segment(False)),
-        runtime.compiled((OP, "segment", True, *key), build_segment(True)),
+        runtime.compiled((OP, "segment", True, cache_len, *key),
+                         build_segment(True)),
         runtime.compiled((OP, "loss_head", *key), build_head),
     )
 
 
-def _count_tokens(state: Dict[str, Any]) -> Tuple[int, int, int, int]:
-    """(segments, dispatched tokens, real tokens whose chunk read a carried
-    state, real tokens whose chunk was the quadratic form alone)."""
+def _empty_state(runtime, cfg, cache_len: int):
+    """The state before a document's first segment, made on the device
+    (``None``: the mixer starts from nothing)."""
+    import jax
+
+    from agent_tpu.models import decoder_lm
+    from agent_tpu.ops._model_common import cfg_key
+
+    if decoder_lm.starts_from_nothing(cfg):
+        return None
+
+    def build():
+        def lm_state():
+            return decoder_lm.init_state(cfg, 1, cache_len)
+
+        return jax.jit(lm_state, out_shardings=runtime.replicated())
+
+    return runtime.compiled((OP, "state", cache_len, cfg_key(cfg)), build)()
+
+
+def _record_retention(state: Dict[str, Any]) -> None:
+    """Real tokens whose chunk read a carried state, and those whose chunk
+    was the quadratic form alone (a document's first chunk)."""
     from agent_tpu.kernels.power_retention import retention_chunk
 
-    segments = dispatched = carried = alone = 0
+    carried = alone = 0
     for doc in state["docs"]:
         first = doc["segments"][0]
         quadratic = min(doc["n_tokens"], retention_chunk(first[0].shape[1]))
         alone += quadratic
         carried += doc["n_tokens"] - quadratic
-        segments += len(doc["segments"])
-        dispatched += sum(seg[0].shape[1] for seg in doc["segments"])
-    return segments, dispatched, carried, alone
+    obs_trace.record_retention_tokens("state", carried)
+    obs_trace.record_retention_tokens("quadratic", alone)
+
+
+def _record_sparse_keys(state: Dict[str, Any]) -> None:
+    """Keys the shard's real tokens attend under the selection, and the
+    causal keys they could: token t of a document sees ``t + 1`` keys and
+    keeps ``min(t + 1, index_topk)`` (a layer; from lengths alone)."""
+    topk = int(state["cfg"].index_topk)
+    selected = causal = 0
+    for doc in state["docs"]:
+        n, k = doc["n_tokens"], min(doc["n_tokens"], topk)
+        causal += n * (n + 1) // 2
+        selected += k * (k + 1) // 2 + (n - k) * topk
+    obs_trace.record_sparse_attention_keys("selected", selected)
+    obs_trace.record_sparse_attention_keys("causal", causal)
+
+
+# mixer → what the op counts of a shard at dispatch, from its lengths.
+_MIXER_COUNTERS = {"power_retention": _record_retention,
+                   "sparse_mla": _record_sparse_keys}
 
 
 def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, Any]:
@@ -216,12 +269,8 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
     import jax
     import jax.numpy as jnp
 
-    from agent_tpu.kernels.power_retention import retention_chunk
-    from agent_tpu.ops._model_common import (
-        cfg_key,
-        decoder_lm_fwd_flops,
-        stamp_device_flops,
-    )
+    from agent_tpu.models.decoder_lm import segment_flops
+    from agent_tpu.ops._model_common import cfg_key, stamp_device_flops
 
     state["t_exec0"] = time.perf_counter()
     cfg, model_id = state["cfg"], state["model_id"]
@@ -236,15 +285,19 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
         lambda: _build_params(runtime, model_id, cfg),
     )
     put = lambda a: jax.device_put(a, runtime.replicated())  # noqa: E731
-    programs: Dict[int, Tuple] = {}     # bucket -> its three programs
-    parts, layout = [], []
+    programs: Dict[Tuple[int, int], Tuple] = {}   # (bucket, cache) -> programs
+    parts, routed, layout = [], [], []
+    flops = 0.0
     for doc in state["docs"]:
-        carried = None
+        padded = sum(seg[0].shape[1] for seg in doc["segments"])
+        carried = _empty_state(runtime, cfg, padded)
+        cache_len = padded if carried is not None else 0
         for ids, targets, n_valid, pos0 in doc["segments"]:
             bucket = ids.shape[1]
-            if bucket not in programs:
-                programs[bucket] = _programs(runtime, cfg, bucket)
-            first, later, head = programs[bucket]
+            if (bucket, cache_len) not in programs:
+                programs[bucket, cache_len] = _programs(runtime, cfg, bucket,
+                                                        cache_len)
+            first, later, head = programs[bucket, cache_len]
             pos = put(np.int32(pos0))
             if carried is None:
                 hidden, carried = first(params, put(ids), pos)
@@ -252,22 +305,26 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
                 hidden, carried = later(params, put(ids), pos, carried)
             parts.append(head(hidden, params["head"], put(targets),
                               put(np.int32(n_valid))))
-        layout.append((sum(s[0].shape[1] for s in doc["segments"]),
-                       doc["n_tokens"]))
-    segments, dispatched, with_state, alone = _count_tokens(state)
-    obs_trace.record_lm_segments(OP, segments)
-    obs_trace.record_retention_tokens("state", with_state)
-    obs_trace.record_retention_tokens("quadratic", alone)
-    stamp_device_flops(ctx, decoder_lm_fwd_flops(
-        dispatched, cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.n_heads,
-        cfg.n_kv_heads, cfg.d_head, cfg.vocab_size,
-        retention_chunk(SEGMENT_BUCKETS[-1])),
+            flops += segment_flops(cfg, bucket, pos0)
+        if cfg.n_experts:
+            routed.append(carried["pairs"].reshape(1))
+        layout.append((padded, doc["n_tokens"]))
+    dispatched = sum(padded for padded, _ in layout)
+    obs_trace.record_lm_segments(
+        OP, sum(len(doc["segments"]) for doc in state["docs"]))
+    _MIXER_COUNTERS[cfg.mixer](state)
+    stamp_device_flops(
+        ctx, flops,
         f"B1xS{max(s[0].shape[1] for d in state['docs'] for s in d['segments'])}")
+    parts += routed
     state.update(
         # One array a shard, gathered on the device by the owner thread:
-        # one fetch, not one a segment.
+        # one fetch, not one a segment. Behind the block sums, where the
+        # model routes: a document's (token, expert) pairs held here.
         pending_dev=jnp.concatenate(parts) if len(parts) > 1 else parts[0],
-        layout=layout, device=runtime.platform,
+        layout=layout, device=runtime.platform, n_routed=len(routed),
+        moe_tokens=dispatched * sum(
+            n for _, kind, _, n in cfg.layer_groups if kind == "experts"),
         t_device=time.perf_counter(),
     )
     return state
@@ -283,6 +340,9 @@ def finalize(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, A
     with obs_trace.phase("fetch") as fetched:
         sums = np.asarray(state["pending_dev"], dtype=np.float64)
     state["t_ready"] = fetched.t1
+    if state["n_routed"]:
+        obs_trace.record_moe_routing(float(sums[-state["n_routed"]:].sum()),
+                                     state["moe_tokens"])
     if ctx is not None and hasattr(ctx, "tags"):
         ctx.tags.setdefault("timings", {}).update(
             stage_ms=round((state["t_staged"] - t0) * 1000.0, 3),

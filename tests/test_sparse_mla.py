@@ -1,0 +1,402 @@
+"""The ``sparse_mla`` mixer and the held share of an expert layer on the CPU
+at tiny widths: the kernels (Pallas, interpret mode) against the same
+arithmetic in ``jax.numpy``; the family through ``map_score_lm`` against the
+benchmark's plain reference on documents LONGER than the selection and than
+one segment program; the reference WITHOUT the selection (it must miss: the
+check sees the mechanism) and WITH the served selection (what of a gap the
+selection's near-ties carry); the shares of an expert layer against the uncut
+layer; and that the family's longer ``LEAVES`` left the first mixer's weights
+as they were.
+
+Tolerance: ``dtype: float32`` here, so the op computes what the reference
+computes in another order: 2e-5 nats a token (float32 reordering), 0.02 on a
+block sum of 1,024 tokens, where both attend the same keys. A query whose
+``index_topk``-th and next scores lie closer than that reordering may keep the
+other key: one token's log-probability moves, by up to a few tenths."""
+
+from __future__ import annotations
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from agent_tpu.kernels import grouped_ffn, sparse_mla
+from agent_tpu.models import decoder_lm, moe
+from agent_tpu.ops import get_op
+from agent_tpu.runtime.runtime import reset_runtime
+from benchmarks.harness import manifest
+
+ref = manifest.load_reference("sparse_mla_lm")
+
+# 16 index heads: a score is a sum of 16 rectified products, so an exact 0
+# (every head negative), the one tie a tiny model can make, is a 2^-16 event.
+TINY = {"vocab_size": 3000, "d_model": 64, "n_heads": 4, "d_ff": 96,
+        "n_layers": 2, "max_len": 163840, "mixer": "sparse_mla",
+        "dtype": "float32", "q_lora_rank": 48, "kv_lora_rank": 32,
+        "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+        "index_n_heads": 16, "index_head_dim": 16, "index_topk": 16,
+        "rope_theta": 10000.0, "rope_factor": 40.0,
+        "rope_original_max_len": 4096, "n_dense_layers": 1, "n_experts": 16,
+        "n_experts_held": 4, "expert_first": 0, "n_experts_per_token": 4,
+        "n_expert_groups": 4, "n_groups_per_token": 2, "d_expert": 32,
+        "n_shared_experts": 1, "routed_scale": 2.5}
+REF_CFG = {**TINY, "rms_norm_eps": 1e-6}
+TOKEN_TOL = 2e-5
+BF16 = jnp.bfloat16
+
+
+# ---- the kernels against the plain arithmetic -----------------------------
+
+@pytest.mark.parametrize("pos0", [0, 512])
+def test_kernels_equal_the_plain_arithmetic(pos0):
+    """One 512-token segment against a 1,024-key cache at lane-wide heads,
+    as a document's first segment (half the cache not yet there) and as its
+    second: the selection key for key, the attention to bf16 rounding."""
+    S, Lk, H, Hi, D, dr, topk = 512, 1024, 4, 16, 128, 64, 64
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    qi = jax.random.normal(ks[0], (S, Hi, D), BF16)
+    w = jax.random.normal(ks[1], (S, Hi), jnp.float32)
+    ki = jax.random.normal(ks[2], (Lk, D), BF16)
+    p = jnp.int32(pos0)
+    assert sparse_mla.index_supported(S, Lk, Hi, D, BF16)
+    assert sparse_mla.attention_supported(S, Lk, H, D, D, BF16)
+    kernel = np.asarray(sparse_mla.index_select(
+        qi, w, ki, p, topk, pallas=True, interpret=True))
+    plain = np.asarray(sparse_mla.index_select(qi, w, ki, p, topk,
+                                               pallas=False))
+    assert kernel.shape == (1, S, Lk) and kernel.dtype == np.int8
+    np.testing.assert_array_equal(kernel, plain)
+    dense = plain.transpose(1, 0, 2).reshape(S, Lk)
+    t = pos0 + np.arange(S)
+    np.testing.assert_array_equal(dense.sum(axis=1), np.minimum(t + 1, topk))
+    assert not dense[np.arange(Lk)[None, :] > t[:, None]].any()
+
+    qn = jax.random.normal(ks[3], (H, S, D), BF16)
+    qr = jax.random.normal(ks[4], (H, S, dr), BF16)
+    kn = jax.random.normal(ks[5], (H, Lk, D), BF16)
+    kr = jax.random.normal(ks[6], (Lk, dr), BF16)
+    v = jax.random.normal(ks[7], (H, Lk, D), BF16)
+    args = (qn * 0.1, qr * 0.1, kn, kr, v, jnp.asarray(plain), p)
+    got = sparse_mla.masked_attention(*args, pallas=True, interpret=True)
+    want = sparse_mla.masked_attention(*args, pallas=False)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+
+
+@pytest.mark.parametrize("n_keys", [512, 700, 2048])
+def test_expansion_writes_the_keys_a_segment_can_see(n_keys):
+    """Per-head keys and values of the first ``n_keys`` latents (whole
+    1,024-key tiles of them) equal the plain einsum's; what lies past them is
+    the kernel's not to write."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 2)
+    c = jax.random.normal(ks[0], (2048, 512), BF16)
+    w = (jax.random.normal(ks[1], (8, 512, 256)) / 22.0).astype(BF16)
+    k, v = sparse_mla.expand_latents(c, w, jnp.int32(n_keys), 128,
+                                     pallas=True, interpret=True)
+    k_plain, v_plain = sparse_mla.expand_latents(c, w, jnp.int32(n_keys), 128,
+                                                 pallas=False)
+    assert k.shape == v.shape == (8, 2048, 128) and k.dtype == BF16
+    seen = -(-n_keys // 1024) * 1024
+    for got, want in ((k, k_plain), (v, v_plain)):
+        np.testing.assert_allclose(np.asarray(got[:, :seen], np.float32),
+                                   np.asarray(want[:, :seen], np.float32),
+                                   atol=2e-2)
+
+
+def test_shapes_off_the_kernels_take_the_plain_path():
+    index, attend = sparse_mla.index_supported, sparse_mla.attention_supported
+    assert index(4096, 32768, 64, 128, BF16)
+    assert attend(4096, 32768, 128, 128, 128, BF16)
+    assert not index(4096, 32768, 64, 128, jnp.float32)
+    assert not index(4096, 65536, 64, 128, BF16)         # keys past VMEM
+    assert not attend(4096, 65536, 128, 128, 128, BF16)
+    assert not index(4096, 32768, 64, 64, BF16)
+    assert not attend(4096, 32768, 128, 64, 128, BF16)
+    assert not attend(4000, 32768, 128, 128, 128, BF16)
+    assert sparse_mla.key_tile(256) == 256 and sparse_mla.key_tile(32768) == 1024
+
+
+@pytest.mark.parametrize("first", [0, 4, 28])
+def test_grouped_matmul_equals_every_expert_on_every_token(monkeypatch, first):
+    """The sorted, tile-padded grouped matmul (interpret mode, 64-row tiles
+    so that experts span tiles and leave tails) against the held experts
+    applied densely; the pair count is the router's."""
+    monkeypatch.setattr(grouped_ffn, "ROW_TILE", 64)
+    S, d, fe, E, held, k = 512, 128, 256, 32, 4, 4
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    x = jax.random.normal(ks[0], (S, d), BF16)
+    experts, gates = moe.route_sigmoid_grouped(
+        jax.random.normal(ks[1], (S, E), jnp.float32), jnp.zeros(E),
+        n_groups=4, groups_kept=2, top_k=k, scale=2.5)
+    np.testing.assert_allclose(np.asarray(gates.sum(-1)), 2.5, rtol=1e-5)
+    group = np.asarray(experts) // (E // 4)
+    assert all(len(set(row)) <= 2 for row in group)          # 2 groups kept
+    ws = [(jax.random.normal(key, shape) / np.sqrt(shape[1])).astype(BF16)
+          for key, shape in zip(ks[2:], [(held, d, fe), (held, d, fe),
+                                         (held, fe, d)])]
+    plain, n_plain = moe.held_experts_ffn(x, experts, gates, *ws, first,
+                                          pallas=False)
+    kernel, n_kernel = moe.held_experts_ffn(x, experts, gates, *ws, first,
+                                            pallas=True, interpret=True)
+    in_share = (np.asarray(experts) >= first) & (np.asarray(experts) < first + held)
+    assert int(n_plain) == int(n_kernel) == int(in_share.sum()) > 0
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(plain),
+                               atol=3e-2)
+
+
+# ---- the family against the reference -------------------------------------
+
+LONG = 4200             # 2,048 + 2,048 + 1,024 program tokens under BUCKETS
+# The op's segment sizes, halved for the CPU: three segment programs a
+# document at a quarter of the causal pairs (the 4,096-token segment compiles
+# for the chip in ``tests/test_tpu_compile.py`` and runs in the benchmark's
+# rehearsal of ``brumby-14b-base``).
+BUCKETS = (1024, 2048)
+
+
+def _short_segments() -> pytest.MonkeyPatch:
+    from agent_tpu.ops import map_score_lm
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(map_score_lm, "SEGMENT_BUCKETS", BUCKETS)
+    return mp
+
+
+@pytest.fixture(scope="module")
+def served():
+    """One document of three segments and a short one through
+    ``map_score_lm``: ``(documents, result)``."""
+    reset_runtime()
+    rng = np.random.default_rng(0)
+    docs = [rng.integers(0, TINY["vocab_size"], n).astype(np.int32)
+            for n in (LONG, 37)]
+    mp = _short_segments()
+    try:
+        out = get_op("map_score_lm")({
+            "ids": [d.tolist() for d in docs], "model_config": TINY,
+            "model_path": "sparse-a"})
+    finally:
+        mp.undo()
+    reset_runtime()
+    assert out["ok"] is True, out
+    return docs, out
+
+
+@pytest.fixture(scope="module")
+def selection(served):
+    """The long document once more through the family's own functions, segment
+    by segment as the op runs it, with every selection the program makes
+    kept aside: ``(block sums, one boolean [L, L] array a layer)``."""
+    from agent_tpu.ops.map_score_lm import _stage_document
+
+    cfg = decoder_lm.DecoderLMConfig(**TINY)
+    doc = served[0][0]
+    kept = []
+    real = sparse_mla.index_select
+
+    def recording(qi, w, ki, pos0, topk, **kw):
+        mask = real(qi, w, ki, pos0, topk, **kw)
+        jax.debug.callback(lambda m, p: kept.append(
+            (int(p), np.asarray(m))), mask, pos0, ordered=True)
+        return mask
+
+    params = decoder_lm.init_params(cfg, "sparse-a")
+    mp = _short_segments()
+    try:
+        segments = _stage_document(doc)["segments"]
+    finally:
+        mp.undo()
+    padded = sum(seg[0].shape[1] for seg in segments)
+    state = decoder_lm.init_state(cfg, 1, padded)
+    sums = []
+    mp = pytest.MonkeyPatch()
+    mp.setattr(sparse_mla, "index_select", recording)
+    try:
+        for ids, targets, n_valid, pos0 in segments:
+            hidden, state = jax.jit(lambda p, i, at, st: (
+                decoder_lm.forward_segment(p, i, at, st, cfg)))(
+                    params, ids, jnp.int32(pos0), state)
+            sums.append(np.asarray(decoder_lm.segment_block_sums(
+                hidden, params["head"], jnp.asarray(targets),
+                jnp.int32(n_valid))))
+        jax.effects_barrier()
+    finally:
+        mp.undo()
+    n_layers = cfg.n_layers
+    assert len(kept) == len(segments) * n_layers  # a call a layer a segment
+    keep = [np.zeros((padded, padded), bool) for _ in range(n_layers)]
+    for call, (pos0, mask) in enumerate(kept):
+        rows = mask.transpose(1, 0, 2).reshape(mask.shape[1], padded) != 0
+        keep[call % n_layers][pos0:pos0 + len(rows)] = rows
+    return np.concatenate(sums), [k[:LONG, :LONG] for k in keep]
+
+
+def _gaps(result, logprobs, docs):
+    return [np.abs(np.asarray(blocks) - ref.block_sums(lp))
+            for blocks, lp, _ in zip(result["block_logprob_sums"], logprobs, docs)]
+
+
+def test_documents_longer_than_the_selection_match_the_reference(
+        served, selection):
+    docs, out = served
+    sums, keep = selection
+    assert out["n_tokens"] == [LONG, 37]
+    assert [len(b) for b in out["block_logprob_sums"]] == [5, 1]
+    # The op's programs and the family's functions are one computation.
+    np.testing.assert_allclose(out["block_logprob_sums"][0], sums[:5],
+                               atol=1e-3)
+    # Every query past the first 16 selected: 16 of up to 4,200 keys.
+    assert all(k.sum(axis=1).max() == 16 and k.sum() < 16 * LONG for k in keep)
+    want = ref.token_logprobs(REF_CFG, "sparse-a", docs)
+    long_gap, short_gap = _gaps(out, want, docs)
+    assert short_gap.max() < TOKEN_TOL * 37
+    # The reference's own selection: blocks agree but where a near-tie fell
+    # the other way; no block is off by what a wrong mechanism would give.
+    assert np.median(long_gap) < TOKEN_TOL * 1024
+    assert long_gap.max() < 0.5
+    # Given the selection the program made, the reference agrees everywhere:
+    # all of the gap above is the selection's near-ties.
+    given = ref.token_logprobs(REF_CFG, "sparse-a", docs[:1], attend=keep)
+    np.testing.assert_allclose(out["block_logprob_sums"][0],
+                               ref.block_sums(given[0]),
+                               atol=TOKEN_TOL * 1024)
+
+
+def test_the_reference_without_the_selection_misses_by_ten_tolerances(served):
+    docs, out = served
+    causal = ref.token_logprobs(REF_CFG, "sparse-a", docs, attend="causal")
+    long_gap, short_gap = _gaps(out, causal, docs)
+    assert long_gap.min() > 10 * TOKEN_TOL * 1024
+    assert short_gap.max() > 10 * TOKEN_TOL * 37
+
+
+def test_logits_of_a_segment_match_the_reference():
+    """``forward_segment`` on one 200-token document (a 256-token cache, the
+    plain path) against the reference's whole-vocabulary logits."""
+    cfg = decoder_lm.DecoderLMConfig(**TINY)
+    params = decoder_lm.init_params(cfg, "sparse-b")
+    ids = np.random.default_rng(3).integers(0, 3000, 200).astype(np.int32)
+    padded = np.zeros((1, 256), np.int32)
+    padded[0, :200] = ids
+    hidden, state = jax.jit(lambda p, i, s: decoder_lm.forward_segment(
+        p, i, jnp.int32(0), s, cfg))(params, padded,
+                                     decoder_lm.init_state(cfg, 1, 256))
+    got = np.asarray(hidden[0, :200] @ params["head"].T)
+    want = ref.logits(REF_CFG, "sparse-b", ids, range(200))
+    np.testing.assert_allclose(got, want, atol=2e-4)
+    assert set(state) == {"mixer", "pairs"}
+    assert state["mixer"]["kv"].shape == (2, 1, 256, 40)
+    assert state["mixer"]["ki"].shape == (2, 1, 256, 16)
+    assert 0 < float(state["pairs"]) <= 256 * 4
+
+
+def test_bf16_is_near_the_reference_and_the_int8_control_further_off():
+    """The control's tables: every projection leaf (the router's too), the
+    feed-forwards and the held experts ``[layers, experts, in, out]``; its
+    logits lie further from the reference than the bf16 program's."""
+    from agent_tpu.models.quant import quantize_for_family
+
+    cfg = decoder_lm.DecoderLMConfig(**{**TINY, "dtype": "bfloat16"})
+    ids = np.random.default_rng(4).integers(0, 3000, 256).astype(np.int32)
+    want = ref.logits({**REF_CFG, "dtype": "bfloat16"}, "sparse-q", ids,
+                      range(256))
+
+    def gap(params):
+        hidden, _ = jax.jit(lambda p, i, s: decoder_lm.forward_segment(
+            p, i, jnp.int32(0), s, cfg))(params, ids[None],
+                                         decoder_lm.init_state(cfg, 1, 256))
+        got = hidden[0].astype(jnp.float32) @ params["head"].astype(
+            jnp.float32).T
+        return float(np.abs(np.asarray(got) - want).mean())
+
+    sound = gap(decoder_lm.init_params(cfg, "sparse-q"))
+    q = quantize_for_family("decoder_lm",
+                            decoder_lm.init_params(cfg, "sparse-q"), "int8")
+    experts = q["expert_layers"]
+    assert experts["we_up"]["w_q"].shape == (1, 4, 64, 32)
+    assert experts["we_up"]["w_q"].dtype == jnp.int8
+    assert experts["we_up"]["w_scale"].shape == (1, 4, 32)
+    assert experts["w_router"]["w_scale"].shape == (1, 16)
+    assert q["layers"]["w_ukv"]["w_q"].shape == (1, 32, 4 * 32)
+    assert q["layers"]["wi_w"].dtype == q["embed"].dtype == jnp.bfloat16
+    control = gap(q)
+    assert sound < 0.05 and control > 1.5 * sound, (sound, control)
+
+
+# ---- an expert layer and its shares ---------------------------------------
+
+def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
+    """Four chips of four experts each, and one that holds all sixteen: the
+    routed parts of the shares, with the shared expert counted once, are the
+    uncut layer; an expert's weights are the same wherever it is held; the
+    uncut layer is the reference's."""
+    whole = decoder_lm.DecoderLMConfig(**{**TINY, "n_experts_held": 16})
+    n = jax.random.normal(jax.random.PRNGKey(5), (1, 300, 64), jnp.float32)
+    layer = lambda p: jax.tree_util.tree_map(lambda a: a[0], p["expert_layers"])  # noqa: E731
+    p_whole = layer(decoder_lm.init_params(whole, "sparse-c"))
+    y_whole, pairs_whole = decoder_lm._experts_ffn(p_whole, n, whole, {})
+    shared = decoder_lm._swiglu(p_whole, n, ("ws_gate", "ws_up", "ws_down"),
+                                jnp.float32)
+    total, pairs = shared, 0.0
+    for first in (0, 4, 8, 12):
+        cfg = decoder_lm.DecoderLMConfig(**{**TINY, "expert_first": first})
+        p = layer(decoder_lm.init_params(cfg, "sparse-c"))
+        np.testing.assert_array_equal(
+            np.asarray(p["we_down"]),
+            np.asarray(p_whole["we_down"][first:first + 4]))
+        y, held_pairs = decoder_lm._experts_ffn(p, n, cfg, {})
+        total = total + (y - shared)
+        pairs += float(held_pairs)
+    assert pairs == float(pairs_whole) == 300 * 4     # every choice, once
+    np.testing.assert_allclose(np.asarray(total), np.asarray(y_whole),
+                               atol=1e-5)
+    # The reference's layer, uncut (it adds the residual; n is normed there).
+    u = n[0] * 3.0
+    cfg_ref = {**REF_CFG, "n_experts_held": 16}
+    with jax.default_matmul_precision("highest"):
+        want = ref.expert_layer_ffn(cfg_ref, "sparse-c", 1, u)
+    got = u + decoder_lm._experts_ffn(
+        p_whole, decoder_lm.rms_norm(u, p_whole["ln2"], 1e-6)[None], whole,
+        {})[0][0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+# ---- what the longer LEAVES left alone ------------------------------------
+
+def test_the_first_mixers_weights_are_what_they_were():
+    """``LEAVES`` grew at its end: leaf j keeps its key. The digest is of the
+    tree the parent of this change built for the same config and id."""
+    assert decoder_lm.LEAVES[:10] == (
+        "embed", "head", "wq", "wk", "wv", "wo", "wg", "w_gate", "w_up",
+        "w_down")
+    cfg = decoder_lm.DecoderLMConfig(vocab_size=300, d_model=64, n_heads=10,
+                                     n_kv_heads=2, d_head=16, d_ff=96,
+                                     n_layers=3)
+    params = decoder_lm.init_params(cfg, "digest-model")
+    assert set(params) == {"embed", "head", "final_norm", "layers"}
+    flat = {"/".join(str(k.key) for k in path): np.asarray(
+        leaf.astype(np.float32))
+        for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]}
+    digest = hashlib.sha256()
+    for name in sorted(flat):
+        digest.update(name.encode())
+        digest.update(flat[name].tobytes())
+    assert digest.hexdigest() == (
+        "f1233add1f94f8dae23074661cb866350e859602ab0b303c9cb7d1a7f31ecc34")
+
+
+@pytest.mark.parametrize("over, message", [
+    ({"qk_rope_head_dim": 7}, "qk_rope_head_dim"),
+    ({"index_head_dim": 4}, "index_head_dim"),
+    ({"expert_first": 14}, "experts held"),
+    ({"n_experts": 18}, "whole groups"),
+    ({"n_experts_per_token": 9}, "cannot choose"),
+    ({"n_dense_layers": 3}, "n_dense_layers"),
+    ({"index_topk": 0}, "index_topk"),
+])
+def test_validate_rejects_what_no_program_can_run(over, message):
+    with pytest.raises(ValueError, match=message):
+        decoder_lm.validate(decoder_lm.DecoderLMConfig(**{**TINY, **over}))

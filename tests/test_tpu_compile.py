@@ -293,3 +293,55 @@ def test_mesh_order_on_a_2x2_host(v5e, shape, want):
 
     mesh = build_mesh(v5e.devices, shape)
     assert [d.id for d in mesh.devices.flat] == want
+
+
+def test_sparse_mla_kernels_compile_for_v5e(v5e):
+    """The indexer-with-selection, the masked attention and the latents'
+    expansion at the ``deepseek-v3.2.score-32k`` cell's own shape: a 4,096-token segment
+    against a 32,768-token cache, 128 heads of 128 + 64, 64 index heads of
+    128, 2,048 kept. The index keys, a tile's whole score row and the mask
+    must fit the VMEM the indexer asks for; int8 mask tiles, a scalar-
+    prefetched position and dynamic loop bounds must lower."""
+    from agent_tpu.kernels import sparse_mla as sm
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    bf = jnp.bfloat16
+    S, Lk, H, Hi = 4096, 32768, 128, 64
+    assert sm.index_supported(S, Lk, Hi, 128, bf)
+    assert sm.attention_supported(S, Lk, H, 128, 128, bf)
+    index = jax.jit(lambda qi, w, ki, p: sm.index_select(
+        qi, w, ki, p, 2048, pallas=True, interpret=False)).lower(
+        sd((S, Hi, 128), bf), sd((S, Hi), jnp.float32), sd((Lk, 128), bf),
+        sd((), jnp.int32)).compile()
+    assert index.as_text().count("tpu_custom_call") == 1
+    attend = jax.jit(lambda qn, qr, kn, kr, v, m, p: sm.masked_attention(
+        qn, qr, kn, kr, v, m, p, pallas=True, interpret=False)).lower(
+        sd((H, S, 128), bf), sd((H, S, 64), bf), sd((H, Lk, 128), bf),
+        sd((Lk, 64), bf), sd((H, Lk, 128), bf),
+        sd((Lk // sm.KEY_TILE, S, sm.KEY_TILE), jnp.int8),
+        sd((), jnp.int32)).compile()
+    assert attend.as_text().count("tpu_custom_call") == 1
+    expand = jax.jit(lambda c, w, n: sm.expand_latents(
+        c, w, n, 128, pallas=True, interpret=False)).lower(
+        sd((Lk, 512), bf), sd((H, 512, 256), bf), sd((), jnp.int32)).compile()
+    assert expand.as_text().count("tpu_custom_call") == 1
+
+
+def test_grouped_expert_matmul_compiles_for_v5e(v5e):
+    """The grouped SwiGLU at the cell's own shape: every pair of a 4,096-token
+    segment routed here (the fixed-shape worst case: 8 a token) plus a tile
+    of padding an expert, 16 experts of 7,168 x 2,048."""
+    from agent_tpu.kernels import grouped_ffn as gf
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    bf = jnp.bfloat16
+    tiles = 4096 * 8 // gf.ROW_TILE + 16
+    assert gf.pallas_supported(7168, 2048, bf)
+    compiled = jax.jit(lambda x, te, n, g, u, d: gf.grouped_swiglu(
+        x, te, n, g, u, d, interpret=False)).lower(
+        sd((tiles * gf.ROW_TILE, 7168), bf), sd((tiles,), jnp.int32),
+        sd((), jnp.int32), sd((16, 7168, 2048), bf), sd((16, 7168, 2048), bf),
+        sd((16, 2048, 7168), bf)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
