@@ -18,7 +18,14 @@ scores that are sums of 64 float32 products a tie is an accident).
 
 :func:`masked_attention` is a streaming softmax over that mask with one
 shared rotary key and per-head expanded keys and values (``k = [c W_UK; kR]``,
-``v = c W_UV``: the expansion is the caller's, a matmul). Of the three forms
+``v = c W_UV``: the expansion is the caller's, a matmul). Its key loop has
+two levels: a grid step fetches a tile of 1,024 keys (the mask's layout) and
+turns scores into weights a sub-block of 256 at a time, with the row
+statistics lane-replicated and the next sub-block's score matmuls issued
+before this one's softmax, so the MXU is not kept waiting by the VPU: the
+kernel runs within 2 % of its own three matmuls alone (PERF.md section 5:
+the take-apart table). Float32 scores, statistics and accumulator; the
+weights are rounded to bf16 before the value matmul. Of the three forms
 the layer can take (gathered and absorbed, dense with expanded keys, dense
 and absorbed) this file ships the second: a TPU has no gather that feeds the
 MXU 2,048 scattered rows a query, and the absorbed dense form costs 3.4 x the
@@ -48,18 +55,35 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
-# Keys a tile, of both kernels (the mask's last axis): at 1,024 the
-# attention's rescale of its [queries, 128] accumulator, once a key tile, is
-# an eighth of the work on the [queries, keys] scores.
+# Keys a tile, of both kernels (the mask's last axis, and what a grid step of
+# the attention fetches): 3.1 MiB of keys, values and mask a step. The
+# attention works through it in sub-blocks (ATTN_KEY_BLOCK below).
 KEY_TILE = 1024
 # Queries a grid step of the indexer: the step's score row, [128, Lk] int32,
 # is 16 MB at 32,768 keys, beside the index keys (8 MB) in VMEM.
 INDEX_QUERY_TILE = 128
 # Index heads a matmul: [8 x 128, 128] x [128, 1024].
 INDEX_HEAD_GROUP = 8
-# Queries and heads a grid step of the attention.
+# Queries a segment comes in (the predicates' unit), and heads a grid step
+# of the attention.
 ATTN_QUERY_TILE = 512
 ATTN_HEAD_GROUP = 4
+# Queries a grid step of the attention where the segment has that many: a
+# loaded 128 x 128 block of keys then meets 1,024 query rows, not 512. The
+# kernel alone at 128 heads, 4,096 queries, 32,768 keys (PERF.md section 5),
+# last segment / first: 69.9 / 9.70 ms at 512, 68.3 / 8.38 at 1,024; its
+# three matmuls alone, no softmax: 69.2 / 9.54 and 67.4 / 8.28. Eight heads
+# a step read 67.2 at 1,024 and compile for 18 s, not 9.
+ATTN_QUERY_STEP = 1024
+# Keys a sub-block of the attention's inner loop: a [queries, 256] float32
+# score tile is produced, masked, exponentiated, summed and fed to the value
+# matmul while the next one's score matmuls are already issued. Measured as
+# above at 512 queries a step: whole tiles of 1,024 with [queries, 1]
+# statistics (what shipped before) 86.8 ms; the same with lane-replicated
+# statistics 77.5, and lane-wise sums 75.0; sub-blocks of 512 / 256 / 128
+# without the look-ahead 71.4 / 72.1 / 83.5 (the accumulator's rescale runs
+# once a sub-block), with it 72.3 / 70.0 / 75.2.
+ATTN_KEY_BLOCK = 256
 # Heads a grid step of the expansion.
 EXPAND_HEAD_GROUP = 8
 # Keys the indexer kernel holds in VMEM at once (index keys + score row).
@@ -358,13 +382,17 @@ def _masked_attention_jnp(q_nope, q_rope, k_nope, k_rope, v, mask):
 
 def _attention_kernel(pos_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
                       mask_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                      tq: int, tk: int, hb: int):
-    """One (head group, query tile, key tile) step of the streaming softmax."""
+                      tq: int, tk: int, hb: int, bk: int):
+    """One (head group, query tile, key tile) step of the streaming softmax:
+    the fetched key tile in sub-blocks of ``bk`` keys, a head after another.
+    ``m`` and ``l`` are kept ``[tq, 128]``: ``m`` the row's maximum in every
+    lane, ``l`` the row's sum spread over the lanes (summed at the end)."""
     f32 = jnp.float32
     i, j = pl.program_id(1), pl.program_id(2)
     n_kv = (pos_ref[0] + (i + 1) * tq + tk - 1) // tk
     nt = (((1,), (1,)), ((), ()))           # [m, k] x [n, k]
     nn = (((1,), (0,)), ((), ()))
+    groups = bk // _LANES
 
     @pl.when(j == 0)
     def _():
@@ -372,31 +400,48 @@ def _attention_kernel(pos_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
         l_ref[...] = jnp.zeros(l_ref.shape, f32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
 
+    def block(c):
+        return slice(c * bk, (c + 1) * bk)
+
+    def scores(a, c):
+        return jax.lax.dot_general(
+            qn_ref[a], kn_ref[a, block(c), :], nt, preferred_element_type=f32
+        ) + jax.lax.dot_general(
+            qr_ref[a], kr_ref[block(c), :], nt, preferred_element_type=f32)
+
     @pl.when(j < n_kv)
     def _():
-        keep = mask_ref[0].astype(jnp.int32) != 0               # [tq, tk]
-        kr = kr_ref[...]
-        for a in range(hb):
-            s = jax.lax.dot_general(qn_ref[a], kn_ref[a], nt,
-                                    preferred_element_type=f32)
-            s = s + jax.lax.dot_general(qr_ref[a], kr, nt,
-                                        preferred_element_type=f32)
+        steps = [(a, c) for a in range(hb) for c in range(tk // bk)]
+        keep = {}
+        ahead = scores(*steps[0])
+        for n, (a, c) in enumerate(steps):
+            s = ahead
+            # The next sub-block's score matmuls are issued before this
+            # one's softmax: the MXU has work while the VPU does its part.
+            if n + 1 < len(steps):
+                ahead = scores(*steps[n + 1])
+            if c not in keep:                 # widened once for the heads
+                keep[c] = mask_ref[0, :, block(c)].astype(jnp.int32) != 0
             # A row whose keys so far are all masked holds exp(0) = 1 a key
             # until its first kept key arrives; that key's alpha is 0.
-            s = jnp.where(keep, s, _MASKED)
-            m_prev = m_ref[a]
+            s = jnp.where(keep[c], s, _MASKED)
+            m_prev = m_ref[a]                                   # [tq, 128]
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
+            p = jnp.exp(s - jnp.concatenate([m_new] * groups, axis=1))
             alpha = jnp.exp(m_prev - m_new)
-            l_ref[a] = alpha * l_ref[a] + p.sum(axis=-1, keepdims=True)
+            lanes = p[:, :_LANES]
+            for g in range(1, groups):
+                lanes = lanes + p[:, g * _LANES:(g + 1) * _LANES]
+            l_ref[a] = alpha * l_ref[a] + lanes
             acc_ref[a] = alpha * acc_ref[a] + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[a], nn,
+                p.astype(v_ref.dtype), v_ref[a, block(c), :], nn,
                 preferred_element_type=f32)
             m_ref[a] = m_new
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l_ref[...].sum(
+            axis=-1, keepdims=True)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -406,7 +451,8 @@ def _masked_attention_call(q_nope, q_rope, k_nope, k_rope, v, mask, pos0, *,
     Lk, dr = k_rope.shape
     dv = v.shape[-1]
     tk = mask.shape[-1]
-    tq, hb = min(ATTN_QUERY_TILE, S), ATTN_HEAD_GROUP
+    tq = ATTN_QUERY_STEP if S % ATTN_QUERY_STEP == 0 else ATTN_QUERY_TILE
+    hb, bk = ATTN_HEAD_GROUP, ATTN_KEY_BLOCK
     n_tiles = Lk // tk
 
     def last(i, pos):
@@ -416,7 +462,7 @@ def _masked_attention_call(q_nope, q_rope, k_nope, k_rope, v, mask, pos0, *,
     def at(j, i, pos):
         return jnp.minimum(j, last(i, pos))
 
-    kernel = functools.partial(_attention_kernel, tq=tq, tk=tk, hb=hb)
+    kernel = functools.partial(_attention_kernel, tq=tq, tk=tk, hb=hb, bk=bk)
     pairs = H * S * Lk
     return pl.pallas_call(
         kernel,
@@ -438,8 +484,8 @@ def _masked_attention_call(q_nope, q_rope, k_nope, k_rope, v, mask, pos0, *,
             out_specs=pl.BlockSpec((hb, tq, dv),
                                    lambda h, i, j, pos: (h, i, 0)),
             scratch_shapes=[
-                pltpu.VMEM((hb, tq, 1), jnp.float32),
-                pltpu.VMEM((hb, tq, 1), jnp.float32),
+                pltpu.VMEM((hb, tq, _LANES), jnp.float32),
+                pltpu.VMEM((hb, tq, _LANES), jnp.float32),
                 pltpu.VMEM((hb, tq, dv), jnp.float32),
             ],
         ),

@@ -50,13 +50,52 @@ BF16 = jnp.bfloat16
 
 # ---- the kernels against the plain arithmetic -----------------------------
 
-@pytest.mark.parametrize("pos0", [0, 512])
-def test_kernels_equal_the_plain_arithmetic(pos0):
-    """One 512-token segment against a 1,024-key cache at lane-wide heads,
-    as a document's first segment (half the cache not yet there) and as its
-    second: the selection key for key, the attention to bf16 rounding."""
-    S, Lk, H, Hi, D, dr, topk = 512, 1024, 4, 16, 128, 64, 64
-    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+def _attention_operands(H, S, Lk, dr=64, D=128, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    qn = jax.random.normal(ks[3], (H, S, D), BF16) * 0.1
+    qr = jax.random.normal(ks[4], (H, S, dr), BF16) * 0.1
+    kn = jax.random.normal(ks[5], (H, Lk, D), BF16)
+    kr = jax.random.normal(ks[6], (Lk, dr), BF16)
+    v = jax.random.normal(ks[7], (H, Lk, D), BF16)
+    return ks, (qn, qr, kn, kr, v)
+
+
+def _mask_layout(dense):
+    """[S, Lk] bool → the kernels' [Lk / 1024, S, 1024] int8."""
+    S, Lk = dense.shape
+    return dense.reshape(S, Lk // 1024, 1024).transpose(1, 0, 2).astype(
+        np.int8)
+
+
+# Queries, pos0, cache keys, heads, kept keys, and whether the segment's last
+# 128 rows keep nothing but keys of the cache's last 128 they can see. A
+# segment of 512 is one query tile a grid step, one of 1,024 a whole step.
+KERNEL_CASES = {
+    "first_segment": (512, 0, 1024, 4, 64, False),
+    "second_segment": (512, 512, 1024, 4, 64, False),
+    # Four key tiles; the query tile's last key tile is half causal, the one
+    # before it whole, the two after it never fetched.
+    "half_causal_last_tile": (512, 1536, 4096, 4, 64, False),
+    # Every sub-block and tile before a row's first kept key is fully
+    # masked: the row carries exp(0) a key until that key's alpha of 0.
+    "kept_keys_in_the_last_sub_block": (512, 1536, 4096, 4, 64, True),
+    "two_head_groups": (512, 512, 1024, 8, 64, False),
+    "fewer_kept_than_a_sub_block": (512, 1536, 4096, 4, 16, False),
+    "every_key_tile": (512, 3584, 4096, 4, 200, False),
+    "a_whole_step_of_queries": (1024, 1536, 4096, 4, 64, False),
+    "a_whole_step_kept_keys_late": (1024, 1024, 4096, 4, 64, True),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_kernels_equal_the_plain_arithmetic(case):
+    """One segment of 512 or 1,024 tokens against a cache of one or four
+    1,024-key tiles at lane-wide heads, from a document's first segment (half
+    the cache not yet there) to its last: the selection key for key, the
+    attention to bf16 rounding."""
+    S, pos0, Lk, H, topk, late = KERNEL_CASES[case]
+    Hi, D = 16, 128
+    ks, operands = _attention_operands(H, S, Lk)
     qi = jax.random.normal(ks[0], (S, Hi, D), BF16)
     w = jax.random.normal(ks[1], (S, Hi), jnp.float32)
     ki = jax.random.normal(ks[2], (Lk, D), BF16)
@@ -67,21 +106,54 @@ def test_kernels_equal_the_plain_arithmetic(pos0):
         qi, w, ki, p, topk, pallas=True, interpret=True))
     plain = np.asarray(sparse_mla.index_select(qi, w, ki, p, topk,
                                                pallas=False))
-    assert kernel.shape == (1, S, Lk) and kernel.dtype == np.int8
+    tiles = Lk // 1024
+    assert kernel.shape == (tiles, S, 1024) and kernel.dtype == np.int8
     np.testing.assert_array_equal(kernel, plain)
     dense = plain.transpose(1, 0, 2).reshape(S, Lk)
     t = pos0 + np.arange(S)
     np.testing.assert_array_equal(dense.sum(axis=1), np.minimum(t + 1, topk))
     assert not dense[np.arange(Lk)[None, :] > t[:, None]].any()
 
-    qn = jax.random.normal(ks[3], (H, S, D), BF16)
-    qr = jax.random.normal(ks[4], (H, S, dr), BF16)
-    kn = jax.random.normal(ks[5], (H, Lk, D), BF16)
-    kr = jax.random.normal(ks[6], (Lk, dr), BF16)
-    v = jax.random.normal(ks[7], (H, Lk, D), BF16)
-    args = (qn * 0.1, qr * 0.1, kn, kr, v, jnp.asarray(plain), p)
+    if late:
+        key = np.arange(Lk)[None, :]
+        dense = dense.copy()
+        dense[S - 128:] = ((key >= pos0 + S - 128)
+                           & (key <= t[S - 128:, None]))
+        assert dense[S - 128:, :pos0 + S - 128].sum() == 0
+        plain = _mask_layout(dense)
+    args = (*operands, jnp.asarray(plain), p)
     got = sparse_mla.masked_attention(*args, pallas=True, interpret=True)
     want = sparse_mla.masked_attention(*args, pallas=False)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+
+
+@pytest.mark.parametrize("S", [512, 1024])
+def test_the_attention_never_reads_past_the_segments_last_key(S):
+    """Keys, rotary keys and values at and after ``pos0 + S`` hold NaN (the
+    expansion does not write them) and the mask there says "kept": the
+    output is finite, and bit for bit what clean operands give."""
+    pos0, Lk, H = 2048 - S, 4096, 4
+    _, (qn, qr, kn, kr, v) = _attention_operands(H, S, Lk, seed=3)
+    key = np.arange(Lk)
+    dense = (key[None, :] <= pos0 + np.arange(S)[:, None]) & (
+        (key[None, :] * 7 + np.arange(S)[:, None]) % 11 == 0)
+    mask = _mask_layout(dense)
+    p = jnp.int32(pos0)
+    clean = sparse_mla.masked_attention(qn, qr, kn, kr, v, jnp.asarray(mask),
+                                        p, pallas=True, interpret=True)
+    end = pos0 + S
+    poisoned = mask.copy()
+    poisoned[end // 1024:] = 1
+    got = sparse_mla.masked_attention(
+        qn, qr, kn.at[:, end:].set(jnp.nan), kr.at[end:].set(jnp.nan),
+        v.at[:, end:].set(jnp.nan), jnp.asarray(poisoned), p,
+        pallas=True, interpret=True)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(clean, np.float32))
+    want = sparse_mla.masked_attention(qn, qr, kn, kr, v, jnp.asarray(mask),
+                                       p, pallas=False)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
 
