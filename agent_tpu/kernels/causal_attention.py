@@ -1,0 +1,213 @@
+"""Plain causal grouped-query attention of one document's segment over the
+document's key and value cache: the attention half of the ``hybrid_ssm``
+mixer of the decoder language-model family (``models/decoder_lm.py``).
+
+The segment's ``S`` queries sit at positions ``pos0 .. pos0 + S`` and attend
+the keys ``0 .. pos0 + t`` of a cache allocated at the document's padded
+length. No mask array: causality is the positions' (``pos0`` comes in as a
+prefetched scalar), so a (query tile, key tile) pair above the diagonal is
+never fetched or computed, one across it is masked from two iotas, and one
+below it takes no mask at all. The ``G`` query heads of a key-value head meet
+ONE loaded key and value tile: their rows are stacked, ``[G * tile, D]``, so a
+key tile is fetched once for five heads and every matmul has 2,560 rows.
+
+A streaming softmax with float32 scores, statistics and accumulator; the row
+statistics are kept lane-replicated and the weights are rounded to bf16
+before the value matmul, as ``kernels/sparse_mla.py``'s attention does
+(PERF.md section 5 has that kernel's take-apart table). Which path runs is
+read from shapes and platform (:func:`pallas_supported`): the kernel on a TPU
+at lane-wide heads and whole tiles, the same arithmetic in ``jax.numpy``
+elsewhere. No option, environment variable or ``model_config`` key chooses.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_LANES = 128
+# Queries and keys a tile. A step's scores are [G * 512, 256] float32 a
+# sub-block (2.6 MB at five query heads a key-value head).
+QUERY_TILE = 512
+KEY_TILE = 512
+KEY_BLOCK = 256
+_VMEM_LIMIT = 100 * 1024 * 1024
+_MASKED = -1e30
+
+
+def _note() -> None:
+    from agent_tpu.obs.trace import record_attention_block
+
+    record_attention_block("causal_gqa")
+
+
+def pallas_supported(seq_len: int, cache_len: int, d_head: int, dtype) -> bool:
+    """Shapes the kernel takes on the chip: bf16 operands, lane-wide heads,
+    a segment of whole query tiles and a cache of whole key tiles."""
+    return bool(jnp.dtype(dtype) == jnp.bfloat16 and d_head == _LANES
+                and seq_len % QUERY_TILE == 0 and cache_len % KEY_TILE == 0)
+
+
+def visited_pairs(seq_len: int, pos0: int, query_tile: int = QUERY_TILE,
+                  key_tile: int = KEY_TILE) -> int:
+    """(query, key) pairs the kernel's grid computes for a segment of
+    ``seq_len`` queries at ``pos0``, a query head: a query tile meets the key
+    tiles up to the one that holds its last query's position."""
+    pairs = 0
+    for i in range(-(-seq_len // query_tile)):
+        last = pos0 + min(seq_len, (i + 1) * query_tile)
+        pairs += query_tile * (-(-last // key_tile)) * key_tile
+    return pairs
+
+
+def _attention_jnp(q, k, v, pos0):
+    """q [Hkv, G, S, D], k, v [Hkv, Lk, D]: dense scores, float32, a block of
+    query rows at a time. Keys at and after ``pos0 + S`` are taken out of
+    both products (a cache holds whatever was there)."""
+    f32 = jnp.float32
+    Hkv, G, S, D = q.shape
+    Lk = k.shape[1]
+    seen = (jnp.arange(Lk) < pos0 + S)[None, :, None]
+    kf = jnp.where(seen, k.astype(f32), 0.0)
+    vf = jnp.where(seen, v.astype(f32), 0.0)
+    rows = next(r for r in (256, 128, 64, 32, 16, 8, 4, 2, 1) if S % r == 0)
+
+    def block(args):
+        qb, t = args                                  # [Hkv, G, rows, D], [rows]
+        causal = jnp.arange(Lk)[None, :] <= t[:, None]
+        s = jnp.einsum("hgtd,hsd->hgts", qb, kf)
+        p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+        return jnp.einsum("hgts,hsd->hgtd", p, vf)
+
+    out = jax.lax.map(block, (
+        q.astype(f32).reshape(Hkv, G, S // rows, rows, D).transpose(
+            2, 0, 1, 3, 4),
+        (pos0 + jnp.arange(S)).reshape(S // rows, rows)))
+    return out.transpose(1, 2, 0, 3, 4).reshape(Hkv, G, S, D)
+
+
+def _attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                      acc_ref, *, tq: int, tk: int, bk: int, groups: int):
+    """One (key-value head, query tile, key tile) step."""
+    f32 = jnp.float32
+    i, j = pl.program_id(1), pl.program_id(2)
+    first = pos_ref[0] + i * tq              # position of the tile's first query
+    n_kv = (first + tq + tk - 1) // tk
+    rows = groups * tq
+    nt = (((1,), (1,)), ((), ()))           # [m, k] x [n, k]
+    nn = (((1,), (0,)), ((), ()))
+    lanes = bk // _LANES
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _MASKED, f32)
+        l_ref[...] = jnp.zeros(l_ref.shape, f32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    def tile(masked: bool):
+        q = q_ref[0].reshape(rows, q_ref.shape[-1])
+        for c in range(tk // bk):
+            keys = slice(c * bk, (c + 1) * bk)
+            s = jax.lax.dot_general(q, k_ref[0, keys, :], nt,
+                                    preferred_element_type=f32)  # [rows, bk]
+            if masked:
+                # Row r of the stack is query r % tq of head r // tq.
+                t = first + jnp.bitwise_and(jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, bk), 0), tq - 1)
+                at = j * tk + c * bk + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, bk), 1)
+                s = jnp.where(at <= t, s, _MASKED)
+            m_prev = m_ref[...]                              # [rows, 128]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - jnp.concatenate([m_new] * lanes, axis=1))
+            alpha = jnp.exp(m_prev - m_new)
+            part = p[:, :_LANES]
+            for g in range(1, lanes):
+                part = part + p[:, g * _LANES:(g + 1) * _LANES]
+            l_ref[...] = alpha * l_ref[...] + part
+            acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, keys, :], nn,
+                preferred_element_type=f32)
+            m_ref[...] = m_new
+
+    # A tile whose last key lies past the tile's first query crosses the
+    # diagonal; the tiles before it are wholly below.
+    crosses = (j + 1) * tk - 1 > first
+    pl.when((j < n_kv) & crosses)(lambda: tile(True))
+    pl.when((j < n_kv) & jnp.logical_not(crosses))(lambda: tile(False))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        o_ref[0] = (acc_ref[...] / l_ref[...].sum(
+            axis=-1, keepdims=True)).reshape(o_ref.shape[1:]).astype(
+                o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _attention_call(q, k, v, pos0, *, interpret: bool):
+    Hkv, G, S, D = q.shape
+    Lk = k.shape[1]
+    tq, tk, bk = QUERY_TILE, KEY_TILE, KEY_BLOCK
+
+    # Steps past a query tile's last key tile name that tile again: no copy.
+    def at(j, i, pos):
+        return jnp.minimum(j, (pos[0] + (i + 1) * tq + tk - 1) // tk - 1)
+
+    kv_block = pl.BlockSpec((1, tk, D), lambda h, i, j, pos: (h, at(j, i, pos), 0))
+    q_block = pl.BlockSpec((1, G, tq, D), lambda h, i, j, pos: (h, 0, i, 0))
+    pairs = Hkv * G * S * Lk
+    return pl.pallas_call(
+        functools.partial(_attention_kernel, tq=tq, tk=tk, bk=bk, groups=G),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Hkv, S // tq, Lk // tk),
+            in_specs=[q_block, kv_block, kv_block],
+            out_specs=q_block,
+            scratch_shapes=[
+                pltpu.VMEM((G * tq, _LANES), jnp.float32),
+                pltpu.VMEM((G * tq, _LANES), jnp.float32),
+                pltpu.VMEM((G * tq, D), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * D * pairs,                 # the causal half of 4 D
+            bytes_accessed=2 * (2 * q.size + k.size + v.size),
+            transcendentals=pairs // 2,
+        ),
+        name="causal_gqa_attention",
+        interpret=interpret,
+    )(pos0.reshape(1).astype(jnp.int32), q, k, v)
+
+
+def causal_attention(
+    q: jax.Array,          # [Hkv, G, S, D]  rotated and SCALED queries
+    k: jax.Array,          # [Hkv, Lk, D]    the document's keys so far, rotated
+    v: jax.Array,          # [Hkv, Lk, D]    and its values
+    pos0: jax.Array,       # int32 scalar: position of the segment's first token
+    *,
+    pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """softmax over the keys ``0 .. pos0 + t`` of ``q_t . k`` (the softmax
+    scale is the caller's, folded into q before it is rounded), times ``v`` →
+    ``[Hkv, G, S, D]``. Keys at and after ``pos0 + S`` are never read."""
+    S, D = q.shape[2:]
+    _note()
+    if pallas is None:
+        pallas = jax.default_backend() == "tpu"
+    if pallas and pallas_supported(S, k.shape[1], D, q.dtype):
+        from agent_tpu.kernels.flash_attention import resolve_interpret
+
+        return _attention_call(q, k, v, pos0,
+                               interpret=resolve_interpret(interpret))
+    return _attention_jnp(q, k, v, pos0).astype(q.dtype)

@@ -2,8 +2,9 @@
 
 One file for the family, not one a checkpoint (ROADMAP.md D1): a block is
 described by its LAYER TYPE — pre-norm residual block, RMS norm, rotary
-positions, grouped-query projections with a per-head RMS norm on q and k, a
-``mixer`` that mixes along the sequence, a SwiGLU feed-forward — and the
+positions, a ``mixer`` that mixes along the sequence and returns what enters
+the residual (its projections and out-projection are its own), a SwiGLU
+feed-forward — and the
 ``mixer`` key names which sequence mixer the type runs (``MIXERS``), and the
 feed-forward is chosen by the layer's place (``n_dense_layers`` leading SwiGLU
 layers, then expert layers when ``n_experts`` is set). A document longer than
@@ -18,7 +19,16 @@ one to the next (:func:`forward_segment`):
   latent vector (``kv_lora_rank + qk_rope_head_dim`` numbers) and one index
   key (``index_head_dim``) a token a layer, allocated at the document's
   padded length (:func:`init_state`), written in place, read up to the
-  segment's last token.
+  segment's last token;
+- ``hybrid_ssm`` (a Mamba-2 state-space scan, ``kernels/ssd.py``, and causal
+  grouped-query softmax attention, ``kernels/causal_attention.py``, side by
+  side: both read the one normed input and their outputs are summed into
+  the residual, each through its own out-projection) carries TWO KINDS of
+  state a layer: a key and value cache that grows with position (2 x
+  ``n_kv_heads x d_head`` numbers a token, allocated as ``sparse_mla``'s
+  is) and a fixed-size float32 scan state with the convolution's last
+  ``ssm_d_conv - 1`` inputs. The block's muP multipliers (``*_multiplier``)
+  are applied where the published forward pass applies them.
 
 Layers are stacked by group (the leading dense layers, then the expert
 layers) and each group is scanned; embedding and output head are untied. An
@@ -39,14 +49,17 @@ both groups) from ``fold_in(fold_in(root, j), i)``, expert ``e`` (its id among
 all ``n_experts``) of that layer from one more ``fold_in(., e)``; a standard
 normal in float32 times ``1/sqrt(fan_in)`` (embedding: 1), rounded once to the
 stored dtype. Norm weights are 1, the router's bias and the index keys'
-LayerNorm bias 0. New leaves are APPENDED to ``LEAVES``: a model made before
-keeps its keys. The gate's bias gives key-value head ``b`` a memory of
+LayerNorm bias 0. ``hybrid_ssm`` multiplies a leaf's ``1/sqrt(fan_in)`` by a
+gain (:func:`_leaf_gains`: the inverse of the multipliers on the leaf's
+output, 4 on the queries) and sets the scan's ``A_log``, ``D`` and ``dt_bias``
+by rule (:func:`_layer_constants`). New leaves are APPENDED to ``LEAVES``: a
+model made before keeps its keys. The gate's bias gives key-value head ``b`` a memory of
 ``16 * 2**b`` tokens: ``log(16 * 2**b - 1)`` (see ``GATE_TAU0``).
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, fields
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -63,19 +76,24 @@ LEAVES = ("embed", "head", "wq", "wk", "wv", "wo", "wg", "w_gate", "w_up",
           "w_dq", "w_uq", "w_dkv", "w_ukv", "wi_q", "wi_k", "wi_w",
           # expert layers: router, shared expert, the routed experts held
           "w_router", "ws_gate", "ws_up", "ws_down",
-          "we_gate", "we_up", "we_down")
+          "we_gate", "we_up", "we_down",
+          # hybrid_ssm: the scan's in / out projections, the convolution
+          "w_ssm_in", "w_ssm_out", "conv_w", "conv_b")
 EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
 # The layer leaves a quantized mode replaces (``models.quant``): projections
 # (the router's among them), feed-forwards and experts; the retention gate
-# and the indexer's head weights stay.
+# the indexer's head weights and the convolution stay.
 LINEAR_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                  "w_dq", "w_uq", "w_dkv", "w_ukv", "wi_q", "wi_k",
-                 "w_router", "ws_gate", "ws_up", "ws_down") + EXPERT_LEAVES
+                 "w_router", "ws_gate", "ws_up", "ws_down") + EXPERT_LEAVES + (
+                     "w_ssm_in", "w_ssm_out")
 # Which leaves a layer holds, by its mixer and by its feed-forward.
 MIXER_LEAVES = {
     "power_retention": ("wq", "wk", "wv", "wo", "wg"),
     "sparse_mla": ("wo", "w_dq", "w_uq", "w_dkv", "w_ukv", "wi_q", "wi_k",
                    "wi_w"),
+    "hybrid_ssm": ("wq", "wk", "wv", "wo", "w_ssm_in", "w_ssm_out", "conv_w",
+                   "conv_b"),
 }
 FFN_LEAVES = {
     "dense": ("w_gate", "w_up", "w_down"),
@@ -83,6 +101,11 @@ FFN_LEAVES = {
 }
 # sigmoid(log(tau - 1)) = 1 - 1/tau: head b forgets over tau0 * 2**b tokens.
 GATE_TAU0 = 16.0
+# hybrid_ssm's weight rule: a query's scores spread over this many standard
+# deviations (see the module docstring), and the steps the heads' ``dt_bias``
+# is spaced over.
+QUERY_GAIN = 4.0
+SSM_DT_RANGE = (0.001, 0.1)
 # Tokens a loss block: the op reports the log-probability summed a block.
 LOSS_BLOCK = 1024
 # Vocabulary rows a block of the loss head: [segment, VOCAB_BLOCK] float32
@@ -134,10 +157,57 @@ class DecoderLMConfig:
     d_expert: int = 64
     n_shared_experts: int = 1
     routed_scale: float = 2.5
+    # hybrid_ssm (``n_heads`` query over ``n_kv_heads`` key-value heads of
+    # ``d_head``, and beside them a Mamba-2 scan: ``ssm_n_heads`` heads of
+    # ``ssm_d_head`` in ``ssm_n_groups`` groups that share B and C):
+    ssm_n_heads: int = 4
+    ssm_d_head: int = 16
+    ssm_d_state: int = 16
+    ssm_n_groups: int = 2
+    ssm_d_conv: int = 4
+    ssm_chunk: int = 128
+    # The multipliers of a muP-parametrized forward pass, each applied where
+    # the published pass applies it (1: none). ``ssm_multipliers`` and
+    # ``mlp_multipliers`` are lists where published: seven scalars here,
+    # because every field goes into hashed keys (``_model_common.cfg_key``).
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_z_multiplier: float = 1.0
+    ssm_x_multiplier: float = 1.0
+    ssm_b_multiplier: float = 1.0
+    ssm_c_multiplier: float = 1.0
+    ssm_dt_multiplier: float = 1.0
+    mlp_gate_multiplier: float = 1.0
+    mlp_down_multiplier: float = 1.0
 
     @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def ssm_parts(self) -> Tuple[Tuple[int, float], ...]:
+        """``(columns, multiplier)`` of the scan's in-projection, in its
+        order: z, x, B, C, dt."""
+        d_ssm = self.ssm_n_heads * self.ssm_d_head
+        bc = self.ssm_n_groups * self.ssm_d_state
+        return ((d_ssm, self.ssm_z_multiplier), (d_ssm, self.ssm_x_multiplier),
+                (bc, self.ssm_b_multiplier), (bc, self.ssm_c_multiplier),
+                (self.ssm_n_heads, self.ssm_dt_multiplier))
+
+    @property
+    def ssm_in_dim(self) -> int:
+        """Columns of the scan's in-projection: [z | x | B | C | dt]."""
+        return sum(n for n, _ in self.ssm_parts)
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the scan's convolution runs over: [x | B | C]."""
+        return sum(n for n, _ in self.ssm_parts[1:4])
 
     @property
     def layer_groups(self) -> Tuple[Tuple[str, str, int, int], ...]:
@@ -156,7 +226,7 @@ def validate(cfg: DecoderLMConfig) -> None:
     if cfg.mixer not in MIXERS:
         raise ValueError(f"mixer must be one of {sorted(MIXERS)}, "
                          f"got {cfg.mixer!r}")
-    if cfg.mixer == "power_retention":
+    if cfg.mixer in ("power_retention", "hybrid_ssm"):
         if cfg.n_kv_heads <= 0 or cfg.n_heads % cfg.n_kv_heads:
             raise ValueError("n_heads must be a multiple of n_kv_heads")
         if cfg.d_head % 2:
@@ -169,6 +239,13 @@ def validate(cfg: DecoderLMConfig) -> None:
             raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
         if cfg.index_head_dim < cfg.qk_rope_head_dim:
             raise ValueError("index_head_dim must hold the rotary part")
+    if cfg.mixer == "hybrid_ssm":
+        positive += ["ssm_n_heads", "ssm_d_head", "ssm_d_state",
+                     "ssm_n_groups", "ssm_d_conv", "ssm_chunk"]
+        positive += [f.name for f in fields(cfg)
+                     if f.name.endswith("_multiplier")]
+        if cfg.ssm_n_heads % max(1, cfg.ssm_n_groups):
+            raise ValueError("ssm_n_heads must be whole ssm_n_groups")
     if cfg.n_experts:
         positive += ["n_experts_held", "d_expert", "n_experts_per_token"]
         if not 0 <= cfg.n_dense_layers <= cfg.n_layers:
@@ -185,7 +262,7 @@ def validate(cfg: DecoderLMConfig) -> None:
             raise ValueError("the router cannot choose n_experts_per_token "
                              "experts from n_groups_per_token groups")
     for name in positive:
-        if int(getattr(cfg, name)) <= 0:
+        if not getattr(cfg, name) > 0:
             raise ValueError(f"{name} must be positive")
 
 
@@ -212,6 +289,14 @@ def _leaf_shapes(cfg: DecoderLMConfig) -> Dict[str, Tuple[Tuple[int, ...], int]]
             "wq": ((d, hq), d), "wk": ((d, hkv), d), "wv": ((d, hkv), d),
             "wo": ((hq, d), hq), "wg": ((d, cfg.n_kv_heads), d),
         }
+    if cfg.mixer == "hybrid_ssm":
+        d_ssm = cfg.ssm_n_heads * cfg.ssm_d_head
+        conv, k = cfg.ssm_conv_dim, cfg.ssm_d_conv
+        mixer.update({
+            "w_ssm_in": ((d, cfg.ssm_in_dim), d),
+            "w_ssm_out": ((d_ssm, d), d_ssm),
+            "conv_w": ((k, conv), k), "conv_b": ((conv,), k),
+        })
     fe, fs = cfg.d_expert, cfg.d_expert * cfg.n_shared_experts
     return {
         "embed": ((cfg.vocab_size, d), 1), "head": ((cfg.vocab_size, d), d),
@@ -221,6 +306,58 @@ def _leaf_shapes(cfg: DecoderLMConfig) -> Dict[str, Tuple[Tuple[int, ...], int]]
         "ws_gate": ((d, fs), d), "ws_up": ((d, fs), d), "ws_down": ((fs, d), fs),
         "we_gate": ((d, fe), d), "we_up": ((d, fe), d), "we_down": ((fe, d), fe),
     }
+
+
+def _leaf_gains(cfg: DecoderLMConfig) -> Dict[str, Any]:
+    """leaf → what the drawn leaf is multiplied by beside ``1/sqrt(fan_in)``:
+    a number, or ``((columns, number), ...)`` along the output axis. Only
+    ``hybrid_ssm`` has any: the inverse of every multiplier the forward pass
+    applies to the leaf's output (the published multipliers belong to
+    TRAINED weights; on weights of the plain rule they would leave a layer's
+    branches at a hundredth of the residual and the logits at 1/128, so that
+    every token scored ``-log V`` whatever the state carried), and
+    ``QUERY_GAIN`` on the queries (a trained query's scores select; unit
+    scores over 65,536 keys average them, and the branch is 0.006 of the
+    residual at exactly the lengths it is there for)."""
+    if cfg.mixer != "hybrid_ssm":
+        return {}
+    a_in, s_in = cfg.attention_in_multiplier, cfg.ssm_in_multiplier
+    return {
+        "embed": 1.0 / cfg.embedding_multiplier,
+        "head": 1.0 / cfg.lm_head_multiplier,
+        "wq": QUERY_GAIN / a_in,
+        "wk": 1.0 / (a_in * cfg.key_multiplier),
+        "wv": 1.0 / a_in,
+        "wo": 1.0 / cfg.attention_out_multiplier,
+        "w_ssm_in": tuple((n, 1.0 / (s_in * m)) for n, m in cfg.ssm_parts),
+        "w_ssm_out": 1.0 / cfg.ssm_out_multiplier,
+        "w_gate": 1.0 / cfg.mlp_gate_multiplier,
+        "w_down": 1.0 / cfg.mlp_down_multiplier,
+    }
+
+
+def by_columns(parts) -> np.ndarray:
+    """``((columns, number), ...)`` → the float32 vector along those columns."""
+    return np.concatenate([np.full(n, value, np.float32)
+                           for n, value in parts])
+
+
+def leaf_scale(fan_in: int, gain: Any = 1.0):
+    """What a standard normal is multiplied by: ``gain / sqrt(fan_in)``, a
+    Python float (float64 arithmetic, rounded once to float32 at the
+    multiply), or a float32 vector along the output axis."""
+    root = np.sqrt(max(1, fan_in))
+    if isinstance(gain, tuple):
+        return by_columns((n, g / root) for n, g in gain)
+    return gain / root
+
+
+def ssm_dt_bias(n_heads: int) -> np.ndarray:
+    """Head j's step before the input moves it: ``SSM_DT_RANGE`` spaced
+    log-uniformly over the heads, through the inverse of softplus."""
+    lo, hi = SSM_DT_RANGE
+    dt = np.exp(np.linspace(np.log(lo), np.log(hi), n_heads))
+    return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
 
 
 def gate_bias(n_kv_heads: int) -> np.ndarray:
@@ -237,6 +374,12 @@ def _layer_constants(cfg: DecoderLMConfig, ffn: str) -> Dict[str, Tuple]:
                    kv_norm=(1.0, (cfg.kv_lora_rank,)),
                    ik_norm=(1.0, (cfg.index_head_dim,)),
                    ik_bias=(0.0, (cfg.index_head_dim,)))
+    elif cfg.mixer == "hybrid_ssm":
+        h = cfg.ssm_n_heads
+        out.update(
+            ssm_norm=(1.0, (h * cfg.ssm_d_head,)),
+            A_log=(np.log(np.arange(1, h + 1, dtype=np.float32)), (h,)),
+            D=(1.0, (h,)), dt_bias=(ssm_dt_bias(h), (h,)))
     else:
         out.update(bg=(gate_bias(cfg.n_kv_heads), (cfg.n_kv_heads,)),
                    q_norm=(1.0, (cfg.d_head,)), k_norm=(1.0, (cfg.d_head,)))
@@ -251,12 +394,13 @@ def init_params(cfg: DecoderLMConfig, model_id: str, sharding=None) -> Params:
     ``TpuRuntime.get_params`` keeps them as built."""
     dtype = cfg.compute_dtype
     root = layers.seed_from(model_id)
-    shapes = _leaf_shapes(cfg)
+    shapes, gains = _leaf_shapes(cfg), _leaf_gains(cfg)
 
     programs: Dict[Tuple, Callable] = {}
 
-    def program(shape, fan_in, first, n, experts):
-        """``key -> leaf``: one matrix; stacked over layers ``first .. first
+    def program(shape, fan_in, gain, first, n, experts):
+        """``key -> leaf``: one matrix (a standard normal times
+        :func:`leaf_scale`); stacked over layers ``first .. first
         + n`` when given, and under each over the ``experts`` (ids) held,
         every entry from its own folded key. Layers are drawn at once; a
         layer's experts one after another (one small program in a loop, not
@@ -264,7 +408,7 @@ def init_params(cfg: DecoderLMConfig, model_id: str, sharding=None) -> Params:
         def fn(key):
             def one(k):
                 w = jax.random.normal(k, shape, dtype=jnp.float32)
-                return (w * (1.0 / np.sqrt(max(1, fan_in)))).astype(dtype)
+                return (w * leaf_scale(fan_in, gain)).astype(dtype)
 
             if first is None:
                 return one(key)
@@ -282,7 +426,7 @@ def init_params(cfg: DecoderLMConfig, model_id: str, sharding=None) -> Params:
         # Leaves of one shape share ONE program (wk / wv, w_gate / w_up,
         # embedding / head): tracing and lowering a draw costs the host more
         # than the device takes to run it.
-        which = (*shapes[name], first, n, experts)
+        which = (*shapes[name], gains.get(name, 1.0), first, n, experts)
         if which not in programs:
             programs[which] = program(*which)
         return programs[which](jax.random.fold_in(root, LEAVES.index(name)))
@@ -343,7 +487,8 @@ def linear(w: Any, x: jax.Array, dtype: Any) -> jax.Array:
 
 def _power_retention_mixer(p: Params, h: jax.Array, positions: jax.Array,
                            state, cfg: DecoderLMConfig, kernel_opts):
-    """h [B, L, d] (normed) → (mixed [B, L, Hq*D], new state)."""
+    """h [B, L, d] (normed) → (what enters the residual [B, L, d], new
+    state)."""
     from agent_tpu.kernels.power_retention import power_retention
 
     dtype = cfg.compute_dtype
@@ -359,8 +504,9 @@ def _power_retention_mixer(p: Params, h: jax.Array, positions: jax.Array,
     gate = jnp.dot(h.astype(dtype), p["wg"].astype(dtype),
                    preferred_element_type=jnp.float32) + p["bg"]
     log_g = jax.nn.log_sigmoid(gate)                        # [B, L, Hkv] f32
-    return power_retention(q, k, v, log_g, n_kv_heads=hkv,
-                           initial_state=state, **kernel_opts)
+    y, state = power_retention(q, k, v, log_g, n_kv_heads=hkv,
+                               initial_state=state, **kernel_opts)
+    return linear(p["wo"], y, dtype), state
 
 
 def yarn_inv_freq(cfg: DecoderLMConfig) -> np.ndarray:
@@ -418,8 +564,8 @@ def rope_pairs(x: jax.Array, positions: jax.Array, inv_freq: np.ndarray,
 
 def _sparse_mla_mixer(p: Params, h: jax.Array, positions: jax.Array,
                       state, cfg: DecoderLMConfig, kernel_opts):
-    """h [1, S, d] (normed) → (attended [1, S, H*v], the layer's cache with
-    the segment written at its positions). ``state``: ``{"kv": [1, Lk,
+    """h [1, S, d] (normed) → (what enters the residual [1, S, d], the
+    layer's cache with the segment written at its positions). ``state``: ``{"kv": [1, Lk,
     kv_lora_rank + rope], "ki": [1, Lk, index_head_dim]}``. One document a
     program: the kernels take no batch."""
     from agent_tpu.kernels import sparse_mla
@@ -471,8 +617,8 @@ def _sparse_mla_mixer(p: Params, h: jax.Array, positions: jax.Array,
     o = sparse_mla.masked_attention(
         q[..., :dn].transpose(1, 0, 2), q_rope.transpose(1, 0, 2), k_nope,
         kv[:, kvr:], v, mask, pos0, **kernel_opts)
-    return (o.transpose(1, 0, 2).reshape(1, S, nh * cfg.v_head_dim),
-            {"kv": kv[None], "ki": kic[None]})
+    o = o.transpose(1, 0, 2).reshape(1, S, nh * cfg.v_head_dim)
+    return linear(p["wo"], o, dtype), {"kv": kv[None], "ki": kic[None]}
 
 
 def _sparse_mla_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
@@ -484,14 +630,112 @@ def _sparse_mla_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
             "ki": jnp.zeros((n, batch, cache_len, cfg.index_head_dim), dtype)}
 
 
+def _times(x: jax.Array, m: float, dtype) -> jax.Array:
+    """``x * m`` in float32, rounded to ``dtype``; ``x`` itself where the
+    multiplier is 1 (the other mixers' programs stay as they were)."""
+    if m == 1.0:
+        return x.astype(dtype)
+    return (x.astype(jnp.float32) * m).astype(dtype)
+
+
+def _hybrid_ssm_mixer(p: Params, h: jax.Array, positions: jax.Array,
+                      state, cfg: DecoderLMConfig, kernel_opts):
+    """h [1, S, d] (normed) → (what enters the residual [1, S, d], the
+    layer's state). Two branches read the one ``h`` and their outputs are
+    summed, each through its own out-projection and multiplier: causal
+    grouped-query attention over the key and value cache, and a Mamba-2
+    scan behind a causal convolution. ``state``: ``{"k", "v": [1, Hkv, Lk,
+    D]`` (the cache, written at the segment's positions), ``"ssm": [1, H, N,
+    P]`` float32 (the scan's), ``"conv": [1, K - 1, channels]`` float32 (the
+    convolution's last inputs)``}``. One document a program.
+
+    Every multiplier is applied where the published pass applies it, in
+    float32 on the rounded product, and rounded again; none is folded into a
+    weight. The softmax scale goes into the rotated queries before they are
+    rounded, as ``sparse_mla`` does."""
+    from agent_tpu.kernels import causal_attention, ssd
+
+    if h.shape[0] != 1:
+        raise ValueError("hybrid_ssm runs one document a program")
+    dtype, f32 = cfg.compute_dtype, jnp.float32
+    h = h[0]
+    S = h.shape[0]
+    pos0 = positions[0]
+
+    # Attention: G query heads a key-value head, rotary positions, no norm.
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    ha = _times(h, cfg.attention_in_multiplier, dtype)
+    q = rope(linear(p["wq"], ha, dtype).reshape(S, hq, dh).astype(f32),
+             positions, cfg.rope_theta)
+    q = (q * float(dh) ** -0.5).astype(dtype)
+    k = linear(p["wk"], ha, dtype).astype(f32) * cfg.key_multiplier
+    k = rope(k.reshape(S, hkv, dh), positions, cfg.rope_theta).astype(dtype)
+    v = linear(p["wv"], ha, dtype).reshape(S, hkv, dh)
+    kc = jax.lax.dynamic_update_slice(state["k"][0], k.transpose(1, 0, 2),
+                                      (0, pos0, 0))
+    vc = jax.lax.dynamic_update_slice(state["v"][0], v.transpose(1, 0, 2),
+                                      (0, pos0, 0))
+    o = causal_attention.causal_attention(
+        q.reshape(S, hkv, hq // hkv, dh).transpose(1, 2, 0, 3), kc, vc, pos0,
+        **kernel_opts)
+    attended = linear(p["wo"], o.transpose(2, 0, 1, 3).reshape(S, hq * dh),
+                      dtype)
+
+    # The scan: in-projection [z | x | B | C | dt], each part times its own
+    # multiplier; convolution and SiLU over [x | B | C].
+    H, P, N, G = (cfg.ssm_n_heads, cfg.ssm_d_head, cfg.ssm_d_state,
+                  cfg.ssm_n_groups)
+    d_ssm = H * P
+    proj = linear(p["w_ssm_in"], _times(h, cfg.ssm_in_multiplier, dtype),
+                  dtype).astype(f32)
+    proj = proj * by_columns(cfg.ssm_parts)
+    z, xbc, dt = jnp.split(proj, [d_ssm, 2 * d_ssm + 2 * G * N], axis=1)
+    xbc, tail = ssd.causal_conv(xbc, state["conv"][0], p["conv_w"],
+                                p["conv_b"])
+    x, B, C = jnp.split(jax.nn.silu(xbc), [d_ssm, d_ssm + G * N], axis=1)
+    y, scanned = ssd.ssd_scan(
+        x.astype(dtype), jax.nn.softplus(dt + p["dt_bias"]),
+        -jnp.exp(p["A_log"]), B.astype(dtype), C.astype(dtype), n_heads=H,
+        n_groups=G, chunk=cfg.ssm_chunk, initial_state=state["ssm"][0],
+        **kernel_opts)
+    y = y.astype(f32) + jnp.repeat(p["D"], P) * x
+    # Gate, THEN a group's RMS norm (``mamba_norm_before_gate`` false).
+    y = rms_norm((y * jax.nn.silu(z)).reshape(S, G, d_ssm // G),
+                 p["ssm_norm"].reshape(G, d_ssm // G), cfg.rms_norm_eps)
+    scanned_out = linear(p["w_ssm_out"], y.reshape(S, d_ssm).astype(dtype),
+                         dtype)
+
+    mixed = (attended.astype(f32) * cfg.attention_out_multiplier
+             + scanned_out.astype(f32) * cfg.ssm_out_multiplier).astype(dtype)
+    return mixed[None], {"k": kc[None], "v": vc[None], "ssm": scanned[None],
+                         "conv": tail[None]}
+
+
+def _hybrid_ssm_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
+    """Before a document's first token, TWO kinds of state a layer: the empty
+    key and value cache of ``cache_len`` (padded) tokens in the stored dtype,
+    which grows with position, and the scan's state and the convolution's
+    tail, float32 zeros of fixed size."""
+    n, dtype, f32 = cfg.n_layers, cfg.compute_dtype, jnp.float32
+    cache = (n, batch, cfg.n_kv_heads, cache_len, cfg.d_head)
+    return {"k": jnp.zeros(cache, dtype), "v": jnp.zeros(cache, dtype),
+            "ssm": jnp.zeros((n, batch, cfg.ssm_n_heads, cfg.ssm_d_state,
+                              cfg.ssm_d_head), f32),
+            "conv": jnp.zeros((n, batch, cfg.ssm_d_conv - 1,
+                               cfg.ssm_conv_dim), f32)}
+
+
 # mixer name → fn(layer params, normed h, positions, state, cfg, opts)
-# → (mixed [B, L, Hq*D], new state). One entry a sequence mixer.
+# → (what enters the residual [B, L, d], new state). One entry a sequence
+# mixer; the out-projection is the mixer's (one has two).
 MIXERS: Dict[str, Callable] = {"power_retention": _power_retention_mixer,
-                               "sparse_mla": _sparse_mla_mixer}
+                               "sparse_mla": _sparse_mla_mixer,
+                               "hybrid_ssm": _hybrid_ssm_mixer}
 # mixer name → fn(cfg, batch, cache_len) → the state before a document's
 # first segment, for the mixers whose state is allocated (a cache); the
 # others start from ``None``.
-MIXER_STATES: Dict[str, Callable] = {"sparse_mla": _sparse_mla_state}
+MIXER_STATES: Dict[str, Callable] = {"sparse_mla": _sparse_mla_state,
+                                     "hybrid_ssm": _hybrid_ssm_state}
 
 
 def starts_from_nothing(cfg: DecoderLMConfig) -> bool:
@@ -511,10 +755,12 @@ def init_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
     return mixer
 
 
-def _swiglu(p: Params, n: jax.Array, names, dtype) -> jax.Array:
+def _swiglu(p: Params, n: jax.Array, names, dtype, gate_multiplier=1.0,
+            down_multiplier=1.0) -> jax.Array:
     gate, up, down = names
-    ff = jax.nn.silu(linear(p[gate], n, dtype)) * linear(p[up], n, dtype)
-    return linear(p[down], ff, dtype)
+    g = _times(linear(p[gate], n, dtype), gate_multiplier, dtype)
+    ff = jax.nn.silu(g) * linear(p[up], n, dtype)
+    return _times(linear(p[down], ff, dtype), down_multiplier, dtype)
 
 
 def _plain_weights(leaf: Any, dtype) -> jax.Array:
@@ -560,13 +806,14 @@ def _layer(p: Params, x: jax.Array, positions, state, cfg, kernel_opts,
     dtype = cfg.compute_dtype
     h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
     mixed, state = MIXERS[cfg.mixer](p, h, positions, state, cfg, kernel_opts)
-    x = x + linear(p["wo"], mixed, dtype)
+    x = x + mixed
     n = rms_norm(x, p["ln2"], cfg.rms_norm_eps)
     if ffn == "experts":
         y, pairs = _experts_ffn(p, n, cfg, kernel_opts)
         return x + y, state, pairs
-    return (x + _swiglu(p, n, ("w_gate", "w_up", "w_down"), dtype), state,
-            jnp.zeros((), jnp.float32))
+    y = _swiglu(p, n, ("w_gate", "w_up", "w_down"), dtype,
+                cfg.mlp_gate_multiplier, cfg.mlp_down_multiplier)
+    return x + y, state, jnp.zeros((), jnp.float32)
 
 
 def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
@@ -579,7 +826,8 @@ def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
     ``{"mixer": ..., "pairs": ...}``, ``pairs`` the running count of (token,
     expert) pairs routed to the experts held here. Returns the final-normed
     hidden states [B, S, d] and the state after the segment."""
-    x = params["embed"][ids].astype(cfg.compute_dtype)
+    x = _times(params["embed"][ids], cfg.embedding_multiplier,
+               cfg.compute_dtype)
     positions = pos0.astype(jnp.int32) + jnp.arange(ids.shape[1])
     routed = bool(cfg.n_experts)
     mixer_state = state["mixer"] if routed and state is not None else state
@@ -601,7 +849,10 @@ def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
         new_states.append(st)
     mixer_state = new_states[0] if len(new_states) == 1 else \
         jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, 0), *new_states)
-    hidden = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    # The head's multiplier goes into the hidden states: for a power of two
+    # (the published 2^-7) the logits are the same numbers, bit for bit.
+    hidden = _times(rms_norm(x, params["final_norm"], cfg.rms_norm_eps),
+                    cfg.lm_head_multiplier, cfg.compute_dtype)
     return hidden, ({"mixer": mixer_state, "pairs": pairs} if routed
                     else mixer_state)
 
@@ -623,6 +874,16 @@ def segment_flops(cfg: DecoderLMConfig, n_tokens: int, pos0: int) -> float:
         seen = pos0 + t / 2.0
         mixer = (2.0 * hi * di + 2.0 * h * (dn + dr + dv)) * seen + (
             2.0 * kvr * h * (dn + dv) * (pos0 + t) / t)
+    elif cfg.mixer == "hybrid_ssm":
+        dh, hq, hkv = cfg.d_head, cfg.n_heads, cfg.n_kv_heads
+        H, P, N = cfg.ssm_n_heads, cfg.ssm_d_head, cfg.ssm_d_state
+        c, d_ssm = cfg.ssm_chunk, cfg.ssm_n_heads * cfg.ssm_d_head
+        proj = 2.0 * d * (2 * hq * dh + 2 * hkv * dh + cfg.ssm_in_dim) + (
+            2.0 * d_ssm * d)
+        # Every causal pair's score and value product; a chunk's block, the
+        # state's read and update a head, C B^T once a group.
+        mixer = 4.0 * hq * dh * (pos0 + t / 2.0) + H * (
+            2.0 * c * P + 4.0 * N * P) + 2.0 * cfg.ssm_n_groups * c * N
     else:
         from agent_tpu.kernels.power_retention import retention_chunk
 

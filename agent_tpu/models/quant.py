@@ -572,7 +572,8 @@ def quantize_decoder_lm(params: Params, mode: str = "int8") -> Params:
     layer group become int8 tables (W8A8 or W8A16 by ``mode``; the held
     experts' tables are multiplied out again where the grouped matmul takes
     them: their weights are rounded, their activations not); embedding,
-    head, norms, the retention gate and the indexer's head weights stay.
+    head, norms, the retention gate, the indexer's head weights and the
+    state-space scan's convolution and constants stay.
     ``params`` is CONSUMED: each bf16 leaf is dropped as its table is made,
     so the peak is one leaf's float32 copy over the stored model."""
     from agent_tpu.models.decoder_lm import LINEAR_LEAVES

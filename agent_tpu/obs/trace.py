@@ -629,6 +629,48 @@ def record_sparse_attention_keys(kind: str, keys: int) -> None:
                float(keys), kind=kind)
 
 
+def record_ssm_block(path: str) -> None:
+    """One state-space scan TRACED into an XLA program on ``path``
+    (``state``: a carried state comes in, ``first_chunk``: the document
+    starts in this call; ``kernels/ssd.py``). Beside
+    :func:`record_attention_block`: ticks while a program is traced, once a
+    mixer call (once a scanned layer stack), never when it runs."""
+    _count("ssm_blocks_traced_total",
+           "State-space scans traced into XLA programs, by whether the call "
+           "takes a carried state (state) or starts a document "
+           "(first_chunk); ticks while a program is traced, not when it runs",
+           path=path)
+
+
+def record_ssm_tokens(path: str, tokens: int) -> None:
+    """Real tokens DISPATCHED to a state-space scan whose chunk did
+    (``state``) or did not (``first_chunk``: a document's first chunk, whose
+    state is the empty one) read a carried state: counted by the op at
+    dispatch, from the chunk each token falls in."""
+    if tokens > 0:
+        _count("ssm_tokens_total",
+               "Tokens dispatched to a state-space scan, by whether the "
+               "token's chunk read a carried state (state) or was a "
+               "document's first (first_chunk)",
+               float(tokens), path=path)
+
+
+def record_causal_attention_pairs(kind: str, pairs: int) -> None:
+    """(query, key) pairs of a DISPATCHED shard under plain causal
+    attention, a query head a layer: those its real tokens need (``causal``:
+    ``t + 1`` for token ``t``) and those in the key tiles the kernel's grid
+    visits (``computed``: whole tiles up to the diagonal's, padding
+    included). Counted by the op from the documents' lengths and the
+    kernel's tile sizes."""
+    if pairs > 0:
+        _count("causal_attention_pairs_total",
+               "(query, key) pairs of the tokens dispatched to a causal "
+               "attention mixer, a query head a layer: needed by the real "
+               "tokens (causal) and in the key tiles the kernel's grid "
+               "visits (computed)",
+               float(pairs), kind=kind)
+
+
 def record_moe_routing(pairs: float, tokens: int) -> None:
     """What a FETCHED shard's expert layers routed: (token, expert) pairs
     that went to the experts held here (counted on the device, fetched with

@@ -29,7 +29,12 @@ the host. What that state is depends on the mixer (``models/decoder_lm.py``):
   PADDED length (the sum of its segments: not ``max_len``), donated from
   program to program and written in place; every segment runs the one
   program that takes a state, keyed by that length, and the kernels bound
-  their key loops by the segment's first position. Where the model has
+  their key loops by the segment's first position;
+- ``hybrid_ssm``: TWO kinds of state a layer, side by side: a key and value
+  CACHE that grows with position (allocated, donated and keyed as
+  ``sparse_mla``'s is) and a FIXED-SIZE float32 scan state with the
+  convolution's last three inputs; the empty ones are zeros, so every
+  segment runs the one program that takes a state. Where the model has
   expert layers the state also carries the count of (token, expert) pairs
   routed to the experts held here; it comes back with the block sums in the
   one fetch.
@@ -59,7 +64,8 @@ DEFAULT_MODEL_ID = "score-lm-default"
 # last one in the smallest bucket that holds the rest. 4,096 x 5,120 keeps
 # every matmul of the published widths MXU-bound (PERF.md §5, the ledger's
 # `brumby-14b-base.score-long` lines since PR 27) and the
-# MLP's [4096, 17408] intermediates at 143 MB; one document a program.
+# MLP's [4096, 17408] intermediates at 143 MB ([4096, 21504] under
+# `falcon-h1-34b`: 176 MB); one document a program.
 SEGMENT_BUCKETS = (1024, 4096)
 
 
@@ -257,9 +263,33 @@ def _record_sparse_keys(state: Dict[str, Any]) -> None:
     obs_trace.record_sparse_attention_keys("causal", causal)
 
 
+def _record_hybrid(state: Dict[str, Any]) -> None:
+    """Of a shard's real tokens, those whose scan chunk read a carried state
+    and those in a document's first chunk; and, a query head a layer, the
+    causal (query, key) pairs they need (``t + 1`` for token ``t``) beside
+    the pairs in the key tiles the attention kernel's grid visits for the
+    segments they ran as."""
+    from agent_tpu.kernels.causal_attention import visited_pairs
+
+    chunk = int(state["cfg"].ssm_chunk)
+    first = carried = causal = computed = 0
+    for doc in state["docs"]:
+        n = doc["n_tokens"]
+        first += min(n, chunk)
+        carried += n - min(n, chunk)
+        causal += n * (n + 1) // 2
+        computed += sum(visited_pairs(ids.shape[1], pos0)
+                        for ids, _, _, pos0 in doc["segments"])
+    obs_trace.record_ssm_tokens("state", carried)
+    obs_trace.record_ssm_tokens("first_chunk", first)
+    obs_trace.record_causal_attention_pairs("causal", causal)
+    obs_trace.record_causal_attention_pairs("computed", computed)
+
+
 # mixer → what the op counts of a shard at dispatch, from its lengths.
 _MIXER_COUNTERS = {"power_retention": _record_retention,
-                   "sparse_mla": _record_sparse_keys}
+                   "sparse_mla": _record_sparse_keys,
+                   "hybrid_ssm": _record_hybrid}
 
 
 def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, Any]:

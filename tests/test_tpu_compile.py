@@ -345,3 +345,33 @@ def test_grouped_expert_matmul_compiles_for_v5e(v5e):
         sd((), jnp.int32), sd((16, 7168, 2048), bf), sd((16, 7168, 2048), bf),
         sd((16, 2048, 7168), bf)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_hybrid_ssm_kernels_compile_for_v5e(v5e):
+    """The state-space scan and the causal grouped-query attention at the
+    ``falcon-h1-34b.score-64k`` cell's own shape: a 4,096-token segment, 32
+    scan heads of 128 in 2 groups with a 256-wide state in chunks of 128; 20
+    query over 4 key-value heads of 128 against a 65,536-token cache. A
+    group's 16 float32 states stay resident in VMEM; the stacked rows of five
+    query heads, a scalar-prefetched position and the two branches of a key
+    tile (across the diagonal, below it) must lower."""
+    from agent_tpu.kernels import causal_attention as ca
+    from agent_tpu.kernels import ssd
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    bf, f32 = jnp.bfloat16, jnp.float32
+    S, Lk, H, G, P, N = 4096, 65536, 32, 2, 128, 256
+    assert ssd.pallas_supported(P, N, 128, bf)
+    assert ca.pallas_supported(S, Lk, 128, bf)
+    scan = jax.jit(lambda x, dt, A, B, C, st: ssd.ssd_scan(
+        x, dt, A, B, C, n_heads=H, n_groups=G, chunk=128, initial_state=st,
+        pallas=True, interpret=False)).lower(
+        sd((S, H * P), bf), sd((S, H), f32), sd((H,), f32),
+        sd((S, G * N), bf), sd((S, G * N), bf), sd((H, N, P), f32)).compile()
+    assert scan.as_text().count("tpu_custom_call") == 1
+    attend = jax.jit(lambda q, k, v, p: ca.causal_attention(
+        q, k, v, p, pallas=True, interpret=False)).lower(
+        sd((4, 5, S, 128), bf), sd((4, Lk, 128), bf), sd((4, Lk, 128), bf),
+        sd((), jnp.int32)).compile()
+    assert attend.as_text().count("tpu_custom_call") == 1
