@@ -366,6 +366,17 @@ def _execute_chunks(
                 dot_product_attention as pp_attn,
             )
 
+    # The three programs below take the weights as ARGUMENTS, so each is
+    # filed under what its traced function closes over and nothing else: the
+    # family, the shapes, ``k`` where top-k is fused in, and ``cfg_key(cfg)``
+    # (which tells quantized from plain, ``pp`` and the rest of a config
+    # apart). The segment slots of a program row follow from ``L``; the
+    # attention function and the mesh are the runtime's, as the cache is.
+    # Never a model id: one wrapper, one trace and one executable for every
+    # model of a config, and a tenant whose parameter TREE differs (another
+    # dtype, other leaves) retraces under the shared wrapper by ``jax.jit``'s
+    # own cache and is still answered by its own weights.
+
     def rebuild_ids(i, real):
         ids = i.astype(jnp.int32)
         if i.dtype == jnp.uint8:
@@ -426,10 +437,8 @@ def _execute_chunks(
             return jax.jit(run_head)
 
         fwd = runtime.compiled(
-            ("map_classify_tpu", model_id, family, rows, L, ("packed", most),
+            ("map_classify_tpu", family, rows, L, ("packed", most),
              cfg_key(cfg)), build_slice)
-        # The head's weights are arguments: one executable for every model
-        # of a config.
         head = runtime.compiled(
             ("map_classify_tpu", "packed_head", family, B, rows * G, most, k,
              cfg_key(cfg)), build_head)
@@ -487,7 +496,7 @@ def _execute_chunks(
         # `bert-base` cell would show it); jobs use one topk, so the fused
         # form stays.
         fn = runtime.compiled(
-            ("map_classify_tpu", model_id, family, B, L, k, cfg_key(cfg)),
+            ("map_classify_tpu", family, B, L, k, cfg_key(cfg)),
             build,
         )
         packed = fn(

@@ -9,7 +9,14 @@ bucketed static shapes (``agent_tpu.models.tokenizer.pad_batch``) — the cache
 stays small and stops missing once the buckets are warm.
 
 Keys are caller-built tuples of hashables (op name, shape tuple, dtype string,
-mesh axis sizes). Stats are exported for the metrics channel (SURVEY.md §5.5).
+mesh axis sizes). A key holds what the traced function CLOSES OVER (family,
+shapes, static values such as a fused top-k, the config's fingerprint) and
+nothing else; never whose weights are passed: parameters are arguments, so
+every model of a config shares one wrapper, one trace and one executable
+(``ops/map_classify_tpu.py``; the mesh and the attention function are the
+runtime's, as this cache is). A field the program does not depend on costs a
+trace, a lowering and a load for every value it takes. Stats are exported for
+the metrics channel (SURVEY.md §5.5).
 
 What the cache holds for ``runtime.compiled(key, build)`` is a ``jax.jit``
 WRAPPER: XLA compiles (or loads from the persistent cache) at the wrapper's

@@ -106,12 +106,18 @@ def test_no_fallback_raises(classify):
 
 
 def test_executable_cache_reuse(classify, ctx):
-    """Same shape bucket twice → second call hits the executable cache."""
+    """Same shape bucket twice → second call hits the executable cache. The
+    key holds no model id, so on a runtime other tests share only a config
+    nobody else runs is sure to miss first."""
     runtime = ctx.runtime
+    own = {"d_model": 64, "n_heads": 4, "n_layers": 2, "d_ff": 128,
+           "max_len": 64, "n_classes": 13}
     before = runtime.cache.stats()
-    classify({"input": [5] * 10, "model_path": "cache-test"}, ctx)
+    classify({"input": [5] * 10, "model_path": "cache-test",
+              "model_config": own}, ctx)
     mid = runtime.cache.stats()
-    classify({"input": [6] * 11, "model_path": "cache-test"}, ctx)
+    classify({"input": [6] * 11, "model_path": "cache-test",
+              "model_config": own}, ctx)
     after = runtime.cache.stats()
     assert mid["misses"] == before["misses"] + 1
     assert after["misses"] == mid["misses"]

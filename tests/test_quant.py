@@ -291,22 +291,30 @@ def test_classify_int8_bad_value_soft_error(rt):
     assert out["ok"] is False and "quant" in out["error"]
 
 
-def test_classify_int8_env_switch(rt, monkeypatch):
-    """TPU_QUANT=int8 turns quantized serving on without payload changes."""
+def _classify_keys_of_call(rt, payload):
+    """The ``map_classify_tpu`` executable keys one call looks up. No key
+    holds a model id (the weights are arguments of the program), so a call's
+    own keys are read off its lookups, not picked out of the cache by name."""
     from agent_tpu.ops import get_op
 
-    monkeypatch.setenv("TPU_QUANT", "int8")
-    out = get_op("map_classify_tpu")(
-        {"texts": ["env switch row"], "topk": 3, "model_config": QCFG,
-         "model_path": "quant-env", "allow_fallback": False},
-        OpContext(runtime=rt),
-    )
+    keys, real = [], rt.compiled
+    rt.compiled = lambda key, build: (keys.append(key), real(key, build))[1]
+    try:
+        out = get_op("map_classify_tpu")(payload, OpContext(runtime=rt))
+    finally:
+        del rt.compiled
     assert out["ok"] is True
-    keys = [
-        k for k in rt.cache._cache.keys()
-        if k[0] == "map_classify_tpu" and k[1] == "quant-env"
-    ]
-    assert keys and all(("quant", "int8") in k[-1] for k in keys)
+    return [k for k in keys if k[0] == "map_classify_tpu"]
+
+
+def test_classify_int8_env_switch(rt, monkeypatch):
+    """TPU_QUANT=int8 turns quantized serving on without payload changes."""
+    monkeypatch.setenv("TPU_QUANT", "int8")
+    keys = _classify_keys_of_call(
+        rt, {"texts": ["env switch row"], "topk": 3, "model_config": QCFG,
+             "model_path": "quant-env", "allow_fallback": False})
+    assert keys and all(
+        k[1] == "encoder" and ("quant", "int8") in k[-1] for k in keys)
 
 
 def test_classify_int8_tp_matches_replicated(rt, rt_tp):
@@ -593,20 +601,13 @@ def test_w8a16_params_actually_sharded_and_int8(rt_tp):
 def test_w8a16_env_switch(rt, monkeypatch):
     """TPU_QUANT=w8a16 turns weight-only serving on without payload
     changes — the same env path as int8."""
-    from agent_tpu.ops import get_op
-
     monkeypatch.setenv("TPU_QUANT", "w8a16")
-    out = get_op("map_classify_tpu")(
-        {"texts": ["w8a16 env switch row"], "topk": 3, "model_config": QCFG,
-         "model_path": "w8a16-env", "allow_fallback": False},
-        OpContext(runtime=rt),
-    )
-    assert out["ok"] is True
-    keys = [
-        k for k in rt.cache._cache.keys()
-        if k[0] == "map_classify_tpu" and k[1] == "w8a16-env"
-    ]
-    assert keys and all(("quant", "w8a16") in k[-1] for k in keys)
+    keys = _classify_keys_of_call(
+        rt, {"texts": ["w8a16 env switch row"], "topk": 3,
+             "model_config": QCFG, "model_path": "w8a16-env",
+             "allow_fallback": False})
+    assert keys and all(
+        k[1] == "encoder" and ("quant", "w8a16") in k[-1] for k in keys)
 
 
 def test_bad_env_quant_fails_shard_not_soft(rt, monkeypatch):

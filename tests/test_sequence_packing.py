@@ -417,7 +417,7 @@ def test_a_full_length_shard_runs_todays_program_under_todays_key(ctx):
     finally:
         del ctx.runtime.compiled
     cfg = state["cfg"]
-    assert keys == [("map_classify_tpu", "pack-full", "encoder", 128, 64, 5,
+    assert keys == [("map_classify_tpu", "encoder", 128, 64, 5,
                      mc.cfg_key(cfg))]
 
 
@@ -533,22 +533,25 @@ def test_no_shard_of_a_window_builds_an_executable(ctx):
     """After ONE warm-up shard a tenant, whatever number of slices it had,
     shards with every number of slices a 512-row shard of this length can
     have (2 to 7: eight rows to every program row would be a shorter bucket)
-    obtain nothing from XLA: one slice program a tenant, one head program
-    for all, no gather per arity."""
+    obtain nothing from XLA: one slice program and one head program for
+    every tenant of the config, no gather per arity."""
     tenants = ["pack-t0", "pack-t1"]
+    # A config no other test of this runtime runs: the programs are every
+    # tenant's of a config, so only then does warm-up obtain them itself.
+    own = {"model_config": {**TINY, "n_classes": 17}}
     reg = MetricsRegistry()
     with obs_trace.use_context(obs_trace.TraceContext(
             trace_id="w", registry=reg, op="map_classify_tpu")):
         for slices, tenant in zip((4, 5), tenants):      # warm-up
-            out = get_op("map_classify_tpu")(
-                _payload(_shard_with_slices(slices), model=tenant), ctx)
+            out = get_op("map_classify_tpu")(_payload(
+                _shard_with_slices(slices), model=tenant, **own), ctx)
             assert out["ok"]
         warm = _value(reg, "runtime_xla_executables_total")
         assert warm > 0
         for slices in range(2, 8):                       # the window
             for tenant in tenants:
-                _, state = op.stage(
-                    _payload(_shard_with_slices(slices), model=tenant), ctx)
+                _, state = op.stage(_payload(
+                    _shard_with_slices(slices), model=tenant, **own), ctx)
                 assert state["chunks"][0].ids.shape[0] == 64 * slices
                 assert op.finalize(op.execute(state, ctx), ctx)["ok"]
         assert _value(reg, "runtime_xla_executables_total") == warm
