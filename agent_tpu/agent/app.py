@@ -36,6 +36,7 @@ redelivery idempotent.
 
 from __future__ import annotations
 
+import json
 import os
 import signal
 import sys
@@ -1165,7 +1166,8 @@ class Agent:
             return thunk()
         t0 = time.perf_counter()
         try:
-            return thunk()   # annotated by the caller's obs.trace.phase
+            # annotated by the caller's obs.trace.phase
+            return self._device_done(thunk())
         except Exception:
             record["status"] = "op_failed"  # trace still captured; op raised
             raise
@@ -1175,6 +1177,7 @@ class Agent:
             except Exception:  # noqa: BLE001 — a torn trace close is not
                 pass            # worth failing the op over
             dt_ms = round((time.perf_counter() - t0) * 1e3, 3)
+            parts_file = self._write_program_parts(artifact)
             n_files = sum(
                 len(files) for _, _, files in os.walk(artifact)
             )
@@ -1182,7 +1185,7 @@ class Agent:
                 artifact=artifact,
                 actual_duration_ms=dt_ms,
                 summary={"op": op, "n_trace_files": n_files,
-                         "duration_ms": dt_ms},
+                         "duration_ms": dt_ms, "parts_file": parts_file},
             )
             self._capture_done.append(record)
             self.recorder.record(
@@ -1191,6 +1194,38 @@ class Agent:
             )
             log("deep capture complete", op=op, artifact=artifact,
                 capture_id=record["capture_id"])
+
+    @staticmethod
+    def _device_done(result: Any) -> Any:
+        """``result`` once the device work behind it is over: a capture that
+        closed when the op's DISPATCH returned held none of it. Blocks on
+        every device array in what the thunk returned, which for an op that
+        declares ``deferred = True`` is the state its fetch will read."""
+        import jax
+
+        return jax.block_until_ready(result)
+
+    def _write_program_parts(self, directory: str) -> Optional[str]:
+        """``program_parts.json`` (``TpuRuntime.program_parts``: which part
+        of a model every instruction of every program that has run belongs
+        to) beside the newest ``.xplane.pb`` under ``directory``; what
+        ``scripts/capture_parts.py`` lays over that trace. ``None`` where
+        there is no runtime or no trace; diagnostics never fail the task."""
+        try:
+            traces = [os.path.join(root, name)
+                      for root, _, names in os.walk(directory)
+                      for name in names if name.endswith(".xplane.pb")]
+            if self.runtime is None or not traces:
+                return None
+            path = os.path.join(
+                os.path.dirname(max(traces, key=os.path.getmtime)),
+                "program_parts.json")
+            with open(path, "w", encoding="utf-8") as f:
+                json.dump(self.runtime.program_parts(), f)
+            return path
+        except Exception as exc:  # noqa: BLE001 — see the docstring
+            log("program parts not written", error=str(exc)[:300])
+            return None
 
     def profiled_call(self, op: str, thunk: Any) -> Any:
         """Run ``thunk`` capturing an XProf trace for the first
@@ -1207,8 +1242,11 @@ class Agent:
         if dev.profile_dir and self.tasks_done < dev.profile_tasks:
             import jax
 
-            with jax.profiler.trace(dev.profile_dir):
-                return thunk()
+            try:
+                with jax.profiler.trace(dev.profile_dir):
+                    return self._device_done(thunk())
+            finally:
+                self._write_program_parts(dev.profile_dir)
         return thunk()
 
     def _maybe_profiled(self, op: str, fn: OpFn, payload: Dict[str, Any],

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from agent_tpu.obs.trace import part
+
 _LANES = 128
 # Queries and keys a tile. A step's scores are [G * 512, 256] float32 a
 # sub-block (2.6 MB at five query heads a key-value head).
@@ -38,12 +40,6 @@ KEY_TILE = 512
 KEY_BLOCK = 256
 _VMEM_LIMIT = 100 * 1024 * 1024
 _MASKED = -1e30
-
-
-def _note() -> None:
-    from agent_tpu.obs.trace import record_attention_block
-
-    record_attention_block("causal_gqa")
 
 
 def pallas_supported(seq_len: int, cache_len: int, d_head: int, dtype) -> bool:
@@ -189,6 +185,7 @@ def _attention_call(q, k, v, pos0, *, interpret: bool):
     )(pos0.reshape(1).astype(jnp.int32), q, k, v)
 
 
+@part("mixer")
 def causal_attention(
     q: jax.Array,          # [Hkv, G, S, D]  rotated and SCALED queries
     k: jax.Array,          # [Hkv, Lk, D]    the document's keys so far, rotated
@@ -202,7 +199,6 @@ def causal_attention(
     scale is the caller's, folded into q before it is rounded), times ``v`` →
     ``[Hkv, G, S, D]``. Keys at and after ``pos0 + S`` are never read."""
     S, D = q.shape[2:]
-    _note()
     if pallas is None:
         pallas = jax.default_backend() == "tpu"
     if pallas and pallas_supported(S, k.shape[1], D, q.dtype):

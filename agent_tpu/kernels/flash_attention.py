@@ -42,6 +42,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from agent_tpu.models.layers import NEG_INF, dot_product_attention
 
+from agent_tpu.obs.trace import part
+
 _LANES = 128  # VPU lane width; scratch last dims pad to this anyway
 
 
@@ -195,6 +197,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref,
         ).astype(o_ref.dtype)
 
 
+@part("mixer")
 def flash_attention(
     q: jax.Array,      # [B, H, Lq, D]
     k: jax.Array,      # [B, H, Lk, D]
@@ -468,6 +471,7 @@ def _whole_row_call(q, k, v, mask3d, seg_q=None, *, n_heads: int, rows: int,
     )(*((q, q, q) if fused else (q, k, v)), *masks)
 
 
+@part("mixer")
 def whole_row_attention(
     q: jax.Array,      # [B, L, H*D], or [B, L, 3*H*D] = [Q | K | V]
     k: Optional[jax.Array],      # [B, L, H*D]; None: ``q`` holds all three
@@ -562,6 +566,7 @@ def flash_fold_supported(q_shape, lk: int, *, block_q: int = 512,
     return lq % bq == 0 and lk % bk == 0
 
 
+@part("mixer")
 def flash_fold(q, k, v, mask, m, l, acc, *, block_q: int = 512,
                block_k: int = 512, interpret: Optional[bool] = None,
                vma=None):
@@ -674,6 +679,7 @@ def _flash_t5_kernel(q_ref, k_ref, v_ref, mask_ref, bias_ref, o_ref,
         ).astype(o_ref.dtype)
 
 
+@part("mixer")
 def flash_attention_t5(
     q: jax.Array,          # [B, H, Lq, D]
     k: jax.Array,          # [B, H, Lk, D]
@@ -1083,6 +1089,7 @@ def _flash_bwd_res(q, k, v, mask3d, o, lse, do, *, block_q, block_k,
     return dq, dk, dv
 
 
+@part("mixer")
 def flash_attention_trainable(
     q: jax.Array,      # [B, H, Lq, D]
     k: jax.Array,      # [B, H, Lk, D]

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from agent_tpu.obs.trace import part
+
 # Rows a tile: an expert's rows are padded to whole tiles.
 ROW_TILE = 256
 # Columns of the expert's width a step.
@@ -62,6 +64,7 @@ def _ffn_kernel(tile_expert_ref, n_tiles_ref, x_ref, wg_ref, wu_ref, wd_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
+@part("experts")
 def grouped_swiglu(x, tile_expert, n_tiles, w_gate, w_up, w_down, *,
                    interpret: bool = False):
     """x [R, d] (rows sorted by expert, ``R`` whole tiles), tile_expert

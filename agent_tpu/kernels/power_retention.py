@@ -50,6 +50,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from agent_tpu.obs.trace import part
+
 _LANES = 128
 
 # Tokens a chunk. Per query-head token the intra-chunk block costs 256 c
@@ -99,12 +101,6 @@ def zero_state(batch: int, n_kv_heads: int, d_head: int) -> State:
     r = state_rows(d_head)
     return (jnp.zeros((batch, n_kv_heads, r, d_head, d_head), jnp.float32),
             jnp.zeros((batch, n_kv_heads, d_head, d_head), jnp.float32))
-
-
-def _note(path: str) -> None:
-    from agent_tpu.obs.trace import record_retention_block
-
-    record_retention_block(path)
 
 
 def _row_weight(r: int, d_head: int) -> float:
@@ -379,6 +375,7 @@ def pallas_supported(d_head: int, chunk: int, dtype) -> bool:
                 and jnp.dtype(dtype) == jnp.bfloat16)
 
 
+@part("mixer")
 def power_retention(
     q: jax.Array,          # [B, L, Hq*D]   normed, rotated
     k: jax.Array,          # [B, L, Hkv*D]  normed, rotated
@@ -404,8 +401,6 @@ def power_retention(
     d = k.shape[-1] // H
     groups = HD // (H * d)
     c = retention_chunk(L, chunk)
-    carried = initial_state is not None
-    _note("state" if selects_state_path(L, carried, c) else "quadratic")
     pad = -L % c
     if pad:
         # Padding tokens sit after the real ones: zero keys and values and a
@@ -431,6 +426,7 @@ def power_retention(
     return (y[:, :L] if pad else y), state
 
 
+@part("mixer")
 def retention_step(q_t, k_t, v_t, log_g_t, state: State, eps: float = EPS):
     """The recurrent one-token form, float32: a second statement of the
     equations for the tests (nothing serves decode). q_t [B, H, G, D], k_t,

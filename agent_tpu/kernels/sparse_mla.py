@@ -54,6 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from agent_tpu.obs.trace import part
+
 _LANES = 128
 # Keys a tile, of both kernels (the mask's last axis, and what a grid step of
 # the attention fetches): 3.1 MiB of keys, values and mask a step. The
@@ -124,12 +126,6 @@ def attention_supported(seq_len: int, cache_len: int, n_heads: int,
                 and seq_len % ATTN_QUERY_TILE == 0
                 and nope_dim == _LANES and v_dim == _LANES
                 and n_heads % ATTN_HEAD_GROUP == 0)
-
-
-def _note() -> None:
-    from agent_tpu.obs.trace import record_attention_block
-
-    record_attention_block("sparse_mla")
 
 
 def _on_chip(pallas: Optional[bool]) -> bool:
@@ -263,6 +259,7 @@ def _index_select_call(qi, w, ki, pos0, *, topk: int, interpret: bool):
     )(pos0.reshape(1).astype(jnp.int32), qh, wh, ki)
 
 
+@part("mixer")
 def index_select(
     qi: jax.Array,         # [S, Hi, Di]  the segment's index queries, rotated
     w: jax.Array,          # [S, Hi]      their head weights, float32
@@ -337,6 +334,7 @@ def _expand_call(latents, w, n_keys, *, dn: int, interpret: bool):
     )(n_keys.reshape(1).astype(jnp.int32), latents, w)
 
 
+@part("project")
 def expand_latents(
     latents: jax.Array,    # [Lk, kvr]  the document's normed latents
     w: jax.Array,          # [H, kvr, Dn + Dv]  a head's W_UK | W_UV
@@ -506,6 +504,7 @@ def _masked_attention_call(q_nope, q_rope, k_nope, k_rope, v, mask, pos0, *,
       mask)
 
 
+@part("mixer")
 def masked_attention(
     q_nope: jax.Array,     # [H, S, Dn]   per-head queries, the part no RoPE,
     q_rope: jax.Array,     # [H, S, Dr]   and their rotary part: both SCALED
@@ -524,7 +523,6 @@ def masked_attention(
     ``pos0 + S`` are never read."""
     H, S, dn = q_nope.shape
     Lk = k_rope.shape[0]
-    _note()
     if _on_chip(pallas) and attention_supported(
             S, Lk, H, dn, v.shape[-1], q_nope.dtype):
         from agent_tpu.kernels.flash_attention import resolve_interpret

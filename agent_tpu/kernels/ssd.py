@@ -42,14 +42,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from agent_tpu.obs.trace import part
+
 _LANES = 128
 _VMEM_LIMIT = 100 * 1024 * 1024
-
-
-def _note(path: str) -> None:
-    from agent_tpu.obs.trace import record_ssm_block
-
-    record_ssm_block(path)
 
 
 def zero_state(n_heads: int, d_head: int, d_state: int) -> jax.Array:
@@ -59,6 +55,7 @@ def zero_state(n_heads: int, d_head: int, d_state: int) -> jax.Array:
 
 # ---- the causal depthwise convolution -------------------------------------
 
+@part("around")
 def causal_conv(u: jax.Array, tail: Optional[jax.Array], w: jax.Array,
                 b: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """``out_t = b + sum_i w[i] u_{t - (K-1) + i}`` a channel, float32:
@@ -221,6 +218,7 @@ def pallas_supported(d_head: int, d_state: int, chunk: int, dtype) -> bool:
                 and jnp.dtype(dtype) == jnp.bfloat16)
 
 
+@part("mixer")
 def ssd_scan(
     x: jax.Array,          # [S, H*P]   a head's inputs, after conv and SiLU
     dt: jax.Array,         # [S, H]     softplus(dt + bias) > 0, float32
@@ -246,7 +244,6 @@ def ssd_scan(
     G = int(n_groups)
     N = B.shape[1] // G
     c = int(chunk)
-    _note("first_chunk" if initial_state is None else "state")
     state = zero_state(H, P, N) if initial_state is None else initial_state
     pad = -S % c
     if pad:

@@ -68,6 +68,7 @@ import numpy as np
 
 from agent_tpu.models import layers
 from agent_tpu.models.layers import Params
+from agent_tpu.obs.trace import part
 
 # Leaves that draw random numbers, in the order that keys them.
 LEAVES = ("embed", "head", "wq", "wk", "wv", "wo", "wg", "w_gate", "w_up",
@@ -452,6 +453,7 @@ def init_params(cfg: DecoderLMConfig, model_id: str, sharding=None) -> Params:
 
 # ---- the mathematics ------------------------------------------------------
 
+@part("norm")
 def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     """Float32 statistics, the input's dtype out."""
     xf = x.astype(jnp.float32)
@@ -459,6 +461,7 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return (out * w.astype(jnp.float32)).astype(x.dtype)
 
 
+@part("around")
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotary positions, half-split pairs (i, i + D/2): x [..., L, H, D],
     positions [L]."""
@@ -485,6 +488,14 @@ def linear(w: Any, x: jax.Array, dtype: Any) -> jax.Array:
     return jnp.dot(x.astype(dtype), w.astype(dtype))
 
 
+def _project(w: Any, x: jax.Array, dtype: Any) -> jax.Array:
+    """:func:`linear` as a mixer's in- or out-projection: the part
+    ``project``, inside a mixer whose own work is ``around``."""
+    with part("project"):
+        return linear(w, x, dtype)
+
+
+@part("around")
 def _power_retention_mixer(p: Params, h: jax.Array, positions: jax.Array,
                            state, cfg: DecoderLMConfig, kernel_opts):
     """h [B, L, d] (normed) → (what enters the residual [B, L, d], new
@@ -494,9 +505,9 @@ def _power_retention_mixer(p: Params, h: jax.Array, positions: jax.Array,
     dtype = cfg.compute_dtype
     B, L, _ = h.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
-    q = linear(p["wq"], h, dtype).reshape(B, L, hq, dh)
-    k = linear(p["wk"], h, dtype).reshape(B, L, hkv, dh)
-    v = linear(p["wv"], h, dtype)
+    q = _project(p["wq"], h, dtype).reshape(B, L, hq, dh)
+    k = _project(p["wk"], h, dtype).reshape(B, L, hkv, dh)
+    v = _project(p["wv"], h, dtype)
     q = rope(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions,
              cfg.rope_theta).reshape(B, L, hq * dh)
     k = rope(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions,
@@ -506,7 +517,7 @@ def _power_retention_mixer(p: Params, h: jax.Array, positions: jax.Array,
     log_g = jax.nn.log_sigmoid(gate)                        # [B, L, Hkv] f32
     y, state = power_retention(q, k, v, log_g, n_kv_heads=hkv,
                                initial_state=state, **kernel_opts)
-    return linear(p["wo"], y, dtype), state
+    return _project(p["wo"], y, dtype), state
 
 
 def yarn_inv_freq(cfg: DecoderLMConfig) -> np.ndarray:
@@ -542,6 +553,7 @@ def softmax_scale(cfg: DecoderLMConfig) -> float:
     return scale
 
 
+@part("around")
 def rope_pairs(x: jax.Array, positions: jax.Array, inv_freq: np.ndarray,
                interleaved: bool) -> jax.Array:
     """Rotary positions on the last axis of x [L, ..., D] with given inverse
@@ -562,6 +574,7 @@ def rope_pairs(x: jax.Array, positions: jax.Array, inv_freq: np.ndarray,
                            axis=-1).astype(x.dtype)
 
 
+@part("around")
 def _sparse_mla_mixer(p: Params, h: jax.Array, positions: jax.Array,
                       state, cfg: DecoderLMConfig, kernel_opts):
     """h [1, S, d] (normed) → (what enters the residual [1, S, d], the
@@ -581,30 +594,31 @@ def _sparse_mla_mixer(p: Params, h: jax.Array, positions: jax.Array,
     inv = yarn_inv_freq(cfg)
     pos0 = positions[0]
 
-    cq = rms_norm(linear(p["w_dq"], h, dtype).astype(jnp.float32),
+    cq = rms_norm(_project(p["w_dq"], h, dtype).astype(jnp.float32),
                   p["q_norm"], eps)
     # The softmax scale goes into the query latent before it is rounded to
     # the compute dtype: a multiply less on every score of the attention.
-    q = linear(p["w_uq"], (cq * softmax_scale(cfg)).astype(dtype),
+    q = _project(p["w_uq"], (cq * softmax_scale(cfg)).astype(dtype),
                dtype).reshape(S, nh, dn + dr)
     cq = cq.astype(dtype)
     q_rope = rope_pairs(q[..., dn:], positions, inv, interleaved=True)
-    latent = linear(p["w_dkv"], h, dtype)
+    latent = _project(p["w_dkv"], h, dtype)
     latent = jnp.concatenate([
         rms_norm(latent[:, :kvr], p["kv_norm"], eps),
         rope_pairs(latent[:, kvr:], positions, inv, interleaved=True)], -1)
 
     # The indexer: rotary part FIRST, half-split pairs; a LayerNorm on keys.
-    qi = linear(p["wi_q"], cq, dtype).reshape(S, hi, di)
+    qi = _project(p["wi_q"], cq, dtype).reshape(S, hi, di)
     qi = jnp.concatenate([rope_pairs(qi[..., :dr], positions, inv, False),
                           qi[..., dr:]], -1)
     ki = layers.layer_norm({"scale": p["ik_norm"], "bias": p["ik_bias"]},
-                           linear(p["wi_k"], h, dtype), 1e-6)
+                           _project(p["wi_k"], h, dtype), 1e-6)
     ki = jnp.concatenate([rope_pairs(ki[:, :dr], positions, inv, False),
                           ki[:, dr:]], -1)
-    wi = jnp.dot(h.astype(dtype), p["wi_w"].astype(dtype),
-                 preferred_element_type=jnp.float32) * float(
-        hi ** -0.5 * di ** -0.5)
+    with part("project"):
+        wi = jnp.dot(h.astype(dtype), p["wi_w"].astype(dtype),
+                     preferred_element_type=jnp.float32) * float(
+            hi ** -0.5 * di ** -0.5)
 
     kv = jax.lax.dynamic_update_slice(state["kv"][0], latent, (pos0, 0))
     kic = jax.lax.dynamic_update_slice(state["ki"][0], ki, (pos0, 0))
@@ -618,7 +632,7 @@ def _sparse_mla_mixer(p: Params, h: jax.Array, positions: jax.Array,
         q[..., :dn].transpose(1, 0, 2), q_rope.transpose(1, 0, 2), k_nope,
         kv[:, kvr:], v, mask, pos0, **kernel_opts)
     o = o.transpose(1, 0, 2).reshape(1, S, nh * cfg.v_head_dim)
-    return linear(p["wo"], o, dtype), {"kv": kv[None], "ki": kic[None]}
+    return _project(p["wo"], o, dtype), {"kv": kv[None], "ki": kic[None]}
 
 
 def _sparse_mla_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
@@ -630,6 +644,7 @@ def _sparse_mla_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
             "ki": jnp.zeros((n, batch, cache_len, cfg.index_head_dim), dtype)}
 
 
+@part("around")
 def _times(x: jax.Array, m: float, dtype) -> jax.Array:
     """``x * m`` in float32, rounded to ``dtype``; ``x`` itself where the
     multiplier is 1 (the other mixers' programs stay as they were)."""
@@ -638,6 +653,7 @@ def _times(x: jax.Array, m: float, dtype) -> jax.Array:
     return (x.astype(jnp.float32) * m).astype(dtype)
 
 
+@part("around")
 def _hybrid_ssm_mixer(p: Params, h: jax.Array, positions: jax.Array,
                       state, cfg: DecoderLMConfig, kernel_opts):
     """h [1, S, d] (normed) → (what enters the residual [1, S, d], the
@@ -665,12 +681,12 @@ def _hybrid_ssm_mixer(p: Params, h: jax.Array, positions: jax.Array,
     # Attention: G query heads a key-value head, rotary positions, no norm.
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     ha = _times(h, cfg.attention_in_multiplier, dtype)
-    q = rope(linear(p["wq"], ha, dtype).reshape(S, hq, dh).astype(f32),
+    q = rope(_project(p["wq"], ha, dtype).reshape(S, hq, dh).astype(f32),
              positions, cfg.rope_theta)
     q = (q * float(dh) ** -0.5).astype(dtype)
-    k = linear(p["wk"], ha, dtype).astype(f32) * cfg.key_multiplier
+    k = _project(p["wk"], ha, dtype).astype(f32) * cfg.key_multiplier
     k = rope(k.reshape(S, hkv, dh), positions, cfg.rope_theta).astype(dtype)
-    v = linear(p["wv"], ha, dtype).reshape(S, hkv, dh)
+    v = _project(p["wv"], ha, dtype).reshape(S, hkv, dh)
     kc = jax.lax.dynamic_update_slice(state["k"][0], k.transpose(1, 0, 2),
                                       (0, pos0, 0))
     vc = jax.lax.dynamic_update_slice(state["v"][0], v.transpose(1, 0, 2),
@@ -678,7 +694,7 @@ def _hybrid_ssm_mixer(p: Params, h: jax.Array, positions: jax.Array,
     o = causal_attention.causal_attention(
         q.reshape(S, hkv, hq // hkv, dh).transpose(1, 2, 0, 3), kc, vc, pos0,
         **kernel_opts)
-    attended = linear(p["wo"], o.transpose(2, 0, 1, 3).reshape(S, hq * dh),
+    attended = _project(p["wo"], o.transpose(2, 0, 1, 3).reshape(S, hq * dh),
                       dtype)
 
     # The scan: in-projection [z | x | B | C | dt], each part times its own
@@ -686,7 +702,7 @@ def _hybrid_ssm_mixer(p: Params, h: jax.Array, positions: jax.Array,
     H, P, N, G = (cfg.ssm_n_heads, cfg.ssm_d_head, cfg.ssm_d_state,
                   cfg.ssm_n_groups)
     d_ssm = H * P
-    proj = linear(p["w_ssm_in"], _times(h, cfg.ssm_in_multiplier, dtype),
+    proj = _project(p["w_ssm_in"], _times(h, cfg.ssm_in_multiplier, dtype),
                   dtype).astype(f32)
     proj = proj * by_columns(cfg.ssm_parts)
     z, xbc, dt = jnp.split(proj, [d_ssm, 2 * d_ssm + 2 * G * N], axis=1)
@@ -702,7 +718,7 @@ def _hybrid_ssm_mixer(p: Params, h: jax.Array, positions: jax.Array,
     # Gate, THEN a group's RMS norm (``mamba_norm_before_gate`` false).
     y = rms_norm((y * jax.nn.silu(z)).reshape(S, G, d_ssm // G),
                  p["ssm_norm"].reshape(G, d_ssm // G), cfg.rms_norm_eps)
-    scanned_out = linear(p["w_ssm_out"], y.reshape(S, d_ssm).astype(dtype),
+    scanned_out = _project(p["w_ssm_out"], y.reshape(S, d_ssm).astype(dtype),
                          dtype)
 
     mixed = (attended.astype(f32) * cfg.attention_out_multiplier
@@ -743,6 +759,7 @@ def starts_from_nothing(cfg: DecoderLMConfig) -> bool:
     return cfg.mixer not in MIXER_STATES and not cfg.n_experts
 
 
+@part("around")
 def init_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
     """What :func:`forward_segment` takes as ``state`` for a document's
     FIRST segment: ``None`` where the mixer starts from nothing and no layer
@@ -755,6 +772,7 @@ def init_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
     return mixer
 
 
+@part("ffn")
 def _swiglu(p: Params, n: jax.Array, names, dtype, gate_multiplier=1.0,
             down_multiplier=1.0) -> jax.Array:
     gate, up, down = names
@@ -774,6 +792,7 @@ def _plain_weights(leaf: Any, dtype) -> jax.Array:
     return leaf.astype(dtype)
 
 
+@part("experts")
 def _experts_ffn(p: Params, n: jax.Array, cfg: DecoderLMConfig, kernel_opts):
     """n [B, S, d] (normed) → (shared expert + the routed experts held here,
     [B, S, d]; the (token, expert) pairs routed here)."""
@@ -806,14 +825,17 @@ def _layer(p: Params, x: jax.Array, positions, state, cfg, kernel_opts,
     dtype = cfg.compute_dtype
     h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
     mixed, state = MIXERS[cfg.mixer](p, h, positions, state, cfg, kernel_opts)
-    x = x + mixed
+    with part("around"):
+        x = x + mixed
     n = rms_norm(x, p["ln2"], cfg.rms_norm_eps)
     if ffn == "experts":
         y, pairs = _experts_ffn(p, n, cfg, kernel_opts)
-        return x + y, state, pairs
+        with part("around"):
+            return x + y, state, pairs
     y = _swiglu(p, n, ("w_gate", "w_up", "w_down"), dtype,
                 cfg.mlp_gate_multiplier, cfg.mlp_down_multiplier)
-    return x + y, state, jnp.zeros((), jnp.float32)
+    with part("around"):
+        return x + y, state, jnp.zeros((), jnp.float32)
 
 
 def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
@@ -826,8 +848,9 @@ def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
     ``{"mixer": ..., "pairs": ...}``, ``pairs`` the running count of (token,
     expert) pairs routed to the experts held here. Returns the final-normed
     hidden states [B, S, d] and the state after the segment."""
-    x = _times(params["embed"][ids], cfg.embedding_multiplier,
-               cfg.compute_dtype)
+    with part("embed"):
+        x = _times(params["embed"][ids], cfg.embedding_multiplier,
+                   cfg.compute_dtype)
     positions = pos0.astype(jnp.int32) + jnp.arange(ids.shape[1])
     routed = bool(cfg.n_experts)
     mixer_state = state["mixer"] if routed and state is not None else state
@@ -835,8 +858,9 @@ def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
         (), jnp.float32)
     new_states = []
     for group, ffn, first, n in cfg.layer_groups:
-        mine = None if mixer_state is None else jax.tree_util.tree_map(
-            lambda a: a[first:first + n], mixer_state)
+        with part("around"):
+            mine = None if mixer_state is None else jax.tree_util.tree_map(
+                lambda a: a[first:first + n], mixer_state)
 
         def step(carry, xs, ffn=ffn, mine=mine):
             x, pairs = carry
@@ -845,10 +869,16 @@ def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
             return (x, pairs + more), st
 
         xs = params[group] if mine is None else (params[group], mine)
-        (x, pairs), st = jax.lax.scan(step, (x, pairs), xs)
+        # The loop's own work (a layer's leaves and state sliced out of the
+        # stack, the new state written into it) is ``around``: copies the
+        # model's stacking asks for; every part inside the body is its own.
+        with part("around"):
+            (x, pairs), st = jax.lax.scan(step, (x, pairs), xs)
         new_states.append(st)
-    mixer_state = new_states[0] if len(new_states) == 1 else \
-        jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, 0), *new_states)
+    with part("around"):
+        mixer_state = new_states[0] if len(new_states) == 1 else \
+            jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, 0),
+                                   *new_states)
     # The head's multiplier goes into the hidden states: for a power of two
     # (the published 2^-7) the logits are the same numbers, bit for bit.
     hidden = _times(rms_norm(x, params["final_norm"], cfg.rms_norm_eps),
@@ -902,6 +932,7 @@ def segment_flops(cfg: DecoderLMConfig, n_tokens: int, pos0: int) -> float:
                 + 2.0 * d * cfg.vocab_size)
 
 
+@part("head")
 def blocked_logprobs(hidden: jax.Array, head: jax.Array, targets: jax.Array,
                      vocab_block: Optional[int] = None) -> jax.Array:
     """log p(target) per position, float32, never holding more than a
@@ -937,6 +968,7 @@ def blocked_logprobs(hidden: jax.Array, head: jax.Array, targets: jax.Array,
     return hit - (m + jnp.log(l))
 
 
+@part("head")
 def segment_block_sums(hidden: jax.Array, head: jax.Array,
                        targets: jax.Array, n_valid: jax.Array,
                        block: int = LOSS_BLOCK) -> jax.Array:

@@ -24,6 +24,7 @@ import numpy as np
 
 from agent_tpu.models import layers
 from agent_tpu.models.layers import Params
+from agent_tpu.obs.trace import part
 
 
 @dataclass(frozen=True)
@@ -116,6 +117,7 @@ def load_npz(path: str, cfg: EncoderConfig) -> Params:
     return layers.assign_from_npz(init_params(cfg, model_id=path), path)
 
 
+@part("around")
 def segment_layout(segment_lengths: jax.Array, length: int):
     """The packed layout's slot arrays from its wire form. ``segment_lengths``
     [P, G] int32: the token counts of the rows laid end to end in each
@@ -163,6 +165,7 @@ def _run_blocks(params, x, attn_mask, cfg, attn_fn, remat, mesh,
     return layers.layer_norm(params["ln_f"], x), aux_total
 
 
+@part("head")
 def classify_head(params: Params, pooled: jax.Array, cfg: EncoderConfig):
     """Pooled rows [R, d_model] (f32) → logits [R, n_classes] (f32)."""
     dtype = cfg.compute_dtype
@@ -188,19 +191,22 @@ def pooled_segments(
     dtype = cfg.compute_dtype
     L, G = ids.shape[1], segment_lengths.shape[1]
     segment_ids, positions = segment_layout(segment_lengths, L)
-    x = (params["embed"].astype(dtype)[ids]
-         + params["pos"][:L].astype(dtype)[positions])
+    with part("embed"):
+        x = (params["embed"].astype(dtype)[ids]
+             + params["pos"][:L].astype(dtype)[positions])
     x, _ = _run_blocks(
         params, x, layers.segment_mask_to_attn(segment_ids), cfg, attn_fn,
         remat, mesh, segment_ids=segment_ids)
-    member = (segment_ids[:, None, :]
-              == jnp.arange(1, G + 1, dtype=jnp.int32)[None, :, None])
-    # 0/1 weights: at full precision the sums are the tokens' own f32 sums.
-    sums = jnp.einsum("pgl,pld->pgd", member.astype(jnp.float32),
-                      x.astype(jnp.float32),
-                      precision=jax.lax.Precision.HIGHEST)
-    denom = jnp.maximum(segment_lengths, 1).astype(jnp.float32)
-    return sums / denom[:, :, None]
+    with part("head"):
+        member = (segment_ids[:, None, :]
+                  == jnp.arange(1, G + 1, dtype=jnp.int32)[None, :, None])
+        # 0/1 weights: at full precision the sums are the tokens' own f32
+        # sums.
+        sums = jnp.einsum("pgl,pld->pgd", member.astype(jnp.float32),
+                          x.astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)
+        denom = jnp.maximum(segment_lengths, 1).astype(jnp.float32)
+        return sums / denom[:, :, None]
 
 
 def forward(
@@ -252,17 +258,22 @@ def forward(
         return classify_head(params, pooled, cfg)
     dtype = cfg.compute_dtype
     L = ids.shape[1]
-    x = params["embed"].astype(dtype)[ids] + params["pos"][:L].astype(dtype)[None]
+    with part("embed"):
+        x = (params["embed"].astype(dtype)[ids]
+             + params["pos"][:L].astype(dtype)[None])
     x, aux_total = _run_blocks(
         params, x, layers.pad_mask_to_attn(mask), cfg, attn_fn, remat, mesh)
-    denom = jnp.maximum(mask.sum(axis=1, keepdims=True), 1).astype(jnp.float32)
-    pooled = (x.astype(jnp.float32) * mask[:, :, None]).sum(axis=1) / denom
+    with part("head"):
+        denom = jnp.maximum(
+            mask.sum(axis=1, keepdims=True), 1).astype(jnp.float32)
+        pooled = (x.astype(jnp.float32) * mask[:, :, None]).sum(axis=1) / denom
     logits = classify_head(params, pooled, cfg)
     if with_aux:
         return logits, aux_total / max(1, cfg.n_layers)
     return logits
 
 
+@part("head")
 def topk_probs(logits: jax.Array, k: int):
     """On-device top-k over softmax probabilities → (values, indices), both
     ``[B, k]`` — the host fetches k numbers per row instead of the full

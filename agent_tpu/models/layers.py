@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from agent_tpu.obs.trace import part
+
 Params = Dict[str, Any]
 
 NEG_INF = -1e9  # additive mask value; finite so bf16 stays NaN-free
@@ -65,6 +67,7 @@ def init_layer_norm(d: int) -> Params:
     }
 
 
+@part("norm")
 def layer_norm(p: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     # Normalize in f32 regardless of compute dtype: variance in bf16 is lossy.
     xf = x.astype(jnp.float32)
@@ -91,6 +94,7 @@ def init_attention(key: jax.Array, d_model: int, n_heads: int) -> Params:
     }
 
 
+@part("mixer")
 def dot_product_attention(
     q: jax.Array,       # [B, H, Lq, D]
     k: jax.Array,       # [B, H, Lk, D]
@@ -120,6 +124,7 @@ def dot_product_attention(
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+@part("project")
 def _proj_in(leaf: Any, x: jax.Array, dtype: Any) -> jax.Array:
     """x [B, L, d] @ leaf [d, H, E] → [B, H, L, E]; int8 (W8A8) and W8A16
     paths for quantized leaves (``models.quant`` leaf conventions)."""
@@ -132,6 +137,7 @@ def _proj_in(leaf: Any, x: jax.Array, dtype: Any) -> jax.Array:
     return jnp.einsum("bld,dhe->bhle", x.astype(dtype), leaf.astype(dtype))
 
 
+@part("project")
 def _proj_out(leaf: Any, x: jax.Array, dtype: Any) -> jax.Array:
     """x [B, H, L, E] @ leaf [H, E, d] → [B, L, d]; int8 (W8A8) and W8A16
     paths for quantized leaves."""
@@ -174,6 +180,7 @@ def fuse_qkv(p: Params) -> Params:
     return fused
 
 
+@part("project")
 def qkv_leaves(p: Params) -> Tuple[Any, Any, Any]:
     """``(wq, wk, wv)`` of an attention subtree in either layout: its own
     three leaves, or the column blocks of ``wqkv`` as [d, H, E] views (H and
@@ -220,6 +227,7 @@ def _attention_lane_dense(p: Params, x_q, x_kv, mask, dtype, attn_fn,
     if entry is None:
         return None
 
+    @part("project")
     def proj(w, x):
         # "bld,dhe->blhe" as the 2-D matmul it is: XLA lays a 4-D result out
         # with L in the lanes and copies it back; a [B*L, H*E] one stays put.
@@ -234,10 +242,12 @@ def _attention_lane_dense(p: Params, x_q, x_kv, mask, dtype, attn_fn,
         _note_qkv("separate")
         wq, wk, wv = qkv_leaves(p)
         operands = (proj(wq, x_q), proj(wk, x_kv), proj(wv, x_kv))
-    out = entry(*operands, mask, n_heads=H, segment_ids=segment_ids)
-    y = jnp.dot(out.reshape(-1, H * E),
-                wo.astype(dtype).reshape(H * E, d_model))
-    return y.reshape(B, Lq, d_model)
+    with part("mixer"):
+        out = entry(*operands, mask, n_heads=H, segment_ids=segment_ids)
+    with part("project"):
+        y = jnp.dot(out.reshape(-1, H * E),
+                    wo.astype(dtype).reshape(H * E, d_model))
+        return y.reshape(B, Lq, d_model)
 
 
 def attention(
@@ -310,22 +320,6 @@ def attention(
             maxb = block_table.shape[1]
             bs = cache["k"].shape[2]                  # pool block size
             lk = mask.shape[-1]
-            ji = cache_index // bs                    # [B] logical block
-            off = cache_index % bs                    # [B] offset in block
-            # Rows whose position ran past table coverage (frozen at the
-            # engine's max) write to the trash block, not a clamped real one.
-            blk = jnp.where(
-                ji < maxb,
-                jnp.take_along_axis(
-                    block_table, jnp.minimum(ji, maxb - 1)[:, None], axis=1
-                )[:, 0],
-                0,
-            )
-            # Scatter one K/V row per batch row: pool[blk[b], :, off[b]] =
-            # new_kv[b]. Duplicate (blk, off) pairs only ever collide at the
-            # trash block (allocated blocks are row-exclusive) — harmless.
-            pk = cache["k"].astype(dtype).at[blk, :, off].set(k[:, :, 0])
-            pv = cache["v"].astype(dtype).at[blk, :, off].set(v[:, :, 0])
 
             def view(pool):
                 x = pool[block_table]                 # [B, MAXB, H, BS, D]
@@ -333,7 +327,29 @@ def attention(
                 x = x.reshape(bsz, pool.shape[1], maxb * bs, pool.shape[3])
                 return x[:, :, :lk]                   # dense-shape view
 
-            out = attn_fn(q, view(pk), view(pv), mask)
+            with part("around"):
+                ji = cache_index // bs                # [B] logical block
+                off = cache_index % bs                # [B] offset in block
+                # Rows whose position ran past table coverage (frozen at
+                # the engine's max) write to the trash block, not a clamped
+                # real one.
+                blk = jnp.where(
+                    ji < maxb,
+                    jnp.take_along_axis(
+                        block_table, jnp.minimum(ji, maxb - 1)[:, None],
+                        axis=1
+                    )[:, 0],
+                    0,
+                )
+                # Scatter one K/V row per batch row: pool[blk[b], :,
+                # off[b]] = new_kv[b]. Duplicate (blk, off) pairs only ever
+                # collide at the trash block (allocated blocks are
+                # row-exclusive) — harmless.
+                pk = cache["k"].astype(dtype).at[blk, :, off].set(k[:, :, 0])
+                pv = cache["v"].astype(dtype).at[blk, :, off].set(v[:, :, 0])
+                k, v = view(pk), view(pv)
+            with part("mixer"):
+                out = attn_fn(q, k, v, mask)
             y = _proj_out(p["wo"], out, dtype)
             return y, {"k": pk, "v": pv}
         if getattr(cache_index, "ndim", 0) == 1:
@@ -344,23 +360,28 @@ def attention(
             # over the cache. Which form the chip's decode step runs faster
             # in: not measured on the present tree (PERF.md §7, row 1: the
             # `/v1/infer` kind that was built and not shipped).
-            sel = (
-                jnp.arange(cache["k"].shape[2])[None, :]
-                == cache_index[:, None]
-            )[:, None, :, None]                       # [B, 1, Lmax, 1]
-            k = jnp.where(sel, k, cache["k"].astype(dtype))
-            v = jnp.where(sel, v, cache["v"].astype(dtype))
+            with part("around"):
+                sel = (
+                    jnp.arange(cache["k"].shape[2])[None, :]
+                    == cache_index[:, None]
+                )[:, None, :, None]                   # [B, 1, Lmax, 1]
+                k = jnp.where(sel, k, cache["k"].astype(dtype))
+                v = jnp.where(sel, v, cache["v"].astype(dtype))
         else:
-            zero = jnp.zeros((), dtype=jnp.int32)
-            k = jax.lax.dynamic_update_slice(
-                cache["k"].astype(dtype), k, (zero, zero, cache_index, zero)
-            )
-            v = jax.lax.dynamic_update_slice(
-                cache["v"].astype(dtype), v, (zero, zero, cache_index, zero)
-            )
+            with part("around"):
+                zero = jnp.zeros((), dtype=jnp.int32)
+                k = jax.lax.dynamic_update_slice(
+                    cache["k"].astype(dtype), k,
+                    (zero, zero, cache_index, zero)
+                )
+                v = jax.lax.dynamic_update_slice(
+                    cache["v"].astype(dtype), v,
+                    (zero, zero, cache_index, zero)
+                )
         cache = {"k": k, "v": v}
 
-    out = attn_fn(q, k, v, mask)
+    with part("mixer"):
+        out = attn_fn(q, k, v, mask)
     y = _proj_out(p["wo"], out, dtype)
     return y, cache
 
@@ -370,6 +391,7 @@ def init_ffn(key: jax.Array, d_model: int, d_ff: int) -> Params:
     return {"wi": init_dense(k1, d_model, d_ff), "wo": init_dense(k2, d_ff, d_model)}
 
 
+@part("ffn")
 def ffn(p: Params, x: jax.Array, dtype: Any) -> jax.Array:
     h = jax.nn.gelu(dense(p["wi"], x, dtype))
     return dense(p["wo"], h, dtype)
@@ -413,7 +435,8 @@ def encoder_block(
     h = layer_norm(p["ln1"], x)
     a, _ = attention(p["attn"], h, h, mask, dtype, attn_fn=attn_fn,
                      segment_ids=segment_ids)
-    x = x + a
+    with part("around"):
+        x = x + a
     h = layer_norm(p["ln2"], x)
     if "moe" in p:
         from agent_tpu.models import moe as moe_mod
@@ -429,12 +452,16 @@ def encoder_block(
             )
         mcfg, mesh = moe_ctx
         B, L, d = h.shape
-        y, aux = moe_mod.moe_ffn(
-            p["moe"], h.astype(dtype).reshape(B * L, d), mcfg, mesh=mesh
-        )
-        out = x + y.reshape(B, L, d).astype(x.dtype)
+        with part("experts"):
+            y, aux = moe_mod.moe_ffn(
+                p["moe"], h.astype(dtype).reshape(B * L, d), mcfg, mesh=mesh
+            )
+        with part("around"):
+            out = x + y.reshape(B, L, d).astype(x.dtype)
         return (out, aux) if with_aux else out
-    out = x + ffn(p["ffn"], h, dtype)
+    y = ffn(p["ffn"], h, dtype)
+    with part("around"):
+        out = x + y
     return (out, jnp.float32(0.0)) if with_aux else out
 
 
@@ -454,12 +481,16 @@ def decoder_block(
         p["attn"], h, h, self_mask, dtype, cache=cache,
         cache_index=cache_index, block_table=block_table,
     )
-    x = x + a
+    with part("around"):
+        x = x + a
     h = layer_norm(p["ln_x"], x)
     a, _ = attention(p["xattn"], h, enc_out, enc_mask, dtype)
-    x = x + a
+    with part("around"):
+        x = x + a
     h = layer_norm(p["ln2"], x)
-    return x + ffn(p["ffn"], h, dtype), cache
+    y = ffn(p["ffn"], h, dtype)
+    with part("around"):
+        return x + y, cache
 
 
 def sinusoidal_positions(length: int, d_model: int) -> np.ndarray:
@@ -483,6 +514,7 @@ def pad_mask_to_attn(mask: jax.Array) -> jax.Array:
     return mask[:, None, None, :]
 
 
+@part("around")
 def segment_mask_to_attn(segment_ids: jax.Array) -> jax.Array:
     """[B, L] segment ids (0 = pad) → the block-diagonal [B, 1, L, L] attend
     mask: a query attends the keys of its own segment and no other, a pad

@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from agent_tpu.models import layers
 from agent_tpu.models.layers import Params
+from agent_tpu.obs.trace import part
 
 
 @dataclass(frozen=True)
@@ -265,6 +266,7 @@ def _held_dense(x, local, gates, w_gate, w_up, w_down):
                              jnp.zeros(x.shape, f32))
 
 
+@part("experts")
 def held_experts_ffn(x: jax.Array, experts: jax.Array, gates: jax.Array,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                      first: int, *, pallas=None, interpret=None):

@@ -354,6 +354,37 @@ def annotate(name: str) -> Any:
     return ann
 
 
+# ---- the device side of the same idea: a model's parts, by name ----
+
+# The closed vocabulary of :func:`part`, the same for every model family so
+# that one reduction serves every program: ``embed``; ``norm``; ``project``
+# (a mixer's in- and out-projections, the latents' expansion); ``mixer`` (the
+# token-mixing computation itself: everything inside a ``kernels/`` entry
+# function, or the ``jax.numpy`` path that stands in for it); ``around``
+# (rotary, convolution, gate, SiLU, multipliers, group norm's scaling, cache
+# and state writes, residual adds, relayouts written in the model); ``ffn``
+# (dense and shared-expert feed-forwards); ``experts`` (router, sort, gathers,
+# grouped matmul, combine); ``head``.
+PARTS = ("embed", "norm", "project", "mixer", "around", "ffn", "experts",
+         "head")
+
+
+def part(name: str) -> Any:
+    """``jax.named_scope("part:" + name)`` and nothing else: what
+    :func:`annotate` is to a host span, for the DEVICE's time. Entered where
+    the work is written (``models/``, ``kernels/``), the name rides XLA's
+    ``op_name`` metadata into every executable, the runtime reads it back out
+    of the compiled text (``TpuRuntime.program_parts``) and a reduction lays
+    it over a device trace. Nested, the innermost part is the instruction's.
+    A scope is metadata: the optimized program is the same with and without.
+    A name outside :data:`PARTS` raises while the program is traced."""
+    if name not in PARTS:
+        raise ValueError(f"unknown part {name!r}: one of {PARTS}")
+    import jax
+
+    return jax.named_scope("part:" + name)
+
+
 class phase:
     """One phase boundary, measured once: ``with phase("stage", ctx) as ph``
     reads the clock on entry and exit and feeds every sink from that pair.
@@ -535,6 +566,22 @@ def record_xla_cache_hit() -> None:
            "(a subset of runtime_xla_executables_total)")
 
 
+def record_trace_lower(seconds: float) -> None:
+    """What a program's FIRST call cost its caller beside XLA's own seconds:
+    Python tracing, lowering to MLIR, the persistent cache's key, dispatch
+    (``runtime/executor.py: Program``: the call's wall time less the compile
+    seconds the listener recorded on that thread during it). Ticks
+    ``runtime_trace_lower_seconds_total{op}`` in the ambient task's
+    registry, else the process's."""
+    ctx = current()
+    _count("runtime_trace_lower_seconds_total",
+           "Seconds the first call of a program of the runtime's keyed cache "
+           "took beside what XLA's listener counted for it "
+           "(runtime_compile_seconds_total): tracing, lowering, the cache "
+           "key, dispatch; by the op whose task was running",
+           max(0.0, float(seconds)), op=(ctx.op if ctx else "") or "?")
+
+
 def record_params_build(seconds: float) -> None:
     """One model's weights built and placed on the device (a params-store
     miss in ``TpuRuntime.get_params``)."""
@@ -592,19 +639,6 @@ def record_classify_shard(real_tokens: int, token_slots: int,
            "bucket (padded)", layout="packed" if packed else "padded")
 
 
-def record_retention_block(path: str) -> None:
-    """One retention mixer TRACED into an XLA program on ``path``
-    (``state``: chunk-plus-state, ``quadratic``: the quadratic form alone;
-    ``kernels/power_retention.selects_state_path`` decides from the shapes).
-    Beside :func:`record_attention_block`: ticks while a program is traced,
-    once a mixer call (once a scanned layer stack), never when it runs."""
-    _count("retention_blocks_traced_total",
-           "Retention mixers traced into XLA programs, by the path the "
-           "kernel's shape predicate selected (ticks while a program is "
-           "traced, not when it runs)",
-           path=path)
-
-
 def record_retention_tokens(path: str, tokens: int) -> None:
     """Real tokens DISPATCHED whose mixer did (``state``) or did not
     (``quadratic``) read a carried state: counted by the op at dispatch,
@@ -627,19 +661,6 @@ def record_sparse_attention_keys(kind: str, keys: int) -> None:
                "mixer, a layer: under the selection (selected) and all "
                "causal keys (causal)",
                float(keys), kind=kind)
-
-
-def record_ssm_block(path: str) -> None:
-    """One state-space scan TRACED into an XLA program on ``path``
-    (``state``: a carried state comes in, ``first_chunk``: the document
-    starts in this call; ``kernels/ssd.py``). Beside
-    :func:`record_attention_block`: ticks while a program is traced, once a
-    mixer call (once a scanned layer stack), never when it runs."""
-    _count("ssm_blocks_traced_total",
-           "State-space scans traced into XLA programs, by whether the call "
-           "takes a carried state (state) or starts a document "
-           "(first_chunk); ticks while a program is traced, not when it runs",
-           path=path)
 
 
 def record_ssm_tokens(path: str, tokens: int) -> None:

@@ -21,7 +21,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from agent_tpu.config import DeviceConfig
 from agent_tpu.obs import trace as obs_trace
-from agent_tpu.runtime.executor import ExecutableCache, install_xla_listener
+from agent_tpu.runtime.executor import (
+    ExecutableCache,
+    Program,
+    install_xla_listener,
+    parts_of_text,
+)
 from agent_tpu.runtime.mesh import build_mesh
 from agent_tpu.utils.logging import log
 from agent_tpu.utils.paths import cache_dir
@@ -363,8 +368,30 @@ class TpuRuntime:
         key: Tuple[Hashable, ...],
         build: Callable[[], Callable],
     ) -> Callable:
-        """Executable for ``key``, compiling at most once (see ExecutableCache)."""
-        return self.cache.get_or_build(key, build)
+        """Executable for ``key``, compiling at most once (see ExecutableCache).
+        Until its first call is over the caller gets the first-call layer
+        (``executor.Program``), after it the ``jax.jit`` wrapper itself."""
+        program = self.cache.get_or_build(key, lambda: Program(build()))
+        return program if program.noted is None else program.wrapper
+
+    def program_parts(self) -> Dict[str, list]:
+        """ON DEMAND (a traced benchmark run after its window, an operator's
+        capture): which part of a model (``obs.trace.PARTS``) every
+        instruction of every program that has run belongs to, read out of
+        the compiled text: ``{module name: [{"instructions": {name: part or
+        None}, "mixed": {fusion: [parts]}, "named_share": share}, ...]}``,
+        one map an executable (two shapes of one function share a module
+        name; a trace's reader takes the map that holds the instruction
+        names it sees). Asking a program that has run for its text is a
+        lookup, not a compile (``executor.Program.compiled_text``). Never
+        called on the hot path or in an untraced run."""
+        out: Dict[str, list] = {}
+        for program in self.cache.values():
+            text = program.compiled_text()
+            if text is not None:
+                module, parts = parts_of_text(text)
+                out.setdefault(module, []).append(parts)
+        return out
 
     def _model_ids_snapshot(self) -> set:
         with self._params_lock:

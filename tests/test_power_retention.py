@@ -209,17 +209,20 @@ def test_state_path_is_chosen_from_shapes(seq_len, carried, chunk, want):
     assert pr.selects_state_path(seq_len, carried, chunk) is want
 
 
-def test_traced_blocks_are_counted_by_path():
-    from agent_tpu.obs.metrics import get_registry
+@pytest.mark.parametrize("chunk,path", [(16, "state"), (32, "quadratic")])
+def test_a_traced_mixer_is_named_in_the_compiled_text(chunk, path):
+    """What replaced ``retention_blocks_traced_total{path}``: the program
+    itself says where its retention is. Everything the entry function traces
+    carries the part ``mixer`` in the compiled text (``runtime/executor.py:
+    parts_of_text``), on either path the shapes select."""
+    from agent_tpu.runtime.executor import parts_of_text
 
-    def count(path):
-        snap = get_registry().snapshot().get("retention_blocks_traced_total")
-        return sum(s["value"] for s in (snap or {}).get("series", [])
-                   if s["labels"].get("path") == path)
-
-    before = count("state"), count("quadratic")
+    assert pr.selects_state_path(32, False, chunk) is (path == "state")
     q, k, v, g = _inputs(1, 32, 2, 1, 16)
-    pr.power_retention(q, k, v, g, n_kv_heads=1, chunk=16, pallas=False)
-    pr.power_retention(q, k, v, g, n_kv_heads=1, chunk=32, pallas=False)
-    assert count("state") == before[0] + 1
-    assert count("quadratic") == before[1] + 1
+    text = jax.jit(lambda *a: pr.power_retention(
+        *a, n_kv_heads=1, chunk=chunk, pallas=False)).lower(
+            q, k, v, g).compile().as_text()
+    _, parts = parts_of_text(text)
+    named = {p for p in parts["instructions"].values() if p}
+    assert named == {"mixer"}
+    assert parts["named_share"] > 0.7
