@@ -13,7 +13,15 @@ hold rows) are prefetched scalars: a step past the last tile names that
 tile's blocks again (no copy) and computes nothing, so the program is
 fixed-shape at the worst case (every token routed here ``k`` times) and costs
 what the routed rows cost. At about 128 rows an expert a segment the layer
-is bound by reading the experts' weights once (PERF.md section 5)."""
+is bound by reading the experts' weights once (PERF.md section 5).
+
+The weights are read WHERE THEY LIE: the operands are the model's stacked
+leaves ``[L, E, ...]`` and a third prefetched scalar, ``layer``, is the
+leading block index of every weight block. A custom call wants a standalone
+operand, so a layer's slice handed to it inside the layer scan is a copy of
+all the layer's experts (1.41 GB a layer a segment at deepseek-v3.2's widths)
+made to be read once; the whole stack is the loop's own invariant and costs
+nothing. A leaf of one layer ``[E, ...]`` is the same path at ``L = 1``."""
 
 from __future__ import annotations
 
@@ -38,9 +46,9 @@ def pallas_supported(d_model: int, d_expert: int, dtype) -> bool:
                 and d_expert % WIDTH_TILE == 0)
 
 
-def _ffn_kernel(tile_expert_ref, n_tiles_ref, x_ref, wg_ref, wu_ref, wd_ref,
-                y_ref, acc_ref):
-    del tile_expert_ref
+def _ffn_kernel(tile_expert_ref, n_tiles_ref, layer_ref, x_ref, wg_ref, wu_ref,
+                wd_ref, y_ref, acc_ref):
+    del tile_expert_ref, layer_ref
     f32 = jnp.float32
     t, f = pl.program_id(0), pl.program_id(1)
     nn = (((1,), (0,)), ((), ()))
@@ -52,10 +60,12 @@ def _ffn_kernel(tile_expert_ref, n_tiles_ref, x_ref, wg_ref, wu_ref, wd_ref,
             acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
 
         x = x_ref[...]
-        gate = jax.lax.dot_general(x, wg_ref[0], nn, preferred_element_type=f32)
-        up = jax.lax.dot_general(x, wu_ref[0], nn, preferred_element_type=f32)
+        gate = jax.lax.dot_general(x, wg_ref[0, 0], nn,
+                                   preferred_element_type=f32)
+        up = jax.lax.dot_general(x, wu_ref[0, 0], nn,
+                                 preferred_element_type=f32)
         h = (jax.nn.silu(gate) * up).astype(x.dtype)
-        acc_ref[...] += jax.lax.dot_general(h, wd_ref[0], nn,
+        acc_ref[...] += jax.lax.dot_general(h, wd_ref[0, 0], nn,
                                             preferred_element_type=f32)
 
         @pl.when(f == pl.num_programs(1) - 1)
@@ -65,16 +75,20 @@ def _ffn_kernel(tile_expert_ref, n_tiles_ref, x_ref, wg_ref, wu_ref, wd_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 @part("experts")
-def grouped_swiglu(x, tile_expert, n_tiles, w_gate, w_up, w_down, *,
-                   interpret: bool = False):
+def grouped_swiglu(x, tile_expert, n_tiles, w_gate, w_up, w_down, layer=0,
+                   *, interpret: bool = False):
     """x [R, d] (rows sorted by expert, ``R`` whole tiles), tile_expert
-    [R / ROW_TILE] int32, n_tiles int32 scalar, w_gate, w_up [E, d, f],
-    w_down [E, f, d] → y [R, d]. Rows of tiles at and after ``n_tiles`` are
-    not written."""
+    [R / ROW_TILE] int32, n_tiles int32 scalar, w_gate, w_up [L, E, d, f],
+    w_down [L, E, f, d] (the layers' stack, read in place) and ``layer`` the
+    int32 scalar that says which of the ``L``; or one layer's [E, d, f],
+    [E, f, d] → y [R, d]. Rows of tiles at and after ``n_tiles`` are not
+    written."""
     R, d = x.shape
     fe = w_gate.shape[-1]
     tm, tf = min(ROW_TILE, R), min(WIDTH_TILE, fe)
     n_f = fe // tf
+    if w_gate.ndim == 3:                   # one layer: a stack of one
+        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
 
     def tile(t, n):
         return jnp.minimum(t, jnp.maximum(n[0] - 1, 0))
@@ -85,19 +99,19 @@ def grouped_swiglu(x, tile_expert, n_tiles, w_gate, w_up, w_down, *,
     return pl.pallas_call(
         _ffn_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(R // tm, n_f),
             in_specs=[
-                pl.BlockSpec((tm, d), lambda t, f, te, n: (tile(t, n), 0)),
-                pl.BlockSpec((1, d, tf), lambda t, f, te, n: (
-                    te[tile(t, n)], 0, width(t, f, n))),
-                pl.BlockSpec((1, d, tf), lambda t, f, te, n: (
-                    te[tile(t, n)], 0, width(t, f, n))),
-                pl.BlockSpec((1, tf, d), lambda t, f, te, n: (
-                    te[tile(t, n)], width(t, f, n), 0)),
+                pl.BlockSpec((tm, d), lambda t, f, te, n, ly: (tile(t, n), 0)),
+                pl.BlockSpec((1, 1, d, tf), lambda t, f, te, n, ly: (
+                    ly[0], te[tile(t, n)], 0, width(t, f, n))),
+                pl.BlockSpec((1, 1, d, tf), lambda t, f, te, n, ly: (
+                    ly[0], te[tile(t, n)], 0, width(t, f, n))),
+                pl.BlockSpec((1, 1, tf, d), lambda t, f, te, n, ly: (
+                    ly[0], te[tile(t, n)], width(t, f, n), 0)),
             ],
             out_specs=pl.BlockSpec((tm, d),
-                                   lambda t, f, te, n: (tile(t, n), 0)),
+                                   lambda t, f, te, n, ly: (tile(t, n), 0)),
             scratch_shapes=[pltpu.VMEM((tm, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((R, d), x.dtype),
@@ -108,4 +122,4 @@ def grouped_swiglu(x, tile_expert, n_tiles, w_gate, w_up, w_down, *,
         name="moe_grouped_swiglu",
         interpret=interpret,
     )(tile_expert.astype(jnp.int32), n_tiles.reshape(1).astype(jnp.int32),
-      x, w_gate, w_up, w_down)
+      jnp.asarray(layer, jnp.int32).reshape(1), x, w_gate, w_up, w_down)

@@ -795,7 +795,10 @@ def _plain_weights(leaf: Any, dtype) -> jax.Array:
 @part("experts")
 def _experts_ffn(p: Params, n: jax.Array, cfg: DecoderLMConfig, kernel_opts):
     """n [B, S, d] (normed) → (shared expert + the routed experts held here,
-    [B, S, d]; the (token, expert) pairs routed here)."""
+    [B, S, d]; the (token, expert) pairs routed here). ``p``: one layer's
+    leaves; where it has ``expert_layer`` (:func:`_read_in_place`), its
+    ``EXPERT_LEAVES`` are the whole group's stacks, already plain and in the
+    compute dtype, and that is the layer."""
     from agent_tpu.models import moe
 
     dtype = cfg.compute_dtype
@@ -814,10 +817,32 @@ def _experts_ffn(p: Params, n: jax.Array, cfg: DecoderLMConfig, kernel_opts):
     routed, pairs = moe.held_experts_ffn(
         flat.astype(dtype), experts, gates,
         *(_plain_weights(p[name], dtype) for name in EXPERT_LEAVES),
-        cfg.expert_first, **kernel_opts)
+        cfg.expert_first, layer=p.get("expert_layer"), **kernel_opts)
     shared = _swiglu(p, flat, ("ws_gate", "ws_up", "ws_down"), dtype)
     y = (shared.astype(jnp.float32) + routed).astype(dtype)
     return y.reshape(B, S, d), pairs.astype(jnp.float32)
+
+
+def _read_in_place(leaves: Params, dtype) -> Tuple[Params, Params]:
+    """A group's stacked leaves → (those the layer scan takes a layer's slice
+    of, those its step reads whole). The grouped expert matmul is a custom
+    call and wants a standalone operand: a layer's slice of the held experts'
+    leaves handed to it is a COPY of all of them, made once a layer a segment
+    to be read once, where the stack itself is the loop's invariant and the
+    kernel indexes it. So ``EXPERT_LEAVES`` stored as plain arrays in the
+    compute dtype stay out of the scan, and ``expert_layer`` (the layer's
+    number in the stack) is scanned in their place. Leaves that have to be
+    cast or dequantized (an int8 table is a ``dict``) are sliced first, as
+    every other leaf is: the product is a fresh array either way, and
+    casting the whole stack inside the loop would be the copy again."""
+    if not all(name in leaves and not isinstance(leaves[name], dict)
+               and leaves[name].dtype == dtype for name in EXPERT_LEAVES):
+        return leaves, {}
+    whole = {name: leaves[name] for name in EXPERT_LEAVES}
+    scanned = {k: v for k, v in leaves.items() if k not in whole}
+    scanned["expert_layer"] = jnp.arange(whole["we_gate"].shape[0],
+                                         dtype=jnp.int32)
+    return scanned, whole
 
 
 def _layer(p: Params, x: jax.Array, positions, state, cfg, kernel_opts,
@@ -862,13 +887,16 @@ def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
             mine = None if mixer_state is None else jax.tree_util.tree_map(
                 lambda a: a[first:first + n], mixer_state)
 
-        def step(carry, xs, ffn=ffn, mine=mine):
+        scanned, whole = _read_in_place(params[group], cfg.compute_dtype)
+
+        def step(carry, xs, ffn=ffn, mine=mine, whole=whole):
             x, pairs = carry
             p, st = xs if mine is not None else (xs, None)
-            x, st, more = _layer(p, x, positions, st, cfg, kernel_opts, ffn)
+            x, st, more = _layer({**p, **whole}, x, positions, st, cfg,
+                                 kernel_opts, ffn)
             return (x, pairs + more), st
 
-        xs = params[group] if mine is None else (params[group], mine)
+        xs = scanned if mine is None else (scanned, mine)
         # The loop's own work (a layer's leaves and state sliced out of the
         # stack, the new state written into it) is ``around``: copies the
         # model's stacking asks for; every part inside the body is its own.

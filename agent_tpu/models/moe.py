@@ -269,24 +269,31 @@ def _held_dense(x, local, gates, w_gate, w_up, w_down):
 @part("experts")
 def held_experts_ffn(x: jax.Array, experts: jax.Array, gates: jax.Array,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-                     first: int, *, pallas=None, interpret=None):
+                     first: int, *, layer=None, pallas=None, interpret=None):
     """x [S, d]; experts, gates [S, k] of :func:`route_sigmoid_grouped`;
     w_gate, w_up [Eh, d, f], w_down [Eh, f, d]: experts ``first .. first +
-    Eh - 1``. Returns ``(sum over a token's chosen experts HELD HERE of gate
-    x SwiGLU_e(x), float32 [S, d]; how many (token, expert) pairs that
-    were)``. On the chip: pairs sorted by expert, each expert's rows padded
-    to whole tiles, one grouped matmul over the tiles that hold rows
-    (``kernels/grouped_ffn.py``)."""
+    Eh - 1``; or, with ``layer`` (an int32 scalar), the layers' stacks
+    [L, Eh, d, f], [L, Eh, f, d] as the model holds them, of which that
+    layer's experts are used. Returns ``(sum over a token's chosen experts
+    HELD HERE of gate x SwiGLU_e(x), float32 [S, d]; how many (token,
+    expert) pairs that were)``. On the chip: pairs sorted by expert, each
+    expert's rows padded to whole tiles, one grouped matmul over the tiles
+    that hold rows (``kernels/grouped_ffn.py``), which reads a stack in
+    place: inside a layer scan, hand it the stack and the layer's number,
+    not the layer's slice (a copy of every held expert). Off the kernel the
+    layer's slice is taken here."""
     from agent_tpu.kernels import grouped_ffn
 
     S, k = experts.shape
-    n_held, d, fe = w_gate.shape
+    n_held, d, fe = w_gate.shape[-3:]
     local = experts - first
     held = (local >= 0) & (local < n_held)
     pairs = held.sum()
     if pallas is None:
         pallas = jax.default_backend() == "tpu"
     if not (pallas and grouped_ffn.pallas_supported(d, fe, x.dtype)):
+        if layer is not None:
+            w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
         return _held_dense(x, jnp.where(held, local, -1), gates, w_gate,
                            w_up, w_down), pairs
     from agent_tpu.kernels.flash_attention import resolve_interpret
@@ -310,7 +317,7 @@ def held_experts_ffn(x: jax.Array, experts: jax.Array, gates: jax.Array,
     x_rows = x[jnp.where(j < counts[e_row], token, 0)]
     y_rows = grouped_ffn.grouped_swiglu(
         x_rows, tile_expert, tile_end[-1], w_gate, w_up, w_down,
-        interpret=resolve_interpret(interpret))
+        0 if layer is None else layer, interpret=resolve_interpret(interpret))
     place = jnp.zeros((n_pairs,), jnp.int32).at[order].set(
         jnp.arange(n_pairs, dtype=jnp.int32))
     e_pair = jnp.minimum(of_pair, n_held - 1)

@@ -219,6 +219,112 @@ def test_grouped_matmul_equals_every_expert_on_every_token(monkeypatch, first):
                                atol=3e-2)
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_grouped_matmul_reads_a_layer_of_the_stack_in_place(monkeypatch,
+                                                            layer):
+    """The kernel on the layers' stack ``[3, E, ...]`` with the layer's number
+    (interpret mode, 64-row tiles, the last tile idle) is, bit for bit, the
+    kernel on that layer's own slice: one more block index, no arithmetic."""
+    monkeypatch.setattr(grouped_ffn, "ROW_TILE", 64)
+    L, E, d, fe, tm = 3, 4, 128, 512, 64
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    stacks = [(jax.random.normal(key, shape) / np.sqrt(shape[2])).astype(BF16)
+              for key, shape in zip(ks[1:], [(L, E, d, fe), (L, E, d, fe),
+                                             (L, E, fe, d)])]
+    tile_expert = jnp.array([0, 0, 1, 3, 3, 3, 3], jnp.int32)
+    x = jax.random.normal(ks[0], (tile_expert.size * tm, d), BF16)
+    n_tiles = jnp.int32(6)
+    whole = grouped_ffn.grouped_swiglu(x, tile_expert, n_tiles, *stacks,
+                                       jnp.int32(layer), interpret=True)
+    sliced = grouped_ffn.grouped_swiglu(
+        x, tile_expert, n_tiles, *(w[layer] for w in stacks), interpret=True)
+    written = 6 * tm
+    assert float(jnp.abs(sliced[:written].astype(jnp.float32)).max()) > 0.1
+    np.testing.assert_array_equal(np.asarray(whole[:written]),
+                                  np.asarray(sliced[:written]))
+    if layer:                 # and it is THAT layer's weights it read
+        first = grouped_ffn.grouped_swiglu(x, tile_expert, n_tiles, *stacks,
+                                           interpret=True)
+        assert not np.array_equal(np.asarray(whole[:written]),
+                                  np.asarray(first[:written]))
+
+
+# Two expert layers behind one dense: the layer scan has a second step to
+# get wrong. ``kernel``: widths the grouped matmul takes (the mixer's stay off
+# their kernels), so the stack reaches the ``pallas_call`` itself.
+TWO_EXPERT_LAYERS = {**TINY, "n_layers": 3}
+STACK_CASES = {
+    # name: (config, kernel options, how the expert leaves are stored,
+    #        whether the scan leaves them whole)
+    "plain_arithmetic": (TWO_EXPERT_LAYERS, {}, "as_drawn", True),
+    "kernel": ({**TWO_EXPERT_LAYERS, "dtype": "bfloat16", "d_model": 128,
+                "d_expert": 256}, {"pallas": True, "interpret": True},
+               "as_drawn", True),
+    "stored_in_another_dtype": ({**TWO_EXPERT_LAYERS, "dtype": "bfloat16"},
+                                {}, "float32", False),
+    "int8_table": ({**TWO_EXPERT_LAYERS, "dtype": "bfloat16"}, {}, "int8",
+                   False),
+}
+
+
+@pytest.mark.parametrize("case", STACK_CASES)
+def test_the_layer_scan_reads_the_expert_stack_in_place(monkeypatch, case):
+    """``forward_segment`` with the held experts' leaves left whole beside
+    the scan, against the same leaves scanned a layer's slice at a time (what
+    it did before, and still does for a leaf it has to cast or dequantize):
+    hidden states, state and ``pairs`` equal bit for bit. An int8 table and a
+    leaf stored in another dtype take the sliced path by themselves; the
+    int8 tree agrees with its own dequantized stacks read in place."""
+    from agent_tpu.models.quant import quantize_for_family
+
+    monkeypatch.setattr(grouped_ffn, "ROW_TILE", 64)
+    config, opts, stored, in_place = STACK_CASES[case]
+    cfg = decoder_lm.DecoderLMConfig(**config)
+    dtype = cfg.compute_dtype
+    params = decoder_lm.init_params(cfg, "sparse-stack")
+    if stored == "int8":
+        params = quantize_for_family("decoder_lm", params, "int8")
+    elif stored == "float32":
+        params["expert_layers"] = {
+            k: v.astype(jnp.float32) if k in decoder_lm.EXPERT_LEAVES else v
+            for k, v in params["expert_layers"].items()}
+    ids = np.random.default_rng(6).integers(0, 3000, (1, 256)).astype(np.int32)
+    layers_seen = []
+    held_experts_ffn = moe.held_experts_ffn
+
+    def spy(*args, layer=None, **kwargs):
+        layers_seen.append((layer is not None, args[3].ndim))
+        return held_experts_ffn(*args, layer=layer, **kwargs)
+
+    monkeypatch.setattr(moe, "held_experts_ffn", spy)
+
+    def run(tree):
+        del layers_seen[:]
+        hidden, state = jax.jit(lambda p, i, s: decoder_lm.forward_segment(
+            p, i, jnp.int32(0), s, cfg, **opts))(
+                tree, ids, decoder_lm.init_state(cfg, 1, 256))
+        return jax.tree_util.tree_leaves((hidden, state)), list(layers_seen)
+
+    got, seen = run(params)
+    assert seen == [(in_place, 4 if in_place else 3)], seen   # one scan body
+    with monkeypatch.context() as scanned:
+        scanned.setattr(decoder_lm, "_read_in_place",
+                        lambda leaves, dtype: (leaves, {}))
+        want, seen = run(params)
+    assert seen == [(False, 3)], seen
+    assert float(want[-1]) > 0, "no pair was routed to the held experts"
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if stored == "int8":
+        plain = {k: decoder_lm._plain_weights(v, dtype)
+                 if k in decoder_lm.EXPERT_LEAVES else v
+                 for k, v in params["expert_layers"].items()}
+        whole, seen = run({**params, "expert_layers": plain})
+        assert seen == [(True, 4)], seen
+        for a, b in zip(whole, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 # ---- the family against the reference -------------------------------------
 
 LONG = 4200             # 2,048 + 2,048 + 1,024 program tokens under BUCKETS

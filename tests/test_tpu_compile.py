@@ -347,6 +347,63 @@ def test_grouped_expert_matmul_compiles_for_v5e(v5e):
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
+def test_expert_layer_scan_reads_the_stack_in_place_on_v5e(v5e, monkeypatch):
+    """The FFN half of two scanned expert layers at the
+    ``deepseek-v3.2.score-32k`` cell's own shape (leaves ``[2, 16, 7168,
+    2048]``, a 4,096-token segment at the fixed worst case), split and stepped
+    as ``forward_segment`` does: one ``tpu_custom_call`` in the loop body, and
+    NO instruction whose result is one layer's experts (``bf16[16, 7168,
+    2048]`` or ``[16, 2048, 7168]``, with or without a leading 1): the
+    kernel's operands are the loop's own stacks. The same scan with the
+    leaves sliced a layer at a time holds the three copies (1.41 GB of
+    temporaries): the search finds what it is held to find."""
+    import re
+
+    from agent_tpu.models import decoder_lm
+    from benchmarks.harness import manifest
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)  # noqa: E731
+    model = manifest.load_config(manifest.load_manifest(),
+                                 "deepseek-v3.2")["model"]
+    cfg = decoder_lm.DecoderLMConfig(**{**model, "n_layers": 3})
+    leaves = jax.eval_shape(
+        lambda: decoder_lm.init_params(cfg, "m"))["expert_layers"]
+    assert leaves["we_gate"].shape == (2, 16, 7168, 2048)
+    leaves = {k: sd(leaves[k]) for k in decoder_lm.FFN_LEAVES["experts"] + (
+        "router_bias",)}
+    x = sd(jax.ShapeDtypeStruct((1, 4096, 7168), jnp.bfloat16))
+    a_layers_experts = re.compile(
+        r"= bf16\[(1,)?16,(7168,2048|2048,7168)\]\S* (?!parameter\()")
+
+    def compiled():
+        def ffn_half(leaves, x):
+            scanned, whole = decoder_lm._read_in_place(leaves,
+                                                       cfg.compute_dtype)
+
+            def step(x, p):
+                y, pairs = decoder_lm._experts_ffn(
+                    {**p, **whole}, x, cfg,
+                    {"pallas": True, "interpret": False})
+                return x + y, pairs
+
+            return jax.lax.scan(step, x, scanned)
+
+        done = jax.jit(ffn_half).lower(leaves, x).compile()
+        text = done.as_text()
+        assert text.count("tpu_custom_call") == 1 and " while(" in text
+        return (len(a_layers_experts.findall(text)),
+                done.memory_analysis().temp_size_in_bytes)
+
+    copies, temporaries = compiled()
+    assert copies == 0
+    monkeypatch.setattr(decoder_lm, "_read_in_place",
+                        lambda leaves, dtype: (leaves, {}))
+    sliced_copies, sliced_temporaries = compiled()
+    assert sliced_copies >= 3
+    assert sliced_temporaries - temporaries > 1.4e9
+
+
 def test_hybrid_ssm_kernels_compile_for_v5e(v5e):
     """The state-space scan and the causal grouped-query attention at the
     ``falcon-h1-34b.score-64k`` cell's own shape: a 4,096-token segment, 32
