@@ -10,6 +10,9 @@ never fetched or computed, one across it is masked from two iotas, and one
 below it takes no mask at all. The ``G`` query heads of a key-value head meet
 ONE loaded key and value tile: their rows are stacked, ``[G * tile, D]``, so a
 key tile is fetched once for five heads and every matmul has 2,560 rows.
+Where a key-value head has FEWER query heads (``dense_mla``: one, over keys
+expanded from latents) the query tile grows in their place
+(:func:`query_tile`): a loaded key tile again meets about that many rows.
 
 A streaming softmax with float32 scores, statistics and accumulator; the row
 statistics are kept lane-replicated and the weights are rounded to bf16
@@ -49,6 +52,30 @@ def pallas_supported(seq_len: int, cache_len: int, d_head: int, dtype) -> bool:
                 and seq_len % QUERY_TILE == 0 and cache_len % KEY_TILE == 0)
 
 
+# Rows a grid step's matmuls have at most. Five stacked heads of 512 queries
+# (2,560) stay as they are; ONE head a key-value head takes a whole
+# 4,096-token segment a step. The kernel alone at 32 heads of 128, 4,096
+# queries, 65,536 keys, a document's last segment / one in its middle / its
+# first, ms a call (my chip runs, PR 40; share of the exact causal half's
+# roofline): 512 queries a step 42.97 / 24.00 / 7.36 (50.3 / 43.6 / 9.5 %),
+# 1,024: 32.63 / 17.57 / 4.37, 2,048: 27.61 / 14.48 / 3.00, 4,096: 25.21 /
+# 13.11 / 2.44 (85.8 / 79.8 / 28.6 %): fewer steps and a key tile fetched
+# once a segment outweigh the half tile more that crosses the diagonal.
+MAX_STEP_ROWS = 4096
+
+
+def query_tile(groups: int, seq_len: int) -> int:
+    """Queries a grid step: ``QUERY_TILE``, doubled while the segment is
+    whole tiles of the double and the ``groups`` stacked heads of it stay
+    within ``MAX_STEP_ROWS`` (512 at five heads a key-value head, a whole
+    4,096-token segment at one). A power of two: the kernel's mask takes a row's query from its low
+    bits."""
+    tq = QUERY_TILE
+    while seq_len % (2 * tq) == 0 and groups * 2 * tq <= MAX_STEP_ROWS:
+        tq *= 2
+    return tq
+
+
 def visited_pairs(seq_len: int, pos0: int, query_tile: int = QUERY_TILE,
                   key_tile: int = KEY_TILE) -> int:
     """(query, key) pairs the kernel's grid computes for a segment of
@@ -62,12 +89,12 @@ def visited_pairs(seq_len: int, pos0: int, query_tile: int = QUERY_TILE,
 
 
 def _attention_jnp(q, k, v, pos0):
-    """q [Hkv, G, S, D], k, v [Hkv, Lk, D]: dense scores, float32, a block of
+    """q [Hkv, G, S, D], k [Hkv, Lk, D], v [Hkv, Lk, Dv]: dense scores, float32, a block of
     query rows at a time. Keys at and after ``pos0 + S`` are taken out of
     both products (a cache holds whatever was there)."""
     f32 = jnp.float32
     Hkv, G, S, D = q.shape
-    Lk = k.shape[1]
+    Lk, Dv = v.shape[1:]
     seen = (jnp.arange(Lk) < pos0 + S)[None, :, None]
     kf = jnp.where(seen, k.astype(f32), 0.0)
     vf = jnp.where(seen, v.astype(f32), 0.0)
@@ -84,7 +111,7 @@ def _attention_jnp(q, k, v, pos0):
         q.astype(f32).reshape(Hkv, G, S // rows, rows, D).transpose(
             2, 0, 1, 3, 4),
         (pos0 + jnp.arange(S)).reshape(S // rows, rows)))
-    return out.transpose(1, 2, 0, 3, 4).reshape(Hkv, G, S, D)
+    return out.transpose(1, 2, 0, 3, 4).reshape(Hkv, G, S, Dv)
 
 
 def _attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
@@ -148,7 +175,7 @@ def _attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 def _attention_call(q, k, v, pos0, *, interpret: bool):
     Hkv, G, S, D = q.shape
     Lk = k.shape[1]
-    tq, tk, bk = QUERY_TILE, KEY_TILE, KEY_BLOCK
+    tq, tk, bk = query_tile(G, S), KEY_TILE, KEY_BLOCK
 
     # Steps past a query tile's last key tile name that tile again: no copy.
     def at(j, i, pos):

@@ -103,7 +103,8 @@ def key_tile(cache_len: int) -> int:
 
 def _cache_fits(cache_len: int, dtype) -> bool:
     """bf16 operands and a cache of whole key tiles that the indexer can
-    hold: the three kernels run on one cache and one mask, or none does."""
+    hold: the indexer and the attention run on one cache and one mask, or
+    neither does (the expansion has its own rule, :func:`expand_supported`)."""
     return bool(jnp.dtype(dtype) == jnp.bfloat16
                 and cache_len % KEY_TILE == 0 and cache_len <= MAX_KERNEL_KEYS)
 
@@ -334,6 +335,37 @@ def _expand_call(latents, w, n_keys, *, dn: int, interpret: bool):
     )(n_keys.reshape(1).astype(jnp.int32), latents, w)
 
 
+def join_rotary_key(w: jax.Array, dn: int, dr: int) -> jax.Array:
+    """A head's ``W_UK | W_UV`` ``[H, kvr, dn + Dv]`` → ``[H, kvr + dr, dn +
+    dr + Dv]``: the weight under which the cache's WHOLE vector ``[c | kR]``
+    expands to the joined key ``[c W_UK | kR]`` and the value ``c W_UV``.
+    The rotary key passes through an identity block (exact: one product by
+    1, float32 accumulation), so the one key all heads share is broadcast to
+    every head inside the expansion's matmul and a head's key is ONE
+    lane-wide row where ``dn + dr`` is 128, which plain causal attention
+    takes; the price is ``(kvr + dr)(dn + dr + Dv)`` products a head where
+    ``kvr (dn + Dv)`` are needed."""
+    H, kvr, width = w.shape
+    top = jnp.concatenate([w[..., :dn], jnp.zeros((H, kvr, dr), w.dtype),
+                           w[..., dn:]], axis=-1)
+    carry = jnp.concatenate([jnp.zeros((dr, dn), w.dtype),
+                             jnp.eye(dr, dtype=w.dtype),
+                             jnp.zeros((dr, width - dn), w.dtype)], axis=-1)
+    return jnp.concatenate([top, jnp.broadcast_to(carry, (H, *carry.shape))],
+                           axis=1)
+
+
+def expand_supported(cache_len: int, latent_dim: int, n_heads: int, dn: int,
+                     dv: int, dtype) -> bool:
+    """Shapes the expansion kernel takes on the chip: bf16 operands, a cache
+    of whole key tiles (of ANY length: a step holds one tile, so the
+    indexer's ``MAX_KERNEL_KEYS`` is not its bound), lane-wide keys and
+    values, whole head groups, latents in whole sublane groups."""
+    return bool(jnp.dtype(dtype) == jnp.bfloat16 and cache_len % KEY_TILE == 0
+                and dn == _LANES and dv == _LANES and latent_dim % 64 == 0
+                and n_heads % EXPAND_HEAD_GROUP == 0)
+
+
 @part("project")
 def expand_latents(
     latents: jax.Array,    # [Lk, kvr]  the document's normed latents
@@ -348,12 +380,12 @@ def expand_latents(
     ``n_keys`` latents, head-major as the attention reads them (as one plain
     matmul XLA relaid the result out three times over: PERF.md section 5). On
     the chip the rest is NOT written (and never read); elsewhere every key
-    is expanded."""
+    is expanded. With :func:`join_rotary_key`'s weight the latents are the
+    cache's whole vectors and the keys come out joined with the rotary key."""
     Lk, kvr = latents.shape
     H, _, width = w.shape
-    if (_on_chip(pallas) and _cache_fits(Lk, latents.dtype)
-            and dn == _LANES and width - dn == _LANES and kvr % _LANES == 0
-            and H % EXPAND_HEAD_GROUP == 0):
+    if _on_chip(pallas) and expand_supported(Lk, kvr, H, dn, width - dn,
+                                             latents.dtype):
         from agent_tpu.kernels.flash_attention import resolve_interpret
 
         return _expand_call(latents, w, n_keys, dn=dn,

@@ -28,7 +28,16 @@ one to the next (:func:`forward_segment`):
   ``n_kv_heads x d_head`` numbers a token, allocated as ``sparse_mla``'s
   is) and a fixed-size float32 scan state with the convolution's last
   ``ssm_d_conv - 1`` inputs. The block's muP multipliers (``*_multiplier``)
-  are applied where the published forward pass applies them.
+  are applied where the published forward pass applies them;
+- ``dense_mla`` (latent attention over EVERY causal key: ``sparse_mla``'s
+  projections, norms and rotary positions through the one
+  :func:`_latent_projections`, no indexer and no selection; a query scaled
+  by its own position, ``query_scale_beta``) carries a cache of latents
+  ONLY, ``kv_lora_rank + qk_rope_head_dim`` numbers a token a layer; a
+  segment expands the latents it can see into per-head joined keys ``[c W_UK
+  | kR]`` and values for one layer at a time
+  (``kernels/sparse_mla.py: expand_latents``) and attends them with
+  ``kernels/causal_attention.py`` at one query head a key head.
 
 Layers are stacked by group (the leading dense layers, then the expert
 layers) and each group is scanned; embedding and output head are untied. An
@@ -93,6 +102,7 @@ MIXER_LEAVES = {
     "power_retention": ("wq", "wk", "wv", "wo", "wg"),
     "sparse_mla": ("wo", "w_dq", "w_uq", "w_dkv", "w_ukv", "wi_q", "wi_k",
                    "wi_w"),
+    "dense_mla": ("wo", "w_dq", "w_uq", "w_dkv", "w_ukv"),
     "hybrid_ssm": ("wq", "wk", "wv", "wo", "w_ssm_in", "w_ssm_out", "conv_w",
                    "conv_b"),
 }
@@ -147,6 +157,9 @@ class DecoderLMConfig:
     rope_beta_fast: float = 32.0
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
+    # ``dense_mla``: the query at position t is scaled by ``1 + beta x ln(1 +
+    # floor(t / rope_original_max_len))`` (0: no such scale).
+    query_scale_beta: float = 0.0
     # Expert layers (``n_experts`` 0: every layer's FFN is the dense SwiGLU).
     n_dense_layers: int = 1
     n_experts: int = 0
@@ -158,6 +171,9 @@ class DecoderLMConfig:
     d_expert: int = 64
     n_shared_experts: int = 1
     routed_scale: float = 2.5
+    # How the router scores: ``sigmoid`` (group-limited, a choice bias) or
+    # ``softmax`` (over all experts, no groups): ``models/moe.py``.
+    scoring_func: str = "sigmoid"
     # hybrid_ssm (``n_heads`` query over ``n_kv_heads`` key-value heads of
     # ``d_head``, and beside them a Mamba-2 scan: ``ssm_n_heads`` heads of
     # ``ssm_d_head`` in ``ssm_n_groups`` groups that share B and C):
@@ -222,6 +238,13 @@ class DecoderLMConfig:
             ("expert_layers", "experts", nd, self.n_layers - nd)) if g[3])
 
 
+def _holds(cfg: DecoderLMConfig, leaf: str) -> bool:
+    """Whether the config's mixer holds ``leaf``: ``w_dkv`` says its
+    queries, keys and values come through latents, ``wi_k`` that it has an
+    indexer. What a mixer IS is asked of its leaves, not of its name."""
+    return leaf in MIXER_LEAVES[cfg.mixer]
+
+
 def validate(cfg: DecoderLMConfig) -> None:
     """ValueError (a caller's error) on a config no program can run."""
     if cfg.mixer not in MIXERS:
@@ -233,11 +256,15 @@ def validate(cfg: DecoderLMConfig) -> None:
         if cfg.d_head % 2:
             raise ValueError("d_head must be even (rotary pairs)")
     positive = ["vocab_size", "d_model", "d_ff", "n_layers", "max_len"]
-    if cfg.mixer == "sparse_mla":
+    if _holds(cfg, "w_dkv"):
         positive += ["q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-                     "v_head_dim", "index_n_heads", "index_topk"]
+                     "v_head_dim"]
         if cfg.qk_rope_head_dim % 2 or cfg.qk_rope_head_dim <= 0:
             raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
+        if cfg.query_scale_beta < 0:
+            raise ValueError("query_scale_beta must not be negative")
+    if _holds(cfg, "wi_k"):
+        positive += ["index_n_heads", "index_topk"]
         if cfg.index_head_dim < cfg.qk_rope_head_dim:
             raise ValueError("index_head_dim must hold the rotary part")
     if cfg.mixer == "hybrid_ssm":
@@ -249,6 +276,11 @@ def validate(cfg: DecoderLMConfig) -> None:
             raise ValueError("ssm_n_heads must be whole ssm_n_groups")
     if cfg.n_experts:
         positive += ["n_experts_held", "d_expert", "n_experts_per_token"]
+        if cfg.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError("scoring_func must be 'sigmoid' or 'softmax', "
+                             f"got {cfg.scoring_func!r}")
+        if cfg.scoring_func == "softmax" and cfg.n_expert_groups != 1:
+            raise ValueError("a softmax router has no groups")
         if not 0 <= cfg.n_dense_layers <= cfg.n_layers:
             raise ValueError("n_dense_layers must lie in 0 .. n_layers")
         if (cfg.expert_first < 0
@@ -273,7 +305,7 @@ def _leaf_shapes(cfg: DecoderLMConfig) -> Dict[str, Tuple[Tuple[int, ...], int]]
     """leaf → (shape of one layer's leaf (of one expert's; or the whole
     leaf), fan_in)."""
     d, f = cfg.d_model, cfg.d_ff
-    if cfg.mixer == "sparse_mla":
+    if _holds(cfg, "w_dkv"):
         h, qr, kvr = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
         dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         hi, di = cfg.index_n_heads, cfg.index_head_dim
@@ -370,11 +402,12 @@ def _layer_constants(cfg: DecoderLMConfig, ffn: str) -> Dict[str, Tuple]:
     """leaf → (value, shape of one layer's leaf): what is not drawn."""
     d = cfg.d_model
     out = {"ln1": (1.0, (d,)), "ln2": (1.0, (d,))}
-    if cfg.mixer == "sparse_mla":
+    if _holds(cfg, "w_dkv"):
         out.update(q_norm=(1.0, (cfg.q_lora_rank,)),
-                   kv_norm=(1.0, (cfg.kv_lora_rank,)),
-                   ik_norm=(1.0, (cfg.index_head_dim,)),
-                   ik_bias=(0.0, (cfg.index_head_dim,)))
+                   kv_norm=(1.0, (cfg.kv_lora_rank,)))
+        if _holds(cfg, "wi_k"):
+            out.update(ik_norm=(1.0, (cfg.index_head_dim,)),
+                       ik_bias=(0.0, (cfg.index_head_dim,)))
     elif cfg.mixer == "hybrid_ssm":
         h = cfg.ssm_n_heads
         out.update(
@@ -384,7 +417,7 @@ def _layer_constants(cfg: DecoderLMConfig, ffn: str) -> Dict[str, Tuple]:
     else:
         out.update(bg=(gate_bias(cfg.n_kv_heads), (cfg.n_kv_heads,)),
                    q_norm=(1.0, (cfg.d_head,)), k_norm=(1.0, (cfg.d_head,)))
-    if ffn == "experts":
+    if ffn == "experts" and cfg.scoring_func == "sigmoid":
         out["router_bias"] = (0.0, (cfg.n_experts,))
     return out
 
@@ -574,6 +607,56 @@ def rope_pairs(x: jax.Array, positions: jax.Array, inv_freq: np.ndarray,
                            axis=-1).astype(x.dtype)
 
 
+def query_position_scale(cfg: DecoderLMConfig, positions: jax.Array):
+    """``a(t) = 1 + query_scale_beta x ln(1 + floor(t / rope_original_max_len))``
+    for every position, float32 [S, 1]; ``None`` where the config has no such
+    scale (the program is then the one without it)."""
+    if not cfg.query_scale_beta:
+        return None
+    steps = jnp.floor_divide(positions, cfg.rope_original_max_len)
+    return (1.0 + cfg.query_scale_beta * jnp.log1p(
+        steps.astype(jnp.float32)))[:, None]
+
+
+def _latent_projections(p: Params, h: jax.Array, positions: jax.Array,
+                        cfg: DecoderLMConfig):
+    """What every latent mixer computes of a segment's normed ``h [S, d]``:
+    ``(cq, q, q_rope, latent)``: the normed query latent ``[S, q_lora_rank]``
+    in the compute dtype; the per-head queries ``[S, H, nope + rope]`` as
+    projected (the rotary part NOT yet rotated) and their rotated rotary
+    part ``[S, H, rope]``; what the cache holds of a token, ``[S,
+    kv_lora_rank + rope]`` = ``[RMSNorm(c) | RoPE(kR)]``. The softmax scale,
+    and the position's own scale where the config has one, go into the query
+    latent before it is rounded to the compute dtype: a multiply less on
+    every score of the attention."""
+    dtype, eps = cfg.compute_dtype, cfg.rms_norm_eps
+    S = h.shape[0]
+    nh, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    kvr = cfg.kv_lora_rank
+    inv = yarn_inv_freq(cfg)
+    cq = rms_norm(_project(p["w_dq"], h, dtype).astype(jnp.float32),
+                  p["q_norm"], eps)
+    scale, by_position = softmax_scale(cfg), query_position_scale(cfg, positions)
+    if by_position is not None:
+        scale = scale * by_position
+    q = _project(p["w_uq"], (cq * scale).astype(dtype),
+                 dtype).reshape(S, nh, dn + dr)
+    cq = cq.astype(dtype)
+    q_rope = rope_pairs(q[..., dn:], positions, inv, interleaved=True)
+    latent = _project(p["w_dkv"], h, dtype)
+    latent = jnp.concatenate([
+        rms_norm(latent[:, :kvr], p["kv_norm"], eps),
+        rope_pairs(latent[:, kvr:], positions, inv, interleaved=True)], -1)
+    return cq, q, q_rope, latent
+
+
+def _head_major_ukv(p: Params, cfg: DecoderLMConfig) -> jax.Array:
+    """``w_ukv`` as the expansion takes it: ``[H, kv_lora_rank, nope + v]``."""
+    return _plain_weights(p["w_ukv"], cfg.compute_dtype).reshape(
+        cfg.kv_lora_rank, cfg.n_heads,
+        cfg.qk_nope_head_dim + cfg.v_head_dim).transpose(1, 0, 2)
+
+
 @part("around")
 def _sparse_mla_mixer(p: Params, h: jax.Array, positions: jax.Array,
                       state, cfg: DecoderLMConfig, kernel_opts):
@@ -586,26 +669,13 @@ def _sparse_mla_mixer(p: Params, h: jax.Array, positions: jax.Array,
     if h.shape[0] != 1:
         raise ValueError("sparse_mla runs one document a program")
     dtype = cfg.compute_dtype
-    eps = cfg.rms_norm_eps
     h = h[0]
     S = h.shape[0]
     nh, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     kvr, hi, di = cfg.kv_lora_rank, cfg.index_n_heads, cfg.index_head_dim
     inv = yarn_inv_freq(cfg)
     pos0 = positions[0]
-
-    cq = rms_norm(_project(p["w_dq"], h, dtype).astype(jnp.float32),
-                  p["q_norm"], eps)
-    # The softmax scale goes into the query latent before it is rounded to
-    # the compute dtype: a multiply less on every score of the attention.
-    q = _project(p["w_uq"], (cq * softmax_scale(cfg)).astype(dtype),
-               dtype).reshape(S, nh, dn + dr)
-    cq = cq.astype(dtype)
-    q_rope = rope_pairs(q[..., dn:], positions, inv, interleaved=True)
-    latent = _project(p["w_dkv"], h, dtype)
-    latent = jnp.concatenate([
-        rms_norm(latent[:, :kvr], p["kv_norm"], eps),
-        rope_pairs(latent[:, kvr:], positions, inv, interleaved=True)], -1)
+    cq, q, q_rope, latent = _latent_projections(p, h, positions, cfg)
 
     # The indexer: rotary part FIRST, half-split pairs; a LayerNorm on keys.
     qi = _project(p["wi_q"], cq, dtype).reshape(S, hi, di)
@@ -624,8 +694,7 @@ def _sparse_mla_mixer(p: Params, h: jax.Array, positions: jax.Array,
     kic = jax.lax.dynamic_update_slice(state["ki"][0], ki, (pos0, 0))
     mask = sparse_mla.index_select(qi, wi, kic, pos0, cfg.index_topk,
                                    **kernel_opts)
-    w_ukv = _plain_weights(p["w_ukv"], dtype).reshape(
-        kvr, nh, dn + cfg.v_head_dim).transpose(1, 0, 2)
+    w_ukv = _head_major_ukv(p, cfg)
     k_nope, v = sparse_mla.expand_latents(kv[:, :kvr], w_ukv, pos0 + S, dn,
                                           **kernel_opts)
     o = sparse_mla.masked_attention(
@@ -635,6 +704,39 @@ def _sparse_mla_mixer(p: Params, h: jax.Array, positions: jax.Array,
     return _project(p["wo"], o, dtype), {"kv": kv[None], "ki": kic[None]}
 
 
+@part("around")
+def _dense_mla_mixer(p: Params, h: jax.Array, positions: jax.Array,
+                     state, cfg: DecoderLMConfig, kernel_opts):
+    """h [1, S, d] (normed) → (what enters the residual [1, S, d], the
+    layer's cache with the segment written at its positions). ``state``:
+    ``{"kv": [1, Lk, kv_lora_rank + rope]}``: latents and nothing expanded.
+    The latents the segment can see are expanded HERE, for this layer alone,
+    into head-major joined keys ``[c W_UK | kR]`` and values (the rotary key
+    reaches every head through an identity block of the expansion's weight:
+    ``sparse_mla.join_rotary_key``), and every causal key is attended: plain
+    causal attention at one query head a key head."""
+    from agent_tpu.kernels import causal_attention, sparse_mla
+
+    if h.shape[0] != 1:
+        raise ValueError("dense_mla runs one document a program")
+    dtype = cfg.compute_dtype
+    h = h[0]
+    S = h.shape[0]
+    nh, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    pos0 = positions[0]
+    _, q, q_rope, latent = _latent_projections(p, h, positions, cfg)
+    q = jnp.concatenate([q[..., :dn], q_rope], -1)               # [S, H, Dk]
+
+    kv = jax.lax.dynamic_update_slice(state["kv"][0], latent, (pos0, 0))
+    k, v = sparse_mla.expand_latents(
+        kv, sparse_mla.join_rotary_key(_head_major_ukv(p, cfg), dn, dr),
+        pos0 + S, dn + dr, **kernel_opts)
+    o = causal_attention.causal_attention(
+        q.transpose(1, 0, 2)[:, None], k, v, pos0, **kernel_opts)
+    o = o[:, 0].transpose(1, 0, 2).reshape(1, S, nh * cfg.v_head_dim)
+    return _project(p["wo"], o, dtype), {"kv": kv[None]}
+
+
 def _sparse_mla_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
     """The empty cache of ``batch`` documents of ``cache_len`` (padded)
     tokens: every layer's latents and index keys, in the stored dtype."""
@@ -642,6 +744,12 @@ def _sparse_mla_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
     return {"kv": jnp.zeros((n, batch, cache_len, cfg.kv_lora_rank
                              + cfg.qk_rope_head_dim), dtype),
             "ki": jnp.zeros((n, batch, cache_len, cfg.index_head_dim), dtype)}
+
+
+def _dense_mla_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
+    """The empty cache: every layer's latents, and nothing else."""
+    return {"kv": jnp.zeros((cfg.n_layers, batch, cache_len, cfg.kv_lora_rank
+                             + cfg.qk_rope_head_dim), cfg.compute_dtype)}
 
 
 @part("around")
@@ -746,12 +854,14 @@ def _hybrid_ssm_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
 # mixer; the out-projection is the mixer's (one has two).
 MIXERS: Dict[str, Callable] = {"power_retention": _power_retention_mixer,
                                "sparse_mla": _sparse_mla_mixer,
-                               "hybrid_ssm": _hybrid_ssm_mixer}
+                               "hybrid_ssm": _hybrid_ssm_mixer,
+                               "dense_mla": _dense_mla_mixer}
 # mixer name → fn(cfg, batch, cache_len) → the state before a document's
 # first segment, for the mixers whose state is allocated (a cache); the
 # others start from ``None``.
 MIXER_STATES: Dict[str, Callable] = {"sparse_mla": _sparse_mla_state,
-                                     "hybrid_ssm": _hybrid_ssm_state}
+                                     "hybrid_ssm": _hybrid_ssm_state,
+                                     "dense_mla": _dense_mla_state}
 
 
 def starts_from_nothing(cfg: DecoderLMConfig) -> bool:
@@ -810,10 +920,14 @@ def _experts_ffn(p: Params, n: jax.Array, cfg: DecoderLMConfig, kernel_opts):
     else:
         logits = jnp.dot(flat.astype(dtype), p["w_router"].astype(dtype),
                          preferred_element_type=jnp.float32)
-    experts, gates = moe.route_sigmoid_grouped(
-        logits, p["router_bias"], n_groups=cfg.n_expert_groups,
-        groups_kept=cfg.n_groups_per_token, top_k=cfg.n_experts_per_token,
-        scale=cfg.routed_scale)
+    if cfg.scoring_func == "softmax":
+        experts, gates = moe.route_softmax(
+            logits, top_k=cfg.n_experts_per_token, scale=cfg.routed_scale)
+    else:
+        experts, gates = moe.route_sigmoid_grouped(
+            logits, p["router_bias"], n_groups=cfg.n_expert_groups,
+            groups_kept=cfg.n_groups_per_token, top_k=cfg.n_experts_per_token,
+            scale=cfg.routed_scale)
     routed, pairs = moe.held_experts_ffn(
         flat.astype(dtype), experts, gates,
         *(_plain_weights(p[name], dtype) for name in EXPERT_LEAVES),
@@ -915,41 +1029,63 @@ def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
                     else mixer_state)
 
 
+def _retention_flops(cfg: DecoderLMConfig, t: float, pos0: int):
+    from agent_tpu.kernels.power_retention import retention_chunk
+
+    d, dh = float(cfg.d_model), cfg.d_head
+    chunk = retention_chunk(int(t))
+    hq, hkv = float(cfg.n_heads * dh), float(cfg.n_kv_heads * dh)
+    state = 2.0 * (dh // 2 + 1) * dh * dh
+    return (2.0 * d * (2.0 * hq + 2.0 * hkv + cfg.n_kv_heads),
+            cfg.n_heads * (4.0 * chunk * dh + state) + cfg.n_kv_heads * state)
+
+
+def _latent_flops(cfg: DecoderLMConfig, t: float, pos0: int):
+    d = float(cfg.d_model)
+    h, qr, kvr = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    proj = 2.0 * (d * qr + qr * h * (dn + dr) + d * (kvr + dr) + h * dv * d)
+    # Every causal pair's score and value product on expanded keys, and the
+    # expansion of the keys seen so far; under a selection, the index
+    # scores of every causal pair and the indexer's projections besides.
+    seen = pos0 + t / 2.0
+    mixer = 2.0 * h * (dn + dr + dv) * seen + (
+        2.0 * kvr * h * (dn + dv) * (pos0 + t) / t)
+    if _holds(cfg, "wi_k"):
+        hi, di = cfg.index_n_heads, cfg.index_head_dim
+        proj += 2.0 * (qr * hi * di + d * di + d * hi)
+        mixer += 2.0 * hi * di * seen
+    return proj, mixer
+
+
+def _hybrid_flops(cfg: DecoderLMConfig, t: float, pos0: int):
+    d = float(cfg.d_model)
+    dh, hq, hkv = cfg.d_head, cfg.n_heads, cfg.n_kv_heads
+    H, P, N = cfg.ssm_n_heads, cfg.ssm_d_head, cfg.ssm_d_state
+    c, d_ssm = cfg.ssm_chunk, cfg.ssm_n_heads * cfg.ssm_d_head
+    proj = 2.0 * d * (2 * hq * dh + 2 * hkv * dh + cfg.ssm_in_dim) + (
+        2.0 * d_ssm * d)
+    # Every causal pair's score and value product; a chunk's block, the
+    # state's read and update a head, C B^T once a group.
+    return proj, 4.0 * hq * dh * (pos0 + t / 2.0) + H * (
+        2.0 * c * P + 4.0 * N * P) + 2.0 * cfg.ssm_n_groups * c * N
+
+
+# mixer name → fn(cfg, tokens, pos0) → (projections', mixer's own) FLOPs a
+# token a layer of a segment of ``tokens`` that starts at ``pos0``.
+MIXER_FLOPS: Dict[str, Callable] = {"power_retention": _retention_flops,
+                                    "sparse_mla": _latent_flops,
+                                    "hybrid_ssm": _hybrid_flops,
+                                    "dense_mla": _latent_flops}
+
+
 def segment_flops(cfg: DecoderLMConfig, n_tokens: int, pos0: int) -> float:
     """Forward FLOPs the program does for one dispatched segment of
     ``n_tokens`` tokens that starts at ``pos0`` (matmul terms, the ``device_
     mfu{op}`` numerator): projections and feed-forwards per layer, the
     mixer's own count, the untied head."""
     d, t = float(cfg.d_model), float(n_tokens)
-    if cfg.mixer == "sparse_mla":
-        h, qr, kvr = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
-        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        hi, di = cfg.index_n_heads, cfg.index_head_dim
-        proj = 2.0 * (d * qr + qr * h * (dn + dr) + d * (kvr + dr)
-                      + h * dv * d + qr * hi * di + d * di + d * hi)
-        # Every causal pair: index scores, then the dense masked attention
-        # on expanded keys; the expansion of the keys seen so far.
-        seen = pos0 + t / 2.0
-        mixer = (2.0 * hi * di + 2.0 * h * (dn + dr + dv)) * seen + (
-            2.0 * kvr * h * (dn + dv) * (pos0 + t) / t)
-    elif cfg.mixer == "hybrid_ssm":
-        dh, hq, hkv = cfg.d_head, cfg.n_heads, cfg.n_kv_heads
-        H, P, N = cfg.ssm_n_heads, cfg.ssm_d_head, cfg.ssm_d_state
-        c, d_ssm = cfg.ssm_chunk, cfg.ssm_n_heads * cfg.ssm_d_head
-        proj = 2.0 * d * (2 * hq * dh + 2 * hkv * dh + cfg.ssm_in_dim) + (
-            2.0 * d_ssm * d)
-        # Every causal pair's score and value product; a chunk's block, the
-        # state's read and update a head, C B^T once a group.
-        mixer = 4.0 * hq * dh * (pos0 + t / 2.0) + H * (
-            2.0 * c * P + 4.0 * N * P) + 2.0 * cfg.ssm_n_groups * c * N
-    else:
-        from agent_tpu.kernels.power_retention import retention_chunk
-
-        chunk, dh = retention_chunk(n_tokens), cfg.d_head
-        hq, hkv = float(cfg.n_heads * dh), float(cfg.n_kv_heads * dh)
-        proj = 2.0 * d * (2.0 * hq + 2.0 * hkv + cfg.n_kv_heads)
-        state = 2.0 * (dh // 2 + 1) * dh * dh
-        mixer = cfg.n_heads * (4.0 * chunk * dh + state) + cfg.n_kv_heads * state
+    proj, mixer = MIXER_FLOPS[cfg.mixer](cfg, t, pos0)
     dense = 6.0 * d * cfg.d_ff
     experts = 2.0 * d * cfg.n_experts + 6.0 * d * cfg.d_expert * (
         cfg.n_shared_experts + cfg.n_experts_per_token

@@ -220,10 +220,12 @@ def init_moe_block(key: jax.Array, cfg: MoeConfig) -> Params:
     }
 
 
-# ---- a held share of a group-limited, sigmoid-scored expert layer ---------
+# ---- a held share of a routed expert layer --------------------------------
 #
 # The decoder language-model family's expert layer (``models/decoder_lm.py``):
-# the router scores ALL ``n_experts`` and picks ``top_k`` of them a token; the
+# the router (sigmoid scores and group-limited, or a plain softmax: the
+# config's ``scoring_func`` says which) scores ALL ``n_experts`` and picks
+# ``top_k`` of them a token; the
 # chip holds ``w_gate.shape[0]`` of them, ids ``first ..``, and computes the
 # part of the result its own experts give. No capacity: no token is dropped.
 # What the experts held elsewhere would add is left out here, as on one chip
@@ -252,6 +254,18 @@ def route_sigmoid_grouped(logits: jax.Array, bias: jax.Array, *,
     return experts, scale * picked / picked.sum(axis=-1, keepdims=True)
 
 
+def route_softmax(logits: jax.Array, *, top_k: int, scale: float):
+    """logits [S, E] float32 → ``(experts [S, top_k] int32, gates [S, top_k]
+    float32)``: scores are ``softmax(logits)`` over ALL experts (no groups,
+    no bias), the ``top_k`` largest are chosen (ties to the lower index:
+    ``lax.top_k`` keeps the order of equal entries), and the gates are the
+    chosen scores over their sum, times ``scale``."""
+    score = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    picked, experts = jax.lax.top_k(score, top_k)
+    return experts.astype(jnp.int32), scale * picked / picked.sum(
+        axis=-1, keepdims=True)
+
+
 def _held_dense(x, local, gates, w_gate, w_up, w_down):
     """Every held expert on every token, weighted by the token's gate for
     it (0 where it did not choose it): the tests' sizes and off the chip."""
@@ -270,7 +284,7 @@ def _held_dense(x, local, gates, w_gate, w_up, w_down):
 def held_experts_ffn(x: jax.Array, experts: jax.Array, gates: jax.Array,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                      first: int, *, layer=None, pallas=None, interpret=None):
-    """x [S, d]; experts, gates [S, k] of :func:`route_sigmoid_grouped`;
+    """x [S, d]; experts, gates [S, k] of a router above;
     w_gate, w_up [Eh, d, f], w_down [Eh, f, d]: experts ``first .. first +
     Eh - 1``; or, with ``layer`` (an int32 scalar), the layers' stacks
     [L, Eh, d, f], [L, Eh, f, d] as the model holds them, of which that

@@ -692,6 +692,22 @@ def record_causal_attention_pairs(kind: str, pairs: int) -> None:
                float(pairs), kind=kind)
 
 
+def record_latent_keys(kind: str, keys: int) -> None:
+    """Latents of a DISPATCHED shard under a mixer that caches latents and
+    expands them to keys and values inside its programs, a layer: the real
+    tokens whose latents the cache holds (``cached``) and the latents its
+    segment programs expand (``expanded``: a cached latent once for its own
+    and every later segment). Counted by the op from the documents' lengths
+    and the segments they ran as."""
+    if keys > 0:
+        _count("latent_keys_expanded_total",
+               "Cached latents of the tokens dispatched to a latent-"
+               "attention mixer, a layer: held by the cache (cached) and "
+               "expanded to keys and values by its segment programs "
+               "(expanded)",
+               float(keys), kind=kind)
+
+
 def record_moe_routing(pairs: float, tokens: int) -> None:
     """What a FETCHED shard's expert layers routed: (token, expert) pairs
     that went to the experts held here (counted on the device, fetched with

@@ -34,7 +34,11 @@ the host. What that state is depends on the mixer (``models/decoder_lm.py``):
   CACHE that grows with position (allocated, donated and keyed as
   ``sparse_mla``'s is) and a FIXED-SIZE float32 scan state with the
   convolution's last three inputs; the empty ones are zeros, so every
-  segment runs the one program that takes a state. Where the model has
+  segment runs the one program that takes a state;
+- ``dense_mla``: a CACHE of latents only (``sparse_mla``'s without the index
+  keys), allocated, donated and keyed the same way; keys and values are
+  expanded from it inside a segment program, a layer at a time, and never
+  leave it. Where the model has
   expert layers the state also carries the count of (token, expert) pairs
   routed to the experts held here; it comes back with the block sums in the
   one fetch.
@@ -286,10 +290,34 @@ def _record_hybrid(state: Dict[str, Any]) -> None:
     obs_trace.record_causal_attention_pairs("computed", computed)
 
 
+def _record_dense_latent(state: Dict[str, Any]) -> None:
+    """A query head a layer, the causal pairs a shard's real tokens need
+    beside those in the key tiles the attention kernel's grid visits at ONE
+    query head a key head; and, a layer, the cached latents its segment
+    programs expand (every segment expands all it can see: ``pos0 +
+    bucket``) beside the real tokens whose latents the cache holds."""
+    from agent_tpu.kernels.causal_attention import query_tile, visited_pairs
+
+    cached = expanded = causal = computed = 0
+    for doc in state["docs"]:
+        n = doc["n_tokens"]
+        cached += n
+        causal += n * (n + 1) // 2
+        for ids, _, _, pos0 in doc["segments"]:
+            bucket = ids.shape[1]
+            expanded += pos0 + bucket
+            computed += visited_pairs(bucket, pos0, query_tile(1, bucket))
+    obs_trace.record_causal_attention_pairs("causal", causal)
+    obs_trace.record_causal_attention_pairs("computed", computed)
+    obs_trace.record_latent_keys("expanded", expanded)
+    obs_trace.record_latent_keys("cached", cached)
+
+
 # mixer → what the op counts of a shard at dispatch, from its lengths.
 _MIXER_COUNTERS = {"power_retention": _record_retention,
                    "sparse_mla": _record_sparse_keys,
-                   "hybrid_ssm": _record_hybrid}
+                   "hybrid_ssm": _record_hybrid,
+                   "dense_mla": _record_dense_latent}
 
 
 def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, Any]:

@@ -450,9 +450,12 @@ def test_the_new_entries_are_appended():
     assert decoder_lm.LEAVES[-4:] == ("w_ssm_in", "w_ssm_out", "conv_w",
                                       "conv_b")
     assert decoder_lm.LEAVES[:24] == ref.LEAVES[:24]
-    assert set(decoder_lm.MIXERS) == set(decoder_lm.MIXER_LEAVES) == {
-        "power_retention", "sparse_mla", "hybrid_ssm"}
-    assert set(decoder_lm.MIXER_STATES) == {"sparse_mla", "hybrid_ssm"}
+    # A later mixer (PR 40's ``dense_mla``) joins every table; none leaves.
+    assert set(decoder_lm.MIXERS) == set(decoder_lm.MIXER_LEAVES) == set(
+        decoder_lm.MIXER_FLOPS) >= {"power_retention", "sparse_mla",
+                                    "hybrid_ssm"}
+    assert set(decoder_lm.MIXER_STATES) == set(decoder_lm.MIXERS) - {
+        "power_retention"}
     assert set(map_score_lm._MIXER_COUNTERS) == set(decoder_lm.MIXERS)
     assert decoder_lm.LINEAR_LEAVES[-2:] == ("w_ssm_in", "w_ssm_out")
     assert not decoder_lm.starts_from_nothing(decoder_lm.DecoderLMConfig(**TINY))

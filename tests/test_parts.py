@@ -38,7 +38,7 @@ MULTIPLIERS = {
     "attention_in_multiplier": 1.0, "attention_out_multiplier": 0.5,
     "key_multiplier": 0.5, "ssm_in_multiplier": 0.5,
     "ssm_out_multiplier": 0.25}
-# family → (op, payload): the tiny configs of the three mixers' own test
+# family → (op, payload): the tiny configs of the four mixers' own test
 # files; ``sparse_mla``'s holds 4 of 16 experts behind one dense layer.
 FAMILIES = {
     "encoder": ("map_classify_tpu", {
@@ -68,6 +68,17 @@ FAMILIES = {
             "rope_theta": 1e11, "ssm_n_heads": 6, "ssm_d_head": 16,
             "ssm_d_state": 24, "ssm_n_groups": 2, "ssm_d_conv": 4,
             "ssm_chunk": 128, **MULTIPLIERS}}),
+    "dense_mla": ("map_score_lm", {
+        "model_path": "parts-latent", "model_config": {
+            **LM, "n_heads": 4, "max_len": 16384, "mixer": "dense_mla",
+            "q_lora_rank": 48, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+            "qk_rope_head_dim": 8, "v_head_dim": 16, "rope_theta": 10000.0,
+            "rope_factor": 8.0, "rope_original_max_len": 1500,
+            "query_scale_beta": 0.1, "n_dense_layers": 0, "n_experts": 16,
+            "n_experts_held": 4, "expert_first": 0, "n_experts_per_token": 4,
+            "n_expert_groups": 1, "n_groups_per_token": 1, "d_expert": 32,
+            "n_shared_experts": 1, "routed_scale": 1.0,
+            "scoring_func": "softmax"}}),
 }
 # What every program of the family's op must name, between them.
 FAMILY_PARTS = {
@@ -78,6 +89,8 @@ FAMILY_PARTS = {
                    "experts", "head"},
     "hybrid_ssm": {"embed", "norm", "project", "mixer", "around", "ffn",
                    "head"},
+    "dense_mla": {"embed", "norm", "project", "mixer", "around", "ffn",
+                  "experts", "head"},
 }
 # The CPU backend leaves the op's own iotas, compares and broadcasts (the
 # length mask, ``rebuild_ids``) as instructions of their own: about a third

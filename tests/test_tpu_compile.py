@@ -432,3 +432,81 @@ def test_hybrid_ssm_kernels_compile_for_v5e(v5e):
         sd((4, 5, S, 128), bf), sd((4, Lk, 128), bf), sd((4, Lk, 128), bf),
         sd((), jnp.int32)).compile()
     assert attend.as_text().count("tpu_custom_call") == 1
+
+
+def test_dense_mla_kernels_compile_for_v5e(v5e):
+    """The latents' expansion and the causal attention at the
+    ``mistral-small-4-119b.score-64k-latent`` cell's own shape: a 4,096-token
+    segment against a 65,536-token cache of 320-wide latents, 32 heads whose
+    joined key ``[c W_UK | kR]`` and value are 128 wide. The expansion's
+    contraction over 320 (the rotary key through an identity block; not a
+    multiple of 128) and a cache twice the indexer's bound must lower; the
+    attention at ONE query head a key head takes the whole segment a step."""
+    from agent_tpu.kernels import causal_attention as ca
+    from agent_tpu.kernels import sparse_mla as sm
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    bf = jnp.bfloat16
+    S, Lk, H = 4096, 65536, 32
+    assert Lk > sm.MAX_KERNEL_KEYS
+    assert sm.expand_supported(Lk, 320, H, 128, 128, bf)
+    assert ca.pallas_supported(S, Lk, 128, bf) and ca.query_tile(1, S) == 4096
+    expand = jax.jit(lambda c, w, n: sm.expand_latents(
+        c, sm.join_rotary_key(w, 64, 64), n, 128, pallas=True,
+        interpret=False)).lower(
+        sd((Lk, 320), bf), sd((H, 256, 192), bf), sd((), jnp.int32)).compile()
+    assert expand.as_text().count("tpu_custom_call") == 1
+    attend = jax.jit(lambda q, k, v, p: ca.causal_attention(
+        q, k, v, p, pallas=True, interpret=False)).lower(
+        sd((H, 1, S, 128), bf), sd((H, Lk, 128), bf), sd((H, Lk, 128), bf),
+        sd((), jnp.int32)).compile()
+    assert attend.as_text().count("tpu_custom_call") == 1
+
+
+def test_dense_mla_segment_program_carries_latents_only_on_v5e(v5e):
+    """The whole later-segment program of the ``mistral-small-4-119b`` cell
+    (six scanned layers at the published widths, 32 held experts, a
+    65,536-token cache, the state donated) for a described v5e: three
+    kernels in the loop body (expansion, attention, grouped experts); the
+    carried state is ``[6, 1, 65536, 320]`` latents and the pair count,
+    aliased in place; expanded keys and values (``bf16[32, 65536, 128]``, 537
+    MB each) exist as the expansion's two results of ONE layer, among the
+    program's temporaries: under 2 GB named, beside 10.83 GB of arguments."""
+    import re
+
+    from agent_tpu.models import decoder_lm
+    from benchmarks.harness import manifest
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)  # noqa: E731
+    model = manifest.load_config(manifest.load_manifest(),
+                                 "mistral-small-4-119b")["model"]
+    cfg = decoder_lm.DecoderLMConfig(**model)
+    params = jax.tree_util.tree_map(sd, jax.eval_shape(
+        lambda: decoder_lm.init_params(cfg, "m")))
+    state = jax.tree_util.tree_map(sd, jax.eval_shape(
+        lambda: decoder_lm.init_state(cfg, 1, 65536)))
+    ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+
+    def lm_segment(p, i, at, s):
+        return decoder_lm.forward_segment(p, i, at, s, cfg, pallas=True,
+                                          interpret=False)
+
+    hidden, carried = jax.eval_shape(lm_segment, params, ids, pos, state)
+    assert hidden.shape == (1, 4096, 4096)
+    assert set(carried) == {"mixer", "pairs"} and set(carried["mixer"]) == {"kv"}
+    assert carried["mixer"]["kv"].shape == (6, 1, 65536, 320)
+    done = jax.jit(lm_segment, donate_argnums=(3,)).lower(
+        params, ids, pos, state).compile()
+    text = done.as_text()
+    assert text.count("tpu_custom_call") == 3 and " while(" in text
+    expanded = re.findall(r"= bf16\[32,65536,128\]", text)
+    assert 1 <= len(expanded) <= 2          # one layer's, never stacked by 6
+    assert not re.search(r"bf16\[6,32,65536,128\]", text)
+    memory = done.memory_analysis()
+    cache_bytes = 6 * 65536 * 320 * 2
+    assert memory.alias_size_in_bytes >= cache_bytes        # written in place
+    assert 2 * 32 * 65536 * 128 * 2 < memory.temp_size_in_bytes < 2.0e9
+    assert 10.8e9 < memory.argument_size_in_bytes < 10.9e9
