@@ -1,22 +1,34 @@
-"""One grouped SwiGLU feed-forward over the experts a chip holds: rows
-sorted by expert, every expert's rows a whole number of ``ROW_TILE`` tiles,
-and ONE ``pallas_call`` whose grid walks the tiles that hold rows — sized by
-the real counts, no capacity, no one-hot dispatch (``models/moe.py:
-held_experts_ffn`` sorts and combines).
+"""One grouped SwiGLU feed-forward over the experts a chip holds: the rows
+routed here sorted by expert, every expert's rows a whole number of
+``ROW_TILE`` tiles, and ONE ``pallas_call`` whose grid walks the tiles that
+hold rows, sized by the real counts, no capacity, no one-hot dispatch
+(``models/moe.py: held_experts_ffn`` sorts, counts and lays out the tiles).
 
 Per tile of rows ``x`` of expert ``e``, the expert's width in blocks ``f``:
 
     y = sum_f (silu(x W_gate[e][:, f]) * (x W_up[e][:, f])) W_down[e][f, :]
 
-``tile_expert`` (the expert of every tile) and ``n_tiles`` (how many tiles
-hold rows) are prefetched scalars: a step past the last tile names that
-tile's blocks again (no copy) and computes nothing, so the program is
-fixed-shape at the worst case (every token routed here ``k`` times) and costs
-what the routed rows cost. At about 128 rows an expert a segment the layer
-is bound by reading the experts' weights once (PERF.md section 5).
+**The kernel addresses its own rows** (PR 41). The tokens' rows ``[S, d]``
+stay where they lie; ``token`` and ``slot`` (a prefetched table each: for
+every sorted row, the token it computes on and the row of the result it
+writes) and ``tile_rows`` (how many rows of a tile are real) let it fetch a
+tile's REAL rows by one DMA a row into a double-buffered tile (the next
+tile's rows go out a share every width step, while this tile's weights
+stream) and write its real rows straight to their slots. XLA gathers nothing:
+in front of a custom call a gather is materialized whole, at the fixed worst
+case (every token routed here ``k`` times, plus a tile of padding an expert),
+41 k and 70 k rows a call to serve 4.5 k and 2.3 k at the two cells that run
+this (PERF.md section 6). The slots are ``k``-major (pair ``(token, j)`` at
+row ``j * S + token``), so :func:`combine_pairs` reads them in one pass.
+
+``tile_expert`` and ``n_tiles`` are prefetched too: a step past the last
+tile names that tile's blocks again (no copy) and computes nothing, so the
+program is fixed-shape at the worst case and costs what the routed rows
+cost. At about 128 rows an expert a segment the layer is bound by reading
+the experts' weights once (PERF.md section 5).
 
 The weights are read WHERE THEY LIE: the operands are the model's stacked
-leaves ``[L, E, ...]`` and a third prefetched scalar, ``layer``, is the
+leaves ``[L, E, ...]`` and one more prefetched scalar, ``layer``, is the
 leading block index of every weight block. A custom call wants a standalone
 operand, so a layer's slice handed to it inside the layer scan is a copy of
 all the layer's experts (1.41 GB a layer a segment at deepseek-v3.2's widths)
@@ -26,6 +38,7 @@ nothing. A leaf of one layer ``[E, ...]`` is the same path at ``L = 1``."""
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -39,25 +52,192 @@ ROW_TILE = 256
 # Columns of the expert's width a step.
 WIDTH_TILE = 256
 _VMEM_LIMIT = 100 * 1024 * 1024
+# The combine's block of words (every ``j`` of a run of tokens), at most.
+_COMBINE_BLOCK_BYTES = 8 * 1024 * 1024
 
 
 def pallas_supported(d_model: int, d_expert: int, dtype) -> bool:
-    return bool(jnp.dtype(dtype) == jnp.bfloat16 and d_model % 128 == 0
+    return bool(jnp.dtype(dtype) == jnp.bfloat16 and d_model % 256 == 0
                 and d_expert % WIDTH_TILE == 0)
 
 
-def _ffn_kernel(tile_expert_ref, n_tiles_ref, layer_ref, x_ref, wg_ref, wu_ref,
-                wd_ref, y_ref, acc_ref):
+# A row travels as 32-bit words: the DMA engine addresses ONE row of a
+# ``[rows, 1, words]`` array (a tile of one sublane, so rows lie end to end)
+# and refuses one row of a tiled ``[rows, d]`` array (8 rows a tile, 16 in
+# bf16). Word ``c`` of a row holds column ``c`` in its low half and column
+# ``c + d / 2`` in its high half: both halves are whole lane tiles, and a
+# bf16 is the high half of its float32. XLA re-lays such an array whole
+# before it computes on it, so the two passes that touch it are kernels too.
+_HIGH = 0xFFFF0000
+
+
+def _halves(words):
+    """uint32 words → (low halves, high halves) as the float32 they stand for."""
+    f32 = functools.partial(jax.lax.bitcast_convert_type,
+                            new_dtype=jnp.float32)
+    return f32(words << 16), f32(words & jnp.uint32(_HIGH))
+
+
+def _words(low, high):
+    """Two float32 arrays that hold bf16 values → one of uint32 words."""
+    bits = functools.partial(jax.lax.bitcast_convert_type,
+                             new_dtype=jnp.uint32)
+    return (bits(low) >> 16) | (bits(high) & jnp.uint32(_HIGH))
+
+
+# The two passes beside the kernel see the words as ``[rows * n, 128]``, ``n =
+# d / 256`` lane tiles a row: the same bytes as ``[rows, 1, d / 2]``, but 8
+# sublanes a tile, so a block streams at the memory's rate and lane tile ``c``
+# of ``bs`` rows is ONE strided read, ``[c :: n]``.
+
+def _token_block(S: int) -> int:
+    block = math.gcd(S, ROW_TILE)
+    return block if block % 8 == 0 else S
+
+
+def _pack_kernel(x_ref, words_ref):
+    bs, d = x_ref.shape
+    n = d // 256
+    for c in range(n):
+        at = c * 128
+        words_ref[pl.ds(c, bs, stride=n), :] = _words(
+            x_ref[:, at:at + 128].astype(jnp.float32),
+            x_ref[:, d // 2 + at:d // 2 + at + 128].astype(jnp.float32))
+
+
+def _pack_rows(x, interpret):
+    """[S, d] bf16 → [S, 1, d / 2] uint32, one pass."""
+    S, d = x.shape
+    bs, n = _token_block(S), d // 256
+    return pl.pallas_call(
+        _pack_kernel,
+        grid=(S // bs,),
+        in_specs=[pl.BlockSpec((bs, d), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((bs * n, 128), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((S * n, 128), jnp.uint32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        name="moe_pack_rows",
+        interpret=interpret,
+    )(x).reshape(S, 1, d // 2)
+
+
+def unpack_rows(words, dtype=jnp.bfloat16):
+    """[n, 1, d / 2] uint32 as :func:`grouped_swiglu` writes them → [n, d]
+    (plain XLA: for a test that wants to look at rows)."""
+    low, high = _halves(words[:, 0, :])
+    return jnp.concatenate([low, high], axis=1).astype(dtype)
+
+
+def _sum_pairs(term, js):
+    """``term(j)`` summed over ``js`` in the order XLA's reduce over a token's
+    ``k`` pairs had when they lay in the sublanes (stride halving: ``(a0 + a2)
+    + (a1 + a3)`` at 4, ``((a0 + a4) + (a2 + a6)) + ((a1 + a5) + (a3 + a7))``
+    at 8), so a document's answer is the same bits as before the kernel
+    combined (PERF.md section 6, PR 41)."""
+    if len(js) == 1:
+        return term(js[0])
+    return _sum_pairs(term, js[0::2]) + _sum_pairs(term, js[1::2])
+
+
+def _combine_kernel(words_ref, gate_ref, held_ref, y_ref):
+    bs, d = y_ref.shape
+    k, n = words_ref.shape[0], d // 256
+    gates = [jnp.broadcast_to(gate_ref[j], (bs, 128)) for j in range(k)]
+    held = [jnp.broadcast_to(held_ref[j] != 0, (bs, 128)) for j in range(k)]
+    for c in range(n):
+        def term(j, half):
+            y = _halves(words_ref[j, pl.ds(c, bs, stride=n), :])[half]
+            # A pair held elsewhere was never written: select, not multiply.
+            return jnp.where(held[j], y * gates[j], 0.0)
+
+        for half in range(2):
+            at = half * d // 2 + c * 128
+            y_ref[:, at:at + 128] = _sum_pairs(
+                functools.partial(term, half=half), list(range(k)))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+@part("experts")
+def combine_pairs(words, held, gates, *, interpret: bool = False):
+    """words [k * S, 1, d / 2] uint32 (:func:`grouped_swiglu`'s, pair
+    ``(token, j)`` at row ``j * S + token``); held [S, k] bool, gates [S, k]
+    float32 → ``sum_j where(held[:, j], gates[:, j] * y[j], 0)`` in float32
+    [S, d] (:func:`_sum_pairs`' order). Reads the words once, where they
+    lie."""
+    S, k = held.shape
+    half = words.shape[-1]
+    n = half // 128
+    bs = _token_block(S)
+    while bs % 16 == 0 and k * bs * half * 4 > _COMBINE_BLOCK_BYTES:
+        bs //= 2
+    per_pair = pl.BlockSpec((k, bs, 1), lambda i: (0, i, 0))
+    return pl.pallas_call(
+        _combine_kernel,
+        grid=(S // bs,),
+        in_specs=[pl.BlockSpec((k, bs * n, 128), lambda i: (0, i, 0)),
+                  per_pair, per_pair],
+        out_specs=pl.BlockSpec((bs, 2 * half), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((S, 2 * half), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        name="moe_combine_pairs",
+        interpret=interpret,
+    )(words.reshape(k, S * n, 128), gates.astype(jnp.float32).T[:, :, None],
+      held.astype(jnp.int32).T[:, :, None])
+
+
+def _ffn_kernel(token_ref, slot_ref, tile_expert_ref, tile_rows_ref,
+                n_tiles_ref, layer_ref, x_hbm, wg_ref, wu_ref, wd_ref, y_hbm,
+                rows_in, x_ref, acc_ref, rows_out, sem):
     del tile_expert_ref, layer_ref
     f32 = jnp.float32
     t, f = pl.program_id(0), pl.program_id(1)
+    n_f, n_tiles = pl.num_programs(1), n_tiles_ref[0]
+    tm, d = x_ref.shape
     nn = (((1,), (0,)), ((), ()))
+    OUT = 2                                # rows_in's two slots have sem 0, 1
 
-    @pl.when(t < n_tiles_ref[0])
+    def fetch(tile, lo, hi):
+        """Rows ``lo .. hi - 1`` of ``tile``: x → its slot of ``rows_in``."""
+        def one(j, carry):
+            pltpu.make_async_copy(x_hbm.at[token_ref[tile * tm + j]],
+                                  rows_in.at[tile % 2, j],
+                                  sem.at[tile % 2]).start()
+            return carry
+        jax.lax.fori_loop(lo, hi, one, 0)
+
+    def wait(n, s):
+        """``n`` row copies on semaphore ``s`` (every row is one size)."""
+        def one(j, carry):
+            pltpu.make_async_copy(rows_out.at[0], rows_out.at[0],
+                                  sem.at[s]).wait()
+            return carry
+        jax.lax.fori_loop(0, n, one, 0)
+
+    @pl.when(t < n_tiles)
     def _():
         @pl.when(f == 0)
         def _():
+            @pl.when(t == 0)
+            def _():
+                fetch(0, 0, tile_rows_ref[0])
+
+            wait(tile_rows_ref[t], t % 2)
+            low, high = _halves(rows_in[t % 2, :, 0, :])
+            x_ref[:, :d // 2] = low.astype(x_ref.dtype)
+            x_ref[:, d // 2:] = high.astype(x_ref.dtype)
             acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+        # The next tile's rows, a share of them every width step: the
+        # descriptors go out while this tile's weights stream.
+        @pl.when(t + 1 < n_tiles)
+        def _():
+            share = -(-tm // n_f)
+            fetch(t + 1, f * share,
+                  jnp.minimum((f + 1) * share, tile_rows_ref[t + 1]))
 
         x = x_ref[...]
         gate = jax.lax.dot_general(x, wg_ref[0, 0], nn,
@@ -68,27 +248,51 @@ def _ffn_kernel(tile_expert_ref, n_tiles_ref, layer_ref, x_ref, wg_ref, wu_ref,
         acc_ref[...] += jax.lax.dot_general(h, wd_ref[0, 0], nn,
                                             preferred_element_type=f32)
 
-        @pl.when(f == pl.num_programs(1) - 1)
+        @pl.when(f == n_f - 1)
         def _():
-            y_ref[...] = acc_ref[...].astype(y_ref.dtype)
+            @pl.when(t > 0)                # the tile before's rows have left
+            def _():
+                wait(tile_rows_ref[t - 1], OUT)
+
+            y = acc_ref[...].astype(x_ref.dtype).astype(f32)
+            rows_out[:, 0, :] = _words(y[:, :d // 2], y[:, d // 2:])
+
+            def one(j, carry):
+                pltpu.make_async_copy(rows_out.at[j],
+                                      y_hbm.at[slot_ref[t * tm + j]],
+                                      sem.at[OUT]).start()
+                return carry
+            jax.lax.fori_loop(0, tile_rows_ref[t], one, 0)
+
+            @pl.when(t == n_tiles - 1)
+            def _():
+                wait(tile_rows_ref[t], OUT)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("n_slots", "interpret"))
 @part("experts")
-def grouped_swiglu(x, tile_expert, n_tiles, w_gate, w_up, w_down, layer=0,
-                   *, interpret: bool = False):
-    """x [R, d] (rows sorted by expert, ``R`` whole tiles), tile_expert
-    [R / ROW_TILE] int32, n_tiles int32 scalar, w_gate, w_up [L, E, d, f],
-    w_down [L, E, f, d] (the layers' stack, read in place) and ``layer`` the
-    int32 scalar that says which of the ``L``; or one layer's [E, d, f],
-    [E, f, d] → y [R, d]. Rows of tiles at and after ``n_tiles`` are not
-    written."""
-    R, d = x.shape
+def grouped_swiglu(x, token, slot, tile_expert, tile_rows, w_gate, w_up,
+                   w_down, layer=0, *, n_slots: int, interpret: bool = False):
+    """x [S, d]; for every row ``r`` of the sorted, tile-padded order (tile
+    ``r // ROW_TILE``), ``token[r]`` the row of ``x`` it computes on and
+    ``slot[r]`` the row of the result it writes (each slot at most once);
+    tile_expert [T] int32 and tile_rows [T] int32, how many rows of the tile
+    are real (they come first in it; the tiles that hold rows come first of
+    the ``T``); w_gate, w_up [L, E, d, f], w_down [L, E, f, d] (the layers'
+    stack, read in place) and ``layer`` the int32 scalar that says which of
+    the ``L``; or one layer's [E, d, f], [E, f, d] → y [n_slots, 1, d / 2]
+    uint32, a row's two halves in a word (:func:`combine_pairs` and
+    :func:`unpack_rows` read them). Only the real rows' slots are written:
+    every other row of ``y`` is whatever the memory held."""
+    S, d = x.shape
     fe = w_gate.shape[-1]
-    tm, tf = min(ROW_TILE, R), min(WIDTH_TILE, fe)
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    tile_rows = i32(tile_rows)
+    tm, tf = token.shape[0] // tile_rows.shape[0], min(WIDTH_TILE, fe)
     n_f = fe // tf
     if w_gate.ndim == 3:                   # one layer: a stack of one
         w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+    n_tiles = (tile_rows > 0).sum(dtype=jnp.int32)
 
     def tile(t, n):
         return jnp.minimum(t, jnp.maximum(n[0] - 1, 0))
@@ -96,30 +300,36 @@ def grouped_swiglu(x, tile_expert, n_tiles, w_gate, w_up, w_down, layer=0,
     def width(t, f, n):                    # idle steps keep the last block
         return jnp.where(t < n[0], f, n_f - 1)
 
+    def w_in(t, f, tok, sl, te, tr, n, ly):
+        return ly[0], te[tile(t, n)], 0, width(t, f, n)
+
+    def w_out(t, f, tok, sl, te, tr, n, ly):
+        return ly[0], te[tile(t, n)], width(t, f, n), 0
+
     return pl.pallas_call(
         _ffn_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(R // tm, n_f),
+            num_scalar_prefetch=6,
+            grid=(tile_rows.shape[0], n_f),
             in_specs=[
-                pl.BlockSpec((tm, d), lambda t, f, te, n, ly: (tile(t, n), 0)),
-                pl.BlockSpec((1, 1, d, tf), lambda t, f, te, n, ly: (
-                    ly[0], te[tile(t, n)], 0, width(t, f, n))),
-                pl.BlockSpec((1, 1, d, tf), lambda t, f, te, n, ly: (
-                    ly[0], te[tile(t, n)], 0, width(t, f, n))),
-                pl.BlockSpec((1, 1, tf, d), lambda t, f, te, n, ly: (
-                    ly[0], te[tile(t, n)], width(t, f, n), 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((1, 1, d, tf), w_in),
+                pl.BlockSpec((1, 1, d, tf), w_in),
+                pl.BlockSpec((1, 1, tf, d), w_out),
             ],
-            out_specs=pl.BlockSpec((tm, d),
-                                   lambda t, f, te, n, ly: (tile(t, n), 0)),
-            scratch_shapes=[pltpu.VMEM((tm, d), jnp.float32)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((2, tm, 1, d // 2), jnp.uint32),
+                            pltpu.VMEM((tm, d), x.dtype),
+                            pltpu.VMEM((tm, d), jnp.float32),
+                            pltpu.VMEM((tm, 1, d // 2), jnp.uint32),
+                            pltpu.SemaphoreType.DMA((3,))],
         ),
-        out_shape=jax.ShapeDtypeStruct((R, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_slots, 1, d // 2), jnp.uint32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         name="moe_grouped_swiglu",
         interpret=interpret,
-    )(tile_expert.astype(jnp.int32), n_tiles.reshape(1).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), x, w_gate, w_up, w_down)
+    )(i32(token), i32(slot), i32(tile_expert), tile_rows, n_tiles.reshape(1),
+      i32(layer).reshape(1), _pack_rows(x, interpret), w_gate, w_up, w_down)

@@ -290,12 +290,16 @@ def held_experts_ffn(x: jax.Array, experts: jax.Array, gates: jax.Array,
     [L, Eh, d, f], [L, Eh, f, d] as the model holds them, of which that
     layer's experts are used. Returns ``(sum over a token's chosen experts
     HELD HERE of gate x SwiGLU_e(x), float32 [S, d]; how many (token,
-    expert) pairs that were)``. On the chip: pairs sorted by expert, each
-    expert's rows padded to whole tiles, one grouped matmul over the tiles
-    that hold rows (``kernels/grouped_ffn.py``), which reads a stack in
-    place: inside a layer scan, hand it the stack and the layer's number,
-    not the layer's slice (a copy of every held expert). Off the kernel the
-    layer's slice is taken here."""
+    expert) pairs that were)``. On the chip XLA moves INDICES only: the
+    pairs sorted by expert, the counts, each expert's rows padded to whole
+    tiles, and for every sorted row the token it reads and the slot it
+    writes. The rows themselves are the kernels' (``kernels/grouped_ffn.py``):
+    one grouped matmul over the tiles that hold rows fetches its real rows
+    from ``x`` and writes them to slot ``j * S + token``, a second pass
+    combines the slots under the gates. The matmul reads a stack in place:
+    inside a layer scan, hand it the stack and the layer's number, not the
+    layer's slice (a copy of every held expert). Off the kernel the layer's
+    slice is taken here."""
     from agent_tpu.kernels import grouped_ffn
 
     S, k = experts.shape
@@ -322,23 +326,23 @@ def held_experts_ffn(x: jax.Array, experts: jax.Array, gates: jax.Array,
     tiles = (counts + tm - 1) // tm
     tile_end = jnp.cumsum(tiles)
     first_row = (tile_end - tiles) * tm
-    tile_expert = jnp.minimum(jnp.searchsorted(
-        tile_end, jnp.arange(n_tiles_max), side="right"), n_held - 1)
-    row = jnp.arange(n_tiles_max * tm)
-    e_row = tile_expert[row // tm]
-    j = row - first_row[e_row]
-    token = order[jnp.clip(first_pair[e_row] + j, 0, n_pairs - 1)] // k
-    x_rows = x[jnp.where(j < counts[e_row], token, 0)]
-    y_rows = grouped_ffn.grouped_swiglu(
-        x_rows, tile_expert, tile_end[-1], w_gate, w_up, w_down,
-        0 if layer is None else layer, interpret=resolve_interpret(interpret))
-    place = jnp.zeros((n_pairs,), jnp.int32).at[order].set(
-        jnp.arange(n_pairs, dtype=jnp.int32))
-    e_pair = jnp.minimum(of_pair, n_held - 1)
-    row_of = jnp.where(held.reshape(n_pairs),
-                       first_row[e_pair] + place - first_pair[e_pair], 0)
-    y_pairs = y_rows[row_of].reshape(S, k, d).astype(jnp.float32)
-    # Rows of tiles that hold nothing are never written: select, not multiply.
-    y = jnp.where(held[:, :, None], y_pairs * gates[:, :, None],
-                  0.0).sum(axis=1)
-    return y, pairs
+    tile = jnp.arange(n_tiles_max)
+    tile_expert = jnp.minimum(jnp.searchsorted(tile_end, tile, side="right"),
+                              n_held - 1)
+    # How many of a tile's rows are real (a tile past the last holds none).
+    tile_rows = jnp.clip(
+        (first_row + counts)[tile_expert] - tile * tm, 0, tm)
+    # Sorted row r of a tile is sorted pair r + (the tile's expert's first
+    # pair - its first row): one number a tile, spread over the tile's rows.
+    shift = jnp.repeat((first_pair - first_row)[tile_expert], tm)
+    token, j = jnp.divmod(order[jnp.clip(
+        jnp.arange(n_tiles_max * tm) + shift, 0, n_pairs - 1)], k)
+    # Pair (token, j) writes row j * S + token: the pairs k-major, so the
+    # combine reads the kernel's rows where they lie, one j after another.
+    interpret = resolve_interpret(interpret)
+    y_pairs = grouped_ffn.grouped_swiglu(
+        x, token, j * S + token, tile_expert, tile_rows, w_gate, w_up,
+        w_down, 0 if layer is None else layer, n_slots=n_pairs,
+        interpret=interpret)
+    return grouped_ffn.combine_pairs(y_pairs, held, gates,
+                                     interpret=interpret), pairs

@@ -247,6 +247,40 @@ def test_the_innermost_scope_wins(compiled):
     assert {parts["instructions"][name] for name in deeper} <= {"around"}
 
 
+def test_the_expert_layer_on_the_kernel_path_is_all_experts(monkeypatch):
+    """``held_experts_ffn`` at widths its kernels take (interpret mode, so
+    each kernel is inlined under its own name): what sorts and counts, the
+    pass that packs the rows, the grouped matmul with its row copies and the
+    combine that reads them all lie under ``experts``, and under no other
+    part; what is left without a part has no scope of the layer's at all
+    (the compiler's own copies). On the chip each kernel is one custom call
+    of that name: ``tests/test_tpu_compile.py`` holds the part map there."""
+    from agent_tpu.kernels import grouped_ffn
+    from agent_tpu.models import moe
+
+    monkeypatch.setattr(grouped_ffn, "ROW_TILE", 64)
+    sd = jax.ShapeDtypeStruct
+    S, d, fe, held, k = 128, 256, 256, 4, 4
+    bf = jnp.bfloat16
+    text = jax.jit(lambda x, e, g, a, b, c: moe.held_experts_ffn(
+        x, e, g, a, b, c, 0, pallas=True, interpret=True)).lower(
+        sd((S, d), bf), sd((S, k), jnp.int32), sd((S, k), jnp.float32),
+        sd((held, d, fe), bf), sd((held, d, fe), bf),
+        sd((held, fe, d), bf)).compile().as_text()
+    _, parts = executor.parts_of_text(text)
+    assert {p for p in parts["instructions"].values() if p} == {"experts"}
+    scoped = {m.group(1): m.group(2) for m in re.finditer(
+        r"^\s+(?:ROOT )?%?([\w.\-]+) = .*op_name=\"([^\"]*)\"", text, re.M)}
+    for kernel in ("moe_pack_rows", "moe_grouped_swiglu",
+                   "moe_combine_pairs"):
+        inside = [i for i, op_name in scoped.items()
+                  if f"part:experts/{kernel}/" in op_name]
+        assert inside, kernel
+        assert {parts["instructions"][i] for i in inside} == {"experts"}
+    assert not [i for i, p in parts["instructions"].items()
+                if p is None and "part:" in scoped.get(i, "")]
+
+
 HAND_TEXT = '''HloModule jit_hand, is_scheduled=true
 
 %fused_a (p0: f32[8,8], p1: f32[8,8]) -> f32[8,8] {

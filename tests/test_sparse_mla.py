@@ -197,7 +197,7 @@ def test_grouped_matmul_equals_every_expert_on_every_token(monkeypatch, first):
     so that experts span tiles and leave tails) against the held experts
     applied densely; the pair count is the router's."""
     monkeypatch.setattr(grouped_ffn, "ROW_TILE", 64)
-    S, d, fe, E, held, k = 512, 128, 256, 32, 4, 4
+    S, d, fe, E, held, k = 512, 256, 256, 32, 4, 4
     ks = jax.random.split(jax.random.PRNGKey(1), 5)
     x = jax.random.normal(ks[0], (S, d), BF16)
     experts, gates = moe.route_sigmoid_grouped(
@@ -219,34 +219,149 @@ def test_grouped_matmul_equals_every_expert_on_every_token(monkeypatch, first):
                                atol=3e-2)
 
 
+def _rows_in_place(x, tile_expert, n_tiles, *weights, tm=64):
+    """The grouped kernel on rows that already lie sorted and tile-padded:
+    row ``r`` reads row ``r`` of ``x`` and writes row ``r`` of the result
+    (what the layer gathered in XLA before the kernel addressed its rows)."""
+    rows = jnp.arange(x.shape[0], dtype=jnp.int32)
+    tile_rows = jnp.where(jnp.arange(tile_expert.size) < n_tiles, tm, 0)
+    return grouped_ffn.unpack_rows(grouped_ffn.grouped_swiglu(
+        x, rows, rows, tile_expert, tile_rows, *weights,
+        n_slots=x.shape[0], interpret=True))
+
+
 @pytest.mark.parametrize("layer", [0, 1, 2])
-def test_grouped_matmul_reads_a_layer_of_the_stack_in_place(monkeypatch,
-                                                            layer):
+def test_grouped_matmul_reads_a_layer_of_the_stack_in_place(layer):
     """The kernel on the layers' stack ``[3, E, ...]`` with the layer's number
     (interpret mode, 64-row tiles, the last tile idle) is, bit for bit, the
     kernel on that layer's own slice: one more block index, no arithmetic."""
-    monkeypatch.setattr(grouped_ffn, "ROW_TILE", 64)
-    L, E, d, fe, tm = 3, 4, 128, 512, 64
+    L, E, d, fe, tm = 3, 4, 256, 512, 64
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
     stacks = [(jax.random.normal(key, shape) / np.sqrt(shape[2])).astype(BF16)
               for key, shape in zip(ks[1:], [(L, E, d, fe), (L, E, d, fe),
                                              (L, E, fe, d)])]
     tile_expert = jnp.array([0, 0, 1, 3, 3, 3, 3], jnp.int32)
     x = jax.random.normal(ks[0], (tile_expert.size * tm, d), BF16)
-    n_tiles = jnp.int32(6)
-    whole = grouped_ffn.grouped_swiglu(x, tile_expert, n_tiles, *stacks,
-                                       jnp.int32(layer), interpret=True)
-    sliced = grouped_ffn.grouped_swiglu(
-        x, tile_expert, n_tiles, *(w[layer] for w in stacks), interpret=True)
+    whole = _rows_in_place(x, tile_expert, 6, *stacks, jnp.int32(layer))
+    sliced = _rows_in_place(x, tile_expert, 6, *(w[layer] for w in stacks))
     written = 6 * tm
     assert float(jnp.abs(sliced[:written].astype(jnp.float32)).max()) > 0.1
     np.testing.assert_array_equal(np.asarray(whole[:written]),
                                   np.asarray(sliced[:written]))
     if layer:                 # and it is THAT layer's weights it read
-        first = grouped_ffn.grouped_swiglu(x, tile_expert, n_tiles, *stacks,
-                                           interpret=True)
+        first = _rows_in_place(x, tile_expert, 6, *stacks)
         assert not np.array_equal(np.asarray(whole[:written]),
                                   np.asarray(first[:written]))
+
+
+def _routed(form, S, E, k):
+    logits = jax.random.normal(jax.random.PRNGKey(3), (S, E), jnp.float32)
+    if form == "softmax":
+        return moe.route_softmax(logits, top_k=k, scale=1.0)
+    return moe.route_sigmoid_grouped(logits, jnp.zeros(E), n_groups=8,
+                                     groups_kept=4, top_k=k, scale=2.5)
+
+
+def _by_hand(S, k, counts, first):
+    """Pairs dealt by hand: ``counts[e]`` of them to held expert ``e`` (at
+    most one a token), the rest to an expert held elsewhere."""
+    experts = np.full((S, k), first + len(counts), np.int32)
+    at = 0
+    for e, n in enumerate(counts):
+        for i in range(n):
+            experts[(at + i) % S, (at + i) // S] = first + e
+        at += n
+    gates = jax.random.uniform(jax.random.PRNGKey(4), (S, k), jnp.float32,
+                               0.1, 1.0)
+    return jnp.asarray(experts), gates
+
+
+# name: (the router's pairs, k, held experts, the first of them)
+ROWS_HELD_CASES = {
+    # the two cells' routing forms at their own k and held counts
+    "sigmoid_8_of_16_held": (lambda: _routed("sigmoid", 256, 64, 8), 16, 0),
+    "sigmoid_first_not_0": (lambda: _routed("sigmoid", 256, 64, 8), 16, 32),
+    "softmax_4_of_32_held": (lambda: _routed("softmax", 256, 128, 4), 32, 0),
+    "softmax_first_not_0": (lambda: _routed("softmax", 256, 128, 4), 32, 64),
+    # an expert with no row, with exactly one tile, with one row over a tile
+    "no_row_one_tile_one_over": (
+        lambda: _by_hand(256, 2, [0, 64, 65, 3], 8), 4, 8),
+    # the worst case the shape is fixed for: every pair to ONE held expert
+    "every_pair_to_one_expert": (
+        lambda: (jnp.full((128, 4), 5, jnp.int32), jax.random.uniform(
+            jax.random.PRNGKey(5), (128, 4), jnp.float32, 0.05, 0.5)), 4, 4),
+    # no pair held at all: no tile holds a row
+    "no_pair_held": (lambda: _by_hand(128, 4, [0, 0, 0, 0], 8), 4, 8),
+}
+
+
+@pytest.mark.parametrize("case", ROWS_HELD_CASES)
+def test_the_expert_layer_moves_the_rows_it_holds(monkeypatch, case):
+    """``held_experts_ffn`` on the kernel path (interpret mode, 64-row
+    tiles): the kernel fetches the real rows of every tile from ``x`` by its
+    own table and writes them to slot ``j * S + token``; the combine reads
+    the slots. Every slot the kernel did NOT write is poisoned with NaNs
+    before the combine reads it: nothing unwritten reaches the result.
+    Against ``_held_dense`` within the file's tolerance, and every pair's row
+    bit for bit the row of the parent's form: the rows gathered in XLA, the
+    kernel run in place on them, the rows gathered back by their place."""
+    monkeypatch.setattr(grouped_ffn, "ROW_TILE", 64)
+    route, n_held, first = ROWS_HELD_CASES[case]
+    experts, gates = route()
+    S, k = experts.shape
+    d, fe, tm = 256, 512, 64
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    x = jax.random.normal(ks[0], (S, d), BF16)
+    ws = [(jax.random.normal(key, shape) / np.sqrt(shape[1])).astype(BF16)
+          for key, shape in zip(ks[1:], [(n_held, d, fe), (n_held, d, fe),
+                                         (n_held, fe, d)])]
+    grouped_swiglu, seen = grouped_ffn.grouped_swiglu, {}
+
+    def poisoned(x, token, slot, tile_expert, tile_rows, *rest, **kwargs):
+        words = grouped_swiglu(x, token, slot, tile_expert, tile_rows, *rest,
+                               **kwargs)
+        real = (jnp.arange(token.size) % tm) < jnp.repeat(tile_rows, tm)
+        written = jnp.zeros(words.shape[0], bool).at[
+            jnp.where(real, slot, words.shape[0])].set(True, mode="drop")
+        seen.update(token=token, slot=slot, tile_expert=tile_expert,
+                    tile_rows=tile_rows, real=real, written=written,
+                    rows=grouped_ffn.unpack_rows(words))
+        return jnp.where(written[:, None, None], words,
+                         jnp.uint32(0x7FC07FC0))
+
+    monkeypatch.setattr(grouped_ffn, "grouped_swiglu", poisoned)
+    kernel, n_kernel = moe.held_experts_ffn(x, experts, gates, *ws, first,
+                                            pallas=True, interpret=True)
+    plain, n_plain = moe.held_experts_ffn(x, experts, gates, *ws, first,
+                                          pallas=False)
+    held = (np.asarray(experts) >= first) & (np.asarray(experts)
+                                             < first + n_held)
+    assert int(n_kernel) == int(n_plain) == int(held.sum())
+    assert int(seen["written"].sum()) == int(held.sum())     # each slot once
+    assert seen["tile_rows"].size == S * k // tm + n_held    # the worst case
+    assert np.isfinite(np.asarray(kernel)).all()
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(plain),
+                               atol=3e-2)
+    if not held.any():
+        assert int((seen["tile_rows"] > 0).sum()) == 0
+        np.testing.assert_array_equal(np.asarray(kernel), 0.0)
+        return
+    assert float(jnp.abs(kernel).max()) > 0.05
+    # The parent's form on the same sorted order (the call passes the spy
+    # too: keep what the layer's own call showed).
+    tables = dict(seen)
+    real, slots = np.asarray(tables["real"]), np.asarray(tables["slot"])
+    x_rows = x[jnp.where(tables["real"], tables["token"], 0)]
+    y_rows = _rows_in_place(x_rows, tables["tile_expert"],
+                            int((tables["tile_rows"] > 0).sum()), *ws)
+    np.testing.assert_array_equal(np.asarray(tables["rows"])[slots[real]],
+                                  np.asarray(y_rows)[real])
+    # ... and that slot IS the pair's: row j * S + token, expert by expert.
+    token, j = slots[real] % S, slots[real] // S
+    np.testing.assert_array_equal(token, np.asarray(tables["token"])[real])
+    np.testing.assert_array_equal(
+        np.asarray(experts)[token, j] - first,
+        np.repeat(np.asarray(tables["tile_expert"]), tm)[real])
 
 
 # Two expert layers behind one dense: the layer scan has a second step to
@@ -257,7 +372,7 @@ STACK_CASES = {
     # name: (config, kernel options, how the expert leaves are stored,
     #        whether the scan leaves them whole)
     "plain_arithmetic": (TWO_EXPERT_LAYERS, {}, "as_drawn", True),
-    "kernel": ({**TWO_EXPERT_LAYERS, "dtype": "bfloat16", "d_model": 128,
+    "kernel": ({**TWO_EXPERT_LAYERS, "dtype": "bfloat16", "d_model": 256,
                 "d_expert": 256}, {"pallas": True, "interpret": True},
                "as_drawn", True),
     "stored_in_another_dtype": ({**TWO_EXPERT_LAYERS, "dtype": "bfloat16"},

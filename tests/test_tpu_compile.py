@@ -19,6 +19,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -329,29 +330,39 @@ def test_sparse_mla_kernels_compile_for_v5e(v5e):
 
 
 def test_grouped_expert_matmul_compiles_for_v5e(v5e):
-    """The grouped SwiGLU at the cell's own shape: every pair of a 4,096-token
-    segment routed here (the fixed-shape worst case: 8 a token) plus a tile
-    of padding an expert, 16 experts of 7,168 x 2,048."""
+    """The grouped SwiGLU at the cell's own shape: a 4,096-token segment's
+    rows ``[4096, 7168]`` left where they lie, the tables of the fixed-shape
+    worst case (every pair routed here, 8 a token, plus a tile of padding an
+    expert), 16 experts of 7,168 x 2,048: the packing pass, the kernel with
+    its row copies, and the combine that reads its result."""
     from agent_tpu.kernels import grouped_ffn as gf
 
     chip = SingleDeviceSharding(v5e.devices[0])
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
-    bf = jnp.bfloat16
-    tiles = 4096 * 8 // gf.ROW_TILE + 16
+    bf, i32 = jnp.bfloat16, jnp.int32
+    S, k = 4096, 8
+    tiles = S * k // gf.ROW_TILE + 16
     assert gf.pallas_supported(7168, 2048, bf)
-    compiled = jax.jit(lambda x, te, n, g, u, d: gf.grouped_swiglu(
-        x, te, n, g, u, d, interpret=False)).lower(
-        sd((tiles * gf.ROW_TILE, 7168), bf), sd((tiles,), jnp.int32),
-        sd((), jnp.int32), sd((16, 7168, 2048), bf), sd((16, 7168, 2048), bf),
+    compiled = jax.jit(lambda x, tok, sl, te, tr, g, u, d: gf.grouped_swiglu(
+        x, tok, sl, te, tr, g, u, d, n_slots=S * k, interpret=False)).lower(
+        sd((S, 7168), bf), sd((tiles * gf.ROW_TILE,), i32),
+        sd((tiles * gf.ROW_TILE,), i32), sd((tiles,), i32), sd((tiles,), i32),
+        sd((16, 7168, 2048), bf), sd((16, 7168, 2048), bf),
         sd((16, 2048, 7168), bf)).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.as_text().count("tpu_custom_call") == 2    # pack, kernel
+    combined = jax.jit(lambda w, h, g: gf.combine_pairs(
+        w, h, g, interpret=False)).lower(
+        sd((S * k, 1, 3584), jnp.uint32), sd((S, k), jnp.bool_),
+        sd((S, k), jnp.float32)).compile()
+    assert combined.as_text().count("tpu_custom_call") == 1
 
 
 def test_expert_layer_scan_reads_the_stack_in_place_on_v5e(v5e, monkeypatch):
     """The FFN half of two scanned expert layers at the
     ``deepseek-v3.2.score-32k`` cell's own shape (leaves ``[2, 16, 7168,
     2048]``, a 4,096-token segment at the fixed worst case), split and stepped
-    as ``forward_segment`` does: one ``tpu_custom_call`` in the loop body, and
+    as ``forward_segment`` does: the grouped kernel and the two passes beside
+    it (PR 41: the rows packed, the pairs combined) in the loop body, and
     NO instruction whose result is one layer's experts (``bf16[16, 7168,
     2048]`` or ``[16, 2048, 7168]``, with or without a leading 1): the
     kernel's operands are the loop's own stacks. The same scan with the
@@ -391,7 +402,7 @@ def test_expert_layer_scan_reads_the_stack_in_place_on_v5e(v5e, monkeypatch):
 
         done = jax.jit(ffn_half).lower(leaves, x).compile()
         text = done.as_text()
-        assert text.count("tpu_custom_call") == 1 and " while(" in text
+        assert text.count("tpu_custom_call") == 3 and " while(" in text
         return (len(a_layers_experts.findall(text)),
                 done.memory_analysis().temp_size_in_bytes)
 
@@ -401,7 +412,120 @@ def test_expert_layer_scan_reads_the_stack_in_place_on_v5e(v5e, monkeypatch):
                         lambda leaves, dtype: (leaves, {}))
     sliced_copies, sliced_temporaries = compiled()
     assert sliced_copies >= 3
-    assert sliced_temporaries - temporaries > 1.4e9
+    # 1.41 GB of copies; the layer's own temporaries (the pairs' rows since
+    # PR 41) share some of that room in the sliced form: 1.34 GB apart.
+    assert sliced_temporaries - temporaries > 1.3e9
+
+
+# What the expert layer may NOT hold outside its kernels, a cell: the widths
+# and the issue's own list of worst-case-row shapes (``R_max = S k + held x
+# ROW_TILE`` sorted rows, ``S k`` pairs, the pairs by token), and by how many
+# bytes the temporaries fall short of the same layer's with XLA moving rows.
+WORST_CASE_ROWS = {
+    "deepseek-v3.2": (7168, 8, 16, "36864,7168|32768,7168|4096,8,7168",
+                      0.45e9),
+    "mistral-small-4-119b": (4096, 4, 32,
+                             "24576,4096|16384,4096|4096,4,4096", 0.2e9),
+}
+
+
+@pytest.mark.parametrize("name", WORST_CASE_ROWS)
+def test_expert_layer_moves_no_worst_case_rows_in_xla_on_v5e(v5e, monkeypatch,
+                                                             name):
+    """The FFN half of two scanned expert layers at each cell's own shape
+    for a described v5e: THREE custom calls in the loop body (the rows
+    packed, the grouped kernel, the combine), and outside them no
+    instruction that gathers, re-lays or copies worst-case rows: none of the
+    shapes the parent's form held (in bf16 or f32), and, whatever its shape
+    or type, no result of ``S k d / 2`` elements or more but the kernel's own
+    and views of it. The same layer with the rows moved by XLA (gathered in
+    front of the kernel, gathered back behind it: the parent's form around
+    the same kernel) holds both gathers and 0.46 / 0.20 GB more temporaries
+    (the parent's own tree: 1,067 → 605 MB and 675 → 202 MB): the search
+    finds what it is held to find."""
+    import re
+
+    from agent_tpu.kernels import grouped_ffn as gf
+    from agent_tpu.models import decoder_lm
+    from benchmarks.harness import manifest
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)  # noqa: E731
+    d, k, held, shapes, fewer_temporaries = WORST_CASE_ROWS[name]
+    S = 4096
+    model = manifest.load_config(manifest.load_manifest(), name)["model"]
+    cfg = decoder_lm.DecoderLMConfig(**{
+        **model, "n_layers": model.get("n_dense_layers", 0) + 2})
+    assert (cfg.d_model, cfg.n_experts_per_token, cfg.n_experts_held) == (
+        d, k, held)
+    leaves = jax.eval_shape(
+        lambda: decoder_lm.init_params(cfg, "m"))["expert_layers"]
+    assert leaves["we_gate"].shape == (2, held, d, 2048)
+    leaves = {key: sd(leaves[key]) for key in decoder_lm.FFN_LEAVES["experts"]
+              + ("router_bias",) if key in leaves}
+    x = sd(jax.ShapeDtypeStruct((1, S, d), jnp.bfloat16))
+    listed = re.compile(r"= (bf16|f32)\[(%s)\]\S* (?!parameter\()" % shapes)
+    result = re.compile(r"^\s*(?:ROOT )?(%\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(")
+
+    def compiled():
+        def ffn_half(leaves, x):
+            scanned, whole = decoder_lm._read_in_place(leaves,
+                                                       cfg.compute_dtype)
+
+            def step(x, p):
+                y, pairs = decoder_lm._experts_ffn(
+                    {**p, **whole}, x, cfg,
+                    {"pallas": True, "interpret": False})
+                return x + y, pairs
+
+            return jax.lax.scan(step, x, scanned)
+
+        done = jax.jit(ffn_half).lower(leaves, x).compile()
+        text = done.as_text()
+        assert text.count("tpu_custom_call") == 3 and " while(" in text
+        large, inside_fusion = [], False
+        for line in text.splitlines():
+            if line.endswith("{"):                   # a computation opens
+                inside_fusion = "fused_computation" in line
+            found = result.match(line)
+            if not found or inside_fusion:
+                continue
+            instruction, dims, opcode = found.groups()
+            if (np.prod([int(n) for n in dims.split(",")]) >= S * k * d // 2
+                    and opcode not in ("parameter", "get-tuple-element",
+                                       "bitcast")
+                    and not instruction.startswith("%moe_grouped_swiglu")):
+                large.append(line.strip()[:120])
+        return (listed.findall(text), large,
+                done.memory_analysis().temp_size_in_bytes, text)
+
+    shapes_found, large, temporaries, text = compiled()
+    assert not shapes_found and not large, (shapes_found, large)
+    # ... and the runtime's part map (PR 38) lays all three kernels under
+    # `experts`.
+    from agent_tpu.runtime import executor
+
+    parts = executor.parts_of_text(text)[1]["instructions"]
+    kernels = {i: p for i, p in parts.items() if i.startswith("moe_")}
+    assert sorted(i.split(".")[0] for i in kernels) == [
+        "moe_combine_pairs", "moe_grouped_swiglu", "moe_pack_rows"]
+    assert set(kernels.values()) == {"experts"}
+
+    grouped_swiglu = gf.grouped_swiglu
+
+    def rows_moved_by_xla(x, token, slot, tile_expert, tile_rows, *weights,
+                          n_slots, interpret):
+        rows = jnp.arange(token.size, dtype=jnp.int32)
+        y_rows = grouped_swiglu(x[token], rows, rows, tile_expert, tile_rows,
+                                *weights, n_slots=token.size,
+                                interpret=interpret)
+        return y_rows[jnp.zeros(n_slots, jnp.int32).at[slot].set(rows)]
+
+    monkeypatch.setattr(gf, "grouped_swiglu", rows_moved_by_xla)
+    shapes_found, large, parents_temporaries, _ = compiled()
+    assert shapes_found, "the rows gathered in front of the kernel"
+    assert len(large) >= 2, large
+    assert parents_temporaries - temporaries > fewer_temporaries
 
 
 def test_hybrid_ssm_kernels_compile_for_v5e(v5e):
@@ -467,8 +591,9 @@ def test_dense_mla_kernels_compile_for_v5e(v5e):
 def test_dense_mla_segment_program_carries_latents_only_on_v5e(v5e):
     """The whole later-segment program of the ``mistral-small-4-119b`` cell
     (six scanned layers at the published widths, 32 held experts, a
-    65,536-token cache, the state donated) for a described v5e: three
-    kernels in the loop body (expansion, attention, grouped experts); the
+    65,536-token cache, the state donated) for a described v5e: five
+    kernels in the loop body (expansion, attention, and the grouped experts'
+    three: rows packed, the matmul, pairs combined); the
     carried state is ``[6, 1, 65536, 320]`` latents and the pair count,
     aliased in place; expanded keys and values (``bf16[32, 65536, 128]``, 537
     MB each) exist as the expansion's two results of ONE layer, among the
@@ -501,7 +626,7 @@ def test_dense_mla_segment_program_carries_latents_only_on_v5e(v5e):
     done = jax.jit(lm_segment, donate_argnums=(3,)).lower(
         params, ids, pos, state).compile()
     text = done.as_text()
-    assert text.count("tpu_custom_call") == 3 and " while(" in text
+    assert text.count("tpu_custom_call") == 5 and " while(" in text
     expanded = re.findall(r"= bf16\[32,65536,128\]", text)
     assert 1 <= len(expanded) <= 2          # one layer's, never stacked by 6
     assert not re.search(r"bf16\[6,32,65536,128\]", text)
