@@ -14,6 +14,16 @@ Where a key-value head has FEWER query heads (``dense_mla``: one, over keys
 expanded from latents) the query tile grows in their place
 (:func:`query_tile`): a loaded key tile again meets about that many rows.
 
+A WINDOW layer (``window_gqa``'s three of four) attends the last ``window``
+keys only (:func:`window_attention`): its keys are ``[the window keys before
+the segment | the segment's own]``, never the document's cache, and the same
+kernel body walks ``(window + query tile) / key tile`` key tiles a query tile
+(three at 1,024 and 512) in place of every tile up to the diagonal: tiles
+wholly behind ``t - window`` are not in the grid, the lower edge is masked from
+the same two iotas as the diagonal, and what lies before the document's
+first token is masked by position (a prefetched scalar). The call carries a
+name of its own, ``window_gqa_attention``.
+
 A streaming softmax with float32 scores, statistics and accumulator; the row
 statistics are kept lane-replicated and the weights are rounded to bf16
 before the value matmul, as ``kernels/sparse_mla.py``'s attention does
@@ -52,6 +62,14 @@ def pallas_supported(seq_len: int, cache_len: int, d_head: int, dtype) -> bool:
                 and seq_len % QUERY_TILE == 0 and cache_len % KEY_TILE == 0)
 
 
+def window_supported(seq_len: int, window: int, d_head: int, dtype) -> bool:
+    """The same, for a window layer: its keys are ``window + seq_len``, and
+    the window is whole key tiles (a query tile's first key tile is then a
+    whole one)."""
+    return bool(pallas_supported(seq_len, window + seq_len, d_head, dtype)
+                and window % KEY_TILE == 0)
+
+
 # Rows a grid step's matmuls have at most. Five stacked heads of 512 queries
 # (2,560) stay as they are; ONE head a key-value head takes a whole
 # 4,096-token segment a step. The kernel alone at 32 heads of 128, 4,096
@@ -88,6 +106,38 @@ def visited_pairs(seq_len: int, pos0: int, query_tile: int = QUERY_TILE,
     return pairs
 
 
+def window_visited_pairs(seq_len: int, pos0: int, window: int,
+                         query_tile: int = QUERY_TILE,
+                         key_tile: int = KEY_TILE) -> int:
+    """The same under a window, over ``[window keys before | the segment]``:
+    a query tile meets the ``(window + query_tile) / key_tile`` key tiles
+    from its window's first to its diagonal's, less those that end before the
+    document's first token."""
+    before = max(window - pos0, 0)          # keys that lie before the document
+    pairs = 0
+    for i in range(-(-seq_len // query_tile)):
+        tiles = range(i * query_tile // key_tile,
+                      (i * query_tile + window + query_tile) // key_tile)
+        pairs += query_tile * key_tile * sum(
+            (kt + 1) * key_tile > before for kt in tiles)
+    return pairs
+
+
+def _window_jnp(q, k, v, before, window):
+    """q [Hkv, G, S, D]; k, v [Hkv, window + S, D]: query ``i`` sits at key
+    ``window + i`` and sees the ``window`` keys up to its own, from key
+    ``before`` on (what lies before the document's first token is not a key).
+    Dense float32 scores."""
+    f32 = jnp.float32
+    S = q.shape[2]
+    at = jnp.arange(window + S)[None, :]
+    t = window + jnp.arange(S)[:, None]
+    seen = (at <= t) & (at > t - window) & (at >= before)
+    s = jnp.einsum("hgtd,hsd->hgts", q.astype(f32), k.astype(f32))
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("hgts,hsd->hgtd", p, v.astype(f32))
+
+
 def _attention_jnp(q, k, v, pos0):
     """q [Hkv, G, S, D], k [Hkv, Lk, D], v [Hkv, Lk, Dv]: dense scores, float32, a block of
     query rows at a time. Keys at and after ``pos0 + S`` are taken out of
@@ -115,12 +165,21 @@ def _attention_jnp(q, k, v, pos0):
 
 
 def _attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                      acc_ref, *, tq: int, tk: int, bk: int, groups: int):
-    """One (key-value head, query tile, key tile) step."""
+                      acc_ref, *, tq: int, tk: int, bk: int, groups: int,
+                      window: Optional[int] = None):
+    """One (key-value head, query tile, key tile) step. With ``window`` the
+    keys are ``[window keys before the segment | the segment]``, a query
+    counts from ``window`` in them, step ``j`` is key tile ``i * tq / tk + j``
+    and ``pos_ref`` holds the first key that lies inside the document."""
     f32 = jnp.float32
     i, j = pl.program_id(1), pl.program_id(2)
-    first = pos_ref[0] + i * tq              # position of the tile's first query
-    n_kv = (first + tq + tk - 1) // tk
+    if window is None:
+        first = pos_ref[0] + i * tq          # position of the tile's first query
+        n_kv = (first + tq + tk - 1) // tk
+        kt = j
+    else:
+        first = window + i * tq              # its index among the keys
+        kt = i * (tq // tk) + j
     rows = groups * tq
     nt = (((1,), (1,)), ((), ()))           # [m, k] x [n, k]
     nn = (((1,), (0,)), ((), ()))
@@ -142,9 +201,12 @@ def _attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                 # Row r of the stack is query r % tq of head r // tq.
                 t = first + jnp.bitwise_and(jax.lax.broadcasted_iota(
                     jnp.int32, (rows, bk), 0), tq - 1)
-                at = j * tk + c * bk + jax.lax.broadcasted_iota(
+                at = kt * tk + c * bk + jax.lax.broadcasted_iota(
                     jnp.int32, (rows, bk), 1)
-                s = jnp.where(at <= t, s, _MASKED)
+                seen = at <= t
+                if window is not None:
+                    seen = seen & (at > t - window) & (at >= pos_ref[0])
+                s = jnp.where(seen, s, _MASKED)
             m_prev = m_ref[...]                              # [rows, 128]
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - jnp.concatenate([m_new] * lanes, axis=1))
@@ -158,11 +220,24 @@ def _attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                 preferred_element_type=f32)
             m_ref[...] = m_new
 
-    # A tile whose last key lies past the tile's first query crosses the
-    # diagonal; the tiles before it are wholly below.
-    crosses = (j + 1) * tk - 1 > first
-    pl.when((j < n_kv) & crosses)(lambda: tile(True))
-    pl.when((j < n_kv) & jnp.logical_not(crosses))(lambda: tile(False))
+    if window is None:
+        # A tile whose last key lies past the tile's first query crosses the
+        # diagonal; the tiles before it are wholly below.
+        crosses = (j + 1) * tk - 1 > first
+        pl.when((j < n_kv) & crosses)(lambda: tile(True))
+        pl.when((j < n_kv) & jnp.logical_not(crosses))(lambda: tile(False))
+    else:
+        # The first ``tq / tk`` tiles hold the window's lower edge, the last
+        # as many the diagonal; one that starts before the document does is
+        # masked too, and one that ends before it is left out. (A row whose
+        # keys so far were all masked holds weights of exp(0): its first real
+        # key's ``alpha`` is exp(-1e30 - m) = 0 and takes them out again; a
+        # row's own key is always real.)
+        edge = (j < tq // tk) | (j >= pl.num_programs(2) - tq // tk) | (
+            kt * tk < pos_ref[0])
+        inside = (kt + 1) * tk > pos_ref[0]
+        pl.when(inside & edge)(lambda: tile(True))
+        pl.when(inside & jnp.logical_not(edge))(lambda: tile(False))
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -171,24 +246,39 @@ def _attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                 o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _attention_call(q, k, v, pos0, *, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def _attention_call(q, k, v, pos0, *, window: Optional[int] = None,
+                    interpret: bool):
+    """``pos0``: the position of the segment's first token; under ``window``
+    the first of the keys that lies inside the document."""
     Hkv, G, S, D = q.shape
     Lk = k.shape[1]
     tq, tk, bk = query_tile(G, S), KEY_TILE, KEY_BLOCK
 
-    # Steps past a query tile's last key tile name that tile again: no copy.
-    def at(j, i, pos):
-        return jnp.minimum(j, (pos[0] + (i + 1) * tq + tk - 1) // tk - 1)
+    if window is None:
+        # Steps past a query tile's last key tile name that tile again: no
+        # copy.
+        def at(j, i, pos):
+            return jnp.minimum(j, (pos[0] + (i + 1) * tq + tk - 1) // tk - 1)
+
+        steps, pairs = Lk // tk, Hkv * G * S * Lk
+    else:
+        # A query tile's window starts ``i * tq`` keys in: tiles behind it are
+        # not in the grid.
+        def at(j, i, pos):
+            return i * (tq // tk) + j
+
+        steps = (window + tq) // tk
+        pairs = 2 * Hkv * G * S * window
 
     kv_block = pl.BlockSpec((1, tk, D), lambda h, i, j, pos: (h, at(j, i, pos), 0))
     q_block = pl.BlockSpec((1, G, tq, D), lambda h, i, j, pos: (h, 0, i, 0))
-    pairs = Hkv * G * S * Lk
     return pl.pallas_call(
-        functools.partial(_attention_kernel, tq=tq, tk=tk, bk=bk, groups=G),
+        functools.partial(_attention_kernel, tq=tq, tk=tk, bk=bk, groups=G,
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(Hkv, S // tq, Lk // tk),
+            grid=(Hkv, S // tq, steps),
             in_specs=[q_block, kv_block, kv_block],
             out_specs=q_block,
             scratch_shapes=[
@@ -207,7 +297,8 @@ def _attention_call(q, k, v, pos0, *, interpret: bool):
             bytes_accessed=2 * (2 * q.size + k.size + v.size),
             transcendentals=pairs // 2,
         ),
-        name="causal_gqa_attention",
+        name="causal_gqa_attention" if window is None
+        else "window_gqa_attention",
         interpret=interpret,
     )(pos0.reshape(1).astype(jnp.int32), q, k, v)
 
@@ -234,3 +325,30 @@ def causal_attention(
         return _attention_call(q, k, v, pos0,
                                interpret=resolve_interpret(interpret))
     return _attention_jnp(q, k, v, pos0).astype(q.dtype)
+
+
+@part("mixer")
+def window_attention(
+    q: jax.Array,          # [Hkv, G, S, D]       rotated and SCALED queries
+    k: jax.Array,          # [Hkv, window + S, D] the window keys before the
+    v: jax.Array,          #   segment, then the segment's own; and the values
+    pos0: jax.Array,       # int32 scalar: position of the segment's first token
+    *,
+    window: int,
+    pallas: Optional[bool] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """softmax over the keys ``max(0, t - window + 1) .. t`` of ``q_t . k``,
+    times ``v`` → ``[Hkv, G, S, D]``. Key ``j`` of ``k`` is the document's
+    key ``pos0 - window + j``: where that is negative (a document's first
+    segment) whatever the array holds there is never attended."""
+    S, D = q.shape[2:]
+    before = jnp.maximum(window - pos0, 0).astype(jnp.int32)
+    if pallas is None:
+        pallas = jax.default_backend() == "tpu"
+    if pallas and window_supported(S, window, D, q.dtype):
+        from agent_tpu.kernels.flash_attention import resolve_interpret
+
+        return _attention_call(q, k, v, before, window=window,
+                               interpret=resolve_interpret(interpret))
+    return _window_jnp(q, k, v, before, window).astype(q.dtype)

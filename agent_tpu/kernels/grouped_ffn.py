@@ -24,8 +24,12 @@ row ``j * S + token``), so :func:`combine_pairs` reads them in one pass.
 ``tile_expert`` and ``n_tiles`` are prefetched too: a step past the last
 tile names that tile's blocks again (no copy) and computes nothing, so the
 program is fixed-shape at the worst case and costs what the routed rows
-cost. At about 128 rows an expert a segment the layer is bound by reading
-the experts' weights once (PERF.md section 5).
+cost. What bounds it is the caller's rows an expert a segment: at about 128
+(a held SHARE of a wide router: deepseek-v3.2, mistral-small-4-119b) the
+read of the experts' weights once, for tiles half empty; at 512 (every expert
+held, 8 pairs a token: mellum2-12b-a2.5b) the MXU, on full tiles (PERF.md
+section 5). The expert's width is walked in steps of :func:`width_step`
+columns.
 
 The weights are read WHERE THEY LIE: the operands are the model's stacked
 leaves ``[L, E, ...]`` and one more prefetched scalar, ``layer``, is the
@@ -56,9 +60,31 @@ _VMEM_LIMIT = 100 * 1024 * 1024
 _COMBINE_BLOCK_BYTES = 8 * 1024 * 1024
 
 
+# A width that is no whole number of ``WIDTH_TILE`` is walked in ONE step
+# where it is whole lane tiles and at most this wide (the three weight blocks
+# of a step, double-buffered, are 12 d f bytes: 25 MB at 2,304 x 896).
+_ONE_STEP_WIDTH = 1024
+
+
+def width_step(d_expert: int) -> int:
+    """Columns of the expert's width a grid step walks: ``WIDTH_TILE`` where
+    the width is whole tiles of it (2,048: eight steps); a width of whole lane
+    tiles that is not (896 = 7 x 128) in one step, its weight blocks fetched
+    once an EXPERT and not once a tile (consecutive tiles of one expert name
+    the same blocks); 0 where the kernel cannot walk it."""
+    if d_expert % WIDTH_TILE == 0:
+        return WIDTH_TILE
+    if d_expert % 128 == 0 and d_expert <= _ONE_STEP_WIDTH:
+        return d_expert
+    return 0
+
+
 def pallas_supported(d_model: int, d_expert: int, dtype) -> bool:
+    """bf16 rows whose two halves are whole lane tiles (a row travels as
+    words: column ``c`` beside column ``c + d / 2``), and a width the kernel's
+    second grid axis can walk (:func:`width_step`)."""
     return bool(jnp.dtype(dtype) == jnp.bfloat16 and d_model % 256 == 0
-                and d_expert % WIDTH_TILE == 0)
+                and width_step(d_expert) > 0)
 
 
 # A row travels as 32-bit words: the DMA engine addresses ONE row of a
@@ -288,7 +314,8 @@ def grouped_swiglu(x, token, slot, tile_expert, tile_rows, w_gate, w_up,
     fe = w_gate.shape[-1]
     i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
     tile_rows = i32(tile_rows)
-    tm, tf = token.shape[0] // tile_rows.shape[0], min(WIDTH_TILE, fe)
+    tm = token.shape[0] // tile_rows.shape[0]
+    tf = width_step(fe) or min(WIDTH_TILE, fe)
     n_f = fe // tf
     if w_gate.ndim == 3:                   # one layer: a stack of one
         w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]

@@ -37,7 +37,18 @@ one to the next (:func:`forward_segment`):
   segment expands the latents it can see into per-head joined keys ``[c W_UK
   | kR]`` and values for one layer at a time
   (``kernels/sparse_mla.py: expand_latents``) and attends them with
-  ``kernels/causal_attention.py`` at one query head a key head.
+  ``kernels/causal_attention.py`` at one query head a key head;
+- ``window_gqa`` (plain grouped-query softmax attention with a per-head RMS
+  norm on queries and keys) is the one mixer whose layers come in KINDS
+  (:func:`layer_kinds`): of every ``full_attention_every`` layers the last
+  attends every causal key under YaRN's rotary table and factor, the others
+  the last ``sliding_window`` keys under the plain table. Both kinds hold
+  the same leaves, stacked as every layer is; the layer scan steps over
+  PERIODS and its body runs a period's layers one after another. The state
+  has two shapes side by side: a full layer's keys and values at the
+  document's padded length, a window layer's LAST ``sliding_window`` only
+  (a segment attends ``[the carried tail | its own keys]`` and hands on the
+  last ``sliding_window`` of them).
 
 Layers are stacked by group (the leading dense layers, then the expert
 layers) and each group is scanned; embedding and output head are untied. An
@@ -57,8 +68,8 @@ from ``fold_in(root, j)``, a per-layer leaf for layer ``i`` (counted over
 both groups) from ``fold_in(fold_in(root, j), i)``, expert ``e`` (its id among
 all ``n_experts``) of that layer from one more ``fold_in(., e)``; a standard
 normal in float32 times ``1/sqrt(fan_in)`` (embedding: 1), rounded once to the
-stored dtype. Norm weights are 1, the router's bias and the index keys'
-LayerNorm bias 0. ``hybrid_ssm`` multiplies a leaf's ``1/sqrt(fan_in)`` by a
+stored dtype. Norm weights are 1 (``window_gqa``'s per-head query norm:
+``QUERY_NORM_GAIN``), the router's bias and the index keys' LayerNorm bias 0. ``hybrid_ssm`` multiplies a leaf's ``1/sqrt(fan_in)`` by a
 gain (:func:`_leaf_gains`: the inverse of the multipliers on the leaf's
 output, 4 on the queries) and sets the scan's ``A_log``, ``D`` and ``dt_bias``
 by rule (:func:`_layer_constants`). New leaves are APPENDED to ``LEAVES``: a
@@ -105,6 +116,7 @@ MIXER_LEAVES = {
     "dense_mla": ("wo", "w_dq", "w_uq", "w_dkv", "w_ukv"),
     "hybrid_ssm": ("wq", "wk", "wv", "wo", "w_ssm_in", "w_ssm_out", "conv_w",
                    "conv_b"),
+    "window_gqa": ("wq", "wk", "wv", "wo"),
 }
 FFN_LEAVES = {
     "dense": ("w_gate", "w_up", "w_down"),
@@ -117,6 +129,15 @@ GATE_TAU0 = 16.0
 # is spaced over.
 QUERY_GAIN = 4.0
 SSM_DT_RANGE = (0.001, 0.1)
+# window_gqa's weight rule: the weight of the per-head RMS norm on the
+# queries (every other norm's is 1). Normed queries and keys score with this
+# spread (times YaRN's factor squared, 1.63, on a full layer): at 1 a softmax
+# over a thousand keys averages them and attention enters the residual at a
+# twentieth; at ``QUERY_GAIN``'s 4 one key takes a query's weight, bf16's
+# rounding of a score decides WHICH, and twelve such layers in bf16 leave the
+# float32 reference by 0.02 nats a token a block, as far as a layer that
+# dropped its window does (PERF.md section 6, PR 42).
+QUERY_NORM_GAIN = 2.0
 # Tokens a loss block: the op reports the log-probability summed a block.
 LOSS_BLOCK = 1024
 # Vocabulary rows a block of the loss head: [segment, VOCAB_BLOCK] float32
@@ -201,6 +222,12 @@ class DecoderLMConfig:
     ssm_dt_multiplier: float = 1.0
     mlp_gate_multiplier: float = 1.0
     mlp_down_multiplier: float = 1.0
+    # window_gqa: of every ``full_attention_every`` layers the last attends
+    # every causal key (rotary positions under YaRN: the ``rope_*`` keys
+    # above, its factor on queries AND keys), the others the last
+    # ``sliding_window`` keys (the plain table of ``rope_theta``).
+    sliding_window: int = 1024
+    full_attention_every: int = 4
 
     @property
     def compute_dtype(self):
@@ -239,10 +266,23 @@ class DecoderLMConfig:
 
 
 def _holds(cfg: DecoderLMConfig, leaf: str) -> bool:
-    """Whether the config's mixer holds ``leaf``: ``w_dkv`` says its
+    """Whether the config's layers hold ``leaf``: ``w_dkv`` says the mixer's
     queries, keys and values come through latents, ``wi_k`` that it has an
-    indexer. What a mixer IS is asked of its leaves, not of its name."""
+    indexer, ``wg`` that it has a gate; ``ws_gate`` that an expert layer has
+    a shared expert. What a layer IS is asked of its leaves, not of a name."""
+    if leaf in FFN_LEAVES["experts"]:
+        return bool(cfg.n_experts and (cfg.n_shared_experts
+                                       or not leaf.startswith("ws_")))
     return leaf in MIXER_LEAVES[cfg.mixer]
+
+
+def layer_kinds(cfg: DecoderLMConfig) -> Tuple[str, ...]:
+    """The kinds of one PERIOD's layers, in order, where a model's layers
+    come in kinds (``window_gqa``: ``full_attention_every - 1`` window layers,
+    then a full one); ``()`` where every layer is alike."""
+    if cfg.mixer != "window_gqa":
+        return ()
+    return ("window",) * (cfg.full_attention_every - 1) + ("full",)
 
 
 def validate(cfg: DecoderLMConfig) -> None:
@@ -250,7 +290,7 @@ def validate(cfg: DecoderLMConfig) -> None:
     if cfg.mixer not in MIXERS:
         raise ValueError(f"mixer must be one of {sorted(MIXERS)}, "
                          f"got {cfg.mixer!r}")
-    if cfg.mixer in ("power_retention", "hybrid_ssm"):
+    if cfg.mixer in ("power_retention", "hybrid_ssm", "window_gqa"):
         if cfg.n_kv_heads <= 0 or cfg.n_heads % cfg.n_kv_heads:
             raise ValueError("n_heads must be a multiple of n_kv_heads")
         if cfg.d_head % 2:
@@ -274,8 +314,18 @@ def validate(cfg: DecoderLMConfig) -> None:
                      if f.name.endswith("_multiplier")]
         if cfg.ssm_n_heads % max(1, cfg.ssm_n_groups):
             raise ValueError("ssm_n_heads must be whole ssm_n_groups")
+    if layer_kinds(cfg):
+        positive += ["sliding_window", "full_attention_every"]
+        if cfg.n_layers % max(1, cfg.full_attention_every):
+            raise ValueError("n_layers must be whole periods of "
+                             "full_attention_every layers")
+        if cfg.n_experts and cfg.n_dense_layers:
+            raise ValueError("layers in kinds are stacked as one group: "
+                             "n_dense_layers must be 0")
     if cfg.n_experts:
         positive += ["n_experts_held", "d_expert", "n_experts_per_token"]
+        if cfg.n_shared_experts < 0:
+            raise ValueError("n_shared_experts must not be negative")
         if cfg.scoring_func not in ("sigmoid", "softmax"):
             raise ValueError("scoring_func must be 'sigmoid' or 'softmax', "
                              f"got {cfg.scoring_func!r}")
@@ -414,9 +464,15 @@ def _layer_constants(cfg: DecoderLMConfig, ffn: str) -> Dict[str, Tuple]:
             ssm_norm=(1.0, (h * cfg.ssm_d_head,)),
             A_log=(np.log(np.arange(1, h + 1, dtype=np.float32)), (h,)),
             D=(1.0, (h,)), dt_bias=(ssm_dt_bias(h), (h,)))
-    else:
+    elif _holds(cfg, "wg"):
         out.update(bg=(gate_bias(cfg.n_kv_heads), (cfg.n_kv_heads,)),
                    q_norm=(1.0, (cfg.d_head,)), k_norm=(1.0, (cfg.d_head,)))
+    else:
+        # Normed queries and keys of weight 1 score with unit spread, and a
+        # softmax over thousands of such keys averages them (the lesson of
+        # ``_leaf_gains``): the query norm's weight is ``QUERY_NORM_GAIN``.
+        out.update(q_norm=(QUERY_NORM_GAIN, (cfg.d_head,)),
+                   k_norm=(1.0, (cfg.d_head,)))
     if ffn == "experts" and cfg.scoring_func == "sigmoid":
         out["router_bias"] = (0.0, (cfg.n_experts,))
     return out
@@ -477,7 +533,8 @@ def init_params(cfg: DecoderLMConfig, model_id: str, sharding=None) -> Params:
         params[group] = {
             **{name: draw(name, first, n,
                           held if name in EXPERT_LEAVES else None)
-               for name in MIXER_LEAVES[cfg.mixer] + FFN_LEAVES[ffn]},
+               for name in MIXER_LEAVES[cfg.mixer] + FFN_LEAVES[ffn]
+               if ffn == "dense" or _holds(cfg, name)},
             **{name: const(value, (n, *shape)) for name, (value, shape)
                in _layer_constants(cfg, ffn).items()},
         }
@@ -553,14 +610,21 @@ def _power_retention_mixer(p: Params, h: jax.Array, positions: jax.Array,
     return _project(p["wo"], y, dtype), state
 
 
-def yarn_inv_freq(cfg: DecoderLMConfig) -> np.ndarray:
-    """Inverse frequencies of the ``qk_rope_head_dim / 2`` rotary pairs under
+def plain_inv_freq(base: float, dim: int) -> np.ndarray:
+    """``base^(-2i / dim)`` for the ``dim / 2`` rotary pairs, float64."""
+    return 1.0 / (base ** (np.arange(0, dim, 2, dtype=np.float64) / dim))
+
+
+def yarn_inv_freq(cfg: DecoderLMConfig,
+                  dim: Optional[int] = None) -> np.ndarray:
+    """Inverse frequencies of the ``dim / 2`` rotary pairs (``dim``: latent
+    attention's ``qk_rope_head_dim`` unless given) under
     YaRN (the DeepSeek inference code's ``precompute_freqs_cis``): below the
     ``beta_fast`` rotations correction dimension untouched, above the
     ``beta_slow`` one divided by ``rope_factor``, a linear ramp between.
     Applied whenever ``max_len`` exceeds the original length."""
-    dim, base = cfg.qk_rope_head_dim, float(cfg.rope_theta)
-    inv = 1.0 / (base ** (np.arange(0, dim, 2, dtype=np.float64) / dim))
+    dim, base = dim or cfg.qk_rope_head_dim, float(cfg.rope_theta)
+    inv = plain_inv_freq(base, dim)
     if cfg.rope_factor == 1.0 or cfg.max_len <= cfg.rope_original_max_len:
         return inv.astype(np.float32)
 
@@ -577,13 +641,19 @@ def yarn_inv_freq(cfg: DecoderLMConfig) -> np.ndarray:
         np.float32)
 
 
+def yarn_mscale(cfg: DecoderLMConfig) -> float:
+    """YaRN's attention factor, ``0.1 rope_mscale ln(rope_factor) + 1``,
+    where the positions are scaled; else 1."""
+    if cfg.rope_factor != 1.0 and cfg.max_len > cfg.rope_original_max_len:
+        return 0.1 * cfg.rope_mscale * np.log(cfg.rope_factor) + 1.0
+    return 1.0
+
+
 def softmax_scale(cfg: DecoderLMConfig) -> float:
     """``(nope + rope)^-0.5``, times YaRN's ``mscale`` squared where the
     positions are scaled: ``mscale = 0.1 rope_mscale ln(factor) + 1``."""
-    scale = float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
-    if cfg.rope_factor != 1.0 and cfg.max_len > cfg.rope_original_max_len:
-        scale *= (0.1 * cfg.rope_mscale * np.log(cfg.rope_factor) + 1.0) ** 2
-    return scale
+    return float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * (
+        yarn_mscale(cfg) ** 2)
 
 
 @part("around")
@@ -849,19 +919,101 @@ def _hybrid_ssm_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
                                cfg.ssm_conv_dim), f32)}
 
 
+def kind_rotary(cfg: DecoderLMConfig, kind: str) -> Tuple[np.ndarray, float]:
+    """``(inverse frequencies [d_head / 2], factor)`` of a layer kind's
+    rotary positions: YaRN's table and its attention factor (on cos AND sin:
+    queries and keys both carry it, the scores its square) on a ``full``
+    layer, the plain table of ``rope_theta`` and 1 on a ``window`` layer."""
+    if kind == "full":
+        return yarn_inv_freq(cfg, cfg.d_head), float(yarn_mscale(cfg))
+    return plain_inv_freq(float(cfg.rope_theta), cfg.d_head).astype(
+        np.float32), 1.0
+
+
+@part("around")
+def _window_gqa_mixer(p: Params, h: jax.Array, positions: jax.Array,
+                      state, cfg: DecoderLMConfig, kernel_opts, kind: str):
+    """h [1, S, d] (normed) → (what enters the residual [1, S, d], the
+    layer's state). Grouped-query softmax attention, queries and keys
+    RMS-normed a head and rotated by halves under the KIND's table and factor
+    (:func:`kind_rotary`); the softmax scale goes into the rotated queries
+    before they are rounded. ``state``: ``{"k", "v": [1, Hkv, n, D]}``. A
+    ``full`` layer's is the document's cache (``n`` its padded length),
+    written at the segment's positions and attended up to each query. A
+    ``window`` layer's is the LAST ``sliding_window`` keys and values before
+    the segment: the segment attends ``[that tail | its own]``, each query
+    the ``sliding_window`` keys up to itself, and hands on the last
+    ``sliding_window`` of them. One document a program."""
+    from agent_tpu.kernels import causal_attention
+
+    if h.shape[0] != 1:
+        raise ValueError("window_gqa runs one document a program")
+    dtype, f32, eps = cfg.compute_dtype, jnp.float32, cfg.rms_norm_eps
+    h = h[0]
+    S = h.shape[0]
+    pos0 = positions[0]
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    inv, factor = kind_rotary(cfg, kind)
+
+    def turned(x, norm, scale):
+        x = rms_norm(x.astype(f32), norm, eps)
+        return (rope_pairs(x, positions, inv, False) * scale).astype(dtype)
+
+    q = turned(_project(p["wq"], h, dtype).reshape(S, hq, dh), p["q_norm"],
+               factor * float(dh) ** -0.5)
+    k = turned(_project(p["wk"], h, dtype).reshape(S, hkv, dh), p["k_norm"],
+               factor).transpose(1, 0, 2)
+    v = _project(p["wv"], h, dtype).reshape(S, hkv, dh).transpose(1, 0, 2)
+    q = q.reshape(S, hkv, hq // hkv, dh).transpose(1, 2, 0, 3)
+    if kind == "full":
+        kc = jax.lax.dynamic_update_slice(state["k"][0], k, (0, pos0, 0))
+        vc = jax.lax.dynamic_update_slice(state["v"][0], v, (0, pos0, 0))
+        o = causal_attention.causal_attention(q, kc, vc, pos0, **kernel_opts)
+    else:
+        window = cfg.sliding_window
+        kc = jnp.concatenate([state["k"][0], k], axis=1)
+        vc = jnp.concatenate([state["v"][0], v], axis=1)
+        o = causal_attention.window_attention(q, kc, vc, pos0, window=window,
+                                              **kernel_opts)
+        kc, vc = kc[:, -window:], vc[:, -window:]
+    o = o.transpose(2, 0, 1, 3).reshape(1, S, hq * dh)
+    return _project(p["wo"], o, dtype), {"k": kc[None], "v": vc[None]}
+
+
+def _window_gqa_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
+    """Before a document's first token, by KIND: a full layer's empty key and
+    value cache of ``cache_len`` (padded) tokens, a window layer's
+    ``sliding_window`` keys and values that lie before the document (never
+    attended: the window kernel masks by position), in the stored dtype."""
+    kinds = layer_kinds(cfg)
+    periods = cfg.n_layers // len(kinds)
+
+    def empty(kind, keys):
+        shape = (periods * kinds.count(kind), batch, cfg.n_kv_heads, keys,
+                 cfg.d_head)
+        return {"k": jnp.zeros(shape, cfg.compute_dtype),
+                "v": jnp.zeros(shape, cfg.compute_dtype)}
+
+    return {"window": empty("window", cfg.sliding_window),
+            "full": empty("full", cache_len)}
+
+
 # mixer name → fn(layer params, normed h, positions, state, cfg, opts)
-# → (what enters the residual [B, L, d], new state). One entry a sequence
-# mixer; the out-projection is the mixer's (one has two).
+# → (what enters the residual [B, L, d], new state); a mixer whose layers
+# come in kinds (``layer_kinds``) takes the layer's ``kind`` besides. One
+# entry a sequence mixer; the out-projection is the mixer's (one has two).
 MIXERS: Dict[str, Callable] = {"power_retention": _power_retention_mixer,
                                "sparse_mla": _sparse_mla_mixer,
                                "hybrid_ssm": _hybrid_ssm_mixer,
-                               "dense_mla": _dense_mla_mixer}
+                               "dense_mla": _dense_mla_mixer,
+                               "window_gqa": _window_gqa_mixer}
 # mixer name → fn(cfg, batch, cache_len) → the state before a document's
 # first segment, for the mixers whose state is allocated (a cache); the
 # others start from ``None``.
 MIXER_STATES: Dict[str, Callable] = {"sparse_mla": _sparse_mla_state,
                                      "hybrid_ssm": _hybrid_ssm_state,
-                                     "dense_mla": _dense_mla_state}
+                                     "dense_mla": _dense_mla_state,
+                                     "window_gqa": _window_gqa_state}
 
 
 def starts_from_nothing(cfg: DecoderLMConfig) -> bool:
@@ -873,12 +1025,15 @@ def starts_from_nothing(cfg: DecoderLMConfig) -> bool:
 def init_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
     """What :func:`forward_segment` takes as ``state`` for a document's
     FIRST segment: ``None`` where the mixer starts from nothing and no layer
-    routes; else ``{"mixer": the mixer's empty state or None, "pairs": 0}``
+    routes; else ``{"mixer": the mixer's empty state or None, "pairs": 0}``,
+    with ``"tiles": 0`` beside them where the layers come in kinds
     (see :func:`forward_segment`)."""
     make = MIXER_STATES.get(cfg.mixer)
     mixer = make(cfg, batch, cache_len) if make else None
     if cfg.n_experts:
-        return {"mixer": mixer, "pairs": jnp.zeros((), jnp.float32)}
+        zero = jnp.zeros((), jnp.float32)
+        return {"mixer": mixer, "pairs": zero,
+                **({"tiles": zero} if layer_kinds(cfg) else {})}
     return mixer
 
 
@@ -904,8 +1059,11 @@ def _plain_weights(leaf: Any, dtype) -> jax.Array:
 
 @part("experts")
 def _experts_ffn(p: Params, n: jax.Array, cfg: DecoderLMConfig, kernel_opts):
-    """n [B, S, d] (normed) → (shared expert + the routed experts held here,
-    [B, S, d]; the (token, expert) pairs routed here). ``p``: one layer's
+    """n [B, S, d] (normed) → (shared expert, where the model has one, + the
+    routed experts held here, [B, S, d]; the (token, expert) pairs routed
+    here: a float32 scalar, or ``{"pairs", "tiles"}`` where the model's
+    layers come in kinds: the ``ROW_TILE`` tiles those pairs fill beside
+    them). ``p``: one layer's
     leaves; where it has ``expert_layer`` (:func:`_read_in_place`), its
     ``EXPERT_LEAVES`` are the whole group's stacks, already plain and in the
     compute dtype, and that is the layer."""
@@ -932,9 +1090,15 @@ def _experts_ffn(p: Params, n: jax.Array, cfg: DecoderLMConfig, kernel_opts):
         flat.astype(dtype), experts, gates,
         *(_plain_weights(p[name], dtype) for name in EXPERT_LEAVES),
         cfg.expert_first, layer=p.get("expert_layer"), **kernel_opts)
-    shared = _swiglu(p, flat, ("ws_gate", "ws_up", "ws_down"), dtype)
-    y = (shared.astype(jnp.float32) + routed).astype(dtype)
-    return y.reshape(B, S, d), pairs.astype(jnp.float32)
+    if _holds(cfg, "ws_gate"):
+        shared = _swiglu(p, flat, ("ws_gate", "ws_up", "ws_down"), dtype)
+        routed = shared.astype(jnp.float32) + routed
+    y = routed.astype(dtype).reshape(B, S, d)
+    counted = pairs.astype(jnp.float32)
+    if layer_kinds(cfg):
+        counted = {"pairs": counted, "tiles": moe.held_tiles(
+            experts, cfg.expert_first, cfg.n_experts_held).astype(jnp.float32)}
+    return y, counted
 
 
 def _read_in_place(leaves: Params, dtype) -> Tuple[Params, Params]:
@@ -960,10 +1124,11 @@ def _read_in_place(leaves: Params, dtype) -> Tuple[Params, Params]:
 
 
 def _layer(p: Params, x: jax.Array, positions, state, cfg, kernel_opts,
-           ffn: str = "dense"):
+           ffn: str = "dense", kind: Optional[str] = None):
     dtype = cfg.compute_dtype
     h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
-    mixed, state = MIXERS[cfg.mixer](p, h, positions, state, cfg, kernel_opts)
+    mixed, state = MIXERS[cfg.mixer](p, h, positions, state, cfg, kernel_opts,
+                                     **({"kind": kind} if kind else {}))
     with part("around"):
         x = x + mixed
     n = rms_norm(x, p["ln2"], cfg.rms_norm_eps)
@@ -977,24 +1142,11 @@ def _layer(p: Params, x: jax.Array, positions, state, cfg, kernel_opts,
         return x + y, state, jnp.zeros((), jnp.float32)
 
 
-def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
-                    state, cfg: DecoderLMConfig, **kernel_opts):
-    """One fixed-shape segment of a document: ids [B, S] int32, ``pos0`` the
-    position of its first token, ``state`` what the previous segment
-    returned, or :func:`init_state` for the document's first. The mixer's
-    state is a pytree with a leading layer axis (``None``: a mixer that
-    starts from nothing); where the model has expert layers it comes inside
-    ``{"mixer": ..., "pairs": ...}``, ``pairs`` the running count of (token,
-    expert) pairs routed to the experts held here. Returns the final-normed
-    hidden states [B, S, d] and the state after the segment."""
-    with part("embed"):
-        x = _times(params["embed"][ids], cfg.embedding_multiplier,
-                   cfg.compute_dtype)
-    positions = pos0.astype(jnp.int32) + jnp.arange(ids.shape[1])
-    routed = bool(cfg.n_experts)
-    mixer_state = state["mixer"] if routed and state is not None else state
-    pairs = state["pairs"] if routed and state is not None else jnp.zeros(
-        (), jnp.float32)
+def _scan_layers(params: Params, x: jax.Array, positions, mixer_state, pairs,
+                 cfg: DecoderLMConfig, kernel_opts):
+    """The layer scan of a model whose layers are all alike: group by group
+    (``layer_groups``), one step a layer, its slice of the state scanned
+    beside its leaves. Returns ``(x, the new mixer state, pairs)``."""
     new_states = []
     for group, ffn, first, n in cfg.layer_groups:
         with part("around"):
@@ -1021,11 +1173,100 @@ def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
         mixer_state = new_states[0] if len(new_states) == 1 else \
             jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, 0),
                                    *new_states)
+    return x, mixer_state, pairs
+
+
+def _scan_periods(leaves: Params, x: jax.Array, positions, mixer_state,
+                  counted, cfg: DecoderLMConfig, kernel_opts, ffn: str):
+    """The layer scan of a model whose layers come in kinds: one step a
+    PERIOD (:func:`layer_kinds`), its body the period's layers one after
+    another, each with its kind's share of the state. ``leaves``: the one
+    group's stacked leaves ``[layers, ...]``, seen as ``[periods, a period's
+    layers, ...]`` (a bitcast); the expert stacks stay whole and are read in
+    place by the layer's number (:func:`_read_in_place`: ``period x a
+    period's layers + place``). ``mixer_state``: ``{kind: leaves [that
+    kind's layers, ...]}``, stepped over a period's layers of the kind.
+    ``counted``: what the expert layers count, added up along the way.
+    Returns ``(x, the new mixer state, counted)``."""
+    kinds = layer_kinds(cfg)
+    every = len(kinds)
+
+    def by_period(tree, n):
+        return jax.tree_util.tree_map(
+            lambda a: a.reshape(a.shape[0] // n, n, *a.shape[1:]), tree)
+
+    with part("around"):
+        scanned, whole = _read_in_place(leaves, cfg.compute_dtype)
+        xs = (by_period(scanned, every),
+              {kind: by_period(mixer_state[kind], kinds.count(kind))
+               for kind in sorted(set(kinds))})   # one order, one text
+
+    def step(carry, xs):
+        x, counted = carry
+        period, states = xs
+        seen = {kind: 0 for kind in states}
+        new = {kind: [] for kind in states}
+        for place, kind in enumerate(kinds):
+            with part("around"):
+                p, st = jax.tree_util.tree_map(
+                    lambda a, at=place: a[at], period), jax.tree_util.tree_map(
+                    lambda a, at=seen[kind]: a[at], states[kind])
+            x, st, more = _layer({**p, **whole}, x, positions, st, cfg,
+                                 kernel_opts, ffn, kind)
+            counted = jax.tree_util.tree_map(jnp.add, counted, more)
+            seen[kind] += 1
+            new[kind].append(st)
+        with part("around"):
+            return (x, counted), {kind: jax.tree_util.tree_map(
+                lambda *a: jnp.stack(a), *sts) for kind, sts in new.items()}
+
+    with part("around"):
+        (x, counted), new = jax.lax.scan(step, (x, counted), xs)
+        new = jax.tree_util.tree_map(
+            lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), new)
+    return x, new, counted
+
+
+def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
+                    state, cfg: DecoderLMConfig, **kernel_opts):
+    """One fixed-shape segment of a document: ids [B, S] int32, ``pos0`` the
+    position of its first token, ``state`` what the previous segment
+    returned, or :func:`init_state` for the document's first. The mixer's
+    state is a pytree with a leading layer axis (``None``: a mixer that
+    starts from nothing); where the model has expert layers it comes inside
+    ``{"mixer": ..., "pairs": ...}``, ``pairs`` the running count of (token,
+    expert) pairs routed to the experts held here (a model whose layers come
+    in kinds has ``tiles`` beside it: the tiles the grouped matmul visited for
+    them, and its ``mixer`` is ``{kind: leaves}``, :func:`_scan_periods`).
+    Returns the final-normed
+    hidden states [B, S, d] and the state after the segment."""
+    with part("embed"):
+        x = _times(params["embed"][ids], cfg.embedding_multiplier,
+                   cfg.compute_dtype)
+    positions = pos0.astype(jnp.int32) + jnp.arange(ids.shape[1])
+    routed = bool(cfg.n_experts)
+    mixer_state = state["mixer"] if routed and state is not None else state
+    pairs = state["pairs"] if routed and state is not None else jnp.zeros(
+        (), jnp.float32)
+    if layer_kinds(cfg):
+        if state is None:
+            raise ValueError("layers in kinds start from init_state")
+        counted = ({k: v for k, v in state.items() if k != "mixer"}
+                   if routed else pairs)
+        (group, ffn, _, _), = cfg.layer_groups
+        x, mixer_state, counted = _scan_periods(
+            params[group], x, positions, mixer_state, counted, cfg,
+            kernel_opts, ffn)
+    else:
+        x, mixer_state, pairs = _scan_layers(params, x, positions,
+                                             mixer_state, pairs, cfg,
+                                             kernel_opts)
+        counted = {"pairs": pairs}
     # The head's multiplier goes into the hidden states: for a power of two
     # (the published 2^-7) the logits are the same numbers, bit for bit.
     hidden = _times(rms_norm(x, params["final_norm"], cfg.rms_norm_eps),
                     cfg.lm_head_multiplier, cfg.compute_dtype)
-    return hidden, ({"mixer": mixer_state, "pairs": pairs} if routed
+    return hidden, ({"mixer": mixer_state, **counted} if routed
                     else mixer_state)
 
 
@@ -1071,12 +1312,27 @@ def _hybrid_flops(cfg: DecoderLMConfig, t: float, pos0: int):
         2.0 * c * P + 4.0 * N * P) + 2.0 * cfg.ssm_n_groups * c * N
 
 
+def _window_gqa_flops(cfg: DecoderLMConfig, t: float, pos0: int):
+    d = float(cfg.d_model)
+    dh, hq, hkv = cfg.d_head, cfg.n_heads, cfg.n_kv_heads
+    # A layer's score and value products by its KIND: every causal pair on a
+    # full layer, the window's on the others (``min(position + 1,
+    # sliding_window)`` keys a token); the mean over a period's layers.
+    kinds, w = layer_kinds(cfg), cfg.sliding_window
+    ramp = max(0, min(pos0 + int(t), w) - pos0)     # tokens still under w keys
+    in_window = (ramp * (2 * pos0 + ramp + 1) / 2.0 + (t - ramp) * w) / t
+    keys = {"full": pos0 + t / 2.0, "window": in_window}
+    return (2.0 * d * (2 * hq * dh + 2 * hkv * dh),
+            4.0 * hq * dh * sum(keys[kind] for kind in kinds) / len(kinds))
+
+
 # mixer name → fn(cfg, tokens, pos0) → (projections', mixer's own) FLOPs a
 # token a layer of a segment of ``tokens`` that starts at ``pos0``.
 MIXER_FLOPS: Dict[str, Callable] = {"power_retention": _retention_flops,
                                     "sparse_mla": _latent_flops,
                                     "hybrid_ssm": _hybrid_flops,
-                                    "dense_mla": _latent_flops}
+                                    "dense_mla": _latent_flops,
+                                    "window_gqa": _window_gqa_flops}
 
 
 def segment_flops(cfg: DecoderLMConfig, n_tokens: int, pos0: int) -> float:

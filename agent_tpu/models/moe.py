@@ -280,6 +280,19 @@ def _held_dense(x, local, gates, w_gate, w_up, w_down):
                              jnp.zeros(x.shape, f32))
 
 
+def held_tiles(experts: jax.Array, first: int, n_held: int) -> jax.Array:
+    """How many ``ROW_TILE`` tiles the pairs of ``experts [S, k]`` routed to
+    the experts ``first .. first + n_held - 1`` fill, each expert's rows
+    padded to whole tiles: the tiles :func:`held_experts_ffn`'s grouped
+    matmul visits for them (int32 scalar; pairs over tiles x ``ROW_TILE`` is
+    how full they are)."""
+    from agent_tpu.kernels.grouped_ffn import ROW_TILE
+
+    local = (experts - first).reshape(-1)
+    counts = (local[None, :] == jnp.arange(n_held)[:, None]).sum(axis=1)
+    return ((counts + ROW_TILE - 1) // ROW_TILE).sum()
+
+
 @part("experts")
 def held_experts_ffn(x: jax.Array, experts: jax.Array, gates: jax.Array,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,

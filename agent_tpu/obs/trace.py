@@ -692,6 +692,23 @@ def record_causal_attention_pairs(kind: str, pairs: int) -> None:
                float(pairs), kind=kind)
 
 
+def record_window_attention_pairs(kind: str, pairs: int) -> None:
+    """(query, key) pairs of a DISPATCHED shard under windowed causal
+    attention, a query head a window layer: those its real tokens need
+    (``window``: ``min(t + 1, window)`` for token ``t``) and those in the key
+    tiles the window kernel's grid visits (``computed``: whole tiles from
+    the window's lower edge to the diagonal, padding included). Counted by
+    the op from the documents' lengths, the window and the kernel's tile
+    sizes."""
+    if pairs > 0:
+        _count("window_attention_pairs_total",
+               "(query, key) pairs of the tokens dispatched to a windowed "
+               "attention layer, a query head a layer: inside the real "
+               "tokens' windows (window) and in the key tiles the kernel's "
+               "grid visits (computed)",
+               float(pairs), kind=kind)
+
+
 def record_latent_keys(kind: str, keys: int) -> None:
     """Latents of a DISPATCHED shard under a mixer that caches latents and
     expands them to keys and values inside its programs, a layer: the real
@@ -720,6 +737,18 @@ def record_moe_routing(pairs: float, tokens: int) -> None:
         _count("moe_tokens_total",
                "Token slots dispatched to expert layers (tokens x expert "
                "layers, padding included)", float(tokens))
+
+
+def record_moe_tiles(tiles: float) -> None:
+    """Row tiles the grouped expert matmul visited for a FETCHED shard (an
+    expert's rows padded to whole tiles; counted on the device beside the
+    pairs, fetched with the shard's answer): ``moe_expert_pairs_total`` over
+    this times the rows a tile is how full the tiles were."""
+    if tiles > 0:
+        _count("moe_tiles_total",
+               "Row tiles the grouped expert matmul visited (every held "
+               "expert's rows padded to whole tiles), over all expert "
+               "layers", float(tiles))
 
 
 def record_lm_segments(op: str, segments: int) -> None:

@@ -38,7 +38,13 @@ the host. What that state is depends on the mixer (``models/decoder_lm.py``):
 - ``dense_mla``: a CACHE of latents only (``sparse_mla``'s without the index
   keys), allocated, donated and keyed the same way; keys and values are
   expanded from it inside a segment program, a layer at a time, and never
-  leave it. Where the model has
+  leave it;
+- ``window_gqa``: a state in TWO SHAPES, by the layer's kind: a full
+  layer's key and value cache at the document's padded length (allocated,
+  donated and keyed as ``hybrid_ssm``'s), a window layer's LAST
+  ``sliding_window`` keys and values only, whatever the document's length;
+  such a model also counts the tiles its grouped expert matmul visits.
+  Where the model has
   expert layers the state also carries the count of (token, expert) pairs
   routed to the experts held here; it comes back with the block sums in the
   one fetch.
@@ -313,11 +319,40 @@ def _record_dense_latent(state: Dict[str, Any]) -> None:
     obs_trace.record_latent_keys("cached", cached)
 
 
+def _record_window_gqa(state: Dict[str, Any]) -> None:
+    """A query head a layer of each kind: on a full layer the causal pairs a
+    shard's real tokens need beside those in the key tiles the attention
+    kernel's grid visits (at the query tile the model's heads a key head
+    take); on a window layer the pairs inside their windows (``min(t + 1,
+    sliding_window)`` for token ``t``) beside those in the tiles the window
+    kernel's grid visits."""
+    from agent_tpu.kernels.causal_attention import (
+        query_tile, visited_pairs, window_visited_pairs)
+
+    cfg = state["cfg"]
+    w, groups = int(cfg.sliding_window), cfg.n_heads // cfg.n_kv_heads
+    causal = computed = in_window = window_computed = 0
+    for doc in state["docs"]:
+        n, ramp = doc["n_tokens"], min(doc["n_tokens"], w)
+        causal += n * (n + 1) // 2
+        in_window += ramp * (ramp + 1) // 2 + (n - ramp) * w
+        for ids, _, _, pos0 in doc["segments"]:
+            bucket = ids.shape[1]
+            tq = query_tile(groups, bucket)
+            computed += visited_pairs(bucket, pos0, tq)
+            window_computed += window_visited_pairs(bucket, pos0, w, tq)
+    obs_trace.record_causal_attention_pairs("causal", causal)
+    obs_trace.record_causal_attention_pairs("computed", computed)
+    obs_trace.record_window_attention_pairs("window", in_window)
+    obs_trace.record_window_attention_pairs("computed", window_computed)
+
+
 # mixer → what the op counts of a shard at dispatch, from its lengths.
 _MIXER_COUNTERS = {"power_retention": _record_retention,
                    "sparse_mla": _record_sparse_keys,
                    "hybrid_ssm": _record_hybrid,
-                   "dense_mla": _record_dense_latent}
+                   "dense_mla": _record_dense_latent,
+                   "window_gqa": _record_window_gqa}
 
 
 def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, Any]:
@@ -344,7 +379,7 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
     )
     put = lambda a: jax.device_put(a, runtime.replicated())  # noqa: E731
     programs: Dict[Tuple[int, int], Tuple] = {}   # (bucket, cache) -> programs
-    parts, routed, layout = [], [], []
+    parts, routed, tiles, layout = [], [], [], []
     flops = 0.0
     for doc in state["docs"]:
         padded = sum(seg[0].shape[1] for seg in doc["segments"])
@@ -366,6 +401,8 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
             flops += segment_flops(cfg, bucket, pos0)
         if cfg.n_experts:
             routed.append(carried["pairs"].reshape(1))
+            if "tiles" in carried:
+                tiles.append(carried["tiles"].reshape(1))
         layout.append((padded, doc["n_tokens"]))
     dispatched = sum(padded for padded, _ in layout)
     obs_trace.record_lm_segments(
@@ -374,13 +411,15 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
     stamp_device_flops(
         ctx, flops,
         f"B1xS{max(s[0].shape[1] for d in state['docs'] for s in d['segments'])}")
-    parts += routed
+    parts += routed + tiles
     state.update(
         # One array a shard, gathered on the device by the owner thread:
         # one fetch, not one a segment. Behind the block sums, where the
-        # model routes: a document's (token, expert) pairs held here.
+        # model routes: a document's (token, expert) pairs held here, then
+        # (a model that counts them) the tiles its grouped matmul visited.
         pending_dev=jnp.concatenate(parts) if len(parts) > 1 else parts[0],
         layout=layout, device=runtime.platform, n_routed=len(routed),
+        n_tiles=len(tiles),
         moe_tokens=dispatched * sum(
             n for _, kind, _, n in cfg.layer_groups if kind == "experts"),
         t_device=time.perf_counter(),
@@ -398,6 +437,9 @@ def finalize(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, A
     with obs_trace.phase("fetch") as fetched:
         sums = np.asarray(state["pending_dev"], dtype=np.float64)
     state["t_ready"] = fetched.t1
+    if state["n_tiles"]:
+        obs_trace.record_moe_tiles(float(sums[-state["n_tiles"]:].sum()))
+        sums = sums[:-state["n_tiles"]]
     if state["n_routed"]:
         obs_trace.record_moe_routing(float(sums[-state["n_routed"]:].sum()),
                                      state["moe_tokens"])

@@ -635,3 +635,65 @@ def test_dense_mla_segment_program_carries_latents_only_on_v5e(v5e):
     assert memory.alias_size_in_bytes >= cache_bytes        # written in place
     assert 2 * 32 * 65536 * 128 * 2 < memory.temp_size_in_bytes < 2.0e9
     assert 10.8e9 < memory.argument_size_in_bytes < 10.9e9
+
+
+def test_window_gqa_segment_program_keeps_two_shapes_of_state_on_v5e(v5e):
+    """The whole later-segment program of the ``mellum2-12b-a2.5b`` cell (three
+    scanned PERIODS of three window layers and a full one at the published
+    widths, all 64 experts, a 32,768-token cache, the state donated) for a
+    described v5e: sixteen kernels in the period's body (an attention kernel
+    and the grouped experts' three a layer), the window layers' under a name
+    of their own and over ``[tail | segment]`` = 5,120 keys, never the
+    document's 32,768; the carried state in its two shapes, aliased in place;
+    the expert stacks read where they lie: no instruction but a parameter
+    holds a layer's 64 experts."""
+    import re
+
+    from agent_tpu.models import decoder_lm
+    from benchmarks.harness import manifest
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)  # noqa: E731
+    model = manifest.load_config(manifest.load_manifest(),
+                                 "mellum2-12b-a2.5b")["model"]
+    cfg = decoder_lm.DecoderLMConfig(**model)
+    params = jax.tree_util.tree_map(sd, jax.eval_shape(
+        lambda: decoder_lm.init_params(cfg, "m")))
+    state = jax.tree_util.tree_map(sd, jax.eval_shape(
+        lambda: decoder_lm.init_state(cfg, 1, 32768)))
+    ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+
+    def lm_segment(p, i, at, s):
+        return decoder_lm.forward_segment(p, i, at, s, cfg, pallas=True,
+                                          interpret=False)
+
+    hidden, carried = jax.eval_shape(lm_segment, params, ids, pos, state)
+    assert hidden.shape == (1, 4096, 2304)
+    assert set(carried) == {"mixer", "pairs", "tiles"}
+    assert carried["mixer"]["window"]["k"].shape == (9, 1, 4, 1024, 128)
+    assert carried["mixer"]["full"]["k"].shape == (3, 1, 4, 32768, 128)
+    done = jax.jit(lm_segment, donate_argnums=(3,)).lower(
+        params, ids, pos, state).compile()
+    text = done.as_text()
+    assert text.count("tpu_custom_call") == 16 and " while(" in text
+    calls = [ln for ln in text.splitlines() if "custom_call_target=\"tpu_custom_call\"" in ln]
+    window = [ln for ln in calls if re.search(r"%window_gqa_attention\S* = ", ln)]
+    full = [ln for ln in calls if re.search(r"%causal_gqa_attention\S* = ", ln)]
+    assert (len(window), len(full)) == (3, 1)
+    for ln in window:
+        assert "bf16[4,5120,128]" in ln and "32768" not in ln
+    assert "bf16[4,32768,128]" in full[0]
+    for name in ("moe_pack_rows", "moe_grouped_swiglu", "moe_combine_pairs"):
+        assert len([ln for ln in calls if re.search(
+            r"%%%s\S* = " % name, ln)]) == 4, name
+    # The expert stacks stay the loop's invariant: a layer's 64 experts are
+    # nobody's result.
+    assert not re.search(
+        r"= bf16\[(1,)?64,(2304,896|896,2304)\]\S* (?!parameter\()", text)
+    memory = done.memory_analysis()
+    cache_bytes = 2 * (3 * 32768 + 9 * 1024) * 4 * 128 * 2
+    assert memory.alias_size_in_bytes >= cache_bytes        # written in place
+    assert memory.temp_size_in_bytes < 1.5e9
+    # Every leaf but the head (the loss head's own program) and the state.
+    assert 10.6e9 < memory.argument_size_in_bytes < 10.8e9
