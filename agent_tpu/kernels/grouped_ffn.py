@@ -21,15 +21,29 @@ case (every token routed here ``k`` times, plus a tile of padding an expert),
 this (PERF.md section 6). The slots are ``k``-major (pair ``(token, j)`` at
 row ``j * S + token``), so :func:`combine_pairs` reads them in one pass.
 
+**A tile's row traffic stays out of the matmuls' way** (PR 45). A row's
+words are ``n = d / 256`` consecutive sublane rows of a ``[rows * n, 128]``
+array, in the kernel's buffers as in the passes beside it, so a row's copy
+is ``n`` sublane rows and lane tile ``c`` of a whole tile is ONE strided
+read (seen as ``[rows, 1, d / 2]``, a tile of one sublane a row, a tile of
+256 rows was unpacked by 2,304 single-sublane loads: 4.5 us of a full
+tile's 38). A DMA semaphore counts what has arrived, so a tile's landed
+copies are waited for by SIZE (:func:`wait_sizes`: one wait at a full tile,
+nine at most), and the descriptors go out ``_COPIES_A_TURN`` a loop turn. A
+descriptor itself is NOT hidden: a DMA start is a fence in its basic block
+(static descriptors laid between the matmuls ran no faster than a rolled
+loop in front of them), 7-9 ns each on the scalar core, 512 a full tile.
+
 ``tile_expert`` and ``n_tiles`` are prefetched too: a step past the last
 tile names that tile's blocks again (no copy) and computes nothing, so the
 program is fixed-shape at the worst case and costs what the routed rows
 cost. What bounds it is the caller's rows an expert a segment: at about 128
 (a held SHARE of a wide router: deepseek-v3.2, mistral-small-4-119b) the
 read of the experts' weights once, for tiles half empty; at 512 (every expert
-held, 8 pairs a token: mellum2-12b-a2.5b) the MXU, on full tiles (PERF.md
-section 5). The expert's width is walked in steps of :func:`width_step`
-columns.
+held, 8 pairs a token: mellum2-12b-a2.5b) the three matmuls as the kernel
+compiler lowers them (22.5 us a tile of 256 rows against the MXU's 16.1),
+then the rows' descriptors (about 4 us a full tile) (PERF.md section 5). The
+expert's width is walked in steps of :func:`width_step` columns.
 
 The weights are read WHERE THEY LIE: the operands are the model's stacked
 leaves ``[L, E, ...]`` and one more prefetched scalar, ``layer``, is the
@@ -87,9 +101,10 @@ def pallas_supported(d_model: int, d_expert: int, dtype) -> bool:
                 and width_step(d_expert) > 0)
 
 
-# A row travels as 32-bit words: the DMA engine addresses ONE row of a
-# ``[rows, 1, words]`` array (a tile of one sublane, so rows lie end to end)
-# and refuses one row of a tiled ``[rows, d]`` array (8 rows a tile, 16 in
+# A row travels as 32-bit words that lie end to end: the DMA engine copies a
+# run of sublane rows of a ``[rows * n, 128]`` array (or ONE row of a
+# ``[rows, 1, words]`` array, the same bytes: what the callers see) and
+# refuses one row of a tiled ``[rows, d]`` array (8 rows a tile, 16 in
 # bf16). Word ``c`` of a row holds column ``c`` in its low half and column
 # ``c + d / 2`` in its high half: both halves are whole lane tiles, and a
 # bf16 is the high half of its float32. XLA re-lays such an array whole
@@ -111,10 +126,10 @@ def _words(low, high):
     return (bits(low) >> 16) | (bits(high) & jnp.uint32(_HIGH))
 
 
-# The two passes beside the kernel see the words as ``[rows * n, 128]``, ``n =
-# d / 256`` lane tiles a row: the same bytes as ``[rows, 1, d / 2]``, but 8
-# sublanes a tile, so a block streams at the memory's rate and lane tile ``c``
-# of ``bs`` rows is ONE strided read, ``[c :: n]``.
+# The kernels see the words as ``[rows * n, 128]``, ``n = d / 256`` lane tiles
+# a row: the same bytes as ``[rows, 1, d / 2]``, but 8 sublanes a tile, so a
+# block streams at the memory's rate and lane tile ``c`` of ``bs`` rows is ONE
+# strided read, ``[c :: n]``.
 
 def _token_block(S: int) -> int:
     block = math.gcd(S, ROW_TILE)
@@ -132,7 +147,7 @@ def _pack_kernel(x_ref, words_ref):
 
 
 def _pack_rows(x, interpret):
-    """[S, d] bf16 → [S, 1, d / 2] uint32, one pass."""
+    """[S, d] bf16 → its words [S * n, 128] uint32, one pass."""
     S, d = x.shape
     bs, n = _token_block(S), d // 256
     return pl.pallas_call(
@@ -146,7 +161,7 @@ def _pack_rows(x, interpret):
             vmem_limit_bytes=_VMEM_LIMIT),
         name="moe_pack_rows",
         interpret=interpret,
-    )(x).reshape(S, 1, d // 2)
+    )(x)
 
 
 def unpack_rows(words, dtype=jnp.bfloat16):
@@ -215,6 +230,21 @@ def combine_pairs(words, held, gates, *, interpret: bool = False):
       held.astype(jnp.int32).T[:, :, None])
 
 
+def wait_sizes(n, tile_rows: int):
+    """A DMA semaphore counts what has ARRIVED, and a wait takes off the size
+    of the descriptor it is handed: ``n <= tile_rows`` landed rows are waited
+    for as one descriptor of ``2^b`` rows for every set bit of ``n``, not as
+    ``n`` of one row. ``[(rows, taken)]``, ``taken`` 1 where ``n`` has the
+    bit (``n`` a Python int or a traced scalar): nine entries at a tile of
+    256, of which a FULL tile takes one."""
+    return [(1 << b, (n >> b) & 1) for b in range(tile_rows.bit_length())]
+
+
+# Descriptors a turn of a rows' loop: the turn's own work (its counter, its
+# branch) is paid once for them.
+_COPIES_A_TURN = 8
+
+
 def _ffn_kernel(token_ref, slot_ref, tile_expert_ref, tile_rows_ref,
                 n_tiles_ref, layer_ref, x_hbm, wg_ref, wu_ref, wd_ref, y_hbm,
                 rows_in, x_ref, acc_ref, rows_out, sem):
@@ -223,25 +253,42 @@ def _ffn_kernel(token_ref, slot_ref, tile_expert_ref, tile_rows_ref,
     t, f = pl.program_id(0), pl.program_id(1)
     n_f, n_tiles = pl.num_programs(1), n_tiles_ref[0]
     tm, d = x_ref.shape
+    n = d // 256                           # sublane rows a row's words fill
     nn = (((1,), (0,)), ((), ()))
     OUT = 2                                # rows_in's two slots have sem 0, 1
 
+    def for_rows(lo, hi, one):
+        """``one(j)`` for ``lo <= j < hi``, ``_COPIES_A_TURN`` a turn."""
+        turns = jax.lax.div(jnp.maximum(hi - lo, 0), _COPIES_A_TURN)
+
+        def single(j, carry):
+            one(j)
+            return carry
+
+        def turn(g, carry):                # traced once, unrolled when lowered
+            first = lo + g * _COPIES_A_TURN
+            return jax.lax.fori_loop(
+                0, _COPIES_A_TURN, lambda u, c: single(first + u, c), carry,
+                unroll=True)
+        jax.lax.fori_loop(0, turns, turn, 0)
+        jax.lax.fori_loop(lo + turns * _COPIES_A_TURN, hi, single, 0)
+
     def fetch(tile, lo, hi):
         """Rows ``lo .. hi - 1`` of ``tile``: x → its slot of ``rows_in``."""
-        def one(j, carry):
-            pltpu.make_async_copy(x_hbm.at[token_ref[tile * tm + j]],
-                                  rows_in.at[tile % 2, j],
-                                  sem.at[tile % 2]).start()
-            return carry
-        jax.lax.fori_loop(lo, hi, one, 0)
+        def one(j):
+            pltpu.make_async_copy(
+                x_hbm.at[pl.ds(token_ref[tile * tm + j] * n, n)],
+                rows_in.at[tile % 2, pl.ds(j * n, n)],
+                sem.at[tile % 2]).start()
+        for_rows(lo, hi, one)
 
-    def wait(n, s):
-        """``n`` row copies on semaphore ``s`` (every row is one size)."""
-        def one(j, carry):
-            pltpu.make_async_copy(rows_out.at[0], rows_out.at[0],
-                                  sem.at[s]).wait()
-            return carry
-        jax.lax.fori_loop(0, n, one, 0)
+    def wait(rows, s):
+        """``rows`` landed row copies on semaphore ``s``, by SIZE."""
+        for size, taken in wait_sizes(rows, tm):
+            @pl.when(taken == 1)
+            def _(size=size):
+                landed = rows_out.at[pl.ds(0, size * n)]
+                pltpu.make_async_copy(landed, landed, sem.at[s]).wait()
 
     @pl.when(t < n_tiles)
     def _():
@@ -252,13 +299,18 @@ def _ffn_kernel(token_ref, slot_ref, tile_expert_ref, tile_rows_ref,
                 fetch(0, 0, tile_rows_ref[0])
 
             wait(tile_rows_ref[t], t % 2)
-            low, high = _halves(rows_in[t % 2, :, 0, :])
+            # Lane tile c of every row of the tile is ONE strided read; one
+            # expression over them all (every traced equation is paid again
+            # at every start, compile cache or not).
+            low, high = _halves(jnp.concatenate(
+                [rows_in[t % 2, pl.ds(c, tm, stride=n), :] for c in range(n)],
+                axis=1))
             x_ref[:, :d // 2] = low.astype(x_ref.dtype)
             x_ref[:, d // 2:] = high.astype(x_ref.dtype)
             acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
 
-        # The next tile's rows, a share of them every width step: the
-        # descriptors go out while this tile's weights stream.
+        # The next tile's rows, a share of them every width step: where the
+        # weights stream, the descriptors go out while they do.
         @pl.when(t + 1 < n_tiles)
         def _():
             share = -(-tm // n_f)
@@ -281,14 +333,17 @@ def _ffn_kernel(token_ref, slot_ref, tile_expert_ref, tile_rows_ref,
                 wait(tile_rows_ref[t - 1], OUT)
 
             y = acc_ref[...].astype(x_ref.dtype).astype(f32)
-            rows_out[:, 0, :] = _words(y[:, :d // 2], y[:, d // 2:])
+            words = _words(y[:, :d // 2], y[:, d // 2:])
+            for c in range(n):
+                rows_out[pl.ds(c, tm, stride=n), :] = jax.lax.slice_in_dim(
+                    words, c * 128, (c + 1) * 128, axis=1)
 
-            def one(j, carry):
-                pltpu.make_async_copy(rows_out.at[j],
-                                      y_hbm.at[slot_ref[t * tm + j]],
-                                      sem.at[OUT]).start()
-                return carry
-            jax.lax.fori_loop(0, tile_rows_ref[t], one, 0)
+            def one(j):
+                pltpu.make_async_copy(
+                    rows_out.at[pl.ds(j * n, n)],
+                    y_hbm.at[pl.ds(slot_ref[t * tm + j] * n, n)],
+                    sem.at[OUT]).start()
+            for_rows(0, tile_rows_ref[t], one)
 
             @pl.when(t == n_tiles - 1)
             def _():
@@ -310,7 +365,7 @@ def grouped_swiglu(x, token, slot, tile_expert, tile_rows, w_gate, w_up,
     uint32, a row's two halves in a word (:func:`combine_pairs` and
     :func:`unpack_rows` read them). Only the real rows' slots are written:
     every other row of ``y`` is whatever the memory held."""
-    S, d = x.shape
+    d = x.shape[1]
     fe = w_gate.shape[-1]
     i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
     tile_rows = i32(tile_rows)
@@ -333,7 +388,8 @@ def grouped_swiglu(x, token, slot, tile_expert, tile_rows, w_gate, w_up,
     def w_out(t, f, tok, sl, te, tr, n, ly):
         return ly[0], te[tile(t, n)], width(t, f, n), 0
 
-    return pl.pallas_call(
+    per_row = d // 256                     # sublane rows a row's words fill
+    words = pl.pallas_call(
         _ffn_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
@@ -345,13 +401,13 @@ def grouped_swiglu(x, token, slot, tile_expert, tile_rows, w_gate, w_up,
                 pl.BlockSpec((1, 1, tf, d), w_out),
             ],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            scratch_shapes=[pltpu.VMEM((2, tm, 1, d // 2), jnp.uint32),
+            scratch_shapes=[pltpu.VMEM((2, tm * per_row, 128), jnp.uint32),
                             pltpu.VMEM((tm, d), x.dtype),
                             pltpu.VMEM((tm, d), jnp.float32),
-                            pltpu.VMEM((tm, 1, d // 2), jnp.uint32),
+                            pltpu.VMEM((tm * per_row, 128), jnp.uint32),
                             pltpu.SemaphoreType.DMA((3,))],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_slots, 1, d // 2), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((n_slots * per_row, 128), jnp.uint32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
@@ -360,3 +416,4 @@ def grouped_swiglu(x, token, slot, tile_expert, tile_rows, w_gate, w_up,
         interpret=interpret,
     )(i32(token), i32(slot), i32(tile_expert), tile_rows, n_tiles.reshape(1),
       i32(layer).reshape(1), _pack_rows(x, interpret), w_gate, w_up, w_down)
+    return words.reshape(n_slots, 1, d // 2)

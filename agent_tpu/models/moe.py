@@ -308,8 +308,10 @@ def held_experts_ffn(x: jax.Array, experts: jax.Array, gates: jax.Array,
     tiles, and for every sorted row the token it reads and the slot it
     writes. The rows themselves are the kernels' (``kernels/grouped_ffn.py``):
     one grouped matmul over the tiles that hold rows fetches its real rows
-    from ``x`` and writes them to slot ``j * S + token``, a second pass
-    combines the slots under the gates. The matmul reads a stack in place:
+    from ``x`` and writes them to slot ``j * S + token`` (a copy a row, a
+    tile's copies waited for by size; a padded row of the tables is never
+    copied either way), a second pass combines the slots under the gates.
+    The matmul reads a stack in place:
     inside a layer scan, hand it the stack and the layer's number, not the
     layer's slice (a copy of every held expert). Off the kernel the layer's
     slice is taken here."""

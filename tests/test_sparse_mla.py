@@ -295,8 +295,10 @@ ROWS_HELD_CASES = {
 }
 
 
+@pytest.mark.parametrize("fe", [512, 896], ids=["two_width_steps",
+                                                "one_width_step"])
 @pytest.mark.parametrize("case", ROWS_HELD_CASES)
-def test_the_expert_layer_moves_the_rows_it_holds(monkeypatch, case):
+def test_the_expert_layer_moves_the_rows_it_holds(monkeypatch, case, fe):
     """``held_experts_ffn`` on the kernel path (interpret mode, 64-row
     tiles): the kernel fetches the real rows of every tile from ``x`` by its
     own table and writes them to slot ``j * S + token``; the combine reads
@@ -304,12 +306,17 @@ def test_the_expert_layer_moves_the_rows_it_holds(monkeypatch, case):
     before the combine reads it: nothing unwritten reaches the result.
     Against ``_held_dense`` within the file's tolerance, and every pair's row
     bit for bit the row of the parent's form: the rows gathered in XLA, the
-    kernel run in place on them, the rows gathered back by their place."""
+    kernel run in place on them, the rows gathered back by their place. At
+    a width walked in two steps (the rows' copies in rolled loops, a share a
+    step) and in ONE (PR 45: the copies static, under the tile's matmuls):
+    with a share of the experts held elsewhere the tiles are mostly half
+    empty and most slots are never written."""
     monkeypatch.setattr(grouped_ffn, "ROW_TILE", 64)
     route, n_held, first = ROWS_HELD_CASES[case]
     experts, gates = route()
     S, k = experts.shape
-    d, fe, tm = 256, 512, 64
+    d, tm = 256, 64
+    assert grouped_ffn.width_step(fe) == (fe if fe == 896 else 256)
     ks = jax.random.split(jax.random.PRNGKey(2), 4)
     x = jax.random.normal(ks[0], (S, d), BF16)
     ws = [(jax.random.normal(key, shape) / np.sqrt(shape[1])).astype(BF16)
@@ -362,6 +369,199 @@ def test_the_expert_layer_moves_the_rows_it_holds(monkeypatch, case):
     np.testing.assert_array_equal(
         np.asarray(experts)[token, j] - first,
         np.repeat(np.asarray(tables["tile_expert"]), tm)[real])
+
+
+def _parents_ffn_kernel(token_ref, slot_ref, tile_expert_ref, tile_rows_ref,
+                        n_tiles_ref, layer_ref, x_hbm, wg_ref, wu_ref, wd_ref,
+                        y_hbm, rows_in, x_ref, acc_ref, rows_out, sem):
+    """``kernels/grouped_ffn.py: _ffn_kernel`` as PR 42 left it, to the
+    letter: one wait a ROW, every copy in a rolled loop, a tile's write-back
+    at its own tail."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    t, f = pl.program_id(0), pl.program_id(1)
+    n_f, n_tiles = pl.num_programs(1), n_tiles_ref[0]
+    tm, d = x_ref.shape
+    nn = (((1,), (0,)), ((), ()))
+    OUT = 2
+
+    def fetch(tile, lo, hi):
+        def one(j, carry):
+            pltpu.make_async_copy(x_hbm.at[token_ref[tile * tm + j]],
+                                  rows_in.at[tile % 2, j],
+                                  sem.at[tile % 2]).start()
+            return carry
+        jax.lax.fori_loop(lo, hi, one, 0)
+
+    def wait(n, s):
+        def one(j, carry):
+            pltpu.make_async_copy(rows_out.at[0], rows_out.at[0],
+                                  sem.at[s]).wait()
+            return carry
+        jax.lax.fori_loop(0, n, one, 0)
+
+    @pl.when(t < n_tiles)
+    def _():
+        @pl.when(f == 0)
+        def _():
+            @pl.when(t == 0)
+            def _():
+                fetch(0, 0, tile_rows_ref[0])
+
+            wait(tile_rows_ref[t], t % 2)
+            low, high = grouped_ffn._halves(rows_in[t % 2, :, 0, :])
+            x_ref[:, :d // 2] = low.astype(x_ref.dtype)
+            x_ref[:, d // 2:] = high.astype(x_ref.dtype)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+        @pl.when(t + 1 < n_tiles)
+        def _():
+            share = -(-tm // n_f)
+            fetch(t + 1, f * share,
+                  jnp.minimum((f + 1) * share, tile_rows_ref[t + 1]))
+
+        x = x_ref[...]
+        gate = jax.lax.dot_general(x, wg_ref[0, 0], nn,
+                                   preferred_element_type=f32)
+        up = jax.lax.dot_general(x, wu_ref[0, 0], nn,
+                                 preferred_element_type=f32)
+        h = (jax.nn.silu(gate) * up).astype(x.dtype)
+        acc_ref[...] += jax.lax.dot_general(h, wd_ref[0, 0], nn,
+                                            preferred_element_type=f32)
+
+        @pl.when(f == n_f - 1)
+        def _():
+            @pl.when(t > 0)
+            def _():
+                wait(tile_rows_ref[t - 1], OUT)
+
+            y = acc_ref[...].astype(x_ref.dtype).astype(f32)
+            rows_out[:, 0, :] = grouped_ffn._words(y[:, :d // 2],
+                                                   y[:, d // 2:])
+
+            def one(j, carry):
+                pltpu.make_async_copy(rows_out.at[j],
+                                      y_hbm.at[slot_ref[t * tm + j]],
+                                      sem.at[OUT]).start()
+                return carry
+            jax.lax.fori_loop(0, tile_rows_ref[t], one, 0)
+
+            @pl.when(t == n_tiles - 1)
+            def _():
+                wait(tile_rows_ref[t], OUT)
+
+
+def _parents_grouped_swiglu(x, token, slot, tile_expert, tile_rows, w_gate,
+                            w_up, w_down, layer=0, *, n_slots, interpret):
+    """``grouped_swiglu``'s own call as PR 42 left it, around the kernel
+    above."""
+    import functools
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, d = x.shape
+    fe = w_gate.shape[-1]
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    tile_rows = i32(tile_rows)
+    tm = token.shape[0] // tile_rows.shape[0]
+    tf = grouped_ffn.width_step(fe)
+    n_f = fe // tf
+    if w_gate.ndim == 3:
+        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+    n_tiles = (tile_rows > 0).sum(dtype=jnp.int32)
+
+    def tile(t, n):
+        return jnp.minimum(t, jnp.maximum(n[0] - 1, 0))
+
+    def width(t, f, n):
+        return jnp.where(t < n[0], f, n_f - 1)
+
+    def w_in(t, f, tok, sl, te, tr, n, ly):
+        return ly[0], te[tile(t, n)], 0, width(t, f, n)
+
+    def w_out(t, f, tok, sl, te, tr, n, ly):
+        return ly[0], te[tile(t, n)], width(t, f, n), 0
+
+    return pl.pallas_call(
+        _parents_ffn_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(tile_rows.shape[0], n_f),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec((1, 1, d, tf), w_in),
+                      pl.BlockSpec((1, 1, d, tf), w_in),
+                      pl.BlockSpec((1, 1, tf, d), w_out)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((2, tm, 1, d // 2), jnp.uint32),
+                            pltpu.VMEM((tm, d), x.dtype),
+                            pltpu.VMEM((tm, d), jnp.float32),
+                            pltpu.VMEM((tm, 1, d // 2), jnp.uint32),
+                            pltpu.SemaphoreType.DMA((3,))]),
+        out_shape=jax.ShapeDtypeStruct((n_slots, 1, d // 2), jnp.uint32),
+        name="moe_grouped_swiglu",
+        interpret=interpret,
+    )(i32(token), i32(slot), i32(tile_expert), tile_rows, n_tiles.reshape(1),
+      i32(layer).reshape(1),
+      grouped_ffn._pack_rows(x, interpret).reshape(S, 1, d // 2), w_gate,
+      w_up, w_down)
+
+
+@pytest.mark.parametrize("stack", [False, True],
+                         ids=["one_layer", "stack_in_place"])
+@pytest.mark.parametrize("fe", [896, 512], ids=["one_width_step",
+                                                "two_width_steps"])
+def test_the_layer_alone_returns_the_parents_bytes(monkeypatch, fe, stack):
+    """PR 45 changed WHEN a tile's rows move (waits by size; at one width
+    step every copy a static descriptor under the matmuls, a tile's
+    write-back under the next tile's), not what is computed: the layer alone
+    on the kernel path (interpret mode, 64-row tiles) returns, bit for bit,
+    what it returns around the parent's kernel, on tables whose tiles hold 1
+    row, an odd count, a tile less one, whole tiles and one row over, an
+    expert with none, and idle tiles behind the last; so do the kernel's own
+    words, every slot of them: a padded row wrote nothing."""
+    monkeypatch.setattr(grouped_ffn, "ROW_TILE", 64)
+    S, k, d, n_held, first = 256, 2, 512, 6, 8     # two lane tiles a half row
+    experts, gates = _by_hand(S, k, [0, 1, 37, 63, 64, 65], first)
+    ks = jax.random.split(jax.random.PRNGKey(6), 4)
+    x = jax.random.normal(ks[0], (S, d), BF16)
+    lead = (3,) if stack else ()
+    ws = [(jax.random.normal(key, lead + shape) / np.sqrt(shape[1])).astype(
+        BF16) for key, shape in zip(ks[1:], [(n_held, d, fe), (n_held, d, fe),
+                                             (n_held, fe, d)])]
+    layer = {"layer": jnp.int32(2)} if stack else {}
+    seen = {}
+
+    def spy(name, grouped_swiglu):
+        def call(x, token, slot, tile_expert, tile_rows, *rest, **kwargs):
+            words = grouped_swiglu(x, token, slot, tile_expert, tile_rows,
+                                   *rest, **kwargs)
+            seen[name] = (np.asarray(tile_rows), np.asarray(words))
+            return words
+        return call
+
+    monkeypatch.setattr(grouped_ffn, "grouped_swiglu",
+                        spy("change", grouped_ffn.grouped_swiglu))
+    change, pairs = moe.held_experts_ffn(x, experts, gates, *ws, first,
+                                         pallas=True, interpret=True, **layer)
+    monkeypatch.setattr(grouped_ffn, "grouped_swiglu",
+                        spy("parent", _parents_grouped_swiglu))
+    parent, _ = moe.held_experts_ffn(x, experts, gates, *ws, first,
+                                     pallas=True, interpret=True, **layer)
+    assert int(pairs) == 230
+    tile_rows = seen["change"][0]
+    assert sorted(tile_rows[tile_rows > 0]) == [1, 1, 37, 63, 64, 64]
+    assert (tile_rows[6:] == 0).all() and tile_rows.size == S * k // 64 + 6
+    assert float(jnp.abs(change).max()) > 0.05
+    np.testing.assert_array_equal(seen["change"][1], seen["parent"][1])
+    np.testing.assert_array_equal(np.asarray(change), np.asarray(parent))
+    if stack:                 # and it is THAT layer's weights it read
+        other, _ = moe.held_experts_ffn(x, experts, gates, *ws, first,
+                                        pallas=True, interpret=True,
+                                        layer=jnp.int32(0))
+        assert not np.array_equal(np.asarray(other), np.asarray(parent))
 
 
 # Two expert layers behind one dense: the layer scan has a second step to

@@ -357,6 +357,66 @@ def test_grouped_expert_matmul_compiles_for_v5e(v5e):
     assert combined.as_text().count("tpu_custom_call") == 1
 
 
+# A cell's grouped kernel: tokens a segment, pairs a token, held experts, the
+# widths, the layers of the stack it reads in place, and the width steps.
+GROUPED_CELLS = {
+    "deepseek-v3.2": (4096, 8, 16, 7168, 2048, 4, 8),
+    "mistral-small-4-119b": (4096, 4, 32, 4096, 2048, 6, 8),
+    "mellum2-12b-a2.5b": (4096, 8, 64, 2304, 896, 12, 1),
+}
+
+
+def _copies_of(jaxpr, in_loop=False):
+    """``(primitive, inside a rolled loop?)`` of every DMA start and wait of
+    a jaxpr, the kernels' bodies included."""
+    from jax._src import core
+
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("dma_start", "dma_wait"):
+            yield name, in_loop
+        for inner in core.jaxprs_in_params(eqn.params):
+            yield from _copies_of(inner, in_loop or name in ("scan", "while"))
+
+
+@pytest.mark.parametrize("name", GROUPED_CELLS)
+def test_grouped_kernel_waits_by_size_at_every_cells_shape_on_v5e(v5e, name):
+    """The grouped kernel at each of the three cells' shapes, the layers'
+    stack read in place, compiles for a described v5e (a row's words copied
+    as ``d / 256`` sublane rows of ``[rows * d / 256, 128]`` buffers, at
+    offsets no multiple of 8: 9, 28 and 16 rows a copy), and its text holds
+    NO wait inside a rolled loop: a tile's copies are waited for by size, a
+    fixed handful of waits a tile (PR 45; one a row before: 512 a full
+    tile), nine sizes at each of the three places a tile waits. The copies
+    themselves start in rolled loops at the three places a tile starts
+    them: a loop of ``_COPIES_A_TURN`` a turn (one traced descriptor, unrolled
+    when lowered) and one of the rest, one a turn."""
+    import collections
+
+    from agent_tpu.kernels import grouped_ffn as gf
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    bf, i32 = jnp.bfloat16, jnp.int32
+    S, k, held, d, fe, layers, steps = GROUPED_CELLS[name]
+    assert gf.pallas_supported(d, fe, bf) and fe // gf.width_step(fe) == steps
+    tiles = S * k // gf.ROW_TILE + held
+    fn = jax.jit(lambda x, tok, sl, te, tr, g, u, dn, ly: gf.grouped_swiglu(
+        x, tok, sl, te, tr, g, u, dn, ly, n_slots=S * k, interpret=False))
+    shapes = (sd((S, d), bf), sd((tiles * gf.ROW_TILE,), i32),
+              sd((tiles * gf.ROW_TILE,), i32), sd((tiles,), i32),
+              sd((tiles,), i32), sd((layers, held, d, fe), bf),
+              sd((layers, held, d, fe), bf), sd((layers, held, fe, d), bf),
+              sd((), i32))
+    assert fn.lower(*shapes).compile().as_text().count(
+        "tpu_custom_call") == 2                                # pack, kernel
+    copies = collections.Counter(_copies_of(jax.make_jaxpr(fn)(*shapes).jaxpr))
+    sizes = len(gf.wait_sizes(0, gf.ROW_TILE))
+    assert sizes == 9
+    assert copies == {("dma_wait", False): 3 * sizes,
+                      ("dma_start", True): 3 * 2}
+
+
 def test_expert_layer_scan_reads_the_stack_in_place_on_v5e(v5e, monkeypatch):
     """The FFN half of two scanned expert layers at the
     ``deepseek-v3.2.score-32k`` cell's own shape (leaves ``[2, 16, 7168,

@@ -428,6 +428,23 @@ def test_the_grouped_matmul_walks_a_width_of_896_in_one_step():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-2)
 
 
+@pytest.mark.parametrize("n", [0, 1, 2, 3, 127, 128, 255, 256])
+def test_a_tiles_landed_rows_are_waited_for_by_size(n):
+    """``n`` landed rows are waited for as descriptors of ``2^b`` rows, one a
+    set bit of ``n``: the sizes add up to ``n``, nine at most at a tile of
+    256, ONE where the tile is full; a traced ``n`` takes the same ones."""
+    table = grouped_ffn.wait_sizes(n, grouped_ffn.ROW_TILE)
+    assert [rows for rows, _ in table] == [1, 2, 4, 8, 16, 32, 64, 128, 256]
+    sizes = [rows for rows, taken in table if taken]
+    assert sum(sizes) == n and len(sizes) <= 9
+    assert len(sizes) == bin(n).count("1")
+    if n == grouped_ffn.ROW_TILE:
+        assert sizes == [grouped_ffn.ROW_TILE]
+    traced = jax.jit(lambda m: jnp.stack([taken for _, taken in
+                                          grouped_ffn.wait_sizes(m, 256)]))
+    assert [int(t) for t in traced(jnp.int32(n))] == [t for _, t in table]
+
+
 # ---- (g) the lower precision, and what no program can run ------------------
 
 def test_bf16_is_near_the_reference_and_the_int8_control_further_off():
