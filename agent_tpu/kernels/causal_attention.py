@@ -14,6 +14,13 @@ Where a key-value head has FEWER query heads (``dense_mla``: one, over keys
 expanded from latents) the query tile grows in their place
 (:func:`query_tile`): a loaded key tile again meets about that many rows.
 
+Keys and values come as one layer's ``[Hkv, Lk, D]`` or as the STACK of all
+the layers' caches ``[layers, 1, Hkv, Lk, D]`` with the layer's number (the
+operand's rank says which; the number is a second prefetched scalar, in
+front of the tile's index): a custom call's operand sliced out of a stack
+is a copy of the slice, 67 MB a layer a segment at 65,536 keys, and the
+stack is the layer scan's carry (``models/decoder_lm.py: MIXER_CACHES``).
+
 A WINDOW layer (``window_gqa``'s three of four) attends the last ``window``
 keys only (:func:`window_attention`): its keys are ``[the window keys before
 the segment | the segment's own]``, never the document's cache, and the same
@@ -247,12 +254,13 @@ def _attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def _attention_call(q, k, v, pos0, *, window: Optional[int] = None,
-                    interpret: bool):
+def _attention_call(q, k, v, pos0, layer=None, *,
+                    window: Optional[int] = None, interpret: bool):
     """``pos0``: the position of the segment's first token; under ``window``
-    the first of the keys that lies inside the document."""
+    the first of the keys that lies inside the document. ``layer``: the one
+    to attend where ``k`` and ``v`` are the layers' stack (rank 5)."""
     Hkv, G, S, D = q.shape
-    Lk = k.shape[1]
+    Lk = k.shape[-2]
     tq, tk, bk = query_tile(G, S), KEY_TILE, KEY_BLOCK
 
     if window is None:
@@ -271,7 +279,17 @@ def _attention_call(q, k, v, pos0, *, window: Optional[int] = None,
         steps = (window + tq) // tk
         pairs = 2 * Hkv * G * S * window
 
-    kv_block = pl.BlockSpec((1, tk, D), lambda h, i, j, pos: (h, at(j, i, pos), 0))
+    if k.ndim == 5:
+        # The kernel sees the same ``[1, tk, D]`` tile: the stack's two
+        # leading axes are squeezed away at ``(layer, 0)``.
+        scalars = jnp.stack([pos0, layer]).astype(jnp.int32)
+        kv_block = pl.BlockSpec(
+            (None, None, 1, tk, D),
+            lambda h, i, j, pos: (pos[1], 0, h, at(j, i, pos), 0))
+    else:
+        scalars = pos0.reshape(1).astype(jnp.int32)
+        kv_block = pl.BlockSpec(
+            (1, tk, D), lambda h, i, j, pos: (h, at(j, i, pos), 0))
     q_block = pl.BlockSpec((1, G, tq, D), lambda h, i, j, pos: (h, 0, i, 0))
     return pl.pallas_call(
         functools.partial(_attention_kernel, tq=tq, tk=tk, bk=bk, groups=G,
@@ -294,13 +312,13 @@ def _attention_call(q, k, v, pos0, *, window: Optional[int] = None,
         ),
         cost_estimate=pl.CostEstimate(
             flops=2 * D * pairs,                 # the causal half of 4 D
-            bytes_accessed=2 * (2 * q.size + k.size + v.size),
+            bytes_accessed=2 * (2 * q.size + Hkv * Lk * (D + v.shape[-1])),
             transcendentals=pairs // 2,
         ),
         name="causal_gqa_attention" if window is None
         else "window_gqa_attention",
         interpret=interpret,
-    )(pos0.reshape(1).astype(jnp.int32), q, k, v)
+    )(scalars, q, k, v)
 
 
 @part("mixer")
@@ -309,21 +327,28 @@ def causal_attention(
     k: jax.Array,          # [Hkv, Lk, D]    the document's keys so far, rotated
     v: jax.Array,          # [Hkv, Lk, D]    and its values
     pos0: jax.Array,       # int32 scalar: position of the segment's first token
+    layer: Optional[jax.Array] = None,   # int32 scalar: see below
     *,
     pallas: Optional[bool] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """softmax over the keys ``0 .. pos0 + t`` of ``q_t . k`` (the softmax
     scale is the caller's, folded into q before it is rounded), times ``v`` →
-    ``[Hkv, G, S, D]``. Keys at and after ``pos0 + S`` are never read."""
+    ``[Hkv, G, S, D]``. Keys at and after ``pos0 + S`` are never read.
+
+    ``k`` and ``v`` may be the layers' STACK of caches ``[layers, 1, Hkv, Lk,
+    D]`` with ``layer`` the one to attend (the rank says which): the kernel
+    then reads that layer's tiles out of the stack in place."""
     S, D = q.shape[2:]
     if pallas is None:
         pallas = jax.default_backend() == "tpu"
-    if pallas and pallas_supported(S, k.shape[1], D, q.dtype):
+    if pallas and pallas_supported(S, k.shape[-2], D, q.dtype):
         from agent_tpu.kernels.flash_attention import resolve_interpret
 
-        return _attention_call(q, k, v, pos0,
+        return _attention_call(q, k, v, pos0, layer,
                                interpret=resolve_interpret(interpret))
+    if k.ndim == 5:
+        k, v = k[layer, 0], v[layer, 0]
     return _attention_jnp(q, k, v, pos0).astype(q.dtype)
 
 

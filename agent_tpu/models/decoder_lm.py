@@ -51,7 +51,13 @@ one to the next (:func:`forward_segment`):
   last ``sliding_window`` of them).
 
 Layers are stacked by group (the leading dense layers, then the expert
-layers) and each group is scanned; embedding and output head are untied. An
+layers) and each group is scanned; embedding and output head are untied. A
+key and value cache that grows with the document (``hybrid_ssm``'s, the full
+layers' of ``window_gqa``: ``MIXER_CACHES``) is the scan's CARRY, all its
+layers in one stack: the mixer writes a segment's keys and values into the
+stack at the layer's number and the attention kernel reads that layer's
+tiles out of it, so no layer's cache is ever sliced out of the state or
+copied back (:func:`_caches_apart`). An
 expert layer routes over all ``n_experts`` and computes the experts it HOLDS
 (``n_experts_held`` from ``expert_first``: one chip's share of an
 expert-parallel deployment, ``models/moe.py``).
@@ -88,7 +94,7 @@ import numpy as np
 
 from agent_tpu.models import layers
 from agent_tpu.models.layers import Params
-from agent_tpu.obs.trace import part
+from agent_tpu.obs.trace import part, record_caches_in_place
 
 # Leaves that draw random numbers, in the order that keys them.
 LEAVES = ("embed", "head", "wq", "wk", "wv", "wo", "wg", "w_gate", "w_up",
@@ -822,6 +828,14 @@ def _dense_mla_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
                              + cfg.qk_rope_head_dim), cfg.compute_dtype)}
 
 
+def _write_cache(stack: jax.Array, new: jax.Array, layer, pos0) -> jax.Array:
+    """The layers' stack of caches ``[layers, 1, Hkv, Lk, D]`` with a
+    segment's ``new [Hkv, S, D]`` written at ``layer`` and ``pos0``: in the
+    layer scan's carry that is a write in place."""
+    return jax.lax.dynamic_update_slice(stack, new[None, None],
+                                        (layer, 0, 0, pos0, 0))
+
+
 @part("around")
 def _times(x: jax.Array, m: float, dtype) -> jax.Array:
     """``x * m`` in float32, rounded to ``dtype``; ``x`` itself where the
@@ -838,10 +852,12 @@ def _hybrid_ssm_mixer(p: Params, h: jax.Array, positions: jax.Array,
     layer's state). Two branches read the one ``h`` and their outputs are
     summed, each through its own out-projection and multiplier: causal
     grouped-query attention over the key and value cache, and a Mamba-2
-    scan behind a causal convolution. ``state``: ``{"k", "v": [1, Hkv, Lk,
-    D]`` (the cache, written at the segment's positions), ``"ssm": [1, H, N,
-    P]`` float32 (the scan's), ``"conv": [1, K - 1, channels]`` float32 (the
-    convolution's last inputs)``}``. One document a program.
+    scan behind a causal convolution. ``state``: ``{"k", "v": [layers, 1,
+    Hkv, Lk, D]`` (the caches of ALL the layers, ``MIXER_CACHES``: written at
+    ``"layer"``, an int32 scalar, and the segment's positions, attended
+    there, and handed back whole), ``"ssm": [1, H, N, P]`` float32 (the
+    scan's), ``"conv": [1, K - 1, channels]`` float32 (the convolution's
+    last inputs)``}``. One document a program.
 
     Every multiplier is applied where the published pass applies it, in
     float32 on the rounded product, and rounded again; none is folded into a
@@ -865,13 +881,12 @@ def _hybrid_ssm_mixer(p: Params, h: jax.Array, positions: jax.Array,
     k = _project(p["wk"], ha, dtype).astype(f32) * cfg.key_multiplier
     k = rope(k.reshape(S, hkv, dh), positions, cfg.rope_theta).astype(dtype)
     v = _project(p["wv"], ha, dtype).reshape(S, hkv, dh)
-    kc = jax.lax.dynamic_update_slice(state["k"][0], k.transpose(1, 0, 2),
-                                      (0, pos0, 0))
-    vc = jax.lax.dynamic_update_slice(state["v"][0], v.transpose(1, 0, 2),
-                                      (0, pos0, 0))
+    layer = state["layer"]
+    kc = _write_cache(state["k"], k.transpose(1, 0, 2), layer, pos0)
+    vc = _write_cache(state["v"], v.transpose(1, 0, 2), layer, pos0)
     o = causal_attention.causal_attention(
         q.reshape(S, hkv, hq // hkv, dh).transpose(1, 2, 0, 3), kc, vc, pos0,
-        **kernel_opts)
+        layer, **kernel_opts)
     attended = _project(p["wo"], o.transpose(2, 0, 1, 3).reshape(S, hq * dh),
                       dtype)
 
@@ -901,7 +916,7 @@ def _hybrid_ssm_mixer(p: Params, h: jax.Array, positions: jax.Array,
 
     mixed = (attended.astype(f32) * cfg.attention_out_multiplier
              + scanned_out.astype(f32) * cfg.ssm_out_multiplier).astype(dtype)
-    return mixed[None], {"k": kc[None], "v": vc[None], "ssm": scanned[None],
+    return mixed[None], {"k": kc, "v": vc, "ssm": scanned[None],
                          "conv": tail[None]}
 
 
@@ -937,10 +952,12 @@ def _window_gqa_mixer(p: Params, h: jax.Array, positions: jax.Array,
     layer's state). Grouped-query softmax attention, queries and keys
     RMS-normed a head and rotated by halves under the KIND's table and factor
     (:func:`kind_rotary`); the softmax scale goes into the rotated queries
-    before they are rounded. ``state``: ``{"k", "v": [1, Hkv, n, D]}``. A
-    ``full`` layer's is the document's cache (``n`` its padded length),
-    written at the segment's positions and attended up to each query. A
-    ``window`` layer's is the LAST ``sliding_window`` keys and values before
+    before they are rounded. A ``full`` layer's ``state`` is ``{"k", "v":
+    [full layers, 1, Hkv, Lk, D], "layer"}``: the caches of ALL the full
+    layers (``MIXER_CACHES``), written at ``layer`` and the segment's
+    positions, attended there up to each query, and handed back whole. A
+    ``window`` layer's is ``{"k", "v": [1, Hkv, sliding_window, D]}``, the
+    LAST ``sliding_window`` keys and values before
     the segment: the segment attends ``[that tail | its own]``, each query
     the ``sliding_window`` keys up to itself, and hands on the last
     ``sliding_window`` of them. One document a program."""
@@ -966,18 +983,20 @@ def _window_gqa_mixer(p: Params, h: jax.Array, positions: jax.Array,
     v = _project(p["wv"], h, dtype).reshape(S, hkv, dh).transpose(1, 0, 2)
     q = q.reshape(S, hkv, hq // hkv, dh).transpose(1, 2, 0, 3)
     if kind == "full":
-        kc = jax.lax.dynamic_update_slice(state["k"][0], k, (0, pos0, 0))
-        vc = jax.lax.dynamic_update_slice(state["v"][0], v, (0, pos0, 0))
-        o = causal_attention.causal_attention(q, kc, vc, pos0, **kernel_opts)
+        layer = state["layer"]
+        kc = _write_cache(state["k"], k, layer, pos0)
+        vc = _write_cache(state["v"], v, layer, pos0)
+        o = causal_attention.causal_attention(q, kc, vc, pos0, layer,
+                                              **kernel_opts)
     else:
         window = cfg.sliding_window
         kc = jnp.concatenate([state["k"][0], k], axis=1)
         vc = jnp.concatenate([state["v"][0], v], axis=1)
         o = causal_attention.window_attention(q, kc, vc, pos0, window=window,
                                               **kernel_opts)
-        kc, vc = kc[:, -window:], vc[:, -window:]
+        kc, vc = kc[None, :, -window:], vc[None, :, -window:]
     o = o.transpose(2, 0, 1, 3).reshape(1, S, hq * dh)
-    return _project(p["wo"], o, dtype), {"k": kc[None], "v": vc[None]}
+    return _project(p["wo"], o, dtype), {"k": kc, "v": vc}
 
 
 def _window_gqa_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
@@ -1014,6 +1033,16 @@ MIXER_STATES: Dict[str, Callable] = {"sparse_mla": _sparse_mla_state,
                                      "hybrid_ssm": _hybrid_ssm_state,
                                      "dense_mla": _dense_mla_state,
                                      "window_gqa": _window_gqa_state}
+# mixer name → which of its state is a cache that grows with the document and
+# that the mixer writes and reads IN PLACE: booleans in a tree that is a
+# prefix of the state's own. Those leaves are the layer scan's carry
+# (:func:`_caches_apart`), the whole ``[layers, ...]`` stack handed to the
+# mixer with ``"layer"`` beside it; everything else is stepped over a layer's
+# slice at a time (small states of fixed size, and the latent caches, whose
+# kernels take standalone operands).
+MIXER_CACHES: Dict[str, Any] = {
+    "hybrid_ssm": {"k": True, "v": True, "ssm": False, "conv": False},
+    "window_gqa": {"full": True, "window": False}}
 
 
 def starts_from_nothing(cfg: DecoderLMConfig) -> bool:
@@ -1142,38 +1171,77 @@ def _layer(p: Params, x: jax.Array, positions, state, cfg, kernel_opts,
         return x + y, state, jnp.zeros((), jnp.float32)
 
 
+def _caches_apart(state, caches):
+    """A mixer's state, or a part of it → (what the layer scan steps over a
+    layer's slice at a time, what it CARRIES whole), by ``caches`` (a
+    mixer's ``MIXER_CACHES`` or the same part of it; ``None``: nothing is
+    carried). Each tree has ``None`` where the other has the leaves. A cache
+    stepped over (the scan's ``xs`` and ``ys``) is sliced out of the stack,
+    copied for the attention's custom call, written back, and the stack
+    copied whole once a segment, donated or not."""
+    if state is None or caches is None:
+        return state, None
+    def those(carried: bool):
+        return jax.tree_util.tree_map(
+            lambda cache, leaves: leaves if cache == carried else None,
+            caches, state)
+
+    return those(False), those(True)
+
+
+def _caches_joined(stepped, carried, caches, layer=None):
+    """:func:`_caches_apart` undone; with ``layer`` (the layer's number in the
+    carried stacks) beside the leaves where a mixer is to be handed them."""
+    if carried is None:
+        return stepped
+    state = jax.tree_util.tree_map(
+        lambda cache, one, whole: whole if cache else one, caches, stepped,
+        carried)
+    return state if layer is None else {**state, "layer": layer}
+
+
 def _scan_layers(params: Params, x: jax.Array, positions, mixer_state, pairs,
                  cfg: DecoderLMConfig, kernel_opts):
     """The layer scan of a model whose layers are all alike: group by group
     (``layer_groups``), one step a layer, its slice of the state scanned
-    beside its leaves. Returns ``(x, the new mixer state, pairs)``."""
+    beside its leaves and the mixer's caches carried beside ``x``
+    (:func:`_caches_apart`). Returns ``(x, the new mixer state, pairs)``."""
+    caches = MIXER_CACHES.get(cfg.mixer)
+    stepped, carried = _caches_apart(mixer_state, caches)
     new_states = []
     for group, ffn, first, n in cfg.layer_groups:
         with part("around"):
-            mine = None if mixer_state is None else jax.tree_util.tree_map(
-                lambda a: a[first:first + n], mixer_state)
+            mine = jax.tree_util.tree_map(lambda a: a[first:first + n],
+                                          stepped)
+            layers = None if carried is None else first + jnp.arange(
+                n, dtype=jnp.int32)
+        record_caches_in_place(
+            cfg.mixer, n * len(jax.tree_util.tree_leaves(carried)))
 
         scanned, whole = _read_in_place(params[group], cfg.compute_dtype)
 
-        def step(carry, xs, ffn=ffn, mine=mine, whole=whole):
-            x, pairs = carry
-            p, st = xs if mine is not None else (xs, None)
-            x, st, more = _layer({**p, **whole}, x, positions, st, cfg,
-                                 kernel_opts, ffn)
-            return (x, pairs + more), st
+        def step(carry, xs, ffn=ffn, whole=whole):
+            x, pairs, carried = carry
+            p, st, layer = xs
+            x, st, more = _layer(
+                {**p, **whole}, x, positions,
+                _caches_joined(st, carried, caches, layer), cfg, kernel_opts,
+                ffn)
+            st, carried = _caches_apart(st, caches)
+            return (x, pairs + more, carried), st
 
-        xs = scanned if mine is None else (scanned, mine)
         # The loop's own work (a layer's leaves and state sliced out of the
         # stack, the new state written into it) is ``around``: copies the
         # model's stacking asks for; every part inside the body is its own.
         with part("around"):
-            (x, pairs), st = jax.lax.scan(step, (x, pairs), xs)
+            (x, pairs, carried), st = jax.lax.scan(
+                step, (x, pairs, carried), (scanned, mine, layers))
         new_states.append(st)
     with part("around"):
-        mixer_state = new_states[0] if len(new_states) == 1 else \
+        stepped = new_states[0] if len(new_states) == 1 else \
             jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, 0),
                                    *new_states)
-    return x, mixer_state, pairs
+    return x, _caches_joined(stepped, carried, caches), pairs
 
 
 def _scan_periods(leaves: Params, x: jax.Array, positions, mixer_state,
@@ -1185,11 +1253,16 @@ def _scan_periods(leaves: Params, x: jax.Array, positions, mixer_state,
     layers, ...]`` (a bitcast); the expert stacks stay whole and are read in
     place by the layer's number (:func:`_read_in_place`: ``period x a
     period's layers + place``). ``mixer_state``: ``{kind: leaves [that
-    kind's layers, ...]}``, stepped over a period's layers of the kind.
+    kind's layers, ...]}``, stepped over a period's layers of the kind, but
+    for the mixer's caches: those are carried whole (:func:`_caches_apart`)
+    and the layer's number among its kind's is ``period x the kind's layers
+    a period + its place among them``.
     ``counted``: what the expert layers count, added up along the way.
     Returns ``(x, the new mixer state, counted)``."""
     kinds = layer_kinds(cfg)
     every = len(kinds)
+    periods = cfg.n_layers // every
+    caches = MIXER_CACHES[cfg.mixer]
 
     def by_period(tree, n):
         return jax.tree_util.tree_map(
@@ -1197,13 +1270,18 @@ def _scan_periods(leaves: Params, x: jax.Array, positions, mixer_state,
 
     with part("around"):
         scanned, whole = _read_in_place(leaves, cfg.compute_dtype)
+        stepped, carried = _caches_apart(mixer_state, caches)
         xs = (by_period(scanned, every),
-              {kind: by_period(mixer_state[kind], kinds.count(kind))
-               for kind in sorted(set(kinds))})   # one order, one text
+              {kind: by_period(stepped[kind], kinds.count(kind))
+               for kind in sorted(set(kinds))},   # one order, one text
+              jnp.arange(periods, dtype=jnp.int32))
+    record_caches_in_place(cfg.mixer, sum(
+        periods * kinds.count(kind) * len(jax.tree_util.tree_leaves(
+            carried[kind])) for kind in set(kinds)))
 
     def step(carry, xs):
-        x, counted = carry
-        period, states = xs
+        x, counted, carried = carry
+        period, states, number = xs
         seen = {kind: 0 for kind in states}
         new = {kind: [] for kind in states}
         for place, kind in enumerate(kinds):
@@ -1211,20 +1289,26 @@ def _scan_periods(leaves: Params, x: jax.Array, positions, mixer_state,
                 p, st = jax.tree_util.tree_map(
                     lambda a, at=place: a[at], period), jax.tree_util.tree_map(
                     lambda a, at=seen[kind]: a[at], states[kind])
+                st = _caches_joined(
+                    st, carried[kind], caches[kind],
+                    number * kinds.count(kind) + seen[kind])
             x, st, more = _layer({**p, **whole}, x, positions, st, cfg,
                                  kernel_opts, ffn, kind)
             counted = jax.tree_util.tree_map(jnp.add, counted, more)
             seen[kind] += 1
+            st, kept = _caches_apart(st, caches[kind])
+            carried = {**carried, kind: kept}
             new[kind].append(st)
         with part("around"):
-            return (x, counted), {kind: jax.tree_util.tree_map(
+            return (x, counted, carried), {kind: jax.tree_util.tree_map(
                 lambda *a: jnp.stack(a), *sts) for kind, sts in new.items()}
 
     with part("around"):
-        (x, counted), new = jax.lax.scan(step, (x, counted), xs)
+        (x, counted, carried), new = jax.lax.scan(
+            step, (x, counted, carried), xs)
         new = jax.tree_util.tree_map(
             lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), new)
-    return x, new, counted
+    return x, _caches_joined(new, carried, caches), counted
 
 
 def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,

@@ -620,6 +620,23 @@ def record_attention_qkv(form: str) -> None:
            form=form)
 
 
+def record_caches_in_place(mixer: str, leaves: int) -> None:
+    """A layer scan TRACED with ``leaves`` state leaves a layer as its CARRY
+    (12: six layers' keys and values): the stacks of the layers' caches that
+    the mixer writes and the attention kernel reads where they lie
+    (``models/decoder_lm.py: MIXER_CACHES``). Beside
+    :func:`record_attention_block`: ticks while a program is traced, never
+    when it runs; a model whose caches are stepped over, a layer's slice
+    copied out and back, ticks nothing."""
+    if leaves:
+        _count("state_caches_in_place_traced_total",
+               "State leaves a layer that a traced layer scan carries whole "
+               "and a mixer writes and reads in place (a growing key or "
+               "value cache that is never sliced out of its stack), by "
+               "mixer; ticks while a program is traced, not when it runs",
+               float(leaves), mixer=mixer)
+
+
 def record_classify_shard(real_tokens: int, token_slots: int,
                           packed: bool) -> None:
     """One classify shard DISPATCHED: the tokens its rows hold, the token

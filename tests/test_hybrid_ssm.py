@@ -179,6 +179,37 @@ def test_attention_kernel_never_reads_past_the_segment(pos0):
     np.testing.assert_allclose(np.asarray(plain[1, 2]), want, atol=0.03)
 
 
+def test_attention_kernel_reads_a_layer_of_the_stack_in_place():
+    """The keys and values as the layers' STACK ``[layers, 1, Hkv, Lk, D]``
+    and a layer's number: the kernel (its tiles addressed through a second
+    prefetched scalar) answers to the byte what it answers on that layer's
+    slice, for either layer, and agrees with the plain path, which takes the
+    slice."""
+    layers, Hkv, G, S, D, Lk, pos0 = 2, 2, 5, 512, 128, 1024, 512
+    rng = np.random.default_rng(46)
+    q = jnp.asarray(rng.standard_normal((Hkv, G, S, D)) * 0.3, BF16)
+    k = jnp.asarray(rng.standard_normal((layers, 1, Hkv, Lk, D)), BF16)
+    v = jnp.asarray(rng.standard_normal((layers, 1, Hkv, Lk, D)), BF16)
+    at = jnp.int32(pos0)
+    answers = []
+    for layer in range(layers):
+        got = ca.causal_attention(q, k, v, at, jnp.int32(layer), pallas=True,
+                                  interpret=True)
+        sliced = ca.causal_attention(q, k[layer, 0], v[layer, 0], at,
+                                     pallas=True, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got.astype(F32)),
+                                      np.asarray(sliced.astype(F32)))
+        plain = ca.causal_attention(q, k, v, at, jnp.int32(layer),
+                                    pallas=False)
+        np.testing.assert_array_equal(
+            np.asarray(plain.astype(F32)), np.asarray(ca._attention_jnp(
+                q, k[layer, 0], v[layer, 0], at).astype(BF16).astype(F32)))
+        np.testing.assert_allclose(np.asarray(got.astype(F32)),
+                                   np.asarray(plain.astype(F32)), atol=0.03)
+        answers.append(np.asarray(got.astype(F32)))
+    assert np.abs(answers[0] - answers[1]).max() > 0.1   # two layers' keys
+
+
 def test_shapes_off_the_kernels_take_the_plain_path():
     assert not ssd.pallas_supported(16, 24, 128, F32)
     assert not ssd.pallas_supported(128, 256, 128, F32)
@@ -384,8 +415,12 @@ def test_every_branch_enters_the_residual_at_the_residuals_size():
     rms = lambda a: float(jnp.sqrt(jnp.mean(jnp.square(a.astype(F32)))))  # noqa: E731
     x = decoder_lm._times(params["embed"][ids], cfg.embedding_multiplier, F32)
     h = decoder_lm.rms_norm(x, layer["ln1"], cfg.rms_norm_eps)[None]
-    state = jax.tree_util.tree_map(lambda a: a[0],
-                                   decoder_lm.init_state(cfg, 1, 1024))
+    caches = decoder_lm.MIXER_CACHES["hybrid_ssm"]
+    stepped, carried = decoder_lm._caches_apart(
+        decoder_lm.init_state(cfg, 1, 1024), caches)
+    state = decoder_lm._caches_joined(
+        jax.tree_util.tree_map(lambda a: a[0], stepped), carried, caches,
+        jnp.int32(0))
     branch = lambda **over: decoder_lm._hybrid_ssm_mixer(  # noqa: E731
         layer, h, jnp.arange(1024), state,
         dataclasses.replace(cfg, **over), {})[0][0]

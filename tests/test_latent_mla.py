@@ -448,19 +448,77 @@ PARENT_PROGRAMS = {
     "sparse_mla-bf16": (
         {**SPARSE, "dtype": "bfloat16"},
         "57f286c3a302cb3cc40f9b77be5d3bf8ec02e829ae0d999dd60c8c2ffc7a2b95"),
-    "hybrid_ssm": (
-        HYBRID, "95710c7025d992b9bbfb4a2bfda9b4d83e39457a5347bb5d73a7d086e51646dc"),
+    # Its caches are the layer scan's carry since PR 46: no longer the
+    # parent's text, and held to the parent's NUMBERS instead (below).
+    "hybrid_ssm": (HYBRID, None),
 }
+
+
+def _sliced_out_and_stacked(params, ids, pos0, state, cfg):
+    """``forward_segment`` of a model of one dense group as the parent of
+    PR 46 ran its caches: a layer's slice of EVERY state leaf scanned out of
+    the stack, the mixer's arithmetic on it (its caches handed over as a
+    stack of that one layer), the results stacked again."""
+    caches = decoder_lm.MIXER_CACHES[cfg.mixer]
+    x = decoder_lm._times(params["embed"][ids], cfg.embedding_multiplier,
+                          cfg.compute_dtype)
+    positions = pos0 + jnp.arange(ids.shape[1])
+
+    def step(x, xs):
+        p, mine = xs
+        mine = {name: leaf[None] if caches[name] else leaf
+                for name, leaf in mine.items()}
+        x, mine, _ = decoder_lm._layer(p, x, positions,
+                                       {**mine, "layer": jnp.int32(0)}, cfg, {})
+        return x, {name: leaf[0] if caches[name] else leaf
+                   for name, leaf in mine.items()}
+
+    x, state = jax.lax.scan(step, x, (params["layers"], state))
+    return decoder_lm._times(
+        decoder_lm.rms_norm(x, params["final_norm"], cfg.rms_norm_eps),
+        cfg.lm_head_multiplier, cfg.compute_dtype), state
+
+
+def _carried_caches_answer_as_the_parents_form(cfg):
+    """Three segments of one document: hidden states and state of
+    ``forward_segment`` (caches carried, written and read in place) EQUAL
+    those of the parent's form, to the bit."""
+    params = decoder_lm.init_params(cfg, "carried")
+    ids = np.random.default_rng(7).integers(0, cfg.vocab_size,
+                                            (1, 768)).astype(np.int32)
+    served = lambda p, i, a, s: decoder_lm.forward_segment(  # noqa: E731
+        p, i, a, s, cfg)
+    parents = lambda p, i, a, s: _sliced_out_and_stacked(  # noqa: E731
+        p, i, a, s, cfg)
+    mine = theirs = decoder_lm.init_state(cfg, 1, 768)
+    for pos0 in (0, 256, 512):
+        segment, at = ids[:, pos0:pos0 + 256], jnp.int32(pos0)
+        # Operation by operation: what XLA fuses on this host, and so how it
+        # orders a float32 sum, follows the program around the arithmetic.
+        with jax.disable_jit():
+            hidden, mine = served(params, segment, at, mine)
+            want, theirs = parents(params, segment, at, theirs)
+        np.testing.assert_array_equal(np.asarray(hidden), np.asarray(want))
+        assert set(mine) == set(theirs) == {"k", "v", "ssm", "conv"}
+        for name in theirs:
+            assert mine[name].shape == theirs[name].shape
+            np.testing.assert_array_equal(np.asarray(mine[name]),
+                                          np.asarray(theirs[name]))
+    assert (np.asarray(mine["k"]) != 0).any(axis=-1).all()   # every position
 
 
 @pytest.mark.parametrize("case", list(PARENT_PROGRAMS))
 def test_the_other_mixers_lower_to_the_parents_text(case):
     """The shared latent projections, the softmax router's branch, the
-    attention kernel's query tile and the expansion's own rule leave
-    ``jit_lm_segment`` of the three mixers that were there as it was, on
-    these tiny configs, letter for letter."""
+    attention kernel's query tile, the expansion's own rule and the layer
+    scan's carried caches leave ``jit_lm_segment`` of the mixers that have no
+    such cache as it was, on these tiny configs, letter for letter; the one
+    that has (``hybrid_ssm``) answers as the parent's form does, bit for
+    bit."""
     over, digest = PARENT_PROGRAMS[case]
     cfg = decoder_lm.DecoderLMConfig(**over)
+    if digest is None:
+        return _carried_caches_answer_as_the_parents_form(cfg)
     params = jax.eval_shape(lambda: decoder_lm.init_params(cfg, "x"))
     state = jax.eval_shape(lambda: decoder_lm.init_state(cfg, 1, 512))
     ids = jax.ShapeDtypeStruct((1, 256), jnp.int32)
@@ -476,6 +534,45 @@ def test_the_other_mixers_lower_to_the_parents_text(case):
             state = jax.eval_shape(first, params, ids, pos)[1]
         text = later.lower(params, ids, pos, state).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
+WINDOW = {"vocab_size": 3000, "d_model": 64, "n_heads": 8, "n_kv_heads": 2,
+          "d_head": 16, "d_ff": 96, "n_layers": 6, "max_len": 16384,
+          "mixer": "window_gqa", "dtype": "float32", "sliding_window": 128,
+          "full_attention_every": 2}
+
+
+@pytest.mark.parametrize("over, leaves", [
+    ({**HYBRID, "n_layers": 6}, 12),     # falcon: six layers' keys and values
+    (WINDOW, 6),                         # mellum2: three full layers of six
+    (SPARSE, 0), (TINY, 0), ({}, 0),     # latent caches, a fixed-size state
+], ids=["hybrid_ssm", "window_gqa", "sparse_mla", "dense_mla",
+        "power_retention"])
+def test_a_traced_scan_counts_the_caches_it_carries_in_place(over, leaves):
+    """``state_caches_in_place_traced_total{mixer}``: ticks while a segment
+    program is TRACED, once a state leaf a layer that the layer scan carries
+    whole (``decoder_lm.MIXER_CACHES``); a mixer whose caches are stepped
+    over a layer's slice at a time ticks nothing."""
+    def ticks():
+        family = get_registry().snapshot().get(
+            "state_caches_in_place_traced_total") or {"series": []}
+        return {s["labels"]["mixer"]: s["value"] for s in family["series"]}
+
+    cfg = decoder_lm.DecoderLMConfig(**over)
+    params = jax.eval_shape(lambda: decoder_lm.init_params(cfg, "x"))
+    ids = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+    pos = jax.ShapeDtypeStruct((), jnp.int32)
+    state = jax.eval_shape(lambda: decoder_lm.init_state(cfg, 1, 512))
+    if state is None:
+        state = jax.eval_shape(lambda p, i, a: decoder_lm.forward_segment(
+            p, i, a, None, cfg), params, ids, pos)[1]
+    before = ticks()
+    jax.jit(lambda p, i, a, s: decoder_lm.forward_segment(
+        p, i, a, s, cfg)).lower(params, ids, pos, state)
+    after = ticks()
+    gained = {m: after[m] - before.get(m, 0.0) for m in after
+              if after[m] != before.get(m, 0.0)}
+    assert gained == ({cfg.mixer: float(leaves)} if leaves else {})
 
 
 # ---- what no program can run ----------------------------------------------

@@ -477,6 +477,85 @@ def test_expert_layer_scan_reads_the_stack_in_place_on_v5e(v5e, monkeypatch):
     assert sliced_temporaries - temporaries > 1.3e9
 
 
+def _moves_of(text: str, shape: str):
+    """Names of the instructions of a compiled text that MOVE a bf16 array
+    of ``shape`` (a regular expression of the dimensions; unit axes in front
+    allowed): a ``copy`` (asynchronous ones too), ``slice``,
+    ``dynamic-slice`` or ``concatenate`` with that result, or a fusion or
+    custom call the compiler named after one. A ``dynamic-update-slice``
+    writes where the array lies and is none."""
+    import re
+
+    made = re.compile(r"%(?P<name>\S+) = bf16\[(1,)*" + shape
+                      + r"\]\S* (?P<op>[\w-]+)\(")
+    found = []
+    for line in text.splitlines():
+        m = made.search(line)
+        if m:
+            name, what = m.group("name"), m.group("op")
+            if what == "fusion":           # named after what it holds
+                what = name
+            elif what == "custom-call":
+                what = line.split('custom_call_target="')[1].split('"')[0]
+            if re.search(r"copy|concat|(?<!update-)slice", what, re.I):
+                found.append(name)
+    return found
+
+
+def test_hybrid_ssm_segment_program_writes_its_cache_in_place_on_v5e(v5e):
+    """The whole later-segment program of the ``falcon-h1-34b.score-64k``
+    cell (6 scanned layers at the published widths, a 65,536-token cache, the
+    state donated) for a described v5e: the key and value caches are the
+    layer scan's CARRY (``decoder_lm.MIXER_CACHES``), so nothing moves a
+    layer's cache (``bf16[4,65536,128]``) or the layers' stack
+    (``bf16[6,1,4,65536,128]``): the segment's keys and values are written
+    into the stack by a ``dynamic-update-slice`` and the attention's custom
+    call takes the stack itself, with the layer's number among its scalars.
+    The parent's program (the caches scanned as ``xs`` / ``ys``) sliced a
+    layer's cache out, copied it for the call, wrote it back, and copied both
+    stacks whole once a segment: 1,995.6 MB of temporaries where this one
+    reads 454.0 MB."""
+    import re
+
+    from agent_tpu.models import decoder_lm
+    from benchmarks.harness import manifest
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)  # noqa: E731
+    model = manifest.load_config(manifest.load_manifest(),
+                                 "falcon-h1-34b")["model"]
+    cfg = decoder_lm.DecoderLMConfig(**model)
+    params = jax.tree_util.tree_map(sd, jax.eval_shape(
+        lambda: decoder_lm.init_params(cfg, "m")))
+    state = jax.tree_util.tree_map(sd, jax.eval_shape(
+        lambda: decoder_lm.init_state(cfg, 1, 65536)))
+    assert state["k"].shape == (6, 1, 4, 65536, 128)
+    ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+
+    def lm_segment(p, i, at, s):
+        return decoder_lm.forward_segment(p, i, at, s, cfg, pallas=True,
+                                          interpret=False)
+
+    done = jax.jit(lm_segment, donate_argnums=(3,)).lower(
+        params, ids, pos, state).compile()
+    text = done.as_text()
+    assert " while(" in text
+    assert _moves_of(text, "(6,1,)?4,65536,128") == []
+    written = re.findall(
+        r"= bf16\[6,1,4,65536,128\]\S* dynamic-update-slice\(", text)
+    assert len(written) == 2                                   # keys, values
+    call, = [ln for ln in text.splitlines()
+             if re.search(r"%causal_gqa_attention\S* = ", ln)]
+    assert "custom_call_target=\"tpu_custom_call\"" in call
+    assert call.count("bf16[6,1,4,65536,128]") == 2 and "s32[2]" in call
+    assert "bf16[4,65536,128]" not in call
+    memory = done.memory_analysis()
+    cache_bytes = 2 * 6 * 4 * 65536 * 128 * 2
+    assert memory.alias_size_in_bytes >= cache_bytes        # written in place
+    assert memory.temp_size_in_bytes < 1.45e9   # the parent's less 0.5 GB
+
+
 # What the expert layer may NOT hold outside its kernels, a cell: the widths
 # and the issue's own list of worst-case-row shapes (``R_max = S k + held x
 # ROW_TILE`` sorted rows, ``S k`` pairs, the pairs by token), and by how many
@@ -704,9 +783,10 @@ def test_window_gqa_segment_program_keeps_two_shapes_of_state_on_v5e(v5e):
     described v5e: sixteen kernels in the period's body (an attention kernel
     and the grouped experts' three a layer), the window layers' under a name
     of their own and over ``[tail | segment]`` = 5,120 keys, never the
-    document's 32,768; the carried state in its two shapes, aliased in place;
-    the expert stacks read where they lie: no instruction but a parameter
-    holds a layer's 64 experts."""
+    document's 32,768; the carried state in its two shapes, aliased in place,
+    the full layers' caches written and attended inside their stack
+    (``decoder_lm.MIXER_CACHES``); the expert stacks read where they lie: no
+    instruction but a parameter holds a layer's 64 experts."""
     import re
 
     from agent_tpu.models import decoder_lm
@@ -743,7 +823,11 @@ def test_window_gqa_segment_program_keeps_two_shapes_of_state_on_v5e(v5e):
     assert (len(window), len(full)) == (3, 1)
     for ln in window:
         assert "bf16[4,5120,128]" in ln and "32768" not in ln
-    assert "bf16[4,32768,128]" in full[0]
+    # The full layers' caches are the period scan's carry: the call takes the
+    # three layers' stack, and nothing moves a layer's cache or the stack.
+    assert full[0].count("bf16[3,1,4,32768,128]") == 2
+    assert "bf16[4,32768,128]" not in full[0]
+    assert _moves_of(text, "(3,1,)?4,32768,128") == []
     for name in ("moe_pack_rows", "moe_grouped_swiglu", "moe_combine_pairs"):
         assert len([ln for ln in calls if re.search(
             r"%%%s\S* = " % name, ln)]) == 4, name

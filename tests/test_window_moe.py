@@ -384,6 +384,70 @@ def test_the_carried_state_has_two_shapes_side_by_side():
     assert not decoder_lm.starts_from_nothing(cfg)
 
 
+def _sliced_out_and_stacked(params, ids, pos0, state, cfg):
+    """``forward_segment`` of a model by kind as the parent of PR 46 ran its
+    caches: layer after layer, a full layer's cache SLICED out of its kind's
+    stack (and handed to the mixer as a stack of that one layer), a window
+    layer's tail beside it, the results stacked again a kind."""
+    kinds = decoder_lm.layer_kinds(cfg)
+    (group, ffn, _, n_layers), = cfg.layer_groups
+    x = decoder_lm._times(params["embed"][ids], cfg.embedding_multiplier,
+                          cfg.compute_dtype)
+    positions = pos0 + jnp.arange(ids.shape[1])
+    counted = {k: v for k, v in state.items() if k != "mixer"}
+    new = {kind: [] for kind in set(kinds)}
+    for layer in range(n_layers):
+        kind = kinds[layer % len(kinds)]
+        p = jax.tree_util.tree_map(lambda a, at=layer: a[at], params[group])
+        mine = jax.tree_util.tree_map(lambda a, at=len(new[kind]): a[at],
+                                      state["mixer"][kind])
+        if kind == "full":
+            mine = {"k": mine["k"][None], "v": mine["v"][None],
+                    "layer": jnp.int32(0)}
+        x, mine, more = decoder_lm._layer(p, x, positions, mine, cfg, {}, ffn,
+                                          kind)
+        counted = jax.tree_util.tree_map(jnp.add, counted, more)
+        new[kind].append(jax.tree_util.tree_map(lambda a: a[0], mine)
+                         if kind == "full" else mine)
+    hidden = decoder_lm._times(
+        decoder_lm.rms_norm(x, params["final_norm"], cfg.rms_norm_eps),
+        cfg.lm_head_multiplier, cfg.compute_dtype)
+    return hidden, {"mixer": {kind: jax.tree_util.tree_map(
+        lambda *a: jnp.stack(a), *sts) for kind, sts in new.items()},
+        **counted}
+
+
+def test_carried_caches_answer_as_the_parents_form_bit_for_bit():
+    """Two periods, three segments of one document: the full layers' caches
+    are the period scan's CARRY, written at ``(layer, 0, 0, pos0, 0)`` and
+    attended through the layer's number; hidden states and every leaf of the
+    state EQUAL the parent's form's, in which a layer's cache was sliced out
+    of its stack and stacked again. Operation by operation: what XLA fuses
+    on this host, and so how it orders a float32 sum, follows the program
+    around the arithmetic."""
+    cfg = decoder_lm.DecoderLMConfig(**TINY)
+    params = decoder_lm.init_params(cfg, "window-carried")
+    ids = np.random.default_rng(11).integers(0, 3000, (1, 768)).astype(np.int32)
+    mine = theirs = decoder_lm.init_state(cfg, 1, 768)
+    for pos0 in (0, 256, 512):
+        segment, at = ids[:, pos0:pos0 + 256], jnp.int32(pos0)
+        with jax.disable_jit():
+            hidden, mine = decoder_lm.forward_segment(params, segment, at,
+                                                      mine, cfg)
+            want, theirs = _sliced_out_and_stacked(params, segment, at,
+                                                   theirs, cfg)
+        np.testing.assert_array_equal(np.asarray(hidden), np.asarray(want))
+        got, held = (jax.tree_util.tree_leaves_with_path(t)
+                     for t in (mine, theirs))
+        assert [path for path, _ in got] == [path for path, _ in held]
+        for (path, a), (_, b) in zip(got, held):
+            assert a.shape == b.shape, path
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=str(path))
+    assert mine["mixer"]["full"]["k"].shape == (2, 1, 2, 768, 16)
+    assert (np.asarray(mine["mixer"]["full"]["k"]) != 0).any(axis=-1).all()
+
+
 def test_the_tables_have_the_fifth_mixer():
     assert set(decoder_lm.MIXERS) == set(decoder_lm.MIXER_LEAVES) == set(
         decoder_lm.MIXER_FLOPS) >= {"window_gqa"}
