@@ -26,6 +26,36 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
+# The files of this directory that take a worker longest, slowest first
+# (worker-seconds of the whole run, PR 47's CHANGES.md). ``--dist loadfile``
+# hands files out in the order they are collected, which is by name:
+# ``test_window_moe.py`` and ``test_tpu_compile.py`` came last and ran on while
+# five workers stood idle. Handed out right after ``tests/benchmarks/`` (which
+# keeps its place at the front, in fresh workers, as it always ran), the long
+# files overlap and the short ones fill the end; no id changes, as it would by
+# splitting a file. A file that grows past the last one here (``--durations``,
+# see tests/README.md) is added.
+LONGEST_FIRST = (
+    "test_sparse_mla.py", "test_window_moe.py", "test_tpu_compile.py",
+    "test_hybrid_ssm.py", "test_decoder_lm.py", "test_latent_mla.py",
+    "test_flash_attention.py", "test_parts.py",
+)
+
+
+def pytest_collection_modifyitems(items):
+    """``tests/benchmarks/`` as collected, then the files of
+    ``LONGEST_FIRST`` in that order, then the rest as collected; a file's
+    cases stay together and in their order (the sort is stable), and every
+    worker collects the same list."""
+    rank = {name: at for at, name in enumerate(LONGEST_FIRST)}
+
+    def place(item):
+        if item.path.parent.name == "benchmarks":
+            return -1
+        return rank.get(item.path.name, len(rank))
+
+    items.sort(key=place)
+
 
 @pytest.fixture()
 def tmp_csv(tmp_path):

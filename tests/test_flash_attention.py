@@ -119,6 +119,19 @@ def test_mesh_flash_preserves_dp_sharding():
     _check(got, q[:, :3], k[:, :3], v[:, :3], mask)
 
 
+def _encoder_program(cfg, attn_fn=None):
+    """``encoder.forward`` of a config as ONE program: ``(params, ids,
+    mask)``; eagerly it is a compile a primitive a shape (tests/README.md).
+    Traced a shape and a tree of weights."""
+    import jax
+
+    from agent_tpu.models import encoder
+
+    kw = {} if attn_fn is None else {"attn_fn": attn_fn}
+    return jax.jit(lambda p, ids, mask: encoder.forward(p, ids, mask, cfg,
+                                                        **kw))
+
+
 def test_encoder_forward_with_flash_matches_dense():
     from agent_tpu.models import encoder
 
@@ -136,8 +149,8 @@ def test_encoder_forward_with_flash_matches_dense():
     def attn(q, k, v, m):
         return flash_attention(q, k, v, m, min_key_len=0, interpret=True)
 
-    dense_logits = encoder.forward(params, ids, mask, cfg)
-    flash_logits = encoder.forward(params, ids, mask, cfg, attn_fn=attn)
+    dense_logits = _encoder_program(cfg)(params, ids, mask)
+    flash_logits = _encoder_program(cfg, attn)(params, ids, mask)
     np.testing.assert_allclose(
         np.asarray(flash_logits), np.asarray(dense_logits),
         rtol=5e-5, atol=5e-5,
@@ -512,22 +525,22 @@ def test_encoder_forward_whole_row_within_the_benchmark_limits(L, tree):
             cfg, 1)
     ids, mask = _ids_mask(4, L, 260, seed=L)
     before = dict(fa_mod.SELECTION_COUNTS)
-    fused = encoder.forward(params, ids, mask, cfg, attn_fn=_fused_attn_fn())
+    on_the_kernel = _encoder_program(cfg, _fused_attn_fn())
+    in_float32 = _encoder_program(cfg.scaled(dtype="float32"))
+    fused = on_the_kernel(params, ids, mask)
     assert (fa_mod.SELECTION_COUNTS["whole_row"]
             - before.get("whole_row", 0)) == cfg.n_layers
     if tree == "fused_qkv":
         np.testing.assert_allclose(
-            np.asarray(fused), np.asarray(encoder.forward(
-                three_leaf, ids, mask, cfg, attn_fn=_fused_attn_fn())),
+            np.asarray(fused), np.asarray(on_the_kernel(three_leaf, ids, mask)),
             atol=2e-2)
         # The XLA path in float32: bit for bit.
-        f32 = cfg.scaled(dtype="float32")
         np.testing.assert_array_equal(
-            np.asarray(encoder.forward(params, ids, mask, f32)),
-            np.asarray(encoder.forward(three_leaf, ids, mask, f32)))
+            np.asarray(in_float32(params, ids, mask)),
+            np.asarray(in_float32(three_leaf, ids, mask)))
         params = three_leaf     # the controls below: the canonical tree
-    dense = encoder.forward(params, ids, mask, cfg)
-    f32 = encoder.forward(params, ids, mask, cfg.scaled(dtype="float32"))
+    dense = _encoder_program(cfg)(params, ids, mask)
+    f32 = in_float32(params, ids, mask)
     _check_limits(fused, dense)
     _check_limits(fused, f32)
 

@@ -14,10 +14,10 @@ published), scan heads 3 and 16 a group."""
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
+import lm_once
 import numpy as np
 import pytest
 
@@ -83,6 +83,17 @@ def _token_by_token(x, dt, A, B, C, state, H, G):
     return y, state
 
 
+# Model code runs inside programs built ONCE (``tests/README.md``): the
+# kernels' plain forms and the recurrence above are ``jax.numpy``, eagerly a
+# compile a primitive (the Pallas forms sit under a ``jax.jit`` of the
+# package's own).
+ssd_scan = jax.jit(ssd.ssd_scan, static_argnames=(
+    "n_heads", "n_groups", "chunk", "pallas", "interpret"))
+token_by_token = jax.jit(_token_by_token, static_argnums=(6, 7))
+causal_attention = jax.jit(ca.causal_attention,
+                           static_argnames=("pallas", "interpret"))
+
+
 @pytest.mark.parametrize("carried", [False, True], ids=["first", "carried"])
 def test_scan_kernel_equals_the_recurrence(carried):
     """Three heads a group at lane-wide heads, a state of two lane groups,
@@ -92,10 +103,9 @@ def test_scan_kernel_equals_the_recurrence(carried):
     x, dt, A, B, C, st = _scan_operands(S, G, hg, P, N, BF16)
     st = st if carried else None
     assert ssd.pallas_supported(P, N, 128, BF16)
-    y, out = ssd.ssd_scan(x, dt, A, B, C, n_heads=G * hg, n_groups=G,
-                          chunk=128, initial_state=st, pallas=True,
-                          interpret=True)
-    want, want_state = _token_by_token(
+    y, out = ssd_scan(x, dt, A, B, C, n_heads=G * hg, n_groups=G, chunk=128,
+                      initial_state=st, pallas=True, interpret=True)
+    want, want_state = token_by_token(
         x, dt, A, B, C, ssd.zero_state(G * hg, P, N) if st is None else st,
         G * hg, G)
     assert y.dtype == BF16 and out.dtype == F32
@@ -113,21 +123,22 @@ def test_chunked_form_equals_the_recurrence(G, hg, S, chunk):
     last chunk that is padded (a step of no time)."""
     P, N = 8, 24
     x, dt, A, B, C, st = _scan_operands(S, G, hg, P, N, F32, seed=1)
-    y, out = ssd.ssd_scan(x, dt, A, B, C, n_heads=G * hg, n_groups=G,
-                          chunk=chunk, initial_state=st, pallas=False)
-    want, want_state = _token_by_token(x, dt, A, B, C, st, G * hg, G)
+    y, out = ssd_scan(x, dt, A, B, C, n_heads=G * hg, n_groups=G, chunk=chunk,
+                      initial_state=st, pallas=False)
+    want, want_state = token_by_token(x, dt, A, B, C, st, G * hg, G)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want_state),
                                atol=2e-5)
     # Given in two calls, the state handed over: the same numbers.
     cut = 2 * chunk if S > 2 * chunk else chunk
-    parts = lambda a, b: [t[a:b] for t in (x, dt, B, C)]  # noqa: E731
+    on_host = [np.asarray(t) for t in (x, dt, B, C)]
+    parts = lambda a, b: [t[a:b] for t in on_host]  # noqa: E731
     x1, d1, B1, C1 = parts(0, cut)
-    y1, mid = ssd.ssd_scan(x1, d1, A, B1, C1, n_heads=G * hg, n_groups=G,
-                           chunk=chunk, initial_state=st, pallas=False)
+    y1, mid = ssd_scan(x1, d1, A, B1, C1, n_heads=G * hg, n_groups=G,
+                       chunk=chunk, initial_state=st, pallas=False)
     x2, d2, B2, C2 = parts(cut, S)
-    y2, end = ssd.ssd_scan(x2, d2, A, B2, C2, n_heads=G * hg, n_groups=G,
-                           chunk=chunk, initial_state=mid, pallas=False)
+    y2, end = ssd_scan(x2, d2, A, B2, C2, n_heads=G * hg, n_groups=G,
+                       chunk=chunk, initial_state=mid, pallas=False)
     np.testing.assert_allclose(np.concatenate([y1, y2]), np.asarray(y),
                                atol=2e-5)
     np.testing.assert_allclose(np.asarray(end), np.asarray(out), atol=2e-5)
@@ -163,10 +174,10 @@ def test_attention_kernel_never_reads_past_the_segment(pos0):
     k = k.at[:, pos0 + S:].set(jnp.nan)
     v = v.at[:, pos0 + S:].set(jnp.nan)
     assert ca.pallas_supported(S, Lk, D, BF16)
-    got = ca.causal_attention(q, k, v, jnp.int32(pos0), pallas=True,
-                              interpret=True).astype(F32)
-    plain = ca.causal_attention(q, k, v, jnp.int32(pos0),
-                                pallas=False).astype(F32)
+    got = causal_attention(q, k, v, jnp.int32(pos0), pallas=True,
+                           interpret=True).astype(F32)
+    plain = causal_attention(q, k, v, jnp.int32(pos0),
+                             pallas=False).astype(F32)
     assert bool(jnp.isfinite(got).all()) and bool(jnp.isfinite(plain).all())
     np.testing.assert_allclose(np.asarray(got), np.asarray(plain), atol=0.03)
     # And the plain path is the softmax of the docstring, a head at a time.
@@ -295,13 +306,7 @@ def model():
 def params(model):
     """The sound model's weights, made once (the int8 case makes its own:
     quantizing consumes them)."""
-    return decoder_lm.init_params(model[0], "hybrid-m")
-
-
-@functools.lru_cache(maxsize=None)
-def _segment_step(cfg):
-    return jax.jit(lambda p, i, at, st: decoder_lm.forward_segment(
-        p, i, at, st, cfg))
+    return lm_once.params(model[0], "hybrid-m")
 
 
 def _block_sums(cfg, params, doc, mutate=None):
@@ -314,14 +319,14 @@ def _block_sums(cfg, params, doc, mutate=None):
         segments = map_score_lm._stage_document(doc)["segments"]
     finally:
         mp.undo()
-    state = decoder_lm.init_state(cfg, 1, sum(s[0].shape[1] for s in segments))
-    step = _segment_step(cfg)
+    state = lm_once.state(cfg, 1, sum(s[0].shape[1] for s in segments))
+    step = lm_once.segment_program(cfg)
     sums = []
     for n, (ids, targets, n_valid, pos0) in enumerate(segments):
         if mutate is not None and n:
             state = mutate(dict(state))
         hidden, state = step(params, ids, jnp.int32(pos0), state)
-        sums.append(np.asarray(decoder_lm.segment_block_sums(
+        sums.append(np.asarray(lm_once.segment_block_sums(
             hidden, params["head"], jnp.asarray(targets), jnp.int32(n_valid))))
     return np.concatenate(sums)[: -(-(len(doc) - 1) // 1024)].tolist()
 
@@ -386,8 +391,8 @@ def test_int8_weights_fail_the_limits(model):
     from agent_tpu.models.quant import quantize_for_family
 
     cfg, docs, want = model
-    q = quantize_for_family("decoder_lm",
-                            decoder_lm.init_params(cfg, "hybrid-m"), "int8")
+    q = quantize_for_family("decoder_lm", lm_once.params(cfg, "hybrid-m"),
+                            "int8")
     layers = q["layers"]
     assert set(decoder_lm.LINEAR_LEAVES) & set(layers) == {
         "wq", "wk", "wv", "wo", "w_ssm_in", "w_ssm_out", "w_gate", "w_up",
@@ -409,39 +414,50 @@ def test_every_branch_enters_the_residual_at_the_residuals_size():
     spread over about one unit; under the family's plain rule with the
     published multipliers they would enter at a hundredth and below."""
     cfg = decoder_lm.DecoderLMConfig(**TINY)
-    params = decoder_lm.init_params(cfg, "hybrid-rms")
-    layer = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
+    params = lm_once.params(cfg, "hybrid-m")
     ids = np.random.default_rng(3).integers(0, 3000, 1024).astype(np.int32)
-    rms = lambda a: float(jnp.sqrt(jnp.mean(jnp.square(a.astype(F32)))))  # noqa: E731
-    x = decoder_lm._times(params["embed"][ids], cfg.embedding_multiplier, F32)
-    h = decoder_lm.rms_norm(x, layer["ln1"], cfg.rms_norm_eps)[None]
+    rms = lambda a: float(np.sqrt(np.mean(np.square(  # noqa: E731
+        np.asarray(a, np.float32)))))
     caches = decoder_lm.MIXER_CACHES["hybrid_ssm"]
-    stepped, carried = decoder_lm._caches_apart(
-        decoder_lm.init_state(cfg, 1, 1024), caches)
-    state = decoder_lm._caches_joined(
-        jax.tree_util.tree_map(lambda a: a[0], stepped), carried, caches,
-        jnp.int32(0))
-    branch = lambda **over: decoder_lm._hybrid_ssm_mixer(  # noqa: E731
-        layer, h, jnp.arange(1024), state,
-        dataclasses.replace(cfg, **over), {})[0][0]
-    attended = branch(ssm_out_multiplier=0.0)
-    scanned = branch(attention_out_multiplier=0.0)
-    both = branch()
+
+    @jax.jit
+    def branches(params, ids, state):
+        """The residual going in, the mixer's branches alone and together,
+        the feed-forward: ONE program (a multiplier is a static field of the
+        config, so the mixer is traced three times inside it)."""
+        layer = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
+        x = decoder_lm._times(params["embed"][ids], cfg.embedding_multiplier,
+                              F32)
+        h = decoder_lm.rms_norm(x, layer["ln1"], cfg.rms_norm_eps)[None]
+        stepped, carried = decoder_lm._caches_apart(state, caches)
+        mine = decoder_lm._caches_joined(
+            jax.tree_util.tree_map(lambda a: a[0], stepped), carried, caches,
+            jnp.int32(0))
+        branch = lambda **over: decoder_lm._hybrid_ssm_mixer(  # noqa: E731
+            layer, h, jnp.arange(1024), mine,
+            dataclasses.replace(cfg, **over), {})[0][0]
+        attended = branch(ssm_out_multiplier=0.0)
+        scanned = branch(attention_out_multiplier=0.0)
+        both = branch()
+        n = decoder_lm.rms_norm(x + both, layer["ln2"], cfg.rms_norm_eps)
+        ffn = decoder_lm._swiglu(
+            layer, n, ("w_gate", "w_up", "w_down"), F32,
+            cfg.mlp_gate_multiplier, cfg.mlp_down_multiplier)
+        return x, attended, scanned, both, ffn
+
+    x, attended, scanned, both, ffn = branches(
+        params, ids, lm_once.state(cfg, 1, 1024))
     np.testing.assert_allclose(np.asarray(attended + scanned),
                                np.asarray(both), atol=1e-5)
-    x1 = x + both
-    n = decoder_lm.rms_norm(x1, layer["ln2"], cfg.rms_norm_eps)
-    ffn = decoder_lm._swiglu(layer, n, ("w_gate", "w_up", "w_down"), F32,
-                             cfg.mlp_gate_multiplier, cfg.mlp_down_multiplier)
     residual = rms(x)
     assert 0.5 < residual < 2.0
     for name, value in (("attention", attended), ("scan", scanned),
                         ("ffn", ffn)):
         assert residual / 4 < rms(value) < residual * 4, (name, rms(value))
-    hidden, _ = decoder_lm.forward_segment(
-        params, ids[None], jnp.int32(0), decoder_lm.init_state(cfg, 1, 1024),
-        cfg)
-    logits = hidden[0].astype(F32) @ params["head"].astype(F32).T
+    hidden, _ = lm_once.segment_program(cfg)(
+        params, ids[None], jnp.int32(0), lm_once.state(cfg, 1, 1024))
+    logits = np.asarray(hidden[0], np.float32) @ np.asarray(
+        params["head"], np.float32).T
     assert 0.25 < float(logits.std()) < 4.0
 
 
@@ -453,7 +469,7 @@ def test_the_scan_constants_are_the_written_rule():
     constants = ref.scan_constants(32)
     np.testing.assert_array_equal(constants["dt_bias"], bias)
     cfg = decoder_lm.DecoderLMConfig(**TINY)
-    layers = decoder_lm.init_params(cfg, "hybrid-c")["layers"]
+    layers = lm_once.params(cfg, "hybrid-m")["layers"]
     np.testing.assert_allclose(np.exp(np.asarray(layers["A_log"][1])),
                                np.arange(1, 7), rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(layers["D"]), np.ones((2, 6)))
@@ -465,6 +481,7 @@ def test_the_references_weights_are_the_programs():
     """Two statements of one rule: every drawn leaf, bit for bit."""
     cfg = decoder_lm.DecoderLMConfig(**{**TINY, "dtype": "bfloat16"})
     rcfg = {**TINY, "dtype": "bfloat16"}
+    # About the draw itself: a fresh one, not ``lm_once``'s.
     params = decoder_lm.init_params(cfg, "hybrid-w")
     for name in ("embed", "head"):
         np.testing.assert_array_equal(
@@ -498,12 +515,12 @@ def test_the_new_entries_are_appended():
 
 def test_two_kinds_of_state_side_by_side():
     cfg = decoder_lm.DecoderLMConfig(**TINY)
-    state = decoder_lm.init_state(cfg, 1, 2048)
+    state = lm_once.state_shapes(cfg, 1, 2048)
     assert {k: (v.shape, v.dtype) for k, v in state.items()} == {
         "k": ((2, 1, 3, 2048, 16), F32), "v": ((2, 1, 3, 2048, 16), F32),
         "ssm": ((2, 1, 6, 24, 16), F32), "conv": ((2, 1, 3, 96 + 96), F32)}
     published = decoder_lm.DecoderLMConfig(**PUBLISHED["model"])
-    shapes = jax.eval_shape(lambda: decoder_lm.init_state(published, 1, 65536))
+    shapes = lm_once.state_shapes(published, 1, 65536)
     nbytes = {k: int(np.prod(v.shape)) * v.dtype.itemsize
               for k, v in shapes.items()}
     assert nbytes["k"] + nbytes["v"] == 6 * 65536 * 2048 == 805_306_368

@@ -29,6 +29,7 @@ import hashlib
 
 import jax
 import jax.numpy as jnp
+import lm_once
 import numpy as np
 import pytest
 
@@ -173,27 +174,28 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     uncut layer is the reference's."""
     whole = decoder_lm.DecoderLMConfig(**{**TINY, "n_experts_held": 16})
     n = jax.random.normal(jax.random.PRNGKey(5), (1, 300, 64), jnp.float32)
-    layer = lambda p: jax.tree_util.tree_map(lambda a: a[0], p["expert_layers"])  # noqa: E731
-    p_whole = layer(decoder_lm.init_params(whole, "latent-c"))
+    layer = lambda cfg: lm_once.first_layer(  # noqa: E731
+        lm_once.params(cfg, "latent-c")["expert_layers"])
+    p_whole = layer(whole)
     assert "router_bias" not in p_whole          # a softmax router has none
-    y_whole, pairs_whole = decoder_lm._experts_ffn(p_whole, n, whole, {})
-    shared = decoder_lm._swiglu(p_whole, n, ("ws_gate", "ws_up", "ws_down"),
-                                jnp.float32)
+    y_whole, pairs_whole = lm_once.experts_program(whole)(p_whole, n)
+    shared = lm_once.shared_expert(p_whole, n)
+    down_whole = np.asarray(p_whole["we_down"])
     total, pairs = shared, 0.0
     for first in (0, 4, 8, 12):
         cfg = decoder_lm.DecoderLMConfig(**{**TINY, "expert_first": first})
-        p = layer(decoder_lm.init_params(cfg, "latent-c"))
-        np.testing.assert_array_equal(
-            np.asarray(p["we_down"]),
-            np.asarray(p_whole["we_down"][first:first + 4]))
-        y, held_pairs = decoder_lm._experts_ffn(p, n, cfg, {})
+        p = layer(cfg)
+        np.testing.assert_array_equal(np.asarray(p["we_down"]),
+                                      down_whole[first:first + 4])
+        y, held_pairs = lm_once.experts_program(cfg)(p, n)
         total = total + (y - shared)
         pairs += float(held_pairs)
     assert pairs == float(pairs_whole) == 300 * 4     # every choice, once
     np.testing.assert_allclose(np.asarray(total), np.asarray(y_whole),
                                atol=1e-5)
     # The reference's layer, uncut (it adds the residual; n is normed there),
-    # and its own four shares.
+    # and its own four shares. The reference stays as it is written: its
+    # parts are jitted inside, an expert's rows are found on the host.
     u = n[0] * 3.0
     with jax.default_matmul_precision("highest"):
         want = ref.expert_layer_ffn({**REF_CFG, "n_experts_held": 16},
@@ -205,9 +207,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
         once = ref.shared_expert(REF_CFG, "latent-c", 0, normed)
     np.testing.assert_allclose(np.asarray(u + once + parts), np.asarray(want),
                                atol=1e-5)
-    got = u + decoder_lm._experts_ffn(
-        p_whole, decoder_lm.rms_norm(u, p_whole["ln2"], 1e-6)[None], whole,
-        {})[0][0]
+    got = lm_once.expert_layer_program(whole)(p_whole, u)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
@@ -215,18 +215,18 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
 
 def test_the_carried_state_holds_latents_and_nothing_expanded():
     cfg = decoder_lm.DecoderLMConfig(**TINY)
-    params = decoder_lm.init_params(cfg, "latent-b")
+    params = lm_once.params(cfg, "latent-c")
     assert set(params) == {"embed", "head", "final_norm", "expert_layers"}
     assert set(params["expert_layers"]) == {
         "wo", "w_dq", "w_uq", "w_dkv", "w_ukv", "q_norm", "kv_norm", "ln1",
         "ln2", "w_router", "ws_gate", "ws_up", "ws_down", "we_gate", "we_up",
         "we_down"}
     ids = np.random.default_rng(3).integers(0, 3000, (1, 256)).astype(np.int32)
-    state = decoder_lm.init_state(cfg, 1, 512)
+    state = lm_once.state(cfg, 1, 512)
     assert set(state) == {"mixer", "pairs"} and set(state["mixer"]) == {"kv"}
+    step = lm_once.segment_program(cfg)
     for pos0 in (0, 256):
-        hidden, state = jax.jit(lambda p, i, at, s: decoder_lm.forward_segment(
-            p, i, at, s, cfg))(params, ids, jnp.int32(pos0), state)
+        hidden, state = step(params, ids, jnp.int32(pos0), state)
         assert set(state["mixer"]) == {"kv"}
         # [layers, 1, Lk, kv_lora_rank + rope]: 40 numbers a token a layer.
         assert state["mixer"]["kv"].shape == (2, 1, 512, 32 + 8)
@@ -321,13 +321,14 @@ def test_the_mixer_on_its_kernels_equals_the_plain_path():
         **TINY, "dtype": "bfloat16", "d_model": 128, "n_heads": 8,
         "q_lora_rank": 64, "kv_lora_rank": 256, "qk_nope_head_dim": 64,
         "qk_rope_head_dim": 64, "v_head_dim": 128, "n_layers": 1})
-    params = decoder_lm.init_params(cfg, "latent-k")
-    p = jax.tree_util.tree_map(lambda a: a[0], params["expert_layers"])
+    p = lm_once.first_layer(lm_once.params(cfg, "latent-k")["expert_layers"])
     h = jax.random.normal(jax.random.PRNGKey(7), (1, 1024, 128), BF16)
     cache = jax.random.normal(jax.random.PRNGKey(8), (1, 2048, 320), BF16)
     positions = 1024 + jnp.arange(1024)
-    run = lambda **opts: decoder_lm._dense_mla_mixer(  # noqa: E731
-        p, h, positions, {"kv": cache}, cfg, opts)
+    # Two programs, one a path, each built and run once.
+    run = lambda **opts: jax.jit(  # noqa: E731
+        lambda p, h, at, kv: decoder_lm._dense_mla_mixer(
+            p, h, at, {"kv": kv}, cfg, opts))(p, h, positions, cache)
     got, state = run(pallas=True, interpret=True)
     want, state0 = run(pallas=False)
     np.testing.assert_array_equal(np.asarray(state["kv"], np.float32),
@@ -369,14 +370,16 @@ def test_the_config_says_which_router_runs(monkeypatch):
         real = getattr(moe, name)
         monkeypatch.setattr(moe, name, lambda *a, _n=name, _f=real, **k: (
             seen.append(_n), _f(*a, **k))[1])
-    n = jax.random.normal(jax.random.PRNGKey(0), (1, 16, 64), jnp.float32)
+    n = jax.ShapeDtypeStruct((1, 16, 64), jnp.float32)
     for func, over in (("softmax", {}), ("sigmoid", {"scoring_func": "sigmoid"})):
         cfg = decoder_lm.DecoderLMConfig(**{**TINY, **over})
-        p = jax.tree_util.tree_map(
-            lambda a: a[0],
-            decoder_lm.init_params(cfg, "latent-r")["expert_layers"])
-        assert ("router_bias" in p) == (func == "sigmoid")
-        decoder_lm._experts_ffn(p, n, cfg, {})
+        group = lm_once.param_shapes(cfg)["expert_layers"]
+        assert ("router_bias" in group) == (func == "sigmoid")
+        # Which router a config calls is decided while the layer is TRACED:
+        # nothing has to be drawn, compiled or run to see it.
+        jax.eval_shape(lambda group, n, cfg=cfg: decoder_lm._experts_ffn(
+            jax.tree_util.tree_map(lambda a: a[0], group), n, cfg, {}),
+            group, n)
     assert seen == ["route_softmax", "route_sigmoid_grouped"]
 
 
@@ -393,17 +396,18 @@ def test_bf16_is_near_the_reference_and_the_int8_control_further_off():
     want = ref.token_logprobs({**REF_CFG, "dtype": "bfloat16"}, "latent-q",
                               [ids])[0]
 
+    step = lm_once.segment_program(cfg)     # traced a tree: bf16, int8
+
     def gap(params):
-        hidden, _ = jax.jit(lambda p, i, s: decoder_lm.forward_segment(
-            p, i, jnp.int32(0), s, cfg))(params, ids[None],
-                                         decoder_lm.init_state(cfg, 1, 1024))
-        lp = decoder_lm.blocked_logprobs(hidden[0, :-1], params["head"],
-                                         jnp.asarray(ids[1:]))
+        hidden, _ = step(params, ids[None], jnp.int32(0),
+                         lm_once.state(cfg, 1, 1024))
+        lp = lm_once.blocked_logprobs(hidden[0, :-1], params["head"],
+                                      jnp.asarray(ids[1:]))
         return float(np.sqrt(np.mean((np.asarray(lp) - want) ** 2)))
 
-    sound = gap(decoder_lm.init_params(cfg, "latent-q"))
-    q = quantize_for_family("decoder_lm",
-                            decoder_lm.init_params(cfg, "latent-q"), "int8")
+    sound = gap(lm_once.params(cfg, "latent-q"))
+    q = quantize_for_family("decoder_lm", lm_once.params(cfg, "latent-q"),
+                            "int8")
     layers = q["expert_layers"]
     for name in ("wo", "w_dq", "w_uq", "w_dkv", "w_ukv", "w_router",
                  "ws_gate", "ws_up", "ws_down", "we_gate", "we_up", "we_down"):
@@ -483,14 +487,14 @@ def _carried_caches_answer_as_the_parents_form(cfg):
     """Three segments of one document: hidden states and state of
     ``forward_segment`` (caches carried, written and read in place) EQUAL
     those of the parent's form, to the bit."""
-    params = decoder_lm.init_params(cfg, "carried")
+    params = lm_once.params(cfg, "carried")
     ids = np.random.default_rng(7).integers(0, cfg.vocab_size,
                                             (1, 768)).astype(np.int32)
     served = lambda p, i, a, s: decoder_lm.forward_segment(  # noqa: E731
         p, i, a, s, cfg)
     parents = lambda p, i, a, s: _sliced_out_and_stacked(  # noqa: E731
         p, i, a, s, cfg)
-    mine = theirs = decoder_lm.init_state(cfg, 1, 768)
+    mine = theirs = lm_once.state(cfg, 1, 768)
     for pos0 in (0, 256, 512):
         segment, at = ids[:, pos0:pos0 + 256], jnp.int32(pos0)
         # Operation by operation: what XLA fuses on this host, and so how it
@@ -519,8 +523,8 @@ def test_the_other_mixers_lower_to_the_parents_text(case):
     cfg = decoder_lm.DecoderLMConfig(**over)
     if digest is None:
         return _carried_caches_answer_as_the_parents_form(cfg)
-    params = jax.eval_shape(lambda: decoder_lm.init_params(cfg, "x"))
-    state = jax.eval_shape(lambda: decoder_lm.init_state(cfg, 1, 512))
+    params = lm_once.param_shapes(cfg)
+    state = lm_once.state_shapes(cfg, 1, 512)
     ids = jax.ShapeDtypeStruct((1, 256), jnp.int32)
     pos = jax.ShapeDtypeStruct((), jnp.int32)
     first = jax.jit(lambda p, i, a: decoder_lm.forward_segment(
@@ -559,10 +563,10 @@ def test_a_traced_scan_counts_the_caches_it_carries_in_place(over, leaves):
         return {s["labels"]["mixer"]: s["value"] for s in family["series"]}
 
     cfg = decoder_lm.DecoderLMConfig(**over)
-    params = jax.eval_shape(lambda: decoder_lm.init_params(cfg, "x"))
+    params = lm_once.param_shapes(cfg)
     ids = jax.ShapeDtypeStruct((1, 256), jnp.int32)
     pos = jax.ShapeDtypeStruct((), jnp.int32)
-    state = jax.eval_shape(lambda: decoder_lm.init_state(cfg, 1, 512))
+    state = lm_once.state_shapes(cfg, 1, 512)
     if state is None:
         state = jax.eval_shape(lambda p, i, a: decoder_lm.forward_segment(
             p, i, a, None, cfg), params, ids, pos)[1]

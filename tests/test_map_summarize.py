@@ -98,8 +98,12 @@ def test_incremental_decode_matches_full_attention():
     )(params, ids, mask)
     toks = np.asarray(toks)[0]
 
+    @jax.jit
     def full_prefix_logits(prefix_ids):
-        """Decoder over the whole prefix, full causal attention, cache-free."""
+        """Decoder over the whole prefix, full causal attention, cache-free.
+        ONE program for every prefix: the prefix comes padded to ``T``, and
+        the causal mask hides the padding from every position read (eagerly,
+        each new length was a compile a primitive)."""
         dtype = cfg.compute_dtype
         L = prefix_ids.shape[1]
         x = params["embed"].astype(dtype)[prefix_ids] + \
@@ -111,12 +115,14 @@ def test_incremental_decode_matches_full_attention():
             x, _ = layers.decoder_block(block, x, causal, enc_out, enc_attn, dtype)
         x = layers.layer_norm(params["ln_dec"], x)
         logits = jnp.dot(x.astype(dtype), params["embed"].astype(dtype).T)
-        return np.asarray(logits.astype(jnp.float32))                # [1,L,V]
+        return logits.astype(jnp.float32)                            # [1,L,V]
 
     prefix = [1]  # BOS
     for t in range(T):
-        logits = full_prefix_logits(jnp.asarray([prefix], dtype=jnp.int32))
-        nxt = int(np.argmax(logits[0, -1]))
+        padded = np.zeros((1, T), np.int32)
+        padded[0, :len(prefix)] = prefix
+        logits = np.asarray(full_prefix_logits(padded))
+        nxt = int(np.argmax(logits[0, len(prefix) - 1]))
         if toks[t] == 0:  # post-EOS padding
             break
         assert nxt == toks[t], f"step {t}: full-attn {nxt} != cached {toks[t]}"

@@ -15,6 +15,8 @@ either tolerance, and ``test_bf16_state_fails`` holds the tolerances to it.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,17 +41,32 @@ def _inputs(seed, L, hq, hkv, d, gate=(2.0, 6.0), B=1):
     return q, k, v, log_g
 
 
+@functools.lru_cache(maxsize=None)
+def _retention(**static):
+    """``pr.power_retention`` under the given static options, jitted ONCE
+    (``tests/README.md``): ``(q, k, v, log_g[, initial_state=])``; traced a
+    shape, and for a first and a later segment."""
+    return jax.jit(functools.partial(pr.power_retention, **static))
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_program(hq, hkv, d):
+    def attention_form(q, k, v, log_g):
+        L = q.shape[1]
+        return jnp.stack([ref.retention_attention(
+            q[b].astype(jnp.float32).reshape(L, hq, d),
+            k[b].astype(jnp.float32).reshape(L, hkv, d),
+            v[b].astype(jnp.float32).reshape(L, hkv, d),
+            log_g[b]).reshape(L, hq * d) for b in range(q.shape[0])])
+    return jax.jit(attention_form)
+
+
 def _reference(q, k, v, log_g, hq, hkv, d):
-    out = []
+    """The reference's attention form as ONE program a shape: the same
+    float32 arithmetic at the highest matmul precision, which is what both
+    tolerances below were taken against (the CPU has no lower one)."""
     with jax.default_matmul_precision("highest"):
-        for b in range(q.shape[0]):
-            L = q.shape[1]
-            out.append(ref.retention_attention(
-                q[b].astype(jnp.float32).reshape(L, hq, d),
-                k[b].astype(jnp.float32).reshape(L, hkv, d),
-                v[b].astype(jnp.float32).reshape(L, hkv, d),
-                log_g[b]).reshape(L, hq * d))
-    return np.asarray(jnp.stack(out))
+        return np.asarray(_reference_program(hq, hkv, d)(q, k, v, log_g))
 
 
 def _err(y, want):
@@ -71,9 +88,9 @@ KERNEL_TOL = (2e-2, 4e-3)     # bf16 MXU operands and a bf16 output
 def test_jnp_chunked_form_matches_attention_form(L, chunk):
     hq, hkv, d = 10, 2, 16          # 5 query heads a key-value head
     q, k, v, g = _inputs(L, L, hq, hkv, d)
-    y, _ = pr.power_retention(q.astype(jnp.float32), k.astype(jnp.float32),
-                              v.astype(jnp.float32), g, n_kv_heads=hkv,
-                              chunk=chunk, pallas=False)
+    y, _ = _retention(n_kv_heads=hkv, chunk=chunk, pallas=False)(
+        q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
+        g)
     mx, rms = _err(y, _reference(q, k, v, g, hq, hkv, d))
     assert mx < JNP_TOL[0] and rms < JNP_TOL[1], (mx, rms)
 
@@ -83,9 +100,9 @@ def test_jnp_chunked_form_matches_attention_form(L, chunk):
 def test_jnp_form_over_gate_ranges(gate):
     hq, hkv, d = 5, 1, 16
     q, k, v, g = _inputs(7, 96, hq, hkv, d, gate=gate)
-    y, _ = pr.power_retention(q.astype(jnp.float32), k.astype(jnp.float32),
-                              v.astype(jnp.float32), g, n_kv_heads=hkv,
-                              chunk=32, pallas=False)
+    y, _ = _retention(n_kv_heads=hkv, chunk=32, pallas=False)(
+        q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
+        g)
     mx, rms = _err(y, _reference(q, k, v, g, hq, hkv, d))
     assert mx < JNP_TOL[0] and rms < JNP_TOL[1], (mx, rms)
 
@@ -99,8 +116,8 @@ def test_jnp_form_over_gate_ranges(gate):
 def test_kernel_interpret_matches_attention_form(L, chunk, hq, hkv):
     d = 128
     q, k, v, g = _inputs(L + hq, L, hq, hkv, d)
-    y, _ = pr.power_retention(q, k, v, g, n_kv_heads=hkv, chunk=chunk,
-                              pallas=True, interpret=True)
+    y, _ = _retention(n_kv_heads=hkv, chunk=chunk, pallas=True,
+                      interpret=True)(q, k, v, g)
     mx, rms = _err(y, _reference(q, k, v, g, hq, hkv, d))
     assert mx < KERNEL_TOL[0] and rms < KERNEL_TOL[1], (mx, rms)
 
@@ -110,8 +127,8 @@ def test_kernel_interpret_matches_attention_form(L, chunk, hq, hkv):
 def test_kernel_interpret_over_gate_ranges(gate):
     hq, hkv, d = 5, 1, 128
     q, k, v, g = _inputs(3, 256, hq, hkv, d, gate=gate)
-    y, _ = pr.power_retention(q, k, v, g, n_kv_heads=hkv, chunk=128,
-                              pallas=True, interpret=True)
+    y, _ = _retention(n_kv_heads=hkv, chunk=128, pallas=True,
+                      interpret=True)(q, k, v, g)
     mx, rms = _err(y, _reference(q, k, v, g, hq, hkv, d))
     assert mx < KERNEL_TOL[0] and rms < KERNEL_TOL[1], (mx, rms)
 
@@ -130,12 +147,14 @@ def test_segments_with_state_handed_on_equal_whole_document(pallas, d, cuts):
                 interpret=True if pallas else None)
     if not pallas:
         q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-    whole, final = pr.power_retention(q, k, v, g, **opts)
+    retention = _retention(**opts)
+    whole, final = retention(q, k, v, g)
+    q, k, v, g = (np.asarray(x) for x in (q, k, v, g))   # cut on the host
     parts, state, at = [], None, 0
     for cut in (*cuts, L):
-        y, state = pr.power_retention(
+        y, state = retention(
             q[:, at:cut], k[:, at:cut], v[:, at:cut], g[:, at:cut],
-            initial_state=state, **opts)
+            initial_state=state)
         parts.append(y)
         at = cut
     got = jnp.concatenate(parts, axis=1)
@@ -156,24 +175,26 @@ def test_recurrent_one_token_form_matches_attention_form():
     hq, hkv, d, L = 10, 2, 16, 48
     q, k, v, g = _inputs(5, L, hq, hkv, d)
     state = pr.zero_state(1, hkv, d)
+    step = jax.jit(pr.retention_step)      # one program, 48 calls
+    qs, ks, vs, gs = (np.asarray(x.astype(jnp.float32)) for x in (q, k, v, g))
     ys = []
     for t in range(L):
-        y, state = pr.retention_step(
-            q[:, t].reshape(1, hkv, hq // hkv, d), k[:, t].reshape(1, hkv, d),
-            v[:, t].reshape(1, hkv, d), g[:, t], state)
-        ys.append(y.reshape(1, hq * d))
-    mx, rms = _err(jnp.stack(ys, axis=1), _reference(q, k, v, g, hq, hkv, d))
+        y, state = step(
+            qs[:, t].reshape(1, hkv, hq // hkv, d), ks[:, t].reshape(1, hkv, d),
+            vs[:, t].reshape(1, hkv, d), gs[:, t], state)
+        ys.append(np.asarray(y).reshape(1, hq * d))
+    mx, rms = _err(np.stack(ys, axis=1), _reference(q, k, v, g, hq, hkv, d))
     assert mx < JNP_TOL[0] and rms < JNP_TOL[1], (mx, rms)
 
 
 def test_expansion_is_the_squared_dot_product():
     rng = np.random.default_rng(0)
+    phi = jax.jit(pr._phi, static_argnums=1)
     for d in (8, 16, 128):
         a, b = rng.standard_normal((2, d)).astype(np.float32)
-        got = float((pr._phi(jnp.asarray(a), False)
-                     * pr._phi(jnp.asarray(b), True)).sum())
+        got = float((np.asarray(phi(a, False)) * np.asarray(phi(b, True))).sum())
         assert got == pytest.approx(float(a @ b) ** 2, rel=1e-4)
-        assert pr._phi(jnp.asarray(a), False).shape == (pr.state_rows(d), d)
+        assert phi(a, False).shape == (pr.state_rows(d), d)
 
 
 def test_bf16_state_fails(monkeypatch):
@@ -191,8 +212,15 @@ def test_bf16_state_fails(monkeypatch):
     want = _reference(q, k, v, g, hq, hkv, d)
     f32 = jnp.float32
     args = (q.astype(f32), k.astype(f32), v.astype(f32), g)
+    # The ``jax.numpy`` form walks its chunks in a Python loop, 256 of them
+    # here: ONE program of them all is minutes of compile. The chunk's step
+    # is the program (two a run: the first chunk reads no state), called 256
+    # times; the walk around it stays eager.
+    as_program = lambda fn: jax.jit(  # noqa: E731
+        fn, static_argnames=("read_state", "eps"))
+    monkeypatch.setattr(pr, "_chunk_step", as_program(step))
     sound, _ = pr.power_retention(*args, n_kv_heads=hkv, chunk=4, pallas=False)
-    monkeypatch.setattr(pr, "_chunk_step", rounding_step)
+    monkeypatch.setattr(pr, "_chunk_step", as_program(rounding_step))
     lossy, _ = pr.power_retention(*args, n_kv_heads=hkv, chunk=4, pallas=False)
     mx, rms = _err(sound, want)
     assert mx < JNP_TOL[0] and rms < JNP_TOL[1]

@@ -178,8 +178,10 @@ def test_encoder_forward_quantized_tracks_f32(mode):
     B, L = 16, 32
     ids = rng.integers(4, 200, size=(B, L)).astype(np.int32)
     mask = np.ones((B, L), dtype=np.int32)
-    want = np.asarray(encoder.forward(params, ids, mask, cfg))
-    got = np.asarray(encoder.forward(qparams, ids, mask, cfg))
+    # ONE program, traced a tree of weights (eagerly, a compile a primitive).
+    forward = jax.jit(lambda p, i, m: encoder.forward(p, i, m, cfg))
+    want = np.asarray(forward(params, ids, mask))
+    got = np.asarray(forward(qparams, ids, mask))
     # Per-row cosine similarity of the logit vectors stays ~1 through the
     # whole quantized stack.
     cos = (want * got).sum(-1) / (

@@ -139,6 +139,18 @@ CFG = encoder.EncoderConfig(d_model=128, n_heads=2, n_layers=2, d_ff=256,
                             max_len=64, n_classes=24, dtype="float32")
 
 
+@functools.lru_cache(maxsize=None)
+def _forward_programs(cfg, attn_fn):
+    """``encoder.forward`` in its padded and its segment form, each ONE
+    program a config and attention function (eagerly, a compile a primitive
+    a shape: tests/README.md); traced a shape and a tree of weights."""
+    return (jax.jit(lambda p, ids, mask: encoder.forward(
+                p, ids, mask, cfg, attn_fn=attn_fn)),
+            jax.jit(lambda p, ids, seg_lengths, slots: encoder.forward(
+                p, ids, None, cfg, attn_fn=attn_fn,
+                segment_lengths=seg_lengths, row_slots=slots)))
+
+
 def _forward_pair(cfg, lengths, L, seed=0, attn_fn=layers.dot_product_attention,
                   edit=None, tree="three_leaf"):
     """(padded logits, packed logits in row order) of the same rows; with
@@ -153,8 +165,8 @@ def _forward_pair(cfg, lengths, L, seed=0, attn_fn=layers.dot_product_attention,
         edit(ids)
     ids = ids.astype(np.int32) % cfg.vocab_size
     mask = (np.arange(L)[None, :] < full[:, None]).astype(np.int32)
-    padded = encoder.forward(params, jnp.asarray(ids), jnp.asarray(mask), cfg,
-                             attn_fn=attn_fn)
+    forward_padded, forward_packed = _forward_programs(cfg, attn_fn)
+    padded = forward_padded(params, jnp.asarray(ids), jnp.asarray(mask))
     where, seg, used = mc.pack_rows(list(lengths), L, L // mc.PACKED_MIN_SEGMENT)
     G = L // mc.PACKED_MIN_SEGMENT
     seg_lengths = np.zeros((used, G), np.int32)
@@ -165,10 +177,9 @@ def _forward_pair(cfg, lengths, L, seed=0, attn_fn=layers.dot_product_attention,
         a = starts[where[r], seg[r]]
         packed_ids[where[r], a:a + ln] = ids[r, :ln]
     slots = np.asarray(where) * G + np.asarray(seg)
-    packed = encoder.forward(
-        params, jnp.asarray(packed_ids), None, cfg, attn_fn=attn_fn,
-        segment_lengths=jnp.asarray(seg_lengths),
-        row_slots=jnp.asarray(slots, dtype=jnp.int32))
+    packed = forward_packed(params, jnp.asarray(packed_ids),
+                            jnp.asarray(seg_lengths),
+                            jnp.asarray(slots, dtype=jnp.int32))
     return np.asarray(padded), np.asarray(packed), used
 
 

@@ -16,10 +16,12 @@ other key: one token's log-probability moves, by up to a few tenths."""
 
 from __future__ import annotations
 
+import functools
 import hashlib
 
 import jax
 import jax.numpy as jnp
+import lm_once
 import numpy as np
 import pytest
 
@@ -46,6 +48,26 @@ TINY = {"vocab_size": 3000, "d_model": 64, "n_heads": 4, "d_ff": 96,
 REF_CFG = {**TINY, "rms_norm_eps": 1e-6}
 TOKEN_TOL = 2e-5
 BF16 = jnp.bfloat16
+
+# Model code runs inside programs built ONCE (``tests/README.md``).
+# The kernels' plain forms are eager ``jax.numpy``, a compile a primitive:
+# one program a form and shape instead (the Pallas forms sit under a
+# ``jax.jit`` of the package's own).
+index_select = jax.jit(sparse_mla.index_select, static_argnums=4,
+                       static_argnames=("pallas", "interpret"))
+masked_attention = jax.jit(sparse_mla.masked_attention,
+                           static_argnames=("pallas", "interpret"))
+expand_latents = jax.jit(sparse_mla.expand_latents, static_argnums=3,
+                         static_argnames=("pallas", "interpret"))
+
+
+def _held_program(first, **opts):
+    """``moe.held_experts_ffn`` of the experts from ``first`` as ONE program
+    (its tables are thirty ``jax.numpy`` operations: eagerly, a compile
+    each): ``(x, experts, gates, w_gate, w_up, w_down[, layer=])``. Built
+    where it is called, so traced under the caller's patches."""
+    return jax.jit(lambda x, experts, gates, *ws, **layer: (
+        moe.held_experts_ffn(x, experts, gates, *ws, first, **layer, **opts)))
 
 
 # ---- the kernels against the plain arithmetic -----------------------------
@@ -102,10 +124,9 @@ def test_kernels_equal_the_plain_arithmetic(case):
     p = jnp.int32(pos0)
     assert sparse_mla.index_supported(S, Lk, Hi, D, BF16)
     assert sparse_mla.attention_supported(S, Lk, H, D, D, BF16)
-    kernel = np.asarray(sparse_mla.index_select(
-        qi, w, ki, p, topk, pallas=True, interpret=True))
-    plain = np.asarray(sparse_mla.index_select(qi, w, ki, p, topk,
-                                               pallas=False))
+    kernel = np.asarray(index_select(qi, w, ki, p, topk, pallas=True,
+                                     interpret=True))
+    plain = np.asarray(index_select(qi, w, ki, p, topk, pallas=False))
     tiles = Lk // 1024
     assert kernel.shape == (tiles, S, 1024) and kernel.dtype == np.int8
     np.testing.assert_array_equal(kernel, plain)
@@ -122,8 +143,8 @@ def test_kernels_equal_the_plain_arithmetic(case):
         assert dense[S - 128:, :pos0 + S - 128].sum() == 0
         plain = _mask_layout(dense)
     args = (*operands, jnp.asarray(plain), p)
-    got = sparse_mla.masked_attention(*args, pallas=True, interpret=True)
-    want = sparse_mla.masked_attention(*args, pallas=False)
+    got = masked_attention(*args, pallas=True, interpret=True)
+    want = masked_attention(*args, pallas=False)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
 
@@ -140,20 +161,20 @@ def test_the_attention_never_reads_past_the_segments_last_key(S):
         (key[None, :] * 7 + np.arange(S)[:, None]) % 11 == 0)
     mask = _mask_layout(dense)
     p = jnp.int32(pos0)
-    clean = sparse_mla.masked_attention(qn, qr, kn, kr, v, jnp.asarray(mask),
-                                        p, pallas=True, interpret=True)
+    clean = masked_attention(qn, qr, kn, kr, v, jnp.asarray(mask), p,
+                             pallas=True, interpret=True)
     end = pos0 + S
     poisoned = mask.copy()
     poisoned[end // 1024:] = 1
-    got = sparse_mla.masked_attention(
+    got = masked_attention(
         qn, qr, kn.at[:, end:].set(jnp.nan), kr.at[end:].set(jnp.nan),
         v.at[:, end:].set(jnp.nan), jnp.asarray(poisoned), p,
         pallas=True, interpret=True)
     assert np.isfinite(np.asarray(got, np.float32)).all()
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(clean, np.float32))
-    want = sparse_mla.masked_attention(qn, qr, kn, kr, v, jnp.asarray(mask),
-                                       p, pallas=False)
+    want = masked_attention(qn, qr, kn, kr, v, jnp.asarray(mask), p,
+                            pallas=False)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
 
@@ -166,10 +187,10 @@ def test_expansion_writes_the_keys_a_segment_can_see(n_keys):
     ks = jax.random.split(jax.random.PRNGKey(2), 2)
     c = jax.random.normal(ks[0], (2048, 512), BF16)
     w = (jax.random.normal(ks[1], (8, 512, 256)) / 22.0).astype(BF16)
-    k, v = sparse_mla.expand_latents(c, w, jnp.int32(n_keys), 128,
-                                     pallas=True, interpret=True)
-    k_plain, v_plain = sparse_mla.expand_latents(c, w, jnp.int32(n_keys), 128,
-                                                 pallas=False)
+    k, v = expand_latents(c, w, jnp.int32(n_keys), 128, pallas=True,
+                          interpret=True)
+    k_plain, v_plain = expand_latents(c, w, jnp.int32(n_keys), 128,
+                                      pallas=False)
     assert k.shape == v.shape == (8, 2048, 128) and k.dtype == BF16
     seen = -(-n_keys // 1024) * 1024
     for got, want in ((k, k_plain), (v, v_plain)):
@@ -209,10 +230,10 @@ def test_grouped_matmul_equals_every_expert_on_every_token(monkeypatch, first):
     ws = [(jax.random.normal(key, shape) / np.sqrt(shape[1])).astype(BF16)
           for key, shape in zip(ks[2:], [(held, d, fe), (held, d, fe),
                                          (held, fe, d)])]
-    plain, n_plain = moe.held_experts_ffn(x, experts, gates, *ws, first,
-                                          pallas=False)
-    kernel, n_kernel = moe.held_experts_ffn(x, experts, gates, *ws, first,
-                                            pallas=True, interpret=True)
+    plain, n_plain = _held_program(first, pallas=False)(x, experts, gates,
+                                                        *ws)
+    kernel, n_kernel = _held_program(first, pallas=True, interpret=True)(
+        x, experts, gates, *ws)
     in_share = (np.asarray(experts) >= first) & (np.asarray(experts) < first + held)
     assert int(n_plain) == int(n_kernel) == int(in_share.sum()) > 0
     np.testing.assert_allclose(np.asarray(kernel), np.asarray(plain),
@@ -254,6 +275,7 @@ def test_grouped_matmul_reads_a_layer_of_the_stack_in_place(layer):
                                   np.asarray(first[:written]))
 
 
+@functools.lru_cache(maxsize=None)
 def _routed(form, S, E, k):
     logits = jax.random.normal(jax.random.PRNGKey(3), (S, E), jnp.float32)
     if form == "softmax":
@@ -337,26 +359,30 @@ def test_the_expert_layer_moves_the_rows_it_holds(monkeypatch, case, fe):
                          jnp.uint32(0x7FC07FC0))
 
     monkeypatch.setattr(grouped_ffn, "grouped_swiglu", poisoned)
-    kernel, n_kernel = moe.held_experts_ffn(x, experts, gates, *ws, first,
-                                            pallas=True, interpret=True)
-    plain, n_plain = moe.held_experts_ffn(x, experts, gates, *ws, first,
-                                          pallas=False)
+
+    def layer(x, experts, gates, *ws):
+        y, pairs = moe.held_experts_ffn(x, experts, gates, *ws, first,
+                                        pallas=True, interpret=True)
+        return y, pairs, dict(seen)     # what the spy saw, as results
+
+    kernel, n_kernel, tables = jax.jit(layer)(x, experts, gates, *ws)
+    plain, n_plain = _held_program(first, pallas=False)(x, experts, gates,
+                                                        *ws)
     held = (np.asarray(experts) >= first) & (np.asarray(experts)
                                              < first + n_held)
     assert int(n_kernel) == int(n_plain) == int(held.sum())
-    assert int(seen["written"].sum()) == int(held.sum())     # each slot once
-    assert seen["tile_rows"].size == S * k // tm + n_held    # the worst case
+    assert int(tables["written"].sum()) == int(held.sum())   # each slot once
+    assert tables["tile_rows"].size == S * k // tm + n_held  # the worst case
     assert np.isfinite(np.asarray(kernel)).all()
     np.testing.assert_allclose(np.asarray(kernel), np.asarray(plain),
                                atol=3e-2)
     if not held.any():
-        assert int((seen["tile_rows"] > 0).sum()) == 0
+        assert int((tables["tile_rows"] > 0).sum()) == 0
         np.testing.assert_array_equal(np.asarray(kernel), 0.0)
         return
     assert float(jnp.abs(kernel).max()) > 0.05
     # The parent's form on the same sorted order (the call passes the spy
-    # too: keep what the layer's own call showed).
-    tables = dict(seen)
+    # too: ``tables`` is what the layer's own call showed).
     real, slots = np.asarray(tables["real"]), np.asarray(tables["slot"])
     x_rows = x[jnp.where(tables["real"], tables["token"], 0)]
     y_rows = _rows_in_place(x_rows, tables["tile_expert"],
@@ -532,35 +558,40 @@ def test_the_layer_alone_returns_the_parents_bytes(monkeypatch, fe, stack):
         BF16) for key, shape in zip(ks[1:], [(n_held, d, fe), (n_held, d, fe),
                                              (n_held, fe, d)])]
     layer = {"layer": jnp.int32(2)} if stack else {}
-    seen = {}
 
-    def spy(name, grouped_swiglu):
-        def call(x, token, slot, tile_expert, tile_rows, *rest, **kwargs):
-            words = grouped_swiglu(x, token, slot, tile_expert, tile_rows,
-                                   *rest, **kwargs)
-            seen[name] = (np.asarray(tile_rows), np.asarray(words))
-            return words
-        return call
+    def around(grouped_swiglu):
+        """The layer around one kernel, as ONE program: ``(y, pairs, the
+        kernel's tile_rows, its words)``."""
+        def run(x, experts, gates, *ws, **layer):
+            seen = {}
 
-    monkeypatch.setattr(grouped_ffn, "grouped_swiglu",
-                        spy("change", grouped_ffn.grouped_swiglu))
-    change, pairs = moe.held_experts_ffn(x, experts, gates, *ws, first,
-                                         pallas=True, interpret=True, **layer)
-    monkeypatch.setattr(grouped_ffn, "grouped_swiglu",
-                        spy("parent", _parents_grouped_swiglu))
-    parent, _ = moe.held_experts_ffn(x, experts, gates, *ws, first,
-                                     pallas=True, interpret=True, **layer)
+            def spy(x, token, slot, tile_expert, tile_rows, *rest, **kwargs):
+                words = grouped_swiglu(x, token, slot, tile_expert, tile_rows,
+                                       *rest, **kwargs)
+                seen.update(tile_rows=tile_rows, words=words)
+                return words
+
+            with monkeypatch.context() as traced:
+                traced.setattr(grouped_ffn, "grouped_swiglu", spy)
+                y, pairs = moe.held_experts_ffn(
+                    x, experts, gates, *ws, first, pallas=True,
+                    interpret=True, **layer)
+            return y, pairs, seen["tile_rows"], seen["words"]
+        return jax.jit(run)
+
+    change, pairs, tile_rows, words = around(grouped_ffn.grouped_swiglu)(
+        x, experts, gates, *ws, **layer)
+    parents = around(_parents_grouped_swiglu)
+    parent, _, _, parents_words = parents(x, experts, gates, *ws, **layer)
     assert int(pairs) == 230
-    tile_rows = seen["change"][0]
+    tile_rows = np.asarray(tile_rows)
     assert sorted(tile_rows[tile_rows > 0]) == [1, 1, 37, 63, 64, 64]
     assert (tile_rows[6:] == 0).all() and tile_rows.size == S * k // 64 + 6
     assert float(jnp.abs(change).max()) > 0.05
-    np.testing.assert_array_equal(seen["change"][1], seen["parent"][1])
+    np.testing.assert_array_equal(np.asarray(words), np.asarray(parents_words))
     np.testing.assert_array_equal(np.asarray(change), np.asarray(parent))
     if stack:                 # and it is THAT layer's weights it read
-        other, _ = moe.held_experts_ffn(x, experts, gates, *ws, first,
-                                        pallas=True, interpret=True,
-                                        layer=jnp.int32(0))
+        other = parents(x, experts, gates, *ws, layer=jnp.int32(0))[0]
         assert not np.array_equal(np.asarray(other), np.asarray(parent))
 
 
@@ -596,7 +627,7 @@ def test_the_layer_scan_reads_the_expert_stack_in_place(monkeypatch, case):
     config, opts, stored, in_place = STACK_CASES[case]
     cfg = decoder_lm.DecoderLMConfig(**config)
     dtype = cfg.compute_dtype
-    params = decoder_lm.init_params(cfg, "sparse-stack")
+    params = lm_once.params(cfg, "sparse-stack")
     if stored == "int8":
         params = quantize_for_family("decoder_lm", params, "int8")
     elif stored == "float32":
@@ -614,10 +645,12 @@ def test_the_layer_scan_reads_the_expert_stack_in_place(monkeypatch, case):
     monkeypatch.setattr(moe, "held_experts_ffn", spy)
 
     def run(tree):
+        # Its own ``jax.jit`` a call: each is traced under another patch (or
+        # another tree) and the spy has to see the trace.
         del layers_seen[:]
         hidden, state = jax.jit(lambda p, i, s: decoder_lm.forward_segment(
             p, i, jnp.int32(0), s, cfg, **opts))(
-                tree, ids, decoder_lm.init_state(cfg, 1, 256))
+                tree, ids, lm_once.state(cfg, 1, 256))
         return jax.tree_util.tree_leaves((hidden, state)), list(layers_seen)
 
     got, seen = run(params)
@@ -696,23 +729,24 @@ def selection(served):
             (int(p), np.asarray(m))), mask, pos0, ordered=True)
         return mask
 
-    params = decoder_lm.init_params(cfg, "sparse-a")
+    params = lm_once.params(cfg, "sparse-a")
     mp = _short_segments()
     try:
         segments = _stage_document(doc)["segments"]
     finally:
         mp.undo()
     padded = sum(seg[0].shape[1] for seg in segments)
-    state = decoder_lm.init_state(cfg, 1, padded)
+    state = lm_once.state(cfg, 1, padded)
     sums = []
     mp = pytest.MonkeyPatch()
     mp.setattr(sparse_mla, "index_select", recording)
+    # Traced under the patch, so its own; built once: a program a bucket.
+    step = jax.jit(lambda p, i, at, st: decoder_lm.forward_segment(
+        p, i, at, st, cfg))
     try:
         for ids, targets, n_valid, pos0 in segments:
-            hidden, state = jax.jit(lambda p, i, at, st: (
-                decoder_lm.forward_segment(p, i, at, st, cfg)))(
-                    params, ids, jnp.int32(pos0), state)
-            sums.append(np.asarray(decoder_lm.segment_block_sums(
+            hidden, state = step(params, ids, jnp.int32(pos0), state)
+            sums.append(np.asarray(lm_once.segment_block_sums(
                 hidden, params["head"], jnp.asarray(targets),
                 jnp.int32(n_valid))))
         jax.effects_barrier()
@@ -770,13 +804,12 @@ def test_logits_of_a_segment_match_the_reference():
     """``forward_segment`` on one 200-token document (a 256-token cache, the
     plain path) against the reference's whole-vocabulary logits."""
     cfg = decoder_lm.DecoderLMConfig(**TINY)
-    params = decoder_lm.init_params(cfg, "sparse-b")
+    params = lm_once.params(cfg, "sparse-b")
     ids = np.random.default_rng(3).integers(0, 3000, 200).astype(np.int32)
     padded = np.zeros((1, 256), np.int32)
     padded[0, :200] = ids
-    hidden, state = jax.jit(lambda p, i, s: decoder_lm.forward_segment(
-        p, i, jnp.int32(0), s, cfg))(params, padded,
-                                     decoder_lm.init_state(cfg, 1, 256))
+    hidden, state = lm_once.segment_program(cfg)(
+        params, padded, jnp.int32(0), lm_once.state(cfg, 1, 256))
     got = np.asarray(hidden[0, :200] @ params["head"].T)
     want = ref.logits(REF_CFG, "sparse-b", ids, range(200))
     np.testing.assert_allclose(got, want, atol=2e-4)
@@ -797,17 +830,18 @@ def test_bf16_is_near_the_reference_and_the_int8_control_further_off():
     want = ref.logits({**REF_CFG, "dtype": "bfloat16"}, "sparse-q", ids,
                       range(256))
 
+    step = lm_once.segment_program(cfg)     # traced a tree: bf16, int8
+
     def gap(params):
-        hidden, _ = jax.jit(lambda p, i, s: decoder_lm.forward_segment(
-            p, i, jnp.int32(0), s, cfg))(params, ids[None],
-                                         decoder_lm.init_state(cfg, 1, 256))
+        hidden, _ = step(params, ids[None], jnp.int32(0),
+                         lm_once.state(cfg, 1, 256))
         got = hidden[0].astype(jnp.float32) @ params["head"].astype(
             jnp.float32).T
         return float(np.abs(np.asarray(got) - want).mean())
 
-    sound = gap(decoder_lm.init_params(cfg, "sparse-q"))
-    q = quantize_for_family("decoder_lm",
-                            decoder_lm.init_params(cfg, "sparse-q"), "int8")
+    sound = gap(lm_once.params(cfg, "sparse-q"))
+    q = quantize_for_family("decoder_lm", lm_once.params(cfg, "sparse-q"),
+                            "int8")
     experts = q["expert_layers"]
     assert experts["we_up"]["w_q"].shape == (1, 4, 64, 32)
     assert experts["we_up"]["w_q"].dtype == jnp.int8
@@ -828,19 +862,19 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     uncut layer is the reference's."""
     whole = decoder_lm.DecoderLMConfig(**{**TINY, "n_experts_held": 16})
     n = jax.random.normal(jax.random.PRNGKey(5), (1, 300, 64), jnp.float32)
-    layer = lambda p: jax.tree_util.tree_map(lambda a: a[0], p["expert_layers"])  # noqa: E731
-    p_whole = layer(decoder_lm.init_params(whole, "sparse-c"))
-    y_whole, pairs_whole = decoder_lm._experts_ffn(p_whole, n, whole, {})
-    shared = decoder_lm._swiglu(p_whole, n, ("ws_gate", "ws_up", "ws_down"),
-                                jnp.float32)
+    layer = lambda cfg: lm_once.first_layer(  # noqa: E731
+        lm_once.params(cfg, "sparse-c")["expert_layers"])
+    p_whole = layer(whole)
+    y_whole, pairs_whole = lm_once.experts_program(whole)(p_whole, n)
+    shared = lm_once.shared_expert(p_whole, n)
+    down_whole = np.asarray(p_whole["we_down"])
     total, pairs = shared, 0.0
     for first in (0, 4, 8, 12):
         cfg = decoder_lm.DecoderLMConfig(**{**TINY, "expert_first": first})
-        p = layer(decoder_lm.init_params(cfg, "sparse-c"))
-        np.testing.assert_array_equal(
-            np.asarray(p["we_down"]),
-            np.asarray(p_whole["we_down"][first:first + 4]))
-        y, held_pairs = decoder_lm._experts_ffn(p, n, cfg, {})
+        p = layer(cfg)
+        np.testing.assert_array_equal(np.asarray(p["we_down"]),
+                                      down_whole[first:first + 4])
+        y, held_pairs = lm_once.experts_program(cfg)(p, n)
         total = total + (y - shared)
         pairs += float(held_pairs)
     assert pairs == float(pairs_whole) == 300 * 4     # every choice, once
@@ -851,9 +885,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     cfg_ref = {**REF_CFG, "n_experts_held": 16}
     with jax.default_matmul_precision("highest"):
         want = ref.expert_layer_ffn(cfg_ref, "sparse-c", 1, u)
-    got = u + decoder_lm._experts_ffn(
-        p_whole, decoder_lm.rms_norm(u, p_whole["ln2"], 1e-6)[None], whole,
-        {})[0][0]
+    got = lm_once.expert_layer_program(whole)(p_whole, u)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
@@ -868,6 +900,7 @@ def test_the_first_mixers_weights_are_what_they_were():
     cfg = decoder_lm.DecoderLMConfig(vocab_size=300, d_model=64, n_heads=10,
                                      n_kv_heads=2, d_head=16, d_ff=96,
                                      n_layers=3)
+    # About the draw itself: a fresh one, not ``lm_once``'s.
     params = decoder_lm.init_params(cfg, "digest-model")
     assert set(params) == {"embed", "head", "final_norm", "layers"}
     flat = {"/".join(str(k.key) for k in path): np.asarray(

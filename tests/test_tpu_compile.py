@@ -19,6 +19,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import lm_once
 import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
@@ -408,46 +409,63 @@ def test_grouped_kernel_waits_by_size_at_every_cells_shape_on_v5e(v5e, name):
               sd((tiles,), i32), sd((layers, held, d, fe), bf),
               sd((layers, held, d, fe), bf), sd((layers, held, fe, d), bf),
               sd((), i32))
-    assert fn.lower(*shapes).compile().as_text().count(
+    traced = fn.trace(*shapes)          # one trace: the jaxpr and the text
+    assert traced.lower().compile().as_text().count(
         "tpu_custom_call") == 2                                # pack, kernel
-    copies = collections.Counter(_copies_of(jax.make_jaxpr(fn)(*shapes).jaxpr))
+    copies = collections.Counter(_copies_of(traced.jaxpr.jaxpr))
     sizes = len(gf.wait_sizes(0, gf.ROW_TILE))
     assert sizes == 9
     assert copies == {("dma_wait", False): 3 * sizes,
                       ("dma_start", True): 3 * 2}
 
 
-def test_expert_layer_scan_reads_the_stack_in_place_on_v5e(v5e, monkeypatch):
-    """The FFN half of two scanned expert layers at the
-    ``deepseek-v3.2.score-32k`` cell's own shape (leaves ``[2, 16, 7168,
-    2048]``, a 4,096-token segment at the fixed worst case), split and stepped
-    as ``forward_segment`` does: the grouped kernel and the two passes beside
-    it (PR 41: the rows packed, the pairs combined) in the loop body, and
-    NO instruction whose result is one layer's experts (``bf16[16, 7168,
-    2048]`` or ``[16, 2048, 7168]``, with or without a leading 1): the
-    kernel's operands are the loop's own stacks. The same scan with the
-    leaves sliced a layer at a time holds the three copies (1.41 GB of
-    temporaries): the search finds what it is held to find."""
-    import re
-
+@pytest.fixture(scope="module")
+def expert_layer(v5e):
+    """``compiled(cell, form) -> (config, text, bytes of temporaries)``: the
+    FFN half of two scanned expert layers at a cell's own shape (a
+    4,096-token segment at the fixed worst case), split and stepped as
+    ``forward_segment`` does, compiled for the described v5e ONCE a cell and
+    form, whichever cases read it. Forms: ``"served"`` (the tree's own);
+    ``"leaves_sliced"`` (PR 39's parent: the experts' leaves scanned a
+    layer's slice at a time); ``"rows_moved_by_xla"`` (PR 41's parent: the
+    rows gathered in front of the kernel and gathered back behind it, around
+    the same kernel): the two controls the searches must find."""
+    from agent_tpu.kernels import grouped_ffn as gf
     from agent_tpu.models import decoder_lm
     from benchmarks.harness import manifest
 
     chip = SingleDeviceSharding(v5e.devices[0])
     sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)  # noqa: E731
-    model = manifest.load_config(manifest.load_manifest(),
-                                 "deepseek-v3.2")["model"]
-    cfg = decoder_lm.DecoderLMConfig(**{**model, "n_layers": 3})
-    leaves = jax.eval_shape(
-        lambda: decoder_lm.init_params(cfg, "m"))["expert_layers"]
-    assert leaves["we_gate"].shape == (2, 16, 7168, 2048)
-    leaves = {k: sd(leaves[k]) for k in decoder_lm.FFN_LEAVES["experts"] + (
-        "router_bias",)}
-    x = sd(jax.ShapeDtypeStruct((1, 4096, 7168), jnp.bfloat16))
-    a_layers_experts = re.compile(
-        r"= bf16\[(1,)?16,(7168,2048|2048,7168)\]\S* (?!parameter\()")
+    grouped_swiglu = gf.grouped_swiglu
 
-    def compiled():
+    def rows_moved_by_xla(x, token, slot, tile_expert, tile_rows, *weights,
+                          n_slots, interpret):
+        rows = jnp.arange(token.size, dtype=jnp.int32)
+        y_rows = grouped_swiglu(x[token], rows, rows, tile_expert, tile_rows,
+                                *weights, n_slots=token.size,
+                                interpret=interpret)
+        return y_rows[jnp.zeros(n_slots, jnp.int32).at[slot].set(rows)]
+
+    patches = {
+        "served": (),
+        "leaves_sliced": ((decoder_lm, "_read_in_place",
+                           lambda leaves, dtype: (leaves, {})),),
+        "rows_moved_by_xla": ((gf, "grouped_swiglu", rows_moved_by_xla),),
+    }
+    done = {}
+
+    def compiled(cell, form="served"):
+        if (cell, form) in done:
+            return done[cell, form]
+        model = manifest.load_config(manifest.load_manifest(), cell)["model"]
+        cfg = decoder_lm.DecoderLMConfig(**{
+            **model, "n_layers": model.get("n_dense_layers", 0) + 2})
+        leaves = lm_once.param_shapes(cfg)["expert_layers"]
+        leaves = {key: sd(leaves[key]) for key in
+                  decoder_lm.FFN_LEAVES["experts"] + ("router_bias",)
+                  if key in leaves}
+        x = sd(jax.ShapeDtypeStruct((1, 4096, cfg.d_model), jnp.bfloat16))
+
         def ffn_half(leaves, x):
             scanned, whole = decoder_lm._read_in_place(leaves,
                                                        cfg.compute_dtype)
@@ -460,18 +478,41 @@ def test_expert_layer_scan_reads_the_stack_in_place_on_v5e(v5e, monkeypatch):
 
             return jax.lax.scan(step, x, scanned)
 
-        done = jax.jit(ffn_half).lower(leaves, x).compile()
-        text = done.as_text()
+        with pytest.MonkeyPatch.context() as mp:
+            for patch in patches[form]:
+                mp.setattr(*patch)
+            program = jax.jit(ffn_half).lower(leaves, x).compile()
+        text = program.as_text()
         assert text.count("tpu_custom_call") == 3 and " while(" in text
-        return (len(a_layers_experts.findall(text)),
-                done.memory_analysis().temp_size_in_bytes)
+        done[cell, form] = (cfg, text,
+                            program.memory_analysis().temp_size_in_bytes)
+        return done[cell, form]
 
-    copies, temporaries = compiled()
-    assert copies == 0
-    monkeypatch.setattr(decoder_lm, "_read_in_place",
-                        lambda leaves, dtype: (leaves, {}))
-    sliced_copies, sliced_temporaries = compiled()
-    assert sliced_copies >= 3
+    return compiled
+
+
+def test_expert_layer_scan_reads_the_stack_in_place_on_v5e(expert_layer):
+    """The FFN half of two scanned expert layers at the
+    ``deepseek-v3.2.score-32k`` cell's own shape (leaves ``[2, 16, 7168,
+    2048]``, a 4,096-token segment at the fixed worst case), split and stepped
+    as ``forward_segment`` does: the grouped kernel and the two passes beside
+    it (PR 41: the rows packed, the pairs combined) in the loop body, and
+    NO instruction whose result is one layer's experts (``bf16[16, 7168,
+    2048]`` or ``[16, 2048, 7168]``, with or without a leading 1): the
+    kernel's operands are the loop's own stacks. The same scan with the
+    leaves sliced a layer at a time holds the three copies (1.41 GB of
+    temporaries): the search finds what it is held to find."""
+    import re
+
+    cfg, text, temporaries = expert_layer("deepseek-v3.2")
+    assert lm_once.param_shapes(cfg)["expert_layers"]["we_gate"].shape == (
+        2, 16, 7168, 2048)
+    a_layers_experts = re.compile(
+        r"= bf16\[(1,)?16,(7168,2048|2048,7168)\]\S* (?!parameter\()")
+    assert len(a_layers_experts.findall(text)) == 0
+    _, sliced, sliced_temporaries = expert_layer("deepseek-v3.2",
+                                                 "leaves_sliced")
+    assert len(a_layers_experts.findall(sliced)) >= 3
     # 1.41 GB of copies; the layer's own temporaries (the pairs' rows since
     # PR 41) share some of that room in the sliced form: 1.34 GB apart.
     assert sliced_temporaries - temporaries > 1.3e9
@@ -525,10 +566,8 @@ def test_hybrid_ssm_segment_program_writes_its_cache_in_place_on_v5e(v5e):
     model = manifest.load_config(manifest.load_manifest(),
                                  "falcon-h1-34b")["model"]
     cfg = decoder_lm.DecoderLMConfig(**model)
-    params = jax.tree_util.tree_map(sd, jax.eval_shape(
-        lambda: decoder_lm.init_params(cfg, "m")))
-    state = jax.tree_util.tree_map(sd, jax.eval_shape(
-        lambda: decoder_lm.init_state(cfg, 1, 65536)))
+    params = jax.tree_util.tree_map(sd, lm_once.param_shapes(cfg))
+    state = jax.tree_util.tree_map(sd, lm_once.state_shapes(cfg, 1, 65536))
     assert state["k"].shape == (6, 1, 4, 65536, 128)
     ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
     pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
@@ -569,7 +608,7 @@ WORST_CASE_ROWS = {
 
 
 @pytest.mark.parametrize("name", WORST_CASE_ROWS)
-def test_expert_layer_moves_no_worst_case_rows_in_xla_on_v5e(v5e, monkeypatch,
+def test_expert_layer_moves_no_worst_case_rows_in_xla_on_v5e(expert_layer,
                                                              name):
     """The FFN half of two scanned expert layers at each cell's own shape
     for a described v5e: THREE custom calls in the loop body (the rows
@@ -584,44 +623,12 @@ def test_expert_layer_moves_no_worst_case_rows_in_xla_on_v5e(v5e, monkeypatch,
     finds what it is held to find."""
     import re
 
-    from agent_tpu.kernels import grouped_ffn as gf
-    from agent_tpu.models import decoder_lm
-    from benchmarks.harness import manifest
-
-    chip = SingleDeviceSharding(v5e.devices[0])
-    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)  # noqa: E731
     d, k, held, shapes, fewer_temporaries = WORST_CASE_ROWS[name]
     S = 4096
-    model = manifest.load_config(manifest.load_manifest(), name)["model"]
-    cfg = decoder_lm.DecoderLMConfig(**{
-        **model, "n_layers": model.get("n_dense_layers", 0) + 2})
-    assert (cfg.d_model, cfg.n_experts_per_token, cfg.n_experts_held) == (
-        d, k, held)
-    leaves = jax.eval_shape(
-        lambda: decoder_lm.init_params(cfg, "m"))["expert_layers"]
-    assert leaves["we_gate"].shape == (2, held, d, 2048)
-    leaves = {key: sd(leaves[key]) for key in decoder_lm.FFN_LEAVES["experts"]
-              + ("router_bias",) if key in leaves}
-    x = sd(jax.ShapeDtypeStruct((1, S, d), jnp.bfloat16))
     listed = re.compile(r"= (bf16|f32)\[(%s)\]\S* (?!parameter\()" % shapes)
     result = re.compile(r"^\s*(?:ROOT )?(%\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(")
 
-    def compiled():
-        def ffn_half(leaves, x):
-            scanned, whole = decoder_lm._read_in_place(leaves,
-                                                       cfg.compute_dtype)
-
-            def step(x, p):
-                y, pairs = decoder_lm._experts_ffn(
-                    {**p, **whole}, x, cfg,
-                    {"pallas": True, "interpret": False})
-                return x + y, pairs
-
-            return jax.lax.scan(step, x, scanned)
-
-        done = jax.jit(ffn_half).lower(leaves, x).compile()
-        text = done.as_text()
-        assert text.count("tpu_custom_call") == 3 and " while(" in text
+    def searched(text):
         large, inside_fusion = [], False
         for line in text.splitlines():
             if line.endswith("{"):                   # a computation opens
@@ -635,10 +642,14 @@ def test_expert_layer_moves_no_worst_case_rows_in_xla_on_v5e(v5e, monkeypatch,
                                        "bitcast")
                     and not instruction.startswith("%moe_grouped_swiglu")):
                 large.append(line.strip()[:120])
-        return (listed.findall(text), large,
-                done.memory_analysis().temp_size_in_bytes, text)
+        return listed.findall(text), large
 
-    shapes_found, large, temporaries, text = compiled()
+    cfg, text, temporaries = expert_layer(name)
+    assert (cfg.d_model, cfg.n_experts_per_token, cfg.n_experts_held) == (
+        d, k, held)
+    assert lm_once.param_shapes(cfg)["expert_layers"]["we_gate"].shape == (
+        2, held, d, 2048)
+    shapes_found, large = searched(text)
     assert not shapes_found and not large, (shapes_found, large)
     # ... and the runtime's part map (PR 38) lays all three kernels under
     # `experts`.
@@ -650,18 +661,8 @@ def test_expert_layer_moves_no_worst_case_rows_in_xla_on_v5e(v5e, monkeypatch,
         "moe_combine_pairs", "moe_grouped_swiglu", "moe_pack_rows"]
     assert set(kernels.values()) == {"experts"}
 
-    grouped_swiglu = gf.grouped_swiglu
-
-    def rows_moved_by_xla(x, token, slot, tile_expert, tile_rows, *weights,
-                          n_slots, interpret):
-        rows = jnp.arange(token.size, dtype=jnp.int32)
-        y_rows = grouped_swiglu(x[token], rows, rows, tile_expert, tile_rows,
-                                *weights, n_slots=token.size,
-                                interpret=interpret)
-        return y_rows[jnp.zeros(n_slots, jnp.int32).at[slot].set(rows)]
-
-    monkeypatch.setattr(gf, "grouped_swiglu", rows_moved_by_xla)
-    shapes_found, large, parents_temporaries, _ = compiled()
+    _, parents, parents_temporaries = expert_layer(name, "rows_moved_by_xla")
+    shapes_found, large = searched(parents)
     assert shapes_found, "the rows gathered in front of the kernel"
     assert len(large) >= 2, large
     assert parents_temporaries - temporaries > fewer_temporaries
@@ -747,10 +748,8 @@ def test_dense_mla_segment_program_carries_latents_only_on_v5e(v5e):
     model = manifest.load_config(manifest.load_manifest(),
                                  "mistral-small-4-119b")["model"]
     cfg = decoder_lm.DecoderLMConfig(**model)
-    params = jax.tree_util.tree_map(sd, jax.eval_shape(
-        lambda: decoder_lm.init_params(cfg, "m")))
-    state = jax.tree_util.tree_map(sd, jax.eval_shape(
-        lambda: decoder_lm.init_state(cfg, 1, 65536)))
+    params = jax.tree_util.tree_map(sd, lm_once.param_shapes(cfg))
+    state = jax.tree_util.tree_map(sd, lm_once.state_shapes(cfg, 1, 65536))
     ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
     pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
 
@@ -758,12 +757,13 @@ def test_dense_mla_segment_program_carries_latents_only_on_v5e(v5e):
         return decoder_lm.forward_segment(p, i, at, s, cfg, pallas=True,
                                           interpret=False)
 
-    hidden, carried = jax.eval_shape(lm_segment, params, ids, pos, state)
+    lowered = jax.jit(lm_segment, donate_argnums=(3,)).lower(
+        params, ids, pos, state)
+    hidden, carried = lowered.out_info          # of the one trace
     assert hidden.shape == (1, 4096, 4096)
     assert set(carried) == {"mixer", "pairs"} and set(carried["mixer"]) == {"kv"}
     assert carried["mixer"]["kv"].shape == (6, 1, 65536, 320)
-    done = jax.jit(lm_segment, donate_argnums=(3,)).lower(
-        params, ids, pos, state).compile()
+    done = lowered.compile()
     text = done.as_text()
     assert text.count("tpu_custom_call") == 5 and " while(" in text
     expanded = re.findall(r"= bf16\[32,65536,128\]", text)
@@ -797,10 +797,8 @@ def test_window_gqa_segment_program_keeps_two_shapes_of_state_on_v5e(v5e):
     model = manifest.load_config(manifest.load_manifest(),
                                  "mellum2-12b-a2.5b")["model"]
     cfg = decoder_lm.DecoderLMConfig(**model)
-    params = jax.tree_util.tree_map(sd, jax.eval_shape(
-        lambda: decoder_lm.init_params(cfg, "m")))
-    state = jax.tree_util.tree_map(sd, jax.eval_shape(
-        lambda: decoder_lm.init_state(cfg, 1, 32768)))
+    params = jax.tree_util.tree_map(sd, lm_once.param_shapes(cfg))
+    state = jax.tree_util.tree_map(sd, lm_once.state_shapes(cfg, 1, 32768))
     ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
     pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
 
@@ -808,13 +806,14 @@ def test_window_gqa_segment_program_keeps_two_shapes_of_state_on_v5e(v5e):
         return decoder_lm.forward_segment(p, i, at, s, cfg, pallas=True,
                                           interpret=False)
 
-    hidden, carried = jax.eval_shape(lm_segment, params, ids, pos, state)
+    lowered = jax.jit(lm_segment, donate_argnums=(3,)).lower(
+        params, ids, pos, state)
+    hidden, carried = lowered.out_info          # of the one trace
     assert hidden.shape == (1, 4096, 2304)
     assert set(carried) == {"mixer", "pairs", "tiles"}
     assert carried["mixer"]["window"]["k"].shape == (9, 1, 4, 1024, 128)
     assert carried["mixer"]["full"]["k"].shape == (3, 1, 4, 32768, 128)
-    done = jax.jit(lm_segment, donate_argnums=(3,)).lower(
-        params, ids, pos, state).compile()
+    done = lowered.compile()
     text = done.as_text()
     assert text.count("tpu_custom_call") == 16 and " while(" in text
     calls = [ln for ln in text.splitlines() if "custom_call_target=\"tpu_custom_call\"" in ln]

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import lm_once
 import numpy as np
 import pytest
 
@@ -304,20 +305,21 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     wide = {**TINY, "n_experts": 64, "n_experts_per_token": 8}
     whole = decoder_lm.DecoderLMConfig(**{**wide, "n_experts_held": 64})
     n = jax.random.normal(jax.random.PRNGKey(5), (1, 300, 64), jnp.float32)
-    layer = lambda p: jax.tree_util.tree_map(lambda a: a[0], p["expert_layers"])  # noqa: E731
-    p_whole = layer(decoder_lm.init_params(whole, "window-c"))
+    layer = lambda cfg: lm_once.first_layer(  # noqa: E731
+        lm_once.params(cfg, "window-c")["expert_layers"])
+    p_whole = layer(whole)
     assert not {"router_bias", "ws_gate", "ws_up", "ws_down"} & set(p_whole)
-    y_whole, counted = decoder_lm._experts_ffn(p_whole, n, whole, {})
+    y_whole, counted = lm_once.experts_program(whole)(p_whole, n)
     assert set(counted) == {"pairs", "tiles"}
+    down_whole = np.asarray(p_whole["we_down"])
     total, pairs, tiles = 0.0, 0.0, 0.0
     for first in (0, 16, 32, 48):
         cfg = decoder_lm.DecoderLMConfig(**{**wide, "n_experts_held": 16,
                                             "expert_first": first})
-        p = layer(decoder_lm.init_params(cfg, "window-c"))
-        np.testing.assert_array_equal(
-            np.asarray(p["we_down"]),
-            np.asarray(p_whole["we_down"][first:first + 16]))
-        y, held = decoder_lm._experts_ffn(p, n, cfg, {})
+        p = layer(cfg)
+        np.testing.assert_array_equal(np.asarray(p["we_down"]),
+                                      down_whole[first:first + 16])
+        y, held = lm_once.experts_program(cfg)(p, n)
         total = total + y
         pairs += float(held["pairs"])
         tiles += float(held["tiles"])
@@ -336,9 +338,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
             "window-c", 0, normed) for first in (0, 16, 32, 48))
     np.testing.assert_allclose(np.asarray(u + parts), np.asarray(want),
                                atol=1e-5)
-    got = u + decoder_lm._experts_ffn(
-        p_whole, decoder_lm.rms_norm(u, p_whole["ln2"], 1e-6)[None], whole,
-        {})[0][0]
+    got = lm_once.expert_layer_program(whole)(p_whole, u)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
@@ -354,7 +354,7 @@ def test_held_tiles_counts_whole_tiles_an_expert():
 
 def test_the_carried_state_has_two_shapes_side_by_side():
     cfg = decoder_lm.DecoderLMConfig(**TINY)
-    params = decoder_lm.init_params(cfg, "window-b")
+    params = lm_once.params(cfg, "window-b")
     assert set(params) == {"embed", "head", "final_norm", "expert_layers"}
     assert set(params["expert_layers"]) == {
         "wq", "wk", "wv", "wo", "q_norm", "k_norm", "ln1", "ln2", "w_router",
@@ -364,12 +364,12 @@ def test_the_carried_state_has_two_shapes_side_by_side():
         np.asarray(params["expert_layers"]["q_norm"]),
         np.full((4, 16), decoder_lm.QUERY_NORM_GAIN, np.float32))
     ids = np.random.default_rng(3).integers(0, 3000, (1, 512)).astype(np.int32)
-    state = decoder_lm.init_state(cfg, 1, 1024)
+    state = lm_once.state(cfg, 1, 1024)
     assert set(state) == {"mixer", "pairs", "tiles"}
     assert set(state["mixer"]) == {"window", "full"}
+    step = lm_once.segment_program(cfg)
     for pos0 in (0, 512):
-        hidden, state = jax.jit(lambda p, i, at, s: decoder_lm.forward_segment(
-            p, i, at, s, cfg))(params, ids, jnp.int32(pos0), state)
+        hidden, state = step(params, ids, jnp.int32(pos0), state)
         # Two window layers keep their last 300 keys, whatever the document's
         # length; two full layers keep every key.
         for leaf in ("k", "v"):
@@ -426,9 +426,9 @@ def test_carried_caches_answer_as_the_parents_form_bit_for_bit():
     on this host, and so how it orders a float32 sum, follows the program
     around the arithmetic."""
     cfg = decoder_lm.DecoderLMConfig(**TINY)
-    params = decoder_lm.init_params(cfg, "window-carried")
+    params = lm_once.params(cfg, "window-b")
     ids = np.random.default_rng(11).integers(0, 3000, (1, 768)).astype(np.int32)
-    mine = theirs = decoder_lm.init_state(cfg, 1, 768)
+    mine = theirs = lm_once.state(cfg, 1, 768)
     for pos0 in (0, 256, 512):
         segment, at = ids[:, pos0:pos0 + 256], jnp.int32(pos0)
         with jax.disable_jit():
@@ -554,13 +554,13 @@ def test_a_model_by_kind_runs_with_a_dense_ffn_too():
     """No expert layer: the state is the mixer's alone, nothing is counted."""
     cfg = decoder_lm.DecoderLMConfig(**{**TINY, "n_experts": 0, "d_ff": 96})
     decoder_lm.validate(cfg)
-    params = decoder_lm.init_params(cfg, "window-d")
-    state = decoder_lm.init_state(cfg, 1, 512)
+    params = lm_once.params(cfg, "window-d")
+    state = lm_once.state(cfg, 1, 512)
     assert set(state) == {"window", "full"}
     ids = jnp.asarray(np.random.default_rng(2).integers(0, 3000, (1, 512)),
                       jnp.int32)
-    hidden, state = decoder_lm.forward_segment(params, ids, jnp.int32(0),
-                                               state, cfg)
+    hidden, state = lm_once.segment_program(cfg)(params, ids, jnp.int32(0),
+                                                 state)
     assert hidden.shape == (1, 512, 64) and set(state) == {"window", "full"}
 
 
