@@ -33,27 +33,53 @@ most ``exp(-lower_bound x SUB)`` = ``e^80`` inside the row's own, which is
 what ``lower_bound >= -5`` is for (:func:`pallas_supported`,
 ``decoder_lm.validate``). The unit lower-triangular system is solved in
 blocks: the ``SUB x SUB`` diagonal blocks are inverted by the finite product
-``(I - N)(I + N^2)(I + N^4)(I + N^8)`` (``N^16 = 0``), the rest is
-nilpotent of degree ``CHUNK / SUB`` over those blocks and solved by the same
-identity one level up; a product over the whole chunk at once would sum
-binomially large terms of alternating sign where keys repeat.
+``(I - N)(I + N^2)(I + N^4)(I + N^8)`` (``N^16 = 0``); the rest is
+nilpotent of degree ``CHUNK / SUB`` over those blocks and is solved exactly
+either way: in the kernel by forward substitution over the sub-blocks,
+``U_r = (I + N_rr)^-1 (R_r - sum_{s<r} N_rs U_s)``, in the ``jax.numpy`` form
+by ``CHUNK / SUB - 1`` turns of ``Y = Y0 - M Y`` (:func:`_solve_blocks`). A
+product over the whole chunk at once would sum binomially large terms of
+alternating sign where keys repeat.
 
 The state is float32, ``[H, d (keys), d (values)]``. The same function serves
 a document given whole and one given as segments: ``initial_state`` in,
 final state out. On the chip one Pallas kernel a layer (grid: head block x
 chunk, the chunk axis sequential, the heads' states resident in the output
-block across it, ``HEADS_A_STEP`` independent heads a step so that one's
-chain of small matmuls runs under another's); elsewhere, and for shapes off
-the lane width, the same chunked arithmetic in plain ``jax.numpy`` (float32).
-Which runs is read from shapes and platform (:func:`pallas_supported`); no
-option, environment variable or ``model_config`` key chooses. On the chip the
-matmuls that meet the state or the values take bf16 operands, the triangular
-system float32; sums are float32.
+block across it); elsewhere, and for shapes off the lane width, the same
+chunked arithmetic in plain ``jax.numpy`` (float32). Which runs is read from
+shapes and platform (:func:`pallas_supported`); no option, environment
+variable or ``model_config`` key chooses. On the chip the matmuls that meet
+the state or the values take bf16 operands, the triangular system's float32
+ones (the MXU's float32 product is ONE pass at bf16's rows a cycle on this
+chip; bf16 operands there bought nothing when tried); sums are float32.
+
+The kernel's step, and why it is written as it is (PERF.md, PR 50):
+
+- A step holds ``gcd(H, HEADS_A_STEP)`` heads. Their products are stated IN
+  TURN, every head's first, then every head's second: an MXU takes its
+  products in the order the program states them and a product's result
+  comes some 130 cycles after its last row went in, so a head's chain of
+  dependent products (the inverse's six, the substitution's seven) waits
+  under the other heads' products only if those stand between its own.
+  Stated a head after the other the same arithmetic took four times as long.
+- The diagonal blocks never stand in a ``[CHUNK, CHUNK]`` matrix of mostly
+  zeros: a head's four lie side by side, ``[SUB, CHUNK]``, and where the
+  step holds an even number of heads a PAIR's eight, ``[SUB, 128]``: a
+  vreg's lanes full. ``x @ spread(y)`` (``y``'s blocks down the diagonal of
+  a ``[128, 128]`` matrix) multiplies block by block, so the finite product
+  is six products of 16 rows a pair where it was twelve of 64. A step of an
+  odd number of heads (``H`` odd) inverts each head's four blocks on 64
+  lanes.
+- The substitution's products are ``[SUB, SUB] x [SUB, d]`` and
+  ``[SUB, r SUB] x [r SUB, d]``: the rows the system's structure has, where
+  ``Td (N - Nd)``, ``Td R`` and three turns of ``Y0 - M U`` were five
+  products of 64 rows.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -70,8 +96,8 @@ CHUNK = 64
 SUB = 16
 # The largest exponent a column's factor may take: ``-lower_bound x SUB``.
 _MAX_EXPONENT = 80.0
-# Heads a grid step.
-HEADS_A_STEP = 8
+# The most heads a grid step holds.
+HEADS_A_STEP = 16
 
 
 def zero_state(n_heads: int, d_head: int) -> jax.Array:
@@ -100,7 +126,8 @@ def kda_step(q, k, v, g, beta, state):
 
 def _solve_blocks(N, R):
     """``(I + N)^-1 R`` for ``N [..., c, c]`` strictly lower triangular, by
-    blocks of ``SUB`` (the module docstring's two levels), float32."""
+    blocks of ``SUB`` (the module docstring's two levels: the blocks' finite
+    product, then the turns), float32."""
     c = N.shape[-1]
     eye = jnp.eye(c, dtype=N.dtype)
     block = jnp.arange(c) // SUB
@@ -154,9 +181,13 @@ def _kda_jnp(q, k, v, g, beta, state):
 
 def _kda_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, s_ref,
                 *, heads: int, d: int):
-    """One (head block, chunk) step: ``heads`` heads, each on its own."""
+    """One (head block, chunk) step: ``heads`` heads, each on its own but for
+    the lanes its diagonal blocks share with a neighbour's (the module
+    docstring's "the triangular system")."""
     f32, bf16 = jnp.float32, jnp.bfloat16
     c, n_sub = CHUNK, CHUNK // SUB
+    side = 2 if heads % 2 == 0 else 1       # heads whose blocks share lanes
+    wide = side * c                         # lanes of their blocks
     nn = (((1,), (0,)), ((), ()))           # [m, k] x [k, n]
     nt = (((1,), (1,)), ((), ()))           # [m, k] x [n, k]
     tn = (((0,), (0,)), ((), ()))           # [k, m] x [k, n]
@@ -169,15 +200,33 @@ def _kda_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, s_ref,
     def _():
         s_ref[...] = s0_ref[...]
 
-    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    strict, lower = col < row, col <= row
-    diagonal = (row // SUB) == (col // SUB)
-    eye = (row == col).astype(f32)
-    sub_of_row = jax.lax.broadcasted_iota(jnp.int32, (c, d), 0) // SUB
+    def iota(shape, axis):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+    lower = iota((c, c), 1) <= iota((c, c), 0)
+    # A sub-block's rows against the chunk's columns: the strictly lower part
+    # of the block they make with the sub-block's own columns.
+    row, col = iota((SUB, c), 0), iota((SUB, c), 1)
+    own_strict = [(col >= r * SUB) & (col - r * SUB < row)
+                  for r in range(n_sub)]
+    # ``wide`` lanes of SUB x SUB blocks: the identity in each, and which
+    # entries of a [wide, wide] matrix lie in a block of its diagonal.
+    eye = (iota((SUB, wide), 1) % SUB == iota((SUB, wide), 0)).astype(f32)
+    on_diagonal = (iota((wide, wide), 0) // SUB
+                   == iota((wide, wide), 1) // SUB)
+    sub_of_row = iota((c, d), 0) // SUB
     beta_all = beta_ref[0]                                   # [c, heads] f32
 
-    for a in range(heads):
+    def spread(blocks):
+        """Blocks side by side, [SUB, wide] → the same blocks down the
+        diagonal of a [wide, wide] matrix (zeros elsewhere), so that
+        ``x @ spread(y)`` multiplies block by block."""
+        return jnp.where(on_diagonal, jnp.concatenate(
+            [blocks] * (wide // SUB), axis=0), 0.0)
+
+    def before_the_system(a):
+        """Head ``a`` of the step up to the triangular system: what the
+        system's right-hand side, its matrix and the output need."""
         lanes = slice(a * d, (a + 1) * d)
         q = q_ref[:, lanes].astype(f32)                      # [c, d]
         k = k_ref[:, lanes].astype(f32)
@@ -195,39 +244,75 @@ def _kda_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, s_ref,
         inside = jnp.exp(G - start_of_row)                   # <= 1
         rows = jnp.concatenate([q * inside, k * inside], axis=0)  # [2c, d]
         # Sub-block r's rows of A and B: its rows against the columns seen
-        # from where it starts.
-        a_rows, b_rows = [], []
+        # from where it starts. Of N = Diag(beta) A the system wants the
+        # columns of EARLIER sub-blocks as they lie (``earlier[r]``,
+        # [SUB, r SUB]) and the sub-block's own, strictly lower, side by side
+        # with the other sub-blocks' (``blocks``, [SUB, c]).
+        b_rows, earlier = [], [None]
+        blocks = jnp.zeros((SUB, c), f32)
         for r in range(n_sub):
+            mine = slice(r * SUB, (r + 1) * SUB)
             cols = k * jnp.exp(jnp.minimum(starts[r] - G, _MAX_EXPONENT))
-            mine = jnp.concatenate([
-                rows[r * SUB:(r + 1) * SUB],
-                rows[c + r * SUB:c + (r + 1) * SUB]], axis=0)     # [2 SUB, d]
-            both = dot(mine, cols, nt)                       # [2 SUB, c]
+            both = dot(jnp.concatenate([rows[mine], rows[c:][mine]], axis=0),
+                       cols, nt)                             # [2 SUB, c]
             b_rows.append(both[:SUB])
-            a_rows.append(both[SUB:])
-        A = jnp.concatenate(a_rows, axis=0)                  # [c, c]
-        B = jnp.concatenate(b_rows, axis=0)
+            n_rows = both[SUB:] * beta[mine]
+            blocks = blocks + jnp.where(own_strict[r], n_rows, 0.0)
+            if r:
+                earlier.append(n_rows[:, :r * SUB])
+        B = jnp.concatenate(b_rows, axis=0)                  # [c, c]
 
         eg = jnp.exp(G)
         from_state = dot(jnp.concatenate([q * eg, k * eg], axis=0), S)
         R = beta * (v - from_state[c:])                      # [c, d]
+        return dict(a=a, lanes=lanes, k=k, G=G, S=S, B=B, R=R, blocks=blocks,
+                    earlier=earlier, o=from_state[:c])
 
-        N = jnp.where(strict, A, 0.0) * beta
-        Nd = jnp.where(diagonal, N, 0.0)
-        Td, power = eye - Nd, Nd
-        for _ in range(3):
-            power = dot(power, power, dtype=f32)
-            Td = Td + dot(Td, power, dtype=f32)
-        M = dot(Td, N - Nd, dtype=f32)
-        Y0 = dot(Td, R, dtype=f32)
-        U = Y0
-        for _ in range(n_sub - 1):
-            U = Y0 - dot(M, U, dtype=f32)
+    # Every head of the step advances TOGETHER, a product of each after the
+    # same product of the one before: an MXU takes its products in the order
+    # the program states them, so a head's chain of dependent products waits
+    # out its latency under the other heads' only if theirs stand between.
+    step = [before_the_system(a) for a in range(heads)]
+    groups = [step[first:first + side] for first in range(0, heads, side)]
 
-        o = from_state[:c] + dot(jnp.where(lower, B, 0.0), U)
-        o_ref[:, lanes] = o.astype(o_ref.dtype)
+    # (I + N_rr)^-1 of every diagonal block of a group at once, by the finite
+    # product: ``power`` and ``inverse`` are [SUB, wide], a block every SUB
+    # lanes.
+    powers = [jnp.concatenate([h["blocks"] for h in group], axis=1)
+              for group in groups]
+    inverses = [eye - power for power in powers]
+    spreads = [spread(power) for power in powers]
+    for _ in range(3):                     # (I + N^2)(I + N^4)(I + N^8)
+        powers = [dot(power, across, dtype=f32)
+                  for power, across in zip(powers, spreads)]
+        spreads = [spread(power) for power in powers]
+        inverses = [inverse + dot(inverse, across, dtype=f32)
+                    for inverse, across in zip(inverses, spreads)]
+    # A head's four [SUB, SUB] inverses, in the order of ``step``.
+    inverse_of = [
+        [inverse[:, at:at + SUB] for at in range(j * c, (j + 1) * c, SUB)]
+        for inverse in inverses for j in range(side)]
+
+    # U_r = (I + N_rr)^-1 (R_r - sum_{s<r} N_rs U_s), a sub-block after the
+    # other.
+    solved = [[] for _ in step]
+    for r in range(n_sub):
+        mine = slice(r * SUB, (r + 1) * SUB)
+        sides = [h["R"][mine] for h in step]
+        if r:
+            sides = [rhs - dot(h["earlier"][r], jnp.concatenate(us, axis=0),
+                               dtype=f32)
+                     for rhs, h, us in zip(sides, step, solved)]
+        for rhs, blocks, us in zip(sides, inverse_of, solved):
+            us.append(dot(blocks[r], rhs, dtype=f32))
+
+    for h, us in zip(step, solved):
+        U = jnp.concatenate(us, axis=0)                      # [c, d]
+        k, G, S = h["k"], h["G"], h["S"]
+        o = h["o"] + dot(jnp.where(lower, h["B"], 0.0), U)
+        o_ref[:, h["lanes"]] = o.astype(o_ref.dtype)
         end = G[c - 1:c, :]                                  # [1, d]
-        s_ref[a] = jnp.exp(end).reshape(d, 1) * S + dot(
+        s_ref[h["a"]] = jnp.exp(end).reshape(d, 1) * S + dot(
             k * jnp.exp(end - G), U, tn)
 
 
@@ -239,7 +324,7 @@ def _kda_call(q, k, v, g, beta, state, *, n_heads: int, interpret: bool):
     S, HD = q.shape
     H = n_heads
     d = HD // H
-    hb = HEADS_A_STEP if H % HEADS_A_STEP == 0 else 1
+    hb = math.gcd(H, HEADS_A_STEP)
     n_chunks = S // CHUNK
     f32 = jnp.float32
     G = jnp.cumsum(g.astype(f32).reshape(n_chunks, CHUNK, HD),

@@ -67,20 +67,33 @@ BUCKETS = (1024, 2048)  # the op's segment sizes, halved for the CPU
 
 # ---- (a) the delta rule's forms against the recurrence --------------------
 
-def _inputs(S, H, d, at_the_bound):
+def _inputs(S, H, d, decays):
     """Normalised queries and keys, decays between the bound and 0; with
-    ``at_the_bound`` the second chunk's every decay 1e-4 off the bound: a
-    sub-block's column factor is ``exp(80)`` there."""
+    ``"a chunk at the bound"`` the second chunk's every decay 1e-4 off the
+    bound: a sub-block's column factor is ``exp(80)`` there. With ``"keys
+    repeated"`` a head has FIVE keys, taken in turn (each three times a
+    sub-block and in every sub-block), ``beta`` within 0.03 of 1 and decays
+    within 1e-3 of 0: the rows of the triangular system are nearly equal,
+    which is what the diagonal blocks' finite product is there for. A query
+    there asks for its own token's key (the output is the value just
+    written, scaled), and values and state are 0.3 of the other cases': what
+    a token writes is then the DIFFERENCE of two values, a chunk's thirteen
+    writes under one key cancel in the state, and bf16's rounding of each
+    is measured against the values, not against what is left of them."""
     ks = jax.random.split(jax.random.PRNGKey(3), 6)
     unit = lambda a: a / jnp.sqrt((a * a).sum(-1, keepdims=True) + 1e-6)  # noqa: E731
     q = unit(jax.random.normal(ks[0], (S, H, d))) * d ** -0.5
     k = unit(jax.random.normal(ks[1], (S, H, d)))
     v = jax.random.normal(ks[2], (S, H, d))
     g = -5.0 * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], (S, H, d)) - 2.0)
-    if at_the_bound:
-        g = g.at[kda.CHUNK:2 * kda.CHUNK].set(-5.0 + 1e-4)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (S, H)))
     state = 0.3 * jax.random.normal(ks[5], (H, d, d))
+    if decays == "a chunk at the bound":
+        g = g.at[kda.CHUNK:2 * kda.CHUNK].set(-5.0 + 1e-4)
+    elif decays == "keys repeated":
+        k = k[jnp.arange(S) % 5]
+        q, v, state = k * d ** -0.5, 0.3 * v, 0.3 * state
+        g, beta = g * 2e-4, 1.0 - 0.03 * beta
     return q, k, v, g, beta, state
 
 
@@ -110,12 +123,26 @@ def _by_steps(q, k, v, g, beta, state):
     return o, state
 
 
-@pytest.mark.parametrize("at_the_bound", [False, True],
-                         ids=["decays anywhere", "a chunk at the bound"])
-@pytest.mark.parametrize("form", ["jax.numpy", "kernel", "one token"])
-def test_the_delta_rule_matches_the_recurrence(form, at_the_bound):
-    S, H, d = 3 * kda.CHUNK - 20, 2, 128       # a last chunk padded
-    q, k, v, g, beta, state = _inputs(S, H, d, at_the_bound)
+# The kernel's steps by head count (``gcd(heads, HEADS_A_STEP)`` heads a step):
+# 2 heads are one PAIR, 3 are three steps of ONE head (the unpaired step: the
+# blocks of one head on 64 lanes), 8 one step of four pairs, 32 two grid steps
+# of eight pairs (so a pair's lanes and a head block's state are both
+# indexed).
+_FORMS = [(form, decays, 2) for form in ("jax.numpy", "kernel", "one token")
+          for decays in ("decays anywhere", "a chunk at the bound")] + [
+    ("kernel", decays, heads) for heads in (3, 8, 32)
+    for decays in ("decays anywhere", "a chunk at the bound")] + [
+    ("jax.numpy", "keys repeated", 2)] + [
+    ("kernel", "keys repeated", heads) for heads in (2, 3, 8, 32)]
+
+
+@pytest.mark.parametrize(
+    "form, decays, heads", _FORMS,
+    ids=[f"{form}-{decays}" + (f"-{heads} heads" if heads != 2 else "")
+         for form, decays, heads in _FORMS])
+def test_the_delta_rule_matches_the_recurrence(form, decays, heads):
+    S, H, d = 3 * kda.CHUNK - 20, heads, 128   # a last chunk padded
+    q, k, v, g, beta, state = _inputs(S, H, d, decays)
     flat = lambda a: a.reshape(S, H * d)  # noqa: E731
     with jax.default_matmul_precision("highest"):
         if form == "kernel":
@@ -139,10 +166,38 @@ def test_the_delta_rule_matches_the_recurrence(form, at_the_bound):
                                atol=s_tol)
 
 
+@pytest.mark.parametrize("decays", ["decays anywhere", "keys repeated"])
+def test_a_pair_of_heads_computes_what_each_head_computes_alone(decays):
+    """Eight heads in one call run as four PAIRS (the diagonal blocks'
+    inverse of two heads side by side on the lanes); the same heads one a
+    call run the unpaired step. A head's numbers must not know its
+    neighbour: equal to float32 rounding (a product's zeros are exact, so
+    only the order of a sum may differ), outputs rounded to bf16 once."""
+    S, H, d = 2 * kda.CHUNK, 8, 128
+    q, k, v, g, beta, state = (
+        a if i > 2 else a.astype(BF16)
+        for i, a in enumerate(_inputs(S, H, d, decays)))
+    flat = lambda a: a.reshape(S, -1)  # noqa: E731
+    run = functools.partial(kda.kda_chunks, lower_bound=-5.0, pallas=True,
+                            interpret=True)
+    with jax.default_matmul_precision("highest"):
+        o, new = run(flat(q), flat(k), flat(v), flat(g), beta, n_heads=H,
+                     initial_state=state)
+        for h in range(H):
+            o_h, new_h = run(q[:, h], k[:, h], v[:, h], g[:, h],
+                             beta[:, h:h + 1], n_heads=1,
+                             initial_state=state[h:h + 1])
+            np.testing.assert_allclose(
+                np.asarray(o[:, h * d:(h + 1) * d], np.float32),
+                np.asarray(o_h, np.float32), atol=2e-3 * 2 ** -8, rtol=2 ** -7)
+            np.testing.assert_allclose(np.asarray(new[h]),
+                                       np.asarray(new_h[0]), atol=2e-6)
+
+
 def test_the_references_recurrence_is_the_same_rule():
     """The benchmark's ``delta_rule`` (an update of rank one a token) against
     the matrix form above: two statements of one equation."""
-    q, k, v, g, beta, state = _inputs(100, 2, 16, False)
+    q, k, v, g, beta, state = _inputs(100, 2, 16, "decays anywhere")
     with jax.default_matmul_precision("highest"):
         want, _ = _recurrence(q, k, v, g, beta, jnp.zeros_like(state))
         got = jax.jit(ref.delta_rule)(q, k, v, g, beta)
