@@ -37,13 +37,27 @@ loop in front of them), 7-9 ns each on the scalar core, 512 a full tile.
 ``tile_expert`` and ``n_tiles`` are prefetched too: a step past the last
 tile names that tile's blocks again (no copy) and computes nothing, so the
 program is fixed-shape at the worst case and costs what the routed rows
-cost. What bounds it is the caller's rows an expert a segment: at about 128
-(a held SHARE of a wide router: deepseek-v3.2, mistral-small-4-119b) the
-read of the experts' weights once, for tiles half empty; at 512 (every expert
-held, 8 pairs a token: mellum2-12b-a2.5b) the three matmuls as the kernel
-compiler lowers them (22.5 us a tile of 256 rows against the MXU's 16.1),
-then the rows' descriptors (about 4 us a full tile) (PERF.md section 5). The
-expert's width is walked in steps of :func:`width_step` columns.
+cost. The expert's width is walked in steps of :func:`width_step` columns.
+
+**A tile computes the rows it HOLDS** (PR 51). A tile's real rows come first
+in it, so its unpack, its three matmuls and its pack run over its first
+``ceil(tile_rows / SUB_ROWS)`` sub-blocks of 128 rows, in ONE body for every
+such count (128 rows or all 256: a branch on ``tile_rows``, one kernel
+whatever the caller's routing). The products of a row do not depend on the
+rows beside it, so every real row's words are what the whole tile gave, to
+the bit. What that uncovered is what bounds the kernel (PERF.md section 5,
+the kernel alone on the chip with its products taken out): at about 60 or 128
+rows an expert a segment (a held SHARE of a wide router: ling-3.0-flash-vl,
+mistral-small-4-119b, deepseek-v3.2) the STREAM of the experts' weights, a
+step's three blocks fetched one grid step ahead at 590 to 690 GB/s of the
+memory's 819 (a tile of ling's 59 rows was paced by the MXU's passes over 256
+before, 20.3 us against 17.2 now; deepseek's was at its weights' stream
+already); at 512 (every expert held, 8 pairs a token: mellum2-12b-a2.5b) the
+three matmuls as the kernel compiler lowers them (22.5 us a full tile against
+the MXU's 16.1), then the rows' descriptors (about 4 us a full tile), and a
+spill tile of a few dozen rows saves 3.3 us of its 11 because the NEXT
+expert's weights (12.4 MB, fetched under an expert's last tile and no sooner)
+take 18 us to arrive.
 
 The weights are read WHERE THEY LIE: the operands are the model's stacked
 leaves ``[L, E, ...]`` and one more prefetched scalar, ``layer``, is the
@@ -67,6 +81,11 @@ from agent_tpu.obs.trace import part
 
 # Rows a tile: an expert's rows are padded to whole tiles.
 ROW_TILE = 256
+# Rows a sub-block: a tile's unpack, matmuls and pack run over its sub-blocks
+# that hold a real row, not over the tile. Under 128 rows the MXU's weight
+# loads no longer hide (four sub-blocks of 64 read +50 % on a full tile, PR
+# 45); ``models/moe.py: held_work`` counts the rows by the same number.
+SUB_ROWS = 128
 # Columns of the expert's width a step.
 WIDTH_TILE = 256
 _VMEM_LIMIT = 100 * 1024 * 1024
@@ -264,6 +283,16 @@ def _ffn_kernel(token_ref, slot_ref, tile_expert_ref, tile_rows_ref,
     n = d // 256                           # sublane rows a row's words fill
     nn = (((1,), (0,)), ((), ()))
     OUT = 2                                # rows_in's two slots have sem 0, 1
+    sub = min(SUB_ROWS, tm)
+
+    def live(block):
+        """``block(rows)`` on the tile's first ``rows`` rows, its sub-blocks
+        that hold a real row: a body for every count of them (one body a
+        sub-block loads every weight tile twice on a full tile: 0.2 us of
+        its 22.5 at mellum2's widths)."""
+        blocks = jax.lax.div(tile_rows_ref[t] + (sub - 1), sub)
+        for i in range(1, tm // sub + 1):
+            pl.when(blocks == i)(functools.partial(block, i * sub))
 
     def for_rows(lo, hi, one):
         """``one(j)`` for ``lo <= j < hi``, ``_COPIES_A_TURN`` a turn."""
@@ -307,15 +336,18 @@ def _ffn_kernel(token_ref, slot_ref, tile_expert_ref, tile_rows_ref,
                 fetch(0, 0, tile_rows_ref[0])
 
             wait(tile_rows_ref[t], t % 2)
-            # Lane tile c of every row of the tile is ONE strided read; one
-            # expression over them all (every traced equation is paid again
-            # at every start, compile cache or not).
-            low, high = _halves(jnp.concatenate(
-                [rows_in[t % 2, pl.ds(c, tm, stride=n), :] for c in range(n)],
-                axis=1))
-            x_ref[:, :d // 2] = low.astype(x_ref.dtype)
-            x_ref[:, d // 2:] = high.astype(x_ref.dtype)
-            acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+            @live
+            def _(rows):
+                # Lane tile c of every row is ONE strided read; one
+                # expression over them all (every traced equation is paid
+                # again at every start, compile cache or not).
+                low, high = _halves(jnp.concatenate(
+                    [rows_in[t % 2, pl.ds(c, rows, stride=n), :]
+                     for c in range(n)], axis=1))
+                x_ref[:rows, :d // 2] = low.astype(x_ref.dtype)
+                x_ref[:rows, d // 2:] = high.astype(x_ref.dtype)
+                acc_ref[:rows, :] = jnp.zeros((rows, d), f32)
 
         # The next tile's rows, a share of them every width step: where the
         # weights stream, the descriptors go out while they do.
@@ -325,16 +357,19 @@ def _ffn_kernel(token_ref, slot_ref, tile_expert_ref, tile_rows_ref,
             fetch(t + 1, f * share,
                   jnp.minimum((f + 1) * share, tile_rows_ref[t + 1]))
 
-        x = x_ref[...]
-        gate = jax.lax.dot_general(x, wg_ref[0, 0], nn,
-                                   preferred_element_type=f32)
-        up = jax.lax.dot_general(x, wu_ref[0, 0], nn,
-                                 preferred_element_type=f32)
-        if limit is not None:
-            gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
-        h = (jax.nn.silu(gate) * up).astype(x.dtype)
-        acc_ref[...] += jax.lax.dot_general(h, wd_ref[0, 0], nn,
-                                            preferred_element_type=f32)
+        @live
+        def _(rows):
+            x = x_ref[:rows, :]
+            gate = jax.lax.dot_general(x, wg_ref[0, 0], nn,
+                                       preferred_element_type=f32)
+            up = jax.lax.dot_general(x, wu_ref[0, 0], nn,
+                                     preferred_element_type=f32)
+            if limit is not None:
+                gate, up = (jnp.minimum(gate, limit),
+                            jnp.clip(up, -limit, limit))
+            h = (jax.nn.silu(gate) * up).astype(x.dtype)
+            acc_ref[:rows, :] += jax.lax.dot_general(
+                h, wd_ref[0, 0], nn, preferred_element_type=f32)
 
         @pl.when(f == n_f - 1)
         def _():
@@ -342,11 +377,14 @@ def _ffn_kernel(token_ref, slot_ref, tile_expert_ref, tile_rows_ref,
             def _():
                 wait(tile_rows_ref[t - 1], OUT)
 
-            y = acc_ref[...].astype(x_ref.dtype).astype(f32)
-            words = _words(y[:, :d // 2], y[:, d // 2:])
-            for c in range(n):
-                rows_out[pl.ds(c, tm, stride=n), :] = jax.lax.slice_in_dim(
-                    words, c * 128, (c + 1) * 128, axis=1)
+            @live
+            def _(rows):
+                y = acc_ref[:rows, :].astype(x_ref.dtype).astype(f32)
+                words = _words(y[:, :d // 2], y[:, d // 2:])
+                for c in range(n):
+                    rows_out[pl.ds(c, rows, stride=n), :] = (
+                        jax.lax.slice_in_dim(words, c * 128, (c + 1) * 128,
+                                             axis=1))
 
             def one(j):
                 pltpu.make_async_copy(

@@ -1353,14 +1353,15 @@ def init_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
     """What :func:`forward_segment` takes as ``state`` for a document's
     FIRST segment: ``None`` where the mixer starts from nothing and no layer
     routes; else ``{"mixer": the mixer's empty state or None, "pairs": 0}``,
-    with ``"tiles": 0`` beside them where the layers come in kinds
-    (see :func:`forward_segment`)."""
+    with ``"tiles": {"visited": 0, "rows": 0}`` beside them where the layers
+    come in kinds (see :func:`forward_segment`)."""
     make = MIXER_STATES.get(cfg.mixer)
     mixer = make(cfg, batch, cache_len) if make else None
     if cfg.n_experts:
         zero = jnp.zeros((), jnp.float32)
         return {"mixer": mixer, "pairs": zero,
-                **({"tiles": zero} if layer_kinds(cfg) else {})}
+                **({"tiles": {"visited": zero, "rows": zero}}
+                   if layer_kinds(cfg) else {})}
     return mixer
 
 
@@ -1396,7 +1397,8 @@ def _experts_ffn(p: Params, n: jax.Array, cfg: DecoderLMConfig, kernel_opts):
     """n [B, S, d] (normed) → (shared expert, where the model has one, + the
     routed experts held here, [B, S, d]; the (token, expert) pairs routed
     here: a float32 scalar, or ``{"pairs", "tiles"}`` where the model's
-    layers come in kinds: the ``ROW_TILE`` tiles those pairs fill beside
+    layers come in kinds: ``moe.held_work`` beside them, the ``ROW_TILE``
+    tiles those pairs fill and the rows the grouped matmul computes for
     them). ``p``: one layer's
     leaves; where it has ``expert_layer`` (:func:`_read_in_place`), its
     ``EXPERT_LEAVES`` are the whole group's stacks, already plain and in the
@@ -1432,8 +1434,9 @@ def _experts_ffn(p: Params, n: jax.Array, cfg: DecoderLMConfig, kernel_opts):
     y = routed.astype(dtype).reshape(B, S, d)
     counted = pairs.astype(jnp.float32)
     if layer_kinds(cfg):
-        counted = {"pairs": counted, "tiles": moe.held_tiles(
-            experts, cfg.expert_first, cfg.n_experts_held).astype(jnp.float32)}
+        work = moe.held_work(experts, cfg.expert_first, cfg.n_experts_held)
+        counted = {"pairs": counted, "tiles": {
+            name: n.astype(jnp.float32) for name, n in work.items()}}
     return y, counted
 
 
@@ -1676,8 +1679,9 @@ def forward_segment(params: Params, ids: jax.Array, pos0: jax.Array,
     starts from nothing); where the model has expert layers it comes inside
     ``{"mixer": ..., "pairs": ...}``, ``pairs`` the running count of (token,
     expert) pairs routed to the experts held here (a model whose layers come
-    in kinds has ``tiles`` beside it: the tiles the grouped matmul visited for
-    them, and its ``mixer`` is ``{kind: leaves}``, :func:`_scan_periods`).
+    in kinds has ``tiles`` beside it: ``{"visited", "rows"}``, the tiles the
+    grouped matmul visited for them and the rows it computed, and its
+    ``mixer`` is ``{kind: leaves}``, :func:`_scan_periods`).
     Returns the final-normed
     hidden states [B, S, d] and the state after the segment."""
     with part("embed"):

@@ -285,17 +285,21 @@ def _held_dense(x, local, gates, w_gate, w_up, w_down, limit=None):
                              jnp.zeros(x.shape, f32))
 
 
-def held_tiles(experts: jax.Array, first: int, n_held: int) -> jax.Array:
-    """How many ``ROW_TILE`` tiles the pairs of ``experts [S, k]`` routed to
-    the experts ``first .. first + n_held - 1`` fill, each expert's rows
-    padded to whole tiles: the tiles :func:`held_experts_ffn`'s grouped
-    matmul visits for them (int32 scalar; pairs over tiles x ``ROW_TILE`` is
-    how full they are)."""
-    from agent_tpu.kernels.grouped_ffn import ROW_TILE
+def held_work(experts: jax.Array, first: int, n_held: int) -> dict:
+    """What :func:`held_experts_ffn`'s grouped matmul does for the pairs of
+    ``experts [S, k]`` routed to the experts ``first .. first + n_held - 1``,
+    from one count an expert (int32 scalars): ``visited``, the ``ROW_TILE``
+    tiles it visits, each expert's rows padded to whole tiles (pairs over
+    tiles x ``ROW_TILE`` is how full they are); ``rows``, the rows its
+    matmuls take, a tile's real rows rounded up to whole ``SUB_ROWS``
+    sub-blocks (pairs over rows is the share of them that is a real row)."""
+    from agent_tpu.kernels.grouped_ffn import ROW_TILE, SUB_ROWS
 
     local = (experts - first).reshape(-1)
     counts = (local[None, :] == jnp.arange(n_held)[:, None]).sum(axis=1)
-    return ((counts + ROW_TILE - 1) // ROW_TILE).sum()
+    sub = min(SUB_ROWS, ROW_TILE)          # whole sub-blocks a tile
+    return {"visited": ((counts + ROW_TILE - 1) // ROW_TILE).sum(),
+            "rows": ((counts + sub - 1) // sub).sum() * sub}
 
 
 @part("experts")
@@ -319,7 +323,9 @@ def held_experts_ffn(x: jax.Array, experts: jax.Array, gates: jax.Array,
     one grouped matmul over the tiles that hold rows fetches its real rows
     from ``x`` and writes them to slot ``j * S + token`` (a copy a row, a
     tile's copies waited for by size; a padded row of the tables is never
-    copied either way), a second pass combines the slots under the gates.
+    copied either way, and of a tile's 256 rows the matmuls take the 128-row
+    sub-blocks that hold a real one: :func:`held_work` counts them), a
+    second pass combines the slots under the gates.
     The matmul reads a stack in place:
     inside a layer scan, hand it the stack and the layer's number, not the
     layer's slice (a copy of every held expert). Off the kernel the layer's

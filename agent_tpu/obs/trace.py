@@ -779,16 +779,23 @@ def record_moe_routing(pairs: float, tokens: int) -> None:
                "layers, padding included)", float(tokens))
 
 
-def record_moe_tiles(tiles: float) -> None:
+def record_moe_tiles(tiles: float, rows_computed: float) -> None:
     """Row tiles the grouped expert matmul visited for a FETCHED shard (an
     expert's rows padded to whole tiles; counted on the device beside the
     pairs, fetched with the shard's answer): ``moe_expert_pairs_total`` over
-    this times the rows a tile is how full the tiles were."""
+    this times the rows a tile is how full the tiles were. And the rows its
+    matmuls took (a tile's real rows rounded up to whole sub-blocks, counted
+    beside the tiles): ``moe_expert_pairs_total`` over this is the share of
+    what the MXU computed that was a real row."""
     if tiles > 0:
         _count("moe_tiles_total",
                "Row tiles the grouped expert matmul visited (every held "
                "expert's rows padded to whole tiles), over all expert "
                "layers", float(tiles))
+        _count("moe_rows_computed_total",
+               "Rows the grouped expert matmul's products took (every "
+               "visited tile's real rows rounded up to whole sub-blocks), "
+               "over all expert layers", float(rows_computed))
 
 
 def record_lm_segments(op: str, segments: int) -> None:

@@ -43,7 +43,8 @@ the host. What that state is depends on the mixer (``models/decoder_lm.py``):
   layer's key and value cache at the document's padded length (allocated,
   donated and keyed as ``hybrid_ssm``'s), a window layer's LAST
   ``sliding_window`` keys and values only, whatever the document's length;
-  such a model also counts the tiles its grouped expert matmul visits;
+  such a model also counts the tiles its grouped expert matmul visits and
+  the rows it computes;
 - ``hybrid_kda``: two UNLIKE kinds of state by the layer's kind: a linear
   layer's FIXED-SIZE float32 state (``[heads, 128, 128]``) and its
   convolution's last three inputs, a latent layer's CACHE of latents at the
@@ -406,7 +407,7 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
     )
     put = lambda a: jax.device_put(a, runtime.replicated())  # noqa: E731
     programs: Dict[Tuple[int, int], Tuple] = {}   # (bucket, cache) -> programs
-    parts, routed, tiles, layout = [], [], [], []
+    parts, routed, tiles, rows, layout = [], [], [], [], []
     flops = 0.0
     for doc in state["docs"]:
         padded = sum(seg[0].shape[1] for seg in doc["segments"])
@@ -429,7 +430,8 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
         if cfg.n_experts:
             routed.append(carried["pairs"].reshape(1))
             if "tiles" in carried:
-                tiles.append(carried["tiles"].reshape(1))
+                tiles.append(carried["tiles"]["visited"].reshape(1))
+                rows.append(carried["tiles"]["rows"].reshape(1))
         layout.append((padded, doc["n_tokens"]))
     dispatched = sum(padded for padded, _ in layout)
     obs_trace.record_lm_segments(
@@ -438,12 +440,13 @@ def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, An
     stamp_device_flops(
         ctx, flops,
         f"B1xS{max(s[0].shape[1] for d in state['docs'] for s in d['segments'])}")
-    parts += routed + tiles
+    parts += routed + tiles + rows
     state.update(
         # One array a shard, gathered on the device by the owner thread:
         # one fetch, not one a segment. Behind the block sums, where the
         # model routes: a document's (token, expert) pairs held here, then
-        # (a model that counts them) the tiles its grouped matmul visited.
+        # (a model that counts them) the tiles its grouped matmul visited
+        # and then the rows it computed.
         pending_dev=jnp.concatenate(parts) if len(parts) > 1 else parts[0],
         layout=layout, device=runtime.platform, n_routed=len(routed),
         n_tiles=len(tiles),
@@ -465,8 +468,10 @@ def finalize(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, A
         sums = np.asarray(state["pending_dev"], dtype=np.float64)
     state["t_ready"] = fetched.t1
     if state["n_tiles"]:
-        obs_trace.record_moe_tiles(float(sums[-state["n_tiles"]:].sum()))
-        sums = sums[:-state["n_tiles"]]
+        n = state["n_tiles"]
+        obs_trace.record_moe_tiles(float(sums[-2 * n:-n].sum()),
+                                   float(sums[-n:].sum()))
+        sums = sums[:-2 * n]
     if state["n_routed"]:
         obs_trace.record_moe_routing(float(sums[-state["n_routed"]:].sum()),
                                      state["moe_tokens"])

@@ -290,7 +290,11 @@ def test_the_op_counts_the_new_state_and_the_families_experts(served):
     assert tokens == 6 * (5120 + 1024)
     pairs = _counter(snaps, "moe_expert_pairs_total")
     assert 0.2 * 4 * tokens < pairs < 0.8 * 4 * tokens
-    assert _counter(snaps, "moe_tiles_total") >= pairs / grouped_ffn.ROW_TILE
+    tiles = _counter(snaps, "moe_tiles_total")
+    assert tiles >= pairs / grouped_ffn.ROW_TILE
+    rows = _counter(snaps, "moe_rows_computed_total")
+    assert rows % grouped_ffn.SUB_ROWS == 0
+    assert pairs <= rows <= tiles * grouped_ffn.ROW_TILE
 
 
 # ---- (c) one segment against four ------------------------------------------

@@ -537,6 +537,34 @@ def _parents_grouped_swiglu(x, token, slot, tile_expert, tile_rows, w_gate,
       w_up, w_down)
 
 
+def _layer_around(monkeypatch, kernel, first):
+    """``held_experts_ffn`` around one grouped kernel, as ONE program:
+    ``(y, pairs, the kernel's tile_rows, its words, which slots it wrote)``;
+    every slot the kernel did not write is poisoned with NaNs before the
+    combine reads it."""
+    def run(x, experts, gates, *ws, **opts):
+        seen = {}
+
+        def spy(x, token, slot, tile_expert, tile_rows, *rest, **kwargs):
+            words = kernel(x, token, slot, tile_expert, tile_rows, *rest,
+                           **kwargs)
+            tm = token.size // tile_rows.size
+            real = (jnp.arange(token.size) % tm) < jnp.repeat(tile_rows, tm)
+            written = jnp.zeros(words.shape[0], bool).at[
+                jnp.where(real, slot, words.shape[0])].set(True, mode="drop")
+            seen.update(tile_rows=tile_rows, words=words, written=written)
+            return jnp.where(written[:, None, None], words,
+                             jnp.uint32(0x7FC07FC0))
+
+        with monkeypatch.context() as traced:
+            traced.setattr(grouped_ffn, "grouped_swiglu", spy)
+            y, pairs = moe.held_experts_ffn(
+                x, experts, gates, *ws, first, pallas=True, interpret=True,
+                **opts)
+        return y, pairs, seen["tile_rows"], seen["words"], seen["written"]
+    return jax.jit(run)
+
+
 @pytest.mark.parametrize("stack", [False, True],
                          ids=["one_layer", "stack_in_place"])
 @pytest.mark.parametrize("fe", [896, 512], ids=["one_width_step",
@@ -561,30 +589,11 @@ def test_the_layer_alone_returns_the_parents_bytes(monkeypatch, fe, stack):
                                              (n_held, fe, d)])]
     layer = {"layer": jnp.int32(2)} if stack else {}
 
-    def around(grouped_swiglu):
-        """The layer around one kernel, as ONE program: ``(y, pairs, the
-        kernel's tile_rows, its words)``."""
-        def run(x, experts, gates, *ws, **layer):
-            seen = {}
-
-            def spy(x, token, slot, tile_expert, tile_rows, *rest, **kwargs):
-                words = grouped_swiglu(x, token, slot, tile_expert, tile_rows,
-                                       *rest, **kwargs)
-                seen.update(tile_rows=tile_rows, words=words)
-                return words
-
-            with monkeypatch.context() as traced:
-                traced.setattr(grouped_ffn, "grouped_swiglu", spy)
-                y, pairs = moe.held_experts_ffn(
-                    x, experts, gates, *ws, first, pallas=True,
-                    interpret=True, **layer)
-            return y, pairs, seen["tile_rows"], seen["words"]
-        return jax.jit(run)
-
-    change, pairs, tile_rows, words = around(grouped_ffn.grouped_swiglu)(
-        x, experts, gates, *ws, **layer)
-    parents = around(_parents_grouped_swiglu)
-    parent, _, _, parents_words = parents(x, experts, gates, *ws, **layer)
+    change, pairs, tile_rows, words, _ = _layer_around(
+        monkeypatch, grouped_ffn.grouped_swiglu, first)(
+            x, experts, gates, *ws, **layer)
+    parents = _layer_around(monkeypatch, _parents_grouped_swiglu, first)
+    parent, _, _, parents_words, _ = parents(x, experts, gates, *ws, **layer)
     assert int(pairs) == 230
     tile_rows = np.asarray(tile_rows)
     assert sorted(tile_rows[tile_rows > 0]) == [1, 1, 37, 63, 64, 64]
@@ -595,6 +604,72 @@ def test_the_layer_alone_returns_the_parents_bytes(monkeypatch, fe, stack):
     if stack:                 # and it is THAT layer's weights it read
         other = parents(x, experts, gates, *ws, layer=jnp.int32(0))[0]
         assert not np.array_equal(np.asarray(other), np.asarray(parent))
+
+
+# Rows an expert holds: none, a row, a sub-block less one, a sub-block, one
+# row over, a tile less one, a tile, and a tile and a row (a spill tile).
+SUB_BLOCK_ROWS = [0, 1, 127, 128, 129, 255, 256, 257]
+
+
+@pytest.mark.parametrize("stack", [False, True],
+                         ids=["one_layer", "stack_in_place"])
+@pytest.mark.parametrize("fe, limit", [(896, None), (768, 0.5)],
+                         ids=["one_width_step", "three_width_steps_clamped"])
+def test_a_tile_computes_the_sub_blocks_that_hold_rows(monkeypatch, fe, limit,
+                                                       stack):
+    """PR 51: a tile's unpack, matmuls and pack run over its ``SUB_ROWS``
+    sub-blocks that hold a real row, not over the tile. The layer alone on
+    the kernel path (interpret mode, tiles of 256 rows as on the chip) on
+    tables whose tiles hold 1, 127 and 128 rows (one sub-block), 129 and 255
+    (two), 256 (a full tile), a full tile and a spill tile of one row, and an
+    expert with none: the kernel's words, every slot of them, and the
+    layer's result EQUAL to the bit what the kernel with the sub-block set to
+    the whole tile gives (the parent's body: every row of a tile computed),
+    with every slot neither wrote poisoned before the combine reads it. At a
+    width walked in one step and in three under the clamp (ling's form), one
+    layer and a stack read in place."""
+    tm, sub = grouped_ffn.ROW_TILE, grouped_ffn.SUB_ROWS
+    assert (tm, sub) == (256, 128)
+    S, k, d, first = 512, 3, 512, 8                # two lane tiles a half row
+    n_held = len(SUB_BLOCK_ROWS)
+    experts, gates = _by_hand(S, k, SUB_BLOCK_ROWS, first)
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    x = jax.random.normal(ks[0], (S, d), BF16)
+    lead = (3,) if stack else ()
+    ws = [(jax.random.normal(key, lead + shape) / np.sqrt(shape[1])).astype(
+        BF16) for key, shape in zip(ks[1:], [(n_held, d, fe), (n_held, d, fe),
+                                             (n_held, fe, d)])]
+    opts = {"layer": jnp.int32(2)} if stack else {}
+    if limit is not None:
+        opts["limit"] = jnp.float32(limit)
+    grouped_swiglu = grouped_ffn.grouped_swiglu
+
+    def whole_tiles(*args, **kwargs):
+        """The kernel traced with one sub-block a tile (under the jit's own
+        cache a second trace of the same shapes would not happen)."""
+        with monkeypatch.context() as traced:
+            traced.setattr(grouped_ffn, "SUB_ROWS", tm)
+            return grouped_swiglu.__wrapped__(*args, **kwargs)
+
+    change, pairs, tile_rows, words, written = _layer_around(
+        monkeypatch, grouped_swiglu, first)(x, experts, gates, *ws, **opts)
+    whole, _, _, whole_words, _ = _layer_around(
+        monkeypatch, whole_tiles, first)(x, experts, gates, *ws, **opts)
+    assert int(pairs) == int(written.sum()) == sum(SUB_BLOCK_ROWS)
+    tile_rows = np.asarray(tile_rows)
+    assert list(tile_rows[:9]) == [1, 127, 128, 129, 255, 256, 256, 1, 0]
+    assert tile_rows.size == S * k // tm + n_held
+    assert np.isfinite(np.asarray(change)).all()
+    assert float(jnp.abs(change).max()) > 0.05
+    np.testing.assert_array_equal(np.asarray(words), np.asarray(whole_words))
+    np.testing.assert_array_equal(np.asarray(change), np.asarray(whole))
+    # ... and what the counter says the matmuls took, on the same tables.
+    work = moe.held_work(experts, first, n_held)
+    assert int(work["visited"]) == 8
+    assert int(work["rows"]) == 128 * 3 + 256 * 3 + (256 + 128) == 1536
+    with monkeypatch.context() as whole_tile:
+        whole_tile.setattr(grouped_ffn, "SUB_ROWS", tm)
+        assert int(moe.held_work(experts, first, n_held)["rows"]) == 8 * tm
 
 
 # Two expert layers behind one dense: the layer scan has a second step to

@@ -365,6 +365,7 @@ GROUPED_CELLS = {
     "deepseek-v3.2": (4096, 8, 16, 7168, 2048, 4, 8),
     "mistral-small-4-119b": (4096, 4, 32, 4096, 2048, 6, 8),
     "mellum2-12b-a2.5b": (4096, 8, 64, 2304, 896, 12, 1),
+    "ling-3.0-flash-vl": (4096, 8, 128, 2560, 768, 6, 3),
 }
 
 
@@ -381,18 +382,36 @@ def _copies_of(jaxpr, in_loop=False):
             yield from _copies_of(inner, in_loop or name in ("scan", "while"))
 
 
+def _products_of(jaxpr, branches=0):
+    """``(rows of the left operand, how many ``cond``s it stands under)`` of
+    every ``dot_general`` of a jaxpr, the kernels' bodies included."""
+    from jax._src import core
+
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "dot_general":
+            yield eqn.invars[0].aval.shape[0], branches
+        for inner in core.jaxprs_in_params(eqn.params):
+            yield from _products_of(inner, branches + (name == "cond"))
+
+
 @pytest.mark.parametrize("name", GROUPED_CELLS)
 def test_grouped_kernel_waits_by_size_at_every_cells_shape_on_v5e(v5e, name):
-    """The grouped kernel at each of the three cells' shapes, the layers'
+    """The grouped kernel at each of the four cells' shapes, the layers'
     stack read in place, compiles for a described v5e (a row's words copied
     as ``d / 256`` sublane rows of ``[rows * d / 256, 128]`` buffers, at
-    offsets no multiple of 8: 9, 28 and 16 rows a copy), and its text holds
+    offsets no multiple of 8: 9, 28, 16 and 10 rows a copy), and its text holds
     NO wait inside a rolled loop: a tile's copies are waited for by size, a
     fixed handful of waits a tile (PR 45; one a row before: 512 a full
     tile), nine sizes at each of the three places a tile waits. The copies
     themselves start in rolled loops at the three places a tile starts
     them: a loop of ``_COPIES_A_TURN`` a turn (one traced descriptor, unrolled
-    when lowered) and one of the rest, one a turn."""
+    when lowered) and one of the rest, one a turn. A tile's three products
+    stand once for every count of ``SUB_ROWS`` sub-blocks a tile can hold
+    rows in, on that many rows (128 and 256), each under the branch that
+    asks how many of the tile's sub-blocks hold a real row (PR 51: the
+    kernel computes the rows a tile holds; it starts and waits for nothing
+    new)."""
     import collections
 
     from agent_tpu.kernels import grouped_ffn as gf
@@ -418,6 +437,10 @@ def test_grouped_kernel_waits_by_size_at_every_cells_shape_on_v5e(v5e, name):
     assert sizes == 9
     assert copies == {("dma_wait", False): 3 * sizes,
                       ("dma_start", True): 3 * 2}
+    products = collections.Counter(_products_of(traced.jaxpr.jaxpr))
+    assert gf.SUB_ROWS == 128
+    # Under ``t < n_tiles`` and the branch of the count of sub-blocks.
+    assert products == {(rows, 2): 3 for rows in (gf.SUB_ROWS, gf.ROW_TILE)}
 
 
 @pytest.fixture(scope="module")

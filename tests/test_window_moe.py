@@ -202,6 +202,12 @@ def test_the_op_counts_pairs_by_kind_and_tiles(served):
     tiles = gained("moe_tiles_total")
     assert pairs / grouped_ffn.ROW_TILE <= tiles <= (
         pairs / grouped_ffn.ROW_TILE + 16 * 4 * 4)     # < a tile an expert a call
+    # The rows the matmuls took: whole sub-blocks, under a sub-block an
+    # expert a call over the pairs and never more than the tiles hold.
+    rows = gained("moe_rows_computed_total")
+    assert rows % grouped_ffn.SUB_ROWS == 0
+    assert pairs <= rows <= min(tiles * grouped_ffn.ROW_TILE,
+                                pairs + grouped_ffn.SUB_ROWS * 16 * 4 * 4)
 
 
 # ---- (b) the window's edge, to the key ------------------------------------
@@ -312,7 +318,8 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     y_whole, counted = lm_once.experts_program(whole)(p_whole, n)
     assert set(counted) == {"pairs", "tiles"}
     down_whole = np.asarray(p_whole["we_down"])
-    total, pairs, tiles = 0.0, 0.0, 0.0
+    assert set(counted["tiles"]) == {"visited", "rows"}
+    total, pairs, tiles, rows = 0.0, 0.0, 0.0, 0.0
     for first in (0, 16, 32, 48):
         cfg = decoder_lm.DecoderLMConfig(**{**wide, "n_experts_held": 16,
                                             "expert_first": first})
@@ -322,9 +329,11 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
         y, held = lm_once.experts_program(cfg)(p, n)
         total = total + y
         pairs += float(held["pairs"])
-        tiles += float(held["tiles"])
+        tiles += float(held["tiles"]["visited"])
+        rows += float(held["tiles"]["rows"])
     assert pairs == float(counted["pairs"]) == 300 * 8    # every choice, once
-    assert tiles == float(counted["tiles"]) <= 64
+    assert tiles == float(counted["tiles"]["visited"]) <= 64
+    assert rows == float(counted["tiles"]["rows"]) <= 64 * 128  # a sub-block
     np.testing.assert_allclose(np.asarray(total), np.asarray(y_whole),
                                atol=1e-5)
     u = n[0] * 3.0
@@ -346,8 +355,24 @@ def test_held_tiles_counts_whole_tiles_an_expert():
     experts = jnp.asarray([[0, 1]] * 300 + [[2, 5]] * 3, jnp.int32)
     # Experts 0 and 1: 300 rows = 2 tiles each; 2: 3 rows = 1; 3: none; 5 is
     # held elsewhere.
-    assert int(moe.held_tiles(experts, 0, 4)) == 5
-    assert int(moe.held_tiles(experts, 4, 4)) == 1
+    assert int(moe.held_work(experts, 0, 4)["visited"]) == 5
+    assert int(moe.held_work(experts, 4, 4)["visited"]) == 1
+
+
+@pytest.mark.parametrize("held, tiles, computed", [
+    (0, 0, 0), (1, 1, 128), (59, 1, 128), (128, 1, 128), (129, 1, 256),
+    (130, 1, 256), (256, 1, 256), (257, 2, 384), (512, 2, 512),
+    (540, 3, 640)])
+def test_held_work_counts_the_rows_the_matmuls_take(held, tiles, computed):
+    """``moe_rows_computed_total``'s arithmetic: an expert's rows rounded up
+    to whole ``SUB_ROWS`` sub-blocks (a tile's real rows come first in it, and
+    the kernel computes the sub-blocks that hold one), beside the whole tiles
+    they are padded to; an expert held elsewhere counts nothing."""
+    assert (grouped_ffn.ROW_TILE, grouped_ffn.SUB_ROWS) == (256, 128)
+    experts = jnp.asarray([[1, 6]] * held + [[2, 7]] * 3, jnp.int32)
+    work = moe.held_work(experts, 0, 4)       # expert 2: 3 rows, a sub-block
+    assert int(work["visited"]) == tiles + 1
+    assert int(work["rows"]) == computed + 128
 
 
 # ---- (e) what the carried state holds -------------------------------------
@@ -377,7 +402,8 @@ def test_the_carried_state_has_two_shapes_side_by_side():
             assert state["mixer"]["full"][leaf].shape == (2, 1, 2, 1024, 16)
     assert hidden.shape == (1, 512, 64)
     assert (np.asarray(state["mixer"]["full"]["k"]) != 0).any(axis=-1).all()
-    assert float(state["pairs"]) == 1024 * 4 * 4 and float(state["tiles"]) > 0
+    assert float(state["pairs"]) == 1024 * 4 * 4 and float(
+        state["tiles"]["visited"]) > 0
     with pytest.raises(ValueError, match="init_state"):
         decoder_lm.forward_segment(params, jnp.asarray(ids), jnp.int32(0),
                                    None, cfg)
