@@ -21,6 +21,18 @@ front of the tile's index): a custom call's operand sliced out of a stack
 is a copy of the slice, 67 MB a layer a segment at 65,536 keys, and the
 stack is the layer scan's carry (``models/decoder_lm.py: MIXER_CACHES``).
 
+Heads of HALF a lane tile (64: ``conv_gqa``'s attention layers) lie TWO
+key-value heads a cache row (:func:`cache_rows`: ``[Hkv / 2, Lk, 128]``, head
+``2 p`` on lanes 0-63 and ``2 p + 1`` on 64-127), so a loaded key or value
+tile is whole lanes. A grid step then serves the pair: the ``G`` query heads of
+each are stacked as before, ``[2 G * tile, 128]``, a row of the first head
+zero on the second's lanes and the other way round, so one product over 128
+lanes gives each row its own head's scores (a matmul 64 deep fills half the
+MXU's depth either way: the zeros cost nothing a 64-wide operand would not),
+the value product gives each row both heads' values and the finish keeps a
+row's own half. Which form runs is the SHAPES': queries 64 wide over a cache
+128 wide.
+
 A WINDOW layer (``window_gqa``'s three of four) attends the last ``window``
 keys only (:func:`window_attention`): its keys are ``[the window keys before
 the segment | the segment's own]``, never the document's cache, and the same
@@ -66,10 +78,13 @@ def pallas_supported(seq_len: int, cache_len: int, d_head: int, dtype,
                      d_value: Optional[int] = None) -> bool:
     """Shapes the kernel takes on the chip: bf16 operands, lane-wide heads
     (or, where queries and keys are wider than the values, ``d_value`` of
-    them: keys of whole lanes over lane-wide values),
-    a segment of whole query tiles and a cache of whole key tiles."""
-    wide = d_head == _LANES if d_value is None else (
-        d_head % _LANES == 0 and d_value == _LANES)
+    them: keys of whole lanes over lane-wide values; or heads of HALF a lane
+    tile, keys and values alike, whose cache holds two a row:
+    :func:`cache_rows`), a segment of whole query tiles and a cache of whole
+    key tiles."""
+    d_value = d_head if d_value is None else d_value
+    wide = (d_head % _LANES == 0 and d_value == _LANES) or (
+        d_head == d_value == _LANES // 2)
     return bool(jnp.dtype(dtype) == jnp.bfloat16 and wide
                 and seq_len % QUERY_TILE == 0 and cache_len % KEY_TILE == 0)
 
@@ -77,9 +92,42 @@ def pallas_supported(seq_len: int, cache_len: int, d_head: int, dtype,
 def window_supported(seq_len: int, window: int, d_head: int, dtype) -> bool:
     """The same, for a window layer: its keys are ``window + seq_len``, and
     the window is whole key tiles (a query tile's first key tile is then a
-    whole one)."""
+    whole one); lane-wide heads only (its keys are no cache of paired
+    rows)."""
     return bool(pallas_supported(seq_len, window + seq_len, d_head, dtype)
-                and window % KEY_TILE == 0)
+                and window % KEY_TILE == 0 and d_head % _LANES == 0)
+
+
+def heads_a_row(n_kv_heads: int, d_head: int) -> int:
+    """Key-value heads side by side on a cache row's lanes: two where a head
+    is half a lane tile and the heads pair up, else one."""
+    return 2 if 2 * d_head == _LANES and n_kv_heads % 2 == 0 else 1
+
+
+def cache_shape(n_kv_heads: int, cache_len: int, d_head: int):
+    """``(rows of heads, keys, lanes)`` of one layer's key (or value) cache
+    as the kernel reads it: ``[Hkv, Lk, D]``, or two heads a row."""
+    n = heads_a_row(n_kv_heads, d_head)
+    return n_kv_heads // n, cache_len, n * d_head
+
+
+def cache_rows(x: jax.Array) -> jax.Array:
+    """A segment's keys or values ``[Hkv, S, D]`` as the cache holds them
+    (:func:`cache_shape`): themselves, or ``[Hkv / 2, S, 2 D]`` with head
+    ``2 p`` on a row's first ``D`` lanes and ``2 p + 1`` on its last."""
+    Hkv, S, D = x.shape
+    n = heads_a_row(Hkv, D)
+    if n == 1:
+        return x
+    return x.reshape(Hkv // n, n, S, D).transpose(0, 2, 1, 3).reshape(
+        Hkv // n, S, n * D)
+
+
+def _heads_apart(x: jax.Array, n: int) -> jax.Array:
+    """:func:`cache_rows` undone: ``[Hkv / n, Lk, n D]`` → ``[Hkv, Lk, D]``."""
+    rows, Lk, lanes = x.shape
+    return x.reshape(rows, Lk, n, lanes // n).transpose(0, 2, 1, 3).reshape(
+        rows * n, Lk, lanes // n)
 
 
 # Rows a grid step's matmuls have at most. Five stacked heads of 512 queries
@@ -177,12 +225,16 @@ def _attention_jnp(q, k, v, pos0):
 
 
 def _attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                      acc_ref, *, tq: int, tk: int, bk: int, groups: int,
-                      window: Optional[int] = None):
+                      acc_ref, *pair_ref, tq: int, tk: int, bk: int,
+                      groups: int, window: Optional[int] = None):
     """One (key-value head, query tile, key tile) step. With ``window`` the
     keys are ``[window keys before the segment | the segment]``, a query
     counts from ``window`` in them, step ``j`` is key tile ``i * tq / tk + j``
-    and ``pos_ref`` holds the first key that lies inside the document."""
+    and ``pos_ref`` holds the first key that lies inside the document. With
+    ``pair_ref`` (a scratch ``[2 G tq, 2 D]``) a row of the operands is TWO
+    heads of ``D`` lanes: ``groups`` counts both heads' query heads, the
+    first head's rows are stacked over the second's, each zero on the other
+    head's lanes, and a finished row keeps its own head's lanes."""
     f32 = jnp.float32
     i, j = pl.program_id(1), pl.program_id(2)
     if window is None:
@@ -197,14 +249,24 @@ def _attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     nn = (((1,), (0,)), ((), ()))
     lanes = bk // _LANES
 
+    def first_heads(shape):
+        """Whether a lane of ``[rows / 2, 2 D]`` is the pair's first head's."""
+        return jax.lax.broadcasted_iota(jnp.int32, shape, 1) < shape[1] // 2
+
     @pl.when(j == 0)
     def _():
         m_ref[...] = jnp.full(m_ref.shape, _MASKED, f32)
         l_ref[...] = jnp.zeros(l_ref.shape, f32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+        if pair_ref:
+            both = q_ref[0].reshape(rows // 2, q_ref.shape[-1])
+            mine = first_heads(both.shape)
+            pair_ref[0][:rows // 2] = jnp.where(mine, both, 0)
+            pair_ref[0][rows // 2:] = jnp.where(mine, 0, both)
 
     def tile(masked: bool):
-        q = q_ref[0].reshape(rows, q_ref.shape[-1])
+        q = pair_ref[0][...] if pair_ref else q_ref[0].reshape(
+            rows, q_ref.shape[-1])
         for c in range(tk // bk):
             keys = slice(c * bk, (c + 1) * bk)
             s = jax.lax.dot_general(q, k_ref[0, keys, :], nt,
@@ -253,9 +315,11 @@ def _attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
-        o_ref[0] = (acc_ref[...] / l_ref[...].sum(
-            axis=-1, keepdims=True)).reshape(o_ref.shape[1:]).astype(
-                o_ref.dtype)
+        out = acc_ref[...] / l_ref[...].sum(axis=-1, keepdims=True)
+        if pair_ref:
+            out = jnp.where(first_heads((rows // 2, out.shape[1])),
+                            out[:rows // 2], out[rows // 2:])
+        o_ref[0] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -264,9 +328,16 @@ def _attention_call(q, k, v, pos0, layer=None, *,
     """``pos0``: the position of the segment's first token; under ``window``
     the first of the keys that lies inside the document. ``layer``: the one
     to attend where ``k`` and ``v`` are the layers' stack (rank 5)."""
+    n = k.shape[-1] // q.shape[-1]          # key-value heads a cache row
+    if n > 1:
+        # Query head g of the pair's two heads side by side on the lanes, as
+        # the cache holds their keys: [Hkv / 2, G, S, 2 D].
+        Hkv, G, S, D = q.shape
+        q = q.reshape(Hkv // n, n, G, S, D).transpose(0, 2, 3, 1, 4).reshape(
+            Hkv // n, G, S, n * D)
     Hkv, G, S, D = q.shape
     Lk, Dv = k.shape[-2], v.shape[-1]
-    tq, tk, bk = query_tile(G, S), KEY_TILE, KEY_BLOCK
+    tq, tk, bk = query_tile(n * G, S), KEY_TILE, KEY_BLOCK
 
     if window is None:
         # Steps past a query tile's last key tile name that tile again: no
@@ -297,19 +368,19 @@ def _attention_call(q, k, v, pos0, layer=None, *,
             (1, tk, d), lambda h, i, j, pos: (h, at(j, i, pos), 0))
     q_block = lambda d: pl.BlockSpec(  # noqa: E731
         (1, G, tq, d), lambda h, i, j, pos: (h, 0, i, 0))
-    return pl.pallas_call(
-        functools.partial(_attention_kernel, tq=tq, tk=tk, bk=bk, groups=G,
-                          window=window),
+    out = pl.pallas_call(
+        functools.partial(_attention_kernel, tq=tq, tk=tk, bk=bk,
+                          groups=n * G, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(Hkv, S // tq, steps),
             in_specs=[q_block(D), kv_block(D), kv_block(Dv)],
             out_specs=q_block(Dv),
             scratch_shapes=[
-                pltpu.VMEM((G * tq, _LANES), jnp.float32),
-                pltpu.VMEM((G * tq, _LANES), jnp.float32),
-                pltpu.VMEM((G * tq, Dv), jnp.float32),
-            ],
+                pltpu.VMEM((n * G * tq, _LANES), jnp.float32),
+                pltpu.VMEM((n * G * tq, _LANES), jnp.float32),
+                pltpu.VMEM((n * G * tq, Dv), jnp.float32),
+            ] + ([pltpu.VMEM((n * G * tq, D), q.dtype)] if n > 1 else []),
         ),
         out_shape=jax.ShapeDtypeStruct((Hkv, G, S, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -325,6 +396,10 @@ def _attention_call(q, k, v, pos0, layer=None, *,
         else "window_gqa_attention",
         interpret=interpret,
     )(scalars, q, k, v)
+    if n > 1:
+        out = out.reshape(Hkv, G, S, n, Dv // n).transpose(
+            0, 3, 1, 2, 4).reshape(Hkv * n, G, S, Dv // n)
+    return out
 
 
 @part("mixer")
@@ -345,17 +420,25 @@ def causal_attention(
 
     ``k`` and ``v`` may be the layers' STACK of caches ``[layers, 1, Hkv, Lk,
     D]`` with ``layer`` the one to attend (the rank says which): the kernel
-    then reads that layer's tiles out of the stack in place."""
-    S, D = q.shape[2:]
+    then reads that layer's tiles out of the stack in place. Heads of half a
+    lane tile come two a cache row, ``[.., Hkv / 2, Lk, 2 D]``
+    (:func:`cache_rows`; ``q`` stays ``[Hkv, G, S, D]``)."""
+    Hkv, _, S, D = q.shape
+    n = k.shape[-1] // D                    # key-value heads a cache row
     if pallas is None:
         pallas = jax.default_backend() == "tpu"
-    if pallas and pallas_supported(S, k.shape[-2], D, q.dtype, v.shape[-1]):
+    # Heads of half a lane tile run the kernel only as PAIRS: a cache row of
+    # one such head is half a lane tile.
+    if pallas and n == heads_a_row(Hkv, D) and k.shape[-1] % _LANES == 0 and (
+            pallas_supported(S, k.shape[-2], D, q.dtype, v.shape[-1] // n)):
         from agent_tpu.kernels.flash_attention import resolve_interpret
 
         return _attention_call(q, k, v, pos0, layer,
                                interpret=resolve_interpret(interpret))
     if k.ndim == 5:
         k, v = k[layer, 0], v[layer, 0]
+    if n > 1:
+        k, v = _heads_apart(k, n), _heads_apart(v, n)
     return _attention_jnp(q, k, v, pos0).astype(q.dtype)
 
 

@@ -57,10 +57,11 @@ def zero_state(n_heads: int, d_head: int, d_state: int) -> jax.Array:
 
 @part("around")
 def causal_conv(u: jax.Array, tail: Optional[jax.Array], w: jax.Array,
-                b: jax.Array) -> Tuple[jax.Array, jax.Array]:
+                b: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
     """``out_t = b + sum_i w[i] u_{t - (K-1) + i}`` a channel, float32:
     u [S, C], ``tail`` the ``K - 1`` rows before the segment ([K-1, C];
-    ``None``: the document starts here, zeros), w [K, C], b [C]. Returns the
+    ``None``: the document starts here, zeros), w [K, C], b [C] (``None``:
+    the convolution has no bias, and its sum starts at the first tap). Returns the
     convolved rows and the tail the NEXT segment needs: the last ``K - 1``
     rows of ``[tail; u]``. ``K`` shifted multiply-adds, which XLA fuses into
     the pass that reads ``u``: a kernel has nothing to win on 0.2 % of a
@@ -72,9 +73,10 @@ def causal_conv(u: jax.Array, tail: Optional[jax.Array], w: jax.Array,
     if tail is None:
         tail = jnp.zeros((K - 1, C), f32)
     ext = jnp.concatenate([tail.astype(f32), u], axis=0)     # [S + K - 1, C]
-    out = b.astype(f32)[None, :]
+    out = None if b is None else b.astype(f32)[None, :]
     for i in range(K):
-        out = out + w[i].astype(f32)[None, :] * ext[i:i + S]
+        tap = w[i].astype(f32)[None, :] * ext[i:i + S]
+        out = tap if out is None else out + tap
     return out, ext[S:]
 
 

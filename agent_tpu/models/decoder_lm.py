@@ -61,6 +61,17 @@ one to the next (:func:`forward_segment`):
   state is two unlike kinds too: a linear layer's float32 ``[H, d, d]`` state
   and its convolution's last inputs, of fixed size; a latent layer's cache at
   the document's padded length. Leading dense layers are linear layers.
+- ``conv_gqa`` (double-gated SHORT CONVOLUTIONS, ``[B | C | z] = h W_in``,
+  ``C x conv(B x z)`` over ``conv_taps`` taps a channel, and among them
+  grouped-query attention layers, ``window_gqa``'s full kind under the plain
+  rotary table, the one function) is the mixer whose pattern of kinds is the
+  MODEL'S OWN (``layer_types``, one kind a layer): a stacked group may begin
+  anywhere in a period and the leading dense layers have the kind the
+  pattern gives them (:func:`kinds_by_layer`). Two unlike states: a ``conv``
+  layer's is the last ``conv_taps - 1`` rows of ``B x z`` and nothing that
+  grows; an attention layer's key and value cache is the scan's carry, and
+  at heads of HALF a lane tile (64) it holds two key-value heads side by
+  side on a row's lanes (``kernels/causal_attention.py: cache_rows``).
 
 Layers are stacked by group (the leading dense layers, then the expert
 layers) and each group is scanned; embedding and output head are untied. A
@@ -122,13 +133,15 @@ LEAVES = ("embed", "head", "wq", "wk", "wv", "wo", "wg", "w_gate", "w_up",
           # hybrid_ssm: the scan's in / out projections, the convolution
           "w_ssm_in", "w_ssm_out", "conv_w", "conv_b",
           # hybrid_kda: a linear layer's beta, decay and output-gate maps
-          "w_beta", "w_a", "w_og")
+          "w_beta", "w_a", "w_og",
+          # conv_gqa: a conv layer's in-projection [B | C | z]
+          "w_conv_in")
 EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
 # The layer leaves a quantized mode replaces (``models.quant``): projections
 # (the router's among them), feed-forwards and experts; the retention gate
 # the indexer's head weights and the convolution stay.
-LINEAR_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
-                 "w_dq", "w_uq", "w_dkv", "w_ukv", "wi_q", "wi_k",
+LINEAR_LEAVES = ("wq", "wk", "wv", "wo", "w_conv_in", "w_gate", "w_up",
+                 "w_down", "w_dq", "w_uq", "w_dkv", "w_ukv", "wi_q", "wi_k",
                  "w_router", "ws_gate", "ws_up", "ws_down") + EXPERT_LEAVES + (
                      "w_ssm_in", "w_ssm_out", "w_a")
 # Which leaves a layer holds, by its mixer (and, where a mixer's kinds hold
@@ -144,6 +157,9 @@ MIXER_LEAVES = {
     "hybrid_kda": {
         "linear": ("wq", "wk", "wv", "wo", "conv_w", "w_beta", "w_a", "w_og"),
         "latent": ("wq", "wo", "w_dkv", "w_ukv", "w_og")},
+    "conv_gqa": {
+        "conv": ("w_conv_in", "wo", "conv_w"),
+        "full": ("wq", "wk", "wv", "wo")},
 }
 FFN_LEAVES = {
     "dense": ("w_gate", "w_up", "w_down"),
@@ -268,12 +284,21 @@ class DecoderLMConfig:
     # clip(up, -L, L)``, for the routed experts and for the shared expert.
     expert_swiglu_limits: Tuple[float, ...] = ()
     shared_swiglu_limits: Tuple[float, ...] = ()
+    # conv_gqa: the kind of EVERY layer, the model's own pattern (``conv``: a
+    # double-gated causal convolution of ``conv_taps`` taps a channel;
+    # ``full``, or the published ``full_attention``: ``n_heads`` query over
+    # ``n_kv_heads`` key-value heads of ``d_head``, every causal key).
+    layer_types: Tuple[str, ...] = ()
+    conv_taps: int = 3
 
     def __post_init__(self):
         # A payload's JSON lists: every field goes into hashed keys.
         for name in ("expert_swiglu_limits", "shared_swiglu_limits"):
             object.__setattr__(self, name, tuple(
                 float(v) for v in getattr(self, name)))
+        object.__setattr__(self, "layer_types", tuple(
+            "full" if kind == "full_attention" else str(kind)
+            for kind in self.layer_types))
 
     @property
     def compute_dtype(self):
@@ -325,6 +350,13 @@ def _holds(cfg: DecoderLMConfig, leaf: str) -> bool:
     return leaf in held
 
 
+def _shortest_period(kinds: Tuple[str, ...]) -> Tuple[str, ...]:
+    """The shortest run of ``kinds`` that, repeated, is all of them."""
+    return next((kinds[:n] for n in range(1, len(kinds))
+                 if len(kinds) % n == 0
+                 and kinds[:n] * (len(kinds) // n) == kinds), kinds)
+
+
 # mixer name → fn(cfg) → the kinds of one PERIOD's layers, in order, for the
 # mixers whose layers come in kinds.
 MIXER_KINDS: Dict[str, Callable] = {
@@ -332,39 +364,55 @@ MIXER_KINDS: Dict[str, Callable] = {
         "full",),
     "hybrid_kda": lambda cfg: ("linear",) * (cfg.layer_group_size - 1) + (
         "latent",),
+    # The model's own pattern: the period of the layers BEHIND the leading
+    # dense ones, wherever in it an attention layer stands.
+    "conv_gqa": lambda cfg: _shortest_period(
+        cfg.layer_types[cfg.n_dense_layers if cfg.n_experts else 0:]),
 }
 
 
 def layer_kinds(cfg: DecoderLMConfig) -> Tuple[str, ...]:
     """The kinds of one PERIOD's layers, in order, where a model's layers
     come in kinds (``MIXER_KINDS``: ``window_gqa``'s window layers and then a
-    full one, ``hybrid_kda``'s linear layers and then a latent one); ``()``
-    where every layer is alike."""
+    full one, ``hybrid_kda``'s linear layers and then a latent one,
+    ``conv_gqa``'s as its ``layer_types`` repeat behind the leading dense
+    layers); ``()`` where every layer is alike."""
     kinds = MIXER_KINDS.get(cfg.mixer)
     return kinds(cfg) if kinds else ()
 
 
-def group_kinds(cfg: DecoderLMConfig, ffn: str) -> Tuple[str, ...]:
-    """The period a stacked group's layer scan steps over: the model's
-    (:func:`layer_kinds`), but for leading dense layers in front of expert
-    layers, which are a period's FIRST layers and no whole period: each is
-    a period of its own (``validate`` holds them to one kind)."""
+def kinds_by_layer(cfg: DecoderLMConfig) -> Tuple[str, ...]:
+    """The kind of EVERY layer of a model whose layers come in kinds: the
+    model's own pattern where its config states one (``layer_types``); else
+    whole periods (:func:`layer_kinds`) behind leading dense layers of the
+    period's first kind."""
+    if cfg.layer_types:
+        return cfg.layer_types
     kinds = layer_kinds(cfg)
-    if ffn == "dense" and cfg.n_experts:
-        return kinds[:1]
-    return kinds
+    leading = cfg.n_dense_layers if cfg.n_experts else 0
+    return kinds[:1] * leading + kinds * (
+        (cfg.n_layers - leading) // len(kinds))
+
+
+def group_kinds(cfg: DecoderLMConfig, ffn: str) -> Tuple[str, ...]:
+    """The period a stacked group's layer scan steps over: the shortest that
+    its layers' kinds (:func:`kinds_by_layer`) repeat. A group may begin
+    anywhere in the model's period (its period is then that one, turned), and
+    leading dense layers in front of expert layers, no whole period of the
+    model's, are each a period of their own where they are of one kind."""
+    first, n = next((first, n) for _, kind, first, n in cfg.layer_groups
+                    if kind == ffn)
+    return _shortest_period(kinds_by_layer(cfg)[first:first + n])
 
 
 def layers_of_kinds(cfg: DecoderLMConfig) -> Dict[str, Dict[str, Tuple]]:
     """``{group: {kind: the numbers (over both groups) of the group's layers
     of that kind}}`` of a model whose layers come in kinds."""
-    out: Dict[str, Dict[str, Tuple]] = {}
-    for group, ffn, first, n in cfg.layer_groups:
-        kinds = group_kinds(cfg, ffn)
-        out[group] = {kind: tuple(
-            first + i for i in range(n) if kinds[i % len(kinds)] == kind)
-            for kind in sorted(set(kinds))}
-    return out
+    by_layer = kinds_by_layer(cfg)
+    return {group: {kind: tuple(i for i in range(first, first + n)
+                                if by_layer[i] == kind)
+                    for kind in sorted(set(by_layer[first:first + n]))}
+            for group, _, first, n in cfg.layer_groups}
 
 
 def validate(cfg: DecoderLMConfig) -> None:
@@ -372,7 +420,8 @@ def validate(cfg: DecoderLMConfig) -> None:
     if cfg.mixer not in MIXERS:
         raise ValueError(f"mixer must be one of {sorted(MIXERS)}, "
                          f"got {cfg.mixer!r}")
-    if cfg.mixer in ("power_retention", "hybrid_ssm", "window_gqa"):
+    if cfg.mixer in ("power_retention", "hybrid_ssm", "window_gqa",
+                     "conv_gqa"):
         if cfg.n_kv_heads <= 0 or cfg.n_heads % cfg.n_kv_heads:
             raise ValueError("n_heads must be a multiple of n_kv_heads")
         if cfg.d_head % 2:
@@ -420,6 +469,15 @@ def validate(cfg: DecoderLMConfig) -> None:
         if not -80.0 / SUB <= cfg.kda_lower_bound < 0:
             raise ValueError("kda_lower_bound must lie in [-5, 0): a "
                              "sub-block's decay has to stay inside float32")
+    if cfg.mixer == "conv_gqa":
+        positive += ["conv_taps"]
+        if len(cfg.layer_types) != cfg.n_layers or not set(
+                cfg.layer_types) <= set(MIXER_LEAVES["conv_gqa"]):
+            raise ValueError("layer_types must name every layer's kind, "
+                             "'conv' or 'full_attention'")
+    elif cfg.layer_types:
+        raise ValueError("layer_types is conv_gqa's: this mixer's kinds "
+                         "follow from its own keys")
     for name in ("expert_swiglu_limits", "shared_swiglu_limits"):
         limits = getattr(cfg, name)
         if limits and (len(limits) != cfg.n_layers or min(limits) < 0):
@@ -475,6 +533,10 @@ def _leaf_shapes(cfg: DecoderLMConfig, kind: Optional[str] = None
             "w_dkv": ((d, kvr + dr), d), "w_ukv": ((kvr, h * (dn + dv)), kvr),
             "w_og": ((d, h), d),
         }
+    elif kind == "conv":
+        k = cfg.conv_taps
+        mixer = {"w_conv_in": ((d, 3 * d), d), "wo": ((d, d), d),
+                 "conv_w": ((k, d), k)}
     elif _holds(cfg, "w_dkv"):
         h, qr, kvr = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
         dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -606,6 +668,12 @@ def _layer_constants(cfg: DecoderLMConfig, ffn: Optional[str],
                 "o_norm": (1.0, (cfg.d_head,))}
     if kind == "latent":
         return {"ln1": (1.0, (d,)), "kv_norm": (1.0, (cfg.kv_lora_rank,))}
+    if kind == "conv":
+        return {"ln1": (1.0, (d,))}
+    if kind == "full":
+        # ``window_gqa``'s norms a head, its query norm's weight and reason.
+        return {"ln1": (1.0, (d,)), "q_norm": (QUERY_NORM_GAIN, (cfg.d_head,)),
+                "k_norm": (1.0, (cfg.d_head,))}
     out = {"ln1": (1.0, (d,)), "ln2": (1.0, (d,))}
     if isinstance(MIXER_LEAVES[cfg.mixer], dict):
         del out["ln1"]                     # the mixer's half has it
@@ -1164,7 +1232,8 @@ def _window_gqa_mixer(p: Params, h: jax.Array, positions: jax.Array,
     before they are rounded. A ``full`` layer's ``state`` is ``{"k", "v":
     [full layers, 1, Hkv, Lk, D], "layer"}``: the caches of ALL the full
     layers (``MIXER_CACHES``), written at ``layer`` and the segment's
-    positions, attended there up to each query, and handed back whole. A
+    positions, attended there up to each query, and handed back whole (heads
+    of half a lane tile lie two a row: ``causal_attention.cache_rows``). A
     ``window`` layer's is ``{"k", "v": [1, Hkv, sliding_window, D]}``, the
     LAST ``sliding_window`` keys and values before
     the segment: the segment attends ``[that tail | its own]``, each query
@@ -1193,8 +1262,10 @@ def _window_gqa_mixer(p: Params, h: jax.Array, positions: jax.Array,
     q = q.reshape(S, hkv, hq // hkv, dh).transpose(1, 2, 0, 3)
     if kind == "full":
         layer = state["layer"]
-        kc = _write_cache(state["k"], k, layer, pos0)
-        vc = _write_cache(state["v"], v, layer, pos0)
+        kc = _write_cache(state["k"], causal_attention.cache_rows(k), layer,
+                          pos0)
+        vc = _write_cache(state["v"], causal_attention.cache_rows(v), layer,
+                          pos0)
         o = causal_attention.causal_attention(q, kc, vc, pos0, layer,
                                               **kernel_opts)
     else:
@@ -1213,17 +1284,20 @@ def _window_gqa_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
     value cache of ``cache_len`` (padded) tokens, a window layer's
     ``sliding_window`` keys and values that lie before the document (never
     attended: the window kernel masks by position), in the stored dtype."""
+    from agent_tpu.kernels.causal_attention import cache_shape
+
     kinds = layer_kinds(cfg)
     periods = cfg.n_layers // len(kinds)
 
-    def empty(kind, keys):
-        shape = (periods * kinds.count(kind), batch, cfg.n_kv_heads, keys,
-                 cfg.d_head)
+    def empty(kind, shape):
+        shape = (periods * kinds.count(kind), batch, *shape)
         return {"k": jnp.zeros(shape, cfg.compute_dtype),
                 "v": jnp.zeros(shape, cfg.compute_dtype)}
 
-    return {"window": empty("window", cfg.sliding_window),
-            "full": empty("full", cache_len)}
+    return {"window": empty("window", (cfg.n_kv_heads, cfg.sliding_window,
+                                       cfg.d_head)),
+            "full": empty("full", cache_shape(cfg.n_kv_heads, cache_len,
+                                              cfg.d_head))}
 
 
 def _gated_by_head(o: jax.Array, gate: jax.Array, dtype) -> jax.Array:
@@ -1313,6 +1387,60 @@ def _hybrid_kda_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
              cfg.kv_lora_rank + cfg.qk_rope_head_dim), cfg.compute_dtype)}}
 
 
+@part("around")
+def _conv_gqa_mixer(p: Params, h: jax.Array, positions: jax.Array,
+                    state, cfg: DecoderLMConfig, kernel_opts, kind: str):
+    """h [1, S, d] (normed) → (what enters the residual [1, S, d], the
+    layer's state), by the layer's KIND.
+
+    ``full``: :func:`_window_gqa_mixer`'s full kind, the one function (a
+    per-head RMS norm on queries and keys, the rotation by halves under the
+    plain table where the config names no scaling, every causal key).
+
+    ``conv``: ``[B | C | z] = h W_in``; ``g = B x z``; ``c_t = sum_i w_i
+    g_{t - (K-1) + i}`` a channel (causal, depthwise, ``conv_taps`` taps, no
+    bias, zeros before the document); ``(C x c) W_out``. No activation.
+    ``state``: ``{"tail": [1, K - 1, d]`` float32, the last rows of ``g``
+    before the segment``}``. The gates and the taps are stated on the
+    projection's rounded columns in float32 and rounded once, under one
+    scope (``conv_gate``) and with nothing between them, so that XLA may
+    fuse them as it finds best (on the chip it keeps the projection in the
+    core's memory and rides the taps and ``C x`` inside the out-projection's
+    fusion: PERF.md section 5). One document a program."""
+    if kind == "full":
+        return _window_gqa_mixer(p, h, positions, state, cfg, kernel_opts,
+                                 kind)
+    from agent_tpu.kernels import ssd
+
+    if h.shape[0] != 1:
+        raise ValueError("conv_gqa runs one document a program")
+    dtype, f32, d = cfg.compute_dtype, jnp.float32, cfg.d_model
+    proj = _project(p["w_conv_in"], h[0], dtype)                 # [S, 3 d]
+    with part("mixer"), jax.named_scope("conv_gate"):
+        B, C, z = (proj[:, i * d:(i + 1) * d].astype(f32) for i in range(3))
+        c, tail = ssd.causal_conv(B * z, state["tail"][0], p["conv_w"])
+        y = (C * c).astype(dtype)
+    return _project(p["wo"], y[None], dtype), {"tail": tail[None]}
+
+
+def _conv_gqa_state(cfg: DecoderLMConfig, batch: int, cache_len: int):
+    """Before a document's first token, by KIND: a ``conv`` layer's tail,
+    float32 zeros of ``conv_taps - 1`` rows (what lies before a document is
+    0); an attention layer's empty key and value cache of ``cache_len``
+    (padded) tokens in the stored dtype, laid out as the attention kernel
+    reads it (``causal_attention.cache_shape``)."""
+    from agent_tpu.kernels.causal_attention import cache_shape
+
+    by_layer = kinds_by_layer(cfg)
+    cache = (by_layer.count("full"), batch,
+             *cache_shape(cfg.n_kv_heads, cache_len, cfg.d_head))
+    return {"conv": {"tail": jnp.zeros(
+                (by_layer.count("conv"), batch, cfg.conv_taps - 1,
+                 cfg.d_model), jnp.float32)},
+            "full": {"k": jnp.zeros(cache, cfg.compute_dtype),
+                     "v": jnp.zeros(cache, cfg.compute_dtype)}}
+
+
 # mixer name → fn(layer params, normed h, positions, state, cfg, opts)
 # → (what enters the residual [B, L, d], new state); a mixer whose layers
 # come in kinds (``layer_kinds``) takes the layer's ``kind`` besides. One
@@ -1322,7 +1450,8 @@ MIXERS: Dict[str, Callable] = {"power_retention": _power_retention_mixer,
                                "hybrid_ssm": _hybrid_ssm_mixer,
                                "dense_mla": _dense_mla_mixer,
                                "window_gqa": _window_gqa_mixer,
-                               "hybrid_kda": _hybrid_kda_mixer}
+                               "hybrid_kda": _hybrid_kda_mixer,
+                               "conv_gqa": _conv_gqa_mixer}
 # mixer name → fn(cfg, batch, cache_len) → the state before a document's
 # first segment, for the mixers whose state is allocated (a cache); the
 # others start from ``None``.
@@ -1330,7 +1459,8 @@ MIXER_STATES: Dict[str, Callable] = {"sparse_mla": _sparse_mla_state,
                                      "hybrid_ssm": _hybrid_ssm_state,
                                      "dense_mla": _dense_mla_state,
                                      "window_gqa": _window_gqa_state,
-                                     "hybrid_kda": _hybrid_kda_state}
+                                     "hybrid_kda": _hybrid_kda_state,
+                                     "conv_gqa": _conv_gqa_state}
 # mixer name → which of its state is a cache that grows with the document and
 # that the mixer writes and reads IN PLACE: booleans in a tree that is a
 # prefix of the state's own. Those leaves are the layer scan's carry
@@ -1340,7 +1470,8 @@ MIXER_STATES: Dict[str, Callable] = {"sparse_mla": _sparse_mla_state,
 # kernels take standalone operands).
 MIXER_CACHES: Dict[str, Any] = {
     "hybrid_ssm": {"k": True, "v": True, "ssm": False, "conv": False},
-    "window_gqa": {"full": True, "window": False}}
+    "window_gqa": {"full": True, "window": False},
+    "conv_gqa": {"full": True, "conv": False}}
 
 
 def starts_from_nothing(cfg: DecoderLMConfig) -> bool:
@@ -1790,6 +1921,21 @@ def _hybrid_kda_flops(cfg: DecoderLMConfig, t: float, pos0: int):
             sum(mixer[kind] for kind in kinds) / len(kinds))
 
 
+def _conv_gqa_flops(cfg: DecoderLMConfig, t: float, pos0: int):
+    d = float(cfg.d_model)
+    dh, hq, hkv = cfg.d_head, cfg.n_heads, cfg.n_kv_heads
+    # A layer's projections and its mixer's own work by its KIND: the two
+    # gates and the taps on a conv layer, every causal pair's score and value
+    # product on an attention layer; the mean over the model's layers.
+    kinds = kinds_by_layer(cfg)
+    proj = {"conv": 2.0 * d * 4 * d,
+            "full": 2.0 * d * (2 * hq * dh + 2 * hkv * dh)}
+    mixer = {"conv": (2.0 * cfg.conv_taps + 2.0) * d,
+             "full": 4.0 * hq * dh * (pos0 + t / 2.0)}
+    return (sum(proj[kind] for kind in kinds) / len(kinds),
+            sum(mixer[kind] for kind in kinds) / len(kinds))
+
+
 # mixer name → fn(cfg, tokens, pos0) → (projections', mixer's own) FLOPs a
 # token a layer of a segment of ``tokens`` that starts at ``pos0``.
 MIXER_FLOPS: Dict[str, Callable] = {"power_retention": _retention_flops,
@@ -1797,7 +1943,8 @@ MIXER_FLOPS: Dict[str, Callable] = {"power_retention": _retention_flops,
                                     "hybrid_ssm": _hybrid_flops,
                                     "dense_mla": _latent_flops,
                                     "window_gqa": _window_gqa_flops,
-                                    "hybrid_kda": _hybrid_kda_flops}
+                                    "hybrid_kda": _hybrid_kda_flops,
+                                    "conv_gqa": _conv_gqa_flops}
 
 
 def segment_flops(cfg: DecoderLMConfig, n_tokens: int, pos0: int) -> float:

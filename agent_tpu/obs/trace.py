@@ -706,6 +706,20 @@ def record_kda_tokens(path: str, tokens: int) -> None:
                float(tokens), path=path)
 
 
+def record_conv_tail_tokens(path: str, tokens: int) -> None:
+    """Real tokens DISPATCHED to the double-gated short convolutions whose
+    segment program did (``carried``) or did not (``first_segment``: a
+    document's first, whose tail is the zeros before the document) read a
+    tail handed on by the segment before: counted by the op at dispatch,
+    from the segment each token falls in."""
+    if tokens > 0:
+        _count("conv_tail_tokens_total",
+               "Tokens dispatched to a gated short-convolution layer, by "
+               "whether the token's segment read a carried tail (carried) or "
+               "was a document's first (first_segment)",
+               float(tokens), path=path)
+
+
 def record_kda_chunks(chunks: int) -> None:
     """Chunks the delta-rule kernel walks for a DISPATCHED shard, a head a
     linear layer (its segments' padding included): counted by the op from

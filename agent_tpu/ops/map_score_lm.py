@@ -49,7 +49,13 @@ the host. What that state is depends on the mixer (``models/decoder_lm.py``):
   layer's FIXED-SIZE float32 state (``[heads, 128, 128]``) and its
   convolution's last three inputs, a latent layer's CACHE of latents at the
   document's padded length (``dense_mla``'s); the empty ones are zeros,
-  allocated, donated and keyed as the other caches are.
+  allocated, donated and keyed as the other caches are;
+- ``conv_gqa``: two kinds of state by the layer's kind, one of them next to
+  nothing: a ``conv`` layer's TAIL, the last ``conv_taps - 1`` rows of its
+  gated input (two rows of ``d_model`` float32, whatever the document's
+  length), and an attention layer's key and value cache at the document's
+  padded length, heads of 64 two a row of 128 lanes (allocated, donated and
+  keyed as ``hybrid_ssm``'s).
   Where the model has
   expert layers the state also carries the count of (token, expert) pairs
   routed to the experts held here; it comes back with the block sums in the
@@ -325,28 +331,47 @@ def _record_dense_latent(state: Dict[str, Any]) -> None:
     obs_trace.record_latent_keys("cached", cached)
 
 
-def _record_window_gqa(state: Dict[str, Any]) -> None:
-    """A query head a layer of each kind: on a full layer the causal pairs a
-    shard's real tokens need beside those in the key tiles the attention
-    kernel's grid visits (at the query tile the model's heads a key head
-    take); on a window layer the pairs inside their windows (``min(t + 1,
-    sliding_window)`` for token ``t``) beside those in the tiles the window
-    kernel's grid visits."""
+def _full_layer_pairs(state: Dict[str, Any]) -> Tuple[int, int]:
+    """A query head a layer that attends every causal key through the layers'
+    stacked cache (``window_gqa``'s full kind, which ``conv_gqa`` runs too):
+    the causal pairs a shard's real tokens need, and those in the key tiles
+    the attention kernel's grid visits at the query tile the stacked heads of
+    a cache row take (the query heads of one key-value head, or of the PAIR
+    that heads of half a lane tile lie as)."""
     from agent_tpu.kernels.causal_attention import (
-        query_tile, visited_pairs, window_visited_pairs)
+        heads_a_row, query_tile, visited_pairs)
+
+    cfg = state["cfg"]
+    stacked = cfg.n_heads // cfg.n_kv_heads * heads_a_row(cfg.n_kv_heads,
+                                                         cfg.d_head)
+    causal = computed = 0
+    for doc in state["docs"]:
+        causal += doc["n_tokens"] * (doc["n_tokens"] + 1) // 2
+        computed += sum(
+            visited_pairs(ids.shape[1], pos0, query_tile(stacked, ids.shape[1]))
+            for ids, _, _, pos0 in doc["segments"])
+    return causal, computed
+
+
+def _record_window_gqa(state: Dict[str, Any]) -> None:
+    """A query head a layer of each kind: on a full layer
+    :func:`_full_layer_pairs`; on a window layer the pairs inside their
+    windows (``min(t + 1, sliding_window)`` for token ``t``) beside those in
+    the tiles the window kernel's grid visits."""
+    from agent_tpu.kernels.causal_attention import (
+        query_tile, window_visited_pairs)
 
     cfg = state["cfg"]
     w, groups = int(cfg.sliding_window), cfg.n_heads // cfg.n_kv_heads
-    causal = computed = in_window = window_computed = 0
+    in_window = window_computed = 0
     for doc in state["docs"]:
         n, ramp = doc["n_tokens"], min(doc["n_tokens"], w)
-        causal += n * (n + 1) // 2
         in_window += ramp * (ramp + 1) // 2 + (n - ramp) * w
-        for ids, _, _, pos0 in doc["segments"]:
-            bucket = ids.shape[1]
-            tq = query_tile(groups, bucket)
-            computed += visited_pairs(bucket, pos0, tq)
-            window_computed += window_visited_pairs(bucket, pos0, w, tq)
+        window_computed += sum(
+            window_visited_pairs(ids.shape[1], pos0, w,
+                                 query_tile(groups, ids.shape[1]))
+            for ids, _, _, pos0 in doc["segments"])
+    causal, computed = _full_layer_pairs(state)
     obs_trace.record_causal_attention_pairs("causal", causal)
     obs_trace.record_causal_attention_pairs("computed", computed)
     obs_trace.record_window_attention_pairs("window", in_window)
@@ -374,13 +399,32 @@ def _record_hybrid_kda(state: Dict[str, Any]) -> None:
     _record_dense_latent(state)
 
 
+def _record_conv_gqa(state: Dict[str, Any]) -> None:
+    """Of a shard's real tokens, those whose ``conv`` layers read a carried
+    tail (every segment program but a document's first starts from the rows
+    the one before handed on) and those of a document's first segment, whose
+    tail is the zeros before the document; and what its attention layers
+    need and visit (:func:`_full_layer_pairs`)."""
+    first = carried = 0
+    for doc in state["docs"]:
+        head = min(doc["n_tokens"], doc["segments"][0][0].shape[1])
+        first += head
+        carried += doc["n_tokens"] - head
+    causal, computed = _full_layer_pairs(state)
+    obs_trace.record_conv_tail_tokens("carried", carried)
+    obs_trace.record_conv_tail_tokens("first_segment", first)
+    obs_trace.record_causal_attention_pairs("causal", causal)
+    obs_trace.record_causal_attention_pairs("computed", computed)
+
+
 # mixer → what the op counts of a shard at dispatch, from its lengths.
 _MIXER_COUNTERS = {"power_retention": _record_retention,
                    "sparse_mla": _record_sparse_keys,
                    "hybrid_ssm": _record_hybrid,
                    "dense_mla": _record_dense_latent,
                    "window_gqa": _record_window_gqa,
-                   "hybrid_kda": _record_hybrid_kda}
+                   "hybrid_kda": _record_hybrid_kda,
+                   "conv_gqa": _record_conv_gqa}
 
 
 def execute(state: Dict[str, Any], ctx: Optional[object] = None) -> Dict[str, Any]:

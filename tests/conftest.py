@@ -39,7 +39,7 @@ LONGEST_FIRST = (
     "test_sparse_mla.py", "test_window_moe.py", "test_tpu_compile.py",
     "test_hybrid_ssm.py", "test_decoder_lm.py", "test_latent_mla.py",
     "test_hybrid_kda.py",
-    "test_flash_attention.py", "test_parts.py",
+    "test_flash_attention.py", "test_parts.py", "test_conv_gqa.py",
 )
 
 

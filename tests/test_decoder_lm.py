@@ -115,6 +115,13 @@ ONE_OF_EACH_MIXER = {
                        n_expert_groups=4, n_groups_per_token=2,
                        expert_swiglu_limits=(0.0, 4.0, 4.0),
                        shared_swiglu_limits=(0.0, 5.0, 7.0)),
+    "conv_gqa": dict(vocab_size=500, d_model=64, n_heads=4, n_kv_heads=2,
+                     d_head=64, d_ff=96, n_layers=5, mixer="conv_gqa",
+                     layer_types=("conv", "full_attention", "conv",
+                                  "full_attention", "conv"),
+                     n_dense_layers=1, n_experts=8, n_experts_held=8,
+                     n_experts_per_token=2, n_expert_groups=1,
+                     n_groups_per_token=1, d_expert=32, n_shared_experts=0),
 }
 
 

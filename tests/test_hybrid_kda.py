@@ -512,7 +512,7 @@ def test_the_tables_have_the_sixth_mixer():
         decoder_lm.MIXER_FLOPS) >= {"hybrid_kda"}
     assert "hybrid_kda" in decoder_lm.MIXER_STATES
     assert "hybrid_kda" not in decoder_lm.MIXER_CACHES     # stepped, as mistral's
-    assert set(decoder_lm.MIXER_KINDS) == {"window_gqa", "hybrid_kda"}
+    assert set(decoder_lm.MIXER_KINDS) >= {"window_gqa", "hybrid_kda"}
     cfg = decoder_lm.DecoderLMConfig(**TINY)
     assert decoder_lm.layer_kinds(cfg) == ("linear", "linear", "latent")
     assert decoder_lm.group_kinds(cfg, "dense") == ("linear",)

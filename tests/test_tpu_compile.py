@@ -974,3 +974,106 @@ def test_hybrid_kda_segment_program_keeps_its_stacks_where_they_lie_on_v5e(v5e):
     assert memory.alias_size_in_bytes >= state_bytes        # written in place
     assert memory.temp_size_in_bytes < 1.3e9
     assert 10.1e9 < memory.argument_size_in_bytes < 10.3e9
+
+
+def test_half_lane_attention_compiles_for_v5e(v5e):
+    """The attention kernel at the ``lfm2-24b-a2b`` cell's shape (32 query
+    over 8 key-value heads of 64, a 4,096-token segment, the two attention
+    layers' stack of 32,768-token caches, two heads a row of 128 lanes) for a
+    described v5e: ONE custom call under the name the benchmark's readers
+    find it by, on operands of whole lanes; the stack is the call's operand,
+    not a layer's slice of it."""
+    import re
+
+    from agent_tpu.kernels import causal_attention as ca
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=chip)
+    assert ca.cache_shape(8, 32768, 64) == (4, 32768, 128)
+    assert ca.pallas_supported(4096, 32768, 64, jnp.bfloat16, 64)
+    stack = sd((2, 1, 4, 32768, 128), jnp.bfloat16)
+    done = jax.jit(lambda q, k, v, pos0, layer: ca.causal_attention(
+        q, k, v, pos0, layer, pallas=True, interpret=False)).lower(
+        sd((8, 4, 4096, 64), jnp.bfloat16), stack, stack,
+        sd((), jnp.int32), sd((), jnp.int32)).compile()
+    calls = [ln for ln in done.as_text().splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == 1
+    assert re.search(r"%causal_gqa_attention\S* = bf16\[4,4,4096,128\]", calls[0])
+    assert calls[0].count("bf16[2,1,4,32768,128]") >= 2
+    assert done.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_conv_gqa_segment_program_keeps_its_stacks_where_they_lie_on_v5e(v5e):
+    """The whole later-segment program of the ``lfm2-24b-a2b`` cell (a
+    leading dense ``conv`` layer and two PERIODS of an attention layer and
+    three ``conv`` layers at the published widths, all 64 experts, a
+    32,768-token cache, the state donated) for a described v5e: 13 kernels in
+    the period's body (the half-lane attention once, the grouped experts'
+    three four times). The attention layers' caches are the period scan's
+    carry: the call takes the two layers' stack, and nothing moves a layer's
+    cache or the stack. A kind's leaves are a STACK of that kind's layers and
+    the expert stacks the loop's invariant: nothing but a parameter holds
+    one. The parameters: every leaf but the head (the loss head's own
+    program), 5,312,168,704 - 134,217,728 of them."""
+    import re
+
+    from agent_tpu.models import decoder_lm
+    from benchmarks.harness import manifest
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)  # noqa: E731
+    model = manifest.load_config(manifest.load_manifest(),
+                                 "lfm2-24b-a2b")["model"]
+    cfg = decoder_lm.DecoderLMConfig(**model)
+    shapes = lm_once.param_shapes(cfg)
+    assert sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(
+        shapes)) == 5_312_168_704
+    params = jax.tree_util.tree_map(sd, shapes)
+    state = jax.tree_util.tree_map(sd, lm_once.state_shapes(cfg, 1, 32768))
+    ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+
+    def lm_segment(p, i, at, s):
+        return decoder_lm.forward_segment(p, i, at, s, cfg, pallas=True,
+                                          interpret=False)
+
+    lowered = jax.jit(lm_segment, donate_argnums=(3,)).lower(
+        params, ids, pos, state)
+    hidden, carried = lowered.out_info          # of the one trace
+    assert hidden.shape == (1, 4096, 2048)
+    assert set(carried) == {"mixer", "pairs", "tiles"}
+    assert carried["mixer"]["full"]["k"].shape == (2, 1, 4, 32768, 128)
+    assert carried["mixer"]["conv"]["tail"].shape == (7, 1, 2, 2048)
+    done = lowered.compile()
+    text = done.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    count = lambda name: len([ln for ln in calls if re.search(  # noqa: E731
+        r"%%%s\S* = " % name, ln)])
+    assert len(calls) == 13 and " while(" in text
+    assert count("causal_gqa_attention") == 1
+    for name in ("moe_pack_rows", "moe_grouped_swiglu", "moe_combine_pairs"):
+        assert count(name) == 4, name
+    attention, = [ln for ln in calls if "%causal_gqa_attention" in ln]
+    assert attention.count("bf16[2,1,4,32768,128]") == 2
+    assert "bf16[4,32768,128]" not in attention
+    assert _moves_of(text, "(2,1,)?4,32768,128") == []
+    # A kind's stacks (the six conv layers' leaves of the expert group): no
+    # instruction but a parameter holds one; the expert stacks stay the
+    # loop's invariant: a layer's 64 experts are nobody's result. (The two
+    # attention layers' q stack, 16.8 MB, the compiler may fetch into the
+    # core's memory and lay out again ONCE a program, outside the loop: its
+    # choice, a layout marked ``S(1)``.)
+    for stack in (r"6,2048,6144", r"6,2048,2048"):
+        assert not re.search(
+            r"= bf16\[" + stack + r"\]\S* (?!parameter\()", text), stack
+    assert not re.search(
+        r"= bf16\[(1,)?64,(2048,1536|1536,2048)\]\S* (?!parameter\()", text)
+    memory = done.memory_analysis()
+    cache_bytes = 2 * 2 * 4 * 32768 * 128 * 2
+    assert memory.alias_size_in_bytes >= cache_bytes        # written in place
+    assert memory.temp_size_in_bytes < 0.5e9
+    # Every leaf but the head, 2 bytes a matrix entry, and the state.
+    assert 10.4e9 < memory.argument_size_in_bytes < 10.6e9
